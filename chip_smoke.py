@@ -1,0 +1,525 @@
+"""chip_smoke.py — the quickest proof that paddle_tpu still starts on the chip.
+
+Drives the two main paths once, through the entry points a user calls,
+at the full width of the transformer-large configuration (8 layers,
+d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
+
+  device  jax.devices() must be a TPU (no CPU fall-through)
+  train   T.transformer_lm -> Adam.minimize -> amp.enable_amp ->
+          Executor(TPUPlace(0)); 5 steps on one batch; loss ~ ln(vocab)
+          and falling; the flash kernel is in the compiled step
+  serve   TransformerLMInfer + serving.Engine(defaults, 8 slots); 8
+          requests; float32 (paged Pallas kernel) and bfloat16 (gather),
+          each token-identical to serving.sequential_generate
+
+    python chip_smoke.py                 one TPU chip (the driver's run)
+    python chip_smoke.py --chips 4       ONLY the path across chips:
+                                         ParallelExecutor on a dp2 x tp2
+                                         mesh against the one-device run
+    JAX_PLATFORMS=cpu python chip_smoke.py --rehearse
+                                         same phases, tiny size, kernels
+                                         in interpret mode, on the CPU
+                                         (--chips 4 --rehearse wants
+                                         XLA_FLAGS=--xla_force_host_platform_device_count=4)
+
+One process, no network, no children. Any failed phase raises, so the
+exit code is non-zero and the result line is not printed. The last
+line of stdout is one JSON object naming the device JAX reports; a
+rehearsal says "platform": "cpu" there and can never be read as a
+chip pass.
+"""
+
+import argparse
+import json
+import math
+import sys
+import time
+
+import numpy as np
+
+REAL = dict(n_layer=8, n_head=16, d_model=1024, d_inner=4096,
+            max_len=1024, vocab=8192, batch=8, slots=8, requests=8,
+            prompt=(32, 384), max_new=32)
+TINY = dict(n_layer=2, n_head=4, d_model=64, d_inner=256, max_len=128,
+            vocab=512, batch=4, slots=4, requests=4, prompt=(8, 40),
+            max_new=8)
+
+
+def log(msg):
+    print(msg, flush=True)
+
+
+def peak_hbm(phase):
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    log("[%s] peak_bytes_in_use: %s" % (
+        phase, "%d (%.2f GiB)" % (peak, peak / 2 ** 30)
+        if peak is not None else "not reported by this backend"))
+
+
+def compiled_text(jitted, *args):
+    """Optimized HLO of ``jitted`` for these arguments. Lowering
+    neither runs nor donates; after the real call the compile is a
+    persistent-cache hit."""
+    return jitted.lower(*args).compile().as_text()
+
+
+def kernels_in_interpret_mode():
+    """Rehearsal only: steer both attention dispatchers to their
+    Pallas kernels in interpret mode, so the CPU run walks the kernel
+    code the chip will compile. Done here, in the script — the program
+    has no such option."""
+    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.ops import paged_attention as pa
+    fa_resolve = fa._resolve_path
+    fa._resolve_path = lambda q, scale, bq, bk, force: fa_resolve(
+        q, scale, bq, bk, force or "interpret")
+    pa._resolve_path = lambda q, force: force or "interpret"
+
+
+# --------------------------------------------------------------------------
+def phase_device(rehearse, chips, cache_dir):
+    import jax
+    import jaxlib
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "not installed"
+    devs = jax.devices()
+    dev = devs[0]
+    log("[device] platform=%s kind=%s count=%d jax=%s jaxlib=%s "
+        "libtpu=%s" % (dev.platform, dev.device_kind, len(devs),
+                       jax.__version__, jaxlib.__version__,
+                       libtpu_version))
+    log("[device] compile cache: %s" % cache_dir)
+    if not rehearse and dev.platform != "tpu":
+        raise SystemExit(
+            "chip_smoke: no TPU — JAX reports platform %r (%s). This "
+            "script does not fall back to the CPU; a CPU rehearsal is "
+            "`JAX_PLATFORMS=cpu python chip_smoke.py --rehearse`."
+            % (dev.platform, dev.device_kind))
+    if len(devs) < chips:
+        raise SystemExit(
+            "chip_smoke: --chips %d but JAX reports %d %s device(s)"
+            % (chips, len(devs), dev.platform))
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(devs)}
+
+
+def _place(rehearse):
+    import paddle_tpu as fluid
+    return fluid.CPUPlace() if rehearse else fluid.TPUPlace(0)
+
+
+def _persistables(program, scope):
+    """The executors' state argument: the program's persistable
+    variables that exist in ``scope``."""
+    names = [v.name for v in program.global_block().vars.values()
+             if v.persistable]
+    return {n: scope.find_var(n) for n in names
+            if scope.find_var(n) is not None}
+
+
+def _lm_batch(cfg, seed):
+    from paddle_tpu.models import transformer as T
+    feeds = T.make_lm_batch(np.random.RandomState(seed), cfg["batch"],
+                            cfg["max_len"], cfg["vocab"])
+    feeds["mask"] = np.ones_like(feeds["mask"])     # packed sequences
+    return feeds
+
+
+# --------------------------------------------------------------------------
+def phase_train(cfg, seed, rehearse):
+    """benchmarks/transformer.py's build, 5 steps on one batch."""
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import _normalize_feeds
+    from paddle_tpu.models import transformer as T
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
+        avg_cost, _ = T.transformer_lm(
+            vocab_size=cfg["vocab"], max_len=cfg["max_len"],
+            n_layer=cfg["n_layer"], n_head=cfg["n_head"],
+            d_model=cfg["d_model"], d_inner=cfg["d_inner"], packed=True)
+        fluid.optimizer.Adam(learning_rate=1e-4).minimize(avg_cost)
+        fluid.amp.enable_amp()
+        exe = fluid.Executor(_place(rehearse))
+        exe.run(startup)
+        feeds = _lm_batch(cfg, seed)
+        loader = iter(fluid.reader.DeviceLoader(
+            fluid.reader.repeat_feed(feeds, 5)))
+        losses, block_ms, fetch_ms = [], [], []
+        for step in range(5):
+            feed = next(loader)
+            t0 = time.perf_counter()
+            loss, = exe.run(main, feed=feed, fetch_list=[avg_cost],
+                            return_numpy=False)
+            t_dispatch = time.perf_counter()
+            jax.block_until_ready(loss)
+            t_block = time.perf_counter()
+            losses.append(float(np.asarray(loss)))  # device->host
+            t_fetch = time.perf_counter()
+            if step == 0:
+                log("[train] first step (compile + run): %.1f s"
+                    % (t_block - t0))
+            else:
+                block_ms.append(1e3 * (t_block - t0))
+                fetch_ms.append(1e3 * (t_fetch - t0))
+                log("[train] step %d: dispatch returned %.2f ms, "
+                    "block_until_ready %.2f ms, +device->host "
+                    "fetch %.2f ms" % (
+                        step, 1e3 * (t_dispatch - t0), block_ms[-1],
+                        fetch_ms[-1]))
+        log("[train] losses: %s" % " ".join("%.4f" % x
+                                            for x in losses))
+        log("[train] step ms (smoke timing, steps 1-4): "
+            "block_until_ready median %.2f, with fetch median %.2f "
+            "-> block_until_ready %s" % (
+                np.median(block_ms), np.median(fetch_ms),
+                "BLOCKS (the fetch adds nothing to wait for)"
+                if np.median(block_ms) > 0.9 * np.median(fetch_ms)
+                else "DOES NOT BLOCK (the fetch did the waiting)"))
+        want = math.log(cfg["vocab"])
+        assert all(math.isfinite(x) for x in losses), losses
+        assert abs(losses[0] - want) <= 0.05 * want, \
+            "first loss %.4f is not within 5%% of ln(%d)=%.4f" % (
+                losses[0], cfg["vocab"], want)
+        assert losses[-1] < losses[0], \
+            "loss did not fall: %r" % (losses,)
+        (entry,) = [fn for key, fn in exe._cache.items()
+                    if key[0] is main]
+        with jax.default_device(exe.place.jax_device()):
+            text = compiled_text(entry, _persistables(main, scope),
+                                 _normalize_feeds(feeds)[0],
+                                 jax.random.key(0))
+        n_kernels = text.count("tpu_custom_call")
+        log("[train] tpu_custom_call sites in the compiled step: %d"
+            % n_kernels)
+        if not rehearse:
+            assert n_kernels > 0, \
+                "no tpu_custom_call in the train step: the flash " \
+                "kernel did not run (dense branch taken)"
+        fluid.amp.enable_amp(False)
+    peak_hbm("train")
+
+
+# --------------------------------------------------------------------------
+def _requests(cfg, seed):
+    rng = np.random.RandomState(seed)
+    lo, hi = cfg["prompt"]
+    reqs = []
+    for _ in range(cfg["requests"]):
+        plen = int(rng.randint(lo, hi + 1))
+        prompt = [1] + rng.randint(3, cfg["vocab"], plen - 1).tolist()
+        reqs.append((prompt, cfg["max_new"]))
+    return reqs
+
+
+def _reference_logits(step, infer, prompt, toks):
+    """The sequential path's logits for its NEXT token after emitting
+    ``toks``: ``step`` is ``jit(model._step_logits)`` at batch 1 — the
+    step ``sequential_generate`` jits — teacher-forced along ``prompt +
+    toks``. Only used to judge a divergence."""
+    import jax.numpy as jnp
+    state = infer._init_state(1)
+    for t, tok in enumerate(list(prompt) + list(toks)):
+        logits, state = step(jnp.full((1,), tok, jnp.int32), state,
+                             np.int32(t))
+    return np.asarray(logits[0], np.float32)
+
+
+# How far below the reference's top logit (relative to it) the engine's
+# token may sit where the two streams part. The engine batches 8 rows
+# where the baseline runs 1, so XLA may tile and fuse the same math
+# differently and the last bits differ: float32 accumulation order, or
+# one or two bfloat16 steps (2**-7 each). A random-weight model's top
+# logits are bunched that closely — in bfloat16 they TIE exactly — and
+# argmax then parts the streams. Anything further off is a fault.
+NEAR_TIE = {"float32": 1e-5, "bfloat16": 2 * 2.0 ** -7}
+
+
+def compare_with_sequential(label, infer, step, reqs, want, got):
+    """Engine tokens must equal ``sequential_generate``'s. Where a
+    request's streams part, the engine's token must be a near-tie of
+    the reference's own choice there (NEAR_TIE, judged on ``step`` =
+    ``jit(infer._step_logits)``); what follows a parting has another
+    context and is not compared. Returns the count of exactly
+    identical requests."""
+    exact = 0
+    for i, ((wt, _), (gt, _)) in enumerate(zip(want, got)):
+        if gt == wt:
+            exact += 1
+            continue
+        j = next(k for k, (a, b) in enumerate(zip(gt, wt)) if a != b)
+        logits = _reference_logits(step, infer, reqs[i][0], wt[:j])
+        top = float(logits.max())
+        gap = top - float(logits[gt[j]])
+        tol = NEAR_TIE[label] * abs(top)
+        log("[serve %s] request %d parts from sequential at token %d: "
+            "engine %d, sequential %d; the engine's token is %.3g below "
+            "the reference's top logit %.4g (near-tie tolerance %.3g)"
+            % (label, i, j, gt[j], wt[j], gap, top, tol))
+        assert gap <= tol, \
+            "%s engine request %d token %d is no near-tie of the " \
+            "sequential baseline: a real divergence" % (label, i, j)
+    return exact
+
+
+def phase_serve(cfg, seed, rehearse):
+    """The same model through TransformerLMInfer + serving.Engine with
+    default options: float32 (block kernel) then bfloat16 (gather)."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as fluid
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.models.transformer_infer import TransformerLMInfer
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
+        T.transformer_lm(
+            vocab_size=cfg["vocab"], max_len=cfg["max_len"],
+            n_layer=cfg["n_layer"], n_head=cfg["n_head"],
+            d_model=cfg["d_model"], d_inner=cfg["d_inner"])
+        fluid.Executor(_place(rehearse)).run(startup)
+    reqs = _requests(cfg, seed)
+    log("[serve] %d requests, prompt lengths %s, %d new tokens each"
+        % (len(reqs), [len(p) for p, _ in reqs], cfg["max_new"]))
+
+    for label, dtype in (("float32", None), ("bfloat16", jnp.bfloat16)):
+        # float32 is checked at float32 matmul precision. The TPU's
+        # default multiplies float32 operands in bfloat16 passes, and
+        # that rounding depends on the batch shape: measured on the
+        # chip (PERF.md, PR 21), at the default the float32 engine
+        # parts from the batch-1 baseline at top-2 margins of 4e-4 to
+        # 2e-3 with the block kernel AND with gather alike; at
+        # "highest" both are token-identical. Set process-wide, not as
+        # a context: the engine traces on its own thread.
+        jax.config.update("jax_default_matmul_precision",
+                          "highest" if dtype is None else None)
+        # end_id past the vocab: a random model must not stop early
+        infer = TransformerLMInfer(
+            main, scope, cfg["n_layer"], cfg["n_head"], cfg["d_model"],
+            cfg["max_len"], dtype=dtype, end_id=cfg["vocab"])
+        t0 = time.perf_counter()
+        want = serving.sequential_generate(infer, reqs)
+        seq_s = time.perf_counter() - t0
+        eng = serving.Engine(infer, slots=cfg["slots"],
+                             name="smoke-" + label)
+        try:
+            t0 = time.perf_counter()
+            eng.warmup()
+            warm_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            handles = [eng.submit(p, m) for p, m in reqs]
+            got = [h.result(timeout=900) for h in handles]
+            wall_s = time.perf_counter() - t0
+            # the decode step's LOWERED text, not its compiled one:
+            # the engine's programs close over the weights, so their
+            # executables (0.5-1.3 GB here) are refused by a bounded
+            # persistent cache and compiling again costs 12-30 s. A
+            # Pallas kernel is a custom call already at this level.
+            avals = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                eng._state)
+            n_kernels = eng._step_fn.lower(
+                avals, eng._btab_all(), False).as_text().count(
+                    "tpu_custom_call")
+            path = ("gather" if not eng._block_kernel else
+                    "block kernel (%s)" % (
+                        "pallas, %d tpu_custom_call sites" % n_kernels
+                        if n_kernels else "pallas interpret" if rehearse
+                        else "lax"))
+            log("[serve %s] attention path: %s" % (label, path))
+            for i, (gt, _) in enumerate(got):
+                log("[serve %s] request %d tokens: %s" % (label, i, gt))
+            log("[serve %s] engine wall %.2f s (first use: prefill and "
+                "activate compile inside), decode-step warmup compile "
+                "%.2f s, sequential baseline %.2f s; ttft s: %s" % (
+                    label, wall_s, warm_s, seq_s,
+                    ["%.2f" % h.ttft for h in handles]))
+            log("[serve %s] eng.stats: %s" % (label, json.dumps(
+                eng.stats, sort_keys=True)))
+            assert all(len(gt) == cfg["max_new"] for gt, _ in got)
+            ref_step = jax.jit(infer._step_logits)
+            exact = compare_with_sequential(label, infer, ref_step,
+                                            reqs, want, got)
+            log("[serve %s] identical to sequential: %s" % (
+                label, "True (%d/%d requests)" % (exact, len(reqs))
+                if exact == len(reqs) else
+                "%d/%d requests exactly; the rest part at a near-tie "
+                "of the reference's own logits" % (exact, len(reqs))))
+            # once more, now that every program is compiled and the
+            # radix cache holds the prompts: a wall with no compile in
+            # it, and the prefix-cache path on the device
+            hits0 = eng.stats["prefix_hit_tokens"]
+            t0 = time.perf_counter()
+            handles = [eng.submit(p, m) for p, m in reqs]
+            again = [h.result(timeout=900) for h in handles]
+            again_s = time.perf_counter() - t0
+            exact = compare_with_sequential(label, infer, ref_step,
+                                            reqs, want, again)
+            log("[serve %s] second pass (compiled; %d prompt tokens "
+                "from the prefix cache): wall %.2f s for %d tokens, "
+                "ttft median %.3f s, time per output token median "
+                "%.1f ms (smoke timings); %d/%d requests identical "
+                "to sequential" % (
+                    label, eng.stats["prefix_hit_tokens"] - hits0,
+                    again_s, sum(len(t) for t, _ in again),
+                    np.median([h.ttft for h in handles]),
+                    1e3 * np.median([h.tpot for h in handles]),
+                    exact, len(reqs)))
+            if dtype is None:
+                assert eng._block_kernel, \
+                    "float32 engine did not default to the block kernel"
+                if not rehearse:
+                    assert n_kernels > 0, "no tpu_custom_call in the " \
+                        "float32 decode step: paged kernel did not run"
+            else:
+                assert not eng._block_kernel, \
+                    "bfloat16 un-quantized engine left the gather default"
+        finally:
+            eng.close()
+            jax.config.update("jax_default_matmul_precision", None)
+        peak_hbm("serve " + label)
+
+
+# --------------------------------------------------------------------------
+def phase_multichip(cfg, seed, rehearse):
+    """transformer-large through ParallelExecutor on a dp2 x tp2 mesh,
+    3 steps, against the one-device Executor run of the same program
+    from the same initial parameters."""
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu import parallel
+    from paddle_tpu.core.executor import _normalize_feeds
+    from paddle_tpu.models import transformer as T
+
+    axes = {"dp": 2, "tp": 2}
+    mesh = parallel.make_mesh(axes)
+    strategy = parallel.DistributedStrategy(**axes)
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    feeds = _lm_batch(cfg, seed)
+    steps = 3
+    with fluid.program_guard(main, startup):
+        avg_cost, _ = T.transformer_lm_parallel(
+            vocab_size=cfg["vocab"], max_len=cfg["max_len"],
+            n_layer=cfg["n_layer"], n_head=cfg["n_head"],
+            d_model=cfg["d_model"], d_inner=cfg["d_inner"],
+            strategy=strategy)
+        fluid.optimizer.Adam(learning_rate=1e-4).minimize(avg_cost)
+        fluid.amp.enable_amp()
+        one, four = fluid.Scope(), fluid.Scope()
+        exe = fluid.Executor(_place(rehearse))
+        with fluid.scope_guard(one):
+            exe.run(startup)
+        for n, v in _persistables(main, one).items():
+            four.set(n, np.array(np.asarray(v)))
+        singles = []
+        with fluid.scope_guard(one):
+            for _ in range(steps):
+                loss, = exe.run(main, feed=feeds,
+                                fetch_list=[avg_cost])
+                singles.append(float(np.asarray(loss)))
+        peak_hbm("chips4 one-device baseline")
+        pexe = fluid.ParallelExecutor(
+            loss_name=avg_cost.name, main_program=main, mesh=mesh,
+            scope=four)
+        shardeds, step_s = [], []
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            loss, = pexe.run([avg_cost], feed=feeds)
+            shardeds.append(float(np.asarray(loss)))
+            step_s.append(time.perf_counter() - t0)
+        log("[chips4] one-device losses: %s" % singles)
+        log("[chips4] dp2 x tp2 losses:  %s" % shardeds)
+        log("[chips4] step wall s (first compiles): %s"
+            % ["%.2f" % s for s in step_s])
+        # __graft_entry__.dryrun_multichip's loss tolerance for a
+        # reduced-precision composition
+        tol = 2e-3
+        for i, (ls, lp) in enumerate(zip(singles, shardeds)):
+            assert math.isfinite(lp), lp
+            assert abs(lp - ls) <= tol * max(1.0, abs(ls)), \
+                "step %d: sharded loss %r != one-device %r " \
+                "(tol %g)" % (i, lp, ls, tol)
+        # tp-sharded weights really occupy four devices, each
+        # holding the shard its hint describes
+        hints = sorted(main._sharding_hints.items())
+        assert hints, "program carries no tp sharding hints"
+        for name, spec in hints:
+            arr = four.find_var(name)
+            shards = arr.addressable_shards
+            devs = {s.device for s in shards}
+            assert len(devs) == 4, \
+                "%s lives on %d device(s): %s" % (name, len(devs),
+                                                  devs)
+            want = tuple(dim // (mesh.shape[ax] if ax else 1)
+                         for dim, ax in zip(arr.shape, spec))
+            assert {s.data.shape for s in shards} == {want}, \
+                (name, spec, [s.data.shape for s in shards])
+        log("[chips4] %d tp-sharded weights, each on 4 distinct "
+            "devices with the expected shard shapes" % len(hints))
+        (entry,) = pexe._cache.values()
+        feeds_dev = {k: jax.device_put(v, pexe._data_sharding())
+                     for k, v in _normalize_feeds(feeds)[0].items()}
+        text = compiled_text(entry, _persistables(main, four),
+                             feeds_dev, jax.random.key(0))
+        found = {c: text.count(c) for c in (
+            "all-reduce", "all-gather", "reduce-scatter",
+            "collective-permute", "all-to-all", "tpu_custom_call")}
+        log("[chips4] compiled step: %s" % found)
+        assert found["all-reduce"] or found["reduce-scatter"], \
+            "no collective in the compiled dp2 x tp2 step"
+        if not rehearse:
+            assert found["tpu_custom_call"], \
+                "flash kernel missing from the sharded step"
+        fluid.amp.enable_amp(False)
+    peak_hbm("chips4")
+
+
+# --------------------------------------------------------------------------
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4: run ONLY the ParallelExecutor phase on a "
+                         "dp2 x tp2 mesh and its one-device baseline")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU rehearsal: tiny size, Pallas kernels in "
+                         "interpret mode, no tpu_custom_call "
+                         "assertions; the last line says platform cpu")
+    args = ap.parse_args()
+
+    from paddle_tpu import compile_cache
+    cache_dir = compile_cache.configure()
+    log("[cache] %d entries in %s at start"
+        % (compile_cache.entries(cache_dir), cache_dir))
+    t0 = time.perf_counter()
+    device = phase_device(args.rehearse, args.chips, cache_dir)
+    cfg = TINY if args.rehearse else REAL
+    if args.rehearse:
+        kernels_in_interpret_mode()
+    log("[config] %s%s" % (json.dumps(cfg), "  (REHEARSAL, tiny)"
+                           if args.rehearse else ""))
+    if args.chips == 4:
+        phase_multichip(cfg, args.seed, args.rehearse)
+    else:
+        phase_train(cfg, args.seed, args.rehearse)
+        phase_serve(cfg, args.seed, args.rehearse)
+    log("[cache] %d entries in %s at end"
+        % (compile_cache.entries(cache_dir), cache_dir))
+    log("[done] all phases passed in %.1f s" % (time.perf_counter() - t0))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -36,7 +36,7 @@ def _aval_size(aval):
 
 
 def has_subjaxpr(eqn):
-    """True for call-like eqns (scan/while/cond/pjit/shard_map...) whose
+    """True for call-like eqns (scan/while/cond/jit/shard_map...) whose
     cost lives in their inner jaxpr — counted there, not on the eqn."""
     for _ in sub_jaxprs(eqn):
         return True

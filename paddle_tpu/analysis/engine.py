@@ -17,12 +17,8 @@ lazily built static cost table.
 
 import numpy as np
 import jax
+from jax.extend.core import Jaxpr, ClosedJaxpr, Var, Literal
 from jax.tree_util import tree_flatten_with_path, keystr
-
-try:  # the public jaxpr types; jax.core keeps them across 0.4.x
-    from jax.core import Jaxpr, ClosedJaxpr, Var, Literal
-except ImportError:  # pragma: no cover - future jax moved them
-    from jax._src.core import Jaxpr, ClosedJaxpr, Var, Literal
 
 from .diagnostics import Diagnostic, Report, severity_rank
 
@@ -33,7 +29,7 @@ __all__ = ["Analysis", "GraphView", "Rule", "register_rule",
 
 def sub_jaxprs(eqn):
     """Yield (param_name, Jaxpr) for every jaxpr nested in an eqn's
-    params — scan/while bodies, cond branches, pjit/shard_map/custom_*
+    params — scan/while bodies, cond branches, jit/shard_map/custom_*
     calls — whatever the primitive calls them."""
     for name, val in eqn.params.items():
         vals = val if isinstance(val, (tuple, list)) else (val,)
@@ -150,15 +146,14 @@ class Analysis:
 
     # call-like eqns whose operands/results map 1:1 onto the inner
     # jaxpr's invars/outvars — the resolver walks through them (jnp
-    # ufuncs, custom_jvp bodies etc. show up as pjit wrappers)
+    # ufuncs, custom_jvp bodies etc. show up as jit wrappers)
     CALL_PRIMS = frozenset({
-        "pjit", "closed_call", "core_call", "custom_jvp_call",
-        "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
-        "checkpoint", "custom_lin"})
+        "jit", "closed_call", "core_call", "custom_jvp_call",
+        "custom_vjp_call", "remat2", "custom_lin"})
 
     def resolve_producer(self, view, var):
         """Walk back to the eqn that actually computes ``var``: through
-        shape/dtype-only eqns, into call-like bodies (pjit/custom_*),
+        shape/dtype-only eqns, into call-like bodies (jit/custom_*),
         and back out through their invars. Returns (view, eqn) — eqn is
         None when the value is a program input / constant / literal."""
         for _ in range(256):

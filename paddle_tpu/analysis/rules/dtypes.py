@@ -22,7 +22,7 @@ _F32 = np.dtype(np.float32)
 _ACCUMULATING = {
     "dot_general", "conv_general_dilated", "reduce_sum", "reduce_max",
     "reduce_min", "reduce_prod", "cumsum", "scan", "while", "cond",
-    "pjit", "custom_vjp_call", "custom_jvp_call", "shard_map", "sort",
+    "jit", "custom_vjp_call", "custom_jvp_call", "shard_map", "sort",
     "reduce_precision", "argmax", "argmin",
 }
 

@@ -109,8 +109,7 @@ class RecompileHazardRule(Rule):
                 % n_scalar,
                 hint="stack related scalars into one array argument")
         for const in a.closed_jaxpr.consts:
-            nb = aval_nbytes(const.aval) if hasattr(const, "aval") \
-                else float(getattr(const, "nbytes", 0))
+            nb = aval_nbytes(const)     # anything with shape + dtype
             if nb >= self.const_min_bytes:
                 shape = getattr(const, "shape", ())
                 yield Diagnostic(

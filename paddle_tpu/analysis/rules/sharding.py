@@ -57,13 +57,12 @@ class ShardingTransferRule(Rule):
                          "executor's donated state carry buffers")
                 continue
             if prim == "shard_map":
-                in_names = eqn.params.get("in_names") or ()
-                for var, names in zip(eqn.invars, in_names):
+                for var, spec in zip(eqn.invars, eqn.params["in_specs"]):
                     aval = getattr(var, "aval", None)
                     if aval is None:
                         continue
                     nb = aval_nbytes(aval)
-                    if not names and nb >= self.replicated_min_bytes:
+                    if not any(spec) and nb >= self.replicated_min_bytes:
                         yield Diagnostic(
                             self.name, WARNING,
                             "fully-replicated operand (%s, %s) enters "

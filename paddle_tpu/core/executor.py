@@ -379,6 +379,7 @@ class Executor:
         if not isinstance(place, Place):
             raise TypeError("place must be a Place, got %r" % (place,))
         self.place = place
+        place.jax_device()        # no such device here: raise now
         self._cache = {}          # cache key -> (jitted fn, state_keys, static info)
         # zero-copy host feed path: repeated-shape run() calls skip the
         # per-call normalization derivation and reuse committed device

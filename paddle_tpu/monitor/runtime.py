@@ -376,21 +376,19 @@ def _auto_peak_flops():
         v = 0.0
     if v > 0:
         return v
-    try:
-        import jax
-        dev = jax.local_devices()[0]
-        if dev.platform == "tpu":
-            # single-chip bf16 peak by generation (dense); unknown kinds
-            # fall back to the v5e figure BASELINE.json benches against
-            kind = getattr(dev, "device_kind", "").lower()
-            table = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
-                     "v5p": 459e12, "v6": 918e12}
-            for k, f in table.items():
-                if k in kind:
-                    return f
-            return 197e12
-    except Exception:
-        pass
+    import jax
+    dev = jax.local_devices()[0]
+    if dev.platform != "tpu":
+        return None
+    # single-chip dense bf16 peak by device_kind. A kind not in the
+    # table has NO peak: MFU then goes unreported rather than being
+    # computed against another chip's figure.
+    kind = dev.device_kind.lower()
+    table = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
+             "v5p": 459e12, "v6": 918e12}
+    for k, f in table.items():
+        if k in kind:
+            return f
     return None
 
 

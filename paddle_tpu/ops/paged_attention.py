@@ -65,28 +65,20 @@ __all__ = ["paged_attention", "kv_quant_spec", "quantize_kv",
 # cached (block, position, head) vector stored beside the pool.
 def kv_quant_spec(kind):
     """(pool dtype, qmax) for a kv-quant mode name. int8 is the
-    production path; fp8 (e4m3) is the hook — available only when the
-    installed jax exposes the dtype."""
+    production path; fp8 (e4m3) is the hook."""
     if kind in (None, "", "none", "off"):
         return None
     if kind == "int8":
         return jnp.int8, 127.0
     if kind == "fp8":
-        fp8 = getattr(jnp, "float8_e4m3fn", None)
-        if fp8 is None:
-            raise ValueError(
-                "serving_kv_quant='fp8' needs jnp.float8_e4m3fn, which "
-                "this jax build does not expose; use 'int8'")
-        return fp8, 448.0
+        return jnp.float8_e4m3fn, 448.0
     raise ValueError(
         "unknown kv quantization %r (expected '', 'int8' or 'fp8')"
         % (kind,))
 
 
-_QMAX = {jnp.dtype(jnp.int8): 127.0}
-_FP8 = getattr(jnp, "float8_e4m3fn", None)
-if _FP8 is not None:
-    _QMAX[jnp.dtype(_FP8)] = 448.0
+_QMAX = {jnp.dtype(jnp.int8): 127.0,
+         jnp.dtype(jnp.float8_e4m3fn): 448.0}
 
 
 def quantize_kv(x, qdtype):
@@ -186,6 +178,7 @@ def _paged_kernel(btab_ref, chain_ref, q_ref, qpos_ref, k_ref, v_ref,
                   ks_ref, vs_ref, o_ref, m_s, l_s, acc_s, *, bs, nbmax,
                   quant):
     s = pl.program_id(0)
+    hi = pl.program_id(1)
     b = pl.program_id(2)
 
     @pl.when(b == 0)
@@ -194,6 +187,12 @@ def _paged_kernel(btab_ref, chain_ref, q_ref, qpos_ref, k_ref, v_ref,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
+    def _scale_row(ref):
+        # this head's [1, bs] row of the block's [H, bs] scale tile
+        # (leading unit dims: block, and layer for a full pool)
+        lead = (0,) * (len(ref.shape) - 2)
+        return ref[lead + (pl.ds(hi, 1), slice(None))].reshape(1, bs)
+
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # [C, dk]
         # K/V blocks arrive as (1, 1, bs, dk) (per-layer pool) or
@@ -201,22 +200,23 @@ def _paged_kernel(btab_ref, chain_ref, q_ref, qpos_ref, k_ref, v_ref,
         # map) — collapse the leading unit dims either way
         kk = k_ref[...].reshape(bs, -1).astype(jnp.float32)
         vv = v_ref[...].reshape(bs, -1).astype(jnp.float32)
-        if quant:
-            kk = kk * ks_ref[...].reshape(bs).astype(
-                jnp.float32)[:, None]
-            vv = vv * vs_ref[...].reshape(bs).astype(
-                jnp.float32)[:, None]
         sc = jax.lax.dot_general(
             q, kk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)      # [C, bs]
+        if quant:
+            # one scale per cached vector factors out of its dot
+            # product: scale the [C, bs] scores (and, below, the
+            # probabilities), not the [bs, dk] codes
+            sc = sc * _scale_row(ks_ref)
         kpos = b * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        qp = qpos_ref[0][:, None]                    # [C, 1]
-        sc = jnp.where(kpos <= qp, sc, _NEG_INF)
+        sc = jnp.where(kpos <= qpos_ref[0], sc, _NEG_INF)   # [C, 1]
         m_prev = m_s[:]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(sc - m_new)
         l_s[:] = alpha * l_s[:] + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * _scale_row(vs_ref)
         acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
             p, vv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -252,23 +252,29 @@ def _attend_pallas(q, pool_k, pool_v, btab, qpos, k_scale, v_scale,
     def _chase_sc(si, hi, b, tab, ch):
         blk = tab[si, jnp.minimum(b, ch[si] - 1)]
         if layer is None:
-            return (blk, hi, 0)
-        return (blk, layer, hi, 0)
+            return (blk, 0, 0)
+        return (blk, layer, 0, 0)
 
+    # TPU block rule: the last two dims of every block are multiples
+    # of (8, 128) or the array's own. K/V blocks end in the pool's own
+    # (bs, dk); qpos rides as [S, C, 1] so its (C, 1) block is the
+    # array's own and already the [C, 1] column the mask wants; a
+    # scale block carries all H heads' (H, bs) rows of one pool block
+    # and the kernel picks its head's row.
     kv_block = ((1, 1, bs, dk) if layer is None
                 else (1, 1, 1, bs, dk))
     kv_spec = pl.BlockSpec(kv_block, _chase)
     in_specs = [
         pl.BlockSpec((1, 1, c, dk), lambda si, hi, b, tab, ch:
                      (si, hi, 0, 0)),
-        pl.BlockSpec((1, c), lambda si, hi, b, tab, ch: (si, 0)),
+        pl.BlockSpec((1, c, 1), lambda si, hi, b, tab, ch: (si, 0, 0)),
         kv_spec, kv_spec,
     ]
-    args = [q.astype(jnp.float32), qpos.astype(jnp.int32),
-            pool_k, pool_v]
+    args = [q.astype(jnp.float32),
+            qpos.astype(jnp.int32).reshape(s, c, 1), pool_k, pool_v]
     if quant:
         sc_spec = pl.BlockSpec(
-            (1, 1, bs) if layer is None else (1, 1, 1, bs),
+            (1, h, bs) if layer is None else (1, 1, h, bs),
             _chase_sc)
         in_specs += [sc_spec, sc_spec]
         args += [k_scale, v_scale]
@@ -299,13 +305,14 @@ def _attend_pallas(q, pool_k, pool_v, btab, qpos, k_scale, v_scale,
 
 
 # --------------------------------------------------------------------------
-def _resolve_path(q, pool_k, force):
+def _resolve_path(q, force):
+    # every (dk, bs, pool dtype) compiles for the v5e — each block's
+    # last two dims are its array's own (tests/test_tpu_compile.py) —
+    # so a TPU always takes the kernel; there is no shape carve-out
+    # that could hand a TPU call to lax unseen
     if force is not None:
         return force
-    dk = q.shape[-1]
-    bs = pool_k.shape[-2]
-    usable = dk % 8 == 0 and bs % 8 == 0
-    return "pallas" if (usable and _on_tpu(q)) else "lax"
+    return "pallas" if _on_tpu(q) else "lax"
 
 
 def paged_attention(q, pool_k, pool_v, btab, qpos, nblk=None,
@@ -342,7 +349,7 @@ def paged_attention(q, pool_k, pool_v, btab, qpos, nblk=None,
     if nblk is None:
         nblk = jnp.max(qpos) // bs + 1
     nblk = jnp.clip(jnp.asarray(nblk, jnp.int32), 1, nbmax)
-    path = _resolve_path(q, pool_k, force)
+    path = _resolve_path(q, force)
     if path == "lax":
         return _attend_lax(q, pool_k, pool_v, btab, qpos, nblk,
                            k_scale, v_scale, block_group, layer=layer)

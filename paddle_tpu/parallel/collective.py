@@ -85,7 +85,7 @@ def allreduce(x, axis_name="dp"):
 
 def barrier(mesh):
     """Host-side barrier: tiny psum across the mesh (send_barrier parity)."""
-    from ._shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     f = shard_map(lambda x: lax.psum(x, mesh.axis_names),
                   mesh=mesh,

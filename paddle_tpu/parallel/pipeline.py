@@ -44,10 +44,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from ._shard_map import shard_map
 
 
 def _run_ticks(apply, xs, s_idx, n_stage, axis_name, with_aux=False):

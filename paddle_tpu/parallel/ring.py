@@ -23,8 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ._shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 _NEG_BIG = -1e30   # finite "-inf": keeps exp()==0 without inf-inf NaNs

@@ -49,7 +49,7 @@ def test_image_classification_resnet():
     exe, images, predict, first, last, acc = _train(
         lambda img: resnet.resnet_cifar10(img, depth=20), passes=4)
     assert last < first, (first, last)
-    # ABSOLUTE threshold (VERDICT r4 weak #6): uniform-10-class CE is
+    # ABSOLUTE threshold (round-4 review): uniform-10-class CE is
     # ln(10)=2.30; a converging run must be well under 2.0
     assert last < 2.0, (first, last)
     assert acc > 0.3, acc    # reference threshold: acc converging

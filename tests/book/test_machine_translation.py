@@ -56,7 +56,7 @@ def test_machine_translation_train_and_beam_decode():
             last = float(lv)
     assert last < first * 0.75, (first, last)
     # ABSOLUTE: uniform CE over DICT=64 is ln(64)=4.16; converged runs
-    # sit far below 3.2 (VERDICT r4 weak #6 absolute-threshold ask)
+    # sit far below 3.2 (round-4 review: absolute threshold)
     assert last < 3.2, (first, last)
 
     # beam-search decode with the TRAINED weights (book decode path)

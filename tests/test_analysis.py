@@ -75,7 +75,7 @@ def test_recompile_rule_flags_baked_constant():
 # ---------------------------------------------------------------- R003
 def test_sharding_rule_flags_replicated_param_and_all_gather():
     from jax.sharding import Mesh, PartitionSpec as P
-    from paddle_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
 

@@ -1,4 +1,4 @@
-"""Elastic / fault-tolerance tier (VERDICT r1 #5): master task queue with
+"""Elastic / fault-tolerance tier (round-1 review): master task queue with
 timeout+retry+snapshot, pserver checkpoint/recover, and the two
 kill-and-resume stories — a trainer dying mid-epoch and a pserver dying
 mid-run — completing with correct final state."""

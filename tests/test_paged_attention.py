@@ -168,11 +168,7 @@ def test_kv_quant_spec_validation():
     assert dt == jnp.int8 and qmax == 127.0
     with pytest.raises(ValueError):
         P.kv_quant_spec("int4")
-    if getattr(jnp, "float8_e4m3fn", None) is None:
-        with pytest.raises(ValueError):
-            P.kv_quant_spec("fp8")
-    else:
-        assert P.kv_quant_spec("fp8")[1] == 448.0
+    assert P.kv_quant_spec("fp8") == (jnp.float8_e4m3fn, 448.0)
 
 
 def test_quantized_kernel_rtol_pin():

@@ -100,7 +100,7 @@ def test_convert_reader_and_read_back(tmp_path):
 
 
 def test_train_from_recordio_file(tmp_path):
-    # the data-plane integration the VERDICT asked for: file -> reader ->
+    # the data-plane integration a review asked for: file -> reader ->
     # DataFeeder -> compiled step, loss converges
     path = str(tmp_path / "train.rio")
     rng = np.random.RandomState(0)

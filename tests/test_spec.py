@@ -218,10 +218,13 @@ def test_spec_eos_inside_accepted_draft(rng, lm):
     probe = ([1, 5, 9], 12)
     [(toks, _)] = serving.sequential_generate(lm, [probe])
     lm_eos = copy.copy(lm)
-    lm_eos.end_id = toks[2]     # 3rd emitted token = EOS
+    # EOS = the first emitted token (past index 0) whose value has not
+    # occurred earlier, so the continuation really stops THERE
+    j = next(i for i in range(1, len(toks)) if toks[i] not in toks[:i])
+    lm_eos.end_id = toks[j]
     reqs = [probe] + _requests(rng, 2, min_new=4, max_new=8)
     seq = serving.sequential_generate(lm_eos, reqs)
-    assert len(seq[0][0]) == 3 and seq[0][0][-1] == lm_eos.end_id
+    assert len(seq[0][0]) == j + 1 and seq[0][0][-1] == lm_eos.end_id
     with serving.Engine(lm_eos, slots=2, prefill_chunk=4,
                         speculative=True, spec_gamma=4,
                         spec_drafter="truncated",

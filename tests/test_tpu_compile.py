@@ -1,0 +1,114 @@
+"""Compile the main paths' Pallas kernels for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with jaxlib
+compiles for a topology that is described, and raises what the chip's
+compiler would raise (block shapes off the (8, 128) tiling, scoped
+VMEM overrun, ...) — the class of fault interpret mode cannot see.
+``paged_attention``'s Pallas path passed every interpret-mode test
+while being refused by the compiler at every serving shape; these
+compiles are what keeps that from recurring. Nothing runs, so this says
+nothing about results or times (chip_smoke.py does, on the chip).
+
+Shapes are the two main paths' at real width: the transformer-large
+train step's flash attention (and the XL head dim), and the serve
+phase's pool — 8 slots x max_len 1024 at block 16 -> a [512, 8, 16,
+16, dk] pool and a 64-column block table — at C = 1 (decode), gamma+1
+(speculative scoring) and the prefill chunk.
+"""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from paddle_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from paddle_tpu.ops.paged_attention import paged_attention  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip as a sharding. The persistent compile
+    cache is off around the module: a compile for a described chip is
+    written to it but cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip("cannot describe a v5e topology: %r" % (e,))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *avals):
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (8, 8, 1024, 128)],
+                         ids=["large_dk64", "xl_dk128"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(chip, shape, direction):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, force="pallas")
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compiled_text(fn, q, q, q)
+    # forward is one kernel; backward re-runs it and adds dq and dk/dv
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd"
+                                             else 3)
+
+
+_SLOTS, _LAYERS, _HEADS, _BS, _NBMAX = 8, 8, 16, 16, 64
+_NB = _SLOTS * _NBMAX
+_GAMMA, _CHUNK = 4, 16
+_DECODE, _SPEC, _PREFILL = (_SLOTS, 1), (_SLOTS, _GAMMA + 1), (1, _CHUNK)
+# the engine's calling shape — the full [NB, L, H, bs, dk] pool — at
+# every (C, dk); the per-layer 4-D slice at the decode shape
+_PAGED_CASES = [
+    pytest.param(rc, dk, True, id="%s-dk%d-pool5d" % (name, dk))
+    for name, rc in (("decode", _DECODE), ("spec", _SPEC),
+                     ("prefill", _PREFILL))
+    for dk in (64, 128)
+] + [pytest.param(_DECODE, 64, False, id="decode-dk64-layer4d")]
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16,
+                                        jnp.int8],
+                         ids=["f32", "bf16", "int8"])
+@pytest.mark.parametrize("rows_c,dk,full_pool", _PAGED_CASES)
+def test_paged_attention_compiles_for_v5e(chip, pool_dtype, rows_c, dk,
+                                          full_pool):
+    rows, c = rows_c
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool_shape = ((_NB, _LAYERS, _HEADS, _BS, dk) if full_pool
+                  else (_NB, _HEADS, _BS, dk))
+    layer = 3 if full_pool else None
+    avals = [aval((rows, _HEADS, c, dk), jnp.float32),
+             aval(pool_shape, pool_dtype), aval(pool_shape, pool_dtype),
+             aval((rows, _NBMAX), jnp.int32), aval((rows, c), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        avals += [aval(pool_shape[:-1], jnp.float32)] * 2
+
+    def fn(q, pk, pv, btab, qpos, ks=None, vs=None):
+        return paged_attention(q, pk, pv, btab, qpos, k_scale=ks,
+                               v_scale=vs, layer=layer, force="pallas")
+
+    assert "tpu_custom_call" in _compiled_text(fn, *avals)
