@@ -424,15 +424,13 @@ class Executor:
     def run(self, program=None, feed=None, fetch_list=None,
             feed_var_name="feed", fetch_var_name="fetch", scope=None,
             return_numpy=True, use_program_cache=True):
-        trc = _trc._TRACER
-        if trc is None:
-            return self._run_impl(program, feed, fetch_list,
-                                  feed_var_name, fetch_var_name, scope,
-                                  return_numpy, use_program_cache)
-        # distributed-trace root span per step: RPC verb spans issued
-        # while this step runs (pserver sends/gets, prefetches) nest
-        # under it, making the step the unit of the fleet timeline
-        with trc.span("exe.step"):
+        # the step's root, numbered: always an annotation in the JAX
+        # profiler's timeline (it records only while a profiler session
+        # runs); with the tracer armed also the distributed-trace root
+        # span: RPC verb spans issued while this step runs (pserver
+        # sends/gets, prefetches) nest under it, making the step the
+        # unit of the fleet timeline
+        with _trc.span("exe.step", step=self._rng_counter):
             return self._run_impl(program, feed, fetch_list,
                                   feed_var_name, fetch_var_name, scope,
                                   return_numpy, use_program_cache)
@@ -470,12 +468,7 @@ class Executor:
         steps), and programs with host (IO) ops or newly-materialized
         persistables (startup programs) are rejected — run() those."""
         feeds, k = self._check_run_steps_args(feeds, k)
-        trc = _trc._TRACER
-        if trc is None:
-            return self._run_steps_impl(program, feeds, fetch_list,
-                                        scope, return_numpy, k,
-                                        use_program_cache)
-        with trc.span("exe.step", k=k):
+        with _trc.span("exe.step", step=self._rng_counter, k=k):
             return self._run_steps_impl(program, feeds, fetch_list,
                                         scope, return_numpy, k,
                                         use_program_cache)
@@ -503,30 +496,29 @@ class Executor:
     def _run_steps_impl(self, program, feeds, fetch_list, scope,
                         return_numpy, k, use_program_cache):
         import time as _time
-        program = program or default_main_program()
-        fetch_list = list(fetch_list or [])
-        scope = scope or global_scope()
-        fetch_names = tuple(
-            f.name if isinstance(f, Variable) else str(f)
-            for f in fetch_list)
-        if any(registry.is_host_op(o.type)
-               for o in program.global_block().ops):
-            raise NotImplementedError(
-                "run_steps cannot fuse programs with host (IO) ops — "
-                "send/recv/prefetch must hit the wire once per step; "
-                "use run() per step")
-        if isinstance(feeds, dict):
-            feeds_k, static_info, sig = _stage_prestacked_feeds(feeds, k)
-        else:
-            feeds_k, static_info, sig = _stack_step_feeds(
-                feeds, plan_cache=getattr(self, "_feed_plans", None))
+        step = self._rng_counter          # phases as in _run_impl
+        with _trc.phase("exe.feed", step=step):
+            program = program or default_main_program()
+            fetch_list = list(fetch_list or [])
+            scope = scope or global_scope()
+            fetch_names = tuple(
+                f.name if isinstance(f, Variable) else str(f)
+                for f in fetch_list)
+            if any(registry.is_host_op(o.type)
+                   for o in program.global_block().ops):
+                raise NotImplementedError(
+                    "run_steps cannot fuse programs with host (IO) ops "
+                    "— send/recv/prefetch must hit the wire once per "
+                    "step; use run() per step")
+            if isinstance(feeds, dict):
+                feeds_k, static_info, sig = _stage_prestacked_feeds(
+                    feeds, k)
+            else:
+                feeds_k, static_info, sig = _stack_step_feeds(
+                    feeds, plan_cache=getattr(self, "_feed_plans", None))
 
-        persistable = [v.name
-                       for v in program.global_block().vars.values()
-                       if v.persistable]
-        state = {n: scope.find_var(n) for n in persistable
-                 if scope.find_var(n) is not None}
-        state_keys = tuple(sorted(state))
+        with _trc.phase("exe.state", step=step):
+            state, state_keys = _gather_state(program, scope)
 
         from ..amp import amp_enabled
         from ..flags import get_flag
@@ -538,21 +530,23 @@ class Executor:
         from .. import monitor as _mon
         mon_on = _mon.enabled()
         entry = self._cache.get(key) if use_program_cache else None
-        if entry is None:
-            mega = self._build_megastep(program, tuple(sorted(feeds_k)),
-                                        fetch_names, state_keys,
-                                        static_info, check_nan, k)
-            entry = jax.jit(mega, donate_argnums=(0,))
-            if use_program_cache:
-                self._cache[key] = entry
-            if mon_on and use_program_cache:
-                rng0 = jax.vmap(jax.random.key)(
-                    jnp.zeros((k,), jnp.uint32))
-                _mon.on_compile(
-                    program, key, key[4],
-                    cost_fn=lambda: _step_costs_safe(
-                        mega, dict(state), dict(feeds_k), rng0),
-                    tokens=_mon.tokens_in_feeds(feeds_k))
+        fresh = entry is None
+        if fresh:
+            with _trc.phase("exe.build", step=step):
+                mega = self._build_megastep(
+                    program, tuple(sorted(feeds_k)), fetch_names,
+                    state_keys, static_info, check_nan, k)
+                entry = jax.jit(mega, donate_argnums=(0,))
+                if use_program_cache:
+                    self._cache[key] = entry
+                if mon_on and use_program_cache:
+                    rng0 = jax.vmap(jax.random.key)(
+                        jnp.zeros((k,), jnp.uint32))
+                    _mon.on_compile(
+                        program, key, key[4],
+                        cost_fn=lambda: _step_costs_safe(
+                            mega, dict(state), dict(feeds_k), rng0),
+                        tokens=_mon.tokens_in_feeds(feeds_k))
         elif mon_on:
             _mon.on_cache_hit()
 
@@ -575,7 +569,9 @@ class Executor:
         if mon_on:
             timer = _mon.step_timer(self)
             do_sync = timer.begin(t0)
-        with jax.default_device(self.place.jax_device()):
+        with _trc.phase("exe.build" if fresh else "exe.dispatch",
+                        step=step), \
+                jax.default_device(self.place.jax_device()):
             fetches_k, new_state, guards_k, lods_k = entry(
                 state, feeds_k, keys)
         if mon_on:
@@ -590,20 +586,21 @@ class Executor:
                 _mon.on_megastep(key, _time.perf_counter() - t0, k,
                                  feed_bytes=fb, tokens=tk, synced=False)
 
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if check_nan:
-            self._check_guards_steps(guards_k, k)
-        out = self._split_step_fetches(fetch_names, fetches_k, lods_k,
-                                       k, return_numpy)
-        if check_nan:
-            for i, fi in enumerate(out):
-                self._check_nan_inf(fetch_names, fi)
-        if not return_numpy:
-            # async dispatch: hand back device handles and track the
-            # un-fetched dispatch in the in-flight window
-            inflight.append(fetches_k)
-        return out
+        with _trc.phase("exe.commit", step=step):
+            for n, v in new_state.items():
+                scope.set(n, v)
+            if check_nan:
+                self._check_guards_steps(guards_k, k)
+            out = self._split_step_fetches(fetch_names, fetches_k,
+                                           lods_k, k, return_numpy)
+            if check_nan:
+                for i, fi in enumerate(out):
+                    self._check_nan_inf(fetch_names, fi)
+            if not return_numpy:
+                # async dispatch: hand back device handles and track
+                # the un-fetched dispatch in the in-flight window
+                inflight.append(fetches_k)
+            return out
 
     def _build_megastep(self, program, feed_names, fetch_names,
                         state_keys, static_info, check_nan, k):
@@ -670,28 +667,33 @@ class Executor:
     def _run_impl(self, program, feed, fetch_list, feed_var_name,
                   fetch_var_name, scope, return_numpy,
                   use_program_cache):
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        fetch_list = list(fetch_list or [])
-        scope = scope or global_scope()
+        # the phases of a step (exe.feed / exe.state / exe.build /
+        # exe.dispatch / exe.commit) are annotations in the profiler's
+        # timeline, numbered like their exe.step root; what lies
+        # between them is the root's self time
+        step = self._rng_counter
+        with _trc.phase("exe.feed", step=step):
+            program = program or default_main_program()
+            feed = dict(feed or {})
+            fetch_list = list(fetch_list or [])
+            scope = scope or global_scope()
 
-        fetch_names = tuple(
-            f.name if isinstance(f, Variable) else str(f) for f in fetch_list)
+            fetch_names = tuple(
+                f.name if isinstance(f, Variable) else str(f)
+                for f in fetch_list)
 
-        # Normalize feeds to arrays; remember LoD for LoDTensor feeds.
-        # static_info carries trace-time constants derived host-side from
-        # the feed — the per-feed BUCKETED max sequence length (next power
-        # of two), which bounds in-graph padding at ~Tmax instead of the
-        # total token count (the shape-key bucketing of SURVEY.md §7).
-        feed_arrays, static_info = _normalize_feeds(
-            feed, plan_cache=getattr(self, "_feed_plans", None))
+            # Normalize feeds to arrays; remember LoD for LoDTensor
+            # feeds. static_info carries trace-time constants derived
+            # host-side from the feed — the per-feed BUCKETED max
+            # sequence length (next power of two), which bounds in-graph
+            # padding at ~Tmax instead of the total token count (the
+            # shape-key bucketing of SURVEY.md §7).
+            feed_arrays, static_info = _normalize_feeds(
+                feed, plan_cache=getattr(self, "_feed_plans", None))
 
         # State = persistable vars of this program that exist in scope.
-        persistable = [v.name for v in program.global_block().vars.values()
-                       if v.persistable]
-        state = {n: scope.find_var(n) for n in persistable
-                 if scope.find_var(n) is not None}
-        state_keys = tuple(sorted(state))
+        with _trc.phase("exe.state", step=step):
+            state, state_keys = _gather_state(program, scope)
 
         # NB: the Program object itself is part of the key (kept alive by the
         # cache) so id-reuse after GC can never alias two programs. The AMP
@@ -736,25 +738,31 @@ class Executor:
         from .. import monitor as _mon
         mon_on = _mon.enabled()
         entry = self._cache.get(key) if use_program_cache else None
-        if entry is None:
-            fn = self._build(program, tuple(sorted(feed_arrays)), fetch_names,
-                             state_keys, static_info, check_nan=check_nan)
-            entry = jax.jit(fn, donate_argnums=(0,))
-            if use_program_cache:
-                self._cache[key] = entry
-            if mon_on and use_program_cache:
-                # price the step with the static cost model (traced once
-                # here, at compile time) so per-step MFU is derivable;
-                # classify the compile against this program's history.
-                # use_program_cache=False is a DELIBERATE cache bypass —
-                # counting each of its runs as a recompile would report
-                # key churn that isn't there
-                rng0 = jax.random.key(0)
-                _mon.on_compile(
-                    program, key, key[2],
-                    cost_fn=lambda: _step_costs_safe(
-                        fn, dict(state), dict(feed_arrays), rng0),
-                    tokens=_mon.tokens_in_feeds(feed_arrays))
+        # a cache miss is exe.build twice: here, and round the first
+        # call below, which traces, lowers and compiles
+        fresh = entry is None
+        if fresh:
+            with _trc.phase("exe.build", step=step):
+                fn = self._build(program, tuple(sorted(feed_arrays)),
+                                 fetch_names, state_keys, static_info,
+                                 check_nan=check_nan)
+                entry = jax.jit(fn, donate_argnums=(0,))
+                if use_program_cache:
+                    self._cache[key] = entry
+                if mon_on and use_program_cache:
+                    # price the step with the static cost model (traced
+                    # once here, at compile time) so per-step MFU is
+                    # derivable; classify the compile against this
+                    # program's history. use_program_cache=False is a
+                    # DELIBERATE cache bypass — counting each of its
+                    # runs as a recompile would report key churn that
+                    # isn't there
+                    rng0 = jax.random.key(0)
+                    _mon.on_compile(
+                        program, key, key[2],
+                        cost_fn=lambda: _step_costs_safe(
+                            fn, dict(state), dict(feed_arrays), rng0),
+                        tokens=_mon.tokens_in_feeds(feed_arrays))
         elif mon_on:
             _mon.on_cache_hit()
 
@@ -773,7 +781,9 @@ class Executor:
             # with the profiler on every step blocks anyway — keep the
             # already-paid exact latencies instead of window-averaging
             do_sync = timer.begin(t0) or _prof._enabled
-        with jax.default_device(self.place.jax_device()):
+        with _trc.phase("exe.build" if fresh else "exe.dispatch",
+                        step=step), \
+                jax.default_device(self.place.jax_device()):
             if _prof._enabled:
                 # step-level event; sync INSIDE the event so the row
                 # records real step time, not async dispatch; with
@@ -800,21 +810,23 @@ class Executor:
             else:
                 _mon.on_step(key, now - t0, feed_bytes=fb, tokens=tk,
                              synced=False)
-        fetches = self._trim_fetches(fetch_names, fetches, fetch_lods)
+        with _trc.phase("exe.commit", step=step):
+            fetches = self._trim_fetches(fetch_names, fetches, fetch_lods)
 
-        # Commit updated persistable state back to the scope.
-        for n, v in new_state.items():
-            scope.set(n, v)
-        # New persistable vars materialized by this run (e.g. startup program
-        # initializers) are committed too — _build returns them in new_state.
+            # Commit updated persistable state back to the scope. New
+            # persistable vars materialized by this run (e.g. startup
+            # program initializers) are committed too — _build returns
+            # them in new_state.
+            for n, v in new_state.items():
+                scope.set(n, v)
 
-        if check_nan:
-            self._check_guards(guards)
-            self._check_nan_inf(fetch_names, fetches)
+            if check_nan:
+                self._check_guards(guards)
+                self._check_nan_inf(fetch_names, fetches)
 
-        if return_numpy:
-            return [as_numpy(v) for v in fetches]
-        return list(fetches)
+            if return_numpy:
+                return [as_numpy(v) for v in fetches]
+            return list(fetches)
 
     # ------------------------------------------------------------------
     def _run_eager(self, program, feed_arrays, fetch_names, scope,
@@ -1545,6 +1557,18 @@ class Executor:
             raise FloatingPointError(
                 "NaN/Inf detected in output %r of op %r "
                 "(PADDLE_TPU_CHECK_NAN_INF)" % (var, op_type))
+
+
+def _gather_state(program, scope):
+    """The program's persistable vars that exist in the scope, and
+    their sorted names (part of every cache key)."""
+    state = {}
+    for v in program.global_block().vars.values():
+        if v.persistable:
+            val = scope.find_var(v.name)
+            if val is not None:
+                state[v.name] = val
+    return state, tuple(sorted(state))
 
 
 def _step_costs_safe(fn, state, feeds, rng_key):

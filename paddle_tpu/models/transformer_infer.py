@@ -188,24 +188,33 @@ class TransformerInfer:
         """q_in [rows, Tq, D]; kv_k/v [rows, H, Tk, dk]; bias broadcastable
         to [rows, H, Tq, Tk]."""
         h = self.n_head
-        q = _split_heads(q_in @ p["wq"], h)
-        dk = q.shape[-1]
-        s = jnp.einsum("rhqd,rhkd->rhqk", q * (dk ** -0.5), kv_k,
-                       preferred_element_type=jnp.float32)
-        if bias is not None:
-            s = s + bias
-        w = jax.nn.softmax(s, axis=-1).astype(kv_v.dtype)
-        o = jnp.einsum("rhqk,rhkd->rhqd", w, kv_v)
-        r, t = q_in.shape[0], q_in.shape[1]
-        return o.transpose(0, 2, 1, 3).reshape(r, t, -1) @ p["wo"]
+        with jax.named_scope("attn"):
+            q = _split_heads(q_in @ p["wq"], h)
+            dk = q.shape[-1]
+            s = jnp.einsum("rhqd,rhkd->rhqk", q * (dk ** -0.5), kv_k,
+                           preferred_element_type=jnp.float32)
+            if bias is not None:
+                s = s + bias
+            w = jax.nn.softmax(s, axis=-1).astype(kv_v.dtype)
+            o = jnp.einsum("rhqk,rhkd->rhqd", w, kv_v)
+            r, t = q_in.shape[0], q_in.shape[1]
+            return o.transpose(0, 2, 1, 3).reshape(r, t, -1) @ p["wo"]
 
     def _kv(self, p, x):
         h = self.n_head
-        return _split_heads(x @ p["wk"], h), _split_heads(x @ p["wv"], h)
+        with jax.named_scope("attn"):
+            return (_split_heads(x @ p["wk"], h),
+                    _split_heads(x @ p["wv"], h))
 
     def _ffn(self, p, x):
-        hdn = jax.nn.relu(x @ p["ffn_w1"] + p["ffn_b1"])
-        return hdn @ p["ffn_w2"] + p["ffn_b2"]
+        with jax.named_scope("mlp"):
+            hdn = jax.nn.relu(x @ p["ffn_w1"] + p["ffn_b1"])
+            return hdn @ p["ffn_w2"] + p["ffn_b2"]
+
+    def _head(self, x):
+        """The output projection to vocabulary logits."""
+        with jax.named_scope("head"):
+            return x @ self.w_out
 
     def encode(self, src_tokens, src_mask):
         """src_tokens [B, T] int32, src_mask [B, T] float; → [B, T, D]."""
@@ -375,15 +384,16 @@ class TransformerLMInfer(TransformerInfer):
             jnp.where(write_mask, pos, self.max_len)
         for i, p in enumerate(self.layers):
             k_new, v_new = self._kv(p["attn"], x)        # [S, H, 1, dk]
-            k = state["k%d" % i].at[ridx, :, wpos, :].set(
-                k_new[:, :, 0, :], mode="drop")
-            v = state["v%d" % i].at[ridx, :, wpos, :].set(
-                v_new[:, :, 0, :], mode="drop")
+            with jax.named_scope("kv.write"):
+                k = state["k%d" % i].at[ridx, :, wpos, :].set(
+                    k_new[:, :, 0, :], mode="drop")
+                v = state["v%d" % i].at[ridx, :, wpos, :].set(
+                    v_new[:, :, 0, :], mode="drop")
             state["k%d" % i], state["v%d" % i] = k, v
             a = self._mha(p["attn"], x, k, v, self_bias)
             x = _ln(x + a, *p["ln1"])
             x = _ln(x + self._ffn(p, x), *p["ln2"])
-        return x[:, 0, :] @ self.w_out, state
+        return self._head(x[:, 0, :]), state
 
     # -- paged KV (serving.kvpool block pool, ISSUE 10/20) -------------
     def _init_paged_state(self, num_blocks, block_size, kv_quant=None):
@@ -425,20 +435,22 @@ class TransformerLMInfer(TransformerInfer):
         implementation — every paged entry point (step, speculative,
         prefill, drafter) routes here. Quantized pools quantize per
         stored vector here (codes + per-position scale, ISSUE 20)."""
-        for name, sname, val in (
-                ("pool_k", "pool_ks", k_new), ("pool_v", "pool_vs",
-                                               v_new)):
-            v = val.transpose(0, 2, 1, 3)            # [S, C, H, dk]
-            if sname in pools:
-                codes, scale = _paged_ops.quantize_kv(
-                    v, pools[name].dtype)
-                pools[name] = pools[name].at[wphys, i, :, off, :].set(
-                    codes, mode="drop")
-                pools[sname] = pools[sname].at[wphys, i, :, off].set(
-                    scale, mode="drop")
-            else:
-                pools[name] = pools[name].at[wphys, i, :, off, :].set(
-                    v.astype(pools[name].dtype), mode="drop")
+        with jax.named_scope("kv.write"):
+            for name, sname, val in (
+                    ("pool_k", "pool_ks", k_new), ("pool_v", "pool_vs",
+                                                   v_new)):
+                v = val.transpose(0, 2, 1, 3)        # [S, C, H, dk]
+                if sname in pools:
+                    codes, scale = _paged_ops.quantize_kv(
+                        v, pools[name].dtype)
+                    pools[name] = pools[name].at[
+                        wphys, i, :, off, :].set(codes, mode="drop")
+                    pools[sname] = pools[sname].at[
+                        wphys, i, :, off].set(scale, mode="drop")
+                else:
+                    pools[name] = pools[name].at[
+                        wphys, i, :, off, :].set(
+                            v.astype(pools[name].dtype), mode="drop")
         return pools
 
     def _pool_gather(self, pools, i, btab):
@@ -453,13 +465,15 @@ class TransformerLMInfer(TransformerInfer):
         s = bt.shape[0]
         dk = self.d_model // self.n_head
         out = []
-        for name, sname in (("pool_k", "pool_ks"),
-                            ("pool_v", "pool_vs")):
-            g = pools[name][:, i][bt]        # [S, NB, H, bs, dk]
-            if sname in pools:
-                g = _paged_ops.dequantize_kv(g, pools[sname][:, i][bt])
-            out.append(g.transpose(0, 2, 1, 3, 4).reshape(
-                s, self.n_head, -1, dk)[:, :, :self.max_len])
+        with jax.named_scope("kv.read"):
+            for name, sname in (("pool_k", "pool_ks"),
+                                ("pool_v", "pool_vs")):
+                g = pools[name][:, i][bt]        # [S, NB, H, bs, dk]
+                if sname in pools:
+                    g = _paged_ops.dequantize_kv(
+                        g, pools[sname][:, i][bt])
+                out.append(g.transpose(0, 2, 1, 3, 4).reshape(
+                    s, self.n_head, -1, dk)[:, :, :self.max_len])
         return out
 
     def _mha_paged(self, p, q_in, pools, i, btab, qpos, nblk, bias,
@@ -478,22 +492,23 @@ class TransformerLMInfer(TransformerInfer):
             k, v = self._pool_gather(pools, i, btab)
             return self._mha(p, q_in, k, v, bias)
         h = self.n_head
-        q = _split_heads(q_in @ p["wq"], h)
-        dk = q.shape[-1]
-        bt = btab if btab.ndim == 2 else btab[None]
-        # FULL pool + static layer index: the kernel gathers (block,
-        # layer) pairs; a pools[name][:, i] slice here would copy the
-        # whole pool every step (capacity-proportional)
-        o = _paged_ops.paged_attention(
-            (q * (dk ** -0.5)).astype(jnp.float32),
-            pools["pool_k"], pools["pool_v"], bt, qpos,
-            nblk=nblk,
-            k_scale=pools.get("pool_ks"),
-            v_scale=pools.get("pool_vs"),
-            block_group=attn_unroll, layer=i)
-        o = o.astype(q_in.dtype)
-        r, t = q_in.shape[0], q_in.shape[1]
-        return o.transpose(0, 2, 1, 3).reshape(r, t, -1) @ p["wo"]
+        with jax.named_scope("attn"):
+            q = _split_heads(q_in @ p["wq"], h)
+            dk = q.shape[-1]
+            bt = btab if btab.ndim == 2 else btab[None]
+            # FULL pool + static layer index: the kernel gathers (block,
+            # layer) pairs; a pools[name][:, i] slice here would copy
+            # the whole pool every step (capacity-proportional)
+            o = _paged_ops.paged_attention(
+                (q * (dk ** -0.5)).astype(jnp.float32),
+                pools["pool_k"], pools["pool_v"], bt, qpos,
+                nblk=nblk,
+                k_scale=pools.get("pool_ks"),
+                v_scale=pools.get("pool_vs"),
+                block_group=attn_unroll, layer=i)
+            o = o.astype(q_in.dtype)
+            r, t = q_in.shape[0], q_in.shape[1]
+            return o.transpose(0, 2, 1, 3).reshape(r, t, -1) @ p["wo"]
 
     @staticmethod
     def _pool_slice(state):
@@ -554,7 +569,7 @@ class TransformerLMInfer(TransformerInfer):
             x = _ln(x + a, *p["ln1"])
             x = _ln(x + self._ffn(p, x), *p["ln2"])
         state.update(pools)
-        return x[:, 0, :] @ self.w_out, state
+        return self._head(x[:, 0, :]), state
 
     def _spec_logits_paged(self, toks, state, pos, btab, n_valid,
                            write_mask=None, block_kernel=False,
@@ -618,7 +633,7 @@ class TransformerLMInfer(TransformerInfer):
             x = _ln(x + a, *p["ln1"])
             x = _ln(x + self._ffn(p, x), *p["ln2"])
         state.update(pools)
-        return x @ self.w_out, state                     # [S, C, V]
+        return self._head(x), state                      # [S, C, V]
 
     def _prefill_chunk_paged(self, state, toks, start, n_valid,
                              btab_row, block_kernel=False,
@@ -689,10 +704,11 @@ class TransformerLMInfer(TransformerInfer):
         wpos = jnp.where(valid, cpos, self.max_len)      # OOB → dropped
         for i, p in enumerate(self.layers):
             k_new, v_new = self._kv(p["attn"], x)        # [1, H, C, dk]
-            k = state["k%d" % i].at[slot, :, wpos, :].set(
-                k_new[0].transpose(1, 0, 2), mode="drop")
-            v = state["v%d" % i].at[slot, :, wpos, :].set(
-                v_new[0].transpose(1, 0, 2), mode="drop")
+            with jax.named_scope("kv.write"):
+                k = state["k%d" % i].at[slot, :, wpos, :].set(
+                    k_new[0].transpose(1, 0, 2), mode="drop")
+                v = state["v%d" % i].at[slot, :, wpos, :].set(
+                    v_new[0].transpose(1, 0, 2), mode="drop")
             state["k%d" % i], state["v%d" % i] = k, v
             a = self._mha(p["attn"], x, k[slot][None], v[slot][None],
                           bias)

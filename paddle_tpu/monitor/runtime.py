@@ -27,6 +27,8 @@ import threading
 import time
 import weakref
 
+import jax.monitoring
+
 from . import metrics as _metrics
 from .recorder import FlightRecorder
 from .watchdog import Watchdog
@@ -330,7 +332,6 @@ class _State:
     dog = None            # Watchdog | None
     reporter = None       # (thread, stop_event) | None
     peak_flops = None     # float | None (None = auto-detect)
-    listener_registered = False
     lock = threading.Lock()
     # per-program compile history {"versions", "sigs", "count"} — WEAK
     # keys: a discarded Program must not stay pinned (and a reused id
@@ -424,7 +425,6 @@ def enable(log_path=None, stall_timeout=None, report_interval=None,
                                  daemon=True, name="ptpu-monitor-report")
             t.start()
             _S.reporter = (t, stop)
-    _register_jax_listener()
 
 
 def disable():
@@ -1315,34 +1315,68 @@ def _flag(name):
 
 
 # -- jax compile-time listener ---------------------------------------------
+#
+# Registered when this module is imported (JAX is imported by then:
+# paddle_tpu's ops come first), and it runs only when JAX compiles, so
+# a step pays nothing for it. Always fed: ptpu_xla_compile_seconds and
+# the compile log. Behind the monitor's switch: the recorder row and
+# the watchdog touch.
 
-def _register_jax_listener():
-    with _S.lock:
-        if _S.listener_registered:
-            return
-        _S.listener_registered = True
-    try:
-        import jax.monitoring as jm
+_COMPILE_PHASES = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+                   "backend_compile_duration", "cache_retrieval_time_sec")
+_CACHE_EVENTS = ("cache_hits", "cache_misses")
+_COMPILE_LOG = collections.deque(maxlen=4096)
+# tracing one step opens thousands of nested sub-millisecond traces
+# (every jnp call of every op's lowering): they would push the phases
+# that matter out of the bounded log, and lie inside those anyway
+_COMPILE_LOG_FLOOR_S = 1e-3
 
-        def _listener(event, duration, **kw):
-            if not _S.on or "compile" not in event:
-                return
-            rec, dog = _S.rec, _S.dog
-            what = event.rsplit("/", 1)[-1]
-            XLA_COMPILE_SECONDS.observe(duration, what=what)
-            if dog is not None:
-                # compile phases count as liveness: a long first compile
-                # (tracing, lowering, backend_compile each emit duration
-                # events) must not read as a stall. A single compile
-                # PHASE longer than the deadline can still fire — size
-                # stall_timeout above the worst expected compile phase.
-                dog.touch()
-            if rec is not None and duration >= 0.01:
-                rec.record("xla_compile", what=what, seconds=duration)
 
-        jm.register_event_duration_secs_listener(_listener)
-    except Exception:
-        pass
+def compile_log():
+    """What JAX compiled in this process, oldest first (bounded: the
+    newest 4096 rows; a phase under a millisecond is left out):
+    ``{"what", "fun_name", "end", "seconds"}`` with
+    ``what`` one of the compile phases ``jaxpr_trace_duration``,
+    ``jaxpr_to_mlir_module_duration``, ``backend_compile_duration``
+    (which covers a persistent-cache retrieval, when there is one) and
+    ``cache_retrieval_time_sec``, or a persistent-cache count
+    ``cache_hits`` / ``cache_misses`` (seconds 0). ``end`` is
+    ``time.perf_counter()`` when the phase ended; ``fun_name`` is the
+    jitted function's, where JAX gives it."""
+    return [dict(zip(("what", "fun_name", "end", "seconds"), row))
+            for row in list(_COMPILE_LOG)]
+
+
+def _on_jax_duration(event, duration, **kw):
+    what = event.rsplit("/", 1)[-1]
+    if what in _COMPILE_PHASES and duration >= _COMPILE_LOG_FLOOR_S:
+        _COMPILE_LOG.append((what, kw.get("fun_name"),
+                             time.perf_counter(), duration))
+    if "compile" not in event:
+        return
+    XLA_COMPILE_SECONDS.observe(duration, what=what)
+    if not _S.on:
+        return
+    rec, dog = _S.rec, _S.dog
+    if dog is not None:
+        # compile phases count as liveness: a long first compile
+        # (tracing, lowering, backend_compile each emit duration
+        # events) must not read as a stall. A single compile PHASE
+        # longer than the deadline can still fire — size stall_timeout
+        # above the worst expected compile phase.
+        dog.touch()
+    if rec is not None and duration >= 0.01:
+        rec.record("xla_compile", what=what, seconds=duration)
+
+
+def _on_jax_event(event, **kw):
+    what = event.rsplit("/", 1)[-1]
+    if what in _CACHE_EVENTS and "compilation_cache" in event:
+        _COMPILE_LOG.append((what, None, time.perf_counter(), 0.0))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 # -- stall + reporter ------------------------------------------------------

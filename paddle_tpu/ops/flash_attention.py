@@ -153,6 +153,7 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
     return out.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
 
@@ -291,6 +292,7 @@ def _bwd_pallas(res, dy, causal, scale, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, dy3, lse3, delta3)
 
     dk, dv = pl.pallas_call(
@@ -316,6 +318,7 @@ def _bwd_pallas(res, dy, causal, scale, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, dy3, lse3, delta3)
 
     shape4 = (b, h, t, d)

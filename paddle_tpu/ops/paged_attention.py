@@ -301,6 +301,7 @@ def _attend_pallas(q, pool_k, pool_v, btab, qpos, k_scale, v_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, c, dk), jnp.float32),
         interpret=interpret,
+        name="paged_decode",
     )(btab.astype(jnp.int32), chain, *args)
 
 

@@ -17,8 +17,10 @@ from .mesh import (make_mesh, default_mesh, set_default_mesh,
                    spec_to_named_sharding)
 from ..core.program import default_main_program, Variable
 from ..core.scope import global_scope
-from ..core.executor import Executor, as_numpy, _feed_signature
+from ..core.executor import (Executor, as_numpy, _feed_signature,
+                             _gather_state)
 from ..core.lod import LoDTensor
+from ..trace import runtime as _trc
 
 
 class ParallelExecutor:
@@ -142,13 +144,8 @@ class ParallelExecutor:
                     "over: 'token:<feed_name>'." % sorted(toks))
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        from ..trace import runtime as _trc
-        trc = _trc._TRACER
-        if trc is None:
-            return self._run_impl(fetch_list, feed, feed_dict,
-                                  return_numpy)
-        # distributed-trace root span per step (see core Executor.run)
-        with trc.span("pexe.step"):
+        # the step's root, numbered (see core Executor.run)
+        with _trc.span("pexe.step", step=self._exe._rng_counter):
             return self._run_impl(fetch_list, feed, feed_dict,
                                   return_numpy)
 
@@ -220,12 +217,7 @@ class ParallelExecutor:
         executor when ``return_numpy=False``."""
         from ..core.executor import Executor as _Exe
         feeds, k = _Exe._check_run_steps_args(feeds, k)
-        from ..trace import runtime as _trc
-        trc = _trc._TRACER
-        if trc is None:
-            return self._run_steps_impl(fetch_list, feeds, k,
-                                        return_numpy)
-        with trc.span("pexe.step", k=k):
+        with _trc.span("pexe.step", step=self._exe._rng_counter, k=k):
             return self._run_steps_impl(fetch_list, feeds, k,
                                         return_numpy)
 
@@ -244,41 +236,42 @@ class ParallelExecutor:
                 "one or the other." % self._accum_steps)
         program = self._program
         scope = self._scope
-        fetch_names = tuple(
-            f.name if isinstance(f, Variable) else str(f)
-            for f in (fetch_list or []))
-        if isinstance(feeds, dict):
-            feeds_k, static_info, sig = _stage_prestacked_feeds(feeds, k)
-        else:
-            feeds_k, static_info, sig = _stack_step_feeds(
-                feeds, plan_cache=self._feed_plans)
+        step = self._exe._rng_counter       # phases as in _run_impl
+        with _trc.phase("pexe.feed", step=step):
+            fetch_names = tuple(
+                f.name if isinstance(f, Variable) else str(f)
+                for f in (fetch_list or []))
+            if isinstance(feeds, dict):
+                feeds_k, static_info, sig = _stage_prestacked_feeds(
+                    feeds, k)
+            else:
+                feeds_k, static_info, sig = _stack_step_feeds(
+                    feeds, plan_cache=self._feed_plans)
 
-        dp = 1
-        if "dp" in self.mesh.axis_names:
-            dp = self.mesh.shape["dp"]
-        # ragged LoD buffers stay replicated (SplitLoDTensor parity,
-        # same classification as _run_impl): the derived @LOD/@ACCUM
-        # vectors by suffix AND the flat token buffer itself, found by
-        # its original per-step feed value being a LoDTensor — its dim
-        # 1 is a data-dependent token total, not a batch dim
-        lod_keys = {n for n in feeds_k
-                    if n.endswith("@LOD") or n.endswith("@ACCUM_TOKENS")}
-        if not isinstance(feeds, dict):
-            lod_keys |= {n for f in feeds for n, v in (f or {}).items()
-                         if isinstance(v, LoDTensor)}
-        for n, v in feeds_k.items():
-            if n not in lod_keys and getattr(v, "ndim", 0) >= 2 \
-                    and v.shape[1] % dp != 0:
-                raise ValueError(
-                    "megastep feed %r per-step batch dim %d not "
-                    "divisible by dp=%d" % (n, v.shape[1], dp))
+            dp = 1
+            if "dp" in self.mesh.axis_names:
+                dp = self.mesh.shape["dp"]
+            # ragged LoD buffers stay replicated (SplitLoDTensor parity,
+            # same classification as _run_impl): the derived
+            # @LOD/@ACCUM vectors by suffix AND the flat token buffer
+            # itself, found by its original per-step feed value being a
+            # LoDTensor — its dim 1 is a data-dependent token total,
+            # not a batch dim
+            lod_keys = {n for n in feeds_k if n.endswith("@LOD")
+                        or n.endswith("@ACCUM_TOKENS")}
+            if not isinstance(feeds, dict):
+                lod_keys |= {n for f in feeds
+                             for n, v in (f or {}).items()
+                             if isinstance(v, LoDTensor)}
+            for n, v in feeds_k.items():
+                if n not in lod_keys and getattr(v, "ndim", 0) >= 2 \
+                        and v.shape[1] % dp != 0:
+                    raise ValueError(
+                        "megastep feed %r per-step batch dim %d not "
+                        "divisible by dp=%d" % (n, v.shape[1], dp))
 
-        persistable = [v.name
-                       for v in program.global_block().vars.values()
-                       if v.persistable]
-        state = {n: scope.find_var(n) for n in persistable
-                 if scope.find_var(n) is not None}
-        state_keys = tuple(sorted(state))
+        with _trc.phase("pexe.state", step=step):
+            state, state_keys = _gather_state(program, scope)
         hints = tuple(sorted(
             (n, tuple(v)) for n, v in program._sharding_hints.items()))
         from ..amp import amp_enabled, enable_amp
@@ -293,35 +286,37 @@ class ParallelExecutor:
         from .. import monitor as _mon
         mon_on = _mon.enabled()
         entry = self._cache.get(key)
-        if entry is not None and mon_on:
+        fresh = entry is None
+        if not fresh and mon_on:
             _mon.on_cache_hit()
-        if entry is None:
-            mega = self._exe._build_megastep(
-                program, tuple(sorted(feeds_k)), fetch_names,
-                state_keys, static_info, check_nan, k)
+        if fresh:
+            with _trc.phase("pexe.build", step=step):
+                mega = self._exe._build_megastep(
+                    program, tuple(sorted(feeds_k)), fetch_names,
+                    state_keys, static_info, check_nan, k)
 
-            def fn(state, feeds, keys, _fn=mega, _amp=use_amp):
-                # pin AMP for the trace, restore after (see run())
-                prev = amp_enabled()
-                enable_amp(_amp)
-                try:
-                    return _fn(state, feeds, keys)
-                finally:
-                    enable_amp(prev)
+                def fn(state, feeds, keys, _fn=mega, _amp=use_amp):
+                    # pin AMP for the trace, restore after (see run())
+                    prev = amp_enabled()
+                    enable_amp(_amp)
+                    try:
+                        return _fn(state, feeds, keys)
+                    finally:
+                        enable_amp(prev)
 
-            entry = jax.jit(fn, donate_argnums=(0,))
-            self._cache[key] = entry
-            if mon_on:
-                import jax.numpy as _jnp
-                rng0 = jax.vmap(jax.random.key)(
-                    _jnp.zeros((k,), _jnp.uint32))
-                _mon.on_compile(
-                    program, key, key[4],
-                    cost_fn=lambda: _step_costs_safe(
-                        fn, dict(state), dict(feeds_k), rng0),
-                    executor="pexe",
-                    tokens=_mon.tokens_in_feeds(feeds_k),
-                    devices=self.device_count)
+                entry = jax.jit(fn, donate_argnums=(0,))
+                self._cache[key] = entry
+                if mon_on:
+                    import jax.numpy as _jnp
+                    rng0 = jax.vmap(jax.random.key)(
+                        _jnp.zeros((k,), _jnp.uint32))
+                    _mon.on_compile(
+                        program, key, key[4],
+                        cost_fn=lambda: _step_costs_safe(
+                            fn, dict(state), dict(feeds_k), rng0),
+                        executor="pexe",
+                        tokens=_mon.tokens_in_feeds(feeds_k),
+                        devices=self.device_count)
 
         base = program.random_seed * 1000003 + self._exe._rng_counter
         self._exe._rng_counter += k
@@ -330,8 +325,6 @@ class ParallelExecutor:
             [np.uint32(base + i) for i in range(k)]))
 
         repl = NamedSharding(self.mesh, PartitionSpec())
-        state_dev = {n: self._to_global(v, self._state_sharding(n))
-                     for n, v in state.items()}
         dp_axis = None
         if "dp" in self.mesh.axis_names:
             dp_axis = "dp"
@@ -343,8 +336,11 @@ class ParallelExecutor:
             return NamedSharding(self.mesh,
                                  PartitionSpec(None, dp_axis))
 
-        feeds_dev = {n: self._to_global(v, feed_sharding(n, v))
-                     for n, v in feeds_k.items()}
+        with _trc.phase("pexe.place", step=step):
+            state_dev = {n: self._to_global(v, self._state_sharding(n))
+                         for n, v in state.items()}
+            feeds_dev = {n: self._to_global(v, feed_sharding(n, v))
+                         for n, v in feeds_k.items()}
 
         window = max(1, int(get_flag("megastep_inflight")))
         inflight = self.__dict__.setdefault("_inflight", [])
@@ -355,8 +351,10 @@ class ParallelExecutor:
         if mon_on:
             timer = _mon.step_timer(self)
             do_sync = timer.begin(t0)
-        fetches_k, new_state, guards_k, lods_k = entry(
-            state_dev, feeds_dev, keys)
+        with _trc.phase("pexe.build" if fresh else "pexe.dispatch",
+                        step=step):
+            fetches_k, new_state, guards_k, lods_k = entry(
+                state_dev, feeds_dev, keys)
         if mon_on:
             fb = _mon.feed_nbytes(feeds_k)
             tk = _mon.tokens_in_feeds(feeds_k)
@@ -370,60 +368,68 @@ class ParallelExecutor:
                                  feed_bytes=fb, tokens=tk,
                                  executor="pexe", synced=False)
 
-        fetches_k = [self._local_value(v) for v in fetches_k]
-        lods_k = {n: self._local_value(v) for n, v in lods_k.items()}
-        guards_k = {n: self._local_value(v) for n, v in guards_k.items()}
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if check_nan:
-            _Exe._check_guards_steps(guards_k, k)
-        out = _Exe._split_step_fetches(fetch_names, fetches_k, lods_k,
-                                       k, return_numpy)
-        if check_nan:
-            for fi in out:
-                _Exe._check_nan_inf(fetch_names, fi)
-        if not return_numpy:
-            inflight.append(fetches_k)
-        return out
+        with _trc.phase("pexe.pull", step=step):
+            fetches_k = [self._local_value(v) for v in fetches_k]
+            lods_k = {n: self._local_value(v) for n, v in lods_k.items()}
+            guards_k = {n: self._local_value(v)
+                        for n, v in guards_k.items()}
+        with _trc.phase("pexe.commit", step=step):
+            for n, v in new_state.items():
+                scope.set(n, v)
+            if check_nan:
+                _Exe._check_guards_steps(guards_k, k)
+            out = _Exe._split_step_fetches(fetch_names, fetches_k,
+                                           lods_k, k, return_numpy)
+            if check_nan:
+                for fi in out:
+                    _Exe._check_nan_inf(fetch_names, fi)
+            if not return_numpy:
+                inflight.append(fetches_k)
+            return out
 
     def _run_impl(self, fetch_list, feed=None, feed_dict=None,
                   return_numpy=True):
-        feed = dict(feed or feed_dict or {})
         program = self._program
         scope = self._scope
-        fetch_names = tuple(
-            f.name if isinstance(f, Variable) else str(f)
-            for f in (fetch_list or []))
+        # the phases of a step, as core Executor._run_impl has them
+        # (pexe.feed / state / build / dispatch / commit), plus
+        # pexe.place round what lays state and feeds out on the mesh and
+        # pexe.pull round what brings fetched values to the host
+        step = self._exe._rng_counter
+        with _trc.phase("pexe.feed", step=step):
+            feed = dict(feed or feed_dict or {})
+            fetch_names = tuple(
+                f.name if isinstance(f, Variable) else str(f)
+                for f in (fetch_list or []))
 
-        dp = 1
-        if "dp" in self.mesh.axis_names:
-            dp = self.mesh.shape["dp"]
-        # ragged token buffers keep a replicated layout (their row count is
-        # data-dependent); GSPMD re-shards downstream. _normalize_feeds also
-        # buckets the flat LoD totals so signatures stay cache-stable.
-        from ..core.executor import _normalize_feeds
-        feed_arrays, static_info = _normalize_feeds(
-            feed, accum_steps=self._accum_steps,
-            plan_cache=self._feed_plans)
-        if self._accum_steps > 1:
-            self._check_accum_weights(feed_arrays)
-        lod_keys = {k for k in feed_arrays
-                    if k.endswith("@LOD") or k.endswith("@ACCUM_TOKENS")}
-        lod_keys |= {k for k, v in feed.items() if isinstance(v, LoDTensor)}
-        for k, v in feed_arrays.items():
-            if k in lod_keys:
-                continue
-            if v.ndim >= 1 and v.shape[0] % dp != 0:
-                raise ValueError(
-                    "feed %r batch dim %d not divisible by dp=%d "
-                    "(SplitLoDTensor parity requires equal chunks)"
-                    % (k, v.shape[0], dp))
+            dp = 1
+            if "dp" in self.mesh.axis_names:
+                dp = self.mesh.shape["dp"]
+            # ragged token buffers keep a replicated layout (their row
+            # count is data-dependent); GSPMD re-shards downstream.
+            # _normalize_feeds also buckets the flat LoD totals so
+            # signatures stay cache-stable.
+            from ..core.executor import _normalize_feeds
+            feed_arrays, static_info = _normalize_feeds(
+                feed, accum_steps=self._accum_steps,
+                plan_cache=self._feed_plans)
+            if self._accum_steps > 1:
+                self._check_accum_weights(feed_arrays)
+            lod_keys = {k for k in feed_arrays if k.endswith("@LOD")
+                        or k.endswith("@ACCUM_TOKENS")}
+            lod_keys |= {k for k, v in feed.items()
+                         if isinstance(v, LoDTensor)}
+            for k, v in feed_arrays.items():
+                if k in lod_keys:
+                    continue
+                if v.ndim >= 1 and v.shape[0] % dp != 0:
+                    raise ValueError(
+                        "feed %r batch dim %d not divisible by dp=%d "
+                        "(SplitLoDTensor parity requires equal chunks)"
+                        % (k, v.shape[0], dp))
 
-        persistable = [v.name for v in program.global_block().vars.values()
-                       if v.persistable]
-        state = {n: scope.find_var(n) for n in persistable
-                 if scope.find_var(n) is not None}
-        state_keys = tuple(sorted(state))
+        with _trc.phase("pexe.state", step=step):
+            state, state_keys = _gather_state(program, scope)
 
         hints = tuple(sorted(
             (k, tuple(v)) for k, v in program._sharding_hints.items()))
@@ -442,45 +448,48 @@ class ParallelExecutor:
         mon_on = _mon.enabled()
         entry = self._cache.get(key)
         repl = NamedSharding(self.mesh, PartitionSpec())
-        if entry is not None and mon_on:
+        fresh = entry is None
+        if not fresh and mon_on:
             _mon.on_cache_hit()
-        if entry is None:
-            built = self._exe._build(program, tuple(sorted(feed_arrays)),
-                                     fetch_names, state_keys,
-                                     static_info=static_info,
-                                     check_nan=check_nan,
-                                     accum_steps=self._accum_steps,
-                                     accum_loss_norm=self._accum_loss_norm)
-            if mon_on:
-                from ..core.executor import _step_costs_safe
-                rng0 = jax.random.key(0)
-                _mon.on_compile(
-                    program, key, key[2],
-                    cost_fn=lambda: _step_costs_safe(
-                        built, dict(state), dict(feed_arrays), rng0),
-                    executor="pexe",
-                    tokens=_mon.tokens_in_feeds(feed_arrays),
-                    devices=self.device_count)
+        if fresh:
+            with _trc.phase("pexe.build", step=step):
+                built = self._exe._build(
+                    program, tuple(sorted(feed_arrays)), fetch_names,
+                    state_keys, static_info=static_info,
+                    check_nan=check_nan, accum_steps=self._accum_steps,
+                    accum_loss_norm=self._accum_loss_norm)
+                if mon_on:
+                    from ..core.executor import _step_costs_safe
+                    rng0 = jax.random.key(0)
+                    _mon.on_compile(
+                        program, key, key[2],
+                        cost_fn=lambda: _step_costs_safe(
+                            built, dict(state), dict(feed_arrays), rng0),
+                        executor="pexe",
+                        tokens=_mon.tokens_in_feeds(feed_arrays),
+                        devices=self.device_count)
 
-            def fn(state, feeds, key, _fn=built, _amp=use_amp):
-                # lowering reads the AMP flag at TRACE time; pin it for
-                # the trace and restore the ambient value (no global leak)
-                prev = amp_enabled()
-                enable_amp(_amp)
-                try:
-                    return _fn(state, feeds, key)
-                finally:
-                    enable_amp(prev)
+                def fn(state, feeds, key, _fn=built, _amp=use_amp):
+                    # lowering reads the AMP flag at TRACE time; pin it
+                    # for the trace and restore the ambient value (no
+                    # global leak)
+                    prev = amp_enabled()
+                    enable_amp(_amp)
+                    try:
+                        return _fn(state, feeds, key)
+                    finally:
+                        enable_amp(prev)
 
-            # Shardings are established by COMMITTING the inputs (the
-            # device_put/make_array calls below), not by in_shardings:
-            # constraining the jit would force a reshard of step-2 state
-            # (whose committed sharding is whatever step 1 produced),
-            # which multi-process arrays cannot do. Committed-input
-            # propagation is the standard JAX training-loop pattern and
-            # keeps single- and multi-host behavior identical.
-            entry = jax.jit(fn, donate_argnums=(0,))
-            self._cache[key] = entry
+                # Shardings are established by COMMITTING the inputs
+                # (the device_put/make_array calls below), not by
+                # in_shardings: constraining the jit would force a
+                # reshard of step-2 state (whose committed sharding is
+                # whatever step 1 produced), which multi-process arrays
+                # cannot do. Committed-input propagation is the standard
+                # JAX training-loop pattern and keeps single- and
+                # multi-host behavior identical.
+                entry = jax.jit(fn, donate_argnums=(0,))
+                self._cache[key] = entry
 
         rng_key = jax.random.key(
             np.uint32(program.random_seed * 1000003
@@ -489,12 +498,13 @@ class ParallelExecutor:
 
         # place state per its sharding once; jit keeps the placement
         # on subsequent steps (see _to_global)
-        state_dev = {n: self._to_global(v, self._state_sharding(n))
-                     for n, v in state.items()}
-        data_sh = self._data_sharding()
-        feeds_dev = {k: self._to_global(v, repl if k in lod_keys
-                                        else data_sh)
-                     for k, v in feed_arrays.items()}
+        with _trc.phase("pexe.place", step=step):
+            state_dev = {n: self._to_global(v, self._state_sharding(n))
+                         for n, v in state.items()}
+            data_sh = self._data_sharding()
+            feeds_dev = {k: self._to_global(v, repl if k in lod_keys
+                                            else data_sh)
+                         for k, v in feed_arrays.items()}
 
         import time as _time
         t0 = _time.perf_counter() if mon_on else 0.0
@@ -503,8 +513,10 @@ class ParallelExecutor:
             # same windowing as core Executor.run
             timer = _mon.step_timer(self)
             do_sync = timer.begin(t0)
-        fetches, new_state, guards, fetch_lods = entry(
-            state_dev, feeds_dev, rng_key)
+        with _trc.phase("pexe.build" if fresh else "pexe.dispatch",
+                        step=step):
+            fetches, new_state, guards, fetch_lods = entry(
+                state_dev, feeds_dev, rng_key)
         if mon_on:
             fb = _mon.feed_nbytes(feed_arrays)
             tk = _mon.tokens_in_feeds(feed_arrays)
@@ -518,16 +530,19 @@ class ParallelExecutor:
                              feed_bytes=fb, tokens=tk, executor="pexe",
                              synced=False)
 
-        fetches = [self._local_value(v) for v in fetches]
-        fetch_lods = {k: self._local_value(v)
-                      for k, v in fetch_lods.items()}
-        guards = {k: self._local_value(v) for k, v in guards.items()}
-        fetches = Executor._trim_fetches(fetch_names, fetches, fetch_lods)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        if check_nan:
-            Executor._check_guards(guards)
-            Executor._check_nan_inf(fetch_names, fetches)
-        if return_numpy:
-            return [as_numpy(v) for v in fetches]
-        return list(fetches)
+        with _trc.phase("pexe.pull", step=step):
+            fetches = [self._local_value(v) for v in fetches]
+            fetch_lods = {k: self._local_value(v)
+                          for k, v in fetch_lods.items()}
+            guards = {k: self._local_value(v) for k, v in guards.items()}
+            fetches = Executor._trim_fetches(fetch_names, fetches,
+                                             fetch_lods)
+            if return_numpy:
+                fetches = [as_numpy(v) for v in fetches]
+        with _trc.phase("pexe.commit", step=step):
+            for n, v in new_state.items():
+                scope.set(n, v)
+            if check_nan:
+                Executor._check_guards(guards)
+                Executor._check_nan_inf(fetch_names, fetches)
+            return list(fetches)

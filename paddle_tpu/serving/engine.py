@@ -112,12 +112,13 @@ class Request:
     SLO is written against: ``queue_wait``, ``ttft`` and ``tpot``.
     Stamps later in the lifecycle are ``None`` until reached; reading
     them after ``result()`` returns is race-free (the engine writes
-    them before resolving the future)."""
+    them before resolving the future). ``t_tokens`` holds one stamp
+    per output token (tokens of one dispatch share theirs)."""
 
     __slots__ = ("prompt", "max_new", "tokens", "score", "_event",
                  "_error", "t_enqueue", "t_admit", "t_first_token",
                  "t_retire", "prefill_chunks", "_span", "rid",
-                 "sampling", "preemptions", "_seq")
+                 "sampling", "preemptions", "_seq", "t_tokens")
 
     def __init__(self, prompt, max_new, request_id=None, sampling=None):
         self.prompt = [int(t) for t in prompt]
@@ -136,6 +137,11 @@ class Request:
         # rid attr — the resubmission hop is joinable in `trace merge`
         self.rid = request_id
         self.tokens = []
+        # one perf_counter stamp per output token, appended where
+        # ``tokens`` is, from the ``now`` the engine loop took after
+        # the dispatch that produced it (t_tokens[0] == t_first_token):
+        # the program's half of a per-token gap percentile
+        self.t_tokens = []
         self.score = None
         self._event = threading.Event()
         self._error = None
@@ -658,20 +664,22 @@ class Engine:
         else:
             logits, state = self.model._step_logits_slots(
                 tok, state, pos, write_mask=active)
-        logits32 = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits32)
-        greedy = jnp.argmax(logp, axis=-1).astype(jnp.int32)
-        if sampled:
-            # per-slot draw, SELECTED per slot: temperature-0 slots
-            # take the greedy value through an elementwise where, so
-            # their tokens are bitwise the greedy program's
-            keys = _step_keys(state["seed"], state["count"])
-            drawn = _sample(logits32, state["temp"], state["topk"],
-                            state["topp"], keys)
-            nxt = jnp.where(state["temp"] > 0.0, drawn, greedy)
-        else:
-            nxt = greedy
-        tok_logp = jnp.take_along_axis(logp, nxt[:, None], axis=-1)[:, 0]
+        with jax.named_scope("sample"):
+            logits32 = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits32)
+            greedy = jnp.argmax(logp, axis=-1).astype(jnp.int32)
+            if sampled:
+                # per-slot draw, SELECTED per slot: temperature-0 slots
+                # take the greedy value through an elementwise where,
+                # so their tokens are bitwise the greedy program's
+                keys = _step_keys(state["seed"], state["count"])
+                drawn = _sample(logits32, state["temp"], state["topk"],
+                                state["topp"], keys)
+                nxt = jnp.where(state["temp"] > 0.0, drawn, greedy)
+            else:
+                nxt = greedy
+            tok_logp = jnp.take_along_axis(logp, nxt[:, None],
+                                           axis=-1)[:, 0]
         end = jnp.int32(self.model.end_id)
         emit = jnp.where(active, nxt, end)
         count = state["count"] + active.astype(jnp.int32)
@@ -972,6 +980,7 @@ class Engine:
                                            donate_argnums=0)
             self._state = self._release_fn(self._state, np.int32(slot))
         del req.tokens[:]
+        del req.t_tokens[:]      # stamps restart with the tokens
         req.score = None
         req.preemptions += 1
         req._span.annotate(preemptions=req.preemptions)
@@ -1085,8 +1094,14 @@ class Engine:
         finished = ()
         self._preempted_iter = 0
         try:
-            with _trc.span("engine.step") as sp:
-                admitted = self._admit()
+            # the iteration's root, numbered: always an annotation in
+            # the JAX profiler's timeline, and the Dapper span too when
+            # the tracer is armed. Its phases (engine.admit / prefill /
+            # btab / dispatch / fetch / book) are annotations only.
+            step = self.stats["steps"]
+            with _trc.span("engine.step", step=step) as sp:
+                with _trc.phase("engine.admit", step=step):
+                    admitted = self._admit()
                 # dt clock starts AFTER _admit: the deliberate
                 # wait-for-batch window (serving_admission_wait) is
                 # admission POLICY, and folding its idle sleep into
@@ -1292,6 +1307,7 @@ class Engine:
         positions (possibly evicting prefix chains / preempting), and
         a prefix-cache hit enters here with its cursor already past
         the cached positions."""
+        step = self.stats["steps"]
         for slot, rec in enumerate(self._recs):
             if rec is None or rec["live"]:
                 continue
@@ -1300,19 +1316,28 @@ class Engine:
             cur = rec["cursor"]
             if cur < need:
                 toks = req.prompt[cur:min(cur + self._chunk, need)]
-                if self._paged and not self._ensure_blocks(
-                        rec, cur + len(toks) - 1):
-                    continue               # rec preempted back to queue
+                btab_row = None
+                if self._paged:
+                    with _trc.phase("engine.btab", step=step):
+                        if not self._ensure_blocks(
+                                rec, cur + len(toks) - 1):
+                            continue       # rec preempted back to queue
+                        btab_row = self._btab_row(rec)
                 chunk = np.zeros((self._chunk,), np.int32)
                 chunk[:len(toks)] = toks
-                with _trc.child_span(
-                        "request.prefill_chunk", req._span, start=cur,
-                        tokens=len(toks),
-                        step_span=self._step_span_id()):
+                # engine.prefill carries the request's id (the
+                # caller's, else the admission number);
+                # request.prefill_chunk is its Dapper twin
+                with _trc.phase("engine.prefill", step=step,
+                                rid=req._seq if req.rid is None
+                                else req.rid), \
+                        _trc.child_span(
+                            "request.prefill_chunk", req._span,
+                            start=cur, tokens=len(toks),
+                            step_span=self._step_span_id()):
                     self._state = self._prefill_fn(
                         self._state, np.int32(slot), chunk,
-                        np.int32(cur), np.int32(len(toks)),
-                        self._btab_row(rec) if self._paged else None)
+                        np.int32(cur), np.int32(len(toks)), btab_row)
                 rec["cursor"] = cur + len(toks)
                 req.prefill_chunks += 1
                 self.stats["prefill_chunks"] += 1
@@ -1431,16 +1456,27 @@ class Engine:
                 if r is not None and r["live"]]
         if not live:
             return 0, [], 0, 0, 0
-        btab = self._btab_all()
+        step = self.stats["steps"]
+        with _trc.phase("engine.btab", step=step):
+            btab = self._btab_all()
         sampled = any(
             self._recs[s]["req"].sampling is not None for s in live)
         # ONE packed upload (draft lengths + tokens) and ONE packed
         # fetch (emits + counts + fins): per-dispatch host transfers
         # are exactly the tax this path exists to amortize
         dn = np.concatenate([nd[:, None], drafts], axis=1)
-        self._state, out = self._spec_fn(self._state, btab,
-                                         jnp.asarray(dn), sampled)
-        out = np.asarray(out)
+        with _trc.phase("engine.dispatch", step=step):
+            self._state, out = self._spec_fn(self._state, btab,
+                                             jnp.asarray(dn), sampled)
+        with _trc.phase("engine.fetch", step=step):
+            out = np.asarray(out)
+        with _trc.phase("engine.book", step=step):
+            return self._book_spec(out, nd, live)
+
+    def _book_spec(self, out, nd, live):
+        """Host bookkeeping of one speculative dispatch: commit each
+        live slot's accepted prefix (plus the bonus token), stamp and
+        retire."""
         g1 = self._spec_gamma + 1
         emits, n_emit, fins = out[:, :g1], out[:, g1], out[:, g1 + 1]
         drafted = int(nd.sum())
@@ -1459,6 +1495,7 @@ class Engine:
             ne = int(n_emit[slot])
             for t in emits[slot, :ne]:
                 req.tokens.append(int(t))
+                req.t_tokens.append(now)
             emitted += ne
             accepted += max(0, ne - 1)
             rec["next_pos"] += ne
@@ -1508,6 +1545,43 @@ class Engine:
             drafts, nd = self._build_drafts()
             if drafts is not None:
                 return self._decode_spec(drafts, nd)
+        step = self.stats["steps"]
+        with _trc.phase("engine.btab", step=step):
+            live, btab = self._grow_tables(k)
+        if not live:
+            return 0, [], 0, 0, 0
+        # dispatch the sampling-tail program only while a stochastic
+        # request is actually live (static per-variant compile): the
+        # all-greedy path stays the PR-5 program, bit for bit and
+        # cost for cost
+        sampled = any(
+            self._recs[s]["req"].sampling is not None for s in live)
+        with _trc.phase("engine.dispatch", step=step):
+            if k > 1:
+                if self._megastep_fn is None:
+                    self._megastep_fn = jax.jit(self._megastep_impl,
+                                                donate_argnums=0,
+                                                static_argnums=2)
+                self._state, emits, fins = self._megastep_fn(
+                    self._state, btab, sampled)
+                self.stats["megastep_dispatches"] += 1
+            else:
+                self._state, emits, fins = self._step_fn(
+                    self._state, btab, sampled)
+        with _trc.phase("engine.fetch", step=step):
+            emits, fins = np.asarray(emits), np.asarray(fins)
+            if k == 1:
+                # host-side axis add: [None] on the DEVICE array would
+                # dispatch a reshape per step on the k=1 hot path
+                emits, fins = emits[None], fins[None]
+        with _trc.phase("engine.book", step=step):
+            return self._book(emits, fins, live)
+
+    def _grow_tables(self, k):
+        """Before a decode dispatch: grow every live slot's block table
+        to cover its next ``k`` write positions, then build the
+        [slots, max_blocks] table the dispatch uploads. Returns the
+        live slots and the table (None in dense mode)."""
         if self._paged:
             for slot in range(self.slots):
                 # re-read per iteration: an earlier slot's allocation
@@ -1528,31 +1602,12 @@ class Engine:
                         rec, rec["next_pos"] + min(k, rem) - 1)
         live = [s for s, r in enumerate(self._recs)
                 if r is not None and r["live"]]
-        if not live:
-            return 0, [], 0, 0, 0
-        btab = self._btab_all()
-        # dispatch the sampling-tail program only while a stochastic
-        # request is actually live (static per-variant compile): the
-        # all-greedy path stays the PR-5 program, bit for bit and
-        # cost for cost
-        sampled = any(
-            self._recs[s]["req"].sampling is not None for s in live)
-        if k > 1:
-            if self._megastep_fn is None:
-                self._megastep_fn = jax.jit(self._megastep_impl,
-                                            donate_argnums=0,
-                                            static_argnums=2)
-            self._state, emits, fins = self._megastep_fn(
-                self._state, btab, sampled)
-            self.stats["megastep_dispatches"] += 1
-            emits, fins = np.asarray(emits), np.asarray(fins)
-        else:
-            self._state, emit, fin = self._step_fn(self._state, btab,
-                                                   sampled)
-            # host-side axis add: [None] on the DEVICE array would
-            # dispatch a reshape per step on the k=1 hot path
-            emits = np.asarray(emit)[None]
-            fins = np.asarray(fin)[None]
+        return live, (self._btab_all() if live else None)
+
+    def _book(self, emits, fins, live):
+        """Host bookkeeping of one decode dispatch: append each live
+        slot's tokens with their stamps, mark first tokens, retire.
+        ``emits`` / ``fins`` are the fetched ``[K, S]`` rows."""
         scores = None
         finished = []
         emitted = 0
@@ -1572,6 +1627,7 @@ class Engine:
                 rec = self._recs[slot]
                 req = rec["req"]
                 req.tokens.append(int(emits[j, slot]))
+                req.t_tokens.append(now)
                 emitted += 1
                 if self._paged:
                     rec["next_pos"] += 1   # mirrors the device pos

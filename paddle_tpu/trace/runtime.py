@@ -36,6 +36,17 @@ Design points:
     form already carries the sampled=0 flag) so a remote peer's ring
     buffers the same trace under the same id; ``trace_tail_window=0``
     restores the historical headerless behavior.
+  * ONE timeline with the device (ISSUE 24): ``phase`` is the entry
+    point for a timed phase of a hot path. It IS a
+    ``jax.profiler.TraceAnnotation``, so it records only while a
+    profiler session runs (``jax.profiler.start_trace``) and then
+    lands in the profiler's own trace, on the clock the device ops
+    are on. ``span()`` opens the same annotation for the step ROOTS
+    (``exe.step`` / ``pexe.step`` / ``engine.step``) whether or not
+    the tracer is armed; armed, it also opens the Dapper span whose
+    JSONL row is written as before. The children of a root
+    (``exe.feed`` ... ``engine.book``) are phases only: they write no
+    JSONL row, so RPC verb spans keep the step root as their parent.
   * The span log reuses monitor's FlightRecorder (bounded JSONL,
     atomic-append, in-band truncation marker). Rows:
       span        {trace, span, parent, name, t0, dur, pid, proc, tid,
@@ -52,6 +63,8 @@ import sys
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ..monitor import runtime as _mon
 from ..monitor.recorder import FlightRecorder
 
@@ -59,7 +72,7 @@ __all__ = [
     "SpanContext", "Span", "Tracer", "enable", "disable", "enabled",
     "tracer", "span", "annotate", "current_span", "active_trace_id",
     "extract", "maybe_enable_from_flags", "detached_span", "child_span",
-    "retain_trace", "tail_armed", "tail_dump",
+    "retain_trace", "tail_armed", "tail_dump", "phase",
 ]
 
 _DEFAULT_MAX_BYTES = 64 << 20
@@ -189,6 +202,50 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+class phase(TraceAnnotation):
+    """``with trace.phase("exe.feed", step=n):`` — one timed phase of
+    a hot path, in the JAX profiler's timeline. A
+    ``jax.profiler.TraceAnnotation`` under the span interface: with no
+    profiler session it records nothing (about half a microsecond a
+    ``with``; PERF.md has the figure), with one its name, interval,
+    thread and keyword arguments land in the ``.xplane.pb`` beside
+    the device ops. It never writes a JSONL row."""
+
+    __slots__ = ()
+    ctx = None
+
+    def annotate(self, **attrs):
+        self.set_metadata(**attrs)
+
+
+class _RootSpan:
+    """A step root under an armed tracer: the profiler annotation and
+    the Dapper span, entered and left together."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, span):
+        self._ann = ann
+        self._span = span
+
+    @property
+    def ctx(self):
+        return self._span.ctx
+
+    def annotate(self, **attrs):
+        self._ann.annotate(**attrs)
+        self._span.annotate(**attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self._span.__exit__(etype, exc, tb)
+        return self._ann.__exit__(etype, exc, tb)
 
 
 class _TailRing:
@@ -514,12 +571,14 @@ def tracer():
 
 
 def span(name, **attrs):
-    """``with trace.span("round", step=i):`` — child of the ambient
-    span or a new root; a no-op context manager when disarmed."""
+    """``with trace.span("exe.step", step=i):`` — a ``phase`` in the
+    profiler's timeline, always; with the tracer armed also the Dapper
+    span (child of the ambient span or a new root) whose row goes to
+    the JSONL log."""
     t = _TRACER
     if t is None:
-        return _NULL_SPAN
-    return t.span(name, **attrs)
+        return phase(name, **attrs)
+    return _RootSpan(phase(name, **attrs), t.span(name, **attrs))
 
 
 def detached_span(name, **attrs):

@@ -112,3 +112,31 @@ def test_paged_attention_compiles_for_v5e(chip, pool_dtype, rows_c, dk,
                                v_scale=vs, layer=layer, force="pallas")
 
     assert "tpu_custom_call" in _compiled_text(fn, *avals)
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv", "paged_decode"])
+def test_kernel_name_is_in_the_lowered_text(chip, kernel):
+    """The name a profile of the chip shows for each kernel (ISSUE 24):
+    the ``kernel_name`` of its ``tpu_custom_call`` in the text lowered
+    for the v5e."""
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    if kernel == "paged_decode":
+        pool = aval((_NB, _LAYERS, _HEADS, _BS, 64), jnp.float32)
+        text = jax.jit(lambda q, pk, pv, btab, qpos: paged_attention(
+            q, pk, pv, btab, qpos, layer=3, force="pallas")).lower(
+            aval((_SLOTS, _HEADS, 1, 64), jnp.float32), pool, pool,
+            aval((_SLOTS, _NBMAX), jnp.int32),
+            aval((_SLOTS, 1), jnp.int32)).as_text()
+    else:
+        q = aval((8, 16, 1024, 64), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, force="pallas"
+                                   ).astype(jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).as_text()
+    assert 'kernel_name = "%s"' % kernel in text
