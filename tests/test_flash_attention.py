@@ -1,10 +1,15 @@
 """Pallas flash-attention kernel parity (ops/flash_attention.py).
 
-The kernel runs here in interpret mode (the CPU simulation of the TPU
-kernel — it emulates MXU bf16 matmul precision, hence the loose
-tolerances); real-chip parity is exercised by the TPU benchmarks. The
-dense jnp formulation is the reference (it equals the composed
-matmul+softmax ops the models otherwise emit)."""
+The kernel runs here in interpret mode: the same kernel body, traced to
+XLA ops for the CPU. It keeps the dtypes the body asks for (float32
+inputs stay float32 matmul operands, bfloat16 inputs stay bfloat16 and
+p / ds are rounded to bfloat16 before their matmuls, every dot
+accumulating in float32), so float32 cases agree with dense math to
+rounding and the loose float32 tolerances are headroom, not need; what
+it cannot see is the chip's compiler (tests/test_tpu_compile.py) and
+the chip's own parity (PERF.md section 6 records it). The dense jnp
+formulation is the reference (it equals the composed matmul+softmax ops
+the models otherwise emit)."""
 
 import numpy as np
 import pytest
@@ -225,3 +230,136 @@ def test_auto_block_degenerate_t_demotes_to_dense(monkeypatch):
     assert path_for(2 * 1031) == "dense"     # largest divisor 2
     assert path_for(17 * 127) == "dense"     # largest divisor 127 < 128
     assert path_for(2062, block=1031) == "pallas"  # explicit block wins
+
+
+# -- the walk inside a major block (PR 25) ----------------------------------
+# (T, D, panel target, block_q, block_k, cap on an unmasked panel's
+# scores or None for the module's) -> the panel edge it gives
+_WALKS = [
+    # one major block, the whole of T, cut into 4 panels of 128 on the
+    # diagonal: the tiles above it never computed, the mask on the
+    # diagonal's tiles only, the forward with nothing to rescale (one
+    # key block), dk/dv's panels by key with the scores transposed
+    pytest.param(512, 64, 128, 512, 512, None, 128, id="T512-one_block"),
+    # 2 x 2 major blocks of two panels: the block below the diagonal in
+    # one unmasked panel, the block above it skipped, scratch and the
+    # running max carried between grid steps; a scale that is no power
+    # of two
+    pytest.param(512, 32, 128, 256, 256, None, 128, id="T512-four_blocks"),
+    # the same with unmasked panels capped at 128 x 256 scores: the
+    # block below the diagonal (and every block of the full case) is
+    # cut into two
+    pytest.param(512, 64, 128, 256, 256, 128 * 256, 128,
+                 id="T512-capped_panels"),
+    # unequal blocks cross the diagonal anywhere: one panel masked at
+    # the offset the grid step gives
+    pytest.param(768, 64, 256, 256, 384, None, 256, id="T768-unequal"),
+]
+
+
+def _walk_inputs(t, d, dtype, seed):
+    rng = np.random.RandomState(seed)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape) * 0.5, jnp.float32)
+    q, k, v, dy = (mk(1, 2, t, d).astype(dtype) for _ in range(4))
+    return q, k, v, dy, mk(1, 2, t)
+
+
+def _f32(a):
+    return a.astype(jnp.float32)
+
+
+def _assert_close(name, got, want, tol):
+    """Largest error over the largest reference value."""
+    err = float(jnp.max(jnp.abs(_f32(got) - want))) / (
+        float(jnp.max(jnp.abs(want))) + 1e-9)
+    assert err < tol, (name, err)
+
+
+@pytest.mark.parametrize("t, d, tile, bq, bk, scores, edge", _WALKS)
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_tiled_walk_matches_dense(monkeypatch, dtype, causal, t, d, tile,
+                                  bq, bk, scores, edge):
+    """out, lse, dq, dk, dv of the kernels walking their major blocks
+    in panels, against dense float32 math on the same inputs."""
+    monkeypatch.setattr(FA, "_TILE", tile)
+    if scores:
+        monkeypatch.setattr(FA, "_PANEL_SCORES", scores)
+    assert FA._tile(bq, tile) == edge
+    q, k, v, dy, _ = _walk_inputs(t, d, dtype, seed=5)
+    scale = d ** -0.5
+    kw = dict(causal=causal, force="interpret", block_q=bq, block_k=bk)
+
+    def ref_loss(q, k, v):
+        return (FA._dense(q, k, v, causal, scale) * _f32(dy)).sum()
+
+    def got_loss(q, k, v):
+        return (_f32(FA.flash_attention(q, k, v, **kw)) * _f32(dy)).sum()
+
+    o_ref, lse_ref = FA._dense_lse(_f32(q), _f32(k), _f32(v), causal, scale)
+    g_ref = jax.grad(ref_loss, (0, 1, 2))(_f32(q), _f32(k), _f32(v))
+    o, lse = FA.flash_attention_lse(q, k, v, **kw)
+    g = jax.grad(got_loss, (0, 1, 2))(q, k, v)
+    assert o.dtype == dtype and lse.dtype == jnp.float32
+    assert lse.shape == q.shape[:3]
+    if dtype == jnp.float32:      # today's tolerances
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   atol=2e-3, rtol=2e-2)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                                   atol=2e-3, rtol=2e-2)
+        tol = 5e-3
+    else:                         # bf16 out and grads round at 2^-9
+        _assert_close("out", o, o_ref, 1e-2)
+        _assert_close("lse", lse, lse_ref, 1e-2)
+        tol = 2e-2
+    for name, a, b in zip(("dq", "dk", "dv"), g, g_ref):
+        assert a.dtype == dtype
+        _assert_close(name, a, b, tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_tiled_walk_lse_cotangent(monkeypatch, dtype):
+    """flash_attention_lse with a NON-ZERO lse cotangent (what ring
+    attention sends back) through the tiled backward kernels."""
+    monkeypatch.setattr(FA, "_TILE", 128)
+    t, d = 512, 64
+    q, k, v, dy, dlse = _walk_inputs(t, d, dtype, seed=6)
+    scale = d ** -0.5
+
+    def loss(att):
+        def f(q, k, v):
+            o, lse = att(q, k, v)
+            return (_f32(o) * _f32(dy)).sum() + (lse * dlse).sum()
+        return f
+
+    g_ref = jax.grad(loss(lambda q, k, v: FA._dense_lse(
+        q, k, v, True, scale)), (0, 1, 2))(_f32(q), _f32(k), _f32(v))
+    g = jax.grad(loss(lambda q, k, v: FA.flash_attention_lse(
+        q, k, v, causal=True, force="interpret")), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g, g_ref):
+        _assert_close(name, a, b, 5e-3 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("block, target, want", [
+    (1024, 256, 256), (2048, 512, 512), (768, 512, 384), (1536, 512, 512),
+    (384, 256, 128), (1031, 256, 1031), (128, 256, 128), (64, 256, 64)])
+def test_panel_edge_divides_its_block(block, target, want):
+    """A panel's edge is a multiple of 128 that divides the major
+    block, or the block itself where it has no such divisor."""
+    assert FA._tile(block, target) == want
+
+
+@pytest.mark.parametrize("t, d, dtype, want", [
+    (2048, 64, jnp.bfloat16, 2048),    # the benchmark's cell: one block
+    (2048, 128, jnp.bfloat16, 2048),
+    (1536, 128, jnp.bfloat16, 1536),
+    (1024, 64, jnp.float32, 1024),
+    (2048, 64, jnp.float32, 1024),     # float32 operands: streamed
+    (4096, 64, jnp.bfloat16, 1024),    # too long for one block: streamed
+    (2048, 256, jnp.bfloat16, 1024),
+    (1536, 128, jnp.float32, 768),
+    (2062, 64, jnp.bfloat16, 2)])      # no panel divides it: degenerate
+def test_auto_block_is_all_of_t_where_it_fits(t, d, dtype, want):
+    assert FA._auto_block(t, d, jnp.dtype(dtype).itemsize) == want

@@ -10,7 +10,8 @@ compiles are what keeps that from recurring. Nothing runs, so this says
 nothing about results or times (chip_smoke.py does, on the chip).
 
 Shapes are the two main paths' at real width: the transformer-large
-train step's flash attention (and the XL head dim), and the serve
+train step's flash attention (and the XL head dim), the benchmark's
+cell (``opt350m_train``: batch 4 x 2048, 16 heads of 64), and the serve
 phase's pool — 8 slots x max_len 1024 at block 16 -> a [512, 8, 16,
 16, dk] pool and a 64-column block table — at C = 1 (decode), gamma+1
 (speculative scoring) and the prefill chunk.
@@ -54,8 +55,9 @@ def _compiled_text(fn, *avals):
     return jax.jit(fn).lower(*avals).compile().as_text()
 
 
-@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (8, 8, 1024, 128)],
-                         ids=["large_dk64", "xl_dk128"])
+@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (8, 8, 1024, 128),
+                                   (4, 16, 2048, 64)],
+                         ids=["large_dk64", "xl_dk128", "opt350m_cell"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_compiles_for_v5e(chip, shape, direction):
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
