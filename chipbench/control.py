@@ -3,21 +3,28 @@
     python3 chipbench/control.py --workload <cell> --seeds 11,12,13,...
 
 For a training cell, in ONE process and with no timed window: for each
-seed the cell's program as ``drivers/train_steps.py`` builds it (the
-same seed folding, Adam, bf16 AMP), its parameters as initialised, the
-float32 reference's logits on the first sequence's last ``check_rows``
-rows, and against them the error of
+seed the cell's program as ``drivers/train_steps.py`` builds it (its
+``trainer``: the same seed folding, Adam, bf16 AMP), its parameters as
+initialised, and the driver's own comparison
+(``train_steps.forward_against_reference``: the float32 reference's
+logits on the first sequence's last ``check_rows`` rows, handed the
+program's choices where the model makes any), and against that
+reference the error of
 
 * the program's own bf16-AMP forward: the number ``correct`` compares,
   which has to stay under the architecture's ``TRAIN_LOGITS_RTOL``;
 * the CONTROL (``arch.control_logits_at``): the reference put in the
   program's place and computed in the nearest precision below the one
-  the configuration states. It has to come out as NOT correct.
+  the configuration states. It has to come out as NOT correct. For a
+  model that chooses it is handed what the reference was handed and
+  routes as the float32 reference does, so that fp8 arithmetic alone
+  separates the two (``README.md``, "an architecture", says why).
 
 Every number is printed beside the limit. The last line is one JSON
 object: the largest sound reading, the smallest control reading, the
-limit, and ``separates``: whether the control's smallest is at least
-three times the program's largest, without which no limit holds. Exit
+limit, ``routed`` (whether the reference was handed choices) and
+``separates``: whether the control's smallest is at least three times
+the program's largest, without which no limit holds. Exit
 code 0 only if every program reading is under the limit, every control
 reading over it and they separate. The benchmark's own runs never call
 this; ``tests/chipbench/test_chipbench_control.py`` runs it at tiny
@@ -39,39 +46,26 @@ from chipbench.drivers import train_steps            # noqa: E402
 from chipbench.reference import compare              # noqa: E402
 
 
-def readings(cell, arch, seed, on_tpu, jitted):
-    """(the program's logits error, the control's) for one seed."""
-    import jax.numpy as jnp
+def readings(cell, seed, on_tpu):
+    """(the program's logits error, the control's, whether the
+    reference was handed the program's choices) for one seed."""
+    import jax
     import numpy as np
-    import paddle_tpu as fluid
-
     cfg, mix = cell["config_file"], cell["traffic_file"]
     seq, rows = int(mix["seq_len"]), int(mix["check_rows"])
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 1 + seed % 4093
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        avg_cost, logits = arch.build(cfg, seq)
-        forward = main.clone(for_test=True)
-        fluid.optimizer.Adam(learning_rate=1e-4).minimize(avg_cost)
-        fluid.amp.enable_amp()
-        try:
-            exe = fluid.Executor(fluid.TPUPlace(0) if on_tpu
-                                 else fluid.CPUPlace())
-            exe.run(startup)
-            (first,) = train_steps.declared_feeds(main, traffic.lm_batches(
-                seed, 1, 1, seq, cfg["vocab_size"]))
-            params = arch.params_of_program(main, scope, cfg)
-            tokens = jnp.asarray(first["src"][0])
-            ref, control = (np.asarray(f(params, tokens, seq - rows, rows))
-                            for f in jitted)
-            got = np.asarray(exe.run(
-                forward, feed=first, fetch_list=[logits],
-                return_numpy=False)[0][0, seq - rows:], np.float32)
-        finally:
-            fluid.amp.enable_amp(False)
+    with train_steps.trainer(cell, seed, on_tpu) as t:
+        (first,) = train_steps.declared_feeds(t.main, traffic.lm_batches(
+            seed, 1, 1, seq, cfg["vocab_size"]))
+        # on the host: the forward's run donates the scope's arrays,
+        # and the control is read after it
+        params = jax.tree.map(np.asarray, t.arch.params_of_program(
+            t.main, t.scope, cfg))
+        got, ref, choices = train_steps.forward_against_reference(
+            t, params, first, rows)
+        control = train_steps.reference_rows(
+            t.arch.control_logits_at, cfg, params, first, rows, choices)
     return (compare.logits_error(got, ref),
-            compare.logits_error(control, ref))
+            compare.logits_error(control, ref), choices is not None)
 
 
 def main(argv=None):
@@ -86,15 +80,11 @@ def main(argv=None):
     jax.config.update("jax_compilation_cache_dir", entry.CACHE_DIR)
     cell = entry.load_cell(args.workload, args.rehearse)
     _, devices = entry.device_or_exit(cell["chips"], args.rehearse)
-    cfg = cell["config_file"]
-    arch = cells.load_arch(cfg["arch"])
-    limit = arch.TRAIN_LOGITS_RTOL
-    jitted = [jax.jit(train_steps.for_config(f, cfg), static_argnums=3)
-              for f in (arch.logits_at, arch.control_logits_at)]
+    limit = cells.load_arch(cell["config_file"]["arch"]).TRAIN_LOGITS_RTOL
     got = []
     for seed in [int(s) for s in args.seeds.split(",")]:
-        program, control = readings(cell, arch, seed,
-                                    devices[0].platform == "tpu", jitted)
+        program, control, routed = readings(
+            cell, seed, devices[0].platform == "tpu")
         got.append((program, control))
         entry.log("seed %d: the program's logits error %.3e, the "
                   "control's %.3e (limit %.0e)" % (seed, program, control,
@@ -102,7 +92,7 @@ def main(argv=None):
     program_max = max(p for p, _ in got)
     control_min = min(c for _, c in got)   # NaN: the control gave no number
     result = {"program_max": program_max, "control_min": control_min,
-              "limit": limit, "seeds": len(got),
+              "limit": limit, "seeds": len(got), "routed": routed,
               "separates": bool(control_min >= 3 * program_max)}
     print(json.dumps(result), flush=True)
     return 0 if (program_max <= limit < control_min

@@ -8,15 +8,34 @@ map onto the reference's and how close the two must come are the
 architecture's; the optimizer, the precision and the window are this
 file's, the same for every model.
 
+``correct`` compares the program's forward with the architecture's
+float32 reference in ``forward_against_reference``, which
+``chipbench/control.py`` calls too (through ``trainer``, the program as
+this driver builds it): one comparison, so the tool reads what the
+driver reads. A model that CHOOSES (a top-k router; its architecture
+exports ``router_choices``) has its choices fetched in the same run of
+the forward as its logits, and the reference is handed them: a top-k is
+not continuous, and where two experts are a near-tie bf16 arithmetic
+below the router tips the choice the other way than float32 would,
+which moves a row by a whole expert's output and not by rounding. What
+the reference does with a proposal is the reference's
+(``README.md``, "an architecture").
+
 The window: steps are dispatched back to back, at most two in flight
 (the host stays ahead of the device and never runs away from it), until
 the host clock passes ``seconds``; one ``block_until_ready`` on the
-last loss ends it. The rate is all tokens over all of that time.
+last loss ends it. The rate is all tokens over all of that time. After
+it the run keeps what the program counted on the device
+(``arch.program_counters``, read once) as ``train.counters``, and the
+seconds from the process's start to each phase of set-up as
+``setup_phases``.
 """
 
+import contextlib
 import functools
 import math
 import time
+import types
 
 import numpy as np
 
@@ -44,28 +63,96 @@ def declared_feeds(program, feeds):
             for feed in feeds]
 
 
-def run(cell, seed, seconds, devices, t_start, trace_dir, log):
-    import jax
-    import jax.numpy as jnp
+@contextlib.contextmanager
+def trainer(cell, seed, on_tpu):
+    """The cell's program as a trainer builds it, inside its guards and
+    with bf16 AMP on until the block is left: ``arch``, ``cfg``,
+    ``main`` (with Adam), its ``forward`` clone from before the
+    backward, ``avg_cost``, ``logits``, ``scope`` and ``exe``, with the
+    start-up program run; ``built_at`` is the clock when the programs
+    stood and the executor was about to be made."""
     import paddle_tpu as fluid
-
-    cfg, mix = cell["config_file"], cell["traffic_file"]
+    cfg, seq = cell["config_file"], int(cell["traffic_file"]["seq_len"])
     arch = cells.load_arch(cfg["arch"])
-    seq, batch = int(mix["seq_len"]), int(mix["batch"])
-    rows = int(mix["check_rows"])
     main, startup = fluid.Program(), fluid.Program()
     # the executors fold random_seed * 1000003 into a uint32
     main.random_seed = startup.random_seed = 1 + seed % 4093
     scope = fluid.Scope()
-    on_tpu = devices[0].platform == "tpu"
     with fluid.program_guard(main, startup), fluid.scope_guard(scope):
         avg_cost, logits = arch.build(cfg, seq)
         forward = main.clone(for_test=True)    # before the backward
         fluid.optimizer.Adam(learning_rate=1e-4).minimize(avg_cost)
         fluid.amp.enable_amp()
-        exe = fluid.Executor(fluid.TPUPlace(0) if on_tpu
-                             else fluid.CPUPlace())
-        exe.run(startup)
+        try:
+            built_at = time.perf_counter()
+            exe = fluid.Executor(fluid.TPUPlace(0) if on_tpu
+                                 else fluid.CPUPlace())
+            exe.run(startup)
+            yield types.SimpleNamespace(
+                arch=arch, cfg=cfg, main=main, forward=forward,
+                scope=scope, exe=exe, avg_cost=avg_cost, logits=logits,
+                built_at=built_at)
+        finally:
+            fluid.amp.enable_amp(False)
+
+
+def reference_rows(fn, cfg, params, one, rows, choices=None):
+    """``fn`` (an architecture's ``logits_at`` or ``control_logits_at``)
+    on the last ``rows`` rows of the one sequence in ``one``, jitted
+    with its configuration bound for this call alone (its executable
+    goes when the call returns), and handed ``choices`` where the
+    program's forward made any."""
+    import jax
+    import jax.numpy as jnp
+    seq = one["src"].shape[1]
+    handed = {} if choices is None else {"choices": choices}
+    return np.asarray(jax.jit(for_config(fn, cfg), static_argnums=3)(
+        params, jnp.asarray(one["src"][0]), seq - rows, rows, **handed))
+
+
+def forward_against_reference(t, params, one, rows):
+    """(the program's logits ``[rows, V]``, the reference's, the
+    program's choices or None) on the last ``rows`` rows of the one
+    sequence in ``one``, from ONE run of the ``for_test`` clone.
+
+    An architecture that chooses nothing: the reference first, then the
+    forward fetching its logits alone (OPT's parameter tree is the
+    scope's own arrays; the order decides what is alive when). One that
+    exports ``router_choices``: the forward first, fetching the
+    choices' variables beside the logits, then the reference, handed
+    them stacked as fetched (``[layers, ...]``). Logits and choices
+    come out of the same executable, so the verdict never rests on a
+    second compilation of the program; such an architecture's
+    ``params_of_program`` gives host arrays."""
+    arch = t.arch
+    seq = one["src"].shape[1]
+    chooses = hasattr(arch, "router_choices")
+    if not chooses:
+        ref = reference_rows(arch.logits_at, t.cfg, params, one, rows)
+    fetched = t.exe.run(
+        t.forward, feed=one, return_numpy=False,
+        fetch_list=[t.logits] + (list(arch.router_choices(t.forward))
+                                 if chooses else []))
+    got = np.asarray(fetched[0][0, seq - rows:], np.float32)
+    if not chooses:
+        return got, ref, None
+    choices = np.stack([np.asarray(c) for c in fetched[1:]])
+    return got, reference_rows(arch.logits_at, t.cfg, params, one, rows,
+                               choices), choices
+
+
+def run(cell, seed, seconds, devices, t_start, trace_dir, log):
+    import jax
+    import paddle_tpu as fluid
+
+    since_start = lambda: time.perf_counter() - t_start
+    phases = {"entered": since_start()}
+    cfg, mix = cell["config_file"], cell["traffic_file"]
+    seq, batch = int(mix["seq_len"]), int(mix["batch"])
+    rows = int(mix["check_rows"])
+    with trainer(cell, seed, devices[0].platform == "tpu") as t:
+        arch, exe, main, avg_cost = t.arch, t.exe, t.main, t.avg_cost
+        phases["built"] = t.built_at - t_start
         step = lambda feed: exe.run(main, feed=feed,
                                     fetch_list=[avg_cost],
                                     return_numpy=False)[0]
@@ -73,6 +160,7 @@ def run(cell, seed, seconds, devices, t_start, trace_dir, log):
             seed, int(mix["n_batches"]), batch, seq, cfg["vocab_size"]))
         log("train: %d layers, batch %d x %d tokens, one chip" % (
             cfg["num_hidden_layers"], batch, seq))
+        phases["started"] = since_start()
 
         # the reference on the first batch, from the parameters as
         # initialised (the first step donates and updates them): its
@@ -81,24 +169,22 @@ def run(cell, seed, seconds, devices, t_start, trace_dir, log):
         # ln V whatever the precision; the logits are what a lower
         # precision than bf16 AMP would move.
         first = feeds[0]
-        params = arch.params_of_program(main, scope, cfg)
+        params = arch.params_of_program(main, t.scope, cfg)
+        phases["params_read"] = since_start()
         t0 = time.perf_counter()
         ref_loss = float(jax.jit(for_config(arch.lm_loss, cfg))(
             params, first["src"], first["label"], first["mask"]))
-        ref_logits = np.asarray(jax.jit(
-            for_config(arch.logits_at, cfg), static_argnums=3)(
-                params, jnp.asarray(first["src"][0]), seq - rows, rows))
-        one = {k: v[:1] for k, v in first.items()}
-        got_logits = np.asarray(exe.run(
-            forward, feed=one, fetch_list=[logits],
-            return_numpy=False)[0][0, seq - rows:], np.float32)
+        got_logits, ref_logits, choices = forward_against_reference(
+            t, params, {k: v[:1] for k, v in first.items()}, rows)
         logits_err = compare.logits_error(got_logits, ref_logits)
         log("reference float32 loss on batch 0: %.6f; the program's "
             "bf16-AMP forward on its first sequence, last %d rows of "
-            "logits: error %.3e of the largest logit (tolerance %.0e) "
-            "(%.1f s)" % (ref_loss, rows, logits_err,
-                          arch.TRAIN_LOGITS_RTOL,
+            "logits%s: error %.3e of the largest logit (tolerance %.0e) "
+            "(%.1f s)" % (ref_loss, rows, "" if choices is None else
+                          ", the reference handed the program's choices",
+                          logits_err, arch.TRAIN_LOGITS_RTOL,
                           time.perf_counter() - t0))
+        phases["compared"] = since_start()
 
         t0 = time.perf_counter()
         warm = [step(feeds[i % len(feeds)])
@@ -112,6 +198,10 @@ def run(cell, seed, seconds, devices, t_start, trace_dir, log):
         log("first loss %.6f against the reference: relative error "
             "%.2e (tolerance %.0e)" % (warm[0], loss_err,
                                        arch.LOSS_RTOL))
+
+        phases["warmed_up"] = since_start()
+        log("set-up, seconds after the process began: " + ", ".join(
+            "%s %.1f" % kv for kv in phases.items()))
 
         setup_s = time.perf_counter() - t_start
         trace_steps = int(mix["trace_steps"])
@@ -146,6 +236,8 @@ def run(cell, seed, seconds, devices, t_start, trace_dir, log):
             tracing.stop()
             traced_s = time.perf_counter() - t_tr
         losses = [float(np.asarray(x)) for x in losses]
+        counters = getattr(arch, "program_counters",
+                           lambda program, scope: {})(main, t.scope)
         fluid.amp.enable_amp(False)
     finite = all(math.isfinite(x) for x in warm + losses)
     log("window: %d steps in %.3f s; losses %.4f .. %.4f; all finite: "
@@ -154,9 +246,9 @@ def run(cell, seed, seconds, devices, t_start, trace_dir, log):
             and logits_err <= arch.TRAIN_LOGITS_RTOL,
             "attempted": len(losses),
             "failed": sum(not math.isfinite(x) for x in losses),
-            "setup_s": setup_s,
+            "setup_s": setup_s, "setup_phases": phases,
             "train": {"steps": len(losses), "window_s": window_s,
                       "tokens_per_step": batch * seq, "batch": batch,
                       "seq_len": seq, "dispatch_ms": dispatch_ms,
                       "traced_steps": trace_steps if trace_dir else 0,
-                      "traced_s": traced_s}}
+                      "traced_s": traced_s, "counters": counters}}

@@ -3,9 +3,14 @@
 ``run`` is what a driver returns plus the cell's files: ``requests``
 (every request submitted, with its ``segment``; times in seconds from
 the window's start; the metrics cover segment ``window``), ``engine``
-(counter deltas over the window), ``train``, ``trace`` (the reduced
-trace, in a traced run), ``config``, ``traffic``, ``peaks``, ``chips``,
-``setup_s``.
+(counter deltas over the window), ``train`` (the window's steps and
+times, and ``counters``: ``{name: list of numbers}``, what the program
+counted on the device over the run as the architecture's
+``program_counters`` read it once after the window; ``{}`` where it
+exports none), ``trace`` (the reduced trace, in a traced run),
+``config``, ``traffic``, ``peaks``, ``chips``, ``setup_s`` and
+``setup_phases`` (seconds from the process's start to ``entered``, the
+driver's ``run``, and to each set-up phase the driver stamps).
 """
 
 import numpy as np

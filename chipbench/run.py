@@ -113,8 +113,11 @@ def main(argv=None):
 
     driver = cells.load_driver(cell["traffic_file"]["driver"])
     trace_dir = os.path.join(CACHE_DIR, "trace") if args.trace else None
+    entered_s = time.perf_counter() - T_START
     run = driver.run(cell, args.seed, args.seconds, devices,
                      t_start=T_START, trace_dir=trace_dir, log=log)
+    # a driver that stamps no phases of its own still says when it began
+    run.setdefault("setup_phases", {"entered": entered_s})
     run.update(cell=cell, config=cell["config_file"],
                traffic=cell["traffic_file"], chips=cell["chips"],
                peaks=chip, seconds=args.seconds)

@@ -7,9 +7,8 @@ rows, and ``reduce_rows`` turns rows into what the metric readers use.
 The reduction works on rows, so the tests check it on a small recorded
 trace kept as JSON.
 
-A row: ``{"plane", "line", "name", "start" (s), "dur" (s)}`` plus, on
-device op rows, ``"kernel"`` (true for a Pallas kernel, a
-``tpu_custom_call``); an op's ``name`` is its HLO name alone.
+A row: ``{"plane", "line", "name", "start" (s), "dur" (s)}``; a device
+op's ``name`` is its HLO name alone.
 Device planes are ``/device:TPU:<n>``; their ``XLA Modules`` line has
 one event per run of a jitted program, ``XLA Ops`` one per HLO op.
 """
@@ -56,7 +55,6 @@ def load_rows(trace_dir):
                        "dur": ev.duration_ns * 1e-9}
                 if device and line.name == OP_LINE:
                     # the event's name is the op's whole HLO text
-                    row["kernel"] = "tpu_custom_call" in ev.name
                     row["name"] = ev.name.split(" = ")[0].lstrip("%")
                 rows.append(row)
     return rows
@@ -94,7 +92,6 @@ def reduce_rows(rows, chips=1, gap_floor_s=20e-6):
     ``busy_s``     union of device-op intervals, mean over the chips
     ``modules``    {program: [device seconds of each run]} on chip 0
     ``ops``        {"program/op": device seconds} on chip 0
-    ``kernel_s``   {program: device seconds in Pallas kernels} on chip 0
     ``device_ops`` the same as a list, longest first
     ``idle_gaps``  [[what the host was doing, seconds]], longest first:
                    chip 0's idle gaps over ``gap_floor_s``, each named
@@ -125,7 +122,7 @@ def reduce_rows(rows, chips=1, gap_floor_s=20e-6):
         modules.setdefault(module_name(m["name"]), []).append(m["dur"])
     ops0 = sorted((r for r in ops if r["plane"] == first),
                   key=lambda r: r["start"])
-    op_time, kernel_s, mi = {}, {}, 0
+    op_time, mi = {}, 0
     for r in ops0:                  # both lists are in start order
         while mi + 1 < len(mods) and mods[mi + 1]["start"] <= r["start"]:
             mi += 1
@@ -134,8 +131,6 @@ def reduce_rows(rows, chips=1, gap_floor_s=20e-6):
         module = module_name(mods[mi]["name"]) if inside else "?"
         key = module + "/" + op_name(r["name"])
         op_time[key] = op_time.get(key, 0.0) + r["dur"]
-        if r.get("kernel"):
-            kernel_s[module] = kernel_s.get(module, 0.0) + r["dur"]
 
     merged = _union((r["start"], r["start"] + r["dur"]) for r in ops0)
     host = [r for r in rows if r["plane"].startswith("/host:")
@@ -156,5 +151,5 @@ def reduce_rows(rows, chips=1, gap_floor_s=20e-6):
     by_time = lambda d: sorted(([k, v] for k, v in d.items()),
                                key=lambda kv: -kv[1])
     return {"window_s": t1 - t0, "busy_s": sum(busy) / len(busy),
-            "modules": modules, "ops": op_time, "kernel_s": kernel_s,
+            "modules": modules, "ops": op_time,
             "device_ops": by_time(op_time), "idle_gaps": by_time(gaps)}

@@ -219,13 +219,16 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
     cell = cells.load_cell(str(tmp_path), "chat_slow_s8", str(here))
     assert cell["config_file"]["slots"] == 8
     assert cell["traffic_file"]["rate_per_s"] == 1.0
-    assert [m["name"] for m in cell["per_layer"]] == ["requests_done"]
-    run = {"setup_s": 1.5, "requests": [
+    # ``setup_enter_s`` names no cells, so every cell reports it
+    assert [m["name"] for m in cell["per_layer"]] == ["setup_enter_s",
+                                                      "requests_done"]
+    run = {"setup_s": 1.5, "setup_phases": {"entered": 0.75}, "requests": [
         {"done": True, "due": 0.0, "first": 0.25, "retire": 1.25,
          "tokens": 11},
         {"done": False, "due": 1.0}]}
     got = cells.read_metrics(cell, "per_layer", run, str(here))
-    assert got == {"requests_done": {"value": 1.0, "unit": "1"}}
+    assert got == {"requests_done": {"value": 1.0, "unit": "1"},
+                   "setup_enter_s": {"value": 0.75, "unit": "s"}}
     e2e = cells.read_metrics(cell, "end_to_end", run, str(here))
     assert e2e["itl_mean_ms"]["value"] == pytest.approx(100.0)
     assert e2e["ttft_p90_ms"]["value"] == pytest.approx(250.0)

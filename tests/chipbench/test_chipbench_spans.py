@@ -216,6 +216,21 @@ def test_compile_seconds_union_split_and_cut(monkeypatch):
     assert spans.compile_seconds({"setup_s": 1.0}, (trace_,)) is None
 
 
+def test_setup_enter_reads_the_recorded_phases():
+    """``recorded_run_phases.json``: a warm run of the train cell on a
+    TPU v5e (PR 28). ``setup_enter_s`` is the first stamp, the driver's
+    phases follow in order, and the last is ``setup_s``."""
+    with open(os.path.join(HERE, "recorded_run_phases.json")) as f:
+        run = json.load(f)
+    assert "TPU v5e" in run["what"] and "PR 28" in run["what"]
+    assert cells.load_metric("setup_enter_s").read(run) == 14.3
+    at = list(run["setup_phases"].values())
+    assert list(run["setup_phases"])[0] == "entered" and at == sorted(at)
+    assert at[-1] == pytest.approx(run["setup_s"], abs=0.06)
+    # most of a warm set-up is the driver's own work, not the start
+    assert cells.load_metric("setup_s").read(run) > 2 * at[0]
+
+
 # -- the readers on the recorded window -------------------------------------
 
 @pytest.fixture(scope="module")
@@ -259,10 +274,11 @@ def test_recorded_window_is_what_it_says(recorded):
     assert kernels == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
     assert sum(o["kernel"] for o in window["ops"]) == 3 * 24 * 2
     # the kernels' three times are the time flash_roof_pct divides by
-    three = sum(spans.device_time(window, kind=k, kernel=True)
-                for k in kernels)
-    assert three == pytest.approx(run["trace"]["kernel_s"]["step"],
-                                  rel=1e-9)
+    _, seconds = spans.roof_pct(run, cells.load_metric(
+        "flash_roof_pct").KERNELS, 1.0)
+    assert set(seconds) == kernels
+    assert sum(seconds.values()) == pytest.approx(_sum(
+        fx, lambda text, op: "tpu_custom_call" in text), rel=1e-12)
     # and scoped plus unscoped is the sum of chip 0's op times
     scoped = sum(o["dur"] for o in window["ops"] if o["scope"])
     assert scoped + spans.device_time(window, scope=None) == \
@@ -273,6 +289,7 @@ def test_recorded_window_is_what_it_says(recorded):
 # (the sums of durations are in test_readers_against_plain_sums, by
 # plain string tests; the literals here were computed once from them):
 HAND = {
+    "flash_roof_pct": 17.926660511449594,    # PR 23's reader read this
     "flash_fwd_roof_pct": 19.24216802923744,
     "flash_bwd_roof_pct": 17.449480947624618,
     "matmul_roof_pct": 91.7969420232566,     # the fixture keeps only
@@ -289,16 +306,42 @@ HAND = {
     "flash_fwd_roof_pct", "flash_bwd_roof_pct", "matmul_roof_pct",
     "xent_dev_share_pct", "optimizer_dev_share_pct",
     "unscoped_dev_share_pct", "exe_self_ms.train",
-    "setup_trace_lower_s.train", "setup_compile_s.train"])
+    "setup_trace_lower_s.train", "setup_compile_s.train",
+    "flash_roof_pct"])
 def test_reader_on_the_recorded_window(recorded, monkeypatch, name):
     fx, run = recorded
     monkeypatch.setattr(sys.modules["__main__"], "T_START",
                         fx["t_start"], raising=False)
     value = cells.load_metric(name).read(run)
     assert value == pytest.approx(HAND[name], rel=1e-6)
-    # the flash reader of PR 23 still finds its kernels by their
-    # tpu_custom_call text after the renaming
-    assert cells.load_metric("flash_roof_pct").read(run) > 0
+
+
+@pytest.mark.parametrize("change", ["as recorded", "another kernel",
+                                    "fused backward"])
+def test_flash_reader_finds_its_kernels_by_name(recorded, change):
+    """``flash_roof_pct`` reads the number PR 23's reader read (every
+    ``tpu_custom_call`` of the step's program) to 1e-12; one more
+    Pallas kernel of another name in the step (a grouped matmul) is not
+    flash time, which PR 23's reader would have counted; and the
+    backward's rows renamed to one ``flash_bwd`` read the same."""
+    fx, run = recorded
+    rows = [list(row) for row in fx["ops"]]
+    if change == "another kernel":
+        text, start, dur, op_name = next(
+            row for row in rows if row[0].startswith("%flash_fwd"))
+        rows.append([text.replace("%flash_fwd.", "%grouped_matmul."),
+                     start + dur, 10 * dur, op_name.replace(
+                         "flash_fwd", "grouped_matmul")])
+    if change == "fused backward":
+        for row in rows:
+            row[0] = re.sub(r"^%flash_bwd_(dq|dkv)\.", "%flash_bwd.",
+                            row[0])
+    ops = [spans.device_op(*row) for row in rows]
+    assert sum(o["kernel"] for o in ops) == 3 * 24 * 2 + (
+        change == "another kernel")
+    changed = dict(run, spans=dict(run["spans"], ops=ops))
+    assert cells.load_metric("flash_roof_pct").read(changed) == \
+        pytest.approx(HAND["flash_roof_pct"], rel=1e-12)
 
 
 def test_readers_against_plain_sums(recorded):
@@ -407,7 +450,8 @@ def test_roof_pct_sums_what_it_finds_and_is_none_for_nothing(recorded):
     "xent_dev_share_pct", "optimizer_dev_share_pct",
     "unscoped_dev_share_pct", "exe_self_ms.train",
     "setup_trace_lower_s.train", "setup_compile_s.train",
-    "pool_move_dev_share_pct", "engine_self_ms.serve"])
+    "pool_move_dev_share_pct", "engine_self_ms.serve", "flash_roof_pct",
+    "setup_enter_s"])
 def test_reader_leaves_its_metric_out_where_there_is_nothing(name):
     """A run that was not traced, and a traced run of a program that
     has no such span, scope, kernel name or log (the parent of PR 24):
