@@ -2,7 +2,8 @@
 
 Makes the distributed subsystem reachable from fluid-style model code:
 
-    attn = layers.sequence_parallel_attention(q, k, v, causal=True)
+    attn = layers.sequence_parallel_attention(q, k, v, causal=True,
+                                              n_head=H)   # q/k/v [B, T, H*dk]
     out, aux = layers.sparse_moe(x, num_experts=8, d_inner=2048)
     y = layers.pipelined_decoder_stack(x, n_layer=8, n_head=8, d_inner=2048)
 
@@ -22,15 +23,26 @@ __all__ = ["sequence_parallel_attention", "sparse_moe",
 
 
 def sequence_parallel_attention(q, k, v, causal=False, variant="ring",
-                                scale=0.0, name=None):
-    """q/k/v: [B, H, T, dk] variables (T sharded on the sp mesh axis under
-    ParallelExecutor). Returns [B, H, T, dk]."""
+                                scale=0.0, n_head=None, name=None):
+    """q/k/v: [B, T, H*dk] variables with `n_head` = H, the layout a
+    projection (`fc`) leaves them in, which the flash kernels read and
+    write as it is: no reshape or transpose on either side. Without
+    `n_head`, [B, H, T, dk] variables (the lowering then transposes
+    into the kernels' layout and back). T is sharded on the sp mesh axis
+    under ParallelExecutor. Returns a variable of q's shape."""
+    if (n_head is None) != (len(q.shape) == 4):
+        raise ValueError(
+            "sequence_parallel_attention: q of shape %s wants %s"
+            % (tuple(q.shape), "no n_head" if len(q.shape) == 4
+               else "n_head (q, k, v [B, T, H*dk])"))
     helper = LayerHelper("sp_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    attrs = {"causal": causal, "variant": variant, "scale": scale}
+    if n_head is not None:
+        attrs["n_head"] = int(n_head)
     helper.append_op(
         type="sp_attention", inputs={"Q": [q], "K": [k], "V": [v]},
-        outputs={"Out": [out]},
-        attrs={"causal": causal, "variant": variant, "scale": scale})
+        outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

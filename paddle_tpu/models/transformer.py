@@ -37,7 +37,11 @@ def multi_head_attention(queries, keys, values, attn_bias, d_key, d_value,
     `causal=True` with no bias and no attention dropout takes the FUSED
     path: the sp_attention op, whose local lowering is the Pallas flash
     kernel on TPU (ops/flash_attention.py) — no [T, T] score tensor in
-    HBM. Arbitrary biases keep the composed matmul+softmax form."""
+    HBM. It takes the three projections [B, T, H*dk] as `fc` leaves them
+    and gives the output projection its input the same way: the kernels
+    address a head by a block of the last dimension, so no reshape or
+    transpose moves a head. Arbitrary biases keep the composed
+    matmul+softmax form on [B, H, T, dk]."""
     q = layers.fc(queries, d_key * n_head, num_flatten_dims=2,
                   bias_attr=False)
     k = layers.fc(keys, d_key * n_head, num_flatten_dims=2, bias_attr=False)
@@ -49,13 +53,13 @@ def multi_head_attention(queries, keys, values, attn_bias, d_key, d_value,
         x = layers.reshape(x, [b, t, n_head, d])
         return layers.transpose(x, perm=[0, 2, 1, 3])     # [B, H, T, d]
 
-    q = split_heads(q, d_key)
-    k = split_heads(k, d_key)
-    v = split_heads(v, d_value)
-
     if causal and attn_bias is None and not dropout_rate:
-        ctx = layers.sequence_parallel_attention(q, k, v, causal=True)
+        ctx = layers.sequence_parallel_attention(q, k, v, causal=True,
+                                                 n_head=n_head)
     else:
+        q = split_heads(q, d_key)
+        k = split_heads(k, d_key)
+        v = split_heads(v, d_value)
         if causal:
             # fused-path preconditions not met (dropout/bias): the
             # composed form must still mask the future. The T^2 constant
@@ -86,9 +90,9 @@ def multi_head_attention(queries, keys, values, attn_bias, d_key, d_value,
         if dropout_rate:
             weights = layers.dropout(weights, dropout_prob=dropout_rate)
         ctx = layers.matmul(weights, v)                   # [B, H, Tq, dv]
-    ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-    b, t = ctx.shape[0], ctx.shape[1]
-    ctx = layers.reshape(ctx, [b, t, n_head * d_value])
+        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
+        b, t = ctx.shape[0], ctx.shape[1]
+        ctx = layers.reshape(ctx, [b, t, n_head * d_value])
     return layers.fc(ctx, d_model, num_flatten_dims=2, bias_attr=False)
 
 
@@ -381,19 +385,12 @@ def _parallel_decoder_layer(x, n_head, d_key, d_value, d_model, d_inner,
             shard(name, *col_spec)
         return out
 
-    b, t = x.shape[0], x.shape[1]
     q = named_fc(x, d_key * n_head, "q", (None, "tp"))
     k = named_fc(x, d_key * n_head, "k", (None, "tp"))
     v = named_fc(x, d_value * n_head, "v", (None, "tp"))
 
-    def heads(z, d):
-        z = layers.reshape(z, [b, t, n_head, d])
-        return layers.transpose(z, perm=[0, 2, 1, 3])
-
-    attn = layers.sequence_parallel_attention(
-        heads(q, d_key), heads(k, d_key), heads(v, d_value), causal=True)
-    attn = layers.transpose(attn, perm=[0, 2, 1, 3])
-    attn = layers.reshape(attn, [b, t, n_head * d_value])
+    attn = layers.sequence_parallel_attention(q, k, v, causal=True,
+                                              n_head=n_head)
     o = named_fc(attn, d_model, "o", ("tp", None))
     x = layers.layer_norm(layers.elementwise_add(x, o),
                           begin_norm_axis=len(x.shape) - 1)

@@ -17,9 +17,10 @@ broadcasted_iota).
 
 Two sizes (PR 25). The MAJOR block is what a grid step holds in VMEM
 and the DMA moves: all of T where a [T, D] operand is small there
-(_auto_block: bf16 up to T 2048 at D <= 128, one grid step a head and
-nothing carried between steps), else the largest divisor of T up to
-1024, streamed with the running statistics in scratch. A major block
+(_auto_block: bf16 up to T 2048 at D <= 128, one grid step a block of
+heads, nothing carried between steps and no scratch: each panel
+finishes its own rows), else the largest divisor of T up to 1024,
+streamed with the running statistics in scratch. A major block
 below the diagonal is one batch of work; one ON the diagonal is cut, at
 trace time, into PANELS of _TILE rows whose keys stop at the diagonal,
 so that tiles above it are never computed (36 of 64 tiles of 256 at
@@ -31,15 +32,38 @@ every dot accumulates in float32; the running max, the denominator,
 lse, delta, exp and all accumulators are float32. `scale` is folded
 into q (into k for dk/dv) once a panel.
 
-Shapes: q, k, v [B, H, T, D]; T must be a multiple of the block size
-(the sp bucketing guarantees powers of two); D is the head dim (any
-multiple of 8 — VMEM pads it to 128 lanes). The row statistics (lse,
-delta) travel as [BH, 1, T]: one float a row, the rows along the
-lanes, 1/128 of what a lane-broadcast [BH, T, 128] held.
+Shapes (PR 29): q, k, v and the output are [B, T, H*D], the layout a
+projection leaves them in, with a static n_head. A grid step's block
+is (1, rows, W) of that last dimension, W lanes holding g = W / D whole
+heads (heads_per_block: from D and H alone): one head where D is a
+multiple of 128; 128 / D heads where D divides 128 and that many
+divide H (two heads of 64 to a 128-lane block); else all of H*D where
+a [rows, H*D] operand fits a block. Nothing is transposed or reshaped
+on the way in or out. The g heads of a block take turns in a loop the
+kernel runs (_each_head) and are separated without moving lanes: head
+a's q (k and v in dk/dv) is the block with the other heads' lanes
+zeroed, so s_a contracts all W lanes and the foreign ones add exact
+zeros; every product that comes out W lanes wide keeps head a's D by
+a lane select where it is stored (_only, _put). A [T, 64] operand
+padded to 128 lanes in VMEM before, so two heads take the bytes one
+took, and a contraction or an output of 64 half fills the MXU: the
+passes are those of one head at a time. T must be a multiple of the block size
+(the sp bucketing guarantees powers of two); D any multiple of 8. The
+row statistics (lse, delta) are float32, one float a row and head, the
+rows along the lanes: [B*H, 1, T], g heads' rows to a grid step.
+`delta` = rowsum(dy * o) over each head's lanes is made by flash_bwd_dq
+from the rows of dy it holds and o as one more operand, and handed to
+flash_bwd_dkv as a row statistic: XLA, asked for [B*H, 1, T] from
+[B, T, H*D] operands, first copies both whole into a T-minor layout
+(every formulation compiled for a described v5e did; PR 29).
 
-Dispatch: `flash_attention(q, k, v, causal, scale)` uses the kernel on
-TPU and the dense jnp math elsewhere (CPU tests exercise the kernel via
-interpret mode separately).
+Dispatch: `flash_bthd(q, k, v, n_head, causal, scale)` uses the kernel
+on TPU and the dense jnp math elsewhere (CPU tests exercise the kernel
+via interpret mode separately); `flash_attention` / `flash_attention_lse`
+take [B, H, T, D] and are wrappers round it that transpose in and out:
+such a caller (parallel/ring.py) now pays the transposes the model used
+to pay. Each dispatch counts itself at trace time in
+`ptpu_flash_lowerings_total{path, entry, heads_per_block}`.
 """
 
 import functools
@@ -47,6 +71,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..monitor import metrics as _metrics
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -66,6 +92,17 @@ _LANES = 128
 # alike. Straight-line panels give the scheduler the whole block at once.
 # Panels of 128 to 512 rows are within 2% of one another (256: least
 # masked work without the smallest matmuls); D 128 prefers the same.
+# PR 29, the same call from q/k/v/dy [4, 2048, 1024] as the projections
+# leave them; last column: every device op round the kernels (my chip
+# runs, PR 29):
+#   the parent: XLA splits and merges the heads       0.536  0.692  0.889  0.491
+#   two heads to a block, unrolled at trace time      0.495  0.627  0.810  0
+#   this file: two heads to a block, taking turns     0.518  0.637  0.812  0
+# No scratch where nothing is carried, and a panel's dead branch (below
+# the diagonal, in a grid of one block) not compiled: faster than the
+# parent's kernels although dq now also makes delta. Unrolled, the
+# scheduler overlaps the two heads (1.8% faster) for 2.6 times the
+# equations to trace and lower: 8 s more in every set-up.
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 _AUTO_BLOCK = 1024              # streamed major block, rows
@@ -131,6 +168,25 @@ def _causal(s, off, q_axis):
     return jnp.where(kj - qi <= off, s, _NEG_INF)
 
 
+def _when(cond):
+    """pl.when, settled at trace time where the condition is."""
+    if isinstance(cond, bool):
+        return lambda f: f() if cond else None
+    return pl.when(cond)
+
+
+def _block_ids(nq, nk, by_keys=False):
+    """(i, j) of the grid step's q block and key block: program ids, or
+    the integer 0 on an axis of one block, so that _walk settles at
+    trace time which side of the diagonal the block is on and the other
+    side's panels are neither traced nor compiled."""
+    qa, ka = (2, 1) if by_keys else (1, 2)
+    # hoisted by the kernels: program_id inside a pl.when branch does not
+    # interpret/lower on all paths
+    return (pl.program_id(qa) if nq > 1 else 0,
+            pl.program_id(ka) if nk > 1 else 0)
+
+
 def _walk(panel, i, j, causal, block_q, block_k, tile, by_keys=False):
     """Run `panel(mine, segments)` over the major block (i, j).
 
@@ -157,10 +213,10 @@ def _walk(panel, i, j, causal, block_q, block_k, tile, by_keys=False):
     if not causal:
         return whole()
     first_q, first_k = i * block_q, j * block_k
-    below = first_q >= first_k + block_k - 1
-    pl.when(below)(whole)
+    last_k = first_k + block_k - 1
+    _when(first_q >= last_k)(whole)
 
-    @pl.when(jnp.logical_not(below) & (first_q + block_q - 1 >= first_k))
+    @_when((first_q < last_k) & (first_q + block_q - 1 >= first_k))
     def _crossed():
         if block_q != block_k or tile >= block_q:
             return panel(slice(0, nq), [(slice(0, nk), first_q - first_k)])
@@ -173,274 +229,394 @@ def _walk(panel, i, j, causal, block_q, block_k, tile, by_keys=False):
 
 
 # --------------------------------------------------------------------------
-# forward kernel: grid (BH, nQ, nK); scratch (m, l, acc) carried across the
-# (sequential, innermost) nK dimension
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
-                *, causal, scale, block_q, block_k, tile, nk):
-    i = pl.program_id(1)   # hoisted: program_id inside a pl.when branch
-    j = pl.program_id(2)   # does not interpret/lower on all paths
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    def panel(rows, segments):
-        # ONE streaming-softmax step of these rows over all their
-        # segments: one running-max update however the keys are cut
-        q = q_ref[0, rows, :] * scale               # [tq, D], once a panel
-        scores = []
-        for cols, off in segments:
-            s = _dot(q, k_ref[0, cols, :], _NT)     # [tq, tk]
-            scores.append(s if off is None else _causal(s, off, 0))
-        maxes = [jnp.max(s, axis=1, keepdims=True) for s in scores]
-        if nk == 1:
-            # the only key block: nothing carried in, nothing to rescale
-            m_new = functools.reduce(jnp.maximum, maxes)
-            l = acc = 0.0
-        else:
-            m_prev = m_s[rows]                       # [tq, 1]
-            m_new = functools.reduce(jnp.maximum, maxes, m_prev)
-            alpha = jnp.exp(m_prev - m_new)
-            l = alpha * l_s[rows]
-            acc = alpha * acc_s[rows]
-        for s, (cols, _) in zip(scores, segments):
-            p = jnp.exp(s - m_new)
-            v = v_ref[0, cols, :]
-            l = l + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc + _dot(p.astype(v.dtype), v, _NN)
-        m_s[rows] = m_new
-        l_s[rows] = l
-        acc_s[rows] = acc
-
-    _walk(panel, i, j, causal, block_q, block_k, tile)
-
-    @pl.when(j == nk - 1)
-    def _final():
-        l = jnp.maximum(l_s[:], 1e-30)
-        o_ref[0] = (acc_s[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_s[:] + jnp.log(l)).T        # [Bq, 1] -> row [1, Bq]
+# g heads to a block. Operands stay [B, T, H*D]; a block is W = g * D of
+# the last dimension's lanes. Head a's operand is the block with the
+# other heads' lanes zeroed (a contraction over all W lanes then adds
+# exact zeros), and head a's part of a W-wide product is kept by a lane
+# select as it is stored. The heads of a block take turns in a loop the
+# KERNEL runs: unrolled at trace time, two heads' panels were twice the
+# equations to trace and lower in every set-up (8 s more of a 41 s warm
+# start, my chip run, PR 29) and twice the VMEM. g == 1 (D a multiple of
+# 128) loops over nothing and selects nothing.
+def heads_per_block(n_head, d):
+    """Heads to a block of the last dimension, from D and H alone."""
+    if d % _LANES == 0:
+        return 1
+    if _LANES % d == 0 and n_head % (_LANES // d) == 0:
+        return _LANES // d
+    return n_head                   # all of H*D as one block
 
 
-def _rows_spec(block, d, axis):
-    """Block spec of a [BH, T, D] operand: `block` rows a grid step, the
-    rows following grid axis `axis` (dk/dv's grid puts the keys first)."""
-    return pl.BlockSpec((1, block, d), lambda *g: (g[0], g[axis], 0))
+def _each_head(g, head):
+    """head(a) for each head a of the block, one after the other."""
+    if g == 1:
+        return head(0)
+
+    def turn(a, carry):
+        head(a)
+        return carry
+    lax.fori_loop(0, g, turn, 0)
 
 
-def _stat_spec(block, axis):
-    """Block spec of a row statistic [BH, 1, T]: one float a query row,
-    the rows along the lanes."""
-    return pl.BlockSpec((1, 1, block), lambda *g: (g[0], 0, g[axis]))
+def _lanes(shape, a, d, g):
+    """Where head `a`'s lanes are in a [rows, g * d] tile; None where the
+    block is one head."""
+    if g == 1:
+        return None
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= a * d) & (lane < (a + 1) * d)
+
+
+def _only(x, mine):
+    """x with the other heads' lanes zeroed."""
+    return x if mine is None else jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _put(ref, idx, x, mine):
+    """Store x's lanes of this head, the other heads' as they are."""
+    ref[idx] = x if mine is None else jnp.where(mine, x, ref[idx])
+
+
+# --------------------------------------------------------------------------
+# forward kernel: grid (B * H / g, nQ, nK); scratch (m, l, acc) carried
+# across the (sequential, innermost) nK dimension. One key block carries
+# nothing: each panel finishes its own rows, and there is no scratch.
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                causal, scale, block_q, block_k, tile, nq, nk, d, g):
+    i, j = _block_ids(nq, nk)
+    if nk > 1:
+        m_s, l_s, acc_s = scratch
+
+        @pl.when(j == 0)
+        def _init():
+            m_s[:] = jnp.full_like(m_s, _NEG_INF)
+            l_s[:] = jnp.zeros_like(l_s)
+            acc_s[:] = jnp.zeros_like(acc_s)
+
+    def head(a):
+        def finish(rows, mine, m, l, acc):
+            l = jnp.maximum(l, 1e-30)
+            _put(o_ref, (0, rows, slice(None)),
+                 (acc / l).astype(o_ref.dtype), mine)
+            lse_ref[a, :, rows] = (m + jnp.log(l)).T    # [tq, 1] -> row
+
+        def panel(rows, segments):
+            # ONE streaming-softmax step of these rows over all their
+            # segments: one running-max update however the keys are cut
+            q = q_ref[0, rows, :] * scale           # [tq, W], once a panel
+            mine = _lanes(q.shape, a, d, g)
+            q = _only(q, mine)
+            scores = []
+            for cols, off in segments:
+                s = _dot(q, k_ref[0, cols, :], _NT)     # [tq, tk]
+                scores.append(s if off is None else _causal(s, off, 0))
+            maxes = [jnp.max(s, axis=1, keepdims=True) for s in scores]
+            if nk == 1:
+                # the only key block: nothing carried in, nothing to
+                # rescale
+                m_new = functools.reduce(jnp.maximum, maxes)
+                l = acc = 0.0
+            else:
+                m_prev = m_s[a, rows]                # [tq, 1]
+                m_new = functools.reduce(jnp.maximum, maxes, m_prev)
+                alpha = jnp.exp(m_prev - m_new)
+                l = alpha * l_s[a, rows]
+                acc = alpha * acc_s[rows]
+            for s, (cols, _) in zip(scores, segments):
+                p = jnp.exp(s - m_new)
+                v = v_ref[0, cols, :]
+                l = l + jnp.sum(p, axis=1, keepdims=True)
+                acc = acc + _dot(p.astype(v.dtype), v, _NN)  # [tq, W]
+            if nk == 1:
+                return finish(rows, mine, m_new, l, acc)
+            m_s[a, rows] = m_new
+            l_s[a, rows] = l
+            _put(acc_s, rows, acc, mine)
+
+        _walk(panel, i, j, causal, block_q, block_k, tile)
+
+        if nk > 1:
+            @pl.when(j == nk - 1)
+            def _final():
+                acc = acc_s[:]
+                finish(slice(None), _lanes(acc.shape, a, d, g), m_s[a],
+                       l_s[a], acc)
+
+    _each_head(g, head)
+
+
+def _specs(n_head, g, d):
+    """Block specs of a grid (B * H / g, ., .): `rows(block, axis)` for a
+    [B, T, H*D] operand, `block` rows a grid step following grid axis
+    `axis` (dk/dv's grid puts the keys first) and the g heads of the
+    step on the last dimension; `stat(block, axis)` for a row statistic
+    [B*H, 1, T], one float a query row and head, the rows along the
+    lanes."""
+    hb = n_head // g
+
+    def rows(block, axis):
+        return pl.BlockSpec((1, block, g * d),
+                            lambda *s: (s[0] // hb, s[axis], s[0] % hb))
+
+    def stat(block, axis):
+        return pl.BlockSpec((g, 1, block), lambda *s: (s[0], 0, s[axis]))
+
+    return rows, stat
 
 
 # jitted so that a stack of layers traces and lowers each kernel ONCE:
 # the panels make a kernel's body some hundreds of equations, and without
 # the jit's cache every call site pays for them again (24 layers: 29 s
 # more trace and lowering in the benchmark's set-up; my chip run, PR 25)
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
-    b, h, t, d = q.shape
-    bh = b * h
-    q3 = q.reshape(bh, t, d)
-    k3 = k.reshape(bh, t, d)
-    v3 = v.reshape(bh, t, d)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
+                interpret):
+    b, t, hd = q.shape
+    d = hd // n_head
+    g = heads_per_block(n_head, d)
     bq = min(block_q, t)
     bk = min(block_k, t)
     nq, nk = t // bq, t // bk
+    rows, stat = _specs(n_head, g, d)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
                           block_q=bq, block_k=bk,
-                          tile=_tile(bq, _TILE), nk=nk),
-        grid=(bh, nq, nk),
-        in_specs=[_rows_spec(bq, d, 1), _rows_spec(bk, d, 2),
-                  _rows_spec(bk, d, 2)],
-        out_specs=[_rows_spec(bq, d, 1), _stat_spec(bq, 1)],
+                          tile=_tile(bq, _TILE), nq=nq, nk=nk, d=d, g=g),
+        grid=(b * n_head // g, nq, nk),
+        in_specs=[rows(bq, 1), rows(bk, 2), rows(bk, 2)],
+        out_specs=[rows(bq, 1), stat(bq, 1)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            # one float a row, the rows along the lanes: what the
-            # backward reads back as it is, and 1/128 of a lane tile a row
-            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+            # one float a row and head, the rows along the lanes: what
+            # the backward reads back as it is, and 1/128 of a lane tile
+            # a row
+            jax.ShapeDtypeStruct((b * n_head, 1, t), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
+            pltpu.VMEM((g, bq, 1), jnp.float32),
+            pltpu.VMEM((g, bq, 1), jnp.float32),
+            pltpu.VMEM((bq, g * d), jnp.float32),
+        ] if nk > 1 else [],
         interpret=interpret,
         name="flash_fwd",
-    )(q3, k3, v3)
-    return out.reshape(b, h, t, d), lse.reshape(b, h, t)
+    )(q, k, v)
+    return out, lse.reshape(b, n_head, t)
 
 
 # --------------------------------------------------------------------------
-# backward kernels. delta = rowsum(dy * o) is computed outside; p is
-# recomputed per tile from the saved LSE. Both row statistics arrive as
-# rows [1, Bq]: dk/dv, which holds the scores transposed, broadcasts them
-# down the sublanes as they are; dq turns them into columns once a q block.
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref, dq_ref,
-                   acc_s, lse_s, delta_s,
-                   *, causal, scale, block_q, block_k, tile, nk):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+# backward kernels. p is recomputed per tile from the saved LSE. dq also
+# makes delta = rowsum(dy * o) over each head's lanes [- dlse], from the
+# rows of dy and o it holds anyway (float32, as XLA summed it: but XLA
+# wants T minor for a [B*H, 1, T] result and copies dy and o whole into
+# that layout first), and hands it to dk/dv as the row statistic lse is.
+# Both statistics are rows [1, Bq] a head: dk/dv, which holds the scores
+# transposed, broadcasts them down the sublanes as they are; dq turns
+# lse into a column once a q block.
+def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k, tile, nq, nk, d,
+                   g, has_dlse):
+    q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
+    dlse_ref = refs[6] if has_dlse else None
+    dq_ref, delta_ref = refs[6 + has_dlse:8 + has_dlse]
+    i, j = _block_ids(nq, nk)
+    if nk > 1:
+        acc_s, lse_s, delta_s = refs[8 + has_dlse:]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_s[:] = jnp.zeros_like(acc_s)
-        lse_s[:] = lse_ref[0].T                      # [1, Bq] -> [Bq, 1]
-        delta_s[:] = delta_ref[0].T
+        @pl.when(j == 0)
+        def _init():
+            acc_s[:] = jnp.zeros_like(acc_s)
 
-    def panel(rows, segments):
-        q = q_ref[0, rows, :] * scale
-        dy = dy_ref[0, rows, :]
-        lse = lse_s[rows]
-        delta = delta_s[rows]
-        acc = acc_s[rows]
-        for cols, off in segments:
-            kk = k_ref[0, cols, :]
-            s = _dot(q, kk, _NT)                     # [tq, tk]
-            if off is not None:
-                s = _causal(s, off, 0)
-            p = jnp.exp(s - lse)
-            dp = _dot(dy, v_ref[0, cols, :], _NT)
-            ds = p * (dp - delta)
-            acc = acc + _dot(ds.astype(kk.dtype), kk, _NN)
-        acc_s[rows] = acc
+    def head(a):
+        def stats(rows, dy):
+            """Columns [tq, 1] of the head's lse and delta (dy: its lanes
+            alone); delta's row goes out."""
+            delta = jnp.sum(dy.astype(jnp.float32)
+                            * o_ref[0, rows, :].astype(jnp.float32),
+                            axis=1, keepdims=True)
+            if has_dlse:
+                # lse output cotangent: d lse_i / d s_ij = p_ij, so it
+                # folds into the shared ds = p * (dp - delta') term with
+                # delta' = delta - dlse
+                delta = delta - dlse_ref[a, :, rows].T
+            delta_ref[a, :, rows] = delta.T          # [tq, 1] -> [1, tq]
+            return lse_ref[a, :, rows].T, delta
 
-    _walk(panel, i, j, causal, block_q, block_k, tile)
+        if nk > 1:
+            @pl.when(j == 0)
+            def _columns():                          # once a q block
+                dy = dy_ref[0]
+                lse_s[a], delta_s[a] = stats(
+                    slice(None), _only(dy, _lanes(dy.shape, a, d, g)))
 
-    @pl.when(j == nk - 1)
-    def _final():
-        dq_ref[0] = (acc_s[:] * scale).astype(dq_ref.dtype)
+        def panel(rows, segments):
+            q = q_ref[0, rows, :] * scale
+            mine = _lanes(q.shape, a, d, g)
+            q, dy = _only(q, mine), _only(dy_ref[0, rows, :], mine)
+            if nk == 1:     # the rows' only visit
+                lse, delta = stats(rows, dy)
+            else:
+                lse, delta = lse_s[a, rows], delta_s[a, rows]
+            acc = 0.0
+            for cols, off in segments:
+                kk = k_ref[0, cols, :]
+                s = _dot(q, kk, _NT)                 # [tq, tk]
+                if off is not None:
+                    s = _causal(s, off, 0)
+                p = jnp.exp(s - lse)
+                dp = _dot(dy, v_ref[0, cols, :], _NT)
+                ds = p * (dp - delta)
+                acc = acc + _dot(ds.astype(kk.dtype), kk, _NN)  # [tq, W]
+            if nk == 1:
+                _put(dq_ref, (0, rows, slice(None)),
+                     (acc * scale).astype(dq_ref.dtype), mine)
+            else:
+                acc_s[rows] = acc_s[rows] + _only(acc, mine)
+
+        _walk(panel, i, j, causal, block_q, block_k, tile)
+
+    _each_head(g, head)
+
+    if nk > 1:
+        @pl.when(j == nk - 1)
+        def _final():
+            dq_ref[0] = (acc_s[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_s, dv_s,
-                    *, causal, scale, block_q, block_k, tile, nq):
-    jj = pl.program_id(1)
-    i = pl.program_id(2)   # q blocks iterate innermost here
+                    dk_ref, dv_ref, *scratch, causal, scale, block_q,
+                    block_k, tile, nq, nk, d, g):
+    i, jj = _block_ids(nq, nk, by_keys=True)    # q blocks innermost here
+    if nq > 1:
+        dk_s, dv_s = scratch
 
-    @pl.when(i == 0)
-    def _init():
-        dk_s[:] = jnp.zeros_like(dk_s)
-        dv_s[:] = jnp.zeros_like(dv_s)
+        @pl.when(i == 0)
+        def _init():
+            dk_s[:] = jnp.zeros_like(dk_s)
+            dv_s[:] = jnp.zeros_like(dv_s)
 
-    def panel(cols, segments):
-        # the scores TRANSPOSED, [tk, tq]: k q^T contracts the last dim
-        # of both, and p^T, ds^T come out as dv = p^T dy, dk = ds^T q
-        # want them, with no contraction over a tile's first dim; the
-        # row statistics broadcast down the sublanes as they arrive
-        kk = k_ref[0, cols, :] * scale               # [tk, D], once a panel
-        v = v_ref[0, cols, :]
-        dk, dv = dk_s[cols], dv_s[cols]
-        for rows, off in segments:
-            q = q_ref[0, rows, :]
-            dy = dy_ref[0, rows, :]
-            st = _dot(kk, q, _NT)                    # [tk, tq]
-            if off is not None:
-                st = _causal(st, off, 1)
-            pt = jnp.exp(st - lse_ref[0, :, rows])   # row [1, tq]
-            dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
-            dpt = _dot(v, dy, _NT)
-            dst = pt * (dpt - delta_ref[0, :, rows])
-            dk = dk + _dot(dst.astype(q.dtype), q, _NN)
-        dk_s[cols] = dk
-        dv_s[cols] = dv
+    def head(a):
+        def panel(cols, segments):
+            # the scores TRANSPOSED, [tk, tq]: k q^T contracts the last
+            # dim of both, and p^T, ds^T come out as dv = p^T dy,
+            # dk = ds^T q want them, with no contraction over a tile's
+            # first dim; the row statistics broadcast down the sublanes
+            # as they arrive
+            kk = k_ref[0, cols, :] * scale           # [tk, W], once a panel
+            mine = _lanes(kk.shape, a, d, g)
+            kk, v = _only(kk, mine), _only(v_ref[0, cols, :], mine)
+            dk = dv = 0.0
+            for rows, off in segments:
+                q = q_ref[0, rows, :]
+                dy = dy_ref[0, rows, :]
+                st = _dot(kk, q, _NT)                # [tk, tq]
+                if off is not None:
+                    st = _causal(st, off, 1)
+                pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
+                dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
+                dpt = _dot(v, dy, _NT)
+                dst = pt * (dpt - delta_ref[a, :, rows])
+                dk = dk + _dot(dst.astype(q.dtype), q, _NN)
+            if nq == 1:
+                whole = (0, cols, slice(None))
+                _put(dk_ref, whole, (dk * scale).astype(dk_ref.dtype), mine)
+                _put(dv_ref, whole, dv.astype(dv_ref.dtype), mine)
+            else:
+                dk_s[cols] = dk_s[cols] + _only(dk, mine)
+                dv_s[cols] = dv_s[cols] + _only(dv, mine)
 
-    _walk(panel, i, jj, causal, block_q, block_k, tile, by_keys=True)
+        _walk(panel, i, jj, causal, block_q, block_k, tile, by_keys=True)
 
-    @pl.when(i == nq - 1)
-    def _final():
-        dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+    _each_head(g, head)
+
+    if nq > 1:
+        @pl.when(i == nq - 1)
+        def _final():
+            dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
-def _bwd_pallas(res, dy, causal, scale, block_q, block_k, interpret,
-                dlse=None):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
+def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
+                interpret, dlse=None):
     q, k, v, o, lse = res
-    b, h, t, d = q.shape
-    bh = b * h
+    b, t, hd = q.shape
+    d = hd // n_head
+    g = heads_per_block(n_head, d)
+    w = g * d
     bq = min(block_q, t)
     bk = min(block_k, t)
-    # VMEM guard: the bwd kernels hold six [block, d] operands, double
-    # buffered, plus float32 accumulators of the same shape; with d > 128
+    # VMEM guard: the bwd kernels hold six [block, w] operands, double
+    # buffered, plus float32 accumulators of the same shape; with w > 128
     # at 1024-row blocks that passes the 16 MB scoped-vmem limit. Clamp the
     # BACKWARD blocks only. The clamp must keep dividing T (a non-divisor
     # block would silently drop query rows from dq/dk/dv): shrink to the
     # largest divisor of the incoming block, which also divides T.
-    if d > 128:
+    if w > 128:
         bq = _largest_divisor(bq, 512)
         bk = _largest_divisor(bk, 512)
     nq, nk = t // bq, t // bk
     tile = _tile(bq, _TILE)
-    delta = jnp.sum(dy.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                                  # [B,H,T]
+    stats = [lse.reshape(b * n_head, 1, t)]
     if dlse is not None:
-        # lse output cotangent: d lse_i / d s_ij = p_ij, so it folds into
-        # the shared ds = p * (dp - delta') term with delta' = delta - dlse
-        delta = delta - dlse.astype(jnp.float32)
-    q3, k3, v3 = (a.reshape(bh, t, d) for a in (q, k, v))
-    dy3 = dy.reshape(bh, t, d)
-    lse3 = lse.reshape(bh, 1, t)
-    delta3 = delta.reshape(bh, 1, t)
+        stats.append(dlse.astype(jnp.float32).reshape(b * n_head, 1, t))
+    rows, stat = _specs(n_head, g, d)
+    bthd = jax.ShapeDtypeStruct((b, t, hd), q.dtype)
 
-    dq = pl.pallas_call(
+    dq, delta3 = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, nk=nk, tile=tile),
-        grid=(bh, nq, nk),
-        in_specs=[_rows_spec(bq, d, 1), _rows_spec(bk, d, 2),
-                  _rows_spec(bk, d, 2), _rows_spec(bq, d, 1),
-                  _stat_spec(bq, 1), _stat_spec(bq, 1)],
-        out_specs=_rows_spec(bq, d, 1),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32)],
+                          block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
+                          d=d, g=g, has_dlse=dlse is not None),
+        grid=(b * n_head // g, nq, nk),
+        in_specs=[rows(bq, 1), rows(bk, 2), rows(bk, 2), rows(bq, 1),
+                  rows(bq, 1)] + [stat(bq, 1)] * len(stats),
+        out_specs=[rows(bq, 1), stat(bq, 1)],
+        out_shape=[bthd, jax.ShapeDtypeStruct((b * n_head, 1, t),
+                                              jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32)]
+        if nk > 1 else [],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q3, k3, v3, dy3, lse3, delta3)
+    )(q, k, v, dy, o, *stats)
 
-    # grid (bh, nK, nQ): the q-side operands follow the LAST axis here
+    # grid (B * H / g, nK, nQ): the q-side operands follow the LAST axis
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, nq=nq, tile=tile),
-        grid=(bh, nk, nq),
-        in_specs=[_rows_spec(bq, d, 2), _rows_spec(bk, d, 1),
-                  _rows_spec(bk, d, 1), _rows_spec(bq, d, 2),
-                  _stat_spec(bq, 2), _stat_spec(bq, 2)],
-        out_specs=[_rows_spec(bk, d, 1), _rows_spec(bk, d, 1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                          block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
+                          d=d, g=g),
+        grid=(b * n_head // g, nk, nq),
+        in_specs=[rows(bq, 2), rows(bk, 1), rows(bk, 1), rows(bq, 2),
+                  stat(bq, 2), stat(bq, 2)],
+        out_specs=[rows(bk, 1), rows(bk, 1)],
+        out_shape=[bthd, bthd],
+        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
+                        pltpu.VMEM((bk, w), jnp.float32)]
+        if nq > 1 else [],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q3, k3, v3, dy3, lse3, delta3)
-
-    shape4 = (b, h, t, d)
-    return dq.reshape(shape4), dk.reshape(shape4), dv.reshape(shape4)
+    )(q, k, v, dy, stats[0], delta3)
+    return dq, dk, dv
 
 
 # --------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, _ = _fwd_pallas(q, k, v, causal, scale, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, n_head, causal, scale, block_q, block_k, interpret):
+    out, _ = _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
                          interpret)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                           interpret)
+def _flash_fwd(q, k, v, n_head, causal, scale, block_q, block_k,
+               interpret):
+    out, lse = _fwd_pallas(q, k, v, n_head, causal, scale, block_q,
+                           block_k, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, dy):
-    return _bwd_pallas(res, dy, causal, scale, block_q, block_k, interpret)
+def _flash_bwd(n_head, causal, scale, block_q, block_k, interpret, res,
+               dy):
+    return _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
+                       interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -450,21 +626,25 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # (out, lse) variant: same kernels, but the log-sum-exp rows are a public,
 # differentiable output. Ring attention combines per-shard partial results
 # with these (parallel/ring.py), so d(loss)/d(lse) is generally non-zero.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret):
-    return _fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, n_head, causal, scale, block_q, block_k,
+               interpret):
+    return _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
+                       interpret)
 
 
-def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                           interpret)
+def _flash_lse_fwd(q, k, v, n_head, causal, scale, block_q, block_k,
+                   interpret):
+    out, lse = _fwd_pallas(q, k, v, n_head, causal, scale, block_q,
+                           block_k, interpret)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, res, dys):
+def _flash_lse_bwd(n_head, causal, scale, block_q, block_k, interpret,
+                   res, dys):
     dy, dlse = dys
-    return _bwd_pallas(res, dy, causal, scale, block_q, block_k, interpret,
-                       dlse=dlse)
+    return _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
+                       interpret, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -486,71 +666,143 @@ def _largest_divisor(n, limit):
     return d
 
 
-def _auto_block(t, d, itemsize):
-    """Major block for a sequence of t rows: all of it where a [t, d]
-    operand is small in VMEM (lanes pad d to 128) and t can be cut into
-    panels, so that the grid has one step a head and nothing is carried
-    between steps; otherwise the largest divisor of t up to
-    _AUTO_BLOCK, streamed."""
-    if t % _LANES == 0 and t * max(d, _LANES) * itemsize <= _ONE_BLOCK_BYTES:
+def _auto_block(t, w, itemsize):
+    """Major block for a sequence of t rows: all of it where a [t, w]
+    operand (w: the lanes of a block, g heads) is small in VMEM (lanes
+    pad w to 128) and t can be cut into panels, so that the grid has one
+    step a block of heads and nothing is carried between steps;
+    otherwise the largest divisor of t up to _AUTO_BLOCK, streamed."""
+    if t % _LANES == 0 and t * max(w, _LANES) * itemsize <= _ONE_BLOCK_BYTES:
         return t
     return _largest_divisor(t, _AUTO_BLOCK)
 
 
+_REG = _metrics.registry()
+_LOWERINGS = _REG.counter(
+    "ptpu_flash_lowerings_total",
+    "flash attention dispatches at trace time (one a lowering of the op, "
+    "none a step): the path taken, the layout of the entry called and "
+    "the heads a kernel block holds",
+    ("path", "entry", "heads_per_block"))
+
+
 def _resolve_path(q, scale, block_q, block_k, force):
-    """Shared dispatch: (path, scale, bq, bk). path: "pallas" /
-    "interpret" / "dense" — auto picks the kernel on TPU when T divides
-    the blocks and the head dim tiles onto the lanes. block None → auto
-    (_auto_block): all of T where that is small in VMEM, else the
-    largest divisor of T up to 1024 — a divisor, so non-power-of-two T
-    (1536, ...) keeps the fused kernel instead of demoting to dense."""
-    scale = float(scale) if scale else q.shape[-1] ** -0.5
-    t = q.shape[2]
+    """Shared dispatch: (path, scale, bq, bk), from the heads' shape and
+    dtype: q is anything with .shape [B, H, T, D] and .dtype (the
+    operands themselves stay [B, T, H*D]). path: "pallas" / "interpret" /
+    "dense" — auto picks the kernel on TPU when T divides the blocks
+    and the heads tile onto the lanes. block None → auto (_auto_block):
+    all of T where that is small in VMEM, else the largest divisor of T
+    up to 1024 — a divisor, so non-power-of-two T (1536, ...) keeps the
+    fused kernel instead of demoting to dense."""
+    n_head, t, d = q.shape[1:]
+    g = heads_per_block(n_head, d)
+    w = g * d
+    scale = float(scale) if scale else d ** -0.5
     auto_degenerate = False
     if not block_q or not block_k:
-        auto = _auto_block(t, q.shape[-1], q.dtype.itemsize)
+        auto = _auto_block(t, w, q.dtype.itemsize)
         # a T with no divisor >= 128 below the cap (prime, 2*prime, ...)
         # would yield a near-T^2 grid of tiny blocks — far worse than
         # dense XLA; demote instead of silently compiling a cliff
         auto_degenerate = auto < min(128, t)
         block_q = block_q or auto
         block_k = block_k or auto
+    block_q, block_k = min(block_q, t), min(block_k, t)
     path = force
     if path is None:
-        usable = (t % min(block_q, t) == 0 and t % min(block_k, t) == 0
-                  and t >= 128 and q.shape[-1] % 8 == 0
-                  and not auto_degenerate)
+        # several heads that fill no whole lane tile go as ONE block of
+        # all H*D lanes: only where a grid step's [rows, H*D] operand is
+        # as small as a block may be
+        fits = (g == 1 or w == _LANES
+                or max(block_q, block_k) * max(w, _LANES)
+                * q.dtype.itemsize <= _ONE_BLOCK_BYTES)
+        usable = (t % block_q == 0 and t % block_k == 0 and t >= 128
+                  and d % 8 == 0 and fits and not auto_degenerate)
         path = "pallas" if (usable and _on_tpu(q)) else "dense"
-    return path, scale, min(block_q, t), min(block_k, t)
+    return path, scale, block_q, block_k
 
 
-def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    force=None):
-    """Fused multi-head attention. q/k/v: [B, H, T, D].
+def heads_first(x, n_head):
+    """[B, T, H*D] -> [B, H, T, D]."""
+    b, t, hd = x.shape
+    return x.reshape(b, t, n_head, hd // n_head).transpose(0, 2, 1, 3)
+
+
+def heads_last(x):
+    """[B, H, T, D] -> [B, T, H*D]."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
+            entry, with_lse):
+    """Dispatch of every entry: q/k/v [B, T, H*D] -> out, or (out, lse
+    [B, H, T]) `with_lse`. `entry` labels the count: the layout the
+    caller came in."""
+    b, t, hd = q.shape
+    d = hd // n_head
+    path, scale, bq, bk = _resolve_path(
+        jax.ShapeDtypeStruct((b, n_head, t, d), q.dtype), scale, block_q,
+        block_k, force)
+    _LOWERINGS.inc(path=path, entry=entry,
+                   heads_per_block=str(heads_per_block(n_head, d)))
+    if path == "dense":
+        out, lse = _dense_lse(*(heads_first(x, n_head) for x in (q, k, v)),
+                              causal, scale)
+        return (heads_last(out), lse) if with_lse else heads_last(out)
+    return (_flash_lse if with_lse else _flash)(
+        q, k, v, n_head, causal, scale, bq, bk, path == "interpret")
+
+
+def flash_bthd(q, k, v, n_head, causal=False, scale=None,
+               block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+               force=None):
+    """Fused multi-head attention in the projections' own layout.
+    q/k/v and the result: [B, T, H*D], head h in lanes [h D, (h+1) D).
 
     force: None = auto (Pallas kernel on TPU when T divides the blocks,
     dense XLA math otherwise), "pallas" / "interpret" / "dense" pin a path
     (tests use "interpret" to run the kernel on CPU).
     """
-    path, scale, bq, bk = _resolve_path(q, scale, block_q, block_k, force)
-    if path == "dense":
-        return _dense(q, k, v, causal, scale)
-    return _flash(q, k, v, causal, scale, bq, bk, path == "interpret")
+    return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
+                   "bthd", False)
+
+
+def flash_bthd_lse(q, k, v, n_head, causal=False, scale=None,
+                   block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                   force=None):
+    """Like flash_bthd but returns (out [B, T, H*D], lse [B, H, T]) with
+    lse[b,h,i] = logsumexp_j(q_i·k_j*scale [+mask]) — the statistic ring
+    attention needs to merge partial attention over K/V shards. Both
+    outputs are differentiable (the lse cotangent folds into the shared
+    backward kernels)."""
+    return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
+                   "bthd", True)
+
+
+def flash_attention(q, k, v, causal=False, scale=None,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    force=None):
+    """flash_bthd for q/k/v [B, H, T, D]: a wrapper that transposes into
+    [B, T, H*D] and the output back. A [B, H, T, D] caller (ring and
+    Ulysses attention, the tests; no model) now pays the transposes the
+    model used to pay."""
+    h = q.shape[1]
+    out = _attend(heads_last(q), heads_last(k), heads_last(v), h, causal,
+                  scale, block_q, block_k, force, "bhtd", False)
+    return heads_first(out, h)
 
 
 def flash_attention_lse(q, k, v, causal=False, scale=None,
                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                         force=None):
-    """Like flash_attention but returns (out, lse) with
-    lse[b,h,i] = logsumexp_j(q_i·k_j*scale [+mask]) — the statistic ring
-    attention needs to merge partial attention over K/V shards. Both
-    outputs are differentiable (the lse cotangent folds into the shared
-    backward kernels)."""
-    path, scale, bq, bk = _resolve_path(q, scale, block_q, block_k, force)
-    if path == "dense":
-        return _dense_lse(q, k, v, causal, scale)
-    return _flash_lse(q, k, v, causal, scale, bq, bk, path == "interpret")
+    """flash_bthd_lse for q/k/v [B, H, T, D] (out [B, H, T, D], lse
+    [B, H, T]), through the same transposes as flash_attention."""
+    h = q.shape[1]
+    out, lse = _attend(heads_last(q), heads_last(k), heads_last(v), h,
+                       causal, scale, block_q, block_k, force, "bhtd", True)
+    return heads_first(out, h), lse
 
 
 # pallas imports placed at the end so a CPU-only environment that never

@@ -37,39 +37,62 @@ def _batch_axis(mesh):
     return "dp" if (mesh is not None and "dp" in mesh.axis_names) else None
 
 
-def _dense_attention(q, k, v, causal, scale, mesh=None):
+def _dense_attention(q, k, v, n_head, causal, scale, mesh=None):
+    """Attention off an sp mesh, q/k/v [B, T, H*D]: the projections'
+    layout, which the flash kernels read and write as it is."""
     # routes to the Pallas flash kernel on TPU (streaming softmax, no
     # [T, T] HBM materialization); dense XLA math elsewhere
-    from .flash_attention import flash_attention
-    fn = functools.partial(flash_attention, causal=causal, scale=scale)
+    from .flash_attention import flash_bthd, heads_per_block
+    fn = functools.partial(flash_bthd, causal=causal, scale=scale)
     if mesh is None or mesh.size == 1:
-        return fn(q, k, v)
+        return fn(q, k, v, n_head)
     # GSPMD cannot partition a Mosaic kernel (the TPU lowering refuses:
     # "wrap the call in a shard_map"). Attention is independent per
     # (batch, head), so split those dims over dp / tp by hand; each
-    # device runs the kernel on its own [B/dp, H/tp, T, dk] shard.
+    # device runs the kernel on its own [B/dp, T, (H/tp) D] shard: the
+    # heads are a slice of the last dimension, taken over tp where a
+    # device's heads are whole blocks of the kernel's.
     def axis(name, dim):
         return name if (name in mesh.axis_names
                         and dim % mesh.shape[name] == 0) else None
 
-    spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
+    tp = axis("tp", n_head // heads_per_block(n_head, q.shape[-1] // n_head))
+    spec = P(axis("dp", q.shape[0]), None, tp)
+    fn = functools.partial(fn, n_head=n_head // (mesh.shape[tp] if tp else 1))
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 
 
 @register("sp_attention")
 def _sp_attention(ctx, op):
-    """Sequence-parallel attention. Inputs Q/K/V [B, H, T, dk] (T sharded
-    on the mesh's sp axis when present); attrs: causal, variant
-    ("ring" | "ulysses"). Dense-math-identical fallback off-mesh."""
+    """Sequence-parallel attention. Inputs Q/K/V [B, T, H*dk] with the
+    attr n_head (what a projection leaves: no op moves a head on the way
+    in or out), or [B, H, T, dk]: the op observes the rank, and the
+    lowering makes the layout the path it takes wants (the flash kernels
+    [B, T, H*dk]; ring.py, which shards T on the mesh's sp axis when
+    present, [B, H, T, dk]). attrs: causal, variant ("ring" |
+    "ulysses"). Dense-math-identical fallback off-mesh."""
+    from .flash_attention import heads_first, heads_last
     q = ctx.in1(op, "Q")
     k = ctx.in1(op, "K")
     v = ctx.in1(op, "V")
     causal = bool(op.attr("causal", False))
-    scale = float(op.attr("scale", 0.0)) or q.shape[-1] ** -0.5
+    rank4 = q.ndim == 4
+    n_head = q.shape[1] if rank4 else int(op.attr("n_head", 0))
+    if n_head < 1 or q.shape[-1] % n_head:
+        raise ValueError(
+            "sp_attention: Q of rank 3 %s needs an n_head that divides "
+            "its last dimension, got %d" % (q.shape, n_head))
+    dk = q.shape[-1] if rank4 else q.shape[-1] // n_head
+    scale = float(op.attr("scale", 0.0)) or dk ** -0.5
     mesh = _mesh_axis(ctx, "sp")
+    if rank4 == (mesh is None):      # not the layout this path wants
+        turn = heads_last if rank4 else functools.partial(heads_first,
+                                                          n_head=n_head)
+        q, k, v = turn(q), turn(k), turn(v)
     if mesh is None:
-        out = _dense_attention(q, k, v, causal, scale, mesh=ctx.mesh)
+        out = _dense_attention(q, k, v, n_head, causal, scale,
+                               mesh=ctx.mesh)
     else:
         from ..parallel import ring
         fn = (ring.ulysses_attention
@@ -77,6 +100,8 @@ def _sp_attention(ctx, op):
               else ring.ring_attention)
         out = fn(q, k, v, mesh, axis_name="sp", causal=causal, scale=scale,
                  batch_axis=_batch_axis(mesh))
+    if rank4 == (mesh is None):
+        out = heads_first(out, n_head) if rank4 else heads_last(out)
     ctx.set_out(op, "Out", out)
 
 
@@ -146,18 +171,16 @@ def _decoder_layer_apply_tp(p, x, n_head, tp_axis, sp_axis=None,
     h_local = n_head // tp
     dk = d // n_head
 
-    def heads(z):
-        return z.reshape(b, t, h_local, dk).transpose(0, 2, 1, 3)
-
-    q = heads(x @ p["wq"])
-    k = heads(x @ p["wk"])
-    v = heads(x @ p["wv"])
+    q, k, v = x @ p["wq"], x @ p["wk"], x @ p["wv"]  # [b, t, h_local dk]
     if sp_axis:
         from ..parallel.ring import _ring_attention_sharded
-        a = _ring_attention_sharded(q, k, v, sp_axis, True, dk ** -0.5)
+        from .flash_attention import heads_first, heads_last
+        a = heads_last(_ring_attention_sharded(
+            *(heads_first(z, h_local) for z in (q, k, v)), sp_axis, True,
+            dk ** -0.5))
     else:
-        a = _dense_attention(q, k, v, True, dk ** -0.5)
-    part = a.transpose(0, 2, 1, 3).reshape(b, t, h_local * dk) @ p["wo"]
+        a = _dense_attention(q, k, v, h_local, True, dk ** -0.5)
+    part = a @ p["wo"]
     if tp_axis:
         part = lax.psum(part, tp_axis)
     x = _ln_apply(x + part, p["ln1_s"], p["ln1_b"])

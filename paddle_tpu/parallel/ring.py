@@ -17,6 +17,12 @@ log-sum-exp weighting. Because shards are contiguous sequence chunks,
 the causal mask per step collapses to three cases: the diagonal shard is
 plain causal attention, earlier shards are unmasked, later shards
 contribute nothing.
+
+Layout: everything here is [B, H, T, D], the sequence axis sharded. The
+flash kernels read [B, T, H*D] since PR 29; `flash_attention` /
+`flash_attention_lse` transpose into that and back, so each ring step
+pays two transposes the kernels' own entry (`flash_bthd`) does not. No
+benchmark cell runs ring or Ulysses attention.
 """
 
 import functools

@@ -363,3 +363,251 @@ def test_panel_edge_divides_its_block(block, target, want):
     (2062, 64, jnp.bfloat16, 2)])      # no panel divides it: degenerate
 def test_auto_block_is_all_of_t_where_it_fits(t, d, dtype, want):
     assert FA._auto_block(t, d, jnp.dtype(dtype).itemsize) == want
+
+
+# -- the projections' own layout (PR 29) -------------------------------------
+# (H, D) -> heads to a block, lanes of a block: two heads of 64 to a
+# 128-lane block; one head of 128, nothing to separate; four heads of
+# 32; and heads that fill no whole lane tile (three of 64: 128 / 64
+# does not divide 3), all of H*D as one block.
+_LAYOUTS = [pytest.param(16, 64, 2, id="H16-D64-g2"),
+            pytest.param(4, 128, 1, id="H4-D128-g1"),
+            pytest.param(4, 32, 4, id="H4-D32-g4"),
+            pytest.param(3, 64, 3, id="H3-D64-whole_width")]
+
+
+def _bthd_inputs(h, d, dtype, t=256, b=1, seed=7):
+    rng = np.random.RandomState(seed)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape) * 0.5, jnp.float32)
+    q, k, v, dy = (mk(b, t, h * d).astype(dtype) for _ in range(4))
+    return q, k, v, dy, mk(b, h, t)
+
+
+@pytest.mark.parametrize("h, d, g", _LAYOUTS)
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("block", [None, 128], ids=["one_block", "streamed"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_bthd_entry_matches_dense(dtype, block, causal, h, d, g):
+    """out, lse, dq, dk, dv of the kernels reading [B, T, H*D] as it is,
+    g heads to a block, with a NON-ZERO lse cotangent, against dense
+    float32 math on [B, H, T, D]."""
+    assert FA.heads_per_block(h, d) == g
+    q, k, v, dy, dlse = _bthd_inputs(h, d, dtype)
+    scale = d ** -0.5
+    kw = dict(causal=causal, force="interpret", block_q=block,
+              block_k=block)
+    if block is None:     # all of T is one block at these sizes
+        assert FA._resolve_path(FA.heads_first(q, h), None, None, None,
+                                "interpret")[2:] == (256, 256)
+
+    def ref_loss(q, k, v):
+        o, lse = FA._dense_lse(*(FA.heads_first(x, h) for x in (q, k, v)),
+                               causal, scale)
+        return (FA.heads_last(o) * _f32(dy)).sum() + (lse * dlse).sum()
+
+    def got_loss(q, k, v):
+        o, lse = FA.flash_bthd_lse(q, k, v, h, **kw)
+        return (_f32(o) * _f32(dy)).sum() + (lse * dlse).sum()
+
+    o_ref, lse_ref = FA._dense_lse(
+        *(FA.heads_first(_f32(x), h) for x in (q, k, v)), causal, scale)
+    g_ref = jax.grad(ref_loss, (0, 1, 2))(_f32(q), _f32(k), _f32(v))
+    o, lse = FA.flash_bthd_lse(q, k, v, h, **kw)
+    grads = jax.grad(got_loss, (0, 1, 2))(q, k, v)
+    assert o.shape == q.shape and o.dtype == dtype
+    assert lse.shape == (1, h, 256) and lse.dtype == jnp.float32
+    # the tolerances of test_tiled_walk_matches_dense
+    tol_o, tol_g = (2e-3, 5e-3) if dtype == jnp.float32 else (1e-2, 2e-2)
+    _assert_close("out", o, FA.heads_last(o_ref), tol_o)
+    _assert_close("lse", lse, lse_ref, tol_o)
+    # the output alone, through the other custom_vjp, is the same bits
+    assert jnp.array_equal(o, FA.flash_bthd(q, k, v, h, **kw))
+    for name, a, b in zip(("dq", "dk", "dv"), grads, g_ref):
+        assert a.shape == q.shape and a.dtype == dtype
+        _assert_close(name, a, b, tol_g)
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out_lse"])
+def test_bhtd_wrappers_equal_the_bthd_entry_bit_for_bit(with_lse):
+    """flash_attention / flash_attention_lse on [B, H, T, D] are the new
+    entry between two transposes: the same bits, forward and backward."""
+    h, d = 4, 64
+    q, k, v, dy, dlse = _bthd_inputs(h, d, jnp.bfloat16, b=2)
+    kw = dict(causal=True, force="interpret")
+
+    def loss(att):
+        def f(q, k, v):
+            o, lse = att(q, k, v)
+            extra = (lse * dlse).sum() if with_lse else 0.0
+            return (_f32(o) * _f32(dy)).sum() + extra
+        return f
+
+    def new(q, k, v):
+        if with_lse:
+            return FA.flash_bthd_lse(q, k, v, h, **kw)
+        return FA.flash_bthd(q, k, v, h, **kw), None
+
+    def old(q, k, v):      # the same [B, T, H*D] operands, heads first
+        args = [FA.heads_first(x, h) for x in (q, k, v)]
+        if with_lse:
+            o, lse = FA.flash_attention_lse(*args, **kw)
+            return FA.heads_last(o), lse
+        return FA.heads_last(FA.flash_attention(*args, **kw)), None
+
+    for a, b in zip(new(q, k, v), old(q, k, v)):
+        assert (a is None and b is None) or jnp.array_equal(a, b)
+    for a, b in zip(jax.grad(loss(new), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(old), (0, 1, 2))(q, k, v)):
+        assert jnp.array_equal(a, b)
+
+
+@pytest.mark.parametrize("h, d, t, dtype, block, want", [
+    (16, 64, 2048, jnp.bfloat16, None, "pallas"),   # the benchmark's cell
+    (16, 128, 4096, jnp.bfloat16, None, "pallas"),  # g 1, streamed
+    (2, 32, 2048, jnp.float32, None, "pallas"),     # whole width, 64 lanes
+    (3, 64, 1024, jnp.bfloat16, None, "pallas"),    # whole width, 192 lanes
+    (16, 80, 1024, jnp.bfloat16, None, "dense"),    # 1280 lanes: no block
+    (1, 192, 768, jnp.float32, None, "pallas"),     # one head: as before
+    (16, 60, 1024, jnp.bfloat16, None, "dense"),    # D no multiple of 8
+])
+def test_block_width_follows_from_d_and_h(monkeypatch, h, d, t, dtype,
+                                          block, want):
+    monkeypatch.setattr(FA, "_on_tpu", lambda x: True)
+    q = jnp.zeros((1, h, t, d), dtype)     # the heads' shape
+    assert FA._resolve_path(q, None, block, block, None)[0] == want
+
+
+def _fused_lm(packed, n_layer=2, seed=13):
+    import paddle_tpu as fluid
+    from paddle_tpu.models import transformer as T
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup), fluid.unique_name.guard():
+        prog.random_seed = seed
+        cost, _ = T.transformer_lm(vocab_size=48, max_len=16,
+                                   n_layer=n_layer, n_head=4, d_model=32,
+                                   d_inner=64, packed=packed)
+        grads = fluid.backward.append_backward(cost)
+    return prog, startup, cost, grads
+
+
+def test_fused_lm_hands_the_projections_straight_to_sp_attention():
+    """No reshape or transpose between the q/k/v `mul` ops and
+    sp_attention, nor between it and the output projection; parameter
+    names are those of the composed branch; loss and every gradient
+    equal the composed branch's."""
+    import paddle_tpu as fluid
+    prog, startup, cost, grads = _fused_lm(True)
+    ops = prog.global_block().ops
+    producer = {name: op for op in ops for name in op.output_names}
+    consumers = {}
+    for op in ops:
+        for name in op.input_names:
+            consumers.setdefault(name, []).append(op.type)
+    fused = [op for op in ops if op.type == "sp_attention"]
+    assert len(fused) == 2
+    for op in fused:
+        assert op.attr("n_head") == 4
+        for slot in ("Q", "K", "V"):
+            assert producer[op.input(slot)[0]].type == "mul"
+        assert "mul" in consumers[op.output("Out")[0]]
+    forward = [op.type for op in ops[:ops.index(fused[-1])]]
+    assert "transpose" not in forward
+    # the only reshape before the last layer's attention is none of
+    # attention's: 7 ops a layer's attention had (3 reshape + 3
+    # transpose in, transpose + reshape out) are gone
+    assert forward.count("reshape") == 0
+
+    prog2, startup2, cost2, grads2 = _fused_lm(False)
+    names = [p.name for p in prog.global_block().all_parameters()]
+    assert names == [p.name for p in prog2.global_block().all_parameters()]
+    assert [tuple(p.shape) for p in prog.global_block().all_parameters()] \
+        == [tuple(p.shape) for p in prog2.global_block().all_parameters()]
+
+    rng = np.random.RandomState(0)
+    b, t = 2, 16
+    feeds = {"src": rng.randint(1, 48, (b, t)).astype(np.int64),
+             "pos": np.tile(np.arange(t, dtype=np.int64), (b, 1)),
+             "mask": np.ones((b, t), np.float32),
+             "label": rng.randint(1, 48, (b, t)).astype(np.int64)}
+    exe = fluid.Executor(fluid.CPUPlace())
+    s1, s2 = fluid.Scope(), fluid.Scope()
+    with fluid.scope_guard(s1):
+        exe.run(startup)
+        r1 = exe.run(prog, feed=feeds,
+                     fetch_list=[cost] + [g for _, g in grads])
+    for name in names:
+        s2.set(name, np.array(np.asarray(s1.find_var(name))))
+    with fluid.scope_guard(s2):
+        r2 = exe.run(prog2, feed=feeds,
+                     fetch_list=[cost2] + [g for _, g in grads2])
+    assert [p.name for p, _ in grads] == [p.name for p, _ in grads2]
+    np.testing.assert_allclose(float(np.asarray(r1[0])),
+                               float(np.asarray(r2[0])), rtol=1e-5)
+    for (p, _), a, b_ in zip(grads, r1[1:], r2[1:]):
+        _assert_close(p.name, jnp.asarray(a), jnp.asarray(b_), 5e-3)
+
+
+def test_sp_attention_of_rank_3_and_of_rank_4_agree():
+    """The op observes the rank: [B, T, H*dk] with n_head and
+    [B, H, T, dk] are the same attention."""
+    import paddle_tpu as fluid
+    h, t, dk = 4, 64, 16
+    rng = np.random.RandomState(1)
+    x3 = [rng.randn(2, t, h * dk).astype(np.float32) for _ in range(3)]
+    x4 = [a.reshape(2, t, h, dk).transpose(0, 2, 1, 3) for a in x3]
+    prog = fluid.Program()
+    with fluid.program_guard(prog, fluid.Program()):
+        v3 = [fluid.layers.data(n, [t, h * dk]) for n in ("q3", "k3", "v3")]
+        v4 = [fluid.layers.data(n, [h, t, dk]) for n in ("q4", "k4", "v4")]
+        o3 = fluid.layers.sequence_parallel_attention(*v3, causal=True,
+                                                      n_head=h)
+        o4 = fluid.layers.sequence_parallel_attention(*v4, causal=True)
+        assert tuple(o3.shape[1:]) == (t, h * dk)
+        with pytest.raises(ValueError, match="n_head"):
+            fluid.layers.sequence_parallel_attention(*v3, causal=True)
+        with pytest.raises(ValueError, match="no n_head"):
+            fluid.layers.sequence_parallel_attention(*v4, n_head=h)
+    feed = dict(zip(("q3", "k3", "v3", "q4", "k4", "v4"), x3 + x4))
+    got3, got4 = fluid.Executor(fluid.CPUPlace()).run(
+        prog, feed=feed, fetch_list=[o3, o4])
+    np.testing.assert_allclose(
+        np.asarray(got3).reshape(2, t, h, dk).transpose(0, 2, 1, 3),
+        np.asarray(got4), atol=1e-6)
+    ref = FA._dense(*(jnp.asarray(a) for a in x4), True, dk ** -0.5)
+    np.testing.assert_allclose(np.asarray(got4), np.asarray(ref), atol=1e-5)
+
+
+def test_lowering_counter_says_which_path_engaged():
+    """`ptpu_flash_lowerings_total{path, entry, heads_per_block}`: one
+    count a lowering of the fused model's attention (the forward's
+    trace; none a step), `dense` off the chip; the [B, H, T, D]
+    wrappers count as `bhtd`."""
+    import paddle_tpu as fluid
+    n_layer = 3
+    prog, startup, cost, _ = _fused_lm(True, n_layer=n_layer)
+    rng = np.random.RandomState(0)
+    feeds = {"src": rng.randint(1, 48, (2, 16)).astype(np.int64),
+             "pos": np.tile(np.arange(16, dtype=np.int64), (2, 1)),
+             "mask": np.ones((2, 16), np.float32),
+             "label": rng.randint(1, 48, (2, 16)).astype(np.int64)}
+    count = FA._LOWERINGS
+    # d_model 32 over 4 heads: D 8, sixteen heads would fill 128 lanes,
+    # four do not: all of H*D as one block, four heads to it
+    labels = dict(path="dense", entry="bthd", heads_per_block="4")
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        before = count.value(**labels)
+        exe.run(prog, feed=feeds, fetch_list=[cost])
+        lowered = count.value(**labels) - before
+        exe.run(prog, feed=feeds, fetch_list=[cost])     # a cached step
+        assert count.value(**labels) - before == lowered
+    assert lowered == n_layer
+    q, k, v = _qkv(b=1, h=2, t=128, d=64)
+    was = count.value(path="interpret", entry="bhtd", heads_per_block="2")
+    FA.flash_attention(q, k, v, causal=True, force="interpret")
+    assert count.value(path="interpret", entry="bhtd",
+                       heads_per_block="2") == was + 1
+    assert "ptpu_flash_lowerings_total" in \
+        fluid.monitor.metrics.registry().render_prometheus()

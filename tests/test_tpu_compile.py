@@ -27,13 +27,14 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from paddle_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from paddle_tpu.ops.flash_attention import (  # noqa: E402
+    flash_attention, flash_bthd)
 from paddle_tpu.ops.paged_attention import paged_attention  # noqa: E402
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip as a sharding. The persistent compile
+def topo():
+    """A described v5e host of four chips. The persistent compile
     cache is off around the module: a compile for a described chip is
     written to it but cannot be read back without a chip."""
     from jax.experimental import topologies
@@ -46,9 +47,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One of its chips as a sharding."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_text(fn, *avals):
@@ -73,6 +80,90 @@ def test_flash_attention_compiles_for_v5e(chip, shape, direction):
     # forward is one kernel; backward re-runs it and adds dq and dk/dv
     assert text.count("tpu_custom_call") >= (1 if direction == "fwd"
                                              else 3)
+
+
+# the projections' own layout (PR 29): (B, T, H, D). The benchmark's
+# cell, two heads of 64 to a block and all of T in it; OLMoE's shape,
+# one head of 128 to a block, T 4096 streamed.
+_BTHD = [pytest.param(4, 2048, 16, 64, id="opt350m_cell"),
+         pytest.param(2, 4096, 16, 128, id="olmoe_T4k_dk128")]
+
+
+@pytest.mark.parametrize("b, t, h, d", _BTHD)
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_bthd_compiles_for_v5e(chip, b, t, h, d, direction):
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_bthd(q, k, v, h, causal=True, force="pallas")
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compiled_text(fn, q, q, q)
+    names = ["flash_fwd"] + (["flash_bwd_dq", "flash_bwd_dkv"]
+                             if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name in text
+
+
+def test_nothing_moves_a_head_between_a_projection_and_the_kernels(chip):
+    """The cell's attention layer, projections included, forward and
+    backward: the step compiled for the v5e has no `transpose` and no
+    `copy` of a [4, 2048, 1024] bf16 operand (under any factoring of
+    its dimensions), and every such operand keeps the layout the
+    projections' matmuls write, H*D minor. (Through the [B, H, T, D]
+    wrapper under a model that splits heads it held nine such copies.)"""
+    import math
+    import re
+    b, t, h, d = 4, 2048, 16, 64
+    x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+    w = jax.ShapeDtypeStruct((h * d, h * d), jnp.bfloat16, sharding=chip)
+
+    def layer(x, wq, wk, wv, wo):
+        a = flash_bthd(x @ wq, x @ wk, x @ wv, h, causal=True,
+                       force="pallas")
+        return (a @ wo).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(layer, argnums=(0, 1, 2, 3, 4)),
+                          x, w, w, w, w)
+    assert text.count("tpu_custom_call") == 3
+    results = re.findall(
+        r"= bf16\[([\d,]+)\]\{([\d,]+)[^}]*\} ([\w-]+)\(", text)
+    big = [(dims, layout, op) for dims, layout, op in results
+           if math.prod(int(n) for n in dims.split(",")) == b * t * h * d]
+    assert len(big) > 10
+    assert not [r for r in big if r[2] in ("copy", "transpose")]
+    assert {(dims, layout) for dims, layout, _ in big} \
+        == {("4,2048,1024", "2,1,0")}
+
+
+def test_flash_bthd_lowers_under_shard_map_dp2_tp2(topo, monkeypatch):
+    """ParallelExecutor's dp2 x tp2 form of the op: batch over dp, the
+    heads (a slice of the last dimension) over tp, eight heads a
+    device, whole blocks of two. The dispatch asks JAX for its backend,
+    which is the CPU here: the test answers for the described chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as np
+    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.ops.parallel_ops import _dense_attention
+    monkeypatch.setattr(fa, "_on_tpu", lambda x: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    q = jax.ShapeDtypeStruct(
+        (8, 2048, 1024), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "tp")))
+
+    def loss(q, k, v):
+        return _dense_attention(q, k, v, 16, True, 0.125, mesh=mesh
+                                ).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert text.count("tpu_custom_call") == 3
+    # a device's shard is what its kernels see: no gather of q, k, v
+    assert "bf16[4,2048,512]" in text
+    assert "all-gather" not in text and "all-to-all" not in text
 
 
 _SLOTS, _LAYERS, _HEADS, _BS, _NBMAX = 8, 8, 16, 16, 64
