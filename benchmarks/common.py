@@ -37,36 +37,23 @@ def time_loop(run_step, args, items_per_batch, unit="items", sync=None):
 
     Without `sync`, each run_step() is assumed to sync itself (original
     per-batch protocol). With `sync`, steps are dispatched back-to-back and
-    synced ONCE per timing window — the JAX protocol: a per-step sync
+    synced ONCE after the last — the JAX protocol: a per-step sync
     would put the host round trip inside every step. `sync` must end in
     work that waits for the device (`block_until_ready`, or a
     device→host fetch; chip_smoke.py's train phase prints both timings).
     Returns items/sec."""
-    windows = max(1, int(os.environ.get("PADDLE_TPU_BENCH_WINDOWS", "1")))
     for i in range(args.skip_batch_num):
         run_step(i)
     if sync:
         sync()
-    # N timing windows: report the MEDIAN window plus the spread so the
-    # recorded number carries its own error bar.
-    times = []
-    step_no = args.skip_batch_num
-    for _ in range(windows):
-        t0 = time.perf_counter()
-        for _ in range(args.iterations):
-            run_step(step_no)
-            step_no += 1
-        if sync:
-            sync()
-        times.append((time.perf_counter() - t0) / max(1, args.iterations))
-    times.sort()
-    median = times[len(times) // 2] if len(times) % 2 else \
-        0.5 * (times[len(times) // 2 - 1] + times[len(times) // 2])
-    ips = items_per_batch / median
-    print("median %.4f ms/batch over %d windows "
-          "(best %.4f, worst %.4f), %.1f %s/sec (best %.1f)"
-          % (1000 * median, len(times), 1000 * times[0], 1000 * times[-1],
-             ips, unit, items_per_batch / times[0]))
+    t0 = time.perf_counter()
+    for i in range(args.iterations):
+        run_step(args.skip_batch_num + i)
+    if sync:
+        sync()
+    per_batch = (time.perf_counter() - t0) / max(1, args.iterations)
+    ips = items_per_batch / per_batch
+    print("%.4f ms/batch, %.1f %s/sec" % (1000 * per_batch, ips, unit))
     return ips
 
 

@@ -41,9 +41,7 @@ def main():
     d, t = args.d_model, args.max_len
     flops_tok = 3 * (args.n_layer * (8 * d * d + 4 * d * args.d_inner
                                      + 4 * t * d) + 2 * d * args.vocab)
-    import os
-    windows = max(1, int(os.environ.get("PADDLE_TPU_BENCH_WINDOWS", "1")))
-    total = args.iterations * windows + args.skip_batch_num
+    total = args.iterations + args.skip_batch_num
     loader = iter(fluid.reader.DeviceLoader(
         fluid.reader.repeat_feed(feeds, total + 1)))
 

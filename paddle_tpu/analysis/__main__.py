@@ -31,7 +31,6 @@ IMPORT_CHECK_PACKAGES = (
     "paddle_tpu.monitor.collector",
     "paddle_tpu.monitor.goodput",
     "paddle_tpu.monitor.signals",
-    "paddle_tpu.perfgate",
     "paddle_tpu.serving",
     "paddle_tpu.serving.engine",
     "paddle_tpu.serving.fleet",

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-Entry points (chip_smoke.py, bench.py, benchmarks/common.parse_args,
+Entry points (chip_smoke.py, benchmarks/common.parse_args,
 __graft_entry__) call ``configure()`` before their first compile;
 ``import paddle_tpu`` does not — a library import must not decide where
 a process writes.

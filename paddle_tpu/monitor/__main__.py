@@ -30,7 +30,7 @@ rows (step count, latency percentiles, compile/recompile causes, MFU,
 tokens/s) and serving `serving_step`/`serving_request` rows (engine
 step p50/p95, occupancy, queue depth, TTFT/TPOT percentiles, error
 count) — one command reports whatever ran. `--json` emits the same
-summary as one JSON object for scripts (bench.py consumes this shape).
+summary as one JSON object for scripts.
 `watch` tails a (possibly live) log and renders a refreshing terminal
 dashboard; `--once` renders a single frame and exits (scripts/tests).
 """

@@ -886,7 +886,7 @@ def on_serving_step(active, slots, queue_depth, emitted=0, admitted=0,
     if shadow:
         # SHADOW engine step (canary analysis plane): scored, never
         # served — nothing here may tick the serving counters/gauges
-        # the SLO engine, bench and autoscaler scale_hint read. The
+        # the SLO engine and the autoscaler's scale_hint read. The
         # decode volume lands on the mirror counter; the row below is
         # marked so slo/signals readers skip it too.
         if emitted:
@@ -1422,7 +1422,7 @@ def _fmt_s(v):
 # -- snapshots -------------------------------------------------------------
 
 def summary():
-    """One-look health dict (reporter line / bench.py stamp)."""
+    """One-look health dict (the reporter line, ``session().summary()``)."""
     steps = sum(STEPS.snapshot().values())
     out = {
         "steps": steps,

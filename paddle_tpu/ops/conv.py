@@ -97,8 +97,8 @@ def _maybe_conv1x1_bn_fused(ctx, op, x, w, strides, paddings, dilations,
     # the measured result went the other way — the Pallas matmul (the
     # fusion vehicle) loses more against XLA's conv at the bandwidth-
     # bound 1x1 shapes than the fused stats save (full model: 1134 vs
-    # 2491 img/s; per-shape: benchmarks/perf_probe_mmstats.py). Kept as
-    # an opt-in and as the committed evidence for that conclusion
+    # 2491 img/s). Kept as an opt-in and as the committed evidence for
+    # that conclusion
     # (PERF.md round-4 "ResNet conv+BN fusion probe").
     from ..flags import get_flag
     if not get_flag("fuse_conv_bn"):

@@ -64,8 +64,8 @@ class DeviceLoader:
     def _resolve_device(self):
         """The device committed buffers land on — must agree with what
         a bare device_put would pick, or one batch could mix devices
-        (jax_default_device is process-wide and e.g. serving_bench
-        sets it)."""
+        (jax_default_device is process-wide and a caller may have
+        set it)."""
         if self._device is not None:
             return self._device
         return jax.config.jax_default_device or jax.local_devices()[0]

@@ -306,7 +306,7 @@ class Autoscaler:
         replica at a time: boot the new version, gate on a healthy
         STAT, drain one old-version cell, retire it, repeat until the
         fleet serves only the new version. Returns the target version
-        label; progress via ``roll_status()`` / ``wait_roll()``."""
+        label; ``wait_roll()`` blocks until it ends."""
         if version is None and isinstance(artifact, str):
             import os
             version = os.path.basename(os.path.normpath(artifact))
@@ -324,14 +324,6 @@ class Autoscaler:
                 "draining": None,
             }
         return version
-
-    def roll_status(self):
-        with self._lock:
-            r = self._roll
-            if r is None:
-                return None
-            return {"from": r["from"], "to": r["to"],
-                    "state": r["state"], "replaced": r["replaced"]}
 
     def wait_roll(self, timeout=120.0):
         """Block until the in-progress roll finishes (completed or

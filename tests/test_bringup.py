@@ -1,11 +1,12 @@
 """Bring-up contracts (ISSUE 21): nothing on the main paths hides the
 device. A place names a device this process must have, an unknown TPU
 kind has no peak, the compile cache goes where the environment says or
-to one fixed path, bench.py stamps a failure instead of retrying, and
-chip_smoke.py refuses to run without a TPU."""
+to one fixed path, chip_smoke.py refuses to run without a TPU, and the
+README names only files that exist."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 import types
@@ -102,25 +103,6 @@ def test_import_paddle_tpu_places_no_cache_and_touches_no_backend():
                    timeout=120)
 
 
-def test_bench_guarded_stamps_the_failure_once():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    calls, errors = [], {}
-
-    def boom():
-        calls.append(1)
-        raise RuntimeError("Unable to initialize backend 'tpu'")
-
-    assert bench.guarded("cfg", boom, errors) is None
-    assert len(calls) == 1                      # no retry
-    assert list(errors) == ["cfg"] and "Unable" in errors["cfg"][0]
-    assert bench.guarded("ok", lambda: 3.0, errors) == 3.0
-    assert not hasattr(bench, "_require_accel")
-
-
 def test_chip_smoke_without_a_tpu_fails_and_prints_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
@@ -150,3 +132,49 @@ def test_chip_smoke_rehearsal_passes_and_reports_cpu(chips, tmp_path):
                                            "kind": "cpu", "count": 4}}
     assert ("[chips4]" in out.stdout) == (chips == "4")
     assert ("[train]" in out.stdout) == (chips == "1")
+
+
+# a backquoted word that looks like a file of this repo ...
+_README_PATH = re.compile(r"[A-Za-z0-9_./-]+\.(?:py|json|md)")
+# ... unless it is the reference's tree, a user's own file or what a
+# command writes at run time
+_README_NOT_OURS = ("paddle/", "go/", "benchmark/fluid/", "python/paddle/",
+                    "/tmp/", "chiprun_out/", "__manifest__.json",
+                    "incident.json", "calib.json", "spec.json", "slo.json",
+                    "serving_slo.json", "fleet.json", "m.json",
+                    "timeline.json", "train.py")
+
+
+def _readme_paths(text):
+    parts = text.split("```")            # odd parts are fenced blocks
+    spans = parts[1::2] + [s for p in parts[0::2]
+                           for s in re.findall(r"`([^`\n]+)`", p)]
+    for span in spans:
+        for word in span.split():
+            word = word.strip("()[],;:'\"").split("::")[0]   # file::test
+            if _README_PATH.fullmatch(word) and \
+                    not word.startswith(_README_NOT_OURS):
+                yield word
+
+
+def _repo_files():
+    skip = {".git", "__pycache__", "chiprun_out", "_export", ".jax_cache",
+            ".cache", ".pytest_cache"}
+    for root, dirs, files in os.walk(REPO):
+        dirs[:] = [d for d in dirs if d not in skip]
+        for f in files:
+            yield os.path.relpath(os.path.join(root, f), REPO)
+
+
+def test_readme_names_only_files_that_exist():
+    """A path is given from the root or from ``paddle_tpu/``; a bare
+    file name may be any file of the tree (``rpc.py``)."""
+    with open(os.path.join(REPO, "README.md")) as f:
+        words = sorted(set(_readme_paths(f.read())))
+    assert "chipbench/run.py" in words and "chip_smoke.py" in words
+    files = set(_repo_files())
+    names = {os.path.basename(p) for p in files}
+    gone = [w for w in words
+            if not (w in files or "paddle_tpu/" + w in files
+                    or ("/" not in w and w in names))]
+    assert not gone, gone

@@ -2,7 +2,7 @@
 train steps on CPU with the full monitor armed (flight recorder +
 metrics + cost model), asserting the expected counters/gauges are
 emitted, the JSONL log parses, and the CLI summarizes it — the
-end-to-end contract bench.py and production runs rely on."""
+end-to-end contract production runs rely on."""
 
 import json
 import subprocess

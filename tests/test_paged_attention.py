@@ -21,9 +21,7 @@ module holds the pins the kernel tier itself needs:
   * int8 KV quantization: quantize/dequantize round-trip bounds,
     kernel output pinned at rtol 2e-2 (derivation at the pin), the
     engine arm deterministic and OFF by default, and the quant-aware
-    ``bytes_per_block`` / ``plan_hbm_bytes`` accounting;
-  * perfgate: the block_kernel_* probes gate regressions and skip
-    cleanly on pre-20 baselines.
+    ``bytes_per_block`` / ``plan_hbm_bytes`` accounting.
 
 Budget: ONE module-scoped 1-layer LM (the test_kvpool shape) + three
 small engines; kernel-math tests are pure-array. Soaks live behind
@@ -36,7 +34,7 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import perfgate, serving
+from paddle_tpu import serving
 from paddle_tpu.models import transformer
 from paddle_tpu.models.transformer_infer import TransformerLMInfer
 from paddle_tpu.ops import paged_attention as P
@@ -403,26 +401,6 @@ def test_kv_bytes_telemetry(lm, eng_block):
     eng_block.generate_many([[1, 8, 2]], [4])
     total = monrt.KV_BYTES_TOTAL.value()
     assert total == eng_block._pool.num_blocks * eng_block._block_bytes
-
-
-# -- perfgate wiring -------------------------------------------------------
-
-def test_perfgate_gates_block_kernel_probes():
-    base = {"metric": "x", "platform": "cpu",
-            "serving": {"block_kernel_speedup": 1.7,
-                        "block_kernel_scale_ratio": 1.5,
-                        "block_kernel_quant_speedup": 1.6,
-                        "block_kernel_spread_pct": 5.0}}
-    import json as _json
-    cur = _json.loads(_json.dumps(base))
-    assert perfgate.compare(cur, base)["pass"]
-    cur["serving"]["block_kernel_speedup"] = 1.0        # -41%
-    v = perfgate.compare(cur, base)
-    assert "serving_block_kernel_speedup" in v["regressions"]
-    cur["serving"].pop("block_kernel_speedup")          # pre-20 base
-    v = perfgate.compare(cur, base)
-    st = {p["name"]: p["status"] for p in v["probes"]}
-    assert st["serving_block_kernel_speedup"] == "skipped"
 
 
 # -- soak ------------------------------------------------------------------

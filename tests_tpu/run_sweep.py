@@ -64,8 +64,8 @@ EXEMPT_TPU = {
     "sp_attention": "multi-device shard_map collective (needs an sp>1 "
                     "mesh); validated on the 8-device virtual mesh "
                     "(test_parallel_integration.py) and by the driver "
-                    "dryrun; its compute core (the flash kernel) is "
-                    "TPU-measured by bench.py",
+                    "dryrun; its compute core (the flash kernel) runs on "
+                    "the chip in chipbench's opt350m_train cell",
     "moe_ffn": "multi-device shard_map collective (needs an ep>1 mesh); "
                "validated on the virtual mesh (test_pipeline_moe.py) "
                "and by the driver dryrun",
