@@ -1,5 +1,6 @@
-"""One attention call under the profiler: the three flash kernels and
-everything round them (PR 29's probe rows, PERF.md section 6).
+"""One attention call under the profiler: the flash kernels and
+everything round them (the probe rows of PRs 29 and 31, PERF.md
+section 6).
 
 q, k, v, dy are [B, T, H*D] bf16 as a projection leaves them; the call
 is forward and backward (all three gradients), causal. ``--entry bthd``
@@ -9,7 +10,12 @@ what the model did before PR 29, and the only form a tree before it
 has (copy this file there). On this tree XLA cancels the split
 against the wrapper's own transposes and the two entries read the
 same. Prints one JSON line: device ms a call by op kind (numbering
-stripped), kernels apart from the rest. Needs the chip:
+stripped), kernels apart from the rest. The kernels are told by name
+(``KERNELS``): every tree has ``flash_fwd``; a tree before PR 31 runs
+the backward as ``flash_bwd_dq`` + ``flash_bwd_dkv`` at every shape;
+since PR 31 a shape whose T is one block (the default: bf16, T 2048)
+runs the one kernel ``flash_bwd``, and a streamed one (``--t 4096``)
+still the two. A name with no device time reads 0. Needs the chip:
 ``chiprun -- python benchmarks/perf_probe_flash_layout.py``.
 """
 
@@ -22,7 +28,7 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd")
 
 
 def main():

@@ -5,6 +5,10 @@ at the full width of the transformer-large configuration (8 layers,
 d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
 
   device  jax.devices() must be a TPU (no CPU fall-through)
+  flash   the flash backward alone at the benchmark cell's shape
+          ([4, 2048, 1024] bf16, 16 heads of 64, causal: one block
+          holds all of T, so the ONE kernel flash_bwd): dq, dk, dv
+          against dense float32 math on the same inputs
   train   T.transformer_lm -> Adam.minimize -> amp.enable_amp ->
           Executor(TPUPlace(0)); 5 steps on one batch; loss ~ ln(vocab)
           and falling; the flash kernel is in the compiled step
@@ -128,6 +132,52 @@ def _lm_batch(cfg, seed):
                             cfg["max_len"], cfg["vocab"])
     feeds["mask"] = np.ones_like(feeds["mask"])     # packed sequences
     return feeds
+
+
+# --------------------------------------------------------------------------
+# bf16 gradients round at 2^-9 of their largest value; the cell's shape
+# read 3.0e-3 to 3.9e-3 on the chip (PERF.md section 6, PR 31)
+FLASH_GRAD_TOL = 2e-2
+
+
+def phase_flash(seed, rehearse):
+    """The backward the benchmark's step runs 24 times and nothing else
+    compares: gradients of `flash_bthd` against the dense float32 math
+    (`highest`), largest difference over largest value, each gradient."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+    b, t, h, d = (1, 256, 4, 64) if rehearse else (4, 2048, 16, 64)
+    rng = np.random.RandomState(seed)
+    q, k, v, dy = (jnp.asarray(rng.randn(b, t, h * d) * 0.5, jnp.bfloat16)
+                   for _ in range(4))
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def kernel(q, k, v):
+        return (f32(fa.flash_bthd(q, k, v, h, causal=True)) * f32(dy)).sum()
+
+    def dense(q, k, v):
+        o = fa._dense(*(fa.heads_first(x, h) for x in (q, k, v)), True,
+                      d ** -0.5)
+        return (fa.heads_last(o) * f32(dy)).sum()
+
+    t0 = time.perf_counter()
+    grad = jax.jit(jax.grad(kernel, (0, 1, 2))).lower(q, k, v).compile()
+    got, text = grad(q, k, v), grad.as_text()
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.grad(dense, (0, 1, 2)))(f32(q), f32(k), f32(v))
+    errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+            for a, r in zip(got, want)]
+    log("[flash] q/k/v/dy [%d, %d, %d] bf16 causal: dq %.3e dk %.3e dv "
+        "%.3e from the dense float32 gradients (%.1f s); "
+        "tpu_custom_call sites %d" % (b, t, h * d, *errs,
+                                     time.perf_counter() - t0,
+                                     text.count("tpu_custom_call")))
+    assert max(errs) <= FLASH_GRAD_TOL, errs
+    if not rehearse:
+        # the forward re-run and ONE backward kernel
+        assert "flash_bwd" in text and "flash_bwd_dq" not in text, \
+            "all of T in one block did not take the fused backward"
 
 
 # --------------------------------------------------------------------------
@@ -513,6 +563,7 @@ def main():
     if args.chips == 4:
         phase_multichip(cfg, args.seed, args.rehearse)
     else:
+        phase_flash(args.seed, args.rehearse)
         phase_train(cfg, args.seed, args.rehearse)
         phase_serve(cfg, args.seed, args.rehearse)
     log("[cache] %d entries in %s at end"

@@ -4,8 +4,10 @@ The fused attention kernel the registry docstring promises: computes
 softmax(QK^T * scale [+ causal mask]) V without materializing the [T, T]
 score matrix in HBM. Forward keeps a running (max, denominator,
 accumulator) per query block while streaming key/value blocks through
-VMEM; backward recomputes probabilities from the saved log-sum-exp rows
-(the standard two-kernel dq / dk+dv scheme).
+VMEM; backward recomputes probabilities from the saved log-sum-exp rows:
+in ONE kernel (flash_bwd) where a block holds all of T, each tile's s,
+p, dp and ds computed once and dq, dk, dv all made from them (PR 31),
+and in the standard two-kernel dq / dk+dv scheme where T is streamed.
 
 Reference capability: the reference's attention is composed matmul +
 softmax ops (nets.py:168 scaled_dot_product_attention,
@@ -30,7 +32,9 @@ Precision: MXU operands keep the dtype they arrive in (bf16 under AMP,
 float32 otherwise), p and ds are cast to it before their matmuls, and
 every dot accumulates in float32; the running max, the denominator,
 lse, delta, exp and all accumulators are float32. `scale` is folded
-into q (into k for dk/dv) once a panel.
+into q (into k where the scores are held transposed: flash_bwd_dkv and
+flash_bwd) once a panel; dq and dk are products with the unscaled
+operand, scaled in float32 as they are stored.
 
 Shapes (PR 29): q, k, v and the output are [B, T, H*D], the layout a
 projection leaves them in, with a static n_head. A grid step's block
@@ -51,11 +55,23 @@ passes are those of one head at a time. T must be a multiple of the block size
 (the sp bucketing guarantees powers of two); D any multiple of 8. The
 row statistics (lse, delta) are float32, one float a row and head, the
 rows along the lanes: [B*H, 1, T], g heads' rows to a grid step.
-`delta` = rowsum(dy * o) over each head's lanes is made by flash_bwd_dq
-from the rows of dy it holds and o as one more operand, and handed to
-flash_bwd_dkv as a row statistic: XLA, asked for [B*H, 1, T] from
-[B, T, H*D] operands, first copies both whole into a T-minor layout
-(every formulation compiled for a described v5e did; PR 29).
+`delta` = rowsum(dy * o) over each head's lanes is made inside a kernel
+from the rows of dy it holds and o as one more operand: XLA, asked for
+[B*H, 1, T] from [B, T, H*D] operands, first copies both whole into a
+T-minor layout (every formulation compiled for a described v5e did;
+PR 29). flash_bwd keeps it in VMEM; streamed, flash_bwd_dq hands it to
+flash_bwd_dkv as a row statistic.
+
+The backward (PR 31) follows from the blocks alone (_backward_of; no
+flag). All of T in one block (`nq == nk == 1`: bf16 up to T 2048, the
+benchmark's cell, ring attention's shards that short): flash_bwd, grid
+(B * H / g,), walks by keys with the scores transposed, dv and dk
+finishing inside their panel and dq accumulated across panels in a
+float32 [T, W] scratch: five matmuls a tile where the two kernels ran
+seven, one exp a score, one pass over q, k, v, dy. Streamed (float32
+above T 1024, T 4096, blocks wider than 128 lanes above 512 rows): the
+two kernels as they were, bit for bit; fused, dq would have to be held
+across key blocks, and no cell measures such a shape yet.
 
 Dispatch: `flash_bthd(q, k, v, n_head, causal, scale)` uses the kernel
 on TPU and the dense jnp math elsewhere (CPU tests exercise the kernel
@@ -63,7 +79,8 @@ via interpret mode separately); `flash_attention` / `flash_attention_lse`
 take [B, H, T, D] and are wrappers round it that transpose in and out:
 such a caller (parallel/ring.py) now pays the transposes the model used
 to pay. Each dispatch counts itself at trace time in
-`ptpu_flash_lowerings_total{path, entry, heads_per_block}`.
+`ptpu_flash_lowerings_total{path, entry, heads_per_block, backward}`
+(backward: "fused" / "two_kernels", "none" on the dense path).
 """
 
 import functools
@@ -103,6 +120,17 @@ _LANES = 128
 # parent's kernels although dq now also makes delta. Unrolled, the
 # scheduler overlaps the two heads (1.8% faster) for 2.6 times the
 # equations to trace and lower: 8 s more in every set-up.
+# PR 31, the same call; flash_fwd 0.5175 in every row, then the backward
+# (my chip runs, PR 31):
+#   the parent: flash_bwd_dq + flash_bwd_dkv          0.6371 + 0.8122 = 1.4493
+#   one kernel by keys, dq += dot_general(dst, k) contracting dim 0 of
+#     both, or dst.T in bf16 then a plain matmul (the same lowering)  1.0934
+#   the same, dq^T = k^T dst accumulated [W, T], turned once a head  1.0879
+#   this file: dst turned round in float32, then cast                1.0786
+#   the same with the dq product left out (no candidate: the floor)  0.8318
+# Four matmuls cost 0.208 each, so five cannot go under 1.04; the one
+# that wants ds in the other orientation costs 0.247 with its T^2-sized
+# transposes: they hide behind the MXU all but 0.04 ms.
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 _AUTO_BLOCK = 1024              # streamed major block, rows
@@ -354,12 +382,16 @@ def _specs(n_head, g, d):
     lanes."""
     hb = n_head // g
 
-    def rows(block, axis):
-        return pl.BlockSpec((1, block, g * d),
-                            lambda *s: (s[0] // hb, s[axis], s[0] % hb))
+    def rows(block, axis=None):
+        return pl.BlockSpec(
+            (1, block, g * d),
+            lambda *s: (s[0] // hb, 0 if axis is None else s[axis],
+                        s[0] % hb))
 
-    def stat(block, axis):
-        return pl.BlockSpec((g, 1, block), lambda *s: (s[0], 0, s[axis]))
+    def stat(block, axis=None):
+        return pl.BlockSpec(
+            (g, 1, block),
+            lambda *s: (s[0], 0, 0 if axis is None else s[axis]))
 
     return rows, stat
 
@@ -404,57 +436,107 @@ def _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
 
 
 # --------------------------------------------------------------------------
-# backward kernels. p is recomputed per tile from the saved LSE. dq also
-# makes delta = rowsum(dy * o) over each head's lanes [- dlse], from the
-# rows of dy and o it holds anyway (float32, as XLA summed it: but XLA
-# wants T minor for a [B*H, 1, T] result and copies dy and o whole into
-# that layout first), and hands it to dk/dv as the row statistic lse is.
-# Both statistics are rows [1, Bq] a head: dk/dv, which holds the scores
-# transposed, broadcasts them down the sublanes as they are; dq turns
-# lse into a column once a q block.
+# backward kernels. p is recomputed per tile from the saved LSE, and
+# delta = rowsum(dy * o) over each head's lanes [- dlse] is made INSIDE a
+# kernel from the rows of dy and o it holds anyway (float32, as XLA
+# summed it: but XLA wants T minor for a [B*H, 1, T] result and copies dy
+# and o whole into that layout first). Both statistics are rows [1, T] a
+# head: a kernel that holds the scores transposed broadcasts them down
+# the sublanes as they are.
+#
+# Which backward runs follows from the blocks alone (_backward_of). Where
+# one block holds all of T, ONE kernel, flash_bwd: a panel's s, p, dp and
+# ds are computed once and dq, dk and dv all come out of them, five
+# matmuls a tile. Streamed (several q or key blocks), the two kernels
+# below: flash_bwd_dq walks a q block's keys and flash_bwd_dkv a key
+# block's queries, each recomputing s and dp (seven matmuls for five
+# useful): fused, dq would have to be held across key blocks.
+def _delta(dy_ref, o_ref, dlse_ref, a, d, g):
+    """Column [rows, 1] of head `a`'s delta for the block's rows, and
+    where the head's lanes are in a [rows, W] tile. The lse output's
+    cotangent folds in: d lse_i / d s_ij = p_ij, so
+    ds = p * (dp - delta') with delta' = delta - dlse."""
+    dy = dy_ref[0]
+    mine = _lanes(dy.shape, a, d, g)
+    delta = jnp.sum(_only(dy, mine).astype(jnp.float32)
+                    * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+    if dlse_ref is not None:
+        delta = delta - dlse_ref[a].T
+    return delta, mine
+
+
+def _bwd_fused_kernel(*refs, causal, scale, t, tile, d, g, has_dlse):
+    """grid (B * H / g,): all of T, one block of heads. Walks by keys with
+    the scores transposed [tk, tq], as flash_bwd_dkv does, so that
+    dv = p^T dy and dk = ds^T q are plain matmuls that finish inside
+    their panel; the one product that wants the other orientation,
+    dq[rows] += ds k, takes ds^T turned round in float32 on its way to
+    the MXU (the transpose hides behind the matmuls: the product costs
+    what a plain one does, PERF.md section 6, PR 31) and accumulates
+    across panels in float32 scratch. delta never leaves VMEM."""
+    q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
+    dlse_ref = refs[6] if has_dlse else None
+    dq_ref, dk_ref, dv_ref, dq_s, delta_s = refs[6 + has_dlse:]
+
+    def head(a):
+        delta, all_mine = _delta(dy_ref, o_ref, dlse_ref, a, d, g)
+        delta_s[...] = delta.T                       # [T, 1] -> [1, T]
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+        def panel(cols, segments):
+            k = k_ref[0, cols, :]
+            mine = _lanes(k.shape, a, d, g)
+            kk, v = _only(k * scale, mine), _only(v_ref[0, cols, :], mine)
+            dk = dv = 0.0
+            for rows, off in segments:
+                q = q_ref[0, rows, :]
+                dy = dy_ref[0, rows, :]
+                st = _dot(kk, q, _NT)                # [tk, tq]
+                if off is not None:
+                    st = _causal(st, off, 1)
+                pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
+                dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
+                dst = pt * (_dot(v, dy, _NT) - delta_s[:, rows])
+                dk = dk + _dot(dst.astype(q.dtype), q, _NN)
+                # the other heads' lanes of k give lanes of dq that the
+                # last store drops
+                dq_s[rows] = dq_s[rows] + _dot(dst.T.astype(k.dtype), k, _NN)
+            whole = (0, cols, slice(None))
+            _put(dk_ref, whole, (dk * scale).astype(dk_ref.dtype), mine)
+            _put(dv_ref, whole, dv.astype(dv_ref.dtype), mine)
+
+        _walk(panel, 0, 0, causal, t, t, tile, by_keys=True)
+        _put(dq_ref, (0, slice(None), slice(None)),
+             (dq_s[...] * scale).astype(dq_ref.dtype), all_mine)
+
+    _each_head(g, head)
+
+
 def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k, tile, nq, nk, d,
                    g, has_dlse):
     q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
     dlse_ref = refs[6] if has_dlse else None
-    dq_ref, delta_ref = refs[6 + has_dlse:8 + has_dlse]
+    dq_ref, delta_ref, acc_s, lse_s, delta_s = refs[6 + has_dlse:]
     i, j = _block_ids(nq, nk)
-    if nk > 1:
-        acc_s, lse_s, delta_s = refs[8 + has_dlse:]
 
-        @pl.when(j == 0)
-        def _init():
-            acc_s[:] = jnp.zeros_like(acc_s)
+    @_when(j == 0)
+    def _init():
+        acc_s[:] = jnp.zeros_like(acc_s)
 
     def head(a):
-        def stats(rows, dy):
-            """Columns [tq, 1] of the head's lse and delta (dy: its lanes
-            alone); delta's row goes out."""
-            delta = jnp.sum(dy.astype(jnp.float32)
-                            * o_ref[0, rows, :].astype(jnp.float32),
-                            axis=1, keepdims=True)
-            if has_dlse:
-                # lse output cotangent: d lse_i / d s_ij = p_ij, so it
-                # folds into the shared ds = p * (dp - delta') term with
-                # delta' = delta - dlse
-                delta = delta - dlse_ref[a, :, rows].T
-            delta_ref[a, :, rows] = delta.T          # [tq, 1] -> [1, tq]
-            return lse_ref[a, :, rows].T, delta
-
-        if nk > 1:
-            @pl.when(j == 0)
-            def _columns():                          # once a q block
-                dy = dy_ref[0]
-                lse_s[a], delta_s[a] = stats(
-                    slice(None), _only(dy, _lanes(dy.shape, a, d, g)))
+        @_when(j == 0)
+        def _columns():
+            # once a q block: lse turned into a column, delta made as
+            # one; delta's row goes out to flash_bwd_dkv
+            delta, _ = _delta(dy_ref, o_ref, dlse_ref, a, d, g)
+            delta_ref[a] = delta.T                   # [tq, 1] -> [1, tq]
+            lse_s[a], delta_s[a] = lse_ref[a].T, delta
 
         def panel(rows, segments):
             q = q_ref[0, rows, :] * scale
             mine = _lanes(q.shape, a, d, g)
             q, dy = _only(q, mine), _only(dy_ref[0, rows, :], mine)
-            if nk == 1:     # the rows' only visit
-                lse, delta = stats(rows, dy)
-            else:
-                lse, delta = lse_s[a, rows], delta_s[a, rows]
+            lse, delta = lse_s[a, rows], delta_s[a, rows]
             acc = 0.0
             for cols, off in segments:
                 kk = k_ref[0, cols, :]
@@ -465,33 +547,26 @@ def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k, tile, nq, nk, d,
                 dp = _dot(dy, v_ref[0, cols, :], _NT)
                 ds = p * (dp - delta)
                 acc = acc + _dot(ds.astype(kk.dtype), kk, _NN)  # [tq, W]
-            if nk == 1:
-                _put(dq_ref, (0, rows, slice(None)),
-                     (acc * scale).astype(dq_ref.dtype), mine)
-            else:
-                acc_s[rows] = acc_s[rows] + _only(acc, mine)
+            acc_s[rows] = acc_s[rows] + _only(acc, mine)
 
         _walk(panel, i, j, causal, block_q, block_k, tile)
 
     _each_head(g, head)
 
-    if nk > 1:
-        @pl.when(j == nk - 1)
-        def _final():
-            dq_ref[0] = (acc_s[:] * scale).astype(dq_ref.dtype)
+    @_when(j == nk - 1)
+    def _final():
+        dq_ref[0] = (acc_s[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *scratch, causal, scale, block_q,
+                    dk_ref, dv_ref, dk_s, dv_s, *, causal, scale, block_q,
                     block_k, tile, nq, nk, d, g):
     i, jj = _block_ids(nq, nk, by_keys=True)    # q blocks innermost here
-    if nq > 1:
-        dk_s, dv_s = scratch
 
-        @pl.when(i == 0)
-        def _init():
-            dk_s[:] = jnp.zeros_like(dk_s)
-            dv_s[:] = jnp.zeros_like(dv_s)
+    @_when(i == 0)
+    def _init():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
 
     def head(a):
         def panel(cols, segments):
@@ -515,23 +590,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
                 dpt = _dot(v, dy, _NT)
                 dst = pt * (dpt - delta_ref[a, :, rows])
                 dk = dk + _dot(dst.astype(q.dtype), q, _NN)
-            if nq == 1:
-                whole = (0, cols, slice(None))
-                _put(dk_ref, whole, (dk * scale).astype(dk_ref.dtype), mine)
-                _put(dv_ref, whole, dv.astype(dv_ref.dtype), mine)
-            else:
-                dk_s[cols] = dk_s[cols] + _only(dk, mine)
-                dv_s[cols] = dv_s[cols] + _only(dv, mine)
+            dk_s[cols] = dk_s[cols] + _only(dk, mine)
+            dv_s[cols] = dv_s[cols] + _only(dv, mine)
 
         _walk(panel, i, jj, causal, block_q, block_k, tile, by_keys=True)
 
     _each_head(g, head)
 
-    if nq > 1:
-        @pl.when(i == nq - 1)
-        def _final():
-            dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
-            dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+    @_when(i == nq - 1)
+    def _final():
+        dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+
+def _backward_blocks(t, w, block_q, block_k):
+    """The backward's major blocks. VMEM guard: the bwd kernels hold six
+    [block, w] operands, double buffered, plus float32 accumulators of
+    the same shape; with w > 128 at 1024-row blocks that passes the 16 MB
+    scoped-vmem limit. Clamp the BACKWARD blocks only. The clamp must
+    keep dividing T (a non-divisor block would silently drop query rows
+    from dq/dk/dv): shrink to the largest divisor of the incoming block,
+    which also divides T."""
+    bq, bk = min(block_q, t), min(block_k, t)
+    if w > 128:
+        bq, bk = _largest_divisor(bq, 512), _largest_divisor(bk, 512)
+    return bq, bk
+
+
+def _backward_of(t, w, block_q, block_k):
+    """"fused" where the backward's blocks hold all of T, else
+    "two_kernels": what _bwd_pallas runs and the lowering counter says."""
+    return ("fused" if _backward_blocks(t, w, block_q, block_k) == (t, t)
+            else "two_kernels")
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
@@ -542,17 +632,7 @@ def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
     d = hd // n_head
     g = heads_per_block(n_head, d)
     w = g * d
-    bq = min(block_q, t)
-    bk = min(block_k, t)
-    # VMEM guard: the bwd kernels hold six [block, w] operands, double
-    # buffered, plus float32 accumulators of the same shape; with w > 128
-    # at 1024-row blocks that passes the 16 MB scoped-vmem limit. Clamp the
-    # BACKWARD blocks only. The clamp must keep dividing T (a non-divisor
-    # block would silently drop query rows from dq/dk/dv): shrink to the
-    # largest divisor of the incoming block, which also divides T.
-    if w > 128:
-        bq = _largest_divisor(bq, 512)
-        bk = _largest_divisor(bk, 512)
+    bq, bk = _backward_blocks(t, w, block_q, block_k)
     nq, nk = t // bq, t // bk
     tile = _tile(bq, _TILE)
     stats = [lse.reshape(b * n_head, 1, t)]
@@ -560,6 +640,21 @@ def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
         stats.append(dlse.astype(jnp.float32).reshape(b * n_head, 1, t))
     rows, stat = _specs(n_head, g, d)
     bthd = jax.ShapeDtypeStruct((b, t, hd), q.dtype)
+
+    if nq == nk == 1:       # _backward_of's "fused"
+        return pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, causal=causal, scale=scale,
+                              t=t, tile=tile, d=d, g=g,
+                              has_dlse=dlse is not None),
+            grid=(b * n_head // g,),
+            in_specs=[rows(t)] * 5 + [stat(t)] * len(stats),
+            out_specs=[rows(t)] * 3,
+            out_shape=[bthd] * 3,
+            scratch_shapes=[pltpu.VMEM((t, w), jnp.float32),
+                            pltpu.VMEM((1, t), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd",
+        )(q, k, v, dy, o, *stats)
 
     dq, delta3 = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
@@ -573,8 +668,7 @@ def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
                                               jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32),
                         pltpu.VMEM((g, bq, 1), jnp.float32),
-                        pltpu.VMEM((g, bq, 1), jnp.float32)]
-        if nk > 1 else [],
+                        pltpu.VMEM((g, bq, 1), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, dy, o, *stats)
@@ -590,8 +684,7 @@ def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
         out_specs=[rows(bk, 1), rows(bk, 1)],
         out_shape=[bthd, bthd],
         scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
-                        pltpu.VMEM((bk, w), jnp.float32)]
-        if nq > 1 else [],
+                        pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, dy, stats[0], delta3)
@@ -681,9 +774,9 @@ _REG = _metrics.registry()
 _LOWERINGS = _REG.counter(
     "ptpu_flash_lowerings_total",
     "flash attention dispatches at trace time (one a lowering of the op, "
-    "none a step): the path taken, the layout of the entry called and "
-    "the heads a kernel block holds",
-    ("path", "entry", "heads_per_block"))
+    "none a step): the path taken, the layout of the entry called, the "
+    "heads a kernel block holds and the backward its gradient would run",
+    ("path", "entry", "heads_per_block", "backward"))
 
 
 def _resolve_path(q, scale, block_q, block_k, force):
@@ -745,8 +838,10 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     path, scale, bq, bk = _resolve_path(
         jax.ShapeDtypeStruct((b, n_head, t, d), q.dtype), scale, block_q,
         block_k, force)
-    _LOWERINGS.inc(path=path, entry=entry,
-                   heads_per_block=str(heads_per_block(n_head, d)))
+    g = heads_per_block(n_head, d)
+    _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
+                   backward="none" if path == "dense"
+                   else _backward_of(t, g * d, bq, bk))
     if path == "dense":
         out, lse = _dense_lse(*(heads_first(x, n_head) for x in (q, k, v)),
                               causal, scale)

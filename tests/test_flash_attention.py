@@ -578,11 +578,137 @@ def test_sp_attention_of_rank_3_and_of_rank_4_agree():
     np.testing.assert_allclose(np.asarray(got4), np.asarray(ref), atol=1e-5)
 
 
+# -- one backward kernel where a block holds all of T (PR 31) -----------------
+def _pallas_names(jaxpr):
+    """Names of the pallas_call equations of a jaxpr, sub-jaxprs
+    included, in order."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("h, d, g", [
+    pytest.param(4, 64, 2, id="H4-D64-g2"),
+    pytest.param(2, 128, 1, id="H2-D128-g1"),
+    pytest.param(3, 64, 3, id="H3-D64-all_of_H")])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("with_dlse", [False, True], ids=["out", "out_lse"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_fused_backward_matches_dense(monkeypatch, dtype, with_dlse, causal,
+                                      h, d, g):
+    """dq, dk, dv of the ONE backward kernel (all of T 512 in a block,
+    four panels of 128 keys, dq accumulated across them in scratch)
+    against dense float32 math, with and without an lse cotangent
+    folded into the delta the kernel makes and keeps."""
+    monkeypatch.setattr(FA, "_TILE", 128)
+    monkeypatch.setattr(FA, "_PANEL_SCORES", 128 * 512)
+    assert FA.heads_per_block(h, d) == g
+    t = 512
+    q, k, v, dy, dlse = _bthd_inputs(h, d, dtype, t=t, seed=8)
+    scale = d ** -0.5
+
+    def loss(att):
+        def f(q, k, v):
+            o, lse = att(q, k, v)
+            extra = (lse * dlse).sum() if with_dlse else 0.0
+            return (_f32(o) * _f32(dy)).sum() + extra
+        return f
+
+    def ref(q, k, v):
+        o, lse = FA._dense_lse(*(FA.heads_first(x, h) for x in (q, k, v)),
+                               causal, scale)
+        return FA.heads_last(o), lse
+
+    def got(q, k, v):
+        if with_dlse:
+            return FA.flash_bthd_lse(q, k, v, h, causal=causal,
+                                     force="interpret")
+        return FA.flash_bthd(q, k, v, h, causal=causal,
+                             force="interpret"), None
+
+    grad = jax.grad(loss(got), (0, 1, 2))
+    assert _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr) \
+        == ["flash_fwd", "flash_bwd"]
+    g_ref = jax.grad(loss(ref), (0, 1, 2))(_f32(q), _f32(k), _f32(v))
+    for name, a, b in zip(("dq", "dk", "dv"), grad(q, k, v), g_ref):
+        assert a.shape == q.shape and a.dtype == dtype
+        _assert_close(name, a, b, 5e-3 if dtype == jnp.float32 else 2e-2)
+
+
+# sha256 (first 16 hex digits) of dq, dk, dv as float32 bytes, from
+# `_streamed_grads` run at commit 88444d7, PR 31's parent, whose
+# flash_bwd_dq / flash_bwd_dkv still had their one-block branches. The
+# kernels run in interpret mode: XLA's CPU dots, the same on a machine
+# whatever the tree.
+_PARENT_STREAMED = {
+    ("float32", False): ("36def30fa06eecf5", "2fece4931d993ef2", "8d4fe080df03d3c1"),
+    ("float32", True): ("42089e908b2c1580", "f74aa46134dec4ec", "8d4fe080df03d3c1"),
+    ("bfloat16", False): ("ff8e2ad18a5bdfe6", "0eca2ce7910b981a", "a808b4560d51ddbc"),
+    ("bfloat16", True): ("e37a800128f83e9e", "78c549a2470053da", "a808b4560d51ddbc"),
+}
+
+
+def _streamed_grads(dtype, with_dlse):
+    """(the gradient function, its arguments) at T 512 in 2 x 2 major
+    blocks of 256, two heads of 64 to a block, causal."""
+    h, d = 4, 64
+    q, k, v, dy, dlse = _bthd_inputs(h, d, dtype, t=512, b=2, seed=9)
+
+    def f(q, k, v):
+        kw = dict(causal=True, force="interpret", block_q=256, block_k=256)
+        if not with_dlse:
+            return (_f32(FA.flash_bthd(q, k, v, h, **kw)) * _f32(dy)).sum()
+        o, lse = FA.flash_bthd_lse(q, k, v, h, **kw)
+        return (_f32(o) * _f32(dy)).sum() + (lse * dlse).sum()
+
+    return jax.grad(f, (0, 1, 2)), (q, k, v)
+
+
+@pytest.mark.parametrize("with_dlse", [False, True], ids=["out", "out_lse"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_streamed_backward_is_the_parents_bit_for_bit(dtype, with_dlse):
+    """Several blocks a sequence: the backward is still flash_bwd_dq
+    then flash_bwd_dkv, and every bit of dq, dk, dv is what PR 31's
+    parent gave on these inputs."""
+    import hashlib
+    grad, args = _streamed_grads(dtype, with_dlse)
+    assert _pallas_names(jax.make_jaxpr(grad)(*args).jaxpr) \
+        == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    got = tuple(hashlib.sha256(np.asarray(_f32(g)).tobytes()
+                               ).hexdigest()[:16] for g in grad(*args))
+    case = (jnp.dtype(dtype).name, with_dlse)
+    assert got == _PARENT_STREAMED[case], (case, got)
+
+
+@pytest.mark.parametrize("t, w, block, want", [
+    (2048, 128, 2048, "fused"),          # the benchmark's cell
+    (512, 128, 1024, "fused"),           # a block no longer than T
+    (4096, 128, 1024, "two_kernels"),    # OLMoE's: streamed
+    (2048, 128, 1024, "two_kernels"),    # float32 at T 2048
+    (512, 192, 512, "fused"),            # all of H*D, 192 lanes
+    (1024, 192, 1024, "two_kernels"),    # the same clamped to 512 rows
+])
+def test_backward_follows_from_the_blocks(t, w, block, want):
+    """One kernel exactly where the backward's blocks, after the VMEM
+    clamp of wide blocks, hold all of T: no flag decides it."""
+    assert FA._backward_of(t, w, block, block) == want
+    bq, bk = FA._backward_blocks(t, w, block, block)
+    assert t % bq == 0 and t % bk == 0
+
+
 def test_lowering_counter_says_which_path_engaged():
-    """`ptpu_flash_lowerings_total{path, entry, heads_per_block}`: one
-    count a lowering of the fused model's attention (the forward's
-    trace; none a step), `dense` off the chip; the [B, H, T, D]
-    wrappers count as `bhtd`."""
+    """`ptpu_flash_lowerings_total{path, entry, heads_per_block,
+    backward}`: one count a lowering of the fused model's attention (the
+    forward's trace; none a step), `dense` off the chip, where no
+    backward kernel will run; the [B, H, T, D] wrappers count as `bhtd`;
+    `backward` says which backward the lowering's gradient takes: the
+    one fused kernel where a block holds all of T, else the two."""
     import paddle_tpu as fluid
     n_layer = 3
     prog, startup, cost, _ = _fused_lm(True, n_layer=n_layer)
@@ -594,7 +720,8 @@ def test_lowering_counter_says_which_path_engaged():
     count = FA._LOWERINGS
     # d_model 32 over 4 heads: D 8, sixteen heads would fill 128 lanes,
     # four do not: all of H*D as one block, four heads to it
-    labels = dict(path="dense", entry="bthd", heads_per_block="4")
+    labels = dict(path="dense", entry="bthd", heads_per_block="4",
+                  backward="none")
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
@@ -604,10 +731,13 @@ def test_lowering_counter_says_which_path_engaged():
         exe.run(prog, feed=feeds, fetch_list=[cost])     # a cached step
         assert count.value(**labels) - before == lowered
     assert lowered == n_layer
-    q, k, v = _qkv(b=1, h=2, t=128, d=64)
-    was = count.value(path="interpret", entry="bhtd", heads_per_block="2")
-    FA.flash_attention(q, k, v, causal=True, force="interpret")
-    assert count.value(path="interpret", entry="bhtd",
-                       heads_per_block="2") == was + 1
+    q, k, v = _qkv(b=1, h=2, t=256, d=64)
+    for block, backward in ((None, "fused"), (128, "two_kernels")):
+        labels = dict(path="interpret", entry="bhtd", heads_per_block="2",
+                      backward=backward)
+        was = count.value(**labels)
+        FA.flash_attention(q, k, v, causal=True, force="interpret",
+                           block_q=block, block_k=block)
+        assert count.value(**labels) == was + 1
     assert "ptpu_flash_lowerings_total" in \
         fluid.monitor.metrics.registry().render_prometheus()

@@ -40,9 +40,12 @@ def test_lse_matches_dense(causal):
                                atol=2e-3, rtol=2e-2)
 
 
-def test_lse_cotangent_matches_dense():
-    # loss uses BOTH outputs so the dlse→ds backward fold is exercised
-    q, k, v = _qkv(t=128, seed=1)
+@pytest.mark.parametrize("t", [128, 256], ids=["fused", "two_kernels"])
+def test_lse_cotangent_matches_dense(t):
+    # loss uses BOTH outputs so the dlse→ds backward fold is exercised:
+    # in the one backward kernel where a shard's T is one block of 128
+    # (delta made and kept inside it), in flash_bwd_dq where it is two
+    q, k, v = _qkv(t=t, seed=1)
 
     def loss_fn(att):
         def f(q, k, v):
@@ -96,8 +99,11 @@ def test_ring_with_kernel_forced_matches_dense(monkeypatch):
                                atol=3e-3, rtol=3e-2)
 
 
-def test_ring_grads_with_kernel_forced(monkeypatch):
-    q, k, v = _qkv(t=256, seed=2)
+@pytest.mark.parametrize("t", [256, 512], ids=["fused", "two_kernels"])
+def test_ring_grads_with_kernel_forced(monkeypatch, t):
+    # a shard of 128 rows is one kernel block: the fused backward, with
+    # the merge's non-zero lse cotangent; one of 256 is two: streamed
+    q, k, v = _qkv(t=t, seed=2)
     mesh = _sp_mesh()
 
     orig = FA.flash_attention_lse

@@ -290,15 +290,18 @@ def _pallas_names(jaxpr):
 def test_kernels_carry_their_names():
     from paddle_tpu.ops.flash_attention import flash_attention
     from paddle_tpu.ops.paged_attention import paged_attention
-    q = jnp.ones((1, 2, 128, 64), jnp.float32)
+    q = jnp.ones((1, 2, 256, 64), jnp.float32)
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True,
-                               force="interpret").sum()
+    # all of T in one block: one backward kernel; streamed: the two
+    for block, names in ((None, ["flash_bwd", "flash_fwd"]),
+                         (128, ["flash_bwd_dkv", "flash_bwd_dq",
+                                "flash_fwd"])):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, force="interpret",
+                                   block_q=block, block_k=block).sum()
 
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-    assert sorted(set(_pallas_names(jaxpr.jaxpr))) == [
-        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+        assert sorted(set(_pallas_names(jaxpr.jaxpr))) == names
 
     pool = jnp.ones((8, 16, 2, 16), jnp.float32)
     jaxpr = jax.make_jaxpr(
