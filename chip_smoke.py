@@ -9,6 +9,15 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           ([4, 2048, 1024] bf16, 16 heads of 64, causal: one block
           holds all of T, so the ONE kernel flash_bwd): dq, dk, dv
           against dense float32 math on the same inputs
+  gqa     the streamed kernels under grouped key/value heads and the
+          block-granular mask at the block-diffusion cell's shape (one
+          sequence of [4096, 32 x 128] reading [4096, 4 x 128], blocks
+          of 4, both variants): dq, dk, dv against dense float32 math,
+          the first block's wholly masked rows finite and weighed out
+  experts the dropless expert layer (16,384 rows over 16 held of 128
+          experts, top-8): output and gradients against every held
+          expert evaluated densely, and nothing dropped when every row
+          chooses held experts
   train   T.transformer_lm -> Adam.minimize -> amp.enable_amp ->
           Executor(TPUPlace(0)); 5 steps on one batch; loss ~ ln(vocab)
           and falling; the flash kernel is in the compiled step
@@ -34,6 +43,7 @@ chip pass.
 """
 
 import argparse
+import functools
 import json
 import math
 import sys
@@ -138,6 +148,10 @@ def _lm_batch(cfg, seed):
 # bf16 gradients round at 2^-9 of their largest value; the cell's shape
 # read 3.0e-3 to 3.9e-3 on the chip (PERF.md section 6, PR 31)
 FLASH_GRAD_TOL = 2e-2
+# the expert layer's bf16 matmuls (three in a row, float32 sums) against
+# the float32 layer: readings are set beside the phase's log line in
+# PERF.md section 6, PR 32
+EXPERT_TOL = 3e-2
 
 
 def phase_flash(seed, rehearse):
@@ -178,6 +192,125 @@ def phase_flash(seed, rehearse):
         # the forward re-run and ONE backward kernel
         assert "flash_bwd" in text and "flash_bwd_dq" not in text, \
             "all of T in one block did not take the fused backward"
+
+
+def phase_gqa(seed, rehearse):
+    """The kernels of the block-diffusion step (ISSUE 32): 32 query
+    heads of 128 reading 4 key/value heads, T 4096 streamed, under
+    `mask_block` 4 with and without `strict`; gradients against dense
+    float32 math with the mask written out. Under `strict` the first
+    block's rows see nothing: their output must be finite, their lse
+    -1e30, and weighed out they must leave every gradient right."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+    b, t, h, hkv, d = (2, 256, 4, 2, 128) if rehearse else (
+        2, 4096, 32, 4, 128)
+    rng = np.random.RandomState(seed)
+    mk = lambda n: jnp.asarray(rng.randn(b, t, n * d) * 0.5, jnp.bfloat16)
+    q, k, v, dy = mk(h), mk(hkv), mk(hkv), mk(h)
+    f32 = lambda x: x.astype(jnp.float32)
+    for strict in (False, True):
+        kw = dict(causal=True, n_kv_head=hkv, mask_block=4, strict=strict)
+
+        def loss(attend, dy, q, k, v):
+            o, lse = attend(q, k, v)
+            seen = (lse > -1e29).transpose(0, 2, 1)[..., None]   # [b,T,H,1]
+            o = jnp.where(seen, f32(o).reshape(-1, t, h, d), 0.0)
+            return (o * f32(dy).reshape(-1, t, h, d)).sum() \
+                + jnp.where(lse > -1e29, lse, 0.0).sum()
+
+        def dense(q, k, v):
+            o, lse = fa._dense_lse(
+                fa.heads_first(q, h), fa.heads_first(k, hkv),
+                fa.heads_first(v, hkv), True, d ** -0.5, (2, int(strict)))
+            return fa.heads_last(o), lse
+
+        t0 = time.perf_counter()
+        kernel = lambda q, k, v: fa.flash_bthd_lse(q, k, v, h, **kw)
+        out, lse = jax.jit(kernel)(q, k, v)
+        assert bool(jnp.isfinite(f32(out)).all())
+        unseen = int((lse < -1e29).sum())
+        assert unseen == (4 * h * b if strict else 0), unseen
+        grad = jax.jit(jax.grad(functools.partial(loss, kernel, dy),
+                                (0, 1, 2))).lower(q, k, v).compile()
+        got, text = grad(q, k, v), grad.as_text()
+        # the loss is a sum over sequences, so the dense gradients are
+        # made a sequence at a time (32 heads of 4096^2 float32 scores)
+        dense_grad = jax.jit(jax.grad(functools.partial(loss, dense),
+                                      (1, 2, 3)))
+        with jax.default_matmul_precision("highest"):
+            rows = [dense_grad(dy[r:r + 1], f32(q[r:r + 1]),
+                               f32(k[r:r + 1]), f32(v[r:r + 1]))
+                    for r in range(b)]
+        want = [jnp.concatenate(parts) for parts in zip(*rows)]
+        errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+                for a, r in zip(got, want)]
+        log("[gqa] q [%d, %d, %d] k/v [%d, %d, %d] bf16, blocks of 4%s: dq "
+            "%.3e dk %.3e dv %.3e from the dense float32 gradients "
+            "(%.1f s); %d rows see nothing" % (
+                b, t, h * d, b, t, hkv * d, ", strict" if strict else "",
+                *errs, time.perf_counter() - t0, unseen))
+        assert max(errs) <= FLASH_GRAD_TOL, errs
+        if not rehearse:
+            assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+
+
+def phase_experts(seed, rehearse):
+    """The dropless expert layer against every held expert evaluated
+    densely (float32, `highest`), output and gradients, under the
+    router's own choices and with every row sent to held experts."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import moe
+    n, d, f, e, held, k = (256, 64, 32, 16, 4, 4) if rehearse else (
+        16384, 2048, 768, 128, 16, 8)
+    rng = np.random.RandomState(seed)
+    mk = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
+    x = mk(n, d)
+    wr = mk(d, e) * 0.02
+    wg, wu, wd = (mk(held, d, f) * d ** -0.5, mk(held, d, f) * d ** -0.5,
+                  mk(held, f, d) * f ** -0.5)
+    bf16 = lambda a: a.astype(jnp.bfloat16)
+
+    def layer(x, wr, wg, wu, wd):
+        return moe.routed_experts(x, wr, bf16(wg), bf16(wu), bf16(wd), e,
+                                  0, k)
+
+    def dense(x, wr, wg, wu, wd):
+        _, w, idx = moe.route(x, wr, k, True)
+        out = 0.0
+        for i in range(held):
+            w_i = jnp.sum(jnp.where(idx == i, w, 0.0), 1)[:, None]
+            out = out + w_i * ((jax.nn.silu(x @ wg[i]) * (x @ wu[i])) @ wd[i])
+        return out
+
+    for routing in ("the router's own", "every row on held experts"):
+        if routing != "the router's own":
+            x = x.at[:, 0].set(8.0)
+            wr = (wr * 0.01).at[0, :k].set(5.0)
+        t0 = time.perf_counter()
+        out, _, counts, _ = jax.jit(layer)(x, wr, wg, wu, wd)
+        pairs = int(counts[:held].sum())
+        sq = lambda fn: lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum()
+        got = jax.jit(jax.grad(sq(lambda *a: layer(*a)[0]),
+                               (0, 2, 3, 4)))(x, wr, wg, wu, wd)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(dense)(x, wr, wg, wu, wd)
+            want = jax.jit(jax.grad(sq(dense), (0, 2, 3, 4)))(
+                x, wr, wg, wu, wd)
+        rel = lambda a, r: float(jnp.max(jnp.abs(a.astype(jnp.float32) - r))
+                                 / jnp.max(jnp.abs(r)))
+        errs = [rel(out, ref)] + [rel(a, r) for a, r in zip(got, want)]
+        log("[experts] %d rows, %d of %d experts held, top-%d, %s: %d pairs "
+            "on held experts; out %.3e dx %.3e dgate %.3e dup %.3e ddown "
+            "%.3e from the dense float32 layer (%.1f s)" % (
+                n, held, e, k, routing, pairs, *errs,
+                time.perf_counter() - t0))
+        assert int(counts.sum()) == n * k
+        if routing != "the router's own":
+            assert pairs == n * k, "a held pair was dropped"
+        assert max(errs) <= EXPERT_TOL, errs
 
 
 # --------------------------------------------------------------------------
@@ -564,6 +697,8 @@ def main():
         phase_multichip(cfg, args.seed, args.rehearse)
     else:
         phase_flash(args.seed, args.rehearse)
+        phase_gqa(args.seed, args.rehearse)
+        phase_experts(args.seed, args.rehearse)
         phase_train(cfg, args.seed, args.rehearse)
         phase_serve(cfg, args.seed, args.rehearse)
     log("[cache] %d entries in %s at end"

@@ -555,6 +555,10 @@ class Program:
 _TEST_MODE_OPS = {
     "dropout": ("is_test",),
     "batch_norm": ("is_test",),
+    # their persistable counters (the step, the rows an expert took) count
+    # train steps: a for_test clone reads them and leaves them
+    "block_diffusion_noise": ("is_test",),
+    "routed_experts": ("is_test",),
 }
 
 

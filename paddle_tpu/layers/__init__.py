@@ -17,11 +17,14 @@ from .nn import (  # noqa: F401
     layer_norm, matmul, mean, one_hot, reduce_max, reduce_mean, reduce_min,
     reduce_prod, reduce_sum, softmax, softmax_with_cross_entropy,
     square_error_cost, topk,
+    block_diffusion_attention, block_diffusion_noise, rms_norm, rope,
+    silu_mul,
 )
 from .ops import *  # noqa: F401,F403
 from .math_ops import scale  # noqa: F401
 from .parallel_layers import (  # noqa: F401
-    pipelined_decoder_stack, sequence_parallel_attention, sparse_moe,
+    pipelined_decoder_stack, routed_experts, sequence_parallel_attention,
+    sparse_moe,
 )
 from .sequence_layers import *  # noqa: F401,F403
 from .compat import *  # noqa: F401,F403

@@ -453,3 +453,93 @@ def cos_sim(X, Y, name=None):
     helper.append_op(type="cos_sim", inputs={"X": [X], "Y": [Y]},
                      outputs={"Out": [out], "XNorm": [xn], "YNorm": [yn]})
     return out
+
+
+# ---------------------------------------------------------------------------
+# the pre-norm block's pieces and block-diffusion training (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+def rms_norm(input, epsilon=1e-6, groups=1, param_attr=None, name=None):
+    """RMSNorm over the last dimension with a learned weight, computed
+    in float32: ``x * rsqrt(mean(x^2) + eps) * w``. `groups` G > 1
+    normalises each of G equal parts of the last dimension with ONE
+    weight of their size: QK-norm over the heads of a projection's
+    output ``[B, T, H * D]`` with G = H."""
+    from ..initializer import ConstantInitializer
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    scale = helper.create_parameter(
+        helper.param_attr, shape=[input.shape[-1] // groups],
+        dtype="float32", default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype,
+                                                    shape=input.shape)
+    helper.append_op(type="rms_norm", inputs={"X": [input],
+                                              "Scale": [scale]},
+                     outputs={"Out": [out]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def rope(input, n_head, theta=10000.0, wrap=0, name=None):
+    """Rotary position embedding (rotate-half form) of ``[B, T, H * D]``
+    by each row's position: its index in T, modulo `wrap` where given
+    (two halves of T that share positions 0..wrap-1)."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype,
+                                                    shape=input.shape)
+    helper.append_op(type="rope", inputs={"X": [input]},
+                     outputs={"Out": [out]},
+                     attrs={"n_head": int(n_head), "theta": float(theta),
+                            "wrap": int(wrap)})
+    return out
+
+
+def silu_mul(x, y, name=None):
+    """``silu(x) * y``: the gated FFN's hidden activation."""
+    helper = LayerHelper("silu_mul", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    helper.append_op(type="silu_mul", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def block_diffusion_noise(tokens, block, mask_id, name=None):
+    """Block-diffusion noise of ``tokens`` [B, L]: ``(noised, weight,
+    step)``. Each block of `block` tokens draws t ~ U(1e-3, 1) and each
+    of its tokens becomes `mask_id` with probability t; ``weight`` is
+    1/t where masked, else 0. The draw is a pure function of the salt
+    (the program's ``random_seed``, kept as a persistable variable
+    ``<name>.salt``), the persistable ``step`` counter, which every TRAIN run of the program advances (a
+    ``for_test`` clone does not), and the batch row
+    (``ops/block_diffusion.draw_noise``)."""
+    from .tensor import create_global_var
+    helper = LayerHelper("block_diffusion_noise", name=name)
+    step = create_global_var([1], 0, "int32", persistable=True,
+                             name=helper.name + ".step")
+    salt = create_global_var(
+        [1], int(helper.main_program.random_seed) % 2 ** 31, "int32",
+        persistable=True, name=helper.name + ".salt")
+    noised = helper.create_variable_for_type_inference(
+        tokens.dtype, shape=tokens.shape, stop_gradient=True)
+    weight = helper.create_variable_for_type_inference(
+        "float32", shape=tokens.shape, stop_gradient=True)
+    helper.append_op(
+        type="block_diffusion_noise",
+        inputs={"X": [tokens], "Salt": [salt], "Step": [step]},
+        outputs={"Noised": [noised], "Weight": [weight], "StepOut": [step]},
+        attrs={"block": int(block), "mask_id": int(mask_id)})
+    return noised, weight, step
+
+
+def block_diffusion_attention(q, k, v, n_head, n_kv_head, block, scale=0.0,
+                              name=None):
+    """Attention over ``[noised; clean]`` rows under the block-diffusion
+    training mask (``ops/block_diffusion.py``): q ``[B, 2L, H * D]``, k
+    and v ``[B, 2L, Hkv * D]``, query head h reading key/value head
+    ``h // (H / Hkv)``. Returns a variable of q's shape."""
+    helper = LayerHelper("block_diffusion_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    helper.append_op(
+        type="block_diffusion_attention",
+        inputs={"Q": [q], "K": [k], "V": [v]}, outputs={"Out": [out]},
+        attrs={"n_head": int(n_head), "n_kv_head": int(n_kv_head),
+               "block": int(block), "scale": float(scale)})
+    return out

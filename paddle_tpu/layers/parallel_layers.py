@@ -18,7 +18,7 @@ from .layer_helper import LayerHelper
 from ..initializer import Normal, Constant
 from ..param_attr import ParamAttr
 
-__all__ = ["sequence_parallel_attention", "sparse_moe",
+__all__ = ["sequence_parallel_attention", "sparse_moe", "routed_experts",
            "pipelined_decoder_stack"]
 
 
@@ -90,6 +90,48 @@ def sparse_moe(x, num_experts, d_inner, capacity_factor=1.25,
     if return_overflow:
         return out, aux, overflow
     return out, aux
+
+
+def routed_experts(x, num_experts, experts_held, first_expert, top_k,
+                   d_inner, norm_topk=True, name=None):
+    """One chip's share of a mixture of SiLU-gated experts over
+    ``[B, T, D]`` input, dropless (``parallel/moe.routed_experts``): a
+    float32 router over all `num_experts`, the `top_k` largest a row
+    (their weights divided by their sum where `norm_topk`), and the
+    `experts_held` experts with ids from `first_expert` computed here,
+    ``w_down(silu(w_gate x) * (w_up x))`` of width `d_inner`; what the
+    other experts would add is left out, as an expert-parallel group
+    leaves it to its other members. Returns ``(out, aux_loss, choices,
+    load)``: `aux_loss` is ``E * sum_e f_e P_e`` (scale it and add it to
+    the cost), `choices` the router's ``[B, T, top_k]`` indices, `load`
+    a persistable ``[num_experts]`` int32 count of the rows that chose
+    each expert, summed over every TRAIN run of the program. Parameters
+    are named ``<name>.router``, ``.w_gate``, ``.w_up``, ``.w_down``."""
+    helper = LayerHelper("routed_experts", name=name)
+    d = int(x.shape[-1])
+    param = lambda suffix, shape, std: helper.create_parameter(
+        ParamAttr(name="%s.%s" % (helper.name, suffix)), shape=shape,
+        dtype="float32", default_initializer=Normal(0., std))
+    router = param("router", [d, num_experts], 0.02)
+    w_gate = param("w_gate", [experts_held, d, d_inner], d ** -0.5)
+    w_up = param("w_up", [experts_held, d, d_inner], d ** -0.5)
+    w_down = param("w_down", [experts_held, d_inner, d], d_inner ** -0.5)
+    from .tensor import create_global_var
+    load = create_global_var([num_experts], 0, "int32", persistable=True,
+                             name=helper.name + ".load")
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    aux = helper.create_variable_for_type_inference("float32", shape=())
+    choices = helper.create_variable_for_type_inference(
+        "int32", shape=tuple(x.shape[:-1]) + (top_k,), stop_gradient=True)
+    helper.append_op(
+        type="routed_experts",
+        inputs={"X": [x], "RouterW": [router], "WGate": [w_gate],
+                "WUp": [w_up], "WDown": [w_down], "Load": [load]},
+        outputs={"Out": [out], "AuxLoss": [aux], "Indices": [choices],
+                 "LoadOut": [load]},
+        attrs={"first_expert": int(first_expert), "top_k": int(top_k),
+               "norm_topk": bool(norm_topk)})
+    return out, aux, choices, load
 
 
 def pipelined_decoder_stack(x, n_layer, n_head, d_inner,

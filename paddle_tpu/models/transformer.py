@@ -160,6 +160,29 @@ def _embed(tokens, vocab_size, d_model, max_len, pos_input, name):
     return layers.elementwise_add(word, pos)
 
 
+def lm_cost(logits, label, mask, vocab_size, label_smooth_eps=0.0,
+            weight=None):
+    """The masked mean of the tokens' cross-entropy: logits [B, T, V]
+    against label [B, T], each token weighed by mask [B, T] (and by
+    weight [B, T] where given: a diffusion objective's 1/t), over the
+    sum of the mask."""
+    flat_logits = layers.reshape(logits, [-1, vocab_size])
+    flat_label = layers.reshape(label, [-1, 1])
+    if label_smooth_eps:
+        smooth = layers.label_smooth(
+            layers.one_hot(flat_label, vocab_size), epsilon=label_smooth_eps)
+        cost = layers.softmax_with_cross_entropy(flat_logits, smooth,
+                                                 soft_label=True)
+    else:
+        cost = layers.softmax_with_cross_entropy(flat_logits, flat_label)
+    flat_mask = layers.reshape(mask, [-1, 1])
+    masked = layers.elementwise_mul(cost, flat_mask)
+    if weight is not None:
+        masked = layers.elementwise_mul(
+            masked, layers.reshape(weight, [-1, 1]))
+    return layers.reduce_sum(masked) / layers.reduce_sum(flat_mask)
+
+
 def make_attn_bias(mask_2d, n_head, causal=False, seq_len=None):
     """mask_2d: [B, T] 1/0 validity → additive bias [B, H, T, T]."""
     b, t = mask_2d.shape[0], mask_2d.shape[1]
@@ -205,21 +228,7 @@ def transformer_lm(vocab_size=4096, max_len=256, n_layer=4, n_head=8,
                               d_model, d_inner, dropout_rate,
                               causal=packed)
     logits = layers.fc(x, vocab_size, num_flatten_dims=2, bias_attr=False)
-
-    b, t = logits.shape[0], logits.shape[1]
-    flat_logits = layers.reshape(logits, [-1, vocab_size])
-    flat_label = layers.reshape(label, [-1, 1])
-    if label_smooth_eps:
-        smooth = layers.label_smooth(
-            layers.one_hot(flat_label, vocab_size), epsilon=label_smooth_eps)
-        cost = layers.softmax_with_cross_entropy(flat_logits, smooth,
-                                                 soft_label=True)
-    else:
-        cost = layers.softmax_with_cross_entropy(flat_logits, flat_label)
-    flat_mask = layers.reshape(mask, [-1, 1])
-    masked = layers.elementwise_mul(cost, flat_mask)
-    avg_cost = layers.reduce_sum(masked) / layers.reduce_sum(flat_mask)
-    return avg_cost, logits
+    return lm_cost(logits, label, mask, vocab_size, label_smooth_eps), logits
 
 
 def transformer(src_vocab_size=4096, trg_vocab_size=4096, max_len=64,
@@ -264,20 +273,8 @@ def transformer(src_vocab_size=4096, trg_vocab_size=4096, max_len=64,
 
     logits = layers.fc(dec, trg_vocab_size, num_flatten_dims=2,
                        bias_attr=False)
-    flat_logits = layers.reshape(logits, [-1, trg_vocab_size])
-    flat_label = layers.reshape(lbl_word, [-1, 1])
-    if label_smooth_eps:
-        smooth = layers.label_smooth(
-            layers.one_hot(flat_label, trg_vocab_size),
-            epsilon=label_smooth_eps)
-        cost = layers.softmax_with_cross_entropy(flat_logits, smooth,
-                                                 soft_label=True)
-    else:
-        cost = layers.softmax_with_cross_entropy(flat_logits, flat_label)
-    flat_mask = layers.reshape(trg_mask, [-1, 1])
-    masked = layers.elementwise_mul(cost, flat_mask)
-    avg_cost = layers.reduce_sum(masked) / layers.reduce_sum(flat_mask)
-    return avg_cost, logits
+    return lm_cost(logits, lbl_word, trg_mask, trg_vocab_size,
+                   label_smooth_eps), logits
 
 
 def transformer_lm_parallel(vocab_size=4096, max_len=256, n_layer=4,
@@ -353,12 +350,7 @@ def transformer_lm_parallel(vocab_size=4096, max_len=256, n_layer=4,
                                         aux_losses)
     logits = layers.fc(x, vocab_size, num_flatten_dims=2, bias_attr=False)
 
-    flat_logits = layers.reshape(logits, [-1, vocab_size])
-    flat_label = layers.reshape(label, [-1, 1])
-    cost = layers.softmax_with_cross_entropy(flat_logits, flat_label)
-    flat_mask = layers.reshape(mask, [-1, 1])
-    masked = layers.elementwise_mul(cost, flat_mask)
-    avg_cost = layers.reduce_sum(masked) / layers.reduce_sum(flat_mask)
+    avg_cost = lm_cost(logits, label, mask, vocab_size)
     for aux in aux_losses:
         avg_cost = layers.elementwise_add(
             avg_cost, layers.scale(aux, moe_aux_weight))

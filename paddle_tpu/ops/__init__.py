@@ -4,6 +4,7 @@ lowerings (the analog of the reference's static REGISTER_OPERATOR blocks)."""
 from . import (  # noqa: F401
     activations,
     beam_search,
+    block_diffusion,
     control_flow,
     conv,
     crf_ctc,

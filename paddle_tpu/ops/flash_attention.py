@@ -143,15 +143,21 @@ def _dense(q, k, v, causal, scale):
     return _dense_lse(q, k, v, causal, scale)[0]
 
 
-def _dense_lse(q, k, v, causal, scale):
+def _dense_lse(q, k, v, causal, scale, mask=(0, 0)):
     """Dense math returning (out, lse) — lse[b,h,i] = logsumexp_j s_ij.
-    The math-identical fallback for flash_attention_lse."""
+    The math-identical fallback for flash_attention_lse. k and v may
+    hold fewer heads than q (query head h reads head h // group);
+    `mask` = (shift, strict) is _causal's, read where `causal`."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         t = s.shape[-1]
-        mask = jnp.tril(jnp.ones((t, t), bool))
-        s = jnp.where(mask, s, _NEG_INF)
+        shift, strict = mask
+        at = jnp.arange(t) >> shift
+        s = jnp.where(at[None, :] + strict <= at[:, None], s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -188,12 +194,23 @@ def _tile(block, target):
     return block
 
 
-def _causal(s, off, q_axis):
+def _causal(s, off, q_axis, mask=(0, 0)):
     """Mask one score tile. `off` = its first query row less its first
-    key; queries run along `q_axis` of the tile, keys along the other."""
+    key; queries run along `q_axis` of the tile, keys along the other.
+    `mask` = (shift, strict): rows and keys are counted in blocks of
+    2^shift, a query sees the keys of its own block and of those before
+    it, and `strict` 1 takes its own block away (a first block's rows
+    then see nothing: their scores are all _NEG_INF, which is finite, so
+    out and lse come out finite, and lse = -1e30 weighs nothing in a
+    merge). (0, 0) is plain causal. Tiles start on a multiple of the
+    block, so `off >> shift` is their distance in blocks."""
+    shift, strict = mask
     qi = lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     kj = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(kj - qi <= off, s, _NEG_INF)
+    if not shift and not strict:
+        return jnp.where(kj - qi <= off, s, _NEG_INF)
+    return jnp.where((kj >> shift) - (qi >> shift) + strict
+                     <= (off >> shift), s, _NEG_INF)
 
 
 def _when(cond):
@@ -215,7 +232,7 @@ def _block_ids(nq, nk, by_keys=False):
             pl.program_id(ka) if nk > 1 else 0)
 
 
-def _walk(panel, i, j, causal, block_q, block_k, tile, by_keys=False):
+def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False):
     """Run `panel(mine, segments)` over the major block (i, j).
 
     `mine` is a static slice of the block's query rows (of its keys if
@@ -230,6 +247,9 @@ def _walk(panel, i, j, causal, block_q, block_k, tile, by_keys=False):
     at trace time: panel r holds rows [r t, (r+1) t) with the keys
     before them unmasked and their own keys masked (the transpose of
     that by keys). Unequal blocks cross it anywhere: one masked panel.
+    `mask` is None (every score counts) or _causal's (shift, strict): a
+    mask in blocks of 2^shift no larger than a tile moves no tile from
+    one side of the diagonal to the other, so the walk is causal's.
     """
     nq, nk = (block_k, block_q) if by_keys else (block_q, block_k)
 
@@ -238,10 +258,12 @@ def _walk(panel, i, j, causal, block_q, block_k, tile, by_keys=False):
         for r in range(nq // step):
             panel(slice(r * step, (r + 1) * step), [(slice(0, nk), None)])
 
-    if not causal:
+    if mask is None:
         return whole()
     first_q, first_k = i * block_q, j * block_k
-    last_k = first_k + block_k - 1
+    # the last key every row of the block sees unmasked, plus one where
+    # a row does not see its own key
+    last_k = first_k + block_k - 1 + mask[1]
     _when(first_q >= last_k)(whole)
 
     @_when((first_q < last_k) & (first_q + block_q - 1 >= first_k))
@@ -310,7 +332,7 @@ def _put(ref, idx, x, mine):
 # across the (sequential, innermost) nK dimension. One key block carries
 # nothing: each panel finishes its own rows, and there is no scratch.
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                causal, scale, block_q, block_k, tile, nq, nk, d, g):
+                mask, scale, block_q, block_k, tile, nq, nk, d, g):
     i, j = _block_ids(nq, nk)
     if nk > 1:
         m_s, l_s, acc_s = scratch
@@ -337,7 +359,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             scores = []
             for cols, off in segments:
                 s = _dot(q, k_ref[0, cols, :], _NT)     # [tq, tk]
-                scores.append(s if off is None else _causal(s, off, 0))
+                scores.append(s if off is None else _causal(s, off, 0, mask))
             maxes = [jnp.max(s, axis=1, keepdims=True) for s in scores]
             if nk == 1:
                 # the only key block: nothing carried in, nothing to
@@ -361,7 +383,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             l_s[a, rows] = l
             _put(acc_s, rows, acc, mine)
 
-        _walk(panel, i, j, causal, block_q, block_k, tile)
+        _walk(panel, i, j, mask, block_q, block_k, tile)
 
         if nk > 1:
             @pl.when(j == nk - 1)
@@ -373,13 +395,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     _each_head(g, head)
 
 
-def _specs(n_head, g, d):
+def _specs(n_head, g, d, group=1):
     """Block specs of a grid (B * H / g, ., .): `rows(block, axis)` for a
     [B, T, H*D] operand, `block` rows a grid step following grid axis
     `axis` (dk/dv's grid puts the keys first) and the g heads of the
-    step on the last dimension; `stat(block, axis)` for a row statistic
-    [B*H, 1, T], one float a query row and head, the rows along the
-    lanes."""
+    step on the last dimension; `kv(block, axis)` the same for k and v
+    [B, T, (H / group) * D], which `group` query heads read (one head
+    to a block: the step's head over `group`); `stat(block, axis)` for
+    a row statistic [B*H, 1, T], one float a query row and head, the
+    rows along the lanes."""
     hb = n_head // g
 
     def rows(block, axis=None):
@@ -388,20 +412,26 @@ def _specs(n_head, g, d):
             lambda *s: (s[0] // hb, 0 if axis is None else s[axis],
                         s[0] % hb))
 
+    def kv(block, axis=None):
+        return pl.BlockSpec(
+            (1, block, g * d),
+            lambda *s: (s[0] // hb, 0 if axis is None else s[axis],
+                        s[0] % hb // group))
+
     def stat(block, axis=None):
         return pl.BlockSpec(
             (g, 1, block),
             lambda *s: (s[0], 0, 0 if axis is None else s[axis]))
 
-    return rows, stat
+    return rows, (rows if group == 1 else kv), stat
 
 
 # jitted so that a stack of layers traces and lowers each kernel ONCE:
 # the panels make a kernel's body some hundreds of equations, and without
 # the jit's cache every call site pays for them again (24 layers: 29 s
 # more trace and lowering in the benchmark's set-up; my chip run, PR 25)
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
-def _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _fwd_pallas(q, k, v, n_head, n_kv_head, mask, scale, block_q, block_k,
                 interpret):
     b, t, hd = q.shape
     d = hd // n_head
@@ -409,13 +439,13 @@ def _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
     bq = min(block_q, t)
     bk = min(block_k, t)
     nq, nk = t // bq, t // bk
-    rows, stat = _specs(n_head, g, d)
+    rows, kv, stat = _specs(n_head, g, d, n_head // n_kv_head)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, scale=scale,
+        functools.partial(_fwd_kernel, mask=mask, scale=scale,
                           block_q=bq, block_k=bk,
                           tile=_tile(bq, _TILE), nq=nq, nk=nk, d=d, g=g),
         grid=(b * n_head // g, nq, nk),
-        in_specs=[rows(bq, 1), rows(bk, 2), rows(bk, 2)],
+        in_specs=[rows(bq, 1), kv(bk, 2), kv(bk, 2)],
         out_specs=[rows(bq, 1), stat(bq, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, hd), q.dtype),
@@ -465,7 +495,7 @@ def _delta(dy_ref, o_ref, dlse_ref, a, d, g):
     return delta, mine
 
 
-def _bwd_fused_kernel(*refs, causal, scale, t, tile, d, g, has_dlse):
+def _bwd_fused_kernel(*refs, mask, scale, t, tile, d, g, has_dlse):
     """grid (B * H / g,): all of T, one block of heads. Walks by keys with
     the scores transposed [tk, tq], as flash_bwd_dkv does, so that
     dv = p^T dy and dk = ds^T q are plain matmuls that finish inside
@@ -493,7 +523,7 @@ def _bwd_fused_kernel(*refs, causal, scale, t, tile, d, g, has_dlse):
                 dy = dy_ref[0, rows, :]
                 st = _dot(kk, q, _NT)                # [tk, tq]
                 if off is not None:
-                    st = _causal(st, off, 1)
+                    st = _causal(st, off, 1, mask)
                 pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
                 dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
                 dst = pt * (_dot(v, dy, _NT) - delta_s[:, rows])
@@ -505,14 +535,14 @@ def _bwd_fused_kernel(*refs, causal, scale, t, tile, d, g, has_dlse):
             _put(dk_ref, whole, (dk * scale).astype(dk_ref.dtype), mine)
             _put(dv_ref, whole, dv.astype(dv_ref.dtype), mine)
 
-        _walk(panel, 0, 0, causal, t, t, tile, by_keys=True)
+        _walk(panel, 0, 0, mask, t, t, tile, by_keys=True)
         _put(dq_ref, (0, slice(None), slice(None)),
              (dq_s[...] * scale).astype(dq_ref.dtype), all_mine)
 
     _each_head(g, head)
 
 
-def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k, tile, nq, nk, d,
+def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
                    g, has_dlse):
     q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
     dlse_ref = refs[6] if has_dlse else None
@@ -542,14 +572,14 @@ def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k, tile, nq, nk, d,
                 kk = k_ref[0, cols, :]
                 s = _dot(q, kk, _NT)                 # [tq, tk]
                 if off is not None:
-                    s = _causal(s, off, 0)
+                    s = _causal(s, off, 0, mask)
                 p = jnp.exp(s - lse)
                 dp = _dot(dy, v_ref[0, cols, :], _NT)
                 ds = p * (dp - delta)
                 acc = acc + _dot(ds.astype(kk.dtype), kk, _NN)  # [tq, W]
             acc_s[rows] = acc_s[rows] + _only(acc, mine)
 
-        _walk(panel, i, j, causal, block_q, block_k, tile)
+        _walk(panel, i, j, mask, block_q, block_k, tile)
 
     _each_head(g, head)
 
@@ -559,7 +589,7 @@ def _bwd_dq_kernel(*refs, causal, scale, block_q, block_k, tile, nq, nk, d,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_s, dv_s, *, causal, scale, block_q,
+                    dk_ref, dv_ref, dk_s, dv_s, *, mask, scale, block_q,
                     block_k, tile, nq, nk, d, g):
     i, jj = _block_ids(nq, nk, by_keys=True)    # q blocks innermost here
 
@@ -584,7 +614,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
                 dy = dy_ref[0, rows, :]
                 st = _dot(kk, q, _NT)                # [tk, tq]
                 if off is not None:
-                    st = _causal(st, off, 1)
+                    st = _causal(st, off, 1, mask)
                 pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
                 dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
                 dpt = _dot(v, dy, _NT)
@@ -593,7 +623,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
             dk_s[cols] = dk_s[cols] + _only(dk, mine)
             dv_s[cols] = dv_s[cols] + _only(dv, mine)
 
-        _walk(panel, i, jj, causal, block_q, block_k, tile, by_keys=True)
+        _walk(panel, i, jj, mask, block_q, block_k, tile, by_keys=True)
 
     _each_head(g, head)
 
@@ -624,13 +654,23 @@ def _backward_of(t, w, block_q, block_k):
             else "two_kernels")
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
-def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
+def _group_sum(dkv, group, d, dtype):
+    """dk or dv as the kernels leave it under grouped key/value heads,
+    [B, T, H*D] in float32 with one head's worth a QUERY head, summed
+    over each group of `group` query heads: [B, T, (H / group) * D]."""
+    b, t, hd = dkv.shape
+    return dkv.reshape(b, t, hd // (group * d), group, d).sum(3).reshape(
+        b, t, hd // group).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
+def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
                 interpret, dlse=None):
     q, k, v, o, lse = res
     b, t, hd = q.shape
     d = hd // n_head
     g = heads_per_block(n_head, d)
+    group = n_head // n_kv_head
     w = g * d
     bq, bk = _backward_blocks(t, w, block_q, block_k)
     nq, nk = t // bq, t // bk
@@ -638,30 +678,39 @@ def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
     stats = [lse.reshape(b * n_head, 1, t)]
     if dlse is not None:
         stats.append(dlse.astype(jnp.float32).reshape(b * n_head, 1, t))
-    rows, stat = _specs(n_head, g, d)
+    rows, kv, stat = _specs(n_head, g, d, group)
     bthd = jax.ShapeDtypeStruct((b, t, hd), q.dtype)
+    # grouped key/value heads: a grid step still makes ONE query head's
+    # dk and dv, in float32, and XLA sums each group's after the kernel
+    # (_group_sum)
+    dkv = bthd if group == 1 else jax.ShapeDtypeStruct((b, t, hd),
+                                                       jnp.float32)
+    summed = (lambda x: x) if group == 1 else functools.partial(
+        _group_sum, group=group, d=d, dtype=k.dtype)
 
     if nq == nk == 1:       # _backward_of's "fused"
-        return pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, causal=causal, scale=scale,
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, mask=mask, scale=scale,
                               t=t, tile=tile, d=d, g=g,
                               has_dlse=dlse is not None),
             grid=(b * n_head // g,),
-            in_specs=[rows(t)] * 5 + [stat(t)] * len(stats),
+            in_specs=[rows(t), kv(t), kv(t), rows(t), rows(t)]
+            + [stat(t)] * len(stats),
             out_specs=[rows(t)] * 3,
-            out_shape=[bthd] * 3,
+            out_shape=[bthd, dkv, dkv],
             scratch_shapes=[pltpu.VMEM((t, w), jnp.float32),
                             pltpu.VMEM((1, t), jnp.float32)],
             interpret=interpret,
             name="flash_bwd",
         )(q, k, v, dy, o, *stats)
+        return dq, summed(dk), summed(dv)
 
     dq, delta3 = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
+        functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
                           block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
                           d=d, g=g, has_dlse=dlse is not None),
         grid=(b * n_head // g, nq, nk),
-        in_specs=[rows(bq, 1), rows(bk, 2), rows(bk, 2), rows(bq, 1),
+        in_specs=[rows(bq, 1), kv(bk, 2), kv(bk, 2), rows(bq, 1),
                   rows(bq, 1)] + [stat(bq, 1)] * len(stats),
         out_specs=[rows(bq, 1), stat(bq, 1)],
         out_shape=[bthd, jax.ShapeDtypeStruct((b * n_head, 1, t),
@@ -675,41 +724,39 @@ def _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
 
     # grid (B * H / g, nK, nQ): the q-side operands follow the LAST axis
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
+        functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
                           block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
                           d=d, g=g),
         grid=(b * n_head // g, nk, nq),
-        in_specs=[rows(bq, 2), rows(bk, 1), rows(bk, 1), rows(bq, 2),
+        in_specs=[rows(bq, 2), kv(bk, 1), kv(bk, 1), rows(bq, 2),
                   stat(bq, 2), stat(bq, 2)],
         out_specs=[rows(bk, 1), rows(bk, 1)],
-        out_shape=[bthd, bthd],
+        out_shape=[dkv, dkv],
         scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
                         pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, dy, stats[0], delta3)
-    return dq, dk, dv
+    return dq, summed(dk), summed(dv)
 
 
 # --------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, n_head, causal, scale, block_q, block_k, interpret):
-    out, _ = _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
-                         interpret)
-    return out
+# The static arguments of every entry below, in order: n_head,
+# n_kv_head, mask (None, or _causal's (shift, strict)), scale, block_q,
+# block_k, interpret.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, *static):
+    return _fwd_pallas(q, k, v, *static)[0]
 
 
-def _flash_fwd(q, k, v, n_head, causal, scale, block_q, block_k,
-               interpret):
-    out, lse = _fwd_pallas(q, k, v, n_head, causal, scale, block_q,
-                           block_k, interpret)
+def _flash_fwd(q, k, v, *static):
+    out, lse = _fwd_pallas(q, k, v, *static)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(n_head, causal, scale, block_q, block_k, interpret, res,
-               dy):
-    return _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
-                       interpret)
+def _flash_bwd(*args):
+    *static, res, dy = args
+    return _bwd_pallas(res, dy, *static)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -719,25 +766,19 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # (out, lse) variant: same kernels, but the log-sum-exp rows are a public,
 # differentiable output. Ring attention combines per-shard partial results
 # with these (parallel/ring.py), so d(loss)/d(lse) is generally non-zero.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, n_head, causal, scale, block_q, block_k,
-               interpret):
-    return _fwd_pallas(q, k, v, n_head, causal, scale, block_q, block_k,
-                       interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_lse(q, k, v, *static):
+    return _fwd_pallas(q, k, v, *static)
 
 
-def _flash_lse_fwd(q, k, v, n_head, causal, scale, block_q, block_k,
-                   interpret):
-    out, lse = _fwd_pallas(q, k, v, n_head, causal, scale, block_q,
-                           block_k, interpret)
+def _flash_lse_fwd(q, k, v, *static):
+    out, lse = _fwd_pallas(q, k, v, *static)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd(n_head, causal, scale, block_q, block_k, interpret,
-                   res, dys):
-    dy, dlse = dys
-    return _bwd_pallas(res, dy, n_head, causal, scale, block_q, block_k,
-                       interpret, dlse=dlse)
+def _flash_lse_bwd(*args):
+    *static, res, (dy, dlse) = args
+    return _bwd_pallas(res, dy, *static, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -775,8 +816,10 @@ _LOWERINGS = _REG.counter(
     "ptpu_flash_lowerings_total",
     "flash attention dispatches at trace time (one a lowering of the op, "
     "none a step): the path taken, the layout of the entry called, the "
-    "heads a kernel block holds and the backward its gradient would run",
-    ("path", "entry", "heads_per_block", "backward"))
+    "heads a kernel block holds, the backward its gradient would run, "
+    "the mask (none, causal, block_causal, block_causal_strict) and the "
+    "query heads that read one key/value head",
+    ("path", "entry", "heads_per_block", "backward", "mask", "kv_groups"))
 
 
 def _resolve_path(q, scale, block_q, block_k, force):
@@ -828,52 +871,93 @@ def heads_last(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
+def _mask_of(causal, mask_block, strict):
+    """(the kernels' mask, its label): None / "none" where not causal,
+    else _causal's (shift, strict) for blocks of `mask_block` rows, a
+    power of two."""
+    if not causal:
+        return None, "none"
+    shift = int(mask_block).bit_length() - 1
+    if mask_block < 1 or 1 << shift != mask_block:
+        raise ValueError("flash attention: the mask's block is a power "
+                         "of two, got %r" % (mask_block,))
+    return (shift, int(bool(strict))), "%scausal%s" % (
+        "block_" if shift else "", "_strict" if strict else "")
+
+
 def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-            entry, with_lse):
+            entry, with_lse, n_kv_head=None, mask_block=1, strict=False):
     """Dispatch of every entry: q/k/v [B, T, H*D] -> out, or (out, lse
     [B, H, T]) `with_lse`. `entry` labels the count: the layout the
-    caller came in."""
+    caller came in. k and v may hold `n_kv_head` < H heads, [B, T,
+    Hkv*D]: query head h reads head h // (H / Hkv), and the kernels
+    take that where a block is one head (D a multiple of 128)."""
     b, t, hd = q.shape
     d = hd // n_head
+    n_kv_head = n_kv_head or n_head
+    if n_head % n_kv_head or k.shape[-1] != n_kv_head * d:
+        raise ValueError(
+            "flash attention: %d query heads of %d cannot read k of "
+            "shape %s as %d heads" % (n_head, d, k.shape, n_kv_head))
+    mask, mask_label = _mask_of(causal, mask_block, strict)
     path, scale, bq, bk = _resolve_path(
         jax.ShapeDtypeStruct((b, n_head, t, d), q.dtype), scale, block_q,
         block_k, force)
     g = heads_per_block(n_head, d)
+    # what the kernels cannot take goes the dense way whoever asked: a
+    # group of query heads shares a block of k only where a block is one
+    # head, and a mask's block must divide every tile's edge
+    edge = _tile(_backward_blocks(t, g * d, bq, bk)[0], _TILE)
+    if (n_kv_head != n_head and g > 1) or (
+            mask and any(x % (1 << mask[0]) for x in (bq, bk, edge))):
+        path = "dense"
     _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
                    backward="none" if path == "dense"
-                   else _backward_of(t, g * d, bq, bk))
+                   else _backward_of(t, g * d, bq, bk),
+                   mask=mask_label, kv_groups=str(n_head // n_kv_head))
     if path == "dense":
-        out, lse = _dense_lse(*(heads_first(x, n_head) for x in (q, k, v)),
-                              causal, scale)
+        out, lse = _dense_lse(
+            heads_first(q, n_head), heads_first(k, n_kv_head),
+            heads_first(v, n_kv_head), causal, scale, mask or (0, 0))
         return (heads_last(out), lse) if with_lse else heads_last(out)
     return (_flash_lse if with_lse else _flash)(
-        q, k, v, n_head, causal, scale, bq, bk, path == "interpret")
+        q, k, v, n_head, n_kv_head, mask, scale, bq, bk,
+        path == "interpret")
 
 
 def flash_bthd(q, k, v, n_head, causal=False, scale=None,
                block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-               force=None):
+               force=None, n_kv_head=None, mask_block=1, strict=False):
     """Fused multi-head attention in the projections' own layout.
-    q/k/v and the result: [B, T, H*D], head h in lanes [h D, (h+1) D).
+    q and the result: [B, T, H*D], head h in lanes [h D, (h+1) D); k and
+    v the same, or [B, T, Hkv*D] with `n_kv_head` = Hkv heads, each read
+    by H / Hkv query heads (dk and dv are the sums over them).
+
+    `causal` with `mask_block` m (a power of two) masks in blocks of m
+    rows: a query sees the keys of its own block and of the blocks
+    before it, and with `strict` only of the blocks before it (a first
+    block's rows then see nothing: their output is finite and their lse
+    -1e30, which weighs nothing where partial results are merged by
+    lse). m 1 and no `strict` is plain causal.
 
     force: None = auto (Pallas kernel on TPU when T divides the blocks,
     dense XLA math otherwise), "pallas" / "interpret" / "dense" pin a path
     (tests use "interpret" to run the kernel on CPU).
     """
     return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-                   "bthd", False)
+                   "bthd", False, n_kv_head, mask_block, strict)
 
 
 def flash_bthd_lse(q, k, v, n_head, causal=False, scale=None,
                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                   force=None):
+                   force=None, n_kv_head=None, mask_block=1, strict=False):
     """Like flash_bthd but returns (out [B, T, H*D], lse [B, H, T]) with
     lse[b,h,i] = logsumexp_j(q_i·k_j*scale [+mask]) — the statistic ring
     attention needs to merge partial attention over K/V shards. Both
     outputs are differentiable (the lse cotangent folds into the shared
     backward kernels)."""
     return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-                   "bthd", True)
+                   "bthd", True, n_kv_head, mask_block, strict)
 
 
 def flash_attention(q, k, v, causal=False, scale=None,

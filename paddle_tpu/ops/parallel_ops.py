@@ -133,6 +133,37 @@ def _moe_ffn(ctx, op):
         ctx.set_out(op, "Overflow", stats["overflow"])
 
 
+@register("routed_experts")
+def _routed_experts(ctx, op):
+    """One chip's share of a mixture of SiLU-gated experts, dropless
+    (parallel/moe.routed_experts). Inputs X [B, T, D], RouterW [D, E]
+    over all E experts, WGate / WUp [Eh, D, F] and WDown [Eh, F, D] of
+    the Eh experts held here, Load [E] int32 (persistable); attrs
+    first_expert, top_k, norm_topk. Outputs Out (what the held experts
+    give; x's dtype), AuxLoss, Indices [B, T, k] (the router's choices)
+    and LoadOut = Load + the rows that chose each expert, which a
+    `for_test` clone leaves alone. Under AMP the experts' matmuls take
+    bfloat16 operands; the router is float32 either way."""
+    from ..amp import maybe_bf16
+    from ..parallel import moe
+    x = ctx.in1(op, "X")
+    shape = x.shape
+    router_w = ctx.in1(op, "RouterW")
+    w_gate, w_up, w_down = maybe_bf16(
+        ctx.in1(op, "WGate"), ctx.in1(op, "WUp"), ctx.in1(op, "WDown"))
+    k = int(op.attr("top_k"))
+    out, aux, counts, experts = moe.routed_experts(
+        x.reshape(-1, shape[-1]), router_w, w_gate, w_up, w_down,
+        router_w.shape[1],
+        first_expert=int(op.attr("first_expert", 0)), top_k=k,
+        norm_topk=bool(op.attr("norm_topk", True)))
+    ctx.set_out(op, "Out", out.reshape(shape))
+    ctx.set_out(op, "AuxLoss", aux)
+    ctx.set_out(op, "Indices", experts.reshape(shape[:-1] + (k,)))
+    if not (op.attr("is_test", False) or ctx.is_test):
+        ctx.set_out(op, "LoadOut", ctx.in1(op, "Load") + counts)
+
+
 def _decoder_layer_apply(p, x, n_head):
     """One pre-LN-free (post-LN, matching models/transformer.py 'dan')
     decoder-only layer from a param dict of arrays — the tp/sp twin with

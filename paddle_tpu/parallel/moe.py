@@ -7,9 +7,13 @@ expert weights stacked [E, ...] sharded on ``ep`` — XLA GSPMD turns the
 dispatch einsum into the all-to-all over ICI, no manual comm code.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..monitor import metrics as _metrics
 
 
 def top1_gating(logits, capacity, rng=None, noise_std=0.0):
@@ -168,3 +172,166 @@ def moe_ffn_pp_sharded(x, gate_w, w_up_local, w_down_local, ep_axis,
                                 concat_axis=0, tiled=True)
     out = jnp.einsum("tec,ecd->td", combine, expert_out)
     return out, aux
+
+
+# --------------------------------------------------------------------------
+# A dropless expert layer that is told which experts it holds (ISSUE 32).
+#
+# One chip of an expert-parallel group routes its rows over ALL the
+# experts, computes what the experts it holds give, and leaves the rest
+# out: nothing here stands in for the other chips or the exchange with
+# them. No token is dropped whatever the routing: the (row, expert) pairs
+# that fall on held experts are sorted by expert and go through
+# lax.ragged_dot (on a TPU XLA's own grouped-matmul kernel, which walks
+# the tiles that hold rows and no others) in CHUNKS of twice the pairs
+# uniform routing expects; a loop runs as many chunks as hold pairs, one
+# as a rule, all of them when every row chooses held experts. So the
+# matmuls' work follows the rows present, and memory a chunk, not the
+# worst case of N * top_k pairs. A chunk's rows are gathered from x and
+# go back to their tokens by one scatter-add (both move the whole chunk).
+# The backward is written out: it recomputes a chunk's hidden
+# activations from x instead of keeping them, so that nothing of a
+# chunk's size outlives its iteration.
+_REG = _metrics.registry()
+_LOWERINGS = _REG.counter(
+    "ptpu_moe_lowerings_total",
+    "lowerings of the routed expert layer at trace time (none a step): "
+    "the grouped matmul's path, the experts routed over, those held here "
+    "and the experts a row takes",
+    ("path", "experts", "experts_held", "top_k"))
+
+
+def route(x, router_w, top_k, norm_topk):
+    """(probs [N, E], weights [N, k], experts [N, k]): the float32
+    router. Softmax over ALL experts, the k largest, their weights
+    divided by their sum where `norm_topk`."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = lax.top_k(probs, top_k)
+    if norm_topk:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return probs, top_p, top_i
+
+
+def _swiglu_experts(xs, w_gate, w_up, w_down, sizes):
+    """The held experts on sorted rows: xs [C, d], `sizes` rows to each
+    expert in turn; rows past their sum are not computed."""
+    rd = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                           preferred_element_type=jnp.float32)
+    h = jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)
+    return rd(h.astype(xs.dtype), w_down)
+
+
+def _chunk(c, cap, order, ends, k):
+    """Chunk c of the sorted pairs: (pairs [cap], their rows [cap],
+    whether each place holds a pair, the rows to each expert)."""
+    at = c * cap + jnp.arange(cap, dtype=jnp.int32)
+    pairs = lax.dynamic_slice_in_dim(order, c * cap, cap)
+    inside = jnp.clip(ends - c * cap, 0, cap)
+    sizes = inside - jnp.concatenate([inside[:1] * 0, inside[:-1]])
+    return pairs, pairs // k, at < ends[-1], sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _held_experts(x, weight, w_gate, w_up, w_down, order, ends, cap):
+    return _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap)[0]
+
+
+def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap):
+    k = weight.shape[1]
+
+    def body(c, out):
+        pairs, rows, there, sizes = _chunk(c, cap, order, ends, k)
+        y = _swiglu_experts(x[rows], w_gate, w_up, w_down, sizes)
+        y = y * weight.reshape(-1)[pairs][:, None]
+        return out.at[rows].add(jnp.where(there[:, None], y, 0.0))
+
+    out = lax.fori_loop(0, (ends[-1] + cap - 1) // cap, body,
+                        jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype), (x, weight, w_gate, w_up, w_down, order,
+                                 ends)
+
+
+def _held_bwd(cap, res, dout):
+    x, weight, w_gate, w_up, w_down, order, ends = res
+    k = weight.shape[1]
+    dout = dout.astype(x.dtype)
+
+    def body(c, carry):
+        dx, dweight, dws = carry
+        pairs, rows, there, sizes = _chunk(c, cap, order, ends, k)
+        y, vjp = jax.vjp(
+            lambda xs, *ws: _swiglu_experts(xs, *ws, sizes),
+            x[rows], w_gate, w_up, w_down)
+        dy = jnp.where(there[:, None], dout[rows], 0).astype(jnp.float32)
+        # a pair's weight multiplies its sorted row, and its gradient is
+        # that row's product with the row's cotangent
+        dweight = dweight.at[pairs].add(
+            jnp.where(there, jnp.sum(dy * y, axis=1), 0.0))
+        dxs, *dw = vjp(dy * weight.reshape(-1)[pairs][:, None])
+        dx = dx.at[rows].add(
+            jnp.where(there[:, None], dxs, 0).astype(jnp.float32))
+        return dx, dweight, [a + b.astype(jnp.float32)
+                             for a, b in zip(dws, dw)]
+
+    dx, dweight, dws = lax.fori_loop(
+        0, (ends[-1] + cap - 1) // cap, body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(weight.size, weight.dtype),
+         [jnp.zeros(w.shape, jnp.float32) for w in (w_gate, w_up, w_down)]))
+    return (dx.astype(x.dtype), dweight.reshape(weight.shape),
+            *(d.astype(w.dtype) for d, w in zip(dws, (w_gate, w_up, w_down))),
+            None, None)
+
+
+_held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
+                   first_expert=0, top_k=8, norm_topk=True):
+    """One chip's share of a mixture of SiLU-gated experts, dropless.
+
+    x [N, d]; router_w [d, E] over ALL `num_experts`; w_gate, w_up
+    [Eh, d, f] and w_down [Eh, f, d]: the Eh experts held here, ids
+    `first_expert` .. `first_expert` + Eh - 1. Returns
+
+      out     [N, d], x's dtype: sum over a row's chosen experts THAT ARE
+              HELD HERE of weight * w_down(silu(w_gate x) * (w_up x));
+              what the other experts would add is left out
+      aux     E * sum_e f_e P_e over all E (f_e the rows that chose e
+              over N, constant; P_e the mean router probability)
+      counts  [E] int32, the rows that chose each expert
+      experts [N, k] int32, the router's choices
+
+    The router is float32 and reads x as it comes; the experts compute
+    in their weights' dtype (bfloat16 under AMP), accumulating in
+    float32. Every held pair is computed, also when all rows choose
+    held experts."""
+    n, d = x.shape
+    held = w_gate.shape[0]
+    _LOWERINGS.inc(path="ragged_dot", experts=str(num_experts),
+                   experts_held=str(held), top_k=str(top_k))
+    probs, weight, experts = route(x, router_w, top_k, norm_topk)
+    counts = jnp.sum(experts[..., None] == jnp.arange(num_experts),
+                     axis=(0, 1), dtype=jnp.int32)
+    aux = num_experts * jnp.sum(
+        lax.stop_gradient(counts.astype(jnp.float32) / n)
+        * jnp.mean(probs, axis=0))
+
+    # the pairs on held experts, sorted by expert (stable: by row within
+    # one); the others sort behind them and are never visited
+    local = experts.reshape(-1) - first_expert
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    ends = jnp.cumsum(lax.dynamic_slice_in_dim(
+        counts, first_expert, held)).astype(jnp.int32)
+    # a chunk: twice what uniform routing sends here, in whole tiles of
+    # the grouped matmul
+    pairs = n * top_k
+    cap = min(-(-2 * pairs * held // num_experts // 512) * 512,
+              -(-pairs // 8) * 8)
+    order = jnp.pad(order, (0, -(-pairs // cap) * cap - pairs))
+    out = _held_experts(x.astype(w_gate.dtype), weight, w_gate, w_up,
+                        w_down, order, ends, cap)
+    return out.astype(x.dtype), aux, counts, experts.astype(jnp.int32)

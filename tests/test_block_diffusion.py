@@ -1,0 +1,328 @@
+"""The pre-norm block's ops, the dropless expert layer and the
+block-diffusion objective (ISSUE 32), at small sizes with seeded
+weights on the CPU: each op against ``jax.numpy``, the expert layer
+against a dense loop over experts, the two-piece attention against the
+2L x 2L mask written out, and the whole small model against the
+benchmark's float32 reference (``chipbench/reference/sdar_lm.py``).
+The ops: "rms_norm", "rope", "silu_mul", "block_diffusion_noise",
+"block_diffusion_attention", "routed_experts".
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from op_test import check_grad, check_output, run_op
+from paddle_tpu.ops import block_diffusion as BD
+from paddle_tpu.parallel import moe
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from chipbench.reference import sdar_lm  # noqa: E402
+
+
+def _r(*shape, seed=0, scale=0.5):
+    return (np.random.RandomState(seed).randn(*shape) * scale).astype(
+        np.float32)
+
+
+# -- the ops ------------------------------------------------------------------
+
+@pytest.mark.parametrize("groups", [1, 4], ids=["whole", "qk_norm"])
+def test_rms_norm_op(groups):
+    x, w = _r(2, 6, 32, seed=1), 1.0 + _r(32 // groups, seed=2, scale=0.1)
+    g = x.reshape(2, 6, groups, -1)
+    want = g / np.sqrt(np.mean(g * g, -1, keepdims=True) + 1e-6) * w
+    check_output("rms_norm", {"X": x, "Scale": w}, {"epsilon": 1e-6},
+                 {"Out": want.reshape(x.shape)}, rtol=1e-5)
+    check_grad("rms_norm", {"X": x, "Scale": w}, {"epsilon": 1e-6},
+               ["X", "Scale"])
+
+
+@pytest.mark.parametrize("wrap", [0, 4], ids=["by_index", "two_halves"])
+def test_rope_op(wrap):
+    """Against the reference's own rotate-half RoPE, and the row at
+    position 0 comes back as it went in."""
+    x = _r(2, 8, 4 * 16, seed=3)
+    pos = np.arange(8) % wrap if wrap else np.arange(8)
+    want = np.stack([np.asarray(sdar_lm._rope(
+        jnp.asarray(row).reshape(8, 4, 16), jnp.asarray(pos), 1e6)).reshape(
+            8, 64) for row in x])
+    attrs = {"n_head": 4, "theta": 1e6, "wrap": wrap}
+    got = run_op("rope", {"X": x}, attrs, ["Out"])["Out"]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got[:, 0], x[:, 0], rtol=1e-6)
+    if wrap:
+        assert not np.allclose(got[:, 1], got[:, 5])   # other rows, same turn
+    check_grad("rope", {"X": x}, attrs, ["X"])
+
+
+def test_silu_mul_op():
+    a, b = _r(3, 5, 8, seed=4), _r(3, 5, 8, seed=5)
+    check_output("silu_mul", {"X": a, "Y": b}, {},
+                 {"Out": a / (1.0 + np.exp(-a)) * b}, rtol=1e-5)
+    check_grad("silu_mul", {"X": a, "Y": b}, {}, ["X", "Y"])
+
+
+def test_noise_op_is_the_references_at_steps_0_and_1():
+    """The draw is a function of (salt, step, batch row): the op gives
+    the reference's ``noise`` at the step its counter holds, advances
+    the counter in a train run and leaves it in a `for_test` run."""
+    tokens = np.random.RandomState(6).randint(3, 50, (3, 32)).astype(np.int64)
+    drawn = []
+    for step in (0, 1):
+        for is_test in (False, True):
+            got = run_op("block_diffusion_noise",
+                         {"X": tokens, "Salt": np.array([77], np.int32),
+                          "Step": np.array([step], np.int32)},
+                         {"block": 4, "mask_id": 0},
+                         ["Noised", "Weight"] + ([] if is_test
+                                                 else ["StepOut"]),
+                         is_test=is_test)
+            masked, t = sdar_lm.noise(77, step, 3, 32, 4)
+            np.testing.assert_array_equal(
+                got["Noised"], np.where(masked, 0, tokens))
+            np.testing.assert_allclose(
+                got["Weight"], np.where(masked, 1.0 / t, 0.0), rtol=1e-6)
+            if not is_test:
+                assert got["StepOut"].tolist() == [step + 1]
+        # one t a block of 4, within (1e-3, 1)
+        t = np.asarray(t).reshape(3, 8, 4)
+        assert (t == t[..., :1]).all() and (t > 1e-3).all() and (t < 1).all()
+        drawn.append(got["Noised"])
+    assert (drawn[0] != drawn[1]).any()          # fresh noise a step
+    assert (run_op("block_diffusion_noise",
+                   {"X": tokens, "Salt": np.array([78], np.int32),
+                    "Step": np.array([0], np.int32)},
+                   {"block": 4, "mask_id": 0},
+                   ["Noised"])["Noised"] != drawn[0]).any()
+
+
+# -- attention ----------------------------------------------------------------
+
+def _dense_bd_attention(q, k, v, n_head, n_kv_head, block):
+    """The 2L x 2L mask written out (the reference's ``bd_mask``)."""
+    b, t2, hd = q.shape
+    d = hd // n_head
+    mask = sdar_lm.bd_mask(t2 // 2, block)
+    split = lambda x, h: x.reshape(b, t2, h, d).transpose(0, 2, 1, 3)
+    qh = split(q, n_head)
+    kh, vh = (jnp.repeat(split(x, n_kv_head), n_head // n_kv_head, 1)
+              for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, vh).transpose(
+        0, 2, 1, 3).reshape(b, t2, hd)
+
+
+@pytest.mark.parametrize("force", ["dense", "interpret"])
+@pytest.mark.parametrize("block", [4, 32])
+def test_two_piece_attention_is_the_dense_mask(block, force):
+    """Two flash pieces merged by lse with the noised rows' own blocks
+    against the mask written out, forward and gradients; the first
+    block's noised rows, which see no clean key, come out as their own
+    block's attention alone."""
+    h, hkv, d, seq = 4, 2, 128, 128
+    q = jnp.asarray(_r(1, 2 * seq, h * d, seed=7))
+    k, v = (jnp.asarray(_r(1, 2 * seq, hkv * d, seed=s)) for s in (8, 9))
+    dy = jnp.asarray(_r(1, 2 * seq, h * d, seed=10))
+    got = lambda q, k, v: BD.attention(q, k, v, h, hkv, block, force=force)
+    want = lambda q, k, v: _dense_bd_attention(q, k, v, h, hkv, block)
+    np.testing.assert_allclose(got(q, k, v), want(q, k, v), atol=2e-5)
+    loss = lambda f: lambda *a: (f(*a) * dy).sum()
+    for name, a, b in zip("qkv", jax.grad(loss(got), (0, 1, 2))(q, k, v),
+                          jax.grad(loss(want), (0, 1, 2))(q, k, v)):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+
+
+# -- the expert layer ---------------------------------------------------------
+
+N, D, F, E, HELD, K = 96, 32, 16, 16, 4, 4
+
+
+def _experts(seed=0):
+    rng = np.random.RandomState(seed)
+    mk = lambda *s: jnp.asarray(rng.randn(*s) * 0.3, jnp.float32)
+    return mk(N, D), mk(D, E), mk(E, D, F), mk(E, D, F), mk(E, F, D)
+
+
+def _dense_experts(x, wr, wg, wu, wd, first, held):
+    """Every expert of the share on every row."""
+    _, w, idx = moe.route(x, wr, K, True)
+    out = 0.0
+    for e in range(first, first + held):
+        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), 1)
+        out = out + w_e[:, None] * (
+            (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+    return out
+
+
+def _held(x, wr, wg, wu, wd, first, held):
+    return moe.routed_experts(x, wr, wg[first:first + held],
+                              wu[first:first + held], wd[first:first + held],
+                              E, first, K, True)[0]
+
+
+def _one_sided(x, wr):
+    """Every row chooses experts 4..7, all held by the share at 4."""
+    return x.at[:, 0].set(1.0), (wr * 0.01).at[0, 4:8].set(50.0)
+
+
+@pytest.mark.parametrize("routing", ["uniform", "all_on_held"])
+def test_expert_layer_is_the_dense_loop(routing):
+    """Output and every gradient (x, router, the three weights) against
+    a dense loop over the held experts; with every row sent to held
+    experts the layer runs several chunks and drops nothing."""
+    x, wr, wg, wu, wd = _experts()
+    if routing == "all_on_held":
+        x, wr = _one_sided(x, wr)
+    args = (x, wr, wg, wu, wd)
+    _, _, counts, _ = moe.routed_experts(x, wr, wg[4:8], wu[4:8], wd[4:8],
+                                         E, 4, K, True)
+    assert int(counts.sum()) == N * K
+    if routing == "all_on_held":
+        assert counts.tolist() == [0] * 4 + [N] * 4 + [0] * 8
+    np.testing.assert_allclose(_held(*args, 4, HELD),
+                               _dense_experts(*args, 4, HELD), atol=1e-5)
+    sq = lambda f: lambda *a: (f(*a, 4, HELD) ** 2).sum()
+    got = jax.grad(sq(_held), (0, 1, 2, 3, 4))(*args)
+    want = jax.grad(sq(_dense_experts), (0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip(("x", "router", "gate", "up", "down"), got, want):
+        scale = float(jnp.max(jnp.abs(b))) + 1e-9
+        assert float(jnp.max(jnp.abs(a - b))) / scale < 1e-4, name
+
+
+@pytest.mark.parametrize("routing", ["uniform", "all_on_held"])
+def test_the_shares_add_up(routing):
+    """The 4 shares of 4 experts sum to the uncut layer's output: no
+    share computes another's experts or leaves one of its own out."""
+    x, wr, wg, wu, wd = _experts(seed=3)
+    if routing == "all_on_held":
+        x, wr = _one_sided(x, wr)
+    shares = sum(_held(x, wr, wg, wu, wd, first, HELD)
+                 for first in range(0, E, HELD))
+    np.testing.assert_allclose(
+        shares, _dense_experts(x, wr, wg, wu, wd, 0, E), atol=1e-5)
+
+
+def test_aux_loss_and_lowering_counter():
+    x, wr, wg, wu, wd = _experts(seed=5)
+    labels = dict(path="ragged_dot", experts=str(E), experts_held=str(HELD),
+                  top_k=str(K))
+    was = moe._LOWERINGS.value(**labels)
+    _, aux, counts, idx = moe.routed_experts(x, wr, wg[:4], wu[:4], wd[:4],
+                                             E, 0, K, True)
+    assert moe._LOWERINGS.value(**labels) == was + 1
+    probs = jax.nn.softmax(x @ wr, -1)
+    np.testing.assert_allclose(
+        aux, E * jnp.sum(counts / N * jnp.mean(probs, 0)), rtol=1e-5)
+    assert idx.shape == (N, K) and len(set(idx[0].tolist())) == K
+
+
+def test_routed_experts_op_counts_train_runs_only():
+    x, wr, wg, wu, wd = (np.asarray(a) for a in _experts(seed=7))
+    ins = {"X": x.reshape(2, N // 2, D), "RouterW": wr, "WGate": wg[:4],
+           "WUp": wu[:4], "WDown": wd[:4], "Load": np.ones(E, np.int32)}
+    attrs = {"first_expert": 0, "top_k": K, "norm_topk": True}
+    got = run_op("routed_experts", ins, attrs,
+                 ["Out", "AuxLoss", "Indices", "LoadOut"])
+    np.testing.assert_allclose(
+        got["Out"].reshape(N, D),
+        _dense_experts(*(jnp.asarray(a) for a in (x, wr, wg, wu, wd)), 0, 4),
+        atol=1e-5)
+    assert got["Indices"].shape == (2, N // 2, K)
+    assert int(got["LoadOut"].sum()) == E + N * K
+    test = run_op("routed_experts", ins, attrs, ["Out"], is_test=True)
+    np.testing.assert_allclose(test["Out"], got["Out"], atol=1e-6)
+
+
+# -- the whole small model against the benchmark's reference -------------------
+
+CFG = {"vocab_size": 96, "num_hidden_layers": 2, "hidden_size": 32,
+       "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+       "moe_intermediate_size": 24, "num_experts": 4, "first_expert": 4,
+       "published": {"num_experts": 16}, "num_experts_per_tok": 4,
+       "norm_topk_prob": True, "block_length": 4, "mask_token_id": 0,
+       "rope_theta": 1e6, "rms_norm_eps": 1e-6,
+       "router_aux_loss_coef": 1e-3, "embedding_init_std": 1.0,
+       "arch": "sdar"}
+SEQ = 32
+
+
+def _small_model():
+    from chipbench import cells
+    arch = cells.load_arch("sdar")
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 11
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
+        cost, logits = arch.build(CFG, SEQ)
+        forward = main.clone(for_test=True)
+    return arch, main, startup, forward, scope, cost, logits
+
+
+def _batch(rows=2):
+    rng = np.random.RandomState(12)
+    return {"src": rng.randint(3, 96, (rows, SEQ)).astype(np.int64),
+            "label": np.zeros((rows, SEQ), np.int64),
+            "mask": (rng.rand(rows, SEQ) > 0.2).astype(np.float32)}
+
+
+def test_small_model_loss_and_logits_are_the_references():
+    arch, main, startup, forward, scope, cost, logits = _small_model()
+    feed = _batch()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        params = arch.params_of_program(main, scope, CFG)
+        assert int(params["salt"]) == 11 and int(params["step"]) == 0
+        fetched = exe.run(forward, feed=feed, fetch_list=[cost, logits] + list(
+            arch.router_choices(forward)))
+    got_cost, got_logits, choices = fetched[0], fetched[1], fetched[2:]
+    want = arch.lm_loss(params, feed["src"], feed["label"], feed["mask"], CFG)
+    np.testing.assert_allclose(got_cost, want, rtol=2e-5)
+    assert len(choices) == 2 and choices[0].shape == (2, 2 * SEQ, 4)
+    for row in range(1):       # logits_at is batch row 0's draw
+        ref = arch.logits_at(params, jnp.asarray(feed["src"][row]), 0, SEQ,
+                             CFG)
+        np.testing.assert_allclose(got_logits[row], ref, atol=2e-5)
+        # handed the program's own choices the reference changes nothing
+        handed = arch.logits_at(params, jnp.asarray(feed["src"][row]), 0,
+                                SEQ, CFG, np.stack([c[:1] for c in choices]))
+        np.testing.assert_allclose(handed, ref, atol=1e-6)
+
+
+def test_small_model_one_steps_gradients_are_the_references():
+    """SGD at rate 1 turns a step's parameter change into its gradient:
+    every parameter's against jax.grad of the reference's loss; the
+    step counter and the experts' loads advance."""
+    arch, main, startup, _, scope, cost, _ = _small_model()
+    feed = _batch()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
+        fluid.optimizer.SGD(learning_rate=1.0).minimize(cost)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        before = arch.params_of_program(main, scope, CFG)
+        exe.run(main, feed=feed, fetch_list=[cost])
+        after = arch.params_of_program(main, scope, CFG)
+        counters = arch.program_counters(main, scope)
+    assert counters["steps"] == [1] and int(after["step"]) == 1
+    assert sum(counters["expert_rows"]) == 2 * 2 * (2 * SEQ) * 4
+    floats = lambda p: {k: v for k, v in p.items()
+                        if k not in ("salt", "step")}
+    grads = jax.grad(lambda p: arch.lm_loss(
+        {**p, "salt": before["salt"], "step": before["step"]},
+        feed["src"], feed["label"], feed["mask"], CFG))(floats(before))
+    moved = jax.tree.map(lambda a, b: a - b, floats(before), floats(after))
+    flat_g, _ = jax.tree_util.tree_flatten_with_path(grads)
+    flat_m = jax.tree.leaves(moved)
+    assert len(flat_g) == 3 + 2 * 12
+    for (path, g), m in zip(flat_g, flat_m):
+        scale = float(np.max(np.abs(g))) + 1e-8
+        assert float(np.max(np.abs(g - m))) / scale < 2e-3, \
+            jax.tree_util.keystr(path)

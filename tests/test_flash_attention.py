@@ -704,7 +704,7 @@ def test_backward_follows_from_the_blocks(t, w, block, want):
 
 def test_lowering_counter_says_which_path_engaged():
     """`ptpu_flash_lowerings_total{path, entry, heads_per_block,
-    backward}`: one count a lowering of the fused model's attention (the
+    backward, mask, kv_groups}`: one count a lowering of the fused model's attention (the
     forward's trace; none a step), `dense` off the chip, where no
     backward kernel will run; the [B, H, T, D] wrappers count as `bhtd`;
     `backward` says which backward the lowering's gradient takes: the
@@ -721,7 +721,7 @@ def test_lowering_counter_says_which_path_engaged():
     # d_model 32 over 4 heads: D 8, sixteen heads would fill 128 lanes,
     # four do not: all of H*D as one block, four heads to it
     labels = dict(path="dense", entry="bthd", heads_per_block="4",
-                  backward="none")
+                  backward="none", mask="causal", kv_groups="1")
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
@@ -734,10 +734,175 @@ def test_lowering_counter_says_which_path_engaged():
     q, k, v = _qkv(b=1, h=2, t=256, d=64)
     for block, backward in ((None, "fused"), (128, "two_kernels")):
         labels = dict(path="interpret", entry="bhtd", heads_per_block="2",
-                      backward=backward)
+                      backward=backward, mask="causal", kv_groups="1")
         was = count.value(**labels)
         FA.flash_attention(q, k, v, causal=True, force="interpret",
                            block_q=block, block_k=block)
         assert count.value(**labels) == was + 1
     assert "ptpu_flash_lowerings_total" in \
         fluid.monitor.metrics.registry().render_prometheus()
+
+
+# -- grouped key/value heads and the block-granular mask (ISSUE 32) ----------
+
+def _gqa_inputs(h, hkv, d, t, dtype, seed=11):
+    rng = np.random.RandomState(seed)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape) * 0.5,
+                                    jnp.float32).astype(dtype)
+    return (mk(1, t, h * d), mk(1, t, hkv * d), mk(1, t, hkv * d),
+            mk(1, t, h * d), jnp.asarray(rng.randn(1, h, t) * 0.5,
+                                         jnp.float32))
+
+
+def _dense_block_causal(q, k, v, h, hkv, mask_block, strict):
+    """(out [B, T, H*D], lse [B, H, T], seen [T]) by dense float32 math
+    with the mask written out: query i sees key j iff
+    j // m + strict <= i // m."""
+    b, t, hd = q.shape
+    d = hd // h
+    qh = FA.heads_first(_f32(q), h)
+    kh, vh = (jnp.repeat(FA.heads_first(_f32(x), hkv), h // hkv, 1)
+              for x in (k, v))
+    at = jnp.arange(t) // mask_block
+    seen = at[None, :] + int(strict) <= at[:, None]
+    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * d ** -0.5
+    s = jnp.where(seen, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, -1)
+    p = jnp.where(seen, jnp.exp(s - jnp.where(jnp.isfinite(lse), lse,
+                                              0.0)[..., None]), 0.0)
+    return (FA.heads_last(jnp.einsum("bhqk,bhkd->bhqd", p, vh)), lse,
+            seen.any(1))
+
+
+@pytest.mark.parametrize("mask_block, strict", [
+    (1, False), (4, False), (4, True), (32, False), (32, True)],
+    ids=["causal", "b4", "b4_strict", "b32", "b32_strict"])
+@pytest.mark.parametrize("block", [None, 128], ids=["one_block", "streamed"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_grouped_kv_block_causal_matches_dense(dtype, block, mask_block,
+                                               strict):
+    """4 query heads of 128 reading 2 key/value heads under each mask,
+    one block and streamed, in interpret mode against dense float32
+    math with the mask written out: out, lse, dq, and dk, dv summed over
+    each group, with a non-zero lse cotangent. Under `strict` the first
+    block's rows see nothing: their out is finite, their lse -1e30, and
+    weighed out (as a merge by lse weighs them) they leave every
+    gradient finite and right."""
+    h, hkv, d, t = 4, 2, 128, 256
+    q, k, v, dy, dlse = _gqa_inputs(h, hkv, d, t, dtype)
+    kw = dict(causal=True, force="interpret", block_q=block, block_k=block,
+              n_kv_head=hkv, mask_block=mask_block, strict=strict)
+    o_ref, lse_ref, seen = _dense_block_causal(q, k, v, h, hkv, mask_block,
+                                               strict)
+    assert int((~seen).sum()) == (mask_block if strict else 0)
+    o, lse = FA.flash_bthd_lse(q, k, v, h, **kw)
+    assert o.shape == q.shape and lse.shape == (1, h, t)
+    assert bool(jnp.isfinite(_f32(o)).all())
+    tol = 5e-3 if dtype == jnp.float32 else 2e-2
+    _assert_close("out", jnp.where(seen[None, :, None], o, 0), o_ref, tol)
+    _assert_close("lse", jnp.where(seen, lse, 0),
+                  jnp.where(seen, lse_ref, 0), tol)
+    assert bool((jnp.where(seen, 0, lse) <= 0).all())
+    assert bool((lse[..., ~np.asarray(seen)] < -1e29).all())
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            w = seen.astype(jnp.float32)
+            return (_f32(o) * _f32(dy) * w[None, :, None]).sum() \
+                + (jnp.where(seen, lse, 0.0) * dlse).sum()
+        return f
+
+    got = jax.grad(loss(lambda q, k, v: FA.flash_bthd_lse(q, k, v, h, **kw)),
+                   (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _dense_block_causal(
+        q, k, v, h, hkv, mask_block, strict)[:2]), (0, 1, 2))(
+            _f32(q), _f32(k), _f32(v))
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape and a.dtype == dtype
+        assert bool(jnp.isfinite(_f32(a)).all()), name
+        _assert_close(name, a, b, tol)
+
+
+def test_block_mask_of_one_row_is_causal_bit_for_bit():
+    """`mask_block` 1 without `strict` IS causal: the same kernels on
+    the same operands, every bit."""
+    h, hkv, d, t = 4, 2, 128, 256
+    q, k, v, dy, _ = _gqa_inputs(h, hkv, d, t, jnp.bfloat16)
+    def grads(**kw):
+        f = lambda q, k, v: (_f32(FA.flash_bthd(
+            q, k, v, h, causal=True, force="interpret", n_kv_head=hkv,
+            block_q=128, block_k=128, **kw)) * _f32(dy)).sum()
+        return jax.value_and_grad(f, (0, 1, 2))(q, k, v)
+    (a, ga), (b, gb) = grads(), grads(mask_block=1, strict=False)
+    assert float(a) == float(b)
+    for x, y in zip(ga, gb):
+        assert bool((x == y).all())
+
+
+def test_what_the_kernels_cannot_take_goes_dense():
+    """A group of query heads shares a block of k only where a block is
+    one head, and a mask's block must divide the tiles: anything else is
+    dense math, also when a caller forces the kernel; the counter's
+    `mask` and `kv_groups` labels say what was asked."""
+    count = FA._LOWERINGS
+    q, k, v, _, _ = _gqa_inputs(4, 2, 64, 256, jnp.float32)
+    labels = dict(path="dense", entry="bthd", heads_per_block="2",
+                  backward="none", mask="block_causal_strict", kv_groups="2")
+    was = count.value(**labels)
+    o = FA.flash_bthd(q, k, v, 4, causal=True, force="interpret",
+                      n_kv_head=2, mask_block=4, strict=True)
+    assert count.value(**labels) == was + 1
+    o_ref, _, seen = _dense_block_causal(q, k, v, 4, 2, 4, True)
+    _assert_close("out", jnp.where(seen[None, :, None], o, 0), o_ref, 1e-5)
+    q, k, v, _, _ = _gqa_inputs(4, 2, 128, 256, jnp.float32)
+    labels = dict(path="interpret", entry="bthd", heads_per_block="1",
+                  backward="fused", mask="block_causal", kv_groups="2")
+    was = count.value(**labels)
+    FA.flash_bthd(q, k, v, 4, causal=True, force="interpret", n_kv_head=2,
+                  mask_block=32)
+    assert count.value(**labels) == was + 1
+    with pytest.raises(ValueError):
+        FA.flash_bthd(q, k, v, 4, causal=True, mask_block=6, n_kv_head=2)
+    with pytest.raises(ValueError):
+        FA.flash_bthd(q, k, v, 4, n_kv_head=3)
+
+
+# sha256 (first 16 hex digits) of dq, dk, dv as float32 bytes from the
+# ONE-block causal backward (flash_bwd, two heads of 64 to a block: the
+# path the benchmark's OPT cell takes) at commit a539599, PR 32's parent,
+# before the kernels learnt a mask and a group size.
+_PARENT_FUSED = {
+    ("float32", False): ("1848e6b5aed696ee", "f7afa5d50d2ee3ab", "c03bbf9fcf6e3b7b"),
+    ("float32", True): ("5e96e14ece489662", "1a733a770f92ac14", "c03bbf9fcf6e3b7b"),
+    ("bfloat16", False): ("7d7c2e4e9466d224", "b311016cf14f5045", "af90e6f377929259"),
+    ("bfloat16", True): ("9bb9de23b235f79a", "21cba15f2d22ecae", "af90e6f377929259"),
+}
+
+
+@pytest.mark.parametrize("with_dlse", [False, True], ids=["out", "out_lse"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_fused_causal_backward_is_the_parents_bit_for_bit(dtype, with_dlse):
+    """The causal path of the OPT cell (g 2, one block, flash_bwd)
+    lowers to the kernels it had: every bit of dq, dk, dv is what PR
+    32's parent gave at T 512."""
+    import hashlib
+    h, d = 4, 64
+    q, k, v, dy, dlse = _bthd_inputs(h, d, dtype, t=512, b=2, seed=9)
+
+    def f(q, k, v):
+        kw = dict(causal=True, force="interpret")
+        if not with_dlse:
+            return (_f32(FA.flash_bthd(q, k, v, h, **kw)) * _f32(dy)).sum()
+        o, lse = FA.flash_bthd_lse(q, k, v, h, **kw)
+        return (_f32(o) * _f32(dy)).sum() + (lse * dlse).sum()
+
+    grad = jax.grad(f, (0, 1, 2))
+    assert _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr) \
+        == ["flash_fwd", "flash_bwd"]
+    got = tuple(hashlib.sha256(np.asarray(_f32(g)).tobytes()
+                               ).hexdigest()[:16] for g in grad(q, k, v))
+    case = (jnp.dtype(dtype).name, with_dlse)
+    assert got == _PARENT_FUSED[case], (case, got)

@@ -873,6 +873,14 @@ COVERED_ELSEWHERE = {
     # the fusion tier pins golden rewrites + bitwise execution identity
     "fused_matmul_bias_act": "test_specialize.py",
     "fused_scale_cast": "test_specialize.py",
+    # the pre-norm block's pieces, the dropless expert layer and the
+    # block-diffusion objective (ISSUE 32), each against jax.numpy
+    "rms_norm": "test_block_diffusion.py",
+    "rope": "test_block_diffusion.py",
+    "silu_mul": "test_block_diffusion.py",
+    "block_diffusion_noise": "test_block_diffusion.py",
+    "block_diffusion_attention": "test_block_diffusion.py",
+    "routed_experts": "test_block_diffusion.py",
 }
 
 # ops with no one-op test by design; each entry documents why

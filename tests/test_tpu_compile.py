@@ -244,3 +244,82 @@ def test_kernel_name_is_in_the_lowered_text(chip, kernel):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, q, q).as_text()
     assert 'kernel_name = "%s"' % kernel in text
+
+
+# ISSUE 32: the block-diffusion cell's shapes. 32 query heads of 128
+# reading 4 key/value heads at T 4096 (streamed 1024-blocks, the two
+# backward kernels), under both variants of the block-granular mask; the
+# attention of the whole objective (two kernel calls merged by lse); and
+# the dropless expert layer at 16,384 rows over 16 held of 128 experts,
+# whose grouped matmuls are XLA's own `ragged-dot` kernels.
+@pytest.mark.parametrize("strict", [False, True],
+                         ids=["block_causal", "block_causal_strict"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_grouped_kv_block_causal_compiles_for_v5e(chip, strict, direction):
+    b, t, h, hkv, d = 2, 4096, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_bthd(q, k, v, h, causal=True, force="pallas",
+                          n_kv_head=hkv, mask_block=4, strict=strict)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compiled_text(fn, q, kv, kv)
+    names = ["flash_fwd"] + (_TWO if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+
+
+def test_block_diffusion_attention_compiles_for_v5e(chip):
+    """[noised; clean] rows of one step's two sequences, forward and
+    backward: two calls of each kernel and no [T, T] tensor: the largest
+    float32 buffer the program names is an operand's size."""
+    import math
+    import re
+    from paddle_tpu.ops import block_diffusion as BD
+    b, t, h, hkv, d = 2, 4096, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, 2 * t, h * d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, 2 * t, hkv * d), jnp.bfloat16,
+                              sharding=chip)
+
+    def loss(q, k, v):
+        return BD.attention(q, k, v, h, hkv, 4, force="pallas").astype(
+            jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") == 6
+    sizes = [math.prod(int(x) for x in dims.split(","))
+             for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert max(sizes) <= b * 2 * t * h * d
+
+
+def test_routed_experts_compile_for_v5e(chip):
+    """The cell's expert layer, forward and backward: grouped matmuls as
+    XLA's ragged-dot kernels inside the two loops over chunks, on a
+    chunk's 32,768 rows: no hidden activation of the worst case's
+    N * top_k rows exists."""
+    import re
+    from paddle_tpu.parallel import moe
+    n, d, f, e, held, k = 16384, 2048, 768, 128, 16, 8
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=chip)
+    x = sds((n, d), jnp.float32)
+    wr = sds((d, e), jnp.float32)
+    w_in, w_out = sds((held, d, f), jnp.bfloat16), sds((held, f, d),
+                                                       jnp.bfloat16)
+
+    def loss(x, wr, wg, wu, wd):
+        out, aux, _, _ = moe.routed_experts(x, wr, wg, wu, wd, e, 0, k)
+        return out.astype(jnp.float32).sum() + aux
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                          x, wr, w_in, w_in, w_out)
+    assert "ragged-dot" in text and "while" in text
+    hidden = {int(rows) for rows in re.findall(
+        r"(?:bf16|f32)\[(\d+),%d\]" % f, text)}
+    assert hidden and max(hidden) == 2 * n * k * held // e
