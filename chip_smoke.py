@@ -14,6 +14,11 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           sequence of [4096, 32 x 128] reading [4096, 4 x 128], blocks
           of 4, both variants): dq, dk, dv against dense float32 math,
           the first block's wholly masked rows finite and weighed out
+  rotary  QK-norm and RoPE in the projections' own layout (the kernel
+          pair of ops/rotary.py) at the block-diffusion cell's shapes,
+          q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
+          dScale against float32 math, and a call's time forward and
+          backward beside the HBM floor
   experts the dropless expert layer (16,384 rows over 16 held of 128
           experts, top-8): output and gradients against every held
           expert evaluated densely, and nothing dropped when every row
@@ -86,10 +91,14 @@ def kernels_in_interpret_mode():
     has no such option."""
     from paddle_tpu.ops import flash_attention as fa
     from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops import rotary
     fa_resolve = fa._resolve_path
     fa._resolve_path = lambda q, scale, bq, bk, force: fa_resolve(
         q, scale, bq, bk, force or "interpret")
     pa._resolve_path = lambda q, force: force or "interpret"
+    rotary_resolve = rotary._resolve_path
+    rotary._resolve_path = lambda x, d, rows, rotate, force: rotary_resolve(
+        x, d, rows, rotate, force or "interpret")
 
 
 # --------------------------------------------------------------------------
@@ -254,6 +263,71 @@ def phase_gqa(seed, rehearse):
         assert max(errs) <= FLASH_GRAD_TOL, errs
         if not rehearse:
             assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+
+
+def phase_rotary(seed, rehearse):
+    """The kernel pair that norms and turns q and k in the block-
+    diffusion step (ISSUE 33), at the cell's shapes, wrap L: output, dx
+    and dScale against the same math in float32 on the heads' view, and
+    the time of a call, forward and backward, beside the bytes it must
+    move at the HBM peak (x in and out forward; dy and x in, dx out
+    backward; q and k together 0.37 / 0.55 ms)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import rotary
+    b, t, d, calls = (2, 64, 128, 2) if rehearse else (2, 8192, 128, 16)
+    rng = np.random.RandomState(seed)
+    f32 = lambda x: x.astype(jnp.float32)
+    total = [0.0, 0.0, 0.0, 0.0]
+
+    def both(turn, x, w, dy):
+        out, vjp = jax.vjp(turn, x, w)
+        return (out,) + vjp(dy)
+
+    for n_head in (4, 2) if rehearse else (32, 4):
+        x, dy = (jnp.asarray(rng.randn(b, t, n_head * d) * 0.5, jnp.bfloat16)
+                 for _ in range(2))
+        w = jnp.asarray(1.0 + 0.1 * rng.randn(d), jnp.float32)
+        turn = lambda x, w, force=None: rotary.norm_rope(
+            x, w, n_head, 1e6, t // 2, 1e-6, force=force)
+        step = jax.jit(functools.partial(both, turn)).lower(x, w, dy).compile()
+        got, text = step(x, w, dy), step.as_text()
+        want = jax.jit(functools.partial(both, functools.partial(
+            turn, force="xla")))(f32(x), w, f32(dy))
+        errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+                for a, r in zip(got, want)]
+
+        # `calls` kernel calls in one executable, each fed the last one's
+        # result, so that the host's dispatch is paid once
+        def chain(x, w, dy):
+            for _ in range(calls):
+                x = turn(x, w)
+            pull = jax.vjp(turn, x, w)[1]
+            for _ in range(calls):
+                dy = pull(dy)[0]
+            return x, dy
+
+        forward = jax.jit(lambda x, w, dy: chain(x, w, dy)[0])
+        timed = []
+        for fn in (forward, jax.jit(chain)):
+            jax.block_until_ready(fn(x, w, dy))
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(x, w, dy))
+            timed.append((time.perf_counter() - t0) * 1e3 / calls)
+        floor = [k * x.size * 2 / 819e9 * 1e3 for k in (2, 3)]
+        # forward, its floor, backward, its floor
+        ms = (timed[0], floor[0], timed[1] - timed[0], floor[1])
+        total = [a + b for a, b in zip(total, ms)]
+        log("[rotary] x [%d, %d, %d x %d] bf16, wrap %d: out %.3e dx %.3e "
+            "dScale %.3e from float32 math; a call %.3f ms forward (floor "
+            "%.3f), %.3f ms backward (floor %.3f)" % (
+                b, t, n_head, d, t // 2, *errs, *ms))
+        assert max(errs) <= FLASH_GRAD_TOL, errs
+        if not rehearse:
+            assert "qk_norm_rope_fwd" in text and "qk_norm_rope_bwd" in text
+    log("[rotary] q and k together: %.3f ms forward (floor %.3f), %.3f ms "
+        "backward (floor %.3f)%s" % (*total, "  (REHEARSAL: a CPU's time, "
+                                     "no device number)" if rehearse else ""))
 
 
 def phase_experts(seed, rehearse):
@@ -698,6 +772,7 @@ def main():
     else:
         phase_flash(args.seed, args.rehearse)
         phase_gqa(args.seed, args.rehearse)
+        phase_rotary(args.seed, args.rehearse)
         phase_experts(args.seed, args.rehearse)
         phase_train(cfg, args.seed, args.rehearse)
         phase_serve(cfg, args.seed, args.rehearse)

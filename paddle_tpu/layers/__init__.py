@@ -17,8 +17,8 @@ from .nn import (  # noqa: F401
     layer_norm, matmul, mean, one_hot, reduce_max, reduce_mean, reduce_min,
     reduce_prod, reduce_sum, softmax, softmax_with_cross_entropy,
     square_error_cost, topk,
-    block_diffusion_attention, block_diffusion_noise, rms_norm, rope,
-    silu_mul,
+    block_diffusion_attention, block_diffusion_noise, qk_norm_rope,
+    rms_norm, rope, silu_mul,
 )
 from .ops import *  # noqa: F401,F403
 from .math_ops import scale  # noqa: F401

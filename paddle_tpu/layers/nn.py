@@ -459,17 +459,22 @@ def cos_sim(X, Y, name=None):
 # the pre-norm block's pieces and block-diffusion training (ISSUE 32)
 # ---------------------------------------------------------------------------
 
+def _norm_weight(helper, size):
+    """An RMSNorm's learned weight ``[size]``, float32, ones."""
+    from ..initializer import ConstantInitializer
+    return helper.create_parameter(
+        helper.param_attr, shape=[size], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+
+
 def rms_norm(input, epsilon=1e-6, groups=1, param_attr=None, name=None):
     """RMSNorm over the last dimension with a learned weight, computed
     in float32: ``x * rsqrt(mean(x^2) + eps) * w``. `groups` G > 1
     normalises each of G equal parts of the last dimension with ONE
     weight of their size: QK-norm over the heads of a projection's
     output ``[B, T, H * D]`` with G = H."""
-    from ..initializer import ConstantInitializer
     helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
-    scale = helper.create_parameter(
-        helper.param_attr, shape=[input.shape[-1] // groups],
-        dtype="float32", default_initializer=ConstantInitializer(1.0))
+    scale = _norm_weight(helper, input.shape[-1] // groups)
     out = helper.create_variable_for_type_inference(input.dtype,
                                                     shape=input.shape)
     helper.append_op(type="rms_norm", inputs={"X": [input],
@@ -489,6 +494,25 @@ def rope(input, n_head, theta=10000.0, wrap=0, name=None):
                      outputs={"Out": [out]},
                      attrs={"n_head": int(n_head), "theta": float(theta),
                             "wrap": int(wrap)})
+    return out
+
+
+def qk_norm_rope(input, n_head, theta=10000.0, wrap=0, epsilon=1e-6,
+                 param_attr=None, name=None):
+    """``rope(rms_norm(input, groups=n_head), n_head, theta, wrap)`` as
+    ONE op on ``[B, T, H * D]``: QK-norm under one learned weight ``[D]``
+    (named by `param_attr`, as ``rms_norm``'s) and the rotary embedding
+    of each head, float32 from end to end, in the layout a projection
+    leaves (``ops/rotary.py``)."""
+    helper = LayerHelper("qk_norm_rope", param_attr=param_attr, name=name)
+    scale = _norm_weight(helper, input.shape[-1] // n_head)
+    out = helper.create_variable_for_type_inference(input.dtype,
+                                                    shape=input.shape)
+    helper.append_op(type="qk_norm_rope",
+                     inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Out": [out]},
+                     attrs={"n_head": int(n_head), "theta": float(theta),
+                            "wrap": int(wrap), "epsilon": epsilon})
     return out
 
 

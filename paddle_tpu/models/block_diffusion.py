@@ -36,16 +36,16 @@ def pre_norm_block(x, name, seq_len, n_head, n_kv_head, head_dim,
     ``(y, aux_loss)``. Parameters are named from
     `name`: ``_ln1``, ``_wq``, ``_wk``, ``_wv``, ``_q_norm``,
     ``_k_norm``, ``_wo``, ``_ln2`` and the expert layer's ``_moe.*``."""
-    norm = lambda v, part, groups=1: layers.rms_norm(
-        v, epsilon=rms_eps, groups=groups,
-        param_attr=fluid.ParamAttr(name="%s_%s" % (name, part)))
+    named = lambda part: fluid.ParamAttr(name="%s_%s" % (name, part))
+    norm = lambda v, part: layers.rms_norm(v, epsilon=rms_eps,
+                                           param_attr=named(part))
+    qk = lambda v, part, heads: layers.qk_norm_rope(
+        v, heads, rope_theta, seq_len, rms_eps, param_attr=named(part))
     h = norm(x, "ln1")
     q = _linear(h, n_head * head_dim, name + "_wq")
     k = _linear(h, n_kv_head * head_dim, name + "_wk")
     v = _linear(h, n_kv_head * head_dim, name + "_wv")
-    q = layers.rope(norm(q, "q_norm", n_head), n_head, rope_theta, seq_len)
-    k = layers.rope(norm(k, "k_norm", n_kv_head), n_kv_head, rope_theta,
-                    seq_len)
+    q, k = qk(q, "q_norm", n_head), qk(k, "k_norm", n_kv_head)
     attn = layers.block_diffusion_attention(q, k, v, n_head, n_kv_head,
                                             block_length)
     x = layers.elementwise_add(x, _linear(attn, int(x.shape[-1]),

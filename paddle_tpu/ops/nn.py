@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
+from .rotary import norm_rope
 
 
 @register("softmax")
@@ -221,15 +222,9 @@ def _im2sequence(ctx, op):
 # ---------------------------------------------------------------------------
 # the pre-norm block's pieces (ISSUE 32): RMSNorm, rotary embedding and
 # the gated FFN's product. Each computes in float32 and hands back x's
-# dtype, so that under AMP a bfloat16 activation stays one.
-def rms_norm_of(x, scale, epsilon):
-    """x * rsqrt(mean(x^2) + eps) * scale over the last dimension."""
-    x32 = x.astype(jnp.float32)
-    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
-                        + epsilon)
-    return (x32 * inv * scale.astype(jnp.float32)).astype(x.dtype)
-
-
+# dtype, so that under AMP a bfloat16 activation stays one. The norm and
+# the rotation work on x as it comes, [.., H*D] (ops/rotary.py: one
+# kernel pair where a head is whole lane tiles, no heads' view anywhere).
 @register("rms_norm")
 def _rms_norm(ctx, op):
     """X [..., G * D] with Scale [D]: RMSNorm over each of the G groups
@@ -237,33 +232,28 @@ def _rms_norm(ctx, op):
     number of heads: QK-norm, one weight shared by the heads)."""
     x = ctx.in1(op, "X")
     scale = ctx.in1(op, "Scale")
-    grouped = x.reshape(x.shape[:-1] + (-1, scale.shape[0]))
-    ctx.set_out(op, "Out", rms_norm_of(
-        grouped, scale, op.attr("epsilon", 1e-6)).reshape(x.shape))
-
-
-def rope_of(x, n_head, theta, wrap=0):
-    """Rotary embedding, rotate-half form, of x [B, T, H * D] by each
-    row's position: its index, taken modulo `wrap` where given."""
-    b, t, hd = x.shape
-    d = hd // n_head
-    pos = jnp.arange(t)
-    if wrap:
-        pos = pos % wrap
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[:, None] * inv_freq          # [T, D/2]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None]
-    x32 = x.astype(jnp.float32).reshape(b, t, n_head, d)
-    turned = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
-    return (x32 * cos + turned * sin).reshape(x.shape).astype(x.dtype)
+    ctx.set_out(op, "Out", norm_rope(
+        x, scale, x.shape[-1] // scale.shape[0],
+        epsilon=op.attr("epsilon", 1e-6)))
 
 
 @register("rope")
 def _rope(ctx, op):
-    ctx.set_out(op, "Out", rope_of(
-        ctx.in1(op, "X"), int(op.attr("n_head")),
+    """Rotary embedding, rotate-half form, of X [B, T, H * D] by each
+    row's position: its index, taken modulo `wrap` where given."""
+    ctx.set_out(op, "Out", norm_rope(
+        ctx.in1(op, "X"), None, int(op.attr("n_head")),
         float(op.attr("theta", 10000.0)), int(op.attr("wrap", 0))))
+
+
+@register("qk_norm_rope")
+def _qk_norm_rope(ctx, op):
+    """rope(rms_norm(X)) over the `n_head` heads of X [B, T, H * D]
+    under one Scale [D], float32 from end to end."""
+    ctx.set_out(op, "Out", norm_rope(
+        ctx.in1(op, "X"), ctx.in1(op, "Scale"), int(op.attr("n_head")),
+        float(op.attr("theta", 10000.0)), int(op.attr("wrap", 0)),
+        op.attr("epsilon", 1e-6)))
 
 
 @register("silu_mul")

@@ -1,0 +1,336 @@
+"""Per-head RMSNorm and rotary embedding in the projections' own layout.
+
+QK-norm and RoPE work on the heads of a projection's output. That
+output is [B, T, H*D], and on the chip its last two dimensions are
+tiled (8 rows of T, 128 lanes); the heads' view [B, T, H, D] is tiled
+(8 HEADS, 128 lanes), so every reshape between the two is a physical
+copy of the whole tensor, rotate-half's slices and concatenation are
+more, 4 key/value heads pad to 8 sublanes, and autodiff repeats all of
+it backward (41 + 23 ms of a 416 ms step in `sdar_train_bd4k`; PERF.md
+section 5, PR 32). Where D is a multiple of 128 a head IS whole lane
+tiles of the [B*T, H*D] view, so nothing has to move: the norm is a
+lane reduction inside each head's lanes and rotate-half a roll by D/2
+lanes inside them.
+
+One kernel pair, `qk_norm_rope_fwd` and `qk_norm_rope_bwd` (named in
+the trace). A grid step holds [rows, g*D] of the [B*T, H*D] view (a
+leading-dimension collapse, free), g whole heads that take turns:
+
+  forward   x -> float32; `inv = rsqrt(mean(x^2) + eps)` over the
+            head's lanes; `y = x * inv * w` (w [D], resident); then
+            `y * cos + roll(y, D/2) * sin_signed` against a float32
+            [period, D] table of cos and sign-folded sin (-sin in the
+            first half, +sin in the second) made ONCE an op outside the
+            kernel; the block's row index modulo the period picks its
+            table rows (period: `wrap` where it divides T, the noised
+            and the clean half sharing positions 0..L-1; else T).
+            Output in x's dtype.
+  backward  the transposed rotation (the same table, sin negated), then
+            RMSNorm's gradient with `inv` RECOMPUTED from the saved x
+            (x is saved as it came: no float32 copy is kept), and dScale
+            as per-block partial sums [blocks, 8, D] that XLA adds.
+
+Static flags `norm` (a Scale is given) and `rotate` (a theta is given)
+let the one body serve the fused op and each op alone, so the Program
+ops `qk_norm_rope`, `rms_norm` (grouped) and `rope` all lower here.
+
+Dispatch (`norm_rope`): the kernel where there are heads (or a
+rotation), D is a multiple of 128, the rows can be cut into blocks and
+the backend is a TPU; any other head size or backend takes the
+jax.numpy form (`_xla`), float32 inside and x's dtype out like the
+kernel, with the heads' view only where there are heads: one group
+(the stream's norms) is computed on x as it comes, on every backend,
+and XLA fuses it with its neighbours. Each dispatch
+counts itself at trace time in `ptpu_rotary_lowerings_total{path,
+heads, head_dim, norm, rotate}` (path: "pallas" / "interpret" / "xla").
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..monitor import metrics as _metrics
+from .flash_attention import _largest_divisor, _on_tpu
+
+_LANES = 128
+# A grid step's block of x: at most _MAX_LANES lanes of whole heads and
+# _BLOCK_BYTES in x's dtype (the backward holds three such blocks, dy, x
+# and dx, twice over for the double buffering, beside a head's float32
+# temporaries, in 16 MB of scoped VMEM). Measured on one TPU v5e, q
+# [2, 8192, 32 x 128] bf16 with the norm and the rotation, ms a call
+# forward / backward (my chip run, PR 33; the floors at 819 GB/s are
+# 0.328 / 0.492; the jax.numpy form on the heads' view 10.27 / 9.11):
+#   [1024, 1024]  0.455  0.678      [128, 4096]  0.673  4.435
+#   [512, 1024]   0.455  0.702      [64, 4096]   1.093  1.243
+#   [256, 2048]   0.480  0.698      [256, 4096]  out of VMEM
+#   [1024, 512]   0.459  0.700      [256, 1024]  0.541  0.756
+#   [4096, 128]   0.472  0.704      [256, 512]   0.617  0.837
+# So 512 rows or more of at most 1024 lanes: 32 heads taking turns in
+# one block spill (the backward carries dScale's partial sums across
+# them), and under 512 rows a grid step's hand-over shows. One group of
+# 2048 float32 values a row ([2, 8192, 2048], the stream's norms) at
+# [128, 2048]: 0.428 / 0.628 against XLA's 0.609 / 1.164; at [256, 2048]
+# the backward's temporaries run out of VMEM.
+_BLOCK_BYTES = 1024 * 1024
+_MAX_LANES = 1024
+
+_REG = _metrics.registry()
+_LOWERINGS = _REG.counter(
+    "ptpu_rotary_lowerings_total",
+    "per-head RMSNorm / rotary embedding dispatches at trace time (one a "
+    "lowering of the op, none a step): the path taken, the heads and their "
+    "size, and which of the two halves of the work the call asked for",
+    ("path", "heads", "head_dim", "norm", "rotate"))
+
+
+def _period(t, wrap):
+    """Rows after which the positions repeat in the [B*T] view."""
+    return wrap if wrap and t % wrap == 0 else t
+
+
+def _angles(t, wrap, d, theta):
+    """(cos, sin), float32 [period, d / 2], of the positions of the
+    first `period` of t rows: a row's index, modulo `wrap` where
+    given."""
+    pos = jnp.arange(_period(t, wrap))
+    if wrap:
+        pos = pos % wrap
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, None] * inv_freq
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def rope_table(t, wrap, d, theta):
+    """The kernels' table (cos, sin_signed), float32 [period, d], in
+    rotate-half form: both halves of d hold the same angles, and sin
+    carries rotate-half's sign (-sin, +sin), so that
+    `y * cos + roll(y, d/2) * sin_signed` is the rotation."""
+    cos, sin = _angles(t, wrap, d, theta)
+    return (jnp.concatenate([cos, cos], -1),
+            jnp.concatenate([-sin, sin], -1))
+
+
+def _xla(x, scale, n_head, theta, wrap, epsilon):
+    """The jax.numpy form: float32 inside, x's dtype out."""
+    hd = x.shape[-1]
+    d = hd // n_head
+    y = x.astype(jnp.float32)
+    if n_head > 1:
+        y = y.reshape(x.shape[:-1] + (n_head, d))
+    if scale is not None:
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                            + epsilon)
+        y = y * inv * scale.astype(jnp.float32)
+    if theta is not None:
+        t = x.shape[-2]
+        cos, sin = (jnp.tile(half, (t // half.shape[0], 2))
+                    for half in _angles(t, wrap, d, theta))
+        if n_head > 1:
+            cos, sin = cos[:, None], sin[:, None]
+        turned = jnp.concatenate([-y[..., d // 2:], y[..., :d // 2]], -1)
+        y = y * cos + turned * sin
+    return y.reshape(x.shape).astype(x.dtype)
+
+
+# --------------------------------------------------------------------------
+# the kernels. refs, in order: x [w] [cos sin] -> out forward, and
+# dy [x w] [cos sin] -> dx [dw] backward; a head is the lanes
+# [a d, (a + 1) d) of the block, static slices of whole lane tiles.
+def _take(refs, norm, rotate):
+    """(the weight where `norm`, the table where `rotate`, the refs
+    left)."""
+    refs = list(refs)
+    w = refs.pop(0)[...] if norm else None
+    table = (refs.pop(0)[...], refs.pop(0)[...]) if rotate else None
+    return w, table, refs
+
+
+def _inv_rms(x, eps):
+    return jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+
+
+def _fwd_kernel(x_ref, *refs, norm, rotate, d, g, eps):
+    w, table, (o_ref,) = _take(refs, norm, rotate)
+    for a in range(g):
+        lanes = slice(a * d, (a + 1) * d)
+        y = x_ref[:, lanes].astype(jnp.float32)
+        if norm:
+            y = y * _inv_rms(y, eps) * w
+        if rotate:
+            y = y * table[0] + pltpu.roll(y, d // 2, 1) * table[1]
+        o_ref[:, lanes] = y.astype(o_ref.dtype)
+
+
+def _bwd_kernel(dy_ref, *refs, norm, rotate, d, g, eps):
+    x_ref = refs[0] if norm else None
+    w, table, outs = _take(refs[norm:], norm, rotate)
+    dx_ref = outs[0]
+    rows = dy_ref.shape[0]
+    dw = jnp.zeros((8, d), jnp.float32)
+    for a in range(g):
+        lanes = slice(a * d, (a + 1) * d)
+        gy = dy_ref[:, lanes].astype(jnp.float32)
+        if rotate:
+            gy = gy * table[0] - pltpu.roll(gy, d // 2, 1) * table[1]
+        if norm:
+            x = x_ref[:, lanes].astype(jnp.float32)
+            inv = _inv_rms(x, eps)
+            xhat = x * inv
+            # eight partial sums down the sublanes: adds, no shuffle
+            dw = dw + (gy * xhat).reshape(rows // 8, 8, d).sum(0)
+            gy = gy * w
+            gy = inv * (gy - xhat * jnp.mean(gy * xhat, -1, keepdims=True))
+        dx_ref[:, lanes] = gy.astype(dx_ref.dtype)
+    if norm:
+        outs[1][...] = dw
+
+
+def _call(kernel, name, rowwise, scale, cos, sin, d, eps, rows, lanes,
+          interpret):
+    """One of the two kernels over the [N, H*D] operands `rowwise` (x,
+    or dy and the saved x): a result of their shape and dtype and, from
+    the backward under a norm, dScale's partial sums a block."""
+    norm, rotate = scale is not None, cos is not None
+    n, hd = rowwise[0].shape
+    grid = (n // rows, hd // lanes)
+    block = pl.BlockSpec((rows, lanes), lambda i, j: (i, j))
+    operands, specs = list(rowwise), [block] * len(rowwise)
+    out_shape = [jax.ShapeDtypeStruct((n, hd), rowwise[0].dtype)]
+    out_specs = [block]
+    if norm:
+        operands.append(scale.astype(jnp.float32).reshape(1, d))
+        specs.append(pl.BlockSpec((1, d), lambda i, j: (0, 0)))
+        if kernel is _bwd_kernel:
+            out_shape.append(jax.ShapeDtypeStruct(grid + (8, d), jnp.float32))
+            out_specs.append(pl.BlockSpec((None, None, 8, d),
+                                          lambda i, j: (i, j, 0, 0)))
+    if rotate:
+        # the table's blocks come round every `turns` blocks of rows
+        turns = cos.shape[0] // rows
+        table = pl.BlockSpec((rows, d), lambda i, j: (i % turns, 0))
+        operands += [cos, sin]
+        specs += [table, table]
+    return pl.pallas_call(
+        functools.partial(kernel, norm=norm, rotate=rotate, d=d,
+                          g=lanes // d, eps=eps),
+        grid=grid, in_specs=specs, out_specs=out_specs, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret, name=name,
+    )(*operands)
+
+
+# jitted as the flash kernels are: a stack of layers traces and lowers
+# each kernel once
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _rotary_fwd(x, scale, cos, sin, *static):
+    return _call(_fwd_kernel, "qk_norm_rope_fwd", [x], scale, cos, sin,
+                 *static)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _rotary_bwd(dy, x, scale, cos, sin, *static):
+    if scale is None:
+        return _call(_bwd_kernel, "qk_norm_rope_bwd", [dy], None, cos, sin,
+                     *static)[0], None
+    dx, partial_sums = _call(_bwd_kernel, "qk_norm_rope_bwd", [dy, x], scale,
+                             cos, sin, *static)
+    return dx, partial_sums.sum((0, 1, 2)).astype(scale.dtype)
+
+
+# x [N, H*D], scale [D] or None, the table or (None, None); static: d,
+# eps, rows, lanes, interpret
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _norm_rope(x, scale, cos, sin, *static):
+    return _rotary_fwd(x, scale, cos, sin, *static)
+
+
+def _norm_rope_fwd(x, scale, cos, sin, *static):
+    saved = x if scale is not None else None
+    return _rotary_fwd(x, scale, cos, sin, *static), (saved, scale, cos, sin)
+
+
+def _norm_rope_bwd(*args):
+    *static, (x, scale, cos, sin), dy = args
+    dx, dscale = _rotary_bwd(dy, x, scale, cos, sin, *static)
+    zeros = lambda tab: None if tab is None else jnp.zeros_like(tab)
+    return dx, dscale, zeros(cos), zeros(sin)
+
+
+_norm_rope.defvjp(_norm_rope_fwd, _norm_rope_bwd)
+
+
+def _blocks(n, period, hd, d, itemsize):
+    """(rows, lanes) of a grid step's block of the [n, hd] view: as many
+    whole heads as _MAX_LANES hold, and the largest number of rows up
+    to _BLOCK_BYTES that divides the period of the positions (n where
+    nothing rotates) in whole sublane tiles of x's dtype; rows 0 where
+    there is none."""
+    lanes = d * _largest_divisor(hd // d, max(_MAX_LANES // d, 1))
+    tile = 32 // itemsize
+    most = _BLOCK_BYTES // (lanes * itemsize) // tile
+    rows = period or n
+    if rows % tile or not most:
+        return 0, lanes
+    return tile * _largest_divisor(rows // tile, most), lanes
+
+
+def _resolve_path(x, d, rows, rotate, force):
+    """ "pallas" / "interpret" / "xla": auto takes the kernel on a TPU
+    where a head is whole lane tiles, the rows cut into blocks and the
+    jax.numpy form would need the heads' view or rotate-half's slices.
+    ONE group over all of the last dimension, not rotated, is a plain
+    row reduction that XLA fuses with its neighbours (the residual add
+    before it, the projections' cast after it): in the block-diffusion
+    step the kernel there was 2 ms a step slower than leaving it to XLA
+    (my chip run, PR 33), though faster called alone."""
+    if force is None:
+        usable = (d % _LANES == 0 and rows > 0
+                  and (rotate or d != x.shape[-1]))
+        return "pallas" if usable and _on_tpu(x) else "xla"
+    if force != "xla" and not rows:
+        raise ValueError("norm_rope: %s rows of %s cannot be cut into "
+                         "blocks of whole sublane tiles" % (x.shape, x.dtype))
+    return force
+
+
+def norm_rope(x, scale=None, n_head=1, theta=None, wrap=0, epsilon=1e-6,
+              force=None):
+    """RMSNorm over each of the `n_head` heads of x [..., H*D]'s last
+    dimension with ONE weight `scale` [D] (None: no norm), then the
+    rotary embedding of each head, rotate-half form, by the row's
+    position (`theta` None: no rotation): its index in x [B, T, H*D]'s
+    T, taken modulo `wrap` where given. float32 inside, x's dtype out.
+
+    force: None = auto, "pallas" / "interpret" / "xla" pin a path (tests
+    run the kernel on the CPU with "interpret")."""
+    norm, rotate = scale is not None, theta is not None
+    hd = x.shape[-1]
+    d = hd // n_head
+    if hd % n_head or (norm and scale.shape != (d,)) or (rotate and d % 2):
+        raise ValueError(
+            "norm_rope: x of shape %s is not %d heads of an even size "
+            "under a weight of shape %s"
+            % (x.shape, n_head, scale.shape if norm else None))
+    if rotate and x.ndim != 3:
+        raise ValueError("norm_rope: rotary positions want x [B, T, H*D], "
+                         "got %s" % (x.shape,))
+    n = x.size // hd
+    period = _period(x.shape[1], wrap) if rotate else 0
+    rows, lanes = _blocks(n, period, hd, d, x.dtype.itemsize)
+    path = _resolve_path(x, d, rows, rotate, force)
+    _LOWERINGS.inc(path=path, heads=str(n_head), head_dim=str(d),
+                   norm=str(norm).lower(), rotate=str(rotate).lower())
+    if path == "xla":
+        return _xla(x, scale, n_head, theta, wrap, epsilon)
+    cos, sin = rope_table(x.shape[1], wrap, d, float(theta)) if rotate \
+        else (None, None)
+    return _norm_rope(x.reshape(n, hd), scale, cos, sin, d, float(epsilon),
+                      rows, lanes, path == "interpret").reshape(x.shape)
+
+
+# pallas imports placed at the end, as in flash_attention.py: a CPU-only
+# environment that never takes the kernel path still imports this module
+from jax.experimental import pallas as pl                    # noqa: E402
+from jax.experimental.pallas import tpu as pltpu             # noqa: E402

@@ -5,7 +5,9 @@ against a dense loop over experts, the two-piece attention against the
 2L x 2L mask written out, and the whole small model against the
 benchmark's float32 reference (``chipbench/reference/sdar_lm.py``).
 The ops: "rms_norm", "rope", "silu_mul", "block_diffusion_noise",
-"block_diffusion_attention", "routed_experts".
+"block_diffusion_attention", "routed_experts", and (ISSUE 33)
+"qk_norm_rope" with the kernel pair of ``ops/rotary.py`` in interpret
+mode against the reference's ``_rms`` and ``_rope``.
 """
 
 import os
@@ -19,6 +21,7 @@ import pytest
 import paddle_tpu as fluid
 from op_test import check_grad, check_output, run_op
 from paddle_tpu.ops import block_diffusion as BD
+from paddle_tpu.ops import rotary
 from paddle_tpu.parallel import moe
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -59,6 +62,129 @@ def test_rope_op(wrap):
     if wrap:
         assert not np.allclose(got[:, 1], got[:, 5])   # other rows, same turn
     check_grad("rope", {"X": x}, attrs, ["X"])
+
+
+# -- QK-norm and RoPE in the projections' own layout (ISSUE 33) ---------------
+
+def _reference_norm_rope(x, w, n_head, wrap, norm=True, rotate=True):
+    """The benchmark reference's ``_rope(_rms(x))`` a sequence at a
+    time, on the heads' view it works in."""
+    b, t, hd = x.shape
+    pos = jnp.arange(t) % wrap if wrap else jnp.arange(t)
+    rows = []
+    for row in x:
+        y = row.reshape(t, n_head, hd // n_head)
+        if norm:
+            y = sdar_lm._rms(y, w, 1e-6)
+        if rotate:
+            y = sdar_lm._rope(y, pos, 1e6)
+        rows.append(y.reshape(t, hd))
+    return jnp.stack(rows)
+
+
+def _largest(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+# L 136: 272 rows a sequence, which the 256 rows that 1 MB of float32
+# holds at 1024 lanes do not divide: a block is the period's divisor, 136
+@pytest.mark.parametrize("wrap", [0, 136], ids=["by_index", "two_halves"])
+@pytest.mark.parametrize("n_head", [32, 4], ids=["q_heads", "kv_heads"])
+def test_norm_rope_kernel_is_the_reference(n_head, wrap):
+    """The kernel pair in interpret mode at D 128: the output and the
+    gradients of x and of Scale against the reference's `_rms` and
+    `_rope` and against the jax.numpy form; under `wrap` the gradient
+    passes through the shared positions: the clean half's rows turn as
+    the noised half's do."""
+    d, t = 128, 272
+    x = jnp.asarray(_r(2, t, n_head * d, seed=21))
+    w = jnp.asarray(1.0 + _r(d, seed=22, scale=0.1))
+    dy = jnp.asarray(_r(2, t, n_head * d, seed=23))
+    rows, lanes = rotary._blocks(2 * t, rotary._period(t, wrap), n_head * d,
+                                 d, 4)
+    assert (rows, lanes) == (136 if wrap or n_head == 32 else 272,
+                             min(n_head * d, 1024))
+    run = lambda force: lambda x, w: rotary.norm_rope(
+        x, w, n_head, 1e6, wrap, 1e-6, force=force)
+    want, pull = jax.vjp(
+        lambda x, w: _reference_norm_rope(x, w, n_head, wrap), x, w)
+    for force in ("interpret", "xla"):
+        got, vjp = jax.vjp(run(force), x, w)
+        assert _largest(got, want) < 1e-6
+        for mine, ref in zip(vjp(dy), pull(dy)):
+            assert _largest(mine, ref) < 2e-6
+    if wrap:
+        twice = jnp.concatenate([x[:, :wrap]] * 2, 1)
+        got, vjp = jax.vjp(run("interpret"), twice, w)
+        np.testing.assert_array_equal(got[:, :wrap], got[:, wrap:])
+        dx = vjp(jnp.concatenate([dy[:, :wrap]] * 2, 1))[0]
+        np.testing.assert_array_equal(dx[:, :wrap], dx[:, wrap:])
+
+
+@pytest.mark.parametrize("norm, rotate", [(True, False), (False, True)],
+                         ids=["norm_alone", "rope_alone"])
+def test_norm_rope_kernel_serves_each_op_alone(norm, rotate):
+    """One flag off: the grouped `rms_norm` and `rope` lower to the same
+    kernel body; bfloat16 in, bfloat16 out, float32 inside."""
+    n_head, d, t = 4, 128, 32
+    x = jnp.asarray(_r(2, t, n_head * d, seed=24))
+    w = jnp.asarray(1.0 + _r(d, seed=25, scale=0.1))
+    dy = jnp.asarray(_r(2, t, n_head * d, seed=26))
+    args = (n_head, 1e6 if rotate else None, 16, 1e-6)
+    run = lambda x, w: rotary.norm_rope(x, w if norm else None, *args,
+                                        force="interpret")
+    want, pull = jax.vjp(lambda x, w: _reference_norm_rope(
+        x, w, n_head, 16, norm, rotate), x, w)
+    got, vjp = jax.vjp(run, x, w)
+    assert _largest(got, want) < 1e-6
+    dx, dw = vjp(dy)
+    assert _largest(dx, pull(dy)[0]) < 2e-6
+    assert _largest(dw, pull(dy)[1]) < 2e-6 if norm else not dw.any()
+    half = run(x.astype(jnp.bfloat16), w)
+    assert half.dtype == jnp.bfloat16
+    assert _largest(half.astype(jnp.float32), want) < 2 ** -7
+
+
+def test_qk_norm_rope_op_is_rope_of_rms_norm():
+    """The fused op against the two ops one after the other, and its
+    gradients against finite differences."""
+    x, w = _r(2, 8, 4 * 16, seed=27), 1.0 + _r(16, seed=28, scale=0.1)
+    attrs = {"n_head": 4, "theta": 1e6, "wrap": 4, "epsilon": 1e-6}
+    normed = run_op("rms_norm", {"X": x, "Scale": w}, {"epsilon": 1e-6},
+                    ["Out"])["Out"]
+    want = run_op("rope", {"X": normed},
+                  {k: attrs[k] for k in ("n_head", "theta", "wrap")},
+                  ["Out"])["Out"]
+    check_output("qk_norm_rope", {"X": x, "Scale": w}, attrs, {"Out": want},
+                 rtol=1e-5, atol=1e-6)
+    check_grad("qk_norm_rope", {"X": x, "Scale": w}, attrs, ["X", "Scale"])
+
+
+def test_rotary_lowering_counter_says_which_path_engaged():
+    """`ptpu_rotary_lowerings_total{path, heads, head_dim, norm,
+    rotate}`: one count a lowering, whichever path: the kernel in
+    interpret mode, the jax.numpy form off the chip (what the Program's
+    ops take on the CPU, at any head size), and `pallas` never here."""
+    count = rotary._LOWERINGS
+    x, w = jnp.asarray(_r(1, 16, 2 * 128, seed=29)), jnp.ones(128)
+    for force, path, scale, theta in (
+            ("interpret", "interpret", w, 1e6), (None, "xla", w, 1e6),
+            (None, "xla", w, None), ("interpret", "interpret", None, 1e6)):
+        labels = dict(path=path, heads="2", head_dim="128",
+                      norm=str(scale is not None).lower(),
+                      rotate=str(theta is not None).lower())
+        was = count.value(**labels)
+        rotary.norm_rope(x, scale, 2, theta, force=force)
+        assert count.value(**labels) == was + 1
+    labels = dict(path="xla", heads="4", head_dim="16", norm="true",
+                  rotate="true")
+    was = count.value(**labels)
+    run_op("qk_norm_rope", {"X": _r(2, 8, 64), "Scale": np.ones(16, "f")},
+           {"n_head": 4, "theta": 1e6, "wrap": 4, "epsilon": 1e-6}, ["Out"])
+    assert count.value(**labels) == was + 1
+    rendered = fluid.monitor.metrics.registry().render_prometheus()
+    assert "ptpu_rotary_lowerings_total" in rendered
+    assert 'ptpu_rotary_lowerings_total{path="pallas"' not in rendered
 
 
 def test_silu_mul_op():

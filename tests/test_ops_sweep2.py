@@ -877,6 +877,7 @@ COVERED_ELSEWHERE = {
     # block-diffusion objective (ISSUE 32), each against jax.numpy
     "rms_norm": "test_block_diffusion.py",
     "rope": "test_block_diffusion.py",
+    "qk_norm_rope": "test_block_diffusion.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",

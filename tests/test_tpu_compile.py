@@ -17,6 +17,7 @@ phase's pool — 8 slots x max_len 1024 at block 16 -> a [512, 8, 16,
 (speculative scoring) and the prefill chunk.
 """
 
+import functools
 import os
 
 import pytest
@@ -296,6 +297,74 @@ def test_block_diffusion_attention_compiles_for_v5e(chip):
     sizes = [math.prod(int(x) for x in dims.split(","))
              for dims in re.findall(r"f32\[([\d,]+)\]", text)]
     assert max(sizes) <= b * 2 * t * h * d
+
+
+def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
+    """The block-diffusion cell's attention up to its kernels (ISSUE
+    33): projections, QK-norm and RoPE as ONE op each for q and k, then
+    the flash kernels, compiled for the v5e. Forward: two
+    `qk_norm_rope_fwd` calls, and between `mul` and `flash_fwd` no
+    `reshape`, `concatenate`, `pad`, `slice`, `copy` or `transpose`
+    result of q's or k's size: both stay [B, T, H*D] bfloat16 as the
+    projections' matmuls write them. Backward: two `qk_norm_rope_bwd`
+    more, and no such result that the same layer with neither norm nor
+    rotation does not have (the flash backward sums dk and dv over each
+    group of query heads on a copy). Each op alone (`rms_norm` grouped,
+    `rope`) lowers to the same kernels."""
+    import math
+    import re
+    from paddle_tpu.ops import rotary
+    b, t, h, hkv, d, dm = 2, 4096, 32, 4, 128, 2048
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=chip)
+    scale = jax.ShapeDtypeStruct((d,), jnp.float32, sharding=chip)
+    avals = (sds(b, t, dm), sds(dm, h * d), sds(dm, hkv * d),
+             sds(dm, hkv * d), scale, scale)
+    moves = ("reshape", "concatenate", "pad", "slice", "copy", "transpose")
+
+    def layer(fused, x, wq, wk, wv, sq, sk):
+        if fused is None:
+            turn = lambda y, s, n: y
+        elif fused:
+            turn = lambda y, s, n: rotary.norm_rope(y, s, n, 1e6, t // 2,
+                                                    1e-6, force="pallas")
+        else:
+            turn = lambda y, s, n: rotary.norm_rope(
+                rotary.norm_rope(y, s, n, force="pallas"), None, n, 1e6,
+                t // 2, force="pallas")
+        return flash_bthd(turn(x @ wq, sq, h), turn(x @ wk, sk, hkv), x @ wv,
+                          h, causal=True, force="pallas", n_kv_head=hkv,
+                          mask_block=4)
+
+    def sized(text):
+        """(op, line) of every result of q's or k's size."""
+        found = []
+        for line in text.split("\n"):
+            m = re.search(r"= (?:bf16|f32)\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(",
+                          line)
+            if m and math.prod(int(n) for n in m.group(1).split(",")) in (
+                    b * t * h * d, b * t * hkv * d):
+                found.append((m.group(2), line))
+        return found
+
+    calls = lambda text, name: len(re.findall(r"%%%s[.\d]* = " % name, text))
+    moved = lambda text: [op for op, _ in sized(text) if op in moves]
+    grad = lambda fused: jax.grad(
+        lambda *a: layer(fused, *a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3))
+    bare = moved(_compiled_text(grad(None), *avals))
+    for fused, n in ((True, 2), (False, 4)):
+        text = _compiled_text(functools.partial(layer, fused), *avals)
+        assert calls(text, "qk_norm_rope_fwd") == n
+        assert text.count("tpu_custom_call") == n + 1
+        assert len(sized(text)) >= n and not moved(text)
+        text = _compiled_text(grad(fused), *avals)
+        assert calls(text, "qk_norm_rope_fwd") == n
+        assert calls(text, "qk_norm_rope_bwd") == n
+        assert text.count("tpu_custom_call") == 2 * n + 3
+        # the group sum's result is relaid once for dk and once for dv
+        # either way, as a `reshape` or as a `copy`
+        assert len(moved(text)) == len(bare) == 4
 
 
 def test_routed_experts_compile_for_v5e(chip):
