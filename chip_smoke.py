@@ -14,6 +14,12 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           sequence of [4096, 32 x 128] reading [4096, 4 x 128], blocks
           of 4, both variants): dq, dk, dv against dense float32 math,
           the first block's wholly masked rows finite and weighed out
+  mla     the two-part score of latent attention through the streamed
+          kernels at the cell xing4_train_T4k's shape (one sequence of
+          [4096, 32 x 128] with q_pe [4096, 32 x 64] reading ONE k_pe
+          [4096, 64], values 128 wide): dq_nope, dq_pe, dk_nope, dk_pe
+          (summed over the 32 heads in the kernel) and dv against dense
+          float32 math
   rotary  QK-norm and RoPE in the projections' own layout (the kernel
           pair of ops/rotary.py) at the block-diffusion cell's shapes,
           q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
@@ -263,6 +269,57 @@ def phase_gqa(seed, rehearse):
         assert max(errs) <= FLASH_GRAD_TOL, errs
         if not rehearse:
             assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+
+
+def phase_mla(seed, rehearse):
+    """The kernels of the latent-attention step (ISSUE 34): a score of
+    two parts, q_nope k_nope^T over 128 lanes a head plus q_pe k_pe^T
+    over 64 lanes against ONE key that all 32 heads read, values 128
+    wide, T 4096 streamed, causal; every gradient against dense float32
+    math with the shared key broadcast in the einsum alone."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+    b, t, h, d, d2 = (1, 256, 4, 128, 64) if rehearse else (
+        1, 4096, 32, 128, 64)
+    rng = np.random.RandomState(seed)
+    mk = lambda lanes: jnp.asarray(rng.randn(b, t, lanes) * 0.5,
+                                   jnp.bfloat16)
+    q, k, v, q2, k2, dy = mk(h * d), mk(h * d), mk(h * d), mk(h * d2), \
+        mk(d2), mk(h * d)
+    f32 = lambda x: x.astype(jnp.float32)
+    scale = (d + d2) ** -0.5 * (0.1 * math.log(64) + 1) ** 2
+
+    def kernel(q, k, v, q2, k2):
+        out = fa.flash_bthd(q, k, v, h, causal=True, scale=scale, q2=q2,
+                            k2=k2)
+        return (f32(out) * f32(dy)).sum()
+
+    def dense(q, k, v, q2, k2):
+        o, _ = fa._dense_lse(*(fa.heads_first(x, h) for x in (q, k, v)),
+                             True, scale, (0, 0), fa.heads_first(q2, h), k2)
+        return (fa.heads_last(o) * f32(dy)).sum()
+
+    t0 = time.perf_counter()
+    wrt = (0, 1, 2, 3, 4)
+    grad = jax.jit(jax.grad(kernel, wrt)).lower(q, k, v, q2, k2).compile()
+    got, text = grad(q, k, v, q2, k2), grad.as_text()
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.grad(dense, wrt))(*(f32(x) for x in
+                                               (q, k, v, q2, k2)))
+    errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+            for a, r in zip(got, want)]
+    log("[mla] q/k/v [%d, %d, %d] q_pe [.., %d] k_pe [.., %d] bf16 causal: "
+        "dq_nope %.3e dk_nope %.3e dv %.3e dq_pe %.3e dk_pe %.3e from the "
+        "dense float32 gradients (%.1f s); tpu_custom_call sites %d" % (
+            b, t, h * d, h * d2, d2, *errs, time.perf_counter() - t0,
+            text.count("tpu_custom_call")))
+    assert max(errs) <= FLASH_GRAD_TOL, errs
+    if not rehearse:
+        assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+        # nothing of k_pe's size times the heads, and no operand padded
+        # to 256 lanes a head, is made round the kernels
+        assert "%d,%d]" % (t, h * 2 * d) not in text
 
 
 def phase_rotary(seed, rehearse):
@@ -750,6 +807,10 @@ def main():
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run ONLY the ParallelExecutor phase on a "
                          "dp2 x tp2 mesh and its one-device baseline")
+    ap.add_argument("--phases", default="",
+                    help="comma separated: only these one-chip phases "
+                         "(flash, gqa, mla, rotary, experts, train, "
+                         "serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: tiny size, Pallas kernels in "
                          "interpret mode, no tpu_custom_call "
@@ -770,12 +831,12 @@ def main():
     if args.chips == 4:
         phase_multichip(cfg, args.seed, args.rehearse)
     else:
-        phase_flash(args.seed, args.rehearse)
-        phase_gqa(args.seed, args.rehearse)
-        phase_rotary(args.seed, args.rehearse)
-        phase_experts(args.seed, args.rehearse)
-        phase_train(cfg, args.seed, args.rehearse)
-        phase_serve(cfg, args.seed, args.rehearse)
+        phases = {"flash": phase_flash, "gqa": phase_gqa, "mla": phase_mla,
+                  "rotary": phase_rotary, "experts": phase_experts,
+                  "train": functools.partial(phase_train, cfg),
+                  "serve": functools.partial(phase_serve, cfg)}
+        for name in (args.phases.split(",") if args.phases else phases):
+            phases[name](args.seed, args.rehearse)
     log("[cache] %d entries in %s at end"
         % (compile_cache.entries(cache_dir), cache_dir))
     log("[done] all phases passed in %.1f s" % (time.perf_counter() - t0))

@@ -21,6 +21,7 @@ replacing the reference's per-op GradOpDescMaker machinery (backward.py:425)
 with JAX's program transform.
 """
 
+import contextlib
 import os
 
 import numpy as np
@@ -1618,7 +1619,12 @@ def _lower_op(ctx, op):
         # reference's per-op RecordEvent naming
         seq = getattr(ctx, "_op_seq", 0)
         ctx._op_seq = seq + 1
-        with jax.named_scope("%s.%d" % (op.type, seq)):
+        # a recompute region is not an op of the model: the ops inside
+        # it name themselves (numbered on from here), so that a trace
+        # attributes a layer's time to its ops and not to the region
+        scope = contextlib.nullcontext() if op.type == "recompute_block" \
+            else jax.named_scope("%s.%d" % (op.type, seq))
+        with scope:
             info.lower(ctx, op)
     except EnforceError:
         raise

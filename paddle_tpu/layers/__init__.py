@@ -18,7 +18,7 @@ from .nn import (  # noqa: F401
     reduce_prod, reduce_sum, softmax, softmax_with_cross_entropy,
     square_error_cost, topk,
     block_diffusion_attention, block_diffusion_noise, qk_norm_rope,
-    rms_norm, rope, silu_mul,
+    rms_norm, rope, silu_mul, hyper_connection, mla_attention,
 )
 from .ops import *  # noqa: F401,F403
 from .math_ops import scale  # noqa: F401

@@ -567,3 +567,89 @@ def block_diffusion_attention(q, k, v, n_head, n_kv_head, block, scale=0.0,
         attrs={"n_head": int(n_head), "n_kv_head": int(n_kv_head),
                "block": int(block), "scale": float(scale)})
     return out
+
+
+def hyper_connection(x, lanes, stage, y=None, coefficients=None,
+                     sinkhorn_iters=20, sinkhorn_eps=1e-6,
+                     clamp=(-30.0, 30.0), epsilon=1e-6, alpha_init=0.01,
+                     res_diagonal=4.0, name=None):
+    """One stage of a residual stream of `lanes` lanes ``[B, T, n * d]``
+    under manifold-constrained hyper-connections
+    (``ops/hyper_connection.py``), all in float32:
+
+    * ``"widen"``: x ``[B, T, d]`` copied to the n lanes;
+    * ``"mix"``: the stream's coefficients and the sublayer's input:
+      returns ``(H_pre X [B, T, d], (post, res))``, the pair to hand to
+      "merge". Parameters ``<name>.proj`` [n d, n (n + 2)] (N(0, 0.02)),
+      ``<name>.alpha`` [3] (`alpha_init`) and ``<name>.bias`` [n (n + 2)]
+      (zero but `res_diagonal` on the residual mix's diagonal, so that
+      it starts near the identity);
+    * ``"merge"``: ``H_res X + H_post^T y`` for the sublayer's output y
+      ``[B, T, d]`` and `coefficients` from "mix";
+    * ``"narrow"``: the lanes summed, ``[B, T, d]``."""
+    from ..initializer import (ConstantInitializer, NormalInitializer,
+                               NumpyArrayInitializer)
+    from ..param_attr import ParamAttr
+    helper = LayerHelper("hyper_connection", name=name)
+    n = int(lanes)
+    lead, width = tuple(x.shape[:-1]), int(x.shape[-1])
+    attrs = {"stage": stage, "lanes": n}
+    new = lambda shape: helper.create_variable_for_type_inference(
+        "float32", shape=shape)
+    if stage in ("widen", "narrow"):
+        out = new(lead + (width * n if stage == "widen" else width // n,))
+        helper.append_op(type="hyper_connection", inputs={"X": [x]},
+                         outputs={"Out": [out]}, attrs=attrs)
+        return out
+    if stage == "mix":
+        param = lambda suffix, shape, init: helper.create_parameter(
+            ParamAttr(name="%s.%s" % (helper.name, suffix)), shape=shape,
+            dtype="float32", default_initializer=init)
+        bias = np.zeros(n * (n + 2), np.float32)
+        bias[2 * n:] = res_diagonal * np.eye(n, dtype=np.float32).reshape(-1)
+        proj = param("proj", [width, n * (n + 2)], NormalInitializer(0., 0.02))
+        alpha = param("alpha", [3], ConstantInitializer(alpha_init))
+        bias = param("bias", [n * (n + 2)], NumpyArrayInitializer(bias))
+        out = new(lead + (width // n,))
+        rows = int(np.prod(lead)) if all(int(s) > 0 for s in lead) else -1
+        post, res = new((rows, n)), new((n, n, rows))
+        attrs.update(sinkhorn_iters=int(sinkhorn_iters),
+                     sinkhorn_eps=float(sinkhorn_eps),
+                     clamp_min=float(clamp[0]), clamp_max=float(clamp[1]),
+                     epsilon=float(epsilon))
+        helper.append_op(
+            type="hyper_connection",
+            inputs={"X": [x], "Proj": [proj], "Alpha": [alpha],
+                    "Bias": [bias]},
+            outputs={"Out": [out], "Post": [post], "Res": [res]}, attrs=attrs)
+        return out, (post, res)
+    if stage != "merge":
+        raise ValueError("hyper_connection: no stage %r" % (stage,))
+    post, res = coefficients
+    out = new(lead + (width,))
+    helper.append_op(
+        type="hyper_connection",
+        inputs={"X": [x], "Post": [post], "Res": [res], "Y": [y]},
+        outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def mla_attention(q_nope, q_pe, k_nope, k_pe, v, n_head, inv_freq, scale,
+                  name=None):
+    """Causal latent attention over the five projections as they come
+    (``ops/latent_attention.py``): q_nope, k_nope, v ``[B, T, H * D]``,
+    q_pe ``[B, T, H * Dr]`` and ONE rotary key k_pe ``[B, T, Dr]`` that
+    every head reads; q_pe and k_pe are turned by their rows' positions
+    with the given frequencies `inv_freq` (Dr / 2 floats), and a score
+    is ``(q_nope k_nope^T + q_pe k_pe^T) * scale``. Returns a variable
+    of v's shape."""
+    helper = LayerHelper("mla_attention", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype, shape=v.shape)
+    helper.append_op(
+        type="mla_attention",
+        inputs={"QNope": [q_nope], "QPe": [q_pe], "KNope": [k_nope],
+                "KPe": [k_pe], "V": [v]},
+        outputs={"Out": [out]},
+        attrs={"n_head": int(n_head), "scale": float(scale),
+               "inv_freq": [float(f) for f in inv_freq]})
+    return out

@@ -93,7 +93,9 @@ def sparse_moe(x, num_experts, d_inner, capacity_factor=1.25,
 
 
 def routed_experts(x, num_experts, experts_held, first_expert, top_k,
-                   d_inner, norm_topk=True, name=None):
+                   d_inner, norm_topk=True, name=None, score_func="softmax",
+                   routed_scaling_factor=1.0, bias_update_rate=None,
+                   shared_expert=False):
     """One chip's share of a mixture of SiLU-gated experts over
     ``[B, T, D]`` input, dropless (``parallel/moe.routed_experts``): a
     float32 router over all `num_experts`, the `top_k` largest a row
@@ -106,7 +108,17 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     the cost), `choices` the router's ``[B, T, top_k]`` indices, `load`
     a persistable ``[num_experts]`` int32 count of the rows that chose
     each expert, summed over every TRAIN run of the program. Parameters
-    are named ``<name>.router``, ``.w_gate``, ``.w_up``, ``.w_down``."""
+    are named ``<name>.router``, ``.w_gate``, ``.w_up``, ``.w_down``.
+
+    `score_func` "sigmoid" scores each expert by itself, and the chosen
+    weights are multiplied by `routed_scaling_factor`. A
+    `bias_update_rate` u (not None) routes without an auxiliary loss: a
+    persistable ``<name>.bias`` [num_experts], zero at first and never
+    differentiated, is added to the scores for the CHOICE alone, and
+    every train run moves it by u towards an even load
+    (``moe.bias_step``) and adds one to the persistable
+    ``<name>.steps`` [1] int32. `shared_expert` labels the lowering's count:
+    the caller adds a shared expert's output to `out`."""
     helper = LayerHelper("routed_experts", name=name)
     d = int(x.shape[-1])
     param = lambda suffix, shape, std: helper.create_parameter(
@@ -123,14 +135,28 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     aux = helper.create_variable_for_type_inference("float32", shape=())
     choices = helper.create_variable_for_type_inference(
         "int32", shape=tuple(x.shape[:-1]) + (top_k,), stop_gradient=True)
-    helper.append_op(
-        type="routed_experts",
-        inputs={"X": [x], "RouterW": [router], "WGate": [w_gate],
-                "WUp": [w_up], "WDown": [w_down], "Load": [load]},
-        outputs={"Out": [out], "AuxLoss": [aux], "Indices": [choices],
-                 "LoadOut": [load]},
-        attrs={"first_expert": int(first_expert), "top_k": int(top_k),
-               "norm_topk": bool(norm_topk)})
+    inputs = {"X": [x], "RouterW": [router], "WGate": [w_gate],
+              "WUp": [w_up], "WDown": [w_down], "Load": [load]}
+    outputs = {"Out": [out], "AuxLoss": [aux], "Indices": [choices],
+               "LoadOut": [load]}
+    attrs = {"first_expert": int(first_expert), "top_k": int(top_k),
+             "norm_topk": bool(norm_topk)}
+    if (score_func, routed_scaling_factor, shared_expert) != (
+            "softmax", 1.0, False):
+        attrs.update(score_func=str(score_func),
+                     routed_scaling_factor=float(routed_scaling_factor),
+                     shared_expert=bool(shared_expert))
+    if bias_update_rate is not None:
+        bias = create_global_var([num_experts], 0.0, "float32",
+                                 persistable=True, name=helper.name + ".bias")
+        bias.stop_gradient = True
+        steps = create_global_var([1], 0, "int32", persistable=True,
+                                  name=helper.name + ".steps")
+        inputs.update(Bias=[bias], Steps=[steps])
+        outputs.update(BiasOut=[bias], StepsOut=[steps])
+        attrs["bias_update_rate"] = float(bias_update_rate)
+    helper.append_op(type="routed_experts", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     return out, aux, choices, load
 
 

@@ -11,6 +11,8 @@ from . import (  # noqa: F401
     detection_ops,
     elementwise,
     fused,
+    hyper_connection,
+    latent_attention,
     rnn_ops,
     loss,
     math,

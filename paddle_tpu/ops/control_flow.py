@@ -219,6 +219,8 @@ def _recompute_block(ctx, op):
     base_env = dict(ctx.env)
     region_key = ctx._rng_fn()
     guard_start = getattr(ctx, "_nan_idx", 0)
+    op_seq = getattr(ctx, "_op_seq", 0)
+    ctx._op_seq = op_seq + len(block.ops)
 
     def f(vals, key):
         env = dict(base_env)
@@ -235,6 +237,7 @@ def _recompute_block(ctx, op):
                             fetch_names=getattr(ctx, "fetch_names", ()))
         sctx.check_nan = getattr(ctx, "check_nan", False)
         sctx._nan_idx = guard_start   # program-order guard keys continue
+        sctx._op_seq = op_seq         # and so do the ops' scope numbers
         for op2 in block.ops:
             _lower_op(sctx, op2)
         # exports: region outputs + their @LOD lengths (sequence ops

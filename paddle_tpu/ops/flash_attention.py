@@ -79,8 +79,16 @@ via interpret mode separately); `flash_attention` / `flash_attention_lse`
 take [B, H, T, D] and are wrappers round it that transpose in and out:
 such a caller (parallel/ring.py) now pays the transposes the model used
 to pay. Each dispatch counts itself at trace time in
-`ptpu_flash_lowerings_total{path, entry, heads_per_block, backward}`
-(backward: "fused" / "two_kernels", "none" on the dense path).
+`ptpu_flash_lowerings_total{path, entry, heads_per_block, backward,
+mask, kv_groups, key_width, value_width, second_part}` (backward:
+"fused" / "two_kernels", "none" on the dense path).
+
+A score of two parts (PR 34): `flash_bthd(..., q2=, k2=)` adds
+`q2_h k2^T` to head h's scores, k2 ONE key [B, T, D2] that every head
+reads (latent attention's rotary part): key D + D2 wide, value D. The
+same three streamed kernels under the same names take it on what they
+are handed (`part2`; see "A score of two parts" below); handed no
+second part they trace exactly what they traced before.
 """
 
 import functools
@@ -143,16 +151,22 @@ def _dense(q, k, v, causal, scale):
     return _dense_lse(q, k, v, causal, scale)[0]
 
 
-def _dense_lse(q, k, v, causal, scale, mask=(0, 0)):
+def _dense_lse(q, k, v, causal, scale, mask=(0, 0), q2=None, k2=None):
     """Dense math returning (out, lse) — lse[b,h,i] = logsumexp_j s_ij.
     The math-identical fallback for flash_attention_lse. k and v may
     hold fewer heads than q (query head h reads head h // group);
-    `mask` = (shift, strict) is _causal's, read where `causal`."""
+    `mask` = (shift, strict) is _causal's, read where `causal`. q2
+    [B, H, T, D2] and k2 [B, T, D2], where given, add a second part to
+    every score: q2 against the ONE key k2 that all heads read."""
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
+                   preferred_element_type=jnp.float32)
+    if q2 is not None:
+        s = s + jnp.einsum("bhqd,bkd->bhqk", q2, k2,
+                           preferred_element_type=jnp.float32)
+    s = s * scale
     if causal:
         t = s.shape[-1]
         shift, strict = mask
@@ -230,6 +244,16 @@ def _block_ids(nq, nk, by_keys=False):
     # interpret/lower on all paths
     return (pl.program_id(qa) if nq > 1 else 0,
             pl.program_id(ka) if nk > 1 else 0)
+
+
+def _unfold(at, outer, inner):
+    """(at // inner, at % inner) of a grid axis that folds two, settled
+    at trace time where one of them has a single step."""
+    if inner == 1:
+        return (at if outer > 1 else 0), 0
+    if outer == 1:
+        return 0, at
+    return at // inner, at % inner
 
 
 def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False):
@@ -331,8 +355,13 @@ def _put(ref, idx, x, mine):
 # forward kernel: grid (B * H / g, nQ, nK); scratch (m, l, acc) carried
 # across the (sequential, innermost) nK dimension. One key block carries
 # nothing: each panel finishes its own rows, and there is no scratch.
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                mask, scale, block_q, block_k, tile, nq, nk, d, g):
+def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
+                part2=None):
+    if part2:
+        q_ref, k_ref, v_ref, q2_ref, k2_ref, o_ref, lse_ref, *scratch = refs
+        a2 = pl.program_id(0) % part2[1]    # its place in q2's block
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch = refs
     i, j = _block_ids(nq, nk)
     if nk > 1:
         m_s, l_s, acc_s = scratch
@@ -356,9 +385,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             q = q_ref[0, rows, :] * scale           # [tq, W], once a panel
             mine = _lanes(q.shape, a, d, g)
             q = _only(q, mine)
+            if part2:
+                q2 = q2_ref[0, rows, :] * scale
+                q2 = _only(q2, _lanes(q2.shape, a2, *part2[:2]))
             scores = []
             for cols, off in segments:
                 s = _dot(q, k_ref[0, cols, :], _NT)     # [tq, tk]
+                if part2:
+                    s = s + _dot(q2, k2_ref[0, cols, :], _NT)
                 scores.append(s if off is None else _causal(s, off, 0, mask))
             maxes = [jnp.max(s, axis=1, keepdims=True) for s in scores]
             if nk == 1:
@@ -543,15 +577,25 @@ def _bwd_fused_kernel(*refs, mask, scale, t, tile, d, g, has_dlse):
 
 
 def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
-                   g, has_dlse):
+                   g, has_dlse, part2=None):
     q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
     dlse_ref = refs[6] if has_dlse else None
-    dq_ref, delta_ref, acc_s, lse_s, delta_s = refs[6 + has_dlse:]
     i, j = _block_ids(nq, nk)
+    if part2:
+        # grid (B * H / g2, nQ, g2 * nK): the g2 heads that share a
+        # block of q2 follow one another, so that dq2's block stays in
+        # VMEM while each of them stores its own lanes of it
+        (q2_ref, k2_ref, dq_ref, delta_ref, dq2_ref, acc_s, lse_s, delta_s,
+         acc2_s) = refs[6 + has_dlse:]
+        a2, j = _unfold(pl.program_id(2), part2[1], nk)
+    else:
+        dq_ref, delta_ref, acc_s, lse_s, delta_s = refs[6 + has_dlse:]
 
     @_when(j == 0)
     def _init():
         acc_s[:] = jnp.zeros_like(acc_s)
+        if part2:
+            acc2_s[:] = jnp.zeros_like(acc2_s)
 
     def head(a):
         @_when(j == 0)
@@ -566,18 +610,28 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
             q = q_ref[0, rows, :] * scale
             mine = _lanes(q.shape, a, d, g)
             q, dy = _only(q, mine), _only(dy_ref[0, rows, :], mine)
+            if part2:
+                q2 = q2_ref[0, rows, :] * scale
+                q2 = _only(q2, _lanes(q2.shape, a2, *part2[:2]))
             lse, delta = lse_s[a, rows], delta_s[a, rows]
-            acc = 0.0
+            acc = acc2 = 0.0
             for cols, off in segments:
                 kk = k_ref[0, cols, :]
                 s = _dot(q, kk, _NT)                 # [tq, tk]
+                if part2:
+                    kk2 = k2_ref[0, cols, :]
+                    s = s + _dot(q2, kk2, _NT)
                 if off is not None:
                     s = _causal(s, off, 0, mask)
                 p = jnp.exp(s - lse)
                 dp = _dot(dy, v_ref[0, cols, :], _NT)
-                ds = p * (dp - delta)
-                acc = acc + _dot(ds.astype(kk.dtype), kk, _NN)  # [tq, W]
+                ds = (p * (dp - delta)).astype(kk.dtype)
+                acc = acc + _dot(ds, kk, _NN)        # [tq, W]
+                if part2:
+                    acc2 = acc2 + _dot(ds, kk2, _NN)
             acc_s[rows] = acc_s[rows] + _only(acc, mine)
+            if part2:
+                acc2_s[rows] = acc2_s[rows] + acc2
 
         _walk(panel, i, j, mask, block_q, block_k, tile)
 
@@ -586,12 +640,33 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
     @_when(j == nk - 1)
     def _final():
         dq_ref[0] = (acc_s[:] * scale).astype(dq_ref.dtype)
+        if part2:
+            # k2 repeats the shared key under each of the g2 heads'
+            # lanes, so every lane group of acc2 holds this head's dq2
+            acc2 = acc2_s[:]
+            _put(dq2_ref, (0, slice(None), slice(None)),
+                 (acc2 * scale).astype(dq2_ref.dtype),
+                 _lanes(acc2.shape, a2, *part2[:2]))
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_s, dv_s, *, mask, scale, block_q,
-                    block_k, tile, nq, nk, d, g):
+def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
+                    g, part2=None):
+    q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref = refs[:6]
     i, jj = _block_ids(nq, nk, by_keys=True)    # q blocks innermost here
+    if part2:
+        # grid (B, nK, H * nQ): every head's q blocks pass under one
+        # resident block of k2, whose gradient is the sum over the heads
+        (q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_s, dv_s,
+         dk2_s) = refs[6:]
+        at = pl.program_id(2)
+        head, i = _unfold(at, part2[2], nq)
+        a2 = head % part2[1]
+
+        @pl.when(at == 0)
+        def _init2():
+            dk2_s[:] = jnp.zeros_like(dk2_s)
+    else:
+        dk_ref, dv_ref, dk_s, dv_s = refs[6:]
 
     @_when(i == 0)
     def _init():
@@ -608,20 +683,32 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
             kk = k_ref[0, cols, :] * scale           # [tk, W], once a panel
             mine = _lanes(kk.shape, a, d, g)
             kk, v = _only(kk, mine), _only(v_ref[0, cols, :], mine)
-            dk = dv = 0.0
+            if part2:
+                kk2 = k2_ref[0, cols, :] * scale
+            dk = dv = dk2 = 0.0
             for rows, off in segments:
                 q = q_ref[0, rows, :]
                 dy = dy_ref[0, rows, :]
                 st = _dot(kk, q, _NT)                # [tk, tq]
+                if part2:
+                    # this head's lanes of q2 alone: dk2 then comes out
+                    # in those lanes, the other heads' in theirs
+                    q2 = q2_ref[0, rows, :]
+                    q2 = _only(q2, _lanes(q2.shape, a2, *part2[:2]))
+                    st = st + _dot(kk2, q2, _NT)
                 if off is not None:
                     st = _causal(st, off, 1, mask)
                 pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
                 dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
                 dpt = _dot(v, dy, _NT)
-                dst = pt * (dpt - delta_ref[a, :, rows])
-                dk = dk + _dot(dst.astype(q.dtype), q, _NN)
+                dst = (pt * (dpt - delta_ref[a, :, rows])).astype(q.dtype)
+                dk = dk + _dot(dst, q, _NN)
+                if part2:
+                    dk2 = dk2 + _dot(dst, q2, _NN)
             dk_s[cols] = dk_s[cols] + _only(dk, mine)
             dv_s[cols] = dv_s[cols] + _only(dv, mine)
+            if part2:
+                dk2_s[cols] = dk2_s[cols] + dk2
 
         _walk(panel, i, jj, mask, block_q, block_k, tile, by_keys=True)
 
@@ -631,6 +718,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref,
     def _final():
         dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+    if part2:
+        @pl.when(at == part2[2] * nq - 1)
+        def _final2():
+            dk2_ref[0] = (dk2_s[:] * scale).astype(dk2_ref.dtype)
 
 
 def _backward_blocks(t, w, block_q, block_k):
@@ -741,6 +833,168 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
 
 
 # --------------------------------------------------------------------------
+# A score of two parts (ISSUE 34: latent attention). s = q k^T + q2 k2^T
+# where q2 is [B, T, H*D2], a second part a head, and k2 [B, T, D2] is
+# ONE key that every query head reads; values keep k's width, so a
+# head's key is D + D2 wide and its value D. Nothing is padded or
+# repeated over the heads in HBM: q2's block is 128 lanes, g2 = 128 / D2
+# heads of it (one where D2 is a multiple of 128), of which a grid step
+# keeps its own head's lanes (_only) and contracts all 128 against k2
+# with the shared key REPEATED under each of the g2 lane groups (a
+# [B, T, 128] operand that _attend makes outside the custom_vjp, whose
+# transpose folds dk2's lane groups back). The kernels are the streamed
+# three with `part2` = (D2, g2, H) set, under the same names; a block is
+# one head (D a multiple of 128). Their grids differ from the one-part
+# grids where an output is shared between heads: flash_bwd_dq runs the
+# g2 heads of a q2 block one after the other, so that dq2's block stays
+# in VMEM while each stores its lanes; flash_bwd_dkv runs ALL heads
+# under one resident block of k2 and sums dk2 over them in float32
+# scratch, so the sum over 32 heads never exists in HBM a head at a time.
+def _part2_of(n_head, q2):
+    d2 = q2.shape[-1] // n_head
+    g2 = max(_LANES // d2, 1)
+    return d2, g2, n_head
+
+
+def _specs2(n_head, g2, w2, fold):
+    """Block specs of the two-part kernels for one head to a block of
+    the first part. `fold(s)` -> (batch, head, q block, key block) of a
+    grid step; returns builders of (q-side rows of W lanes, key-side
+    rows, a row statistic, q2's rows, k2's rows)."""
+    def rows(block, w=_LANES):
+        return pl.BlockSpec((1, block, w), lambda *s: (
+            fold(s)[0], fold(s)[2], fold(s)[1]))
+
+    def keys(block, w=_LANES):
+        return pl.BlockSpec((1, block, w), lambda *s: (
+            fold(s)[0], fold(s)[3], fold(s)[1]))
+
+    def stat(block):
+        return pl.BlockSpec((1, 1, block), lambda *s: (
+            fold(s)[0] * n_head + fold(s)[1], 0, fold(s)[2]))
+
+    def rows2(block):
+        return pl.BlockSpec((1, block, w2), lambda *s: (
+            fold(s)[0], fold(s)[2], fold(s)[1] // g2))
+
+    def keys2(block):
+        return pl.BlockSpec((1, block, w2), lambda *s: (
+            fold(s)[0], fold(s)[3], 0))
+
+    return rows, keys, stat, rows2, keys2
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10))
+def _fwd_pallas2(q, k, v, q2, k2, n_head, mask, scale, block_q, block_k,
+                 interpret):
+    b, t, hd = q.shape
+    d = hd // n_head
+    part2 = _part2_of(n_head, q2)
+    w2 = k2.shape[-1]
+    bq, bk = min(block_q, t), min(block_k, t)
+    nq, nk = t // bq, t // bk
+    rows, keys, stat, rows2, keys2 = _specs2(
+        n_head, part2[1], w2,
+        lambda s: (s[0] // n_head, s[0] % n_head, s[1], s[2]))
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, mask=mask, scale=scale,
+                          block_q=bq, block_k=bk, tile=_tile(bq, _TILE),
+                          nq=nq, nk=nk, d=d, g=1, part2=part2),
+        grid=(b * n_head, nq, nk),
+        in_specs=[rows(bq, d), keys(bk, d), keys(bk, d), rows2(bq),
+                  keys2(bk)],
+        out_specs=[rows(bq, d), stat(bq)],
+        out_shape=[jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+                   jax.ShapeDtypeStruct((b * n_head, 1, t), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bq, 1), jnp.float32),
+                        pltpu.VMEM((1, bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)] if nk > 1 else [],
+        interpret=interpret,
+        name="flash_fwd",
+    )(q, k, v, q2, k2)
+    return out, lse.reshape(b, n_head, t)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
+def _bwd_pallas2(res, dy, n_head, mask, scale, block_q, block_k, interpret):
+    q, k, v, q2, k2, o, lse = res
+    b, t, hd = q.shape
+    d = hd // n_head
+    part2 = _part2_of(n_head, q2)
+    g2, w2 = part2[1], k2.shape[-1]
+    bq, bk = _backward_blocks(t, d, block_q, block_k)
+    nq, nk = t // bq, t // bk
+    tile = _tile(bq, _TILE)
+    lse3 = lse.reshape(b * n_head, 1, t)
+    stat_shape = jax.ShapeDtypeStruct((b * n_head, 1, t), jnp.float32)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    pairs = n_head // g2
+
+    def fold_dq(s):
+        a2, j = _unfold(s[2], g2, nk)
+        return s[0] // pairs, s[0] % pairs * g2 + a2, s[1], j
+
+    rows, keys, stat, rows2, keys2 = _specs2(n_head, g2, w2, fold_dq)
+    dq, delta3, dq2 = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
+                          block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
+                          d=d, g=1, has_dlse=False, part2=part2),
+        grid=(b * pairs, nq, g2 * nk),
+        in_specs=[rows(bq, d), keys(bk, d), keys(bk, d), rows(bq, d),
+                  rows(bq, d), stat(bq), rows2(bq), keys2(bk)],
+        out_specs=[rows(bq, d), stat(bq), rows2(bq)],
+        out_shape=[like(q), stat_shape, like(q2)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((1, bq, 1), jnp.float32),
+                        pltpu.VMEM((1, bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, w2), jnp.float32)],
+        interpret=interpret,
+        name="flash_bwd_dq",
+    )(q, k, v, dy, o, lse3, q2, k2)
+
+    def fold_dkv(s):
+        head, i = _unfold(s[2], n_head, nq)
+        return s[0], head, i, s[1]
+
+    rows, keys, stat, rows2, keys2 = _specs2(n_head, g2, w2, fold_dkv)
+    dk, dv, dk2 = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
+                          block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
+                          d=d, g=1, part2=part2),
+        grid=(b, nk, n_head * nq),
+        in_specs=[rows(bq, d), keys(bk, d), keys(bk, d), rows(bq, d),
+                  stat(bq), stat(bq), rows2(bq), keys2(bk)],
+        out_specs=[keys(bk, d), keys(bk, d), keys2(bk)],
+        out_shape=[like(k), like(v), like(k2)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, w2), jnp.float32)],
+        interpret=interpret,
+        name="flash_bwd_dkv",
+    )(q, k, v, dy, lse3, delta3, q2, k2)
+    return dq, dk, dv, dq2, dk2
+
+
+# static: n_head, mask, scale, block_q, block_k, interpret
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash2(q, k, v, q2, k2, *static):
+    return _fwd_pallas2(q, k, v, q2, k2, *static)[0]
+
+
+def _flash2_fwd(q, k, v, q2, k2, *static):
+    out, lse = _fwd_pallas2(q, k, v, q2, k2, *static)
+    return out, (q, k, v, q2, k2, out, lse)
+
+
+def _flash2_bwd(*args):
+    *static, res, dy = args
+    return _bwd_pallas2(res, dy, *static)
+
+
+_flash2.defvjp(_flash2_fwd, _flash2_bwd)
+
+
+# --------------------------------------------------------------------------
 # The static arguments of every entry below, in order: n_head,
 # n_kv_head, mask (None, or _causal's (shift, strict)), scale, block_q,
 # block_k, interpret.
@@ -817,9 +1071,12 @@ _LOWERINGS = _REG.counter(
     "flash attention dispatches at trace time (one a lowering of the op, "
     "none a step): the path taken, the layout of the entry called, the "
     "heads a kernel block holds, the backward its gradient would run, "
-    "the mask (none, causal, block_causal, block_causal_strict) and the "
-    "query heads that read one key/value head",
-    ("path", "entry", "heads_per_block", "backward", "mask", "kv_groups"))
+    "the mask (none, causal, block_causal, block_causal_strict), the "
+    "query heads that read one key/value head, a head's key and value "
+    "widths and its score's second part (none, or shared: one key that "
+    "every head reads)",
+    ("path", "entry", "heads_per_block", "backward", "mask", "kv_groups",
+     "key_width", "value_width", "second_part"))
 
 
 def _resolve_path(q, scale, block_q, block_k, force):
@@ -886,15 +1143,29 @@ def _mask_of(causal, mask_block, strict):
 
 
 def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-            entry, with_lse, n_kv_head=None, mask_block=1, strict=False):
+            entry, with_lse, n_kv_head=None, mask_block=1, strict=False,
+            q2=None, k2=None):
     """Dispatch of every entry: q/k/v [B, T, H*D] -> out, or (out, lse
     [B, H, T]) `with_lse`. `entry` labels the count: the layout the
     caller came in. k and v may hold `n_kv_head` < H heads, [B, T,
     Hkv*D]: query head h reads head h // (H / Hkv), and the kernels
-    take that where a block is one head (D a multiple of 128)."""
+    take that where a block is one head (D a multiple of 128). q2
+    [B, T, H*D2] and k2 [B, T, D2] add q2_h k2^T to head h's scores
+    (the kernels: one head to a block, no groups, D2 a multiple of 128
+    or dividing it in as many heads as divide H); `scale` then defaults
+    to (D + D2)^-0.5."""
     b, t, hd = q.shape
     d = hd // n_head
     n_kv_head = n_kv_head or n_head
+    d2 = 0
+    if q2 is not None:
+        d2 = k2.shape[-1]
+        if q2.shape != (b, t, n_head * d2) or k2.shape != (b, t, d2):
+            raise ValueError(
+                "flash attention: a second score part wants q2 [B, T, "
+                "H*D2] and ONE shared key k2 [B, T, D2], got %s and %s "
+                "beside q %s" % (q2.shape, k2.shape, q.shape))
+        scale = scale or (d + d2) ** -0.5
     if n_head % n_kv_head or k.shape[-1] != n_kv_head * d:
         raise ValueError(
             "flash attention: %d query heads of %d cannot read k of "
@@ -911,15 +1182,33 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     if (n_kv_head != n_head and g > 1) or (
             mask and any(x % (1 << mask[0]) for x in (bq, bk, edge))):
         path = "dense"
+    if d2:
+        g2 = _part2_of(n_head, q2)[1]
+        if (g > 1 or n_kv_head != n_head or with_lse or n_head % g2
+                or (d2 % _LANES and _LANES % d2)):
+            path = "dense"
+    if v.shape[-1] != k.shape[-1]:      # the kernels' value is D wide
+        path = "dense"
+    backward = ("none" if path == "dense" else "two_kernels" if d2
+                else _backward_of(t, g * d, bq, bk))
     _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
-                   backward="none" if path == "dense"
-                   else _backward_of(t, g * d, bq, bk),
-                   mask=mask_label, kv_groups=str(n_head // n_kv_head))
+                   backward=backward, mask=mask_label,
+                   kv_groups=str(n_head // n_kv_head),
+                   key_width=str(d + d2),
+                   value_width=str(v.shape[-1] // n_kv_head),
+                   second_part="shared" if d2 else "none")
     if path == "dense":
         out, lse = _dense_lse(
             heads_first(q, n_head), heads_first(k, n_kv_head),
-            heads_first(v, n_kv_head), causal, scale, mask or (0, 0))
+            heads_first(v, n_kv_head), causal, scale, mask or (0, 0),
+            None if q2 is None else heads_first(q2, n_head), k2)
         return (heads_last(out), lse) if with_lse else heads_last(out)
+    if d2:
+        # the shared key under each of the g2 heads' lanes of q2's
+        # block: made here, outside the custom_vjp, so that autodiff
+        # folds dk2's lane groups back into one
+        return _flash2(q, k, v, q2, jnp.tile(k2, (1, 1, g2)), n_head, mask,
+                       scale, bq, bk, path == "interpret")
     return (_flash_lse if with_lse else _flash)(
         q, k, v, n_head, n_kv_head, mask, scale, bq, bk,
         path == "interpret")
@@ -927,11 +1216,17 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
 
 def flash_bthd(q, k, v, n_head, causal=False, scale=None,
                block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-               force=None, n_kv_head=None, mask_block=1, strict=False):
+               force=None, n_kv_head=None, mask_block=1, strict=False,
+               q2=None, k2=None):
     """Fused multi-head attention in the projections' own layout.
     q and the result: [B, T, H*D], head h in lanes [h D, (h+1) D); k and
     v the same, or [B, T, Hkv*D] with `n_kv_head` = Hkv heads, each read
     by H / Hkv query heads (dk and dv are the sums over them).
+
+    `q2` [B, T, H*D2] and `k2` [B, T, D2] make the score the sum of two
+    products, q_h k_h^T + q2_h k2^T, the second against ONE key that
+    every head reads (latent attention's rotary part): a head's key is
+    D + D2 wide, its value D, and dk2 is the sum over the heads.
 
     `causal` with `mask_block` m (a power of two) masks in blocks of m
     rows: a query sees the keys of its own block and of the blocks
@@ -945,7 +1240,7 @@ def flash_bthd(q, k, v, n_head, causal=False, scale=None,
     (tests use "interpret" to run the kernel on CPU).
     """
     return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-                   "bthd", False, n_kv_head, mask_block, strict)
+                   "bthd", False, n_kv_head, mask_block, strict, q2, k2)
 
 
 def flash_bthd_lse(q, k, v, n_head, causal=False, scale=None,

@@ -143,7 +143,12 @@ def _routed_experts(ctx, op):
     give; x's dtype), AuxLoss, Indices [B, T, k] (the router's choices)
     and LoadOut = Load + the rows that chose each expert, which a
     `for_test` clone leaves alone. Under AMP the experts' matmuls take
-    bfloat16 operands; the router is float32 either way."""
+    bfloat16 operands; the router is float32 either way. Attrs
+    score_func ("softmax" / "sigmoid"), routed_scaling_factor and
+    shared_expert are `moe.routed_experts`'; with a Bias [E] float32
+    (persistable, no gradient) the choice is the k largest of score +
+    bias, and a train run writes BiasOut = `moe.bias_step` of the
+    step's own counts at `bias_update_rate` and StepsOut = Steps + 1."""
     from ..amp import maybe_bf16
     from ..parallel import moe
     x = ctx.in1(op, "X")
@@ -152,16 +157,24 @@ def _routed_experts(ctx, op):
     w_gate, w_up, w_down = maybe_bf16(
         ctx.in1(op, "WGate"), ctx.in1(op, "WUp"), ctx.in1(op, "WDown"))
     k = int(op.attr("top_k"))
+    bias = ctx.in1(op, "Bias") if op.input("Bias") else None
     out, aux, counts, experts = moe.routed_experts(
         x.reshape(-1, shape[-1]), router_w, w_gate, w_up, w_down,
         router_w.shape[1],
         first_expert=int(op.attr("first_expert", 0)), top_k=k,
-        norm_topk=bool(op.attr("norm_topk", True)))
+        norm_topk=bool(op.attr("norm_topk", True)),
+        score=op.attr("score_func", "softmax"), bias=bias,
+        scaling=float(op.attr("routed_scaling_factor", 1.0)),
+        shared_expert=bool(op.attr("shared_expert", False)))
     ctx.set_out(op, "Out", out.reshape(shape))
     ctx.set_out(op, "AuxLoss", aux)
     ctx.set_out(op, "Indices", experts.reshape(shape[:-1] + (k,)))
     if not (op.attr("is_test", False) or ctx.is_test):
         ctx.set_out(op, "LoadOut", ctx.in1(op, "Load") + counts)
+        if bias is not None:
+            ctx.set_out(op, "BiasOut", moe.bias_step(
+                bias, counts, float(op.attr("bias_update_rate", 0.0))))
+            ctx.set_out(op, "StepsOut", ctx.in1(op, "Steps") + 1)
 
 
 def _decoder_layer_apply(p, x, n_head):

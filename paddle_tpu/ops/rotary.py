@@ -89,14 +89,39 @@ def _period(t, wrap):
     return wrap if wrap and t % wrap == 0 else t
 
 
+def yarn_inv_freq(d, theta, factor, original_positions, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's d / 2 blended frequencies as a tuple of floats: pair i
+    turns at theta^(-2i/d) where it completes more than `beta_fast`
+    turns over the `original_positions` (extrapolated), at 1/`factor`
+    of that where fewer than `beta_slow` (interpolated), and on a
+    linear ramp over the pairs between the two."""
+    import math
+    turns_at = lambda turns: d * math.log(
+        original_positions / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), d - 1)
+    out = []
+    for i in range(d // 2):
+        freq = theta ** (-2.0 * i / d)
+        ramp = min(max((i - low) / max(high - low, 1e-3), 0.0), 1.0)
+        out.append(freq / factor * ramp + freq * (1.0 - ramp))
+    return tuple(out)
+
+
 def _angles(t, wrap, d, theta):
     """(cos, sin), float32 [period, d / 2], of the positions of the
     first `period` of t rows: a row's index, modulo `wrap` where
-    given."""
+    given. `theta` is the base the d / 2 frequencies are made from, or
+    the frequencies themselves, given (a sequence of d / 2 floats:
+    YaRN's, `yarn_inv_freq`)."""
     pos = jnp.arange(_period(t, wrap))
     if wrap:
         pos = pos % wrap
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if isinstance(theta, (tuple, list)):
+        inv_freq = jnp.asarray(theta, jnp.float32)
+    else:
+        inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[:, None] * inv_freq
     return jnp.cos(ang), jnp.sin(ang)
 
@@ -300,7 +325,8 @@ def norm_rope(x, scale=None, n_head=1, theta=None, wrap=0, epsilon=1e-6,
     """RMSNorm over each of the `n_head` heads of x [..., H*D]'s last
     dimension with ONE weight `scale` [D] (None: no norm), then the
     rotary embedding of each head, rotate-half form, by the row's
-    position (`theta` None: no rotation): its index in x [B, T, H*D]'s
+    position (`theta` None: no rotation; a sequence of D / 2 floats:
+    the frequencies themselves, given): its index in x [B, T, H*D]'s
     T, taken modulo `wrap` where given. float32 inside, x's dtype out.
 
     force: None = auto, "pallas" / "interpret" / "xla" pin a path (tests
@@ -308,7 +334,8 @@ def norm_rope(x, scale=None, n_head=1, theta=None, wrap=0, epsilon=1e-6,
     norm, rotate = scale is not None, theta is not None
     hd = x.shape[-1]
     d = hd // n_head
-    if hd % n_head or (norm and scale.shape != (d,)) or (rotate and d % 2):
+    if hd % n_head or (norm and scale.shape != (d,)) or (rotate and d % 2) \
+            or (isinstance(theta, (tuple, list)) and len(theta) != d // 2):
         raise ValueError(
             "norm_rope: x of shape %s is not %d heads of an even size "
             "under a weight of shape %s"
@@ -324,7 +351,9 @@ def norm_rope(x, scale=None, n_head=1, theta=None, wrap=0, epsilon=1e-6,
                    norm=str(norm).lower(), rotate=str(rotate).lower())
     if path == "xla":
         return _xla(x, scale, n_head, theta, wrap, epsilon)
-    cos, sin = rope_table(x.shape[1], wrap, d, float(theta)) if rotate \
+    if rotate and not isinstance(theta, (tuple, list)):
+        theta = float(theta)
+    cos, sin = rope_table(x.shape[1], wrap, d, theta) if rotate \
         else (None, None)
     return _norm_rope(x.reshape(n, hd), scale, cos, sin, d, float(epsilon),
                       rows, lanes, path == "interpret").reshape(x.shape)

@@ -196,22 +196,42 @@ _REG = _metrics.registry()
 _LOWERINGS = _REG.counter(
     "ptpu_moe_lowerings_total",
     "lowerings of the routed expert layer at trace time (none a step): "
-    "the grouped matmul's path, the experts routed over, those held here "
-    "and the experts a row takes",
-    ("path", "experts", "experts_held", "top_k"))
+    "the grouped matmul's path, the experts routed over, those held here, "
+    "the experts a row takes, the router's score function (softmax, "
+    "sigmoid) and whether a shared expert rides beside the routed ones",
+    ("path", "experts", "experts_held", "top_k", "score", "shared_expert"))
 
 
-def route(x, router_w, top_k, norm_topk):
-    """(probs [N, E], weights [N, k], experts [N, k]): the float32
-    router. Softmax over ALL experts, the k largest, their weights
-    divided by their sum where `norm_topk`."""
+def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
+          scaling=1.0):
+    """(scores [N, E], weights [N, k], experts [N, k]): the float32
+    router. `score` over ALL experts ("softmax", or "sigmoid": each
+    expert's own), the k largest, their weights divided by their sum
+    where `norm_topk`, times `scaling`. A selection `bias` [E] is added
+    for the CHOICE alone (the k largest of score + bias, no gradient):
+    the weights are the unbiased scores at the chosen."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = lax.top_k(probs, top_k)
+    probs = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_p, top_i = lax.top_k(probs, top_k)
+    else:
+        _, top_i = lax.top_k(lax.stop_gradient(probs + bias), top_k)
+        top_p = jnp.take_along_axis(probs, top_i, axis=1)
     if norm_topk:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scaling != 1.0:
+        top_p = top_p * scaling
     return probs, top_p, top_i
+
+
+def bias_step(bias, counts, rate):
+    """The selection bias after a step that sent `counts` [E] rows to
+    the experts: up by `rate` where an expert took fewer than the mean,
+    down where more (auxiliary-loss-free balancing)."""
+    counts = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(counts) - counts)
 
 
 def _swiglu_experts(xs, w_gate, w_up, w_down, sizes):
@@ -289,7 +309,8 @@ _held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
-                   first_expert=0, top_k=8, norm_topk=True):
+                   first_expert=0, top_k=8, norm_topk=True, score="softmax",
+                   bias=None, scaling=1.0, shared_expert=False):
     """One chip's share of a mixture of SiLU-gated experts, dropless.
 
     x [N, d]; router_w [d, E] over ALL `num_experts`; w_gate, w_up
@@ -307,12 +328,20 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     The router is float32 and reads x as it comes; the experts compute
     in their weights' dtype (bfloat16 under AMP), accumulating in
     float32. Every held pair is computed, also when all rows choose
-    held experts."""
+    held experts. `score`, `bias` and `scaling` are `route`'s;
+    `shared_expert` says that the caller runs a shared expert beside
+    this layer (the counter's label: nothing here computes it, and a
+    chip's share of the layer holds it once)."""
     n, d = x.shape
     held = w_gate.shape[0]
     _LOWERINGS.inc(path="ragged_dot", experts=str(num_experts),
-                   experts_held=str(held), top_k=str(top_k))
-    probs, weight, experts = route(x, router_w, top_k, norm_topk)
+                   experts_held=str(held), top_k=str(top_k), score=score,
+                   shared_expert=str(bool(shared_expert)).lower())
+    # the plain softmax router is called as it always was, four
+    # arguments: its callers' stand-ins (tests) have that signature
+    how = {} if (score, bias, scaling) == ("softmax", None, 1.0) else {
+        "score": score, "bias": bias, "scaling": scaling}
+    probs, weight, experts = route(x, router_w, top_k, norm_topk, **how)
     counts = jnp.sum(experts[..., None] == jnp.arange(num_experts),
                      axis=(0, 1), dtype=jnp.int32)
     aux = num_experts * jnp.sum(

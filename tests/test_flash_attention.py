@@ -721,7 +721,8 @@ def test_lowering_counter_says_which_path_engaged():
     # d_model 32 over 4 heads: D 8, sixteen heads would fill 128 lanes,
     # four do not: all of H*D as one block, four heads to it
     labels = dict(path="dense", entry="bthd", heads_per_block="4",
-                  backward="none", mask="causal", kv_groups="1")
+                  backward="none", mask="causal", kv_groups="1",
+                  key_width="8", value_width="8", second_part="none")
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
@@ -734,7 +735,8 @@ def test_lowering_counter_says_which_path_engaged():
     q, k, v = _qkv(b=1, h=2, t=256, d=64)
     for block, backward in ((None, "fused"), (128, "two_kernels")):
         labels = dict(path="interpret", entry="bhtd", heads_per_block="2",
-                      backward=backward, mask="causal", kv_groups="1")
+                      backward=backward, mask="causal", kv_groups="1",
+                      key_width="64", value_width="64", second_part="none")
         was = count.value(**labels)
         FA.flash_attention(q, k, v, causal=True, force="interpret",
                            block_q=block, block_k=block)
@@ -849,7 +851,8 @@ def test_what_the_kernels_cannot_take_goes_dense():
     count = FA._LOWERINGS
     q, k, v, _, _ = _gqa_inputs(4, 2, 64, 256, jnp.float32)
     labels = dict(path="dense", entry="bthd", heads_per_block="2",
-                  backward="none", mask="block_causal_strict", kv_groups="2")
+                  backward="none", mask="block_causal_strict", kv_groups="2",
+                  key_width="64", value_width="64", second_part="none")
     was = count.value(**labels)
     o = FA.flash_bthd(q, k, v, 4, causal=True, force="interpret",
                       n_kv_head=2, mask_block=4, strict=True)
@@ -858,7 +861,8 @@ def test_what_the_kernels_cannot_take_goes_dense():
     _assert_close("out", jnp.where(seen[None, :, None], o, 0), o_ref, 1e-5)
     q, k, v, _, _ = _gqa_inputs(4, 2, 128, 256, jnp.float32)
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
-                  backward="fused", mask="block_causal", kv_groups="2")
+                  backward="fused", mask="block_causal", kv_groups="2",
+                  key_width="128", value_width="128", second_part="none")
     was = count.value(**labels)
     FA.flash_bthd(q, k, v, 4, causal=True, force="interpret", n_kv_head=2,
                   mask_block=32)

@@ -878,6 +878,8 @@ COVERED_ELSEWHERE = {
     "rms_norm": "test_block_diffusion.py",
     "rope": "test_block_diffusion.py",
     "qk_norm_rope": "test_block_diffusion.py",
+    "mla_attention": "test_latent_moe.py",
+    "hyper_connection": "test_latent_moe.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",

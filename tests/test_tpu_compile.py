@@ -392,3 +392,44 @@ def test_routed_experts_compile_for_v5e(chip):
     hidden = {int(rows) for rows in re.findall(
         r"(?:bf16|f32)\[(\d+),%d\]" % f, text)}
     assert hidden and max(hidden) == 2 * n * k * held // e
+
+
+# --------------------------------------------------------------------------
+# ISSUE 34: latent attention's score of two parts at the cell
+# xing4_train_T4k's shape, T 4096 streamed: q_nope / k_nope / v
+# [1, 4096, 32 x 128], q_pe [1, 4096, 32 x 64] and ONE k_pe [1, 4096, 64].
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_two_part_score_compiles_for_v5e(chip, direction):
+    """The three streamed kernels with a second score part, under their
+    own names; nothing of k_pe's size times the heads exists (the shared
+    key is repeated to ONE 128-lane tile, [1, 4096, 128]), no operand
+    is padded to 256 lanes a head, and dk_pe's sum over the 32 heads
+    is made in the kernel: the only results between the kernels and the
+    gradients are the fold of that one tile."""
+    import math
+    import re
+    b, t, h, d, d2 = 1, 4096, 32, 128, 64
+    sds = lambda lanes: jax.ShapeDtypeStruct((b, t, lanes), jnp.bfloat16,
+                                             sharding=chip)
+    avals = (sds(h * d), sds(h * d), sds(h * d), sds(h * d2), sds(d2))
+
+    def fwd(q, k, v, q2, k2):
+        return flash_bthd(q, k, v, h, causal=True, force="pallas", q2=q2,
+                          k2=k2)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss,
+                                                 argnums=(0, 1, 2, 3, 4))
+    text = _compiled_text(fn, *avals)
+    names = ["flash_fwd"] + (_TWO if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    sizes = {math.prod(int(x) for x in dims.split(","))
+             for dims in re.findall(r"(?:bf16|f32)\[([\d,]+)\]", text)}
+    # operands and gradients as they come, the statistics' rows, the
+    # one tile of k_pe, and nothing wider
+    assert max(sizes) == b * t * h * d
+    assert b * t * h * 2 * d not in sizes and b * t * h * (d + d2) not in sizes
