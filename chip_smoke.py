@@ -25,10 +25,16 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
           dScale against float32 math, and a call's time forward and
           backward beside the HBM floor
-  experts the dropless expert layer (16,384 rows over 16 held of 128
-          experts, top-8): output and gradients against every held
-          expert evaluated densely, and nothing dropped when every row
-          chooses held experts
+  experts the dropless expert layer at both routed cells' shapes
+          (16,384 rows of 2048 over 16 held of 128 experts, softmax
+          top-8; 4,096 rows of 3584 over 8 held of 64, sigmoid top-4):
+          output and gradients against every held expert evaluated
+          densely, nothing dropped when every row chooses held experts,
+          a chunk's unread tail harmless to the grouped matmuls
+  rows    the expert layer's row moves alone (ISSUE 35) at both
+          cells' chunks, filled to the cells' share and filled whole:
+          XLA's gather, and the scatter-add as XLA's op against the
+          kernel of ops/moe_rows.py; device milliseconds and GB/s
   train   T.transformer_lm -> Adam.minimize -> amp.enable_amp ->
           Executor(TPUPlace(0)); 5 steps on one batch; loss ~ ln(vocab)
           and falling; the flash kernel is in the compiled step
@@ -57,6 +63,7 @@ import argparse
 import functools
 import json
 import math
+import os
 import sys
 import time
 
@@ -105,6 +112,8 @@ def kernels_in_interpret_mode():
     rotary_resolve = rotary._resolve_path
     rotary._resolve_path = lambda x, d, rows, rotate, force: rotary_resolve(
         x, d, rows, rotate, force or "interpret")
+    from paddle_tpu.ops import moe_rows
+    moe_rows._resolve_path = lambda shape, like, force: force or "interpret"
 
 
 # --------------------------------------------------------------------------
@@ -390,58 +399,185 @@ def phase_rotary(seed, rehearse):
 def phase_experts(seed, rehearse):
     """The dropless expert layer against every held expert evaluated
     densely (float32, `highest`), output and gradients, under the
-    router's own choices and with every row sent to held experts."""
+    router's own choices and with every row sent to held experts, at
+    the shapes of the two routed cells (ISSUE 35: the rows go back to
+    their tokens by the kernel of ops/moe_rows.py); and the grouped
+    matmuls' gradients with NaN in a chunk's tail past its pairs, which
+    must reach nothing."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.parallel import moe
-    n, d, f, e, held, k = (256, 64, 32, 16, 4, 4) if rehearse else (
-        16384, 2048, 768, 128, 16, 8)
+    shapes = [(256, 256, 32, 16, 4, 4, "softmax", 1.0)] if rehearse else [
+        (16384, 2048, 768, 128, 16, 8, "softmax", 1.0),
+        (4096, 3584, 1024, 64, 8, 4, "sigmoid", 2.0)]
     rng = np.random.RandomState(seed)
     mk = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
-    x = mk(n, d)
-    wr = mk(d, e) * 0.02
-    wg, wu, wd = (mk(held, d, f) * d ** -0.5, mk(held, d, f) * d ** -0.5,
-                  mk(held, f, d) * f ** -0.5)
     bf16 = lambda a: a.astype(jnp.bfloat16)
+    rel = lambda a, r: float(jnp.max(jnp.abs(a.astype(jnp.float32) - r))
+                             / jnp.max(jnp.abs(r)))
+    for n, d, f, e, held, k, score, scaling in shapes:
+        x = mk(n, d)
+        wr = mk(d, e) * 0.02
+        wg, wu, wd = (mk(held, d, f) * d ** -0.5, mk(held, d, f) * d ** -0.5,
+                      mk(held, f, d) * f ** -0.5)
+        # the series' last label is `rows`: what adds a chunk's rows
+        by_kernel = lambda: sum(
+            v for key, v in moe._LOWERINGS.snapshot().items()
+            if key[-1] == ("interpret" if rehearse else "pallas"))
+        was = by_kernel()
 
-    def layer(x, wr, wg, wu, wd):
-        return moe.routed_experts(x, wr, bf16(wg), bf16(wu), bf16(wd), e,
-                                  0, k)
+        def layer(x, wr, wg, wu, wd):
+            return moe.routed_experts(x, wr, bf16(wg), bf16(wu), bf16(wd), e,
+                                      0, k, score=score, scaling=scaling)
 
-    def dense(x, wr, wg, wu, wd):
-        _, w, idx = moe.route(x, wr, k, True)
-        out = 0.0
-        for i in range(held):
-            w_i = jnp.sum(jnp.where(idx == i, w, 0.0), 1)[:, None]
-            out = out + w_i * ((jax.nn.silu(x @ wg[i]) * (x @ wu[i])) @ wd[i])
-        return out
+        def dense(x, wr, wg, wu, wd):
+            _, w, idx = moe.route(x, wr, k, True, score=score,
+                                  scaling=scaling)
+            out = 0.0
+            for i in range(held):
+                w_i = jnp.sum(jnp.where(idx == i, w, 0.0), 1)[:, None]
+                out = out + w_i * (
+                    (jax.nn.silu(x @ wg[i]) * (x @ wu[i])) @ wd[i])
+            return out
 
-    for routing in ("the router's own", "every row on held experts"):
-        if routing != "the router's own":
-            x = x.at[:, 0].set(8.0)
-            wr = (wr * 0.01).at[0, :k].set(5.0)
+        for routing in ("the router's own", "every row on held experts"):
+            if routing != "the router's own":
+                x = x.at[:, 0].set(8.0)
+                wr = (wr * 0.01).at[0, :k].set(5.0)
+            t0 = time.perf_counter()
+            out, _, counts, _ = jax.jit(layer)(x, wr, wg, wu, wd)
+            pairs = int(counts[:held].sum())
+            sq = lambda fn: lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum()
+            got = jax.jit(jax.grad(sq(lambda *a: layer(*a)[0]),
+                                   (0, 2, 3, 4)))(x, wr, wg, wu, wd)
+            with jax.default_matmul_precision("highest"):
+                ref = jax.jit(dense)(x, wr, wg, wu, wd)
+                want = jax.jit(jax.grad(sq(dense), (0, 2, 3, 4)))(
+                    x, wr, wg, wu, wd)
+            errs = [rel(out, ref)] + [rel(a, r) for a, r in zip(got, want)]
+            log("[experts] %d rows of %d, %d of %d experts held, %s top-%d, "
+                "%s: %d pairs on held experts; out %.3e dx %.3e dgate %.3e "
+                "dup %.3e ddown %.3e from the dense float32 layer (%.1f s)"
+                % (n, d, held, e, score, k, routing, pairs, *errs,
+                   time.perf_counter() - t0))
+            assert int(counts.sum()) == n * k
+            if routing != "the router's own":
+                assert pairs == n * k, "a held pair was dropped"
+            assert max(errs) <= EXPERT_TOL, errs
+        assert by_kernel() > was, "the layer did not take the row kernel"
+
+        # a chunk's tail past its pairs holds other experts' rows, and
+        # might hold anything: NaN there must reach no gradient of the
+        # grouped matmuls
+        cap = min(4096, n)
+        sizes = jnp.full((held,), cap // (2 * held), jnp.int32)
+        live = int(sizes.sum())
+        xs, dy = bf16(mk(cap, d)), mk(cap, d).at[live:].set(0.0)
+
+        def pull(xs):
+            y, vjp = jax.vjp(lambda xs, *ws: moe._swiglu_experts(
+                xs, *ws, sizes), xs, bf16(wg), bf16(wu), bf16(wd))
+            return (y[:live],) + tuple(
+                g[:live] if g.shape[0] == cap else g for g in vjp(dy))
+
+        clean = jax.jit(pull)(xs)
+        dirty = jax.jit(pull)(xs.at[live:].set(jnp.nan))
+        worst = max(float(jnp.max(jnp.abs(
+            a.astype(jnp.float32) - b.astype(jnp.float32))))
+            for a, b in zip(clean, dirty))
+        log("[experts] NaN in the %d places past %d pairs of a chunk: the "
+            "grouped matmuls' output and gradients move by %.1e"
+            % (cap - live, live, worst))
+        assert worst == 0.0, worst
+
+
+def phase_rows(seed, rehearse):
+    """Each row move of the expert layer alone (ISSUE 35), at the two
+    routed cells' shapes with the chunk filled to the cell's share and
+    filled whole: the gather into a chunk (bfloat16 rows; XLA's op on
+    every path) and the scatter-add out of it (float32 rows times a
+    weight), XLA's op over the whole chunk against the kernel of
+    ops/moe_rows.py, which visits the places that hold pairs. The rows
+    are sorted runs as the layer's are: ascending within an expert, a
+    token coming again across experts. Times are device times of the
+    jitted call under the profiler (`calls` dispatches; the
+    accumulator donated, as the layer's loop carries it). GB/s counts
+    the bytes of the places that hold pairs: a row read and written by
+    the gather; a row of y read, a row of the accumulator read and
+    written by the scatter-add."""
+    import jax
+    import jax.numpy as jnp
+    from chipbench import tracing
+    from paddle_tpu.ops import moe_rows as mr
+    cells = [("tiny", 64, 256, 128, 4, 70)] if rehearse else [
+        ("sdar_train_bd4k", 16384, 2048, 32768, 16, 16000),
+        ("xing4_train_T4k", 4096, 3584, 4096, 8, 2200)]
+    path = "interpret" if rehearse else "pallas"
+    calls = 2 if rehearse else 10
+    rng = np.random.RandomState(seed)
+    trace_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "chiprun_out", "rows_trace")
+
+    def timed(fn, first, *rest, carried=False):
+        """(ms a call, the last result): the median device time of the
+        jitted program's runs; in a rehearsal the host's clock."""
+        out = jax.block_until_ready(fn(first, *rest))
+        tracing.start(trace_dir)
         t0 = time.perf_counter()
-        out, _, counts, _ = jax.jit(layer)(x, wr, wg, wu, wd)
-        pairs = int(counts[:held].sum())
-        sq = lambda fn: lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum()
-        got = jax.jit(jax.grad(sq(lambda *a: layer(*a)[0]),
-                               (0, 2, 3, 4)))(x, wr, wg, wu, wd)
-        with jax.default_matmul_precision("highest"):
-            ref = jax.jit(dense)(x, wr, wg, wu, wd)
-            want = jax.jit(jax.grad(sq(dense), (0, 2, 3, 4)))(
-                x, wr, wg, wu, wd)
-        rel = lambda a, r: float(jnp.max(jnp.abs(a.astype(jnp.float32) - r))
-                                 / jnp.max(jnp.abs(r)))
-        errs = [rel(out, ref)] + [rel(a, r) for a, r in zip(got, want)]
-        log("[experts] %d rows, %d of %d experts held, top-%d, %s: %d pairs "
-            "on held experts; out %.3e dx %.3e dgate %.3e dup %.3e ddown "
-            "%.3e from the dense float32 layer (%.1f s)" % (
-                n, held, e, k, routing, pairs, *errs,
-                time.perf_counter() - t0))
-        assert int(counts.sum()) == n * k
-        if routing != "the router's own":
-            assert pairs == n * k, "a held pair was dropped"
-        assert max(errs) <= EXPERT_TOL, errs
+        for _ in range(calls):
+            out = fn(out if carried else first, *rest)
+        jax.block_until_ready(out)
+        wall = (time.perf_counter() - t0) * 1e3 / calls
+        tracing.stop()
+        runs = [row["dur"] * 1e3 for row in tracing.load_rows(trace_dir)
+                if row["plane"].startswith("/device:")
+                and row["line"] == tracing.MODULE_LINE]
+        assert rehearse or len(runs) == calls, len(runs)
+        return (float(np.median(runs)) if runs else wall), out
+
+    for cell, n, d, cap, held, share in cells:
+        x = jnp.asarray(rng.randn(n, d), jnp.bfloat16)
+        y = jnp.asarray(rng.randn(cap, d), jnp.float32)
+        scale = jnp.asarray(rng.rand(cap), jnp.float32)
+        for count in (share, cap):
+            run = count // held
+            rows = np.concatenate(
+                [np.sort(rng.choice(n, run, replace=False))
+                 for _ in range(held)]
+                + [rng.randint(0, n, cap - run * held)]).astype(np.int32)
+            rows, count = jnp.asarray(rows), run * held
+            cnt = jnp.asarray(count, jnp.int32)
+            ms, _ = timed(jax.jit(lambda x, rows: x[rows]), x, rows)
+            log("[rows] %s gather bf16 [%d, %d] -> %d places, %d hold "
+                "pairs: XLA's op %.3f ms (%.0f GB/s on the pairs' bytes)"
+                % (cell, n, d, cap, count, ms,
+                   2 * count * d * 2 / 1e6 / ms))
+
+            def xla_add(acc, y, rows, scale, c):
+                return mr.scatter_add(acc, (n, d), y, rows, scale, c, "xla")
+
+            def kernel_add(acc, y, rows, scale, c):
+                return mr.scatter_add(acc, (n, d), y, rows, scale, c, path)
+
+            xla_ms, want = timed(
+                jax.jit(xla_add, donate_argnums=0),
+                mr.zeros((n, d), "xla"), y, rows, scale, cnt, carried=True)
+            ker_ms, got = timed(
+                jax.jit(kernel_add, donate_argnums=0),
+                mr.zeros((n, d), path), y, rows, scale, cnt, carried=True)
+            leave = jax.jit(lambda a: mr.result(a, (n, d), jnp.float32, path))
+            back_ms, got = timed(leave, got)
+            err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+            add_gb = 3 * count * d * 4 / 1e6
+            log("[rows] %s scatter-add f32 %d places, %d hold pairs -> "
+                "[%d, %d]: XLA's op %.3f ms (%.0f GB/s), the kernel %.3f ms "
+                "(%.0f GB/s) + %.3f ms to leave the slab, once a pass; "
+                "%d sums apart by %.1e" % (
+                    cell, cap, count, n, d, xla_ms, add_gb / xla_ms, ker_ms,
+                    add_gb / ker_ms, back_ms, calls + 1, err))
+            assert err <= 1e-5, err
+    if rehearse:
+        log("[rows] (REHEARSAL: a CPU's times, no device number)")
 
 
 # --------------------------------------------------------------------------
@@ -809,8 +945,8 @@ def main():
                          "dp2 x tp2 mesh and its one-device baseline")
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
-                         "(flash, gqa, mla, rotary, experts, train, "
-                         "serve); all of them if not given")
+                         "(flash, gqa, mla, rotary, experts, rows, "
+                         "train, serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: tiny size, Pallas kernels in "
                          "interpret mode, no tpu_custom_call "
@@ -833,6 +969,7 @@ def main():
     else:
         phases = {"flash": phase_flash, "gqa": phase_gqa, "mla": phase_mla,
                   "rotary": phase_rotary, "experts": phase_experts,
+                  "rows": phase_rows,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}
         for name in (args.phases.split(",") if args.phases else phases):

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..monitor import metrics as _metrics
+from ..ops import moe_rows
 
 
 def top1_gating(logits, capacity, rng=None, noise_std=0.0):
@@ -188,7 +189,10 @@ def moe_ffn_pp_sharded(x, gate_w, w_up_local, w_down_local, ep_axis,
 # as a rule, all of them when every row chooses held experts. So the
 # matmuls' work follows the rows present, and memory a chunk, not the
 # worst case of N * top_k pairs. A chunk's rows are gathered from x and
-# go back to their tokens by one scatter-add (both move the whole chunk).
+# go back to their tokens by one scatter-add: a Pallas kernel that
+# visits only the places of the chunk that hold pairs (ops/moe_rows.py,
+# ISSUE 35) where the device and the width allow, XLA's scatter-add over
+# the whole chunk elsewhere; the gather is XLA's on every path.
 # The backward is written out: it recomputes a chunk's hidden
 # activations from x instead of keeping them, so that nothing of a
 # chunk's size outlives its iteration.
@@ -198,8 +202,10 @@ _LOWERINGS = _REG.counter(
     "lowerings of the routed expert layer at trace time (none a step): "
     "the grouped matmul's path, the experts routed over, those held here, "
     "the experts a row takes, the router's score function (softmax, "
-    "sigmoid) and whether a shared expert rides beside the routed ones",
-    ("path", "experts", "experts_held", "top_k", "score", "shared_expert"))
+    "sigmoid), whether a shared expert rides beside the routed ones, and "
+    "what adds a chunk's rows to their tokens (pallas, interpret, xla)",
+    ("path", "experts", "experts_held", "top_k", "score", "shared_expert",
+     "rows"))
 
 
 def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
@@ -245,42 +251,50 @@ def _swiglu_experts(xs, w_gate, w_up, w_down, sizes):
 
 def _chunk(c, cap, order, ends, k):
     """Chunk c of the sorted pairs: (pairs [cap], their rows [cap],
-    whether each place holds a pair, the rows to each expert)."""
-    at = c * cap + jnp.arange(cap, dtype=jnp.int32)
+    how many of the places hold a pair, the rows to each expert)."""
     pairs = lax.dynamic_slice_in_dim(order, c * cap, cap)
     inside = jnp.clip(ends - c * cap, 0, cap)
     sizes = inside - jnp.concatenate([inside[:1] * 0, inside[:-1]])
-    return pairs, pairs // k, at < ends[-1], sizes
+    return pairs, pairs // k, inside[-1], sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _held_experts(x, weight, w_gate, w_up, w_down, order, ends, cap):
-    return _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held_experts(x, weight, w_gate, w_up, w_down, order, ends, cap, how,
+                  dtype):
+    return _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap,
+                     how, dtype)[0]
 
 
-def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap):
+def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype):
+    """`how` adds a chunk's rows to their tokens ("pallas" /
+    "interpret" / "xla", moe_rows._resolve_path); the result is rounded
+    to x's dtype and returned as `dtype`, the layer's. The places of a
+    gathered chunk past its pairs hold other experts' rows: ragged_dot
+    computes no row past the sum of its sizes, and every other reader
+    masks them or stops at `count`."""
     k = weight.shape[1]
 
     def body(c, out):
-        pairs, rows, there, sizes = _chunk(c, cap, order, ends, k)
+        pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
         y = _swiglu_experts(x[rows], w_gate, w_up, w_down, sizes)
-        y = y * weight.reshape(-1)[pairs][:, None]
-        return out.at[rows].add(jnp.where(there[:, None], y, 0.0))
+        return moe_rows.scatter_add(out, x.shape, y, rows,
+                                    weight.reshape(-1)[pairs], count, how)
 
     out = lax.fori_loop(0, (ends[-1] + cap - 1) // cap, body,
-                        jnp.zeros(x.shape, jnp.float32))
-    return out.astype(x.dtype), (x, weight, w_gate, w_up, w_down, order,
-                                 ends)
+                        moe_rows.zeros(x.shape, how))
+    return moe_rows.result(out, x.shape, x.dtype, how, dtype), (
+        x, weight, w_gate, w_up, w_down, order, ends)
 
 
-def _held_bwd(cap, res, dout):
+def _held_bwd(cap, how, dtype, res, dout):
     x, weight, w_gate, w_up, w_down, order, ends = res
     k = weight.shape[1]
     dout = dout.astype(x.dtype)
 
     def body(c, carry):
         dx, dweight, dws = carry
-        pairs, rows, there, sizes = _chunk(c, cap, order, ends, k)
+        pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
+        there = jnp.arange(cap, dtype=jnp.int32) < count
         y, vjp = jax.vjp(
             lambda xs, *ws: _swiglu_experts(xs, *ws, sizes),
             x[rows], w_gate, w_up, w_down)
@@ -290,17 +304,17 @@ def _held_bwd(cap, res, dout):
         dweight = dweight.at[pairs].add(
             jnp.where(there, jnp.sum(dy * y, axis=1), 0.0))
         dxs, *dw = vjp(dy * weight.reshape(-1)[pairs][:, None])
-        dx = dx.at[rows].add(
-            jnp.where(there[:, None], dxs, 0).astype(jnp.float32))
+        dx = moe_rows.scatter_add(dx, x.shape, dxs, rows, None, count, how)
         return dx, dweight, [a + b.astype(jnp.float32)
                              for a, b in zip(dws, dw)]
 
     dx, dweight, dws = lax.fori_loop(
         0, (ends[-1] + cap - 1) // cap, body,
-        (jnp.zeros(x.shape, jnp.float32),
+        (moe_rows.zeros(x.shape, how),
          jnp.zeros(weight.size, weight.dtype),
          [jnp.zeros(w.shape, jnp.float32) for w in (w_gate, w_up, w_down)]))
-    return (dx.astype(x.dtype), dweight.reshape(weight.shape),
+    return (moe_rows.result(dx, x.shape, x.dtype, how),
+            dweight.reshape(weight.shape),
             *(d.astype(w.dtype) for d, w in zip(dws, (w_gate, w_up, w_down))),
             None, None)
 
@@ -310,7 +324,7 @@ _held_experts.defvjp(_held_fwd, _held_bwd)
 
 def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
                    first_expert=0, top_k=8, norm_topk=True, score="softmax",
-                   bias=None, scaling=1.0, shared_expert=False):
+                   bias=None, scaling=1.0, shared_expert=False, force=None):
     """One chip's share of a mixture of SiLU-gated experts, dropless.
 
     x [N, d]; router_w [d, E] over ALL `num_experts`; w_gate, w_up
@@ -331,12 +345,16 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     held experts. `score`, `bias` and `scaling` are `route`'s;
     `shared_expert` says that the caller runs a shared expert beside
     this layer (the counter's label: nothing here computes it, and a
-    chip's share of the layer holds it once)."""
+    chip's share of the layer holds it once). A chunk's rows go back to
+    their tokens by the kernel of `ops/moe_rows.py` on a TPU where d is
+    whole lane tiles, by XLA's scatter-add elsewhere; `force` ("pallas"
+    / "interpret" / "xla") is for tests."""
     n, d = x.shape
     held = w_gate.shape[0]
+    adder = moe_rows._resolve_path(x.shape, x, force)
     _LOWERINGS.inc(path="ragged_dot", experts=str(num_experts),
                    experts_held=str(held), top_k=str(top_k), score=score,
-                   shared_expert=str(bool(shared_expert)).lower())
+                   shared_expert=str(bool(shared_expert)).lower(), rows=adder)
     # the plain softmax router is called as it always was, four
     # arguments: its callers' stand-ins (tests) have that signature
     how = {} if (score, bias, scaling) == ("softmax", None, 1.0) else {
@@ -362,5 +380,5 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
               -(-pairs // 8) * 8)
     order = jnp.pad(order, (0, -(-pairs // cap) * cap - pairs))
     out = _held_experts(x.astype(w_gate.dtype), weight, w_gate, w_up,
-                        w_down, order, ends, cap)
-    return out.astype(x.dtype), aux, counts, experts.astype(jnp.int32)
+                        w_down, order, ends, cap, adder, x.dtype)
+    return out, aux, counts, experts.astype(jnp.int32)

@@ -367,14 +367,22 @@ def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
         assert len(moved(text)) == len(bare) == 4
 
 
-def test_routed_experts_compile_for_v5e(chip):
-    """The cell's expert layer, forward and backward: grouped matmuls as
-    XLA's ragged-dot kernels inside the two loops over chunks, on a
-    chunk's 32,768 rows: no hidden activation of the worst case's
-    N * top_k rows exists."""
+@pytest.mark.parametrize("shape", [(16384, 2048, 768, 128, 16, 8),
+                                   (4096, 3584, 1024, 64, 8, 4)],
+                         ids=["sdar_train_bd4k", "xing4_train_T4k"])
+def test_routed_experts_compile_for_v5e(chip, shape):
+    """A routed cell's expert layer, forward and backward: grouped
+    matmuls as XLA's ragged-dot kernels inside the two loops over chunks,
+    on a chunk's rows (32,768; 4,096): no hidden activation of the worst
+    case's N * top_k rows exists. ISSUE 35: a chunk's rows go back to
+    their tokens by `moe_scatter_add_rows` (once forward, once for dx)
+    and each accumulator leaves its slab by `moe_leave_slab`, under
+    their own names; XLA scatters nothing of x's width (what is left of
+    that kind is the pairs' weights, one number a place), and its
+    gathers of a chunk's rows stay: x forward, x and dout backward."""
     import re
     from paddle_tpu.parallel import moe
-    n, d, f, e, held, k = 16384, 2048, 768, 128, 16, 8
+    n, d, f, e, held, k = shape
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=chip)
     x = sds((n, d), jnp.float32)
@@ -383,15 +391,25 @@ def test_routed_experts_compile_for_v5e(chip):
                                                        jnp.bfloat16)
 
     def loss(x, wr, wg, wu, wd):
-        out, aux, _, _ = moe.routed_experts(x, wr, wg, wu, wd, e, 0, k)
+        out, aux, _, _ = moe.routed_experts(x, wr, wg, wu, wd, e, 0, k,
+                                            force="pallas")
         return out.astype(jnp.float32).sum() + aux
 
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+    # the value too: XLA drops a forward whose result nobody reads
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
                           x, wr, w_in, w_in, w_out)
     assert "ragged-dot" in text and "while" in text
     hidden = {int(rows) for rows in re.findall(
         r"(?:bf16|f32)\[(\d+),%d\]" % f, text)}
-    assert hidden and max(hidden) == 2 * n * k * held // e
+    cap = 2 * n * k * held // e
+    assert hidden and max(hidden) == cap
+    calls = lambda name: len(re.findall(
+        r"%%%s[.\d]* = \S+ custom-call\(" % name, text))
+    assert calls("moe_scatter_add_rows") == 2
+    assert calls("moe_leave_slab") == 2
+    wide = lambda kind: [line for line in text.splitlines() if re.search(
+        r" %s\(" % kind, line) and re.search(r"\[\d+,%d\]" % d, line)]
+    assert not wide("scatter"), wide("scatter")[:2]
 
 
 # --------------------------------------------------------------------------
