@@ -588,6 +588,7 @@ class Executor:
                                  feed_bytes=fb, tokens=tk, synced=False)
 
         with _trc.phase("exe.commit", step=step):
+            _trc.fetched(fetches_k)
             for n, v in new_state.items():
                 scope.set(n, v)
             if check_nan:
@@ -812,6 +813,7 @@ class Executor:
                 _mon.on_step(key, now - t0, feed_bytes=fb, tokens=tk,
                              synced=False)
         with _trc.phase("exe.commit", step=step):
+            _trc.fetched(fetches)
             fetches = self._trim_fetches(fetch_names, fetches, fetch_lods)
 
             # Commit updated persistable state back to the scope. New

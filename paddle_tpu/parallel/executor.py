@@ -369,6 +369,7 @@ class ParallelExecutor:
                                  executor="pexe", synced=False)
 
         with _trc.phase("pexe.pull", step=step):
+            _trc.fetched(fetches_k)
             fetches_k = [self._local_value(v) for v in fetches_k]
             lods_k = {n: self._local_value(v) for n, v in lods_k.items()}
             guards_k = {n: self._local_value(v)
@@ -531,6 +532,7 @@ class ParallelExecutor:
                              synced=False)
 
         with _trc.phase("pexe.pull", step=step):
+            _trc.fetched(fetches)
             fetches = [self._local_value(v) for v in fetches]
             fetch_lods = {k: self._local_value(v)
                           for k, v in fetch_lods.items()}

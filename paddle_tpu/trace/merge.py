@@ -214,12 +214,24 @@ def stats_files(paths, root_name=None):
              and (root_name is None or s["name"] == root_name)]
     rounds = []
     strag = {}
+    waited = {}
     for r in roots:
         kids = children.get(r["span"], [])
         by_verb = {}
         for k in kids:
             by_verb[k["name"]] = by_verb.get(k["name"], 0.0) \
                 + float(k["dur"])
+        # a step root carries its row of the step ledger: its phases
+        # (children that write no row of their own) and whether the
+        # device had run dry at its entry
+        attrs = r.get("attrs") or {}
+        prefix = r["name"].rpartition(".")[0]
+        for name, secs in (attrs.get("phases") or {}).items():
+            name = name if "." in name else "%s.%s" % (prefix, name)
+            by_verb[name] = by_verb.get(name, 0.0) + float(secs)
+        if "device_waited" in attrs:
+            key = json.dumps(attrs["device_waited"])
+            waited[key] = waited.get(key, 0) + 1
         total = float(r["dur"])
         rpc_total = sum(by_verb.values())
         entry = {"trace": r["trace"], "name": r["name"], "dur_s": total,
@@ -254,6 +266,7 @@ def stats_files(paths, root_name=None):
             if n else {},
             "mean_local_s": (sum(r["local_s"] for r in rounds) / n)
             if n else None,
+            "device_waited": waited,
         },
         "stragglers": sorted(
             ({"who": who, "rounds": st["rounds"],
@@ -288,6 +301,11 @@ def render_stats(s):
              for v, d in sorted(r["mean_by_verb_s"].items(),
                                 key=lambda kv: -kv[1])]
             + ["local(compute) %s" % _ms(r["mean_local_s"])]))
+        if r.get("device_waited"):
+            lines.append("  device waited at entry (the step before was "
+                         "done: the host was late): " + "  ".join(
+                             "%s %d" % kv for kv in
+                             sorted(r["device_waited"].items())))
     for e in s["stragglers"][:5]:
         lines.append("  straggler %-40s dominated %d round(s), mean "
                      "%.0f%% of the round"

@@ -47,6 +47,15 @@ Design points:
     JSONL row is written as before. The children of a root
     (``exe.feed`` ... ``engine.book``) are phases only: they write no
     JSONL row, so RPC verb spans keep the step root as their parent.
+  * The step ledger (ISSUE 36): a step root also leaves ONE row in a
+    bounded in-memory ring, always (no profiler session, no armed
+    tracer needed), on ``time.perf_counter()``: entry, exit, the
+    seconds of each phase that ran inside it, the seconds since the
+    thread's previous root (``outside``: the caller's), whether the
+    previous step's fetch was already done at entry
+    (``device_waited``), and collections of 1 ms or more as event rows
+    between them. ``steps()`` reads it. It is what says where a run
+    lost time when no profiler was on.
   * The span log reuses monitor's FlightRecorder (bounded JSONL,
     atomic-append, in-band truncation marker). Rows:
       span        {trace, span, parent, name, t0, dur, pid, proc, tid,
@@ -57,11 +66,13 @@ Design points:
 """
 
 import collections
+import gc
 import os
 import random
 import sys
 import threading
 import time
+import weakref
 
 from jax.profiler import TraceAnnotation
 
@@ -72,7 +83,8 @@ __all__ = [
     "SpanContext", "Span", "Tracer", "enable", "disable", "enabled",
     "tracer", "span", "annotate", "current_span", "active_trace_id",
     "extract", "maybe_enable_from_flags", "detached_span", "child_span",
-    "retain_trace", "tail_armed", "tail_dump", "phase",
+    "retain_trace", "tail_armed", "tail_dump", "phase", "steps",
+    "fetched",
 ]
 
 _DEFAULT_MAX_BYTES = 64 << 20
@@ -204,25 +216,66 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+# -- the step ledger ---------------------------------------------------------
+
+_STEP_ROOTS = frozenset(("exe.step", "pexe.step", "engine.step"))
+# step rows and event rows, oldest first; bounded as the monitor's
+# compile log is, and on the same clock
+_STEP_LOG = collections.deque(maxlen=4096)
+_GC_FLOOR_S = 1e-3        # a collection shorter than this leaves no row
+# per thread: ``root``, its open step root, and ``last``, {root name:
+# (the previous root's exit, its first fetch)}
+_thread = threading.local()
+_now = time.perf_counter
+_ANN_INIT = TraceAnnotation.__init__
+_ANN_ENTER = TraceAnnotation.__enter__
+_ANN_EXIT = TraceAnnotation.__exit__
+
+
 class phase(TraceAnnotation):
     """``with trace.phase("exe.feed", step=n):`` — one timed phase of
     a hot path, in the JAX profiler's timeline. A
     ``jax.profiler.TraceAnnotation`` under the span interface: with no
-    profiler session it records nothing (about half a microsecond a
-    ``with``; PERF.md has the figure), with one its name, interval,
-    thread and keyword arguments land in the ``.xplane.pb`` beside
-    the device ops. It never writes a JSONL row."""
+    profiler session the annotation records nothing, with one its
+    name, interval, thread and keyword arguments land in the
+    ``.xplane.pb`` beside the device ops. It never writes a JSONL row.
+    Inside a step root it also adds its seconds to that root's row of
+    the step ledger (``steps()`` gives it under its name less the
+    root's prefix: ``exe.feed`` -> ``feed``); a phase inside a phase
+    adds nothing, so a row's phases sum to no more than the root. Some
+    1.2 microseconds a ``with`` outside a root (0.6 of them the
+    annotation's own) and 1.7 inside one (PERF.md has the figures)."""
 
-    __slots__ = ()
+    __slots__ = ("_name", "_root", "_t0")
     ctx = None
+
+    def __init__(self, name, **attrs):
+        _ANN_INIT(self, name, **attrs)
+        self._name = name
 
     def annotate(self, **attrs):
         self.set_metadata(**attrs)
 
+    def __enter__(self):
+        root = self._root = getattr(_thread, "root", None)
+        if root is not None:
+            root._depth += 1
+            self._t0 = _now()
+        return _ANN_ENTER(self)
+
+    def __exit__(self, etype, exc, tb):
+        root = self._root
+        if root is not None:
+            root._depth -= 1
+            if not root._depth:
+                phases, name = root._phases, self._name
+                phases[name] = phases.get(name, 0.0) + _now() - self._t0
+        return _ANN_EXIT(self, etype, exc, tb)
+
 
 class _RootSpan:
-    """A step root under an armed tracer: the profiler annotation and
-    the Dapper span, entered and left together."""
+    """A root under an armed tracer: the profiler annotation and the
+    Dapper span, entered and left together."""
 
     __slots__ = ("_ann", "_span")
 
@@ -246,6 +299,172 @@ class _RootSpan:
     def __exit__(self, etype, exc, tb):
         self._span.__exit__(etype, exc, tb)
         return self._ann.__exit__(etype, exc, tb)
+
+
+class _StepRoot(_RootSpan):
+    """A step root with no other open on its thread: the annotation,
+    the Dapper span when the tracer is armed, and its row of the step
+    ledger, which is in the ring from entry (``t_exit`` None while the
+    step runs, so a step that never returns is there to be seen)."""
+
+    __slots__ = ("_name", "_row", "_phases", "_depth", "_fetch")
+
+    def __init__(self, name, attrs, span):
+        # no ``phase``: there is no root open for it to add itself to
+        self._ann = TraceAnnotation(name, **attrs)
+        self._span = span
+        self._name = name
+        self._depth = 0
+        self._fetch = None
+        self._phases = {}
+        self._row = {
+            "root": name, "step": attrs.get("step"), "k": attrs.get("k"),
+            "thread": threading.get_ident(), "t_enter": None,
+            "t_exit": None, "outside": None, "device_waited": None,
+            "fresh": False, "phases": self._phases}
+
+    @property
+    def ctx(self):
+        return None if self._span is None else self._span.ctx
+
+    def annotate(self, **attrs):
+        self._ann.set_metadata(**attrs)
+        if self._span is not None:
+            self._span.annotate(**attrs)
+
+    def __enter__(self):
+        row = self._row
+        last = getattr(_thread, "last", None)
+        if last is None:
+            last = _thread.last = {}
+        before = last.get(self._name)
+        now = _now()
+        if before is not None:
+            row["outside"] = now - before[0]
+            row["device_waited"] = _fetch_done(before[1])
+        row["t_enter"] = now
+        _STEP_LOG.append(row)
+        self._ann.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        _thread.root = self
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        _thread.root = None
+        row = self._row
+        row["fresh"] = self._name[:-4] + "build" in self._phases
+        if etype is not None:
+            row["error"] = repr(exc)
+        fetch, self._fetch = self._fetch, None
+        if fetch is not None and fetch is not True:
+            # done already (the step pulled it to the host): the next
+            # entry needs no array to know that the device ran dry
+            fetch = True if _fetch_done(fetch) else weakref.ref(fetch)
+        row["t_exit"] = now = _now()
+        _thread.last[self._name] = (now, fetch)
+        if self._span is not None:
+            self._span.annotate(
+                phases=_short(self._name, self._phases),
+                outside=row["outside"], fresh=row["fresh"],
+                device_waited=row["device_waited"])
+            self._span.__exit__(etype, exc, tb)
+        return self._ann.__exit__(etype, exc, tb)
+
+
+def _short(root, phases):
+    """A row's phases under their names less the root's prefix
+    (``exe.feed`` -> ``feed``; a root opened inside ``pexe.step`` stays
+    ``exe.step``)."""
+    cut = len(root) - 4               # "exe.step" -> "exe."
+    return {name[cut:] if name.startswith(root[:cut]) else name: s
+            for name, s in phases.items()}
+
+
+def _fetch_done(fetch):
+    """Whether a step's first fetch is done, without blocking: True
+    (it was on the host already), a ``jax.Array`` or a weak reference
+    to one; None where there was no fetch, the caller has dropped it
+    or it was donated since."""
+    if fetch is None or fetch is True:
+        return fetch
+    if isinstance(fetch, weakref.ref):
+        fetch = fetch()
+        if fetch is None:
+            return None
+    try:
+        return bool(fetch.is_ready())
+    except RuntimeError:              # deleted: donated to a later step
+        return None
+
+
+def fetched(values):
+    """An executor hands its open step root the step's fetches where
+    it commits them, so that the NEXT root on the thread can ask at
+    its entry whether this step's first fetch was done by then
+    (``device_waited``). The root keeps the array until it exits and a
+    weak reference after that: no buffer lives a step longer for it."""
+    root = getattr(_thread, "root", None)
+    if root is None or root._fetch is not None:
+        return
+    first = next(iter(values), None)
+    if first is not None:
+        # a value with no is_ready is on the host already
+        root._fetch = first if hasattr(first, "is_ready") else True
+
+
+_gc_began = 0.0
+
+
+def _on_gc(when, info):
+    """``gc.callbacks``: a collection of ``_GC_FLOOR_S`` or more is an
+    event row of the step ledger, between the steps it fell among."""
+    global _gc_began
+    if when == "start":
+        _gc_began = time.perf_counter()
+        return
+    now = time.perf_counter()
+    if now - _gc_began >= _GC_FLOOR_S:
+        _STEP_LOG.append({"event": "gc", "generation": info["generation"],
+                          "seconds": now - _gc_began, "end": now,
+                          "thread": threading.get_ident()})
+
+
+gc.callbacks.append(_on_gc)
+
+
+def steps(root=None, since=None):
+    """The step ledger: a copy of its rows, oldest first (the last
+    4096). A step row is what one step root (``exe.step``,
+    ``pexe.step``, ``engine.step``) left: ``root``, ``step``, ``k``
+    (a megastep's), ``thread``, ``t_enter`` and ``t_exit`` on
+    ``time.perf_counter()`` (the clock of ``monitor.compile_log()``'s
+    ``end``; ``t_exit`` None while the step runs), ``phases`` (seconds
+    by phase: ``feed``, ``state``, ``build``, ``dispatch``, ``commit``
+    ...; the root's self time is its duration less their sum),
+    ``outside`` (seconds between the thread's previous root's exit and
+    this entry: the caller's; None for a first), ``device_waited``
+    (whether the previous step's first fetch was done at this entry:
+    True, the device had run dry and the host was late; False, the
+    device, or the runtime under it, was still at work; None, nothing
+    was fetched, or the caller dropped it), ``fresh`` (a ``build``
+    phase ran: this step compiled) and ``error`` where it raised. An
+    event row is a collection of 1 ms or more: ``event`` ("gc"),
+    ``generation``, ``seconds``, ``end``, ``thread``.
+
+    ``root`` keeps the step rows of one root; ``since`` the rows
+    entered (events: ended) at or after that reading of the clock."""
+    rows = []
+    for row in list(_STEP_LOG):
+        if root is not None and row.get("root") != root:
+            continue
+        if since is not None and row.get("t_enter", row.get("end")) < since:
+            continue
+        row = dict(row)
+        if "phases" in row:
+            row["phases"] = _short(row["root"], row["phases"])
+        rows.append(row)
+    return rows
 
 
 class _TailRing:
@@ -574,11 +793,18 @@ def span(name, **attrs):
     """``with trace.span("exe.step", step=i):`` — a ``phase`` in the
     profiler's timeline, always; with the tracer armed also the Dapper
     span (child of the ambient span or a new root) whose row goes to
-    the JSONL log."""
+    the JSONL log. A step root (``exe.step`` / ``pexe.step`` /
+    ``engine.step``) also leaves its row in the step ledger
+    (``steps()``), and armed its JSONL row carries that row's
+    ``phases``, ``outside``, ``device_waited`` and ``fresh`` as
+    attributes; one opened inside another on its thread is, as a
+    phase is, seconds in the outer one's row."""
     t = _TRACER
-    if t is None:
-        return phase(name, **attrs)
-    return _RootSpan(phase(name, **attrs), t.span(name, **attrs))
+    dapper = None if t is None else t.span(name, **attrs)
+    if name in _STEP_ROOTS and getattr(_thread, "root", None) is None:
+        return _StepRoot(name, attrs, dapper)
+    ann = phase(name, **attrs)
+    return ann if dapper is None else _RootSpan(ann, dapper)
 
 
 def detached_span(name, **attrs):
