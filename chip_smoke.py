@@ -14,6 +14,13 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           sequence of [4096, 32 x 128] reading [4096, 4 x 128], blocks
           of 4, both variants): dq, dk, dv against dense float32 math,
           the first block's wholly masked rows finite and weighed out
+  own_block  the rest of the gqa check (ISSUE 37; a phase of its own so
+          that `--phases own_block` probes it alone): block diffusion's
+          whole attention, [noised; clean] rows q [2, 8192, 32 x 128]
+          and k/v [2, 8192, 4 x 128], as ONE call of each kernel (the
+          own-block mask form) against dense float32 gradients, and its
+          device ms forward and backward beside the form it replaced
+          (two calls merged by lse, the own blocks as dense math)
   mla     the two-part score of latent attention through the streamed
           kernels at the cell xing4_train_T4k's shape (one sequence of
           [4096, 32 x 128] with q_pe [4096, 32 x 64] reading ONE k_pe
@@ -280,6 +287,155 @@ def phase_gqa(seed, rehearse):
             assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
 
 
+def _two_piece_attention(q, k, v, n_head, n_kv_head, block):
+    """Block diffusion's attention as ops/block_diffusion.py had it
+    until ISSUE 37, kept here as what the own-block form is timed
+    against: two calls of the flash kernels (clean on clean; noised on
+    the clean keys of earlier blocks, with its lse) and the noised
+    rows' own blocks as dense math in XLA, merged by log-sum-exp, round
+    slices of the halves and a concatenate."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+    b, t2, hd = q.shape
+    seq, d, group = t2 // 2, hd // n_head, n_head // n_kv_head
+    scale = d ** -0.5
+    kw = dict(causal=True, scale=scale, n_kv_head=n_kv_head,
+              mask_block=block)
+    q_n, k_n, v_n = q[:, :seq], k[:, :seq], v[:, :seq]
+    k_c, v_c = k[:, seq:], v[:, seq:]
+    clean = fa.flash_bthd(q[:, seq:], k_c, v_c, n_head, **kw)
+    before, lse_before = fa.flash_bthd_lse(q_n, k_c, v_c, n_head,
+                                           strict=True, **kw)
+    blocks = seq // block
+    qb = q_n.reshape(b, blocks, block, n_kv_head, group, d)
+    kb = k_n.reshape(b, blocks, block, n_kv_head, d)
+    vb = v_n.reshape(b, blocks, block, n_kv_head, d)
+    s = jnp.einsum("bnqhgd,bnkhd->bnqhgk", qb, kb,
+                   preferred_element_type=jnp.float32) * scale
+    lse_own = jax.nn.logsumexp(s, axis=-1)
+    own = jnp.einsum("bnqhgk,bnkhd->bnqhgd",
+                     jnp.exp(s - lse_own[..., None]).astype(v.dtype), vb,
+                     preferred_element_type=jnp.float32)
+    lse_own = lse_own.reshape(b, seq, n_head)
+    lse_before = lse_before.transpose(0, 2, 1)
+    top = jnp.maximum(lse_own, lse_before)
+    w_own = jnp.exp(lse_own - top)[..., None]
+    w_before = jnp.exp(lse_before - top)[..., None]
+    noised = (w_own * own.reshape(b, seq, n_head, d) + w_before
+              * before.reshape(b, seq, n_head, d).astype(jnp.float32)
+              ) / (w_own + w_before)
+    return jnp.concatenate(
+        [noised.reshape(b, seq, hd).astype(q.dtype), clean], axis=1)
+
+
+def _device_ms(call, args, calls, rehearse, name, carried=False):
+    """(device ms a call of the jitted `call`: the median of its runs
+    under the profiler; the last result; {op kind: ms a call}); in a
+    rehearsal the host's clock and no ops. `carried`: each call takes
+    the one before's result as its first argument (a donated
+    accumulator)."""
+    import collections
+    import jax
+    from chipbench import tracing
+    trace_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "chiprun_out", name)
+    out = jax.block_until_ready(call(*args))
+    tracing.start(trace_dir)
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = call(*((out,) + tuple(args[1:]) if carried else args))
+    jax.block_until_ready(out)
+    wall = (time.perf_counter() - t0) * 1e3 / calls
+    tracing.stop()
+    rows = [row for row in tracing.load_rows(trace_dir)
+            if row["plane"].startswith("/device:")]
+    runs = [row["dur"] * 1e3 for row in rows
+            if row["line"] == tracing.MODULE_LINE]
+    assert rehearse or len(runs) == calls, len(runs)
+    ops = collections.Counter()
+    for row in rows:
+        if row["line"] == tracing.OP_LINE:
+            ops[tracing.op_name(row["name"])] += row["dur"] * 1e3 / calls
+    return (float(np.median(runs)) if runs else wall), out, ops
+
+
+def phase_own_block(seed, rehearse):
+    """Block diffusion's attention inside the kernels (ISSUE 37; the
+    `gqa` phase's third mask form): the own-block form at the cell's shape, out, dq,
+    dk and dv of both halves against dense float32 math with the
+    2L x 2L mask written out (a sequence and a key/value head at a
+    time: 8 heads of 8192^2 float32 scores), then a layer's attention
+    forward and backward under the profiler, this form and the
+    two-piece form it replaced: whole, the kernels, and the rest."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import block_diffusion as bd
+    from paddle_tpu.ops import flash_attention as fa
+    b, seq, h, hkv, d = (2, 128, 4, 2, 128) if rehearse else (
+        2, 4096, 32, 4, 128)
+    group = h // hkv
+    rng = np.random.RandomState(seed + 37)
+    mk = lambda n: jnp.asarray(rng.randn(b, 2 * seq, n * d) * 0.5,
+                               jnp.bfloat16)
+    q, k, v, dy = mk(h), mk(hkv), mk(hkv), mk(h)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def both(attend):
+        def call(q, k, v, dy):
+            out, vjp = jax.vjp(attend, q, k, v)
+            return (out,) + vjp(dy)
+        return jax.jit(call)
+
+    def dense(q, k, v):         # one sequence, one key/value head
+        o, _ = fa._dense_lse(
+            fa.heads_first(q, group), fa.heads_first(k, 1),
+            fa.heads_first(v, 1), True, d ** -0.5, (2, fa._OWN))
+        return fa.heads_last(o)
+
+    t0 = time.perf_counter()
+    new = both(lambda q, k, v: bd.attention(q, k, v, h, hkv, 4))
+    old = both(lambda q, k, v: _two_piece_attention(q, k, v, h, hkv, 4))
+    got = new(q, k, v, dy)
+    text = new.lower(q, k, v, dy).compile().as_text()
+    dense_both = both(dense)
+    parts = []
+    with jax.default_matmul_precision("highest"):
+        for r in range(b):
+            heads = [dense_both(*(f32(x[r:r + 1, :, a * n * d:(a + 1) * n * d])
+                                  for x, n in ((q, group), (k, 1), (v, 1),
+                                               (dy, group))))
+                     for a in range(hkv)]
+            parts.append([jnp.concatenate(x, 2) for x in zip(*heads)])
+    want = [jnp.concatenate(x) for x in zip(*parts)]
+    errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+            for a, r in zip(got, want)]
+    was = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+           for a, r in zip(old(q, k, v, dy), want)]
+    log("[own_block] own-block form, q [%d, %d, %d] k/v [%d, %d, %d] bf16, "
+        "[noised; clean] rows, blocks of 4: out %.3e dq %.3e dk %.3e dv "
+        "%.3e from dense float32 math (the two-piece form: %.3e %.3e %.3e "
+        "%.3e) (%.1f s); tpu_custom_call sites %d" % (
+            b, 2 * seq, h * d, b, 2 * seq, hkv * d, *errs, *was,
+            time.perf_counter() - t0, text.count("tpu_custom_call")))
+    assert max(errs) <= FLASH_GRAD_TOL, errs
+    if not rehearse:
+        assert text.count("tpu_custom_call") == 3, "not one call a kernel"
+    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    for label, call in (("own-block", new), ("two-piece", old)):
+        ms, _, ops = _device_ms(call, (q, k, v, dy), 2 if rehearse else 10,
+                                rehearse, "bd_attention_" + label)
+        inside = {n: ops.pop(n, 0.0) for n in kernels}
+        log("[own_block] %s form, a layer's attention forward and backward: "
+            "%.3f ms a call on the device; kernels %s = %.3f; outside "
+            "them %.3f: %s" % (
+                label, ms, " ".join("%s %.3f" % x for x in inside.items()),
+                sum(inside.values()), sum(ops.values()),
+                " ".join("%s %.3f" % x for x in ops.most_common(12))))
+    if rehearse:
+        log("[own_block] (REHEARSAL: a CPU's times, no device number)")
+
+
 def phase_mla(seed, rehearse):
     """The kernels of the latent-attention step (ISSUE 34): a score of
     two parts, q_nope k_nope^T over 128 lanes a head plus q_pe k_pe^T
@@ -507,7 +663,6 @@ def phase_rows(seed, rehearse):
     written by the scatter-add."""
     import jax
     import jax.numpy as jnp
-    from chipbench import tracing
     from paddle_tpu.ops import moe_rows as mr
     cells = [("tiny", 64, 256, 128, 4, 70)] if rehearse else [
         ("sdar_train_bd4k", 16384, 2048, 32768, 16, 16000),
@@ -515,25 +670,11 @@ def phase_rows(seed, rehearse):
     path = "interpret" if rehearse else "pallas"
     calls = 2 if rehearse else 10
     rng = np.random.RandomState(seed)
-    trace_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "chiprun_out", "rows_trace")
 
-    def timed(fn, first, *rest, carried=False):
-        """(ms a call, the last result): the median device time of the
-        jitted program's runs; in a rehearsal the host's clock."""
-        out = jax.block_until_ready(fn(first, *rest))
-        tracing.start(trace_dir)
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            out = fn(out if carried else first, *rest)
-        jax.block_until_ready(out)
-        wall = (time.perf_counter() - t0) * 1e3 / calls
-        tracing.stop()
-        runs = [row["dur"] * 1e3 for row in tracing.load_rows(trace_dir)
-                if row["plane"].startswith("/device:")
-                and row["line"] == tracing.MODULE_LINE]
-        assert rehearse or len(runs) == calls, len(runs)
-        return (float(np.median(runs)) if runs else wall), out
+    def timed(fn, *args, carried=False):
+        """(ms a call, the last result): _device_ms's first two."""
+        return _device_ms(fn, args, calls, rehearse, "rows_trace",
+                          carried)[:2]
 
     for cell, n, d, cap, held, share in cells:
         x = jnp.asarray(rng.randn(n, d), jnp.bfloat16)
@@ -945,7 +1086,8 @@ def main():
                          "dp2 x tp2 mesh and its one-device baseline")
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
-                         "(flash, gqa, mla, rotary, experts, rows, "
+                         "(flash, gqa, own_block, mla, rotary, experts, "
+                         "rows, "
                          "train, serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: tiny size, Pallas kernels in "
@@ -967,7 +1109,8 @@ def main():
     if args.chips == 4:
         phase_multichip(cfg, args.seed, args.rehearse)
     else:
-        phases = {"flash": phase_flash, "gqa": phase_gqa, "mla": phase_mla,
+        phases = {"flash": phase_flash, "gqa": phase_gqa,
+                  "own_block": phase_own_block, "mla": phase_mla,
                   "rotary": phase_rotary, "experts": phase_experts,
                   "rows": phase_rows,
                   "train": functools.partial(phase_train, cfg),

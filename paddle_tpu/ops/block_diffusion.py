@@ -13,10 +13,13 @@ mask id with probability t. The layers run on 2L rows a sequence,
   key.
 
 Of the (2L)^2 scores L^2 + L * block are useful. ``attention`` computes
-them as two calls of the flash kernels under their block-granular mask
-(clean on clean; noised on the clean keys of EARLIER blocks) and the
-noised rows' own blocks as small dense math, merged by log-sum-exp: no
-[T, T] tensor reaches HBM and no wholly masked tile is computed.
+them in ONE call of each flash kernel under the kernels' own-block mask
+form (``flash_bthd(..., own_block=True)``, ISSUE 37): both halves walk
+the clean keys as block-causal attention does, and a noised row's tile
+on the diagonal takes the noised keys of its own block as one segment
+more of the same streaming-softmax step. No [T, T] tensor reaches HBM,
+no wholly masked tile is computed, and nothing of attention runs outside
+the kernels but the sums of dk and dv over a group of query heads.
 
 The noise is a pure function of explicit integers (a salt, the step,
 the batch row; ``draw_noise``), not of the executor's per-op key, so
@@ -27,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
-from .flash_attention import flash_bthd, flash_bthd_lse
+from .flash_attention import flash_bthd
 
 T_MIN = 1e-3
 
@@ -72,47 +75,11 @@ def _block_diffusion_noise(ctx, op):
 def attention(q, k, v, n_head, n_kv_head, block, scale=None, force=None):
     """q [B, 2L, H*D], k and v [B, 2L, Hkv*D], rows [noised; clean] ->
     [B, 2L, H*D] under the block-diffusion mask (the module's
-    docstring)."""
-    b, t2, hd = q.shape
-    seq, d, group = t2 // 2, hd // n_head, n_head // n_kv_head
-    scale = scale or d ** -0.5
-    kw = dict(causal=True, scale=scale, force=force, n_kv_head=n_kv_head,
-              mask_block=block)
-    q_n, q_c = q[:, :seq], q[:, seq:]
-    k_n, k_c = k[:, :seq], k[:, seq:]
-    v_n, v_c = v[:, :seq], v[:, seq:]
-    clean = flash_bthd(q_c, k_c, v_c, n_head, **kw)
-    # the first block's noised rows see no clean key: the kernel gives
-    # them a finite output and lse -1e30, which the merge weighs at 0
-    before, lse_before = flash_bthd_lse(q_n, k_c, v_c, n_head, strict=True,
-                                        **kw)
-    # each noised row's own block of noised keys: [block, block] scores
-    # a block and head, as small dense math. With the slices and the
-    # merge 43 ms of a 416 ms step on a v5e (the operands are relaid
-    # for the blocks' view); as broadcast products in the kernels'
-    # [B, L, H*D] layout XLA materialised every broadcast, 65 ms more
-    # (my chip runs, PR 32): the place for it is the kernel's own
-    # diagonal tiles (PERF.md section 7)
-    blocks = seq // block
-    qb = q_n.reshape(b, blocks, block, n_kv_head, group, d)
-    kb = k_n.reshape(b, blocks, block, n_kv_head, d)
-    vb = v_n.reshape(b, blocks, block, n_kv_head, d)
-    s = jnp.einsum("bnqhgd,bnkhd->bnqhgk", qb, kb,
-                   preferred_element_type=jnp.float32) * scale
-    lse_own = jax.nn.logsumexp(s, axis=-1)                 # [b,n,q,h,g]
-    own = jnp.einsum("bnqhgk,bnkhd->bnqhgd",
-                     jnp.exp(s - lse_own[..., None]).astype(v.dtype), vb,
-                     preferred_element_type=jnp.float32)
-    lse_own = lse_own.reshape(b, seq, n_head)
-    lse_before = lse_before.transpose(0, 2, 1)             # [b, L, H]
-    top = jnp.maximum(lse_own, lse_before)
-    w_own = jnp.exp(lse_own - top)[..., None]
-    w_before = jnp.exp(lse_before - top)[..., None]
-    noised = (w_own * own.reshape(b, seq, n_head, d) + w_before
-              * before.reshape(b, seq, n_head, d).astype(jnp.float32)
-              ) / (w_own + w_before)
-    return jnp.concatenate(
-        [noised.reshape(b, seq, hd).astype(q.dtype), clean], axis=1)
+    docstring): the flash kernels' own-block form, on the arrays as the
+    projections wrote them."""
+    return flash_bthd(q, k, v, n_head, causal=True, scale=scale,
+                      force=force, n_kv_head=n_kv_head, mask_block=block,
+                      own_block=True)
 
 
 @register("block_diffusion_attention")

@@ -89,6 +89,17 @@ reads (latent attention's rotary part): key D + D2 wide, value D. The
 same three streamed kernels under the same names take it on what they
 are handed (`part2`; see "A score of two parts" below); handed no
 second part they trace exactly what they traced before.
+
+The own-block mask form (PR 37): `flash_bthd(..., causal=True,
+mask_block=m, own_block=True)` is block diffusion's whole attention.
+The rows are two halves at the same positions, [noised; clean]; the
+grid's q axis runs through both, the keys walked are the clean half's
+(block offsets in the index maps: nothing is sliced out of q, k or v
+and the result is written at the halves' places), and ON the diagonal
+a noised panel takes one segment more, the noised keys of its own
+rows, kept where `kj >> shift == qi >> shift`, inside the same
+streaming-softmax step (_walk, _causal). The mask is a static value;
+under the other forms every kernel traces what it traced before.
 """
 
 import functools
@@ -155,7 +166,8 @@ def _dense_lse(q, k, v, causal, scale, mask=(0, 0), q2=None, k2=None):
     """Dense math returning (out, lse) — lse[b,h,i] = logsumexp_j s_ij.
     The math-identical fallback for flash_attention_lse. k and v may
     hold fewer heads than q (query head h reads head h // group);
-    `mask` = (shift, strict) is _causal's, read where `causal`. q2
+    `mask` = (shift, form) is _causal's, read where `causal`; under the
+    form _OWN the T rows are [noised; clean] halves. q2
     [B, H, T, D2] and k2 [B, T, D2], where given, add a second part to
     every score: q2 against the ONE key k2 that all heads read."""
     group = q.shape[1] // k.shape[1]
@@ -170,8 +182,20 @@ def _dense_lse(q, k, v, causal, scale, mask=(0, 0), q2=None, k2=None):
     if causal:
         t = s.shape[-1]
         shift, strict = mask
-        at = jnp.arange(t) >> shift
-        s = jnp.where(at[None, :] + strict <= at[:, None], s, _NEG_INF)
+        if strict == _OWN:
+            # a clean key counts for its own half from its own block on
+            # and for the noised half from the block after; a noised key
+            # for the noised rows of its block alone
+            at = jnp.tile(jnp.arange(t // 2) >> shift, 2)
+            noised = jnp.arange(t) < t // 2
+            seen = jnp.where(
+                noised[None, :],
+                noised[:, None] & (at[None, :] == at[:, None]),
+                at[None, :] + noised[:, None] <= at[:, None])
+        else:
+            at = jnp.arange(t) >> shift
+            seen = at[None, :] + strict <= at[:, None]
+        s = jnp.where(seen, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -208,23 +232,37 @@ def _tile(block, target):
     return block
 
 
+_OWN = 2       # the third mask form: see _causal
+
+
 def _causal(s, off, q_axis, mask=(0, 0)):
     """Mask one score tile. `off` = its first query row less its first
     key; queries run along `q_axis` of the tile, keys along the other.
-    `mask` = (shift, strict): rows and keys are counted in blocks of
+    `mask` = (shift, form): rows and keys are counted in blocks of
     2^shift, a query sees the keys of its own block and of those before
-    it, and `strict` 1 takes its own block away (a first block's rows
-    then see nothing: their scores are all _NEG_INF, which is finite, so
-    out and lse come out finite, and lse = -1e30 weighs nothing in a
-    merge). (0, 0) is plain causal. Tiles start on a multiple of the
-    block, so `off >> shift` is their distance in blocks."""
+    it, and form 1 (`strict`) takes its own block away (a first block's
+    rows then see nothing: their scores are all _NEG_INF, which is
+    finite, so out and lse come out finite, and lse = -1e30 weighs
+    nothing in a merge). (0, 0) is plain causal. Tiles start on a
+    multiple of the block, so `off >> shift` is their distance in
+    blocks. Form _OWN keeps the keys of the query's own block and no
+    others: the tile a noised row of block diffusion makes with the
+    noised keys of its own rows (`off` 0), beside the strict tile it
+    makes with the clean ones (_walk)."""
     shift, strict = mask
     qi = lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     kj = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    if strict == _OWN:
+        return jnp.where((kj >> shift) == (qi >> shift), s, _NEG_INF)
     if not shift and not strict:
         return jnp.where(kj - qi <= off, s, _NEG_INF)
     return jnp.where((kj >> shift) - (qi >> shift) + strict
                      <= (off >> shift), s, _NEG_INF)
+
+
+def _own(mask):
+    """Whether a mask (a kernel's, or a segment's) is the own-block form."""
+    return mask is not None and mask[1] == _OWN
 
 
 def _when(cond):
@@ -256,50 +294,80 @@ def _unfold(at, outer, inner):
     return at // inner, at % inner
 
 
-def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False):
+def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False, half=0):
     """Run `panel(mine, segments)` over the major block (i, j).
 
     `mine` is a static slice of the block's query rows (of its keys if
     `by_keys`: dk/dv accumulates by key) and `segments` a list of
-    (static slice of the other side, off): off None = every score of
-    the segment counts, else the segment is masked with `off`, its
-    first query row less its first key. A block below the diagonal is
-    one unmasked segment, in panels of at most _PANEL_SCORES scores
-    (a panel's scores are live in VMEM, several float32 copies of
-    them); a block above it is skipped.
+    (static slice of the other side, off, how): off None = every score
+    of the segment counts, else the segment is masked by
+    `_causal(s, off, ., how)`, off its first query row less its first
+    key. A block below the diagonal is one unmasked segment, in panels
+    of at most _PANEL_SCORES scores (a panel's scores are live in VMEM,
+    several float32 copies of them); a block above it is skipped.
     Equal blocks cross the diagonal only ON it, where the cut is known
     at trace time: panel r holds rows [r t, (r+1) t) with the keys
     before them unmasked and their own keys masked (the transpose of
     that by keys). Unequal blocks cross it anywhere: one masked panel.
-    `mask` is None (every score counts) or _causal's (shift, strict): a
+    `mask` is None (every score counts) or _causal's (shift, form): a
     mask in blocks of 2^shift no larger than a tile moves no tile from
     one side of the diagonal to the other, so the walk is causal's.
+
+    Under the form _OWN (block diffusion) the q blocks are two halves of
+    `half` blocks each, [noised; clean], `i` counts through both, and
+    the keys walked are the CLEAN half's (equal blocks). Both halves
+    walk as causal does; ON the diagonal the clean rows' own tile is
+    masked block-causal and the noised rows' strict, and a noised
+    panel takes one segment more: the NOISED keys of its own rows, kept
+    where they are of the row's block (how = (shift, _OWN); the kernel
+    reads them from its second pair of key/value blocks). By keys that
+    segment is a panel of its own, since it ends in the noised keys' dk
+    and dv and not in the clean ones'.
     """
     nq, nk = (block_k, block_q) if by_keys else (block_q, block_k)
 
     def whole():
         step = _tile(nq, max(_PANEL_SCORES // nk, _LANES))
         for r in range(nq // step):
-            panel(slice(r * step, (r + 1) * step), [(slice(0, nk), None)])
+            panel(slice(r * step, (r + 1) * step),
+                  [(slice(0, nk), None, None)])
+
+    def crossed(off, how, own=None):
+        if block_q != block_k or tile >= block_q:
+            cuts = [(slice(0, nq), slice(0, nk), slice(0, 0), off)]
+        else:
+            cuts = []
+            for r in range(block_q // tile):
+                mine = slice(r * tile, (r + 1) * tile)
+                cuts.append((mine, mine, slice((r + 1) * tile, block_q)
+                             if by_keys else slice(0, r * tile), 0))
+        for mine, other, rest, at in cuts:
+            segments = [(other, at, how)] + (
+                [(rest, None, None)] if rest.stop > rest.start else [])
+            if own and by_keys:
+                panel(mine, [(mine, 0, own)])
+            elif own:
+                segments.append((mine, 0, own))
+            panel(mine, segments)
 
     if mask is None:
         return whole()
+    if _own(mask):
+        noised = i < half
+        i = jnp.where(noised, i, i - half)
+        pl.when(i > j)(whole)
+        pl.when((i == j) & noised)(
+            lambda: crossed(0, (mask[0], 1), own=mask))
+        pl.when((i == j) & jnp.logical_not(noised))(
+            lambda: crossed(0, (mask[0], 0)))
+        return
     first_q, first_k = i * block_q, j * block_k
     # the last key every row of the block sees unmasked, plus one where
     # a row does not see its own key
     last_k = first_k + block_k - 1 + mask[1]
     _when(first_q >= last_k)(whole)
-
-    @_when((first_q < last_k) & (first_q + block_q - 1 >= first_k))
-    def _crossed():
-        if block_q != block_k or tile >= block_q:
-            return panel(slice(0, nq), [(slice(0, nk), first_q - first_k)])
-        for r in range(block_q // tile):
-            mine = slice(r * tile, (r + 1) * tile)
-            rest = (slice((r + 1) * tile, block_q) if by_keys
-                    else slice(0, r * tile))
-            panel(mine, [(mine, 0)] + ([(rest, None)]
-                                       if rest.stop > rest.start else []))
+    _when((first_q < last_k) & (first_q + block_q - 1 >= first_k))(
+        lambda: crossed(first_q - first_k, mask))
 
 
 # --------------------------------------------------------------------------
@@ -360,6 +428,11 @@ def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
     if part2:
         q_ref, k_ref, v_ref, q2_ref, k2_ref, o_ref, lse_ref, *scratch = refs
         a2 = pl.program_id(0) % part2[1]    # its place in q2's block
+    elif _own(mask):
+        # nq counts both halves' q blocks; kn / vn are the noised keys
+        # and values of the q block's own rows
+        (q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref, lse_ref,
+         *scratch) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch = refs
     i, j = _block_ids(nq, nk)
@@ -389,11 +462,12 @@ def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
                 q2 = q2_ref[0, rows, :] * scale
                 q2 = _only(q2, _lanes(q2.shape, a2, *part2[:2]))
             scores = []
-            for cols, off in segments:
-                s = _dot(q, k_ref[0, cols, :], _NT)     # [tq, tk]
+            for cols, off, how in segments:
+                k = (kn_ref if _own(how) else k_ref)[0, cols, :]
+                s = _dot(q, k, _NT)                     # [tq, tk]
                 if part2:
                     s = s + _dot(q2, k2_ref[0, cols, :], _NT)
-                scores.append(s if off is None else _causal(s, off, 0, mask))
+                scores.append(s if off is None else _causal(s, off, 0, how))
             maxes = [jnp.max(s, axis=1, keepdims=True) for s in scores]
             if nk == 1:
                 # the only key block: nothing carried in, nothing to
@@ -406,9 +480,9 @@ def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
                 alpha = jnp.exp(m_prev - m_new)
                 l = alpha * l_s[a, rows]
                 acc = alpha * acc_s[rows]
-            for s, (cols, _) in zip(scores, segments):
+            for s, (cols, _, how) in zip(scores, segments):
                 p = jnp.exp(s - m_new)
-                v = v_ref[0, cols, :]
+                v = (vn_ref if _own(how) else v_ref)[0, cols, :]
                 l = l + jnp.sum(p, axis=1, keepdims=True)
                 acc = acc + _dot(p.astype(v.dtype), v, _NN)  # [tq, W]
             if nk == 1:
@@ -417,7 +491,7 @@ def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
             l_s[a, rows] = l
             _put(acc_s, rows, acc, mine)
 
-        _walk(panel, i, j, mask, block_q, block_k, tile)
+        _walk(panel, i, j, mask, block_q, block_k, tile, half=nq // 2)
 
         if nk > 1:
             @pl.when(j == nk - 1)
@@ -432,7 +506,9 @@ def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
 def _specs(n_head, g, d, group=1):
     """Block specs of a grid (B * H / g, ., .): `rows(block, axis)` for a
     [B, T, H*D] operand, `block` rows a grid step following grid axis
-    `axis` (dk/dv's grid puts the keys first) and the g heads of the
+    `axis` (dk/dv's grid puts the keys first; a function of the step
+    where the block is not the axis' own: the own-block form's halves)
+    and the g heads of the
     step on the last dimension; `kv(block, axis)` the same for k and v
     [B, T, (H / group) * D], which `group` query heads read (one head
     to a block: the step's head over `group`); `stat(block, axis)` for
@@ -440,22 +516,21 @@ def _specs(n_head, g, d, group=1):
     rows along the lanes."""
     hb = n_head // g
 
+    def at(axis, s):
+        return 0 if axis is None else axis(s) if callable(axis) else s[axis]
+
     def rows(block, axis=None):
         return pl.BlockSpec(
             (1, block, g * d),
-            lambda *s: (s[0] // hb, 0 if axis is None else s[axis],
-                        s[0] % hb))
+            lambda *s: (s[0] // hb, at(axis, s), s[0] % hb))
 
     def kv(block, axis=None):
         return pl.BlockSpec(
             (1, block, g * d),
-            lambda *s: (s[0] // hb, 0 if axis is None else s[axis],
-                        s[0] % hb // group))
+            lambda *s: (s[0] // hb, at(axis, s), s[0] % hb // group))
 
     def stat(block, axis=None):
-        return pl.BlockSpec(
-            (g, 1, block),
-            lambda *s: (s[0], 0, 0 if axis is None else s[axis]))
+        return pl.BlockSpec((g, 1, block), lambda *s: (s[0], 0, at(axis, s)))
 
     return rows, (rows if group == 1 else kv), stat
 
@@ -470,16 +545,30 @@ def _fwd_pallas(q, k, v, n_head, n_kv_head, mask, scale, block_q, block_k,
     b, t, hd = q.shape
     d = hd // n_head
     g = heads_per_block(n_head, d)
-    bq = min(block_q, t)
-    bk = min(block_k, t)
-    nq, nk = t // bq, t // bk
     rows, kv, stat = _specs(n_head, g, d, n_head // n_kv_head)
+    if _own(mask):
+        # q blocks through both halves; the keys walked are the clean
+        # half's, `half` blocks on; the noised keys of a noised q
+        # block's own rows ride along the q axis (the clean q blocks
+        # keep the last of them: nothing is fetched for them)
+        bq = bk = min(block_q, t // 2)
+        half = t // 2 // bq
+        nq, nk = 2 * half, half
+        keys = [kv(bk, lambda s: s[2] + half)] * 2 + [
+            kv(bq, lambda s: jnp.minimum(s[1], half - 1))] * 2
+        operands = (q, k, v, k, v)
+    else:
+        bq = min(block_q, t)
+        bk = min(block_k, t)
+        nq, nk = t // bq, t // bk
+        keys = [kv(bk, 2), kv(bk, 2)]
+        operands = (q, k, v)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, mask=mask, scale=scale,
                           block_q=bq, block_k=bk,
                           tile=_tile(bq, _TILE), nq=nq, nk=nk, d=d, g=g),
         grid=(b * n_head // g, nq, nk),
-        in_specs=[rows(bq, 1), kv(bk, 2), kv(bk, 2)],
+        in_specs=[rows(bq, 1)] + keys,
         out_specs=[rows(bq, 1), stat(bq, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, hd), q.dtype),
@@ -495,7 +584,7 @@ def _fwd_pallas(q, k, v, n_head, n_kv_head, mask, scale, block_q, block_k,
         ] if nk > 1 else [],
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v)
+    )(*operands)
     return out, lse.reshape(b, n_head, t)
 
 
@@ -552,12 +641,12 @@ def _bwd_fused_kernel(*refs, mask, scale, t, tile, d, g, has_dlse):
             mine = _lanes(k.shape, a, d, g)
             kk, v = _only(k * scale, mine), _only(v_ref[0, cols, :], mine)
             dk = dv = 0.0
-            for rows, off in segments:
+            for rows, off, how in segments:
                 q = q_ref[0, rows, :]
                 dy = dy_ref[0, rows, :]
                 st = _dot(kk, q, _NT)                # [tk, tq]
                 if off is not None:
-                    st = _causal(st, off, 1, mask)
+                    st = _causal(st, off, 1, how)
                 pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
                 dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
                 dst = pt * (_dot(v, dy, _NT) - delta_s[:, rows])
@@ -588,6 +677,9 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
         (q2_ref, k2_ref, dq_ref, delta_ref, dq2_ref, acc_s, lse_s, delta_s,
          acc2_s) = refs[6 + has_dlse:]
         a2, j = _unfold(pl.program_id(2), part2[1], nk)
+    elif _own(mask):
+        (kn_ref, vn_ref, dq_ref, delta_ref, acc_s, lse_s,
+         delta_s) = refs[6 + has_dlse:]
     else:
         dq_ref, delta_ref, acc_s, lse_s, delta_s = refs[6 + has_dlse:]
 
@@ -615,16 +707,17 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
                 q2 = _only(q2, _lanes(q2.shape, a2, *part2[:2]))
             lse, delta = lse_s[a, rows], delta_s[a, rows]
             acc = acc2 = 0.0
-            for cols, off in segments:
-                kk = k_ref[0, cols, :]
+            for cols, off, how in segments:
+                kk = (kn_ref if _own(how) else k_ref)[0, cols, :]
                 s = _dot(q, kk, _NT)                 # [tq, tk]
                 if part2:
                     kk2 = k2_ref[0, cols, :]
                     s = s + _dot(q2, kk2, _NT)
                 if off is not None:
-                    s = _causal(s, off, 0, mask)
+                    s = _causal(s, off, 0, how)
                 p = jnp.exp(s - lse)
-                dp = _dot(dy, v_ref[0, cols, :], _NT)
+                dp = _dot(dy, (vn_ref if _own(how) else v_ref)[0, cols, :],
+                          _NT)
                 ds = (p * (dp - delta)).astype(kk.dtype)
                 acc = acc + _dot(ds, kk, _NN)        # [tq, W]
                 if part2:
@@ -633,7 +726,7 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
             if part2:
                 acc2_s[rows] = acc2_s[rows] + acc2
 
-        _walk(panel, i, j, mask, block_q, block_k, tile)
+        _walk(panel, i, j, mask, block_q, block_k, tile, half=nq // 2)
 
     _each_head(g, head)
 
@@ -665,6 +758,10 @@ def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
         @pl.when(at == 0)
         def _init2():
             dk2_s[:] = jnp.zeros_like(dk2_s)
+    elif _own(mask):
+        # the noised keys and values of the key block's rows, and their
+        # gradients: whole at the one step whose q block is those rows
+        kn_ref, vn_ref, dk_ref, dv_ref, dkn_ref, dvn_ref, dk_s, dv_s = refs[6:]
     else:
         dk_ref, dv_ref, dk_s, dv_s = refs[6:]
 
@@ -680,13 +777,15 @@ def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
             # dk = ds^T q want them, with no contraction over a tile's
             # first dim; the row statistics broadcast down the sublanes
             # as they arrive
-            kk = k_ref[0, cols, :] * scale           # [tk, W], once a panel
+            own = _own(segments[0][2])      # the noised keys' own panel
+            kk = (kn_ref if own else k_ref)[0, cols, :] * scale  # [tk, W]
             mine = _lanes(kk.shape, a, d, g)
-            kk, v = _only(kk, mine), _only(v_ref[0, cols, :], mine)
+            kk = _only(kk, mine)
+            v = _only((vn_ref if own else v_ref)[0, cols, :], mine)
             if part2:
                 kk2 = k2_ref[0, cols, :] * scale
             dk = dv = dk2 = 0.0
-            for rows, off in segments:
+            for rows, off, how in segments:
                 q = q_ref[0, rows, :]
                 dy = dy_ref[0, rows, :]
                 st = _dot(kk, q, _NT)                # [tk, tq]
@@ -697,7 +796,7 @@ def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
                     q2 = _only(q2, _lanes(q2.shape, a2, *part2[:2]))
                     st = st + _dot(kk2, q2, _NT)
                 if off is not None:
-                    st = _causal(st, off, 1, mask)
+                    st = _causal(st, off, 1, how)
                 pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
                 dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
                 dpt = _dot(v, dy, _NT)
@@ -705,12 +804,18 @@ def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
                 dk = dk + _dot(dst, q, _NN)
                 if part2:
                     dk2 = dk2 + _dot(dst, q2, _NN)
+            if own:
+                whole = (0, cols, slice(None))
+                _put(dkn_ref, whole, (dk * scale).astype(dkn_ref.dtype),
+                     mine)
+                return _put(dvn_ref, whole, dv.astype(dvn_ref.dtype), mine)
             dk_s[cols] = dk_s[cols] + _only(dk, mine)
             dv_s[cols] = dv_s[cols] + _only(dv, mine)
             if part2:
                 dk2_s[cols] = dk2_s[cols] + dk2
 
-        _walk(panel, i, jj, mask, block_q, block_k, tile, by_keys=True)
+        _walk(panel, i, jj, mask, block_q, block_k, tile, by_keys=True,
+              half=nq // 2)
 
     _each_head(g, head)
 
@@ -739,20 +844,27 @@ def _backward_blocks(t, w, block_q, block_k):
     return bq, bk
 
 
-def _backward_of(t, w, block_q, block_k):
+def _backward_of(t, w, block_q, block_k, mask=None):
     """"fused" where the backward's blocks hold all of T, else
-    "two_kernels": what _bwd_pallas runs and the lowering counter says."""
-    return ("fused" if _backward_blocks(t, w, block_q, block_k) == (t, t)
+    "two_kernels": what _bwd_pallas runs and the lowering counter says.
+    The own-block form's rows are two halves, so never one block."""
+    return ("fused" if not _own(mask)
+            and _backward_blocks(t, w, block_q, block_k) == (t, t)
             else "two_kernels")
 
 
 def _group_sum(dkv, group, d, dtype):
     """dk or dv as the kernels leave it under grouped key/value heads,
     [B, T, H*D] in float32 with one head's worth a QUERY head, summed
-    over each group of `group` query heads: [B, T, (H / group) * D]."""
-    b, t, hd = dkv.shape
-    return dkv.reshape(b, t, hd // (group * d), group, d).sum(3).reshape(
-        b, t, hd // group).astype(dtype)
+    over each group of `group` query heads: [B, T, (H / group) * D].
+    As sums of the heads' lane slices, which XLA fuses into one pass
+    over dkv; as a reduce over a [B, T, H / group, group, D] view it
+    first copied the whole array into another tiling (0.41 ms of a
+    134 MB array beside the 0.18 ms reduce, my chip run, PR 37)."""
+    heads = [dkv[..., a * d:(a + 1) * d] for a in range(dkv.shape[-1] // d)]
+    return jnp.concatenate(
+        [sum(heads[a + 1:a + group], heads[a])
+         for a in range(0, len(heads), group)], -1).astype(dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -764,8 +876,12 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
     g = heads_per_block(n_head, d)
     group = n_head // n_kv_head
     w = g * d
-    bq, bk = _backward_blocks(t, w, block_q, block_k)
-    nq, nk = t // bq, t // bk
+    own = _own(mask)
+    # the own-block form: q blocks through both halves [noised; clean],
+    # `nk` key blocks of the clean half, equal blocks (_fwd_pallas)
+    rows_k = t // 2 if own else t
+    bq, bk = _backward_blocks(rows_k, w, block_q, block_k)
+    nq, nk = t // bq, rows_k // bk
     tile = _tile(bq, _TILE)
     stats = [lse.reshape(b * n_head, 1, t)]
     if dlse is not None:
@@ -775,10 +891,21 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
     # grouped key/value heads: a grid step still makes ONE query head's
     # dk and dv, in float32, and XLA sums each group's after the kernel
     # (_group_sum)
-    dkv = bthd if group == 1 else jax.ShapeDtypeStruct((b, t, hd),
-                                                       jnp.float32)
+    dkv = jax.ShapeDtypeStruct((b, rows_k, hd),
+                               q.dtype if group == 1 else jnp.float32)
     summed = (lambda x: x) if group == 1 else functools.partial(
         _group_sum, group=group, d=d, dtype=k.dtype)
+    if own:
+        # the keys a step walks are the clean half's, `nk` blocks on,
+        # and the noised keys and values of a q block's own rows (of
+        # the key block's, by keys) come as two operands more
+        clean = lambda axis: lambda s: s[axis] + nk
+        noised_q = [kv(bq, lambda s: jnp.minimum(s[1], nk - 1))] * 2
+        noised_k = [kv(bk, 1)] * 2
+        kn = (k, v)
+    else:
+        clean = lambda axis: axis
+        noised_q, noised_k, kn = [], [], ()
 
     if nq == nk == 1:       # _backward_of's "fused"
         dq, dk, dv = pl.pallas_call(
@@ -802,8 +929,9 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
                           block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
                           d=d, g=g, has_dlse=dlse is not None),
         grid=(b * n_head // g, nq, nk),
-        in_specs=[rows(bq, 1), kv(bk, 2), kv(bk, 2), rows(bq, 1),
-                  rows(bq, 1)] + [stat(bq, 1)] * len(stats),
+        in_specs=[rows(bq, 1), kv(bk, clean(2)), kv(bk, clean(2)),
+                  rows(bq, 1), rows(bq, 1)] + [stat(bq, 1)] * len(stats)
+        + noised_q,
         out_specs=[rows(bq, 1), stat(bq, 1)],
         out_shape=[bthd, jax.ShapeDtypeStruct((b * n_head, 1, t),
                                               jnp.float32)],
@@ -812,23 +940,28 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
                         pltpu.VMEM((g, bq, 1), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, dy, o, *stats)
+    )(q, k, v, dy, o, *stats, *kn)
 
     # grid (B * H / g, nK, nQ): the q-side operands follow the LAST axis
-    dk, dv = pl.pallas_call(
+    dk, dv, *dkn = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
                           block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
                           d=d, g=g),
         grid=(b * n_head // g, nk, nq),
-        in_specs=[rows(bq, 2), kv(bk, 1), kv(bk, 1), rows(bq, 2),
-                  stat(bq, 2), stat(bq, 2)],
-        out_specs=[rows(bk, 1), rows(bk, 1)],
-        out_shape=[dkv, dkv],
+        in_specs=[rows(bq, 2), kv(bk, clean(1)), kv(bk, clean(1)),
+                  rows(bq, 2), stat(bq, 2), stat(bq, 2)] + noised_k,
+        out_specs=[rows(bk, 1)] * (2 + len(kn)),
+        out_shape=[dkv] * (2 + len(kn)),
         scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
                         pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, dy, stats[0], delta3)
+    )(q, k, v, dy, stats[0], delta3, *kn)
+    if own:
+        # [noised; clean], as k and v came
+        dk, dv = (jnp.concatenate([summed(x), summed(y)], 1)
+                  for x, y in zip(dkn, (dk, dv)))
+        return dq, dk, dv
     return dq, summed(dk), summed(dv)
 
 
@@ -1071,7 +1204,8 @@ _LOWERINGS = _REG.counter(
     "flash attention dispatches at trace time (one a lowering of the op, "
     "none a step): the path taken, the layout of the entry called, the "
     "heads a kernel block holds, the backward its gradient would run, "
-    "the mask (none, causal, block_causal, block_causal_strict), the "
+    "the mask (none, causal, block_causal, block_causal_strict, "
+    "block_causal_own: block diffusion's [noised; clean] halves), the "
     "query heads that read one key/value head, a head's key and value "
     "widths and its score's second part (none, or shared: one key that "
     "every head reads)",
@@ -1128,23 +1262,30 @@ def heads_last(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
-def _mask_of(causal, mask_block, strict):
+def _mask_of(causal, mask_block, strict, own_block=False):
     """(the kernels' mask, its label): None / "none" where not causal,
-    else _causal's (shift, strict) for blocks of `mask_block` rows, a
-    power of two."""
+    else _causal's (shift, form) for blocks of `mask_block` rows, a
+    power of two: form 0, 1 (`strict`) or _OWN (`own_block`)."""
     if not causal:
+        if own_block:
+            raise ValueError("flash attention: own_block is a causal mask")
         return None, "none"
     shift = int(mask_block).bit_length() - 1
     if mask_block < 1 or 1 << shift != mask_block:
         raise ValueError("flash attention: the mask's block is a power "
                          "of two, got %r" % (mask_block,))
+    if own_block:
+        if strict:
+            raise ValueError("flash attention: own_block has its own "
+                             "strict half, `strict` is the other forms'")
+        return (shift, _OWN), "block_causal_own"
     return (shift, int(bool(strict))), "%scausal%s" % (
         "block_" if shift else "", "_strict" if strict else "")
 
 
 def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
             entry, with_lse, n_kv_head=None, mask_block=1, strict=False,
-            q2=None, k2=None):
+            q2=None, k2=None, own_block=False):
     """Dispatch of every entry: q/k/v [B, T, H*D] -> out, or (out, lse
     [B, H, T]) `with_lse`. `entry` labels the count: the layout the
     caller came in. k and v may hold `n_kv_head` < H heads, [B, T,
@@ -1153,7 +1294,8 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     [B, T, H*D2] and k2 [B, T, D2] add q2_h k2^T to head h's scores
     (the kernels: one head to a block, no groups, D2 a multiple of 128
     or dividing it in as many heads as divide H); `scale` then defaults
-    to (D + D2)^-0.5."""
+    to (D + D2)^-0.5. `own_block`: flash_bthd's; the kernels' blocks are
+    then cut from a HALF of the rows."""
     b, t, hd = q.shape
     d = hd // n_head
     n_kv_head = n_kv_head or n_head
@@ -1170,17 +1312,25 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
         raise ValueError(
             "flash attention: %d query heads of %d cannot read k of "
             "shape %s as %d heads" % (n_head, d, k.shape, n_kv_head))
-    mask, mask_label = _mask_of(causal, mask_block, strict)
+    mask, mask_label = _mask_of(causal, mask_block, strict, own_block)
+    if own_block and t % 2:
+        raise ValueError("flash attention: own_block wants two halves of "
+                         "rows, got %d" % t)
+    rows = t // 2 if own_block else t       # what the blocks are cut from
     path, scale, bq, bk = _resolve_path(
-        jax.ShapeDtypeStruct((b, n_head, t, d), q.dtype), scale, block_q,
+        jax.ShapeDtypeStruct((b, n_head, rows, d), q.dtype), scale, block_q,
         block_k, force)
     g = heads_per_block(n_head, d)
     # what the kernels cannot take goes the dense way whoever asked: a
     # group of query heads shares a block of k only where a block is one
     # head, and a mask's block must divide every tile's edge
-    edge = _tile(_backward_blocks(t, g * d, bq, bk)[0], _TILE)
+    edge = _tile(_backward_blocks(rows, g * d, bq, bk)[0], _TILE)
     if (n_kv_head != n_head and g > 1) or (
             mask and any(x % (1 << mask[0]) for x in (bq, bk, edge))):
+        path = "dense"
+    # the own-block form's halves are walked in equal blocks, and it has
+    # neither an lse to give nor a second part
+    if own_block and (bq != bk or rows % bq or with_lse or q2 is not None):
         path = "dense"
     if d2:
         g2 = _part2_of(n_head, q2)[1]
@@ -1190,7 +1340,7 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     if v.shape[-1] != k.shape[-1]:      # the kernels' value is D wide
         path = "dense"
     backward = ("none" if path == "dense" else "two_kernels" if d2
-                else _backward_of(t, g * d, bq, bk))
+                else _backward_of(rows, g * d, bq, bk, mask))
     _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
                    backward=backward, mask=mask_label,
                    kv_groups=str(n_head // n_kv_head),
@@ -1217,7 +1367,7 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
 def flash_bthd(q, k, v, n_head, causal=False, scale=None,
                block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                force=None, n_kv_head=None, mask_block=1, strict=False,
-               q2=None, k2=None):
+               q2=None, k2=None, own_block=False):
     """Fused multi-head attention in the projections' own layout.
     q and the result: [B, T, H*D], head h in lanes [h D, (h+1) D); k and
     v the same, or [B, T, Hkv*D] with `n_kv_head` = Hkv heads, each read
@@ -1235,12 +1385,22 @@ def flash_bthd(q, k, v, n_head, causal=False, scale=None,
     -1e30, which weighs nothing where partial results are merged by
     lse). m 1 and no `strict` is plain causal.
 
+    `own_block` (with `causal`; block diffusion): the T rows are two
+    halves at the same positions, [noised; clean]. A clean query sees
+    the clean keys of its own block and of the blocks before it and no
+    noised key; a noised query the clean keys of the blocks BEFORE its
+    own and the noised keys of its OWN block (so every row sees a key).
+    One call of each kernel walks both halves: q, k, v, the result and
+    the gradients are the [B, 2L, .] arrays as they are, no half is
+    sliced out or put back.
+
     force: None = auto (Pallas kernel on TPU when T divides the blocks,
     dense XLA math otherwise), "pallas" / "interpret" / "dense" pin a path
     (tests use "interpret" to run the kernel on CPU).
     """
     return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-                   "bthd", False, n_kv_head, mask_block, strict, q2, k2)
+                   "bthd", False, n_kv_head, mask_block, strict, q2, k2,
+                   own_block)
 
 
 def flash_bthd_lse(q, k, v, n_head, causal=False, scale=None,

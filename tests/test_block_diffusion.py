@@ -1,8 +1,9 @@
 """The pre-norm block's ops, the dropless expert layer and the
 block-diffusion objective (ISSUE 32), at small sizes with seeded
 weights on the CPU: each op against ``jax.numpy``, the expert layer
-against a dense loop over experts, the two-piece attention against the
-2L x 2L mask written out, and the whole small model against the
+against a dense loop over experts, the attention of [noised; clean]
+rows against the 2L x 2L mask written out, and the whole small model
+against the
 benchmark's float32 reference (``chipbench/reference/sdar_lm.py``).
 The ops: "rms_norm", "rope", "silu_mul", "block_diffusion_noise",
 "block_diffusion_attention", "routed_experts", and (ISSUE 33)
@@ -248,10 +249,11 @@ def _dense_bd_attention(q, k, v, n_head, n_kv_head, block):
 @pytest.mark.parametrize("force", ["dense", "interpret"])
 @pytest.mark.parametrize("block", [4, 32])
 def test_two_piece_attention_is_the_dense_mask(block, force):
-    """Two flash pieces merged by lse with the noised rows' own blocks
-    against the mask written out, forward and gradients; the first
-    block's noised rows, which see no clean key, come out as their own
-    block's attention alone."""
+    """The flash kernels' own-block form (until ISSUE 37 two flash
+    pieces merged by lse with the noised rows' own blocks as dense
+    math) against the mask written out, forward and gradients; the
+    first block's noised rows, which see no clean key, come out as
+    their own block's attention alone."""
     h, hkv, d, seq = 4, 2, 128, 128
     q = jnp.asarray(_r(1, 2 * seq, h * d, seed=7))
     k, v = (jnp.asarray(_r(1, 2 * seq, hkv * d, seed=s)) for s in (8, 9))

@@ -756,10 +756,13 @@ def _gqa_inputs(h, hkv, d, t, dtype, seed=11):
                                          jnp.float32))
 
 
-def _dense_block_causal(q, k, v, h, hkv, mask_block, strict):
+def _dense_block_causal(q, k, v, h, hkv, mask_block, strict, own=False):
     """(out [B, T, H*D], lse [B, H, T], seen [T]) by dense float32 math
     with the mask written out: query i sees key j iff
-    j // m + strict <= i // m."""
+    j // m + strict <= i // m. `own` (ISSUE 37): the T rows are two
+    halves at the same positions, [noised; clean]; a clean key is seen
+    from its block on by the clean queries and from the block after by
+    the noised ones, a noised key by the noised queries of its block."""
     b, t, hd = q.shape
     d = hd // h
     qh = FA.heads_first(_f32(q), h)
@@ -767,6 +770,11 @@ def _dense_block_causal(q, k, v, h, hkv, mask_block, strict):
               for x in (k, v))
     at = jnp.arange(t) // mask_block
     seen = at[None, :] + int(strict) <= at[:, None]
+    if own:
+        at = np.arange(t // 2) // mask_block
+        ahead, same = at[None, :] < at[:, None], at[None, :] == at[:, None]
+        seen = jnp.asarray(np.block([[same, ahead],
+                                     [np.zeros_like(same), ahead | same]]))
     s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * d ** -0.5
     s = jnp.where(seen, s, -jnp.inf)
     lse = jax.nn.logsumexp(s, -1)
@@ -871,6 +879,103 @@ def test_what_the_kernels_cannot_take_goes_dense():
         FA.flash_bthd(q, k, v, 4, causal=True, mask_block=6, n_kv_head=2)
     with pytest.raises(ValueError):
         FA.flash_bthd(q, k, v, 4, n_kv_head=3)
+
+
+# -- the own-block form (ISSUE 37): block diffusion inside the kernels -------
+
+@pytest.mark.parametrize("seq, block", [(256, None), (1024, 512)],
+                         ids=["one_block", "streamed"])
+@pytest.mark.parametrize("mask_block", [4, 128], ids=["b4", "b128"])
+@pytest.mark.parametrize("h, hkv", [(2, 2), (4, 2), (8, 1)],
+                         ids=["group1", "group2", "group8"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_own_block_form_matches_dense(dtype, h, hkv, mask_block, seq, block):
+    """[noised; clean] rows through ONE call of each kernel, in
+    interpret mode against dense float32 math with the 2L x 2L mask
+    written out: out, dq, and dk, dv of both halves, summed over each
+    group. A half in one block (one masked panel; forward grid
+    (., 2, 1)) and in two streamed blocks of 512 (two panels of 256
+    each on the diagonal); the backward is the two kernels either way,
+    since the rows are never one block."""
+    d = 128
+    q, k, v, dy, _ = _gqa_inputs(h, hkv, d, 2 * seq, dtype, seed=3)
+    kw = dict(causal=True, force="interpret", block_q=block, block_k=block,
+              n_kv_head=hkv, mask_block=mask_block, own_block=True)
+    dense = lambda q, k, v: _dense_block_causal(
+        q, k, v, h, hkv, mask_block, False, own=True)[0]
+    run = lambda q, k, v: FA.flash_bthd(q, k, v, h, **kw)
+    tol = 5e-3 if dtype == jnp.float32 else 2e-2
+    o = run(q, k, v)
+    assert o.shape == q.shape and o.dtype == dtype
+    _assert_close("out", o, dense(q, k, v), tol)
+    loss = lambda fn: lambda *a: (_f32(fn(*a)) * _f32(dy)).sum()
+    grad = jax.grad(loss(run), (0, 1, 2))
+    assert _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr) \
+        == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    want = jax.grad(loss(dense), (0, 1, 2))(_f32(q), _f32(k), _f32(v))
+    for name, a, b in zip(("dq", "dk", "dv"), grad(q, k, v), want):
+        assert a.shape == b.shape and a.dtype == dtype
+        for half, rows in (("noised", slice(0, seq)),
+                           ("clean", slice(seq, None))):
+            _assert_close(name + " " + half, a[:, rows], b[:, rows], tol)
+
+
+def test_own_blocks_first_noised_rows_see_themselves_alone():
+    """The first block's noised rows see no clean key (a strict call
+    gave them lse -1e30 for the merge to weigh out): inside the kernels
+    their own block is all their softmax runs over, so their output is
+    the attention of q's first rows on the noised keys of those rows,
+    whatever the clean half holds."""
+    h, hkv, d, seq, m = 4, 2, 128, 256, 4
+    q, k, v, _, _ = _gqa_inputs(h, hkv, d, 2 * seq, jnp.float32, seed=5)
+    run = lambda k, v: FA.flash_bthd(
+        q, k, v, h, causal=True, force="interpret", n_kv_head=hkv,
+        mask_block=m, own_block=True, block_q=128, block_k=128)
+    o = run(k, v)
+    alone = _dense_block_causal(q[:, :m], k[:, :m], v[:, :m], h, hkv, m,
+                                False)[0]
+    _assert_close("first block", o[:, :m], alone, 1e-5)
+    other = run(k.at[:, seq:].multiply(-3.0), v.at[:, seq:].add(1.0))
+    assert bool((other[:, :m] == o[:, :m]).all())
+    assert not bool((other[:, m:2 * m] == o[:, m:2 * m]).all())
+
+
+def test_own_block_form_counts_itself_and_goes_dense_where_it_must():
+    """The counter's `mask` label reads `block_causal_own`, the backward
+    `two_kernels` also where a half is one block; what the kernels
+    cannot take (unequal blocks, two heads of 64 under grouped keys) is
+    the dense mask; `strict`, a full mask or an odd count of rows with
+    the form is a ValueError."""
+    count = FA._LOWERINGS
+    q, k, v, _, _ = _gqa_inputs(4, 2, 128, 512, jnp.float32)
+    labels = dict(path="interpret", entry="bthd", heads_per_block="1",
+                  backward="two_kernels", mask="block_causal_own",
+                  kv_groups="2", key_width="128", value_width="128",
+                  second_part="none")
+    was = count.value(**labels)
+    kw = dict(causal=True, force="interpret", n_kv_head=2, mask_block=4,
+              own_block=True)
+    o = FA.flash_bthd(q, k, v, 4, **kw)
+    assert count.value(**labels) == was + 1
+    labels.update(path="dense", backward="none")
+    was = count.value(**labels)
+    o_dense = FA.flash_bthd(q, k, v, 4, block_q=128, block_k=256, **kw)
+    assert count.value(**labels) == was + 1
+    want = _dense_block_causal(q, k, v, 4, 2, 4, False, own=True)[0]
+    _assert_close("kernels", o, want, 1e-5)
+    _assert_close("dense", o_dense, want, 1e-5)
+    q, k, v, _, _ = _gqa_inputs(4, 2, 64, 512, jnp.float32)
+    labels.update(heads_per_block="2", key_width="64", value_width="64")
+    was = count.value(**labels)
+    FA.flash_bthd(q, k, v, 4, **kw)
+    assert count.value(**labels) == was + 1
+    for bad in (dict(strict=True), dict(causal=False)):
+        with pytest.raises(ValueError):
+            FA.flash_bthd(q, k, v, 4, **dict(kw, **bad))
+    with pytest.raises(ValueError):
+        FA.flash_bthd(q[:, :255], k[:, :255], v[:, :255], 4, **kw)
+    assert "block_causal_own" in count.help
 
 
 # sha256 (first 16 hex digits) of dq, dk, dv as float32 bytes from the

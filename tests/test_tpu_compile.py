@@ -249,21 +249,24 @@ def test_kernel_name_is_in_the_lowered_text(chip, kernel):
 
 # ISSUE 32: the block-diffusion cell's shapes. 32 query heads of 128
 # reading 4 key/value heads at T 4096 (streamed 1024-blocks, the two
-# backward kernels), under both variants of the block-granular mask; the
-# attention of the whole objective (two kernel calls merged by lse); and
+# backward kernels), under the three forms of the block-granular mask; the
+# attention of the whole objective (since ISSUE 37 the third form, one call
+# of each kernel over [noised; clean] rows and nothing outside them); and
 # the dropless expert layer at 16,384 rows over 16 held of 128 experts,
 # whose grouped matmuls are XLA's own `ragged-dot` kernels.
-@pytest.mark.parametrize("strict", [False, True],
-                         ids=["block_causal", "block_causal_strict"])
+@pytest.mark.parametrize("form", [
+    {}, {"strict": True}, {"own_block": True}],
+    ids=["block_causal", "block_causal_strict", "block_causal_own"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_grouped_kv_block_causal_compiles_for_v5e(chip, strict, direction):
+def test_grouped_kv_block_causal_compiles_for_v5e(chip, form, direction):
     b, t, h, hkv, d = 2, 4096, 32, 4, 128
+    t *= 2 if "own_block" in form else 1     # [noised; clean] rows
     q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
     kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=chip)
 
     def fwd(q, k, v):
         return flash_bthd(q, k, v, h, causal=True, force="pallas",
-                          n_kv_head=hkv, mask_block=4, strict=strict)
+                          n_kv_head=hkv, mask_block=4, **form)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
@@ -278,8 +281,14 @@ def test_grouped_kv_block_causal_compiles_for_v5e(chip, strict, direction):
 
 def test_block_diffusion_attention_compiles_for_v5e(chip):
     """[noised; clean] rows of one step's two sequences, forward and
-    backward: two calls of each kernel and no [T, T] tensor: the largest
-    float32 buffer the program names is an operand's size."""
+    backward: ONE call of each kernel (ISSUE 37: the own-block form) and
+    no [T, T] tensor: the largest float32 buffer the program names is an
+    operand's size. Nothing of q's size is made outside the kernels,
+    forward or backward: no dot, slice, concatenate, pad or transpose
+    (the halves are addressed by the kernels' block offsets, the merge
+    is the streaming softmax's); what is left is the sums of dk and dv
+    over each group of 8 query heads and their two halves put end to
+    end, an eighth of q's size."""
     import math
     import re
     from paddle_tpu.ops import block_diffusion as BD
@@ -293,10 +302,17 @@ def test_block_diffusion_attention_compiles_for_v5e(chip):
             jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count("tpu_custom_call") == 6
-    sizes = [math.prod(int(x) for x in dims.split(","))
-             for dims in re.findall(r"f32\[([\d,]+)\]", text)]
-    assert max(sizes) <= b * 2 * t * h * d
+    assert text.count("tpu_custom_call") == 3
+    for name in ["flash_fwd"] + _TWO:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    assert max(size(dims) for dims in re.findall(r"f32\[([\d,]+)\]", text)
+               ) <= b * 2 * t * h * d
+    moved = [line.strip() for line in text.splitlines() for made in
+             [re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* "
+                       r"(dot|slice|concatenate|pad|transpose)\(", line)]
+             if made and size(made.group(1)) >= b * 2 * t * h * d]
+    assert not moved, moved
 
 
 def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
@@ -307,10 +323,12 @@ def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
     `reshape`, `concatenate`, `pad`, `slice`, `copy` or `transpose`
     result of q's or k's size: both stay [B, T, H*D] bfloat16 as the
     projections' matmuls write them. Backward: two `qk_norm_rope_bwd`
-    more, and no such result that the same layer with neither norm nor
-    rotation does not have (the flash backward sums dk and dv over each
-    group of query heads on a copy). Each op alone (`rms_norm` grouped,
-    `rope`) lowers to the same kernels."""
+    more, no such result of q's size, and of k's size only what the same
+    layer with neither norm nor rotation has: the `pad`s that put the
+    key/value heads' group sums of dk and dv side by side (since ISSUE
+    37 the sums are of lane slices, fused into one pass: the copy of
+    the kernels' float32 dk and dv into another tiling is gone). Each
+    op alone (`rms_norm` grouped, `rope`) lowers to the same kernels."""
     import math
     import re
     from paddle_tpu.ops import rotary
@@ -337,18 +355,19 @@ def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
                           mask_block=4)
 
     def sized(text):
-        """(op, line) of every result of q's or k's size."""
+        """(op, size) of every result of q's or k's size."""
         found = []
         for line in text.split("\n"):
             m = re.search(r"= (?:bf16|f32)\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(",
                           line)
-            if m and math.prod(int(n) for n in m.group(1).split(",")) in (
-                    b * t * h * d, b * t * hkv * d):
-                found.append((m.group(2), line))
+            size = m and math.prod(int(n) for n in m.group(1).split(","))
+            if size in (b * t * h * d, b * t * hkv * d):
+                found.append((m.group(2), size))
         return found
 
     calls = lambda text, name: len(re.findall(r"%%%s[.\d]* = " % name, text))
-    moved = lambda text: [op for op, _ in sized(text) if op in moves]
+    moved = lambda text, size=0: [
+        op for op, n in sized(text) if op in moves and n >= size]
     grad = lambda fused: jax.grad(
         lambda *a: layer(fused, *a).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3))
@@ -362,9 +381,9 @@ def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
         assert calls(text, "qk_norm_rope_fwd") == n
         assert calls(text, "qk_norm_rope_bwd") == n
         assert text.count("tpu_custom_call") == 2 * n + 3
-        # the group sum's result is relaid once for dk and once for dv
-        # either way, as a `reshape` or as a `copy`
-        assert len(moved(text)) == len(bare) == 4
+        assert not moved(text, b * t * h * d)
+        assert set(moved(text)) == {"pad"} == set(bare)
+        assert len(moved(text)) <= len(bare)
 
 
 @pytest.mark.parametrize("shape", [(16384, 2048, 768, 128, 16, 8),
