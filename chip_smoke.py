@@ -27,6 +27,12 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           [4096, 64], values 128 wide): dq_nope, dq_pe, dk_nope, dk_pe
           (summed over the 32 heads in the kernel) and dv against dense
           float32 math
+  window  a window bound in the streamed kernels (ISSUE 38): q
+          [2, 4096, 32 x 128] reading k/v [2, 4096, 4 x 128] under a
+          window of 2048 keys: output, dq, dk, dv against dense float32
+          math at `highest`; then the cell trinity_train_T16k's shape,
+          one sequence of 16,384 rows, forward and backward in device
+          ms under the window beside plain causal
   rotary  QK-norm and RoPE in the projections' own layout (the kernel
           pair of ops/rotary.py) at the block-diffusion cell's shapes,
           q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
@@ -485,6 +491,71 @@ def phase_mla(seed, rehearse):
         # nothing of k_pe's size times the heads, and no operand padded
         # to 256 lanes a head, is made round the kernels
         assert "%d,%d]" % (t, h * 2 * d) not in text
+
+
+def phase_window(seed, rehearse):
+    """The kernels of a sliding-window layer (ISSUE 38): 32 query heads
+    of 128 reading 4 key/value heads under a window of 2048 keys at
+    T 4096, streamed (a q block's band is three key blocks of 1024: its
+    own, cut on the diagonal, one whole, and one that the lower edge
+    crosses); output and the three gradients against dense float32 math
+    at `highest` with the band written out. Then what the bound saves,
+    at the cell's own shape (one sequence of 16,384 rows): device ms of
+    forward and backward under the window beside plain causal."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+    b, t, h, hkv, d, window = (2, 512, 4, 1, 128, 200) if rehearse else (
+        2, 4096, 32, 4, 128, 2048)
+    rng = np.random.RandomState(seed)
+    mk = lambda rows, n: jnp.asarray(rng.randn(1, rows, n * d) * 0.5,
+                                     jnp.bfloat16)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def attend(w):
+        def loss(dy, q, k, v):
+            out = fa.flash_bthd(q, k, v, h, causal=True, n_kv_head=hkv,
+                                window=w)
+            return (f32(out) * f32(dy)).sum(), out
+        return loss
+
+    def dense(dy, q, k, v):
+        o, _ = fa._dense_lse(
+            fa.heads_first(q, h), fa.heads_first(k, hkv),
+            fa.heads_first(v, hkv), True, d ** -0.5, (0, fa._WIN, window))
+        return (fa.heads_last(o) * f32(dy)).sum(), fa.heads_last(o)
+
+    t0 = time.perf_counter()
+    kernel = jax.jit(jax.value_and_grad(attend(window), (1, 2, 3),
+                                        has_aux=True))
+    dense_grad = jax.jit(jax.value_and_grad(dense, (1, 2, 3), has_aux=True))
+    errs, text = [], ""
+    for row in range(b):        # a sequence at a time: 32 x 4096^2 scores
+        dy, q, k, v = mk(t, h), mk(t, h), mk(t, hkv), mk(t, hkv)
+        if not text:
+            text = compiled_text(kernel, dy, q, k, v)
+        (_, out), got = kernel(dy, q, k, v)
+        with jax.default_matmul_precision("highest"):
+            (_, ref), want = dense_grad(dy, f32(q), f32(k), f32(v))
+        errs.append([
+            float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+            for a, r in zip((out,) + got, (ref,) + want)])
+    errs = np.max(errs, axis=0)
+    log("[window] q [%d, %d, %d] k/v [.., %d] bf16, window %d: out %.3e "
+        "dq %.3e dk %.3e dv %.3e from dense float32 math (%.1f s)" % (
+            b, t, h * d, hkv * d, window, *errs, time.perf_counter() - t0))
+    assert max(errs) <= FLASH_GRAD_TOL, errs
+    if not rehearse:
+        assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    t = 1024 if rehearse else 16384
+    dy, q, k, v = mk(t, h), mk(t, h), mk(t, hkv), mk(t, hkv)
+    for w in (window, None):
+        ms, _, ops = _device_ms(
+            jax.jit(jax.grad(attend(w), (1, 2, 3), has_aux=True)),
+            (dy, q, k, v), 4, rehearse, "window")
+        log("[window] T %d, window %s: forward + backward %.3f ms a call "
+            "on the device (%s)" % (t, w, ms, ", ".join(
+                "%s %.3f" % kv for kv in ops.most_common(6))))
 
 
 def phase_rotary(seed, rehearse):
@@ -1086,7 +1157,8 @@ def main():
                          "dp2 x tp2 mesh and its one-device baseline")
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
-                         "(flash, gqa, own_block, mla, rotary, experts, "
+                         "(flash, gqa, own_block, mla, window, rotary, "
+                         "experts, "
                          "rows, "
                          "train, serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
@@ -1111,7 +1183,7 @@ def main():
     else:
         phases = {"flash": phase_flash, "gqa": phase_gqa,
                   "own_block": phase_own_block, "mla": phase_mla,
-                  "rotary": phase_rotary, "experts": phase_experts,
+                  "window": phase_window, "rotary": phase_rotary, "experts": phase_experts,
                   "rows": phase_rows,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}

@@ -498,12 +498,14 @@ def rope(input, n_head, theta=10000.0, wrap=0, name=None):
 
 
 def qk_norm_rope(input, n_head, theta=10000.0, wrap=0, epsilon=1e-6,
-                 param_attr=None, name=None):
+                 param_attr=None, name=None, rotate=True):
     """``rope(rms_norm(input, groups=n_head), n_head, theta, wrap)`` as
     ONE op on ``[B, T, H * D]``: QK-norm under one learned weight ``[D]``
     (named by `param_attr`, as ``rms_norm``'s) and the rotary embedding
     of each head, float32 from end to end, in the layout a projection
-    leaves (``ops/rotary.py``)."""
+    leaves (``ops/rotary.py``). `rotate` False leaves the rotation out:
+    the QK-norm of a layer that carries no position signal, under the
+    same op type."""
     helper = LayerHelper("qk_norm_rope", param_attr=param_attr, name=name)
     scale = _norm_weight(helper, input.shape[-1] // n_head)
     out = helper.create_variable_for_type_inference(input.dtype,
@@ -512,7 +514,35 @@ def qk_norm_rope(input, n_head, theta=10000.0, wrap=0, epsilon=1e-6,
                      inputs={"X": [input], "Scale": [scale]},
                      outputs={"Out": [out]},
                      attrs={"n_head": int(n_head), "theta": float(theta),
-                            "wrap": int(wrap), "epsilon": epsilon})
+                            "wrap": int(wrap), "epsilon": epsilon,
+                            **({} if rotate else {"rotate": False})})
+    return out
+
+
+def causal_attention(q, k, v, n_head, n_kv_head, window=0, scale=0.0,
+                     name=None):
+    """Causal attention over grouped heads (``ops/causal_attention.py``):
+    q ``[B, T, H * D]``, k and v ``[B, T, Hkv * D]``, query head h
+    reading key/value head ``h // (H / Hkv)``; under `window` w > 0 a
+    query sees its own key and the w - 1 before it, and the flash
+    kernels do not walk the key blocks under that band. Returns a
+    variable of q's shape."""
+    helper = LayerHelper("causal_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    helper.append_op(
+        type="causal_attention",
+        inputs={"Q": [q], "K": [k], "V": [v]}, outputs={"Out": [out]},
+        attrs={"n_head": int(n_head), "n_kv_head": int(n_kv_head),
+               "window": int(window), "scale": float(scale)})
+    return out
+
+
+def sigmoid_mul(x, y, name=None):
+    """``x * sigmoid(y)``: an output gate."""
+    helper = LayerHelper("sigmoid_mul", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    helper.append_op(type="sigmoid_mul", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]})
     return out
 
 

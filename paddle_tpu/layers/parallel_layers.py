@@ -95,7 +95,7 @@ def sparse_moe(x, num_experts, d_inner, capacity_factor=1.25,
 def routed_experts(x, num_experts, experts_held, first_expert, top_k,
                    d_inner, norm_topk=True, name=None, score_func="softmax",
                    routed_scaling_factor=1.0, bias_update_rate=None,
-                   shared_expert=False):
+                   shared_expert=False, router_std=0.02):
     """One chip's share of a mixture of SiLU-gated experts over
     ``[B, T, D]`` input, dropless (``parallel/moe.routed_experts``): a
     float32 router over all `num_experts`, the `top_k` largest a row
@@ -118,13 +118,14 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     every train run moves it by u towards an even load
     (``moe.bias_step``) and adds one to the persistable
     ``<name>.steps`` [1] int32. `shared_expert` labels the lowering's count:
-    the caller adds a shared expert's output to `out`."""
+    the caller adds a shared expert's output to `out`. `router_std` is
+    the router's initialisation, N(0, `router_std`)."""
     helper = LayerHelper("routed_experts", name=name)
     d = int(x.shape[-1])
     param = lambda suffix, shape, std: helper.create_parameter(
         ParamAttr(name="%s.%s" % (helper.name, suffix)), shape=shape,
         dtype="float32", default_initializer=Normal(0., std))
-    router = param("router", [d, num_experts], 0.02)
+    router = param("router", [d, num_experts], router_std)
     w_gate = param("w_gate", [experts_held, d, d_inner], d ** -0.5)
     w_up = param("w_up", [experts_held, d, d_inner], d ** -0.5)
     w_down = param("w_down", [experts_held, d_inner, d], d_inner ** -0.5)

@@ -5,6 +5,7 @@ from . import (  # noqa: F401
     activations,
     beam_search,
     block_diffusion,
+    causal_attention,
     control_flow,
     conv,
     crf_ctc,

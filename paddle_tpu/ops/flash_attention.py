@@ -100,6 +100,24 @@ a noised panel takes one segment more, the noised keys of its own
 rows, kept where `kj >> shift == qi >> shift`, inside the same
 streaming-softmax step (_walk, _causal). The mask is a static value;
 under the other forms every kernel traces what it traced before.
+
+A window bound (ISSUE 38): `flash_bthd(..., causal=True, window=w)`
+keeps, for query i, the keys j with `i - w < j <= i`: a band under the
+diagonal. The three streamed kernels (and the fused backward, where a
+block holds all of T) take it as a fourth mask form, (0, _WIN, w). What
+it saves is what is NOT walked: the grid's key axis (the q axis in
+flash_bwd_dkv) has only the band's steps, `ceil((w - 1) / block) + 1`,
+and the index maps start it at the q block's own place, so a key block
+wholly under the band is neither fetched nor computed; a block wholly
+inside it is one unmasked batch of work; the blocks that the diagonal
+or the band's lower edge cross are cut, at trace time, into panels
+whose tiles outside the band are left out and whose crossed tiles alone
+are masked (_band_cuts). It composes with grouped key/value heads and
+with an lse output, and with nothing else: a mask in blocks, `strict`,
+`own_block` or a second score part beside it raise. `window >= T` is
+plain causal and takes causal's path. Each lowering that walks a band
+adds, to `ptpu_flash_band_scores_total{window, walk, kind}`, the scores
+its walks compute and the scores the band holds, both static.
 """
 
 import functools
@@ -167,7 +185,8 @@ def _dense_lse(q, k, v, causal, scale, mask=(0, 0), q2=None, k2=None):
     The math-identical fallback for flash_attention_lse. k and v may
     hold fewer heads than q (query head h reads head h // group);
     `mask` = (shift, form) is _causal's, read where `causal`; under the
-    form _OWN the T rows are [noised; clean] halves. q2
+    form _OWN the T rows are [noised; clean] halves, and (0, _WIN, w)
+    keeps the w keys up to the query's own. q2
     [B, H, T, D2] and k2 [B, T, D2], where given, add a second part to
     every score: q2 against the ONE key k2 that all heads read."""
     group = q.shape[1] // k.shape[1]
@@ -181,7 +200,7 @@ def _dense_lse(q, k, v, causal, scale, mask=(0, 0), q2=None, k2=None):
     s = s * scale
     if causal:
         t = s.shape[-1]
-        shift, strict = mask
+        shift, strict = mask[:2]
         if strict == _OWN:
             # a clean key counts for its own half from its own block on
             # and for the noised half from the block after; a noised key
@@ -192,6 +211,9 @@ def _dense_lse(q, k, v, causal, scale, mask=(0, 0), q2=None, k2=None):
                 noised[None, :],
                 noised[:, None] & (at[None, :] == at[:, None]),
                 at[None, :] + noised[:, None] <= at[:, None])
+        elif strict == _WIN:
+            ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+            seen = (ahead >= 0) & (ahead < mask[2])
         else:
             at = jnp.arange(t) >> shift
             seen = at[None, :] + strict <= at[:, None]
@@ -233,6 +255,7 @@ def _tile(block, target):
 
 
 _OWN = 2       # the third mask form: see _causal
+_WIN = 3       # the fourth: (0, _WIN, window), a band under the diagonal
 
 
 def _causal(s, off, q_axis, mask=(0, 0)):
@@ -248,12 +271,17 @@ def _causal(s, off, q_axis, mask=(0, 0)):
     blocks. Form _OWN keeps the keys of the query's own block and no
     others: the tile a noised row of block diffusion makes with the
     noised keys of its own rows (`off` 0), beside the strict tile it
-    makes with the clean ones (_walk)."""
-    shift, strict = mask
+    makes with the clean ones (_walk). (0, _WIN, w) keeps a query's own
+    key and the w - 1 before it: both edges of the band, for a tile
+    that the lower one crosses."""
+    shift, strict = mask[:2]
     qi = lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     kj = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     if strict == _OWN:
         return jnp.where((kj >> shift) == (qi >> shift), s, _NEG_INF)
+    if strict == _WIN:
+        ahead = off - (kj - qi)     # the query's row less the key's
+        return jnp.where((ahead >= 0) & (ahead < mask[2]), s, _NEG_INF)
     if not shift and not strict:
         return jnp.where(kj - qi <= off, s, _NEG_INF)
     return jnp.where((kj >> shift) - (qi >> shift) + strict
@@ -263,6 +291,78 @@ def _causal(s, off, q_axis, mask=(0, 0)):
 def _own(mask):
     """Whether a mask (a kernel's, or a segment's) is the own-block form."""
     return mask is not None and mask[1] == _OWN
+
+
+def _band(mask):
+    """Whether a mask is the window form, (0, _WIN, window)."""
+    return mask is not None and mask[1] == _WIN
+
+
+def _band_steps(window, block, blocks):
+    """The major blocks a q block's band touches (a key block's, by
+    keys): its own and those the `window - 1` keys before its first row
+    reach into, of the `blocks` there are."""
+    return min(-(-(window - 1) // block) + 1, blocks)
+
+
+def _band_cuts(delta, block, tile, window, by_keys=False):
+    """The static cut of the major block pair whose q block lies `delta`
+    (equal) blocks after its key block, under a window: None where the
+    whole block is inside the band (_walk's `whole`), else a list of
+    _walk's (mine, segments), one a panel of `tile` rows (of keys,
+    `by_keys`; a block no larger than a tile is one panel). A tile above
+    the diagonal or wholly under the band is left out, runs of tiles
+    wholly inside the band are one unmasked segment, a tile that the
+    diagonal alone crosses is masked as causal's, and one that the
+    lower edge crosses by both edges."""
+    if delta and window >= (delta + 1) * block:
+        return None
+    tile = min(tile, block)
+    n = block // tile
+
+    def kind(r, c):
+        qt, kt = (c, r) if by_keys else (r, c)
+        q0, k0 = delta * block + qt * tile, kt * tile
+        q1, k1 = q0 + tile - 1, k0 + tile - 1
+        if k0 > q1 or k1 <= q0 - window:
+            return None
+        if k0 > q1 - window:        # the lower edge passes under it
+            return "whole" if k1 <= q0 else "causal"
+        return "band"
+
+    cuts = []
+    for r in range(n):
+        segments, c = [], 0
+        while c < n:
+            what, first = kind(r, c), c
+            while c < n and kind(r, c) == what:
+                c += 1
+            if what:
+                q0, k0 = (first, r) if by_keys else (r, first)
+                segments.append((
+                    slice(first * tile, c * tile),
+                    None if what == "whole"
+                    else delta * block + (q0 - k0) * tile,
+                    {"whole": None, "causal": (0, 0),
+                     "band": (0, _WIN, window)}[what]))
+        if segments:
+            cuts.append((slice(r * tile, (r + 1) * tile), segments))
+    return cuts
+
+
+def band_scores(t, block, tile, window, by_keys=False):
+    """(scores the band's walk computes, scores the band holds) for one
+    head's [t, t]: what _walk does under (0, _WIN, window) in equal
+    major blocks of `block` rows, counted from the same cuts."""
+    blocks = t // block
+    computed = 0
+    for delta in range(_band_steps(window, block, blocks)):
+        cuts = _band_cuts(delta, block, tile, window, by_keys)
+        computed += (blocks - delta) * (block * block if cuts is None else sum(
+            (mine.stop - mine.start) * (cols.stop - cols.start)
+            for mine, segments in cuts for cols, _, _ in segments))
+    seen = min(window, t)       # the first rows see fewer than a window
+    return computed, seen * (seen + 1) // 2 + (t - seen) * seen
 
 
 def _when(cond):
@@ -294,7 +394,8 @@ def _unfold(at, outer, inner):
     return at // inner, at % inner
 
 
-def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False, half=0):
+def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False, half=0,
+          band=None):
     """Run `panel(mine, segments)` over the major block (i, j).
 
     `mine` is a static slice of the block's query rows (of its keys if
@@ -323,6 +424,16 @@ def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False, half=0):
     reads them from its second pair of key/value blocks). By keys that
     segment is a panel of its own, since it ends in the noised keys' dk
     and dv and not in the clean ones'.
+
+    Under the window form (0, _WIN, w), in equal blocks, the grid's key
+    axis holds only the band's steps: `band` = (steps, blocks of the
+    walked side in all). `j` is then the step, and the key block lies
+    `steps - 1 - j` blocks before q block `i` (a step that would start
+    before the first key block does nothing); by keys `i` is the step
+    and the q block lies `i` blocks after key block `j`. Each distance
+    is static inside its branch: a block wholly inside the band runs as
+    `whole`, one that the diagonal or the lower edge crosses by its
+    static cut (_band_cuts).
     """
     nq, nk = (block_k, block_q) if by_keys else (block_q, block_k)
 
@@ -352,6 +463,24 @@ def _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=False, half=0):
 
     if mask is None:
         return whole()
+    if _band(mask):
+        steps, blocks = band or (1, 1)
+        inside = []
+        for delta in range(steps):
+            here = (i == delta) & (j + delta < blocks) if by_keys \
+                else (j == steps - 1 - delta) & (i >= delta)
+            cuts = _band_cuts(delta, block_q, tile, mask[2], by_keys)
+            if cuts is None:
+                inside.append(here)
+                continue
+
+            def crossed_band(cuts=cuts):
+                for cut in cuts:
+                    panel(*cut)
+            _when(here)(crossed_band)
+        if inside:
+            _when(functools.reduce(lambda a, b: a | b, inside))(whole)
+        return
     if _own(mask):
         noised = i < half
         i = jnp.where(noised, i, i - half)
@@ -424,7 +553,7 @@ def _put(ref, idx, x, mine):
 # across the (sequential, innermost) nK dimension. One key block carries
 # nothing: each panel finishes its own rows, and there is no scratch.
 def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
-                part2=None):
+                part2=None, band=None):
     if part2:
         q_ref, k_ref, v_ref, q2_ref, k2_ref, o_ref, lse_ref, *scratch = refs
         a2 = pl.program_id(0) % part2[1]    # its place in q2's block
@@ -491,7 +620,8 @@ def _fwd_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d, g,
             l_s[a, rows] = l
             _put(acc_s, rows, acc, mine)
 
-        _walk(panel, i, j, mask, block_q, block_k, tile, half=nq // 2)
+        _walk(panel, i, j, mask, block_q, block_k, tile, half=nq // 2,
+              band=band)
 
         if nk > 1:
             @pl.when(j == nk - 1)
@@ -563,10 +693,20 @@ def _fwd_pallas(q, k, v, n_head, n_kv_head, mask, scale, block_q, block_k,
         nq, nk = t // bq, t // bk
         keys = [kv(bk, 2), kv(bk, 2)]
         operands = (q, k, v)
+    band = None
+    if _band(mask):
+        # the key axis holds the band's steps alone, and step s[2] of q
+        # block s[1] is the key block that many short of the last step
+        # before it (block 0 again where that would be before the first:
+        # nothing is fetched for a step that does nothing)
+        nk = _band_steps(mask[2], bk, nk)
+        band = (nk, nq)
+        keys = [kv(bk, lambda s: jnp.maximum(s[1] - (nk - 1) + s[2], 0))] * 2
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, mask=mask, scale=scale,
                           block_q=bq, block_k=bk,
-                          tile=_tile(bq, _TILE), nq=nq, nk=nk, d=d, g=g),
+                          tile=_tile(bq, _TILE), nq=nq, nk=nk, d=d, g=g,
+                          band=band),
         grid=(b * n_head // g, nq, nk),
         in_specs=[rows(bq, 1)] + keys,
         out_specs=[rows(bq, 1), stat(bq, 1)],
@@ -666,7 +806,7 @@ def _bwd_fused_kernel(*refs, mask, scale, t, tile, d, g, has_dlse):
 
 
 def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
-                   g, has_dlse, part2=None):
+                   g, has_dlse, part2=None, band=None):
     q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
     dlse_ref = refs[6] if has_dlse else None
     i, j = _block_ids(nq, nk)
@@ -726,7 +866,8 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
             if part2:
                 acc2_s[rows] = acc2_s[rows] + acc2
 
-        _walk(panel, i, j, mask, block_q, block_k, tile, half=nq // 2)
+        _walk(panel, i, j, mask, block_q, block_k, tile, half=nq // 2,
+              band=band)
 
     _each_head(g, head)
 
@@ -743,7 +884,7 @@ def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
 
 
 def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
-                    g, part2=None):
+                    g, part2=None, band=None):
     q_ref, k_ref, v_ref, dy_ref, lse_ref, delta_ref = refs[:6]
     i, jj = _block_ids(nq, nk, by_keys=True)    # q blocks innermost here
     if part2:
@@ -815,7 +956,7 @@ def _bwd_dkv_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
                 dk2_s[cols] = dk2_s[cols] + dk2
 
         _walk(panel, i, jj, mask, block_q, block_k, tile, by_keys=True,
-              half=nq // 2)
+              half=nq // 2, band=band)
 
     _each_head(g, head)
 
@@ -906,6 +1047,15 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
     else:
         clean = lambda axis: axis
         noised_q, noised_k, kn = [], [], ()
+    # what the two kernels' inner grid axis counts and where it points:
+    # every key block of a q block (every q block of a key block, by
+    # keys), or under a window the band's steps alone (_fwd_pallas)
+    steps_k, steps_q, keys_at, rows_at, band = nk, nq, clean(2), 2, None
+    if _band(mask):
+        steps_k = steps_q = _band_steps(mask[2], bk, nk)
+        band = (steps_k, nq)
+        keys_at = lambda s: jnp.maximum(s[1] - (steps_k - 1) + s[2], 0)
+        rows_at = lambda s: jnp.minimum(s[1] + s[2], nq - 1)
 
     if nq == nk == 1:       # _backward_of's "fused"
         dq, dk, dv = pl.pallas_call(
@@ -926,10 +1076,11 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
 
     dq, delta3 = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
-                          block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
-                          d=d, g=g, has_dlse=dlse is not None),
-        grid=(b * n_head // g, nq, nk),
-        in_specs=[rows(bq, 1), kv(bk, clean(2)), kv(bk, clean(2)),
+                          block_q=bq, block_k=bk, nq=nq, nk=steps_k,
+                          tile=tile, d=d, g=g, has_dlse=dlse is not None,
+                          band=band),
+        grid=(b * n_head // g, nq, steps_k),
+        in_specs=[rows(bq, 1), kv(bk, keys_at), kv(bk, keys_at),
                   rows(bq, 1), rows(bq, 1)] + [stat(bq, 1)] * len(stats)
         + noised_q,
         out_specs=[rows(bq, 1), stat(bq, 1)],
@@ -945,11 +1096,12 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
     # grid (B * H / g, nK, nQ): the q-side operands follow the LAST axis
     dk, dv, *dkn = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
-                          block_q=bq, block_k=bk, nq=nq, nk=nk, tile=tile,
-                          d=d, g=g),
-        grid=(b * n_head // g, nk, nq),
-        in_specs=[rows(bq, 2), kv(bk, clean(1)), kv(bk, clean(1)),
-                  rows(bq, 2), stat(bq, 2), stat(bq, 2)] + noised_k,
+                          block_q=bq, block_k=bk, nq=steps_q, nk=nk,
+                          tile=tile, d=d, g=g, band=band),
+        grid=(b * n_head // g, nk, steps_q),
+        in_specs=[rows(bq, rows_at), kv(bk, clean(1)), kv(bk, clean(1)),
+                  rows(bq, rows_at), stat(bq, rows_at), stat(bq, rows_at)]
+        + noised_k,
         out_specs=[rows(bk, 1)] * (2 + len(kn)),
         out_shape=[dkv] * (2 + len(kn)),
         scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
@@ -1207,10 +1359,19 @@ _LOWERINGS = _REG.counter(
     "the mask (none, causal, block_causal, block_causal_strict, "
     "block_causal_own: block diffusion's [noised; clean] halves), the "
     "query heads that read one key/value head, a head's key and value "
-    "widths and its score's second part (none, or shared: one key that "
-    "every head reads)",
+    "widths, its score's second part (none, or shared: one key that "
+    "every head reads) and the window that bounds the keys a query sees "
+    "(0: none)",
     ("path", "entry", "heads_per_block", "backward", "mask", "kv_groups",
-     "key_width", "value_width", "second_part"))
+     "key_width", "value_width", "second_part", "window"))
+_BAND_SCORES = _REG.counter(
+    "ptpu_flash_band_scores_total",
+    "scores of the flash kernels' walks under a window bound, added at "
+    "trace time by each lowering that takes the kernels, a batch row "
+    "and head each: kind computed (what the walk's blocks and panels "
+    "hold, masked tiles whole) and useful (what the band holds), for the "
+    "forward walk and the backward's two (by queries and by keys)",
+    ("window", "walk", "kind"))
 
 
 def _resolve_path(q, scale, block_q, block_k, force):
@@ -1262,14 +1423,24 @@ def heads_last(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
-def _mask_of(causal, mask_block, strict, own_block=False):
+def _mask_of(causal, mask_block, strict, own_block=False, window=None):
     """(the kernels' mask, its label): None / "none" where not causal,
     else _causal's (shift, form) for blocks of `mask_block` rows, a
-    power of two: form 0, 1 (`strict`) or _OWN (`own_block`)."""
+    power of two: form 0, 1 (`strict`) or _OWN (`own_block`); under a
+    `window` (0, _WIN, window), labelled "causal" (the window is a
+    label of its own)."""
     if not causal:
-        if own_block:
-            raise ValueError("flash attention: own_block is a causal mask")
+        if own_block or window:
+            raise ValueError("flash attention: own_block and window "
+                             "are causal masks")
         return None, "none"
+    if window:
+        if window < 1 or mask_block != 1 or strict or own_block:
+            raise ValueError(
+                "flash attention: a window of %r keys goes with plain "
+                "causal alone, not with a mask in blocks, strict or "
+                "own_block" % (window,))
+        return (0, _WIN, int(window)), "causal"
     shift = int(mask_block).bit_length() - 1
     if mask_block < 1 or 1 << shift != mask_block:
         raise ValueError("flash attention: the mask's block is a power "
@@ -1285,7 +1456,7 @@ def _mask_of(causal, mask_block, strict, own_block=False):
 
 def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
             entry, with_lse, n_kv_head=None, mask_block=1, strict=False,
-            q2=None, k2=None, own_block=False):
+            q2=None, k2=None, own_block=False, window=None):
     """Dispatch of every entry: q/k/v [B, T, H*D] -> out, or (out, lse
     [B, H, T]) `with_lse`. `entry` labels the count: the layout the
     caller came in. k and v may hold `n_kv_head` < H heads, [B, T,
@@ -1295,7 +1466,8 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     (the kernels: one head to a block, no groups, D2 a multiple of 128
     or dividing it in as many heads as divide H); `scale` then defaults
     to (D + D2)^-0.5. `own_block`: flash_bthd's; the kernels' blocks are
-    then cut from a HALF of the rows."""
+    then cut from a HALF of the rows. `window`: flash_bthd's; one that
+    holds every key of the sequence is no window."""
     b, t, hd = q.shape
     d = hd // n_head
     n_kv_head = n_kv_head or n_head
@@ -1312,7 +1484,13 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
         raise ValueError(
             "flash attention: %d query heads of %d cannot read k of "
             "shape %s as %d heads" % (n_head, d, k.shape, n_kv_head))
-    mask, mask_label = _mask_of(causal, mask_block, strict, own_block)
+    if window and q2 is not None:
+        raise ValueError("flash attention: a window does not go with a "
+                         "second score part")
+    if window and causal and window >= t:
+        window = None
+    mask, mask_label = _mask_of(causal, mask_block, strict, own_block,
+                                window)
     if own_block and t % 2:
         raise ValueError("flash attention: own_block wants two halves of "
                          "rows, got %d" % t)
@@ -1332,6 +1510,8 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     # neither an lse to give nor a second part
     if own_block and (bq != bk or rows % bq or with_lse or q2 is not None):
         path = "dense"
+    if window and bq != bk:         # the band is walked in equal blocks
+        path = "dense"
     if d2:
         g2 = _part2_of(n_head, q2)[1]
         if (g > 1 or n_kv_head != n_head or with_lse or n_head % g2
@@ -1346,7 +1526,17 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
                    kv_groups=str(n_head // n_kv_head),
                    key_width=str(d + d2),
                    value_width=str(v.shape[-1] // n_kv_head),
-                   second_part="shared" if d2 else "none")
+                   second_part="shared" if d2 else "none",
+                   window=str(window or 0))
+    if window and path != "dense":
+        back = _backward_blocks(t, g * d, bq, bk)[0]
+        for walk, block, by_keys in (("forward", bq, False),
+                                     ("backward_by_queries", back, False),
+                                     ("backward_by_keys", back, True)):
+            for kind, scores in zip(("computed", "useful"), band_scores(
+                    t, block, _tile(block, _TILE), window, by_keys)):
+                _BAND_SCORES.inc(scores, window=str(window), walk=walk,
+                                 kind=kind)
     if path == "dense":
         out, lse = _dense_lse(
             heads_first(q, n_head), heads_first(k, n_kv_head),
@@ -1367,7 +1557,7 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
 def flash_bthd(q, k, v, n_head, causal=False, scale=None,
                block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                force=None, n_kv_head=None, mask_block=1, strict=False,
-               q2=None, k2=None, own_block=False):
+               q2=None, k2=None, own_block=False, window=None):
     """Fused multi-head attention in the projections' own layout.
     q and the result: [B, T, H*D], head h in lanes [h D, (h+1) D); k and
     v the same, or [B, T, Hkv*D] with `n_kv_head` = Hkv heads, each read
@@ -1394,25 +1584,34 @@ def flash_bthd(q, k, v, n_head, causal=False, scale=None,
     the gradients are the [B, 2L, .] arrays as they are, no half is
     sliced out or put back.
 
+    `window` w (with `causal`; a static integer): query i sees the keys
+    j with `i - w < j <= i`, its own and the w - 1 before it. Key
+    blocks wholly under that band are not walked (the module's
+    docstring). It goes with `n_kv_head` and with nothing else: with
+    `mask_block` > 1, `strict`, `own_block` or a second part it raises;
+    w >= T is plain causal.
+
     force: None = auto (Pallas kernel on TPU when T divides the blocks,
     dense XLA math otherwise), "pallas" / "interpret" / "dense" pin a path
     (tests use "interpret" to run the kernel on CPU).
     """
     return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
                    "bthd", False, n_kv_head, mask_block, strict, q2, k2,
-                   own_block)
+                   own_block, window)
 
 
 def flash_bthd_lse(q, k, v, n_head, causal=False, scale=None,
                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                   force=None, n_kv_head=None, mask_block=1, strict=False):
+                   force=None, n_kv_head=None, mask_block=1, strict=False,
+                   window=None):
     """Like flash_bthd but returns (out [B, T, H*D], lse [B, H, T]) with
     lse[b,h,i] = logsumexp_j(q_i·k_j*scale [+mask]) — the statistic ring
     attention needs to merge partial attention over K/V shards. Both
     outputs are differentiable (the lse cotangent folds into the shared
     backward kernels)."""
     return _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
-                   "bthd", True, n_kv_head, mask_block, strict)
+                   "bthd", True, n_kv_head, mask_block, strict,
+                   window=window)
 
 
 def flash_attention(q, k, v, causal=False, scale=None,

@@ -249,11 +249,12 @@ def _rope(ctx, op):
 @register("qk_norm_rope")
 def _qk_norm_rope(ctx, op):
     """rope(rms_norm(X)) over the `n_head` heads of X [B, T, H * D]
-    under one Scale [D], float32 from end to end."""
+    under one Scale [D], float32 from end to end; the norm alone where
+    the attr `rotate` is false (a layer with no position signal)."""
     ctx.set_out(op, "Out", norm_rope(
         ctx.in1(op, "X"), ctx.in1(op, "Scale"), int(op.attr("n_head")),
-        float(op.attr("theta", 10000.0)), int(op.attr("wrap", 0)),
-        op.attr("epsilon", 1e-6)))
+        float(op.attr("theta", 10000.0)) if op.attr("rotate", True)
+        else None, int(op.attr("wrap", 0)), op.attr("epsilon", 1e-6)))
 
 
 @register("silu_mul")
@@ -261,4 +262,12 @@ def _silu_mul(ctx, op):
     """silu(X) * Y: the gated FFN's hidden activation."""
     x, y = ctx.in1(op, "X"), ctx.in1(op, "Y")
     out = jax.nn.silu(x.astype(jnp.float32)) * y.astype(jnp.float32)
+    ctx.set_out(op, "Out", out.astype(x.dtype))
+
+
+@register("sigmoid_mul")
+def _sigmoid_mul(ctx, op):
+    """X * sigmoid(Y): an output gate (attention's, ISSUE 38)."""
+    x, y = ctx.in1(op, "X"), ctx.in1(op, "Y")
+    out = x.astype(jnp.float32) * jax.nn.sigmoid(y.astype(jnp.float32))
     ctx.set_out(op, "Out", out.astype(x.dtype))

@@ -722,7 +722,7 @@ def test_lowering_counter_says_which_path_engaged():
     # four do not: all of H*D as one block, four heads to it
     labels = dict(path="dense", entry="bthd", heads_per_block="4",
                   backward="none", mask="causal", kv_groups="1",
-                  key_width="8", value_width="8", second_part="none")
+                  key_width="8", value_width="8", second_part="none", window="0")
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
@@ -736,7 +736,7 @@ def test_lowering_counter_says_which_path_engaged():
     for block, backward in ((None, "fused"), (128, "two_kernels")):
         labels = dict(path="interpret", entry="bhtd", heads_per_block="2",
                       backward=backward, mask="causal", kv_groups="1",
-                      key_width="64", value_width="64", second_part="none")
+                      key_width="64", value_width="64", second_part="none", window="0")
         was = count.value(**labels)
         FA.flash_attention(q, k, v, causal=True, force="interpret",
                            block_q=block, block_k=block)
@@ -860,7 +860,7 @@ def test_what_the_kernels_cannot_take_goes_dense():
     q, k, v, _, _ = _gqa_inputs(4, 2, 64, 256, jnp.float32)
     labels = dict(path="dense", entry="bthd", heads_per_block="2",
                   backward="none", mask="block_causal_strict", kv_groups="2",
-                  key_width="64", value_width="64", second_part="none")
+                  key_width="64", value_width="64", second_part="none", window="0")
     was = count.value(**labels)
     o = FA.flash_bthd(q, k, v, 4, causal=True, force="interpret",
                       n_kv_head=2, mask_block=4, strict=True)
@@ -870,7 +870,7 @@ def test_what_the_kernels_cannot_take_goes_dense():
     q, k, v, _, _ = _gqa_inputs(4, 2, 128, 256, jnp.float32)
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
                   backward="fused", mask="block_causal", kv_groups="2",
-                  key_width="128", value_width="128", second_part="none")
+                  key_width="128", value_width="128", second_part="none", window="0")
     was = count.value(**labels)
     FA.flash_bthd(q, k, v, 4, causal=True, force="interpret", n_kv_head=2,
                   mask_block=32)
@@ -952,7 +952,7 @@ def test_own_block_form_counts_itself_and_goes_dense_where_it_must():
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
                   backward="two_kernels", mask="block_causal_own",
                   kv_groups="2", key_width="128", value_width="128",
-                  second_part="none")
+                  second_part="none", window="0")
     was = count.value(**labels)
     kw = dict(causal=True, force="interpret", n_kv_head=2, mask_block=4,
               own_block=True)

@@ -107,12 +107,13 @@ def test_one_part_alone_is_todays_kernel():
     count = FA._LOWERINGS
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
                   backward="two_kernels", mask="causal", kv_groups="1",
-                  key_width="192", value_width="128", second_part="shared")
+                  key_width="192", value_width="128", second_part="shared",
+                  window="0")
     was = count.value(**labels)
     FA.flash_bthd(q, k, v, h, causal=True, force="interpret", q2=q2, k2=k2)
     assert count.value(**labels) == was + 1
     plain = dict(labels, backward="fused", key_width="128",
-                 second_part="none")
+                 second_part="none", window="0")
     was = count.value(**plain)
     FA.flash_bthd(q, k, v, h, causal=True, force="interpret")
     assert count.value(**plain) == was + 1

@@ -880,6 +880,8 @@ COVERED_ELSEWHERE = {
     "qk_norm_rope": "test_block_diffusion.py",
     "mla_attention": "test_latent_moe.py",
     "hyper_connection": "test_latent_moe.py",
+    "causal_attention": "test_windowed_moe.py",
+    "sigmoid_mul": "test_windowed_moe.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",

@@ -470,3 +470,36 @@ def test_two_part_score_compiles_for_v5e(chip, direction):
     # one tile of k_pe, and nothing wider
     assert max(sizes) == b * t * h * d
     assert b * t * h * 2 * d not in sizes and b * t * h * (d + d2) not in sizes
+
+
+# ISSUE 38: a window bound. The cell `trinity_train_T16k`'s window
+# layers: one packed 16,384-token sequence, 32 query heads of 128 reading
+# 4 key/value heads, a window of 2048 keys: streamed 1024-blocks whose key
+# axis holds the band's three steps alone.
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_windowed_attention_compiles_for_v5e(chip, direction):
+    """q [1, 16384, 4096] against k, v [1, 16384, 512] under a window of
+    2048: the same three kernels, and no [T, T] value: the largest
+    float32 buffer the program names is an operand's size."""
+    import math
+    import re
+    b, t, h, hkv, d = 1, 16384, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_bthd(q, k, v, h, causal=True, force="pallas",
+                          n_kv_head=hkv, window=2048)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compiled_text(fn, q, kv, kv)
+    names = ["flash_fwd"] + (_TWO if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
+               ) <= b * t * h * d
