@@ -15,7 +15,8 @@ stripped), kernels apart from the rest. The kernels are told by name
 the backward as ``flash_bwd_dq`` + ``flash_bwd_dkv`` at every shape;
 since PR 31 a shape whose T is one block (the default: bf16, T 2048)
 runs the one kernel ``flash_bwd``, and a streamed one (``--t 4096``)
-still the two. A name with no device time reads 0. Needs the chip:
+the two until PR 39, ``flash_bwd`` since (dq held in VMEM across the
+key blocks). A name with no device time reads 0. Needs the chip:
 ``chiprun -- python benchmarks/perf_probe_flash_layout.py``.
 """
 

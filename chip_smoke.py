@@ -13,14 +13,19 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           block-granular mask at the block-diffusion cell's shape (one
           sequence of [4096, 32 x 128] reading [4096, 4 x 128], blocks
           of 4, both variants): dq, dk, dv against dense float32 math,
-          the first block's wholly masked rows finite and weighed out
+          the first block's wholly masked rows finite and weighed out;
+          through the ONE streamed backward kernel (ISSUE 39; the line
+          says which backward ran, from the lowering counter) and
+          through flash_bwd_dq + flash_bwd_dkv, which a T over that
+          kernel's byte bound still takes, with the device ms of each
   own_block  the rest of the gqa check (ISSUE 37; a phase of its own so
           that `--phases own_block` probes it alone): block diffusion's
           whole attention, [noised; clean] rows q [2, 8192, 32 x 128]
           and k/v [2, 8192, 4 x 128], as ONE call of each kernel (the
           own-block mask form) against dense float32 gradients, and its
-          device ms forward and backward beside the form it replaced
-          (two calls merged by lse, the own blocks as dense math)
+          device ms forward and backward, the backward as ONE kernel and
+          as the two, beside the form it replaced (two calls merged by
+          lse, the own blocks as dense math)
   mla     the two-part score of latent attention through the streamed
           kernels at the cell xing4_train_T4k's shape (one sequence of
           [4096, 32 x 128] with q_pe [4096, 32 x 64] reading ONE k_pe
@@ -32,7 +37,8 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           window of 2048 keys: output, dq, dk, dv against dense float32
           math at `highest`; then the cell trinity_train_T16k's shape,
           one sequence of 16,384 rows, forward and backward in device
-          ms under the window beside plain causal
+          ms under the window beside plain causal, the backward as ONE
+          kernel and as the two
   rotary  QK-norm and RoPE in the projections' own layout (the kernel
           pair of ops/rotary.py) at the block-diffusion cell's shapes,
           q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
@@ -73,6 +79,7 @@ chip pass.
 """
 
 import argparse
+import contextlib
 import functools
 import json
 import math
@@ -231,6 +238,38 @@ def phase_flash(seed, rehearse):
             "all of T in one block did not take the fused backward"
 
 
+def _far(got, want):
+    """Largest difference over largest value, each result from its
+    float32 reference."""
+    import jax.numpy as jnp
+    return [float(jnp.max(jnp.abs(a.astype(jnp.float32) - r))
+                  / jnp.max(jnp.abs(r))) for a, r in zip(got, want)]
+
+
+@contextlib.contextmanager
+def _backwards_lowered(fa):
+    """Yields a list that holds, once the block is left, the `backward`
+    labels `ptpu_flash_lowerings_total` counted inside it: which
+    backward what was traced there will run."""
+    before, ran = fa._LOWERINGS.snapshot(), []
+    yield ran
+    at = fa._LOWERINGS.label_names.index("backward")
+    ran += sorted({key[at] for key, n in fa._LOWERINGS.snapshot().items()
+                   if n > before.get(key, 0)})
+
+
+@contextlib.contextmanager
+def _two_kernels(fa):
+    """What is traced inside runs the streamed backward as it was,
+    flash_bwd_dq + flash_bwd_dkv: no shape is within the bound of the
+    ONE streamed kernel (ISSUE 39)."""
+    was, fa._RESIDENT_DQ_BYTES = fa._RESIDENT_DQ_BYTES, 0
+    try:
+        yield
+    finally:
+        fa._RESIDENT_DQ_BYTES = was
+
+
 def phase_gqa(seed, rehearse):
     """The kernels of the block-diffusion step (ISSUE 32): 32 query
     heads of 128 reading 4 key/value heads, T 4096 streamed, under
@@ -269,8 +308,13 @@ def phase_gqa(seed, rehearse):
         assert bool(jnp.isfinite(f32(out)).all())
         unseen = int((lse < -1e29).sum())
         assert unseen == (4 * h * b if strict else 0), unseen
-        grad = jax.jit(jax.grad(functools.partial(loss, kernel, dy),
-                                (0, 1, 2))).lower(q, k, v).compile()
+        compile_grad = lambda: jax.jit(jax.grad(functools.partial(
+            loss, lambda q, k, v: fa.flash_bthd_lse(q, k, v, h, **kw), dy),
+            (0, 1, 2))).lower(q, k, v).compile()
+        with _backwards_lowered(fa) as ran:
+            grad = compile_grad()
+        with _two_kernels(fa):
+            grad_two = compile_grad()
         got, text = grad(q, k, v), grad.as_text()
         # the loss is a sum over sequences, so the dense gradients are
         # made a sequence at a time (32 heads of 4096^2 float32 scores)
@@ -281,16 +325,30 @@ def phase_gqa(seed, rehearse):
                                f32(k[r:r + 1]), f32(v[r:r + 1]))
                     for r in range(b)]
         want = [jnp.concatenate(parts) for parts in zip(*rows)]
-        errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
-                for a, r in zip(got, want)]
-        log("[gqa] q [%d, %d, %d] k/v [%d, %d, %d] bf16, blocks of 4%s: dq "
-            "%.3e dk %.3e dv %.3e from the dense float32 gradients "
-            "(%.1f s); %d rows see nothing" % (
+        errs, errs_two = _far(got, want), _far(grad_two(q, k, v), want)
+        log("[gqa] q [%d, %d, %d] k/v [%d, %d, %d] bf16, blocks of 4%s, "
+            "backward %s: dq %.3e dk %.3e dv %.3e from the dense float32 "
+            "gradients (the two kernels: %.3e %.3e %.3e) (%.1f s); %d "
+            "rows see nothing" % (
                 b, t, h * d, b, t, hkv * d, ", strict" if strict else "",
-                *errs, time.perf_counter() - t0, unseen))
-        assert max(errs) <= FLASH_GRAD_TOL, errs
+                "+".join(ran), *errs, *errs_two, time.perf_counter() - t0,
+                unseen))
+        assert max(errs + errs_two) <= FLASH_GRAD_TOL, (errs, errs_two)
         if not rehearse:
-            assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+            # T streamed: the ONE kernel, and the two it replaced where
+            # no shape is within its bound
+            assert ran == ["fused_streamed"], ran
+            assert "flash_bwd" in text and "flash_bwd_dq" not in text
+            two = grad_two.as_text()
+            assert "flash_bwd_dq" in two and "flash_bwd_dkv" in two
+            # the mask's variant moves no time: once is enough
+            for label, call in () if strict else (
+                    ("fused_streamed", grad), ("two_kernels", grad_two)):
+                ms, _, ops = _device_ms(call, (q, k, v), 6, rehearse,
+                                        "gqa_" + label)
+                log("[gqa] %s: forward + backward %.3f ms a call on the "
+                    "device (%s)" % (label, ms, ", ".join(
+                        "%s %.3f" % kv for kv in ops.most_common(5))))
 
 
 def _two_piece_attention(q, k, v, n_head, n_kv_head, block):
@@ -400,10 +458,14 @@ def phase_own_block(seed, rehearse):
         return fa.heads_last(o)
 
     t0 = time.perf_counter()
-    new = both(lambda q, k, v: bd.attention(q, k, v, h, hkv, 4))
+    own_block = lambda q, k, v: bd.attention(q, k, v, h, hkv, 4)
+    new, new_two = both(own_block), both(own_block)
     old = both(lambda q, k, v: _two_piece_attention(q, k, v, h, hkv, 4))
-    got = new(q, k, v, dy)
+    with _backwards_lowered(fa) as ran:
+        got = new(q, k, v, dy)
     text = new.lower(q, k, v, dy).compile().as_text()
+    with _two_kernels(fa):      # traced here, with the backward as it was
+        got_two = new_two(q, k, v, dy)
     dense_both = both(dense)
     parts = []
     with jax.default_matmul_precision("highest"):
@@ -414,21 +476,24 @@ def phase_own_block(seed, rehearse):
                      for a in range(hkv)]
             parts.append([jnp.concatenate(x, 2) for x in zip(*heads)])
     want = [jnp.concatenate(x) for x in zip(*parts)]
-    errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
-            for a, r in zip(got, want)]
-    was = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
-           for a, r in zip(old(q, k, v, dy), want)]
+    errs, errs_two, was = (_far(x, want) for x in (
+        got, got_two, old(q, k, v, dy)))
     log("[own_block] own-block form, q [%d, %d, %d] k/v [%d, %d, %d] bf16, "
-        "[noised; clean] rows, blocks of 4: out %.3e dq %.3e dk %.3e dv "
-        "%.3e from dense float32 math (the two-piece form: %.3e %.3e %.3e "
-        "%.3e) (%.1f s); tpu_custom_call sites %d" % (
-            b, 2 * seq, h * d, b, 2 * seq, hkv * d, *errs, *was,
-            time.perf_counter() - t0, text.count("tpu_custom_call")))
-    assert max(errs) <= FLASH_GRAD_TOL, errs
+        "[noised; clean] rows, blocks of 4, backward %s: out %.3e dq %.3e "
+        "dk %.3e dv %.3e from dense float32 math (flash_bwd_dq + "
+        "flash_bwd_dkv: %.3e %.3e %.3e %.3e; the two-piece form: %.3e "
+        "%.3e %.3e %.3e) (%.1f s); tpu_custom_call sites %d" % (
+            b, 2 * seq, h * d, b, 2 * seq, hkv * d, "+".join(ran), *errs,
+            *errs_two, *was, time.perf_counter() - t0,
+            text.count("tpu_custom_call")))
+    assert max(errs + errs_two) <= FLASH_GRAD_TOL, (errs, errs_two)
     if not rehearse:
-        assert text.count("tpu_custom_call") == 3, "not one call a kernel"
-    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-    for label, call in (("own-block", new), ("two-piece", old)):
+        # the forward and ONE backward kernel (ISSUE 39)
+        assert ran == ["fused_streamed"], ran
+        assert text.count("tpu_custom_call") == 2, "not one call a kernel"
+    kernels = ("flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv")
+    for label, call in (("own-block", new), ("own-block-two-kernels", new_two),
+                        ("two-piece", old)):
         ms, _, ops = _device_ms(call, (q, k, v, dy), 2 if rehearse else 10,
                                 rehearse, "bd_attention_" + label)
         inside = {n: ops.pop(n, 0.0) for n in kernels}
@@ -546,16 +611,27 @@ def phase_window(seed, rehearse):
             b, t, h * d, hkv * d, window, *errs, time.perf_counter() - t0))
     assert max(errs) <= FLASH_GRAD_TOL, errs
     if not rehearse:
-        assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+        assert "flash_bwd" in text and "flash_bwd_dq" not in text
     t = 1024 if rehearse else 16384
     dy, q, k, v = mk(t, h), mk(t, h), mk(t, hkv), mk(t, hkv)
     for w in (window, None):
-        ms, _, ops = _device_ms(
-            jax.jit(jax.grad(attend(w), (1, 2, 3), has_aux=True)),
-            (dy, q, k, v), 4, rehearse, "window")
-        log("[window] T %d, window %s: forward + backward %.3f ms a call "
-            "on the device (%s)" % (t, w, ms, ", ".join(
-                "%s %.3f" % kv for kv in ops.most_common(6))))
+        for two in (False, True):
+            with _backwards_lowered(fa) as ran, (
+                    _two_kernels(fa) if two else contextlib.nullcontext()):
+                call = jax.jit(jax.grad(attend(w), (1, 2, 3), has_aux=True))
+                ms, (got, _), ops = _device_ms(call, (dy, q, k, v), 4,
+                                               rehearse, "window")
+            if not two:
+                one = [f32(x) for x in got]
+            # the same sums in another order: bf16's last bit
+            apart = max(_far(got, one))
+            log("[window] T %d, window %s, backward %s: forward + backward "
+                "%.3f ms a call on the device (%s); %.3e from the ONE "
+                "kernel's gradients" % (
+                    t, w, "+".join(ran), ms,
+                    ", ".join("%s %.3f" % kv for kv in ops.most_common(6)),
+                    apart))
+            assert apart <= FLASH_GRAD_TOL, apart
 
 
 def phase_rotary(seed, rehearse):

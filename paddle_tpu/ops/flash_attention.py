@@ -4,10 +4,12 @@ The fused attention kernel the registry docstring promises: computes
 softmax(QK^T * scale [+ causal mask]) V without materializing the [T, T]
 score matrix in HBM. Forward keeps a running (max, denominator,
 accumulator) per query block while streaming key/value blocks through
-VMEM; backward recomputes probabilities from the saved log-sum-exp rows:
-in ONE kernel (flash_bwd) where a block holds all of T, each tile's s,
-p, dp and ds computed once and dq, dk, dv all made from them (PR 31),
-and in the standard two-kernel dq / dk+dv scheme where T is streamed.
+VMEM; backward recomputes probabilities from the saved log-sum-exp rows
+in ONE kernel (flash_bwd), each tile's s, p, dp and ds computed once and
+dq, dk, dv all made from them: where a block holds all of T (PR 31) and
+where T is streamed, dq for all rows held in VMEM across the key blocks
+(ISSUE 39). The standard two-kernel dq / dk+dv scheme is left for a
+score of two parts and for a T whose dq VMEM cannot hold.
 
 Reference capability: the reference's attention is composed matmul +
 softmax ops (nets.py:168 scaled_dot_product_attention,
@@ -59,19 +61,38 @@ rows along the lanes: [B*H, 1, T], g heads' rows to a grid step.
 from the rows of dy it holds and o as one more operand: XLA, asked for
 [B*H, 1, T] from [B, T, H*D] operands, first copies both whole into a
 T-minor layout (every formulation compiled for a described v5e did;
-PR 29). flash_bwd keeps it in VMEM; streamed, flash_bwd_dq hands it to
-flash_bwd_dkv as a row statistic.
+PR 29). flash_bwd keeps it in VMEM; in the two kernels flash_bwd_dq
+hands it to flash_bwd_dkv as a row statistic.
 
-The backward (PR 31) follows from the blocks alone (_backward_of; no
-flag). All of T in one block (`nq == nk == 1`: bf16 up to T 2048, the
-benchmark's cell, ring attention's shards that short): flash_bwd, grid
-(B * H / g,), walks by keys with the scores transposed, dv and dk
-finishing inside their panel and dq accumulated across panels in a
-float32 [T, W] scratch: five matmuls a tile where the two kernels ran
-seven, one exp a score, one pass over q, k, v, dy. Streamed (float32
-above T 1024, T 4096, blocks wider than 128 lanes above 512 rows): the
-two kernels as they were, bit for bit; fused, dq would have to be held
-across key blocks, and no cell measures such a shape yet.
+The backward (PR 31, ISSUE 39) follows from the shapes alone
+(_backward_of; no flag), and is ONE kernel named flash_bwd wherever dq
+can stay in VMEM: it walks by keys with the scores transposed and makes
+dv, dk AND dq from one s, p, dp and ds a tile (five matmuls where the
+two kernels run seven, one exp a score for two, one pass over q, k, v,
+dy); delta never goes through HBM. "fused" where one block holds all of
+T (bf16 up to T 2048, the cell opt350m_train, ring attention's shards
+that short): PR 31's kernel as it was, grid (B * H / g,), dv and dk
+finishing inside their panel and dq summed across panels in a float32
+[T, W] scratch. (The streamed kernel run at one block is the same
+device time alone and bit for bit the same gradients, but 0.18 ms a
+step slower inside opt350m_train's step, 0.1% of its tokens/s, my chip
+runs, PR 39: so PR 31's stays.) "fused_streamed" where T is streamed
+(float32 above T 1024, T 4096 and longer, blocks wider than 128 lanes
+above 512 rows, the own-block form's halves: the cells sdar_train_bd4k
+and trinity_train_T16k, a layer's backward 10.9 ms for the two kernels'
+15.6 and 36.7 for 51.2, my chip runs, PR 39): _bwd_one_kernel on
+flash_bwd_dkv's grid (B * H / g, nK, steps_q), dq for ALL rows of the
+block of heads in float32 scratch that stays in VMEM over both inner
+axes, scaled, cast and stored once, at the block of heads' last step;
+delta is made at a q block's first visit and kept in scratch. It asks
+for the scoped VMEM its shapes need (_one_kernel_vmem_bytes: at
+T 16,384 dq is 8 MB and its output block 4 MB twice, which the 16 MB
+default would not hold).
+"two_kernels", flash_bwd_dq then flash_bwd_dkv, each recomputing s and
+dp, where what would stay resident passes _RESIDENT_DQ_BYTES (T above
+32,768 at one head of 128 in bf16: ring attention's longest shards) and
+for a score of two parts, whose flash_bwd_dkv runs ALL heads under one
+resident block of k2.
 
 Dispatch: `flash_bthd(q, k, v, n_head, causal, scale)` uses the kernel
 on TPU and the dense jnp math elsewhere (CPU tests exercise the kernel
@@ -81,12 +102,12 @@ such a caller (parallel/ring.py) now pays the transposes the model used
 to pay. Each dispatch counts itself at trace time in
 `ptpu_flash_lowerings_total{path, entry, heads_per_block, backward,
 mask, kv_groups, key_width, value_width, second_part}` (backward:
-"fused" / "two_kernels", "none" on the dense path).
+"fused" / "fused_streamed" / "two_kernels", "none" on the dense path).
 
 A score of two parts (PR 34): `flash_bthd(..., q2=, k2=)` adds
 `q2_h k2^T` to head h's scores, k2 ONE key [B, T, D2] that every head
 reads (latent attention's rotary part): key D + D2 wide, value D. The
-same three streamed kernels under the same names take it on what they
+streamed forward and the two backward kernels take it on what they
 are handed (`part2`; see "A score of two parts" below); handed no
 second part they trace exactly what they traced before.
 
@@ -103,10 +124,11 @@ under the other forms every kernel traces what it traced before.
 
 A window bound (ISSUE 38): `flash_bthd(..., causal=True, window=w)`
 keeps, for query i, the keys j with `i - w < j <= i`: a band under the
-diagonal. The three streamed kernels (and the fused backward, where a
+diagonal. The streamed kernels (and the fused backward, where a
 block holds all of T) take it as a fourth mask form, (0, _WIN, w). What
-it saves is what is NOT walked: the grid's key axis (the q axis in
-flash_bwd_dkv) has only the band's steps, `ceil((w - 1) / block) + 1`,
+it saves is what is NOT walked: the grid's key axis (the q axis where
+the walk is by keys: flash_bwd, flash_bwd_dkv) has only the band's
+steps, `ceil((w - 1) / block) + 1`,
 and the index maps start it at the q block's own place, so a key block
 wholly under the band is neither fetched nor computed; a block wholly
 inside it is one unmasked batch of work; the blocks that the diagonal
@@ -172,6 +194,12 @@ DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 _AUTO_BLOCK = 1024              # streamed major block, rows
 _ONE_BLOCK_BYTES = 512 * 1024   # a [T, D] operand in VMEM this small: one block
+# dq of every row of a block of heads, float32, plus its output block
+# twice: what the one backward kernel may keep in VMEM from a block of
+# heads' first step to its last. A quarter of a v5e core's 128 MiB:
+# T 16,384 at one head of 128 in bf16 is 16 MiB, T 32,768 the last that
+# fits; longer (ring attention's longest shards) takes the two kernels.
+_RESIDENT_DQ_BYTES = 32 * 1024 * 1024
 _TILE = 256                     # a panel's rows on the diagonal
 _PANEL_SCORES = 1024 * 1024     # an unmasked panel's scores (4 MB in float32)
 
@@ -737,13 +765,15 @@ def _fwd_pallas(q, k, v, n_head, n_kv_head, mask, scale, block_q, block_k,
 # head: a kernel that holds the scores transposed broadcasts them down
 # the sublanes as they are.
 #
-# Which backward runs follows from the blocks alone (_backward_of). Where
-# one block holds all of T, ONE kernel, flash_bwd: a panel's s, p, dp and
-# ds are computed once and dq, dk and dv all come out of them, five
-# matmuls a tile. Streamed (several q or key blocks), the two kernels
-# below: flash_bwd_dq walks a q block's keys and flash_bwd_dkv a key
-# block's queries, each recomputing s and dp (seven matmuls for five
-# useful): fused, dq would have to be held across key blocks.
+# Which backward runs follows from the shapes alone (_backward_of). ONE
+# kernel, flash_bwd, wherever dq for all rows of a block of heads can
+# stay in VMEM: a panel's s, p, dp and ds are computed once and dq, dk
+# and dv all come out of them, five matmuls a tile: _bwd_fused_kernel
+# where one block holds all of T, _bwd_one_kernel where T is streamed.
+# Beyond its byte bound, and for a score of two parts, the two kernels:
+# flash_bwd_dq walks a q block's keys and flash_bwd_dkv a key block's
+# queries, each recomputing s and dp (seven matmuls for five useful, two
+# exp a score for one).
 def _delta(dy_ref, o_ref, dlse_ref, a, d, g):
     """Column [rows, 1] of head `a`'s delta for the block's rows, and
     where the head's lanes are in a [rows, W] tile. The lse output's
@@ -803,6 +833,111 @@ def _bwd_fused_kernel(*refs, mask, scale, t, tile, d, g, has_dlse):
              (dq_s[...] * scale).astype(dq_ref.dtype), all_mine)
 
     _each_head(g, head)
+
+
+def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
+                    blocks_q, d, g, has_dlse, band=None):
+    """flash_bwd, grid (B * H / g, nK, steps_q), the q blocks innermost:
+    one block of heads. Walks by keys with the scores transposed
+    [tk, tq], as flash_bwd_dkv does, so that dv = p^T dy and dk = ds^T q
+    are plain matmuls; the one product that wants the other orientation,
+    dq[rows] += ds k, takes ds^T turned round in float32 on its way to
+    the MXU (the transpose hides behind the matmuls: the product costs
+    what a plain one does, PERF.md section 6, PR 31) and accumulates in
+    a float32 scratch [q blocks, rows of a block, W] that holds every
+    row of the sequence and stays in VMEM over both inner grid axes.
+    dq's output block is all of T (its index map ignores the inner
+    axes) and is scaled, cast and stored at a block of heads' last
+    step. delta is made at a q block's FIRST visit (key block 0; under a
+    band also the last step of every later key block), where its slot
+    of dq is zeroed too, and kept for all rows in scratch: it never goes
+    through HBM. dk and dv are summed over a key block's q blocks in
+    float32 scratch; where a key block has ONE q block (all of the
+    queries in a block, the keys in several) they finish inside their
+    panel and there is no such scratch. `nq` is what the inner axis
+    counts (the band's steps under a window), `blocks_q` the q blocks
+    there are."""
+    q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
+    dlse_ref = refs[6] if has_dlse else None
+    if _own(mask):
+        # as in flash_bwd_dkv: the noised keys and values of the key
+        # block's rows, and their gradients, whole at the step i == j
+        (kn_ref, vn_ref, dq_ref, dk_ref, dv_ref, dkn_ref, dvn_ref,
+         *scratch) = refs[6 + has_dlse:]
+    else:
+        dq_ref, dk_ref, dv_ref, *scratch = refs[6 + has_dlse:]
+    dq_s, *dkv_s, delta_s = scratch
+    i, j = _block_ids(nq, nk, by_keys=True)
+    # the q block of this step, and whether no key block came to it yet
+    at, first = i, j == 0
+    if band:
+        at = jnp.minimum(i + j, blocks_q - 1)
+        first = (first | (i == nq - 1)) & (i + j < blocks_q)
+
+    if dkv_s:
+        dk_s, dv_s = dkv_s
+
+        @_when(i == 0)
+        def _init():
+            dk_s[:] = jnp.zeros_like(dk_s)
+            dv_s[:] = jnp.zeros_like(dv_s)
+
+    @_when(first)
+    def _first():
+        dq_s[at] = jnp.zeros(dq_s.shape[1:], dq_s.dtype)
+
+    def head(a):
+        @_when(first)
+        def _row():
+            delta, _ = _delta(dy_ref, o_ref, dlse_ref, a, d, g)
+            delta_s[at, a] = delta.T                 # [tq, 1] -> [1, tq]
+
+        def panel(cols, segments):
+            own = _own(segments[0][2])      # the noised keys' own panel
+            k = (kn_ref if own else k_ref)[0, cols, :]
+            mine = _lanes(k.shape, a, d, g)
+            # head a's lanes alone, so that its dq comes out in them and
+            # the sum over the heads of a block is the block's dq
+            k = _only(k, mine)
+            kk = k * scale
+            v = _only((vn_ref if own else v_ref)[0, cols, :], mine)
+            dk = dv = 0.0
+            for rows, off, how in segments:
+                q = q_ref[0, rows, :]
+                dy = dy_ref[0, rows, :]
+                st = _dot(kk, q, _NT)                # [tk, tq]
+                if off is not None:
+                    st = _causal(st, off, 1, how)
+                pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
+                dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
+                dst = pt * (_dot(v, dy, _NT) - delta_s[at, a, :, rows])
+                dk = dk + _dot(dst.astype(q.dtype), q, _NN)
+                dq_s[at, rows] = dq_s[at, rows] + _dot(
+                    dst.T.astype(k.dtype), k, _NN)
+            if own or not dkv_s:
+                to_k, to_v = (dkn_ref, dvn_ref) if own else (dk_ref, dv_ref)
+                whole = (0, cols, slice(None))
+                _put(to_k, whole, (dk * scale).astype(to_k.dtype), mine)
+                return _put(to_v, whole, dv.astype(to_v.dtype), mine)
+            dk_s[cols] = dk_s[cols] + _only(dk, mine)
+            dv_s[cols] = dv_s[cols] + _only(dv, mine)
+
+        _walk(panel, i, j, mask, block_q, block_k, tile, by_keys=True,
+              half=nq // 2, band=band)
+
+    _each_head(g, head)
+
+    if dkv_s:
+        @_when(i == nq - 1)
+        def _final():
+            dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+    @_when((i == nq - 1) & (j == nk - 1))
+    def _store_dq():
+        for n in range(blocks_q):
+            dq_ref[0, n * block_q:(n + 1) * block_q, :] = (
+                dq_s[n] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
@@ -985,13 +1120,44 @@ def _backward_blocks(t, w, block_q, block_k):
     return bq, bk
 
 
-def _backward_of(t, w, block_q, block_k, mask=None):
-    """"fused" where the backward's blocks hold all of T, else
-    "two_kernels": what _bwd_pallas runs and the lowering counter says.
-    The own-block form's rows are two halves, so never one block."""
-    return ("fused" if not _own(mask)
-            and _backward_blocks(t, w, block_q, block_k) == (t, t)
-            else "two_kernels")
+def _backward_of(t, w, block_q, block_k, mask=None, itemsize=2):
+    """Which backward a one-part call of `t` rows (a HALF of them under
+    the own-block form) runs, from its shapes alone: what _bwd_pallas is
+    told and the lowering counter says. The one kernel where what it
+    keeps in VMEM for all rows of a block of heads, dq in float32 and
+    its output block twice, is within _RESIDENT_DQ_BYTES: "fused" where
+    the backward's blocks hold all of T (always within: a block is no
+    larger than _ONE_BLOCK_BYTES; the own-block form's rows are two
+    halves, so never one block), "fused_streamed" where T is streamed;
+    else "two_kernels"."""
+    if not _own(mask) and _backward_blocks(t, w, block_q, block_k) == (t, t):
+        return "fused"
+    rows = 2 * t if _own(mask) else t
+    if rows * max(w, _LANES) * (4 + 2 * itemsize) <= _RESIDENT_DQ_BYTES:
+        return "fused_streamed"
+    return "two_kernels"
+
+
+def _one_kernel_vmem_bytes(t, w, bq, bk, g, itemsize, kv_itemsize, n_kv,
+                           n_stats):
+    """The scoped VMEM the streamed flash_bwd asks for, from its shapes:
+    dq for all rows in float32 and its output block, each operand and
+    output block twice (the pipeline's two buffers), the float32 scratch
+    of a key block's dk and dv, the row statistics (a [1, rows] float32
+    row takes eight sublanes), and four float32 copies of the largest
+    panel's scores for what is live inside it. The compiler took the
+    kernel with 6 MB for that last part at 1024 x 1024 panels (the least
+    limit it accepted, compiled for a described v5e: 28 MiB at T 16,384,
+    23 under the own-block form at 2 x 4,096, 15 at T 4,096; PR 39), and
+    a larger limit costs the kernel nothing: the same device time under
+    36 MiB and under 70."""
+    w = max(w, _LANES)
+    resident = t * w * (4 + 2 * itemsize)
+    blocks = 2 * w * itemsize * (3 * bq + n_kv * bk)
+    grads = n_kv * bk * w * 2 * kv_itemsize + 2 * bk * w * 4
+    stats = g * 8 * 4 * (2 * n_stats * bq + t)
+    panel = 4 * 4 * min(bq * bk, max(_PANEL_SCORES, _LANES * bk))
+    return resident + blocks + grads + stats + panel
 
 
 def _group_sum(dkv, group, d, dtype):
@@ -1008,9 +1174,19 @@ def _group_sum(dkv, group, d, dtype):
          for a in range(0, len(heads), group)], -1).astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
+def _backward_for(q, n_head, mask, block_q, block_k):
+    """_backward_of for the operand q [B, T, H*D] of a one-part call."""
+    t, hd = q.shape[1:]
+    d = hd // n_head
+    return _backward_of(t // 2 if _own(mask) else t,
+                        heads_per_block(n_head, d) * d, block_q, block_k,
+                        mask, q.dtype.itemsize)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8, 9))
 def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
-                interpret, dlse=None):
+                interpret, backward, dlse=None):
+    """`backward`: _backward_of's word for these shapes (_backward_for)."""
     q, k, v, o, lse = res
     b, t, hd = q.shape
     d = hd // n_head
@@ -1057,7 +1233,19 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
         keys_at = lambda s: jnp.maximum(s[1] - (steps_k - 1) + s[2], 0)
         rows_at = lambda s: jnp.minimum(s[1] + s[2], nq - 1)
 
-    if nq == nk == 1:       # _backward_of's "fused"
+    def halves(dk, dv, dkn):
+        if own:
+            # [noised; clean], as k and v came
+            return tuple(jnp.concatenate([summed(x), summed(y)], 1)
+                         for x, y in zip(dkn, (dk, dv)))
+        return summed(dk), summed(dv)
+
+    # PR 31's kernel, as it was. It lives in the compiler's default VMEM
+    # and must ask for no more: a limit over the 16 MiB default, asked of
+    # opt350m_train's one-block calls, cost the cell 0.7% (XLA stages
+    # fewer of the matmuls' operands in VMEM round such a call; my chip
+    # runs, PR 39).
+    if backward == "fused":
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, mask=mask, scale=scale,
                               t=t, tile=tile, d=d, g=g,
@@ -1073,6 +1261,33 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
             name="flash_bwd",
         )(q, k, v, dy, o, *stats)
         return dq, summed(dk), summed(dv)
+
+    if backward == "fused_streamed":
+        # grid (B * H / g, nK, steps_q): flash_bwd_dkv's, the q-side
+        # operands following the LAST axis, and dq's block all of T; one
+        # q block a key block needs no scratch for dk and dv
+        dq, dk, dv, *dkn = pl.pallas_call(
+            functools.partial(_bwd_one_kernel, mask=mask, scale=scale,
+                              block_q=bq, block_k=bk, nq=steps_q, nk=nk,
+                              blocks_q=nq, tile=tile, d=d, g=g,
+                              has_dlse=dlse is not None, band=band),
+            grid=(b * n_head // g, nk, steps_q),
+            in_specs=[rows(bq, rows_at), kv(bk, clean(1)), kv(bk, clean(1)),
+                      rows(bq, rows_at), rows(bq, rows_at)]
+            + [stat(bq, rows_at)] * len(stats) + noised_k,
+            out_specs=[rows(t)] + [rows(bk, 1)] * (2 + len(kn)),
+            out_shape=[bthd] + [dkv] * (2 + len(kn)),
+            scratch_shapes=[pltpu.VMEM((nq, bq, w), jnp.float32)]
+            + [pltpu.VMEM((bk, w), jnp.float32)] * (2 * (steps_q > 1))
+            + [pltpu.VMEM((nq, g, 1, bq), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_one_kernel_vmem_bytes(
+                    t, w, bq, bk, g, q.dtype.itemsize, dkv.dtype.itemsize,
+                    2 + len(kn), len(stats))),
+            interpret=interpret,
+            name="flash_bwd",
+        )(q, k, v, dy, o, *stats, *kn)
+        return (dq,) + halves(dk, dv, dkn)
 
     dq, delta3 = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
@@ -1109,12 +1324,7 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, dy, stats[0], delta3, *kn)
-    if own:
-        # [noised; clean], as k and v came
-        dk, dv = (jnp.concatenate([summed(x), summed(y)], 1)
-                  for x, y in zip(dkn, (dk, dv)))
-        return dq, dk, dv
-    return dq, summed(dk), summed(dv)
+    return (dq,) + halves(dk, dv, dkn)
 
 
 # --------------------------------------------------------------------------
@@ -1293,9 +1503,18 @@ def _flash_fwd(q, k, v, *static):
     return out, (q, k, v, out, lse)
 
 
+def _backward(static, res, dy, dlse=None):
+    """_bwd_pallas, told which backward these shapes take: decided here,
+    outside its jit, so that the choice is part of what the jit caches
+    by."""
+    n_head, _, mask, _, block_q, block_k, _ = static
+    return _bwd_pallas(res, dy, *static, _backward_for(
+        res[0], n_head, mask, block_q, block_k), dlse=dlse)
+
+
 def _flash_bwd(*args):
     *static, res, dy = args
-    return _bwd_pallas(res, dy, *static)
+    return _backward(static, res, dy)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1317,7 +1536,7 @@ def _flash_lse_fwd(q, k, v, *static):
 
 def _flash_lse_bwd(*args):
     *static, res, (dy, dlse) = args
-    return _bwd_pallas(res, dy, *static, dlse=dlse)
+    return _backward(static, res, dy, dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -1355,7 +1574,11 @@ _LOWERINGS = _REG.counter(
     "ptpu_flash_lowerings_total",
     "flash attention dispatches at trace time (one a lowering of the op, "
     "none a step): the path taken, the layout of the entry called, the "
-    "heads a kernel block holds, the backward its gradient would run, "
+    "heads a kernel block holds, the backward its gradient would run "
+    "(fused: one kernel, all of T in a block; fused_streamed: one "
+    "kernel too, T streamed and dq for all rows held in VMEM; two_kernels: "
+    "dq, then dk and dv, for a second score part or a T over that "
+    "kernel's byte bound; none: dense math), "
     "the mask (none, causal, block_causal, block_causal_strict, "
     "block_causal_own: block diffusion's [noised; clean] halves), the "
     "query heads that read one key/value head, a head's key and value "
@@ -1370,7 +1593,8 @@ _BAND_SCORES = _REG.counter(
     "trace time by each lowering that takes the kernels, a batch row "
     "and head each: kind computed (what the walk's blocks and panels "
     "hold, masked tiles whole) and useful (what the band holds), for the "
-    "forward walk and the backward's two (by queries and by keys)",
+    "forward walk and the backward's: by keys alone where one kernel runs "
+    "it, by queries and by keys where two do",
     ("window", "walk", "kind"))
 
 
@@ -1520,7 +1744,7 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     if v.shape[-1] != k.shape[-1]:      # the kernels' value is D wide
         path = "dense"
     backward = ("none" if path == "dense" else "two_kernels" if d2
-                else _backward_of(rows, g * d, bq, bk, mask))
+                else _backward_for(q, n_head, mask, bq, bk))
     _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
                    backward=backward, mask=mask_label,
                    kv_groups=str(n_head // n_kv_head),
@@ -1530,9 +1754,10 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
                    window=str(window or 0))
     if window and path != "dense":
         back = _backward_blocks(t, g * d, bq, bk)[0]
-        for walk, block, by_keys in (("forward", bq, False),
-                                     ("backward_by_queries", back, False),
-                                     ("backward_by_keys", back, True)):
+        walks = [("forward", bq, False), ("backward_by_keys", back, True)]
+        if backward == "two_kernels":       # the one kernel has no such walk
+            walks.append(("backward_by_queries", back, False))
+        for walk, block, by_keys in walks:
             for kind, scores in zip(("computed", "useful"), band_scores(
                     t, block, _tile(block, _TILE), window, by_keys)):
                 _BAND_SCORES.inc(scores, window=str(window), walk=walk,

@@ -669,13 +669,24 @@ def _streamed_grads(dtype, with_dlse):
     return jax.grad(f, (0, 1, 2)), (q, k, v)
 
 
+@pytest.fixture
+def two_kernels(monkeypatch):
+    """No shape is within the ONE streamed kernel's byte bound: what is
+    traced under this fixture streams through flash_bwd_dq and
+    flash_bwd_dkv, as every streamed shape did before ISSUE 39 and as a
+    T too long for the bound still does."""
+    monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+
+
 @pytest.mark.parametrize("with_dlse", [False, True], ids=["out", "out_lse"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_streamed_backward_is_the_parents_bit_for_bit(dtype, with_dlse):
-    """Several blocks a sequence: the backward is still flash_bwd_dq
-    then flash_bwd_dkv, and every bit of dq, dk, dv is what PR 31's
-    parent gave on these inputs."""
+def test_streamed_backward_is_the_parents_bit_for_bit(two_kernels, dtype,
+                                                      with_dlse):
+    """Several blocks a sequence, over the byte bound of the one
+    streamed kernel: the backward is still flash_bwd_dq then
+    flash_bwd_dkv, and every bit of dq, dk, dv is what PR 31's parent
+    gave on these inputs."""
     import hashlib
     grad, args = _streamed_grads(dtype, with_dlse)
     assert _pallas_names(jax.make_jaxpr(grad)(*args).jaxpr) \
@@ -686,20 +697,156 @@ def test_streamed_backward_is_the_parents_bit_for_bit(dtype, with_dlse):
     assert got == _PARENT_STREAMED[case], (case, got)
 
 
+@pytest.mark.parametrize("with_dlse", [False, True], ids=["out", "out_lse"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_one_streamed_kernel_is_the_two_kernels_sums(monkeypatch, dtype,
+                                                     with_dlse):
+    """The same inputs through the ONE streamed kernel (ISSUE 39): the
+    forward and flash_bwd, and dq, dk, dv the two kernels' sums in
+    another order: float32's rounding apart in float32, a bf16 step of
+    the largest value in bf16 (each gradient is rounded once, at its
+    store)."""
+    grad, args = _streamed_grads(dtype, with_dlse)
+    assert _pallas_names(jax.make_jaxpr(grad)(*args).jaxpr) \
+        == ["flash_fwd", "flash_bwd"]
+    got = grad(*args)
+    monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+    for name, a, b in zip(("dq", "dk", "dv"), got, grad(*args)):
+        assert a.dtype == dtype
+        _assert_close(name, a, _f32(b), 1e-6 if dtype == jnp.float32
+                      else 8e-3)
+
+
+# the forms the ONE streamed kernel walks, as flash_bthd's arguments
+# beside (H, Hkv, D, T, block_q, block_k): T in several major blocks
+_STREAMED = [
+    pytest.param(2, 2, 128, 512, 128, 128, dict(causal=False), id="full"),
+    pytest.param(2, 2, 128, 512, 128, 128, dict(causal=True), id="causal"),
+    pytest.param(4, 4, 64, 512, 128, 128, dict(causal=True),
+                 id="causal-two_heads_of_64_to_a_block"),
+    pytest.param(3, 3, 64, 512, 256, 256, dict(causal=True),
+                 id="causal-all_of_H_192_lanes"),
+    pytest.param(2, 1, 128, 768, 256, 128, dict(causal=True),
+                 id="causal-nq3_nk6"),
+    pytest.param(2, 1, 128, 768, 128, 384, dict(causal=True),
+                 id="causal-nq6_nk2"),
+    pytest.param(2, 1, 128, 512, 512, 128, dict(causal=False),
+                 id="full-nq1_nk4"),
+    pytest.param(8, 1, 128, 512, 128, 128, dict(causal=True, mask_block=32),
+                 id="block_causal-group8"),
+    pytest.param(8, 1, 128, 512, 128, 128,
+                 dict(causal=True, mask_block=4, strict=True),
+                 id="block_causal_strict-group8"),
+    pytest.param(8, 1, 128, 512, 128, 128,
+                 dict(causal=True, mask_block=4, own_block=True),
+                 id="own_block-group8"),
+    pytest.param(8, 1, 128, 1024, 256, 256,
+                 dict(causal=True, mask_block=128, own_block=True),
+                 id="own_block-panels-group8"),
+]
+
+
+@pytest.mark.parametrize("h, hkv, d, t, bq, bk, form", _STREAMED)
+@pytest.mark.parametrize("backward", ["fused_streamed", "two_kernels"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_streamed_backward_matches_dense(monkeypatch, dtype, backward, h,
+                                         hkv, d, t, bq, bk, form):
+    """dq, dk, dv of a streamed T against dense float32 math with the
+    mask written out, through the ONE kernel (dq for all rows in VMEM
+    scratch across the key blocks, delta made at a q block's first
+    visit) and, the byte bound set to nothing, through the two kernels
+    it replaced: every mask form, grouped heads 8:1, two heads to a
+    block and all of H, unequal counts of q and key blocks, and an lse
+    cotangent where the form gives an lse."""
+    monkeypatch.setattr(FA, "_TILE", 128)
+    if backward == "two_kernels":
+        monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+    own = form.get("own_block", False)
+    q, k, v, dy, dlse = _gqa_inputs(h, hkv, d, t, dtype, seed=17)
+    kw = dict(force="interpret", block_q=bq, block_k=bk, n_kv_head=hkv,
+              **form)
+    mask_block, strict = form.get("mask_block", 1), form.get("strict", False)
+
+    def dense(q, k, v):
+        if not form["causal"]:
+            o, lse = FA._dense_lse(
+                FA.heads_first(q, h), FA.heads_first(k, hkv),
+                FA.heads_first(v, hkv), False, d ** -0.5)
+            return FA.heads_last(o), lse, jnp.ones((t,), bool)
+        return _dense_block_causal(q, k, v, h, hkv, mask_block, strict, own)
+
+    seen = dense(q, k, v)[2]
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            w = seen.astype(jnp.float32)
+            out = (_f32(o) * _f32(dy) * w[None, :, None]).sum()
+            return out if own else out + (
+                jnp.where(seen, lse, 0.0) * dlse).sum()
+        return f
+
+    def run(q, k, v):
+        if own:                 # the form gives no lse
+            return FA.flash_bthd(q, k, v, h, **kw), None
+        return FA.flash_bthd_lse(q, k, v, h, **kw)
+
+    grad = jax.grad(loss(run), (0, 1, 2))
+    assert _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr) == [
+        "flash_fwd"] + {"fused_streamed": ["flash_bwd"], "two_kernels": [
+            "flash_bwd_dq", "flash_bwd_dkv"]}[backward]
+    want = jax.grad(loss(lambda *a: dense(*a)[:2]), (0, 1, 2))(
+        _f32(q), _f32(k), _f32(v))
+    tol = 5e-3 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(("dq", "dk", "dv"), grad(q, k, v), want):
+        assert a.shape == b.shape and a.dtype == dtype
+        assert bool(jnp.isfinite(_f32(a)).all()), name
+        _assert_close(name, a, b, tol)
+
+
 @pytest.mark.parametrize("t, w, block, want", [
     (2048, 128, 2048, "fused"),          # the benchmark's cell
     (512, 128, 1024, "fused"),           # a block no longer than T
-    (4096, 128, 1024, "two_kernels"),    # OLMoE's: streamed
-    (2048, 128, 1024, "two_kernels"),    # float32 at T 2048
+    (4096, 128, 1024, "fused_streamed"),     # OLMoE's: streamed
+    (2048, 128, 1024, "fused_streamed"),     # float32 at T 2048
     (512, 192, 512, "fused"),            # all of H*D, 192 lanes
-    (1024, 192, 1024, "two_kernels"),    # the same clamped to 512 rows
+    (1024, 192, 1024, "fused_streamed"),     # the same clamped to 512 rows
+    (16384, 128, 1024, "fused_streamed"),    # Trinity's: 16 MiB resident
+    (32768, 128, 1024, "fused_streamed"),    # the last T within the bound
+    (65536, 128, 1024, "two_kernels"),   # ring attention's longest shards
+    (16384, 512, 512, "two_kernels"),    # all of H*D, 512 lanes: 64 MiB
 ])
 def test_backward_follows_from_the_blocks(t, w, block, want):
     """One kernel exactly where the backward's blocks, after the VMEM
-    clamp of wide blocks, hold all of T: no flag decides it."""
+    clamp of wide blocks, hold all of T; the ONE streamed kernel where
+    dq for all rows of a block of heads (float32, and its output block
+    twice) is within _RESIDENT_DQ_BYTES; the two kernels beyond: no
+    flag decides it."""
     assert FA._backward_of(t, w, block, block) == want
     bq, bk = FA._backward_blocks(t, w, block, block)
     assert t % bq == 0 and t % bk == 0
+
+
+def test_the_streamed_kernels_bound_counts_what_stays_in_vmem():
+    """The byte bound is read off the shapes: float32 operands keep a
+    float32 output block (12 bytes a resident element for bf16's 8),
+    the own-block form's rows are both halves, and the scoped VMEM the
+    kernel asks for covers what is resident and stays under a v5e
+    core's 128 MiB at the largest shape the bound lets through."""
+    assert FA._backward_of(32768, 128, 1024, 1024, itemsize=2) \
+        == "fused_streamed"
+    assert FA._backward_of(32768, 128, 1024, 1024, itemsize=4) \
+        == "two_kernels"
+    own = (4, FA._OWN)
+    assert FA._backward_of(16384, 128, 1024, 1024, own) == "fused_streamed"
+    assert FA._backward_of(32768, 128, 1024, 1024, own) == "two_kernels"
+    assert FA._backward_of(256, 128, 256, 256, own) == "fused_streamed"
+    for t, itemsize in ((32768, 2), (16384, 4), (4096, 2)):
+        asked = FA._one_kernel_vmem_bytes(t, 128, 1024, 1024, 1, itemsize, 4,
+                                        4, 2)
+        assert t * 128 * (4 + 2 * itemsize) < asked <= 120 * 1024 * 1024
 
 
 def test_lowering_counter_says_which_path_engaged():
@@ -708,7 +855,8 @@ def test_lowering_counter_says_which_path_engaged():
     forward's trace; none a step), `dense` off the chip, where no
     backward kernel will run; the [B, H, T, D] wrappers count as `bhtd`;
     `backward` says which backward the lowering's gradient takes: the
-    one fused kernel where a block holds all of T, else the two."""
+    one fused kernel where a block holds all of T, the ONE streamed
+    kernel where dq for all rows fits its byte bound, else the two."""
     import paddle_tpu as fluid
     n_layer = 3
     prog, startup, cost, _ = _fused_lm(True, n_layer=n_layer)
@@ -733,14 +881,20 @@ def test_lowering_counter_says_which_path_engaged():
         assert count.value(**labels) - before == lowered
     assert lowered == n_layer
     q, k, v = _qkv(b=1, h=2, t=256, d=64)
-    for block, backward in ((None, "fused"), (128, "two_kernels")):
+    for block, bound, backward in ((None, None, "fused"),
+                                   (128, None, "fused_streamed"),
+                                   (128, 0, "two_kernels")):
         labels = dict(path="interpret", entry="bhtd", heads_per_block="2",
                       backward=backward, mask="causal", kv_groups="1",
                       key_width="64", value_width="64", second_part="none", window="0")
         was = count.value(**labels)
-        FA.flash_attention(q, k, v, causal=True, force="interpret",
-                           block_q=block, block_k=block)
+        with pytest.MonkeyPatch.context() as patch:
+            if bound is not None:
+                patch.setattr(FA, "_RESIDENT_DQ_BYTES", bound)
+            FA.flash_attention(q, k, v, causal=True, force="interpret",
+                               block_q=block, block_k=block)
         assert count.value(**labels) == was + 1
+    assert "fused_streamed" in count.help
     assert "ptpu_flash_lowerings_total" in \
         fluid.monitor.metrics.registry().render_prometheus()
 
@@ -896,8 +1050,8 @@ def test_own_block_form_matches_dense(dtype, h, hkv, mask_block, seq, block):
     written out: out, dq, and dk, dv of both halves, summed over each
     group. A half in one block (one masked panel; forward grid
     (., 2, 1)) and in two streamed blocks of 512 (two panels of 256
-    each on the diagonal); the backward is the two kernels either way,
-    since the rows are never one block."""
+    each on the diagonal); the backward is the ONE streamed kernel
+    either way (ISSUE 39), since the rows are never one block."""
     d = 128
     q, k, v, dy, _ = _gqa_inputs(h, hkv, d, 2 * seq, dtype, seed=3)
     kw = dict(causal=True, force="interpret", block_q=block, block_k=block,
@@ -912,7 +1066,7 @@ def test_own_block_form_matches_dense(dtype, h, hkv, mask_block, seq, block):
     loss = lambda fn: lambda *a: (_f32(fn(*a)) * _f32(dy)).sum()
     grad = jax.grad(loss(run), (0, 1, 2))
     assert _pallas_names(jax.make_jaxpr(grad)(q, k, v).jaxpr) \
-        == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+        == ["flash_fwd", "flash_bwd"]
     want = jax.grad(loss(dense), (0, 1, 2))(_f32(q), _f32(k), _f32(v))
     for name, a, b in zip(("dq", "dk", "dv"), grad(q, k, v), want):
         assert a.shape == b.shape and a.dtype == dtype
@@ -943,14 +1097,14 @@ def test_own_blocks_first_noised_rows_see_themselves_alone():
 
 def test_own_block_form_counts_itself_and_goes_dense_where_it_must():
     """The counter's `mask` label reads `block_causal_own`, the backward
-    `two_kernels` also where a half is one block; what the kernels
+    `fused_streamed` also where a half is one block; what the kernels
     cannot take (unequal blocks, two heads of 64 under grouped keys) is
     the dense mask; `strict`, a full mask or an odd count of rows with
     the form is a ValueError."""
     count = FA._LOWERINGS
     q, k, v, _, _ = _gqa_inputs(4, 2, 128, 512, jnp.float32)
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
-                  backward="two_kernels", mask="block_causal_own",
+                  backward="fused_streamed", mask="block_causal_own",
                   kv_groups="2", key_width="128", value_width="128",
                   second_part="none", window="0")
     was = count.value(**labels)

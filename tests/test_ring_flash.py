@@ -40,11 +40,28 @@ def test_lse_matches_dense(causal):
                                atol=2e-3, rtol=2e-2)
 
 
-@pytest.mark.parametrize("t", [128, 256], ids=["fused", "two_kernels"])
-def test_lse_cotangent_matches_dense(t):
+_BACKWARDS = [(1, "fused"), (2, "fused_streamed"), (2, "two_kernels")]
+
+
+def _backward(monkeypatch, t, backward):
+    """Pin what follows from the shapes: `backward` is what a T of `t`
+    rows in blocks of 128 takes, the two kernels with the ONE streamed
+    kernel's byte bound set to nothing (a T too long for it)."""
+    if backward == "two_kernels":
+        monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+    assert FA._backward_of(t, 128, 128, 128, itemsize=4) == backward
+
+
+@pytest.mark.parametrize("blocks, backward", _BACKWARDS,
+                         ids=[b for _, b in _BACKWARDS])
+def test_lse_cotangent_matches_dense(monkeypatch, blocks, backward):
     # loss uses BOTH outputs so the dlse→ds backward fold is exercised:
     # in the one backward kernel where a shard's T is one block of 128
-    # (delta made and kept inside it), in flash_bwd_dq where it is two
+    # (delta made and kept inside it), in the ONE streamed kernel where
+    # it is two (delta made at a q block's first visit), in flash_bwd_dq
+    # where that kernel's bound does not hold the shard
+    t = 128 * blocks
+    _backward(monkeypatch, t, backward)
     q, k, v = _qkv(t=t, seed=1)
 
     def loss_fn(att):
@@ -99,10 +116,14 @@ def test_ring_with_kernel_forced_matches_dense(monkeypatch):
                                atol=3e-3, rtol=3e-2)
 
 
-@pytest.mark.parametrize("t", [256, 512], ids=["fused", "two_kernels"])
-def test_ring_grads_with_kernel_forced(monkeypatch, t):
+@pytest.mark.parametrize("blocks, backward", _BACKWARDS,
+                         ids=[b for _, b in _BACKWARDS])
+def test_ring_grads_with_kernel_forced(monkeypatch, blocks, backward):
     # a shard of 128 rows is one kernel block: the fused backward, with
-    # the merge's non-zero lse cotangent; one of 256 is two: streamed
+    # the merge's non-zero lse cotangent; one of 256 is two: streamed,
+    # through the ONE kernel and through the two
+    t = 2 * 128 * blocks
+    _backward(monkeypatch, t // 2, backward)
     q, k, v = _qkv(t=t, seed=2)
     mesh = _sp_mesh()
 

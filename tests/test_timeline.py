@@ -287,15 +287,21 @@ def _pallas_names(jaxpr):
     return names
 
 
-def test_kernels_carry_their_names():
+def test_kernels_carry_their_names(monkeypatch):
+    from paddle_tpu.ops import flash_attention as fa
     from paddle_tpu.ops.flash_attention import flash_attention
     from paddle_tpu.ops.paged_attention import paged_attention
     q = jnp.ones((1, 2, 256, 64), jnp.float32)
 
-    # all of T in one block: one backward kernel; streamed: the two
-    for block, names in ((None, ["flash_bwd", "flash_fwd"]),
-                         (128, ["flash_bwd_dkv", "flash_bwd_dq",
-                                "flash_fwd"])):
+    # all of T in one block, and streamed within the one kernel's byte
+    # bound: one backward kernel; streamed beyond the bound: the two
+    for block, bound, names in (
+            (None, None, ["flash_bwd", "flash_fwd"]),
+            (128, None, ["flash_bwd", "flash_fwd"]),
+            (128, 0, ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"])):
+        if bound is not None:
+            monkeypatch.setattr(fa, "_RESIDENT_DQ_BYTES", bound)
+
         def loss(q, k, v):
             return flash_attention(q, k, v, causal=True, force="interpret",
                                    block_q=block, block_k=block).sum()

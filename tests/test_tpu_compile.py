@@ -28,6 +28,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
+from paddle_tpu.ops import flash_attention as FA  # noqa: E402
 from paddle_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention, flash_bthd)
 from paddle_tpu.ops.paged_attention import paged_attention  # noqa: E402
@@ -88,20 +89,26 @@ def test_flash_attention_compiles_for_v5e(chip, shape, direction):
 # backward's kernels (PR 31). The benchmark's cell, two heads of 64 to
 # a block and all of T in it: one backward kernel; the same in float32,
 # where T 2048 is two blocks, and OLMoE's shape, one head of 128 to a
-# block, T 4096 streamed: the two kernels.
-_TWO = ["flash_bwd_dq", "flash_bwd_dkv"]
-_BTHD = [pytest.param(4, 2048, 16, 64, jnp.bfloat16, ["flash_bwd"],
+# block, T 4096 streamed: ONE kernel too since ISSUE 39, dq for all rows
+# held in VMEM; and OLMoE's shape over that kernel's byte bound (set to
+# nothing here; on the chip a T above 32,768): the two kernels.
+_ONE, _TWO = ["flash_bwd"], ["flash_bwd_dq", "flash_bwd_dkv"]
+_BTHD = [pytest.param(4, 2048, 16, 64, jnp.bfloat16, _ONE,
                       id="opt350m_cell"),
-         pytest.param(4, 2048, 16, 64, jnp.float32, _TWO,
+         pytest.param(4, 2048, 16, 64, jnp.float32, _ONE,
                       id="opt350m_cell_f32"),
+         pytest.param(2, 4096, 16, 128, jnp.bfloat16, _ONE,
+                      id="olmoe_T4k_dk128"),
          pytest.param(2, 4096, 16, 128, jnp.bfloat16, _TWO,
-                      id="olmoe_T4k_dk128")]
+                      id="olmoe_T4k_dk128_over_the_bound")]
 
 
 @pytest.mark.parametrize("b, t, h, d, dtype, backward", _BTHD)
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_flash_bthd_compiles_for_v5e(chip, b, t, h, d, dtype, backward,
-                                     direction):
+def test_flash_bthd_compiles_for_v5e(chip, monkeypatch, b, t, h, d, dtype,
+                                     backward, direction):
+    if backward == _TWO:
+        monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
     q = jax.ShapeDtypeStruct((b, t, h * d), dtype, sharding=chip)
 
     def fwd(q, k, v):
@@ -116,6 +123,24 @@ def test_flash_bthd_compiles_for_v5e(chip, b, t, h, d, dtype, backward,
     assert text.count("tpu_custom_call") == len(names)
     for name in names:
         assert "%" + name + "." in text or "%" + name + " " in text
+
+
+@pytest.mark.parametrize("t, asks", [(2048, False), (4096, True)],
+                         ids=["one_block", "streamed"])
+def test_only_the_streamed_backward_asks_for_scoped_vmem(chip, t, asks):
+    """All of T in one block lives in the compiler's default, as PR 31's
+    kernel did: a call that asks for more than the default, by however
+    little, loses the matmuls round it their operands staged in VMEM
+    (opt350m_train: 0.7% of a step, PR 39). Streamed, dq for all rows is
+    resident and the kernel asks for what its shapes need."""
+    q = jax.ShapeDtypeStruct((2, t, 16 * 64), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return flash_bthd(q, k, v, 16, causal=True,
+                          force="pallas").astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).as_text()
+    assert ("scoped_memory_configs" in text) == asks
 
 
 def test_nothing_moves_a_head_between_a_projection_and_the_kernels(chip):
@@ -218,11 +243,13 @@ def test_paged_attention_compiles_for_v5e(chip, pool_dtype, rows_c, dk,
 
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd", "flash_bwd_dq",
                                     "flash_bwd_dkv", "paged_decode"])
-def test_kernel_name_is_in_the_lowered_text(chip, kernel):
+def test_kernel_name_is_in_the_lowered_text(chip, monkeypatch, kernel):
     """The name a profile of the chip shows for each kernel (ISSUE 24):
     the ``kernel_name`` of its ``tpu_custom_call`` in the text lowered
     for the v5e. The one backward kernel where T 1024 is one block
-    (bf16), the two where it is streamed (T 4096)."""
+    (bf16), the two where it is streamed (T 4096) over the ONE streamed
+    kernel's byte bound."""
+    monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
@@ -248,8 +275,8 @@ def test_kernel_name_is_in_the_lowered_text(chip, kernel):
 
 
 # ISSUE 32: the block-diffusion cell's shapes. 32 query heads of 128
-# reading 4 key/value heads at T 4096 (streamed 1024-blocks, the two
-# backward kernels), under the three forms of the block-granular mask; the
+# reading 4 key/value heads at T 4096 (streamed 1024-blocks, ONE backward
+# kernel since ISSUE 39), under the three forms of the block-granular mask; the
 # attention of the whole objective (since ISSUE 37 the third form, one call
 # of each kernel over [noised; clean] rows and nothing outside them); and
 # the dropless expert layer at 16,384 rows over 16 held of 128 experts,
@@ -273,7 +300,7 @@ def test_grouped_kv_block_causal_compiles_for_v5e(chip, form, direction):
 
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
     text = _compiled_text(fn, q, kv, kv)
-    names = ["flash_fwd"] + (_TWO if direction == "bwd" else [])
+    names = ["flash_fwd"] + (_ONE if direction == "bwd" else [])
     assert text.count("tpu_custom_call") == len(names)
     for name in names:
         assert "%" + name + "." in text or "%" + name + " " in text
@@ -281,7 +308,8 @@ def test_grouped_kv_block_causal_compiles_for_v5e(chip, form, direction):
 
 def test_block_diffusion_attention_compiles_for_v5e(chip):
     """[noised; clean] rows of one step's two sequences, forward and
-    backward: ONE call of each kernel (ISSUE 37: the own-block form) and
+    backward: ONE call of each kernel (ISSUE 37: the own-block form;
+    ISSUE 39: the backward is one kernel) and
     no [T, T] tensor: the largest float32 buffer the program names is an
     operand's size. Nothing of q's size is made outside the kernels,
     forward or backward: no dot, slice, concatenate, pad or transpose
@@ -302,8 +330,8 @@ def test_block_diffusion_attention_compiles_for_v5e(chip):
             jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count("tpu_custom_call") == 3
-    for name in ["flash_fwd"] + _TWO:
+    assert text.count("tpu_custom_call") == 2
+    for name in ["flash_fwd"] + _ONE:
         assert "%" + name + "." in text or "%" + name + " " in text
     size = lambda dims: math.prod(int(x) for x in dims.split(","))
     assert max(size(dims) for dims in re.findall(r"f32\[([\d,]+)\]", text)
@@ -380,7 +408,8 @@ def test_nothing_relays_q_or_k_between_a_projection_and_the_kernels(chip):
         text = _compiled_text(grad(fused), *avals)
         assert calls(text, "qk_norm_rope_fwd") == n
         assert calls(text, "qk_norm_rope_bwd") == n
-        assert text.count("tpu_custom_call") == 2 * n + 3
+        # the flash forward and, since ISSUE 39, ONE flash backward
+        assert text.count("tpu_custom_call") == 2 * n + 2
         assert not moved(text, b * t * h * d)
         assert set(moved(text)) == {"pad"} == set(bare)
         assert len(moved(text)) <= len(bare)
@@ -479,8 +508,8 @@ def test_two_part_score_compiles_for_v5e(chip, direction):
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_windowed_attention_compiles_for_v5e(chip, direction):
     """q [1, 16384, 4096] against k, v [1, 16384, 512] under a window of
-    2048: the same three kernels, and no [T, T] value: the largest
-    float32 buffer the program names is an operand's size."""
+    2048: the forward and ONE backward kernel, and no [T, T] value: the
+    largest buffer the program names is an operand's size."""
     import math
     import re
     b, t, h, hkv, d = 1, 16384, 32, 4, 128
@@ -496,10 +525,62 @@ def test_windowed_attention_compiles_for_v5e(chip, direction):
 
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
     text = _compiled_text(fn, q, kv, kv)
-    names = ["flash_fwd"] + (_TWO if direction == "bwd" else [])
+    names = ["flash_fwd"] + (_ONE if direction == "bwd" else [])
     assert text.count("tpu_custom_call") == len(names)
     for name in names:
         assert "%" + name + "." in text or "%" + name + " " in text
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
+               ) <= b * t * h * d
+
+
+# ISSUE 39: the streamed backward as ONE kernel, at the two cells' shapes
+# that stream T: block diffusion's [noised; clean] rows (8,192 a sequence)
+# and one packed sequence of 16,384 rows, plain causal (Trinity's full
+# layer) and under a window of 2048 (its four window layers).
+@pytest.mark.parametrize("b, t, form", [
+    (2, 8192, {"mask_block": 4, "own_block": True}),
+    (1, 16384, {}), (1, 16384, {"window": 2048})],
+    ids=["own_block_2x8192", "causal_16384", "window_2048_of_16384"])
+def test_one_streamed_backward_kernel_compiles_for_v5e(chip, b, t, form):
+    """32 query heads of 128 reading 4 key/value heads, forward and
+    backward: the compiler takes the backward with the scoped VMEM it
+    asks for (dq for all rows in float32 and its output block twice pass
+    the 16 MB default: 4 + 2 x 2 MB at 8,192 rows, 8 + 2 x 4 at 16,384),
+    which is what its shapes say and well under a core's 128 MiB; ONE
+    backward custom call, named flash_bwd, whose results are dq, dk, dv
+    (the noised halves' dk, dv under the own-block form) and no row
+    statistic: the only [B*H, 1, T] value in the program is the lse the
+    forward hands it, no delta goes through HBM; and no [T, T] value."""
+    import math
+    import re
+    h, hkv, d = 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return flash_bthd(q, k, v, h, causal=True, force="pallas",
+                          n_kv_head=hkv, **form).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
+    asked = [int(x) for x in re.findall(
+        r'scoped_memory_configs[^\]]*?size\\22: (\d+)', lowered.as_text())]
+    rows_k = t // 2 if "own_block" in form else t
+    assert asked == [FA._one_kernel_vmem_bytes(
+        t, d, 1024, 1024, 1, 2, 4, 4 if "own_block" in form else 2, 1)]
+    assert 16 * 2 ** 20 < asked[0] < 100 * 2 ** 20
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    calls = dict(re.findall(r"%(flash_\w+?)(?:\.\d+)? = (.*?) custom-call\(",
+                            text))
+    assert sorted(calls) == ["flash_bwd", "flash_fwd"]
+    stat = "f32[%d,1,%d]" % (b * h, t)
+    assert stat in calls["flash_fwd"] and stat not in calls["flash_bwd"]
+    assert len(re.findall(r"\w+\[[\d,]+\]", calls["flash_bwd"])) == (
+        5 if "own_block" in form else 3)
+    assert "f32[%d,%d,%d]" % (b, rows_k, h * d) in calls["flash_bwd"]
+    made = set(re.findall(r"= " + re.escape(stat) + r"\S* ([\w-]+)\(", text))
+    assert made <= {"get-tuple-element"}, made     # flash_fwd's lse alone
     size = lambda dims: math.prod(int(x) for x in dims.split(","))
     assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
                ) <= b * t * h * d

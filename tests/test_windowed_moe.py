@@ -61,17 +61,30 @@ _WINDOWS = [
     (1024, 512, 300, "panels_of_256"), (512, None, 200, "all_of_t_one_block")]
 
 
+@pytest.fixture(params=["fused_streamed", "two_kernels"])
+def streamed_backward(request, monkeypatch):
+    """What a streamed T's backward runs: the ONE kernel (ISSUE 39), or,
+    its byte bound set to nothing, the two it replaced (a T too long
+    for the bound keeps them)."""
+    if request.param == "two_kernels":
+        monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+    return {"fused_streamed": ["flash_bwd"],
+            "two_kernels": ["flash_bwd_dq", "flash_bwd_dkv"]}[request.param]
+
+
 @pytest.mark.parametrize("t, block, window", [w[:3] for w in _WINDOWS],
                          ids=[w[3] for w in _WINDOWS])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_windowed_kernels_match_the_band_written_out(dtype, t, block,
+def test_windowed_kernels_match_the_band_written_out(streamed_backward,
+                                                     dtype, t, block,
                                                      window):
     """4 query heads of 128 reading ONE key/value head under a window,
     in interpret mode against dense float32 math with the band written
     out: out, dq, and dk, dv summed over the group; the dense form
-    (the CPU path) beside them. Streamed, the three kernels; all of T
-    in one block, the fused backward."""
+    (the CPU path) beside them. Streamed, the forward and ONE backward
+    kernel, or the two beyond its bound; all of T in one block, the
+    fused backward."""
     h, hkv, d = 4, 1, 128
     q, k, v, dy = _qkv(t, h, hkv, d, dtype, seed=t + window)
     kw = dict(causal=True, block_q=block, block_k=block, n_kv_head=hkv,
@@ -92,8 +105,8 @@ def test_windowed_kernels_match_the_band_written_out(dtype, t, block,
     grad = jax.grad(loss(run), (0, 1, 2))
     names = [eqn.params["name"] for eqn in _pallas_eqns(
         jax.make_jaxpr(grad)(q, k, v).jaxpr)]
-    assert names == (["flash_fwd", "flash_bwd"] if block is None else
-                     ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])
+    assert names == ["flash_fwd"] + (["flash_bwd"] if block is None
+                                     else streamed_backward)
     truth = jax.grad(loss(want), (0, 1, 2))(f32(q), f32(k), f32(v))
     for name, a, b, c in zip(("dq", "dk", "dv"), grad(q, k, v), truth,
                              jax.grad(loss(dense), (0, 1, 2))(q, k, v)):
@@ -110,11 +123,12 @@ def _pallas_eqns(jaxpr):
             yield from _pallas_eqns(sub)
 
 
-def test_the_grids_key_axis_holds_the_bands_steps_alone():
+def test_the_grids_key_axis_holds_the_bands_steps_alone(streamed_backward):
     """T 2048 in blocks of 256 under a window of 512: a q block's band
     is its own block and the two before it, so the forward's grid is
-    (heads, 8, 3) where causal's is (heads, 8, 8), the backward's two
-    likewise; with an lse output the same kernels."""
+    (heads, 8, 3) where causal's is (heads, 8, 8), the backward's
+    (the ONE kernel's, by keys; the two kernels') likewise; with an lse
+    output the same kernels."""
     h, t, d = 2, 2048, 128
     q = jnp.zeros((1, t, h * d), jnp.float32)
     grids = lambda **kw: [
@@ -122,10 +136,11 @@ def test_the_grids_key_axis_holds_the_bands_steps_alone():
             jax.make_jaxpr(jax.grad(lambda q, k, v: FA.flash_bthd(
                 q, k, v, h, causal=True, force="interpret", block_q=256,
                 block_k=256, **kw).sum(), (0, 1, 2)))(q, q, q).jaxpr)]
-    assert grids() == [(h, 8, 8)] * 3
-    assert grids(window=512) == [(h, 8, 3)] * 3
-    assert grids(window=514) == [(h, 8, 4)] * 3
-    assert grids(window=2048) == [(h, 8, 8)] * 3       # plain causal
+    calls = 1 + len(streamed_backward)
+    assert grids() == [(h, 8, 8)] * calls
+    assert grids(window=512) == [(h, 8, 3)] * calls
+    assert grids(window=514) == [(h, 8, 4)] * calls
+    assert grids(window=2048) == [(h, 8, 8)] * calls       # plain causal
     o, lse = FA.flash_bthd_lse(_r(1, 512, h * d), _r(1, 512, h * d, seed=1),
                                _r(1, 512, h * d, seed=2), h, causal=True,
                                force="interpret", block_q=128, block_k=128,
@@ -170,12 +185,15 @@ def test_a_window_counts_itself_and_composes_with_nothing_else():
     h, d, t = 2, 128, 512
     q, k, v, _ = _qkv(t, h, h, d, jnp.float32, seed=5)
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
-                  backward="two_kernels", mask="causal", kv_groups="1",
+                  backward="fused_streamed", mask="causal", kv_groups="1",
                   key_width="128", value_width="128", second_part="none")
     count = lambda w: FA._LOWERINGS.value(window=str(w), **labels)
-    scores = lambda kind: FA._BAND_SCORES.value(
-        window="200", walk="forward", kind=kind)
+    scores = lambda kind, walk="forward": FA._BAND_SCORES.value(
+        window="200", walk=walk, kind=kind)
+    walks = lambda: [scores("computed", "backward_by_" + by)
+                     for by in ("queries", "keys")]
     was = count(200), count(0), scores("computed"), scores("useful")
+    walked = walks()
     kw = dict(causal=True, force="interpret", block_q=128, block_k=128)
     FA.flash_bthd(q, k, v, h, window=200, **kw)
     FA.flash_bthd(q, k, v, h, window=t, **kw)
@@ -184,6 +202,13 @@ def test_a_window_counts_itself_and_composes_with_nothing_else():
     computed, useful = FA.band_scores(t, 128, 128, 200)
     assert scores("computed") == was[2] + computed
     assert scores("useful") == was[3] + useful
+    # the ONE streamed kernel walks by keys alone; the two kernels, for
+    # a T over its bound, by queries too
+    assert walks() == [walked[0], walked[1] + computed]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+        FA.flash_bthd(q, k, v, h, window=200, **kw)
+    assert walks() == [walked[0] + computed, walked[1] + 2 * computed]
     dense = dict(labels, path="dense", backward="none", window="200")
     before = FA._LOWERINGS.value(**dense)
     FA.flash_bthd(q, k, v, h, causal=True, force="interpret", block_q=256,
