@@ -39,6 +39,15 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           one sequence of 16,384 rows, forward and backward in device
           ms under the window beside plain causal, the backward as ONE
           kernel and as the two
+  scan    the selective scan's chunked kernel pair (ISSUE 40) at the
+          cell phi4flash_train_T8k's shape, s and dt [1, 8192, 5120]
+          bf16, 16 states: y and the gradients of all six inputs
+          against the float32 lax.scan form at `highest`, both timed
+  diff    differential attention through the streamed kernels (ISSUE
+          40): 40 query and 20 key/value heads of 64, values of 128,
+          T 4096, full and under a window of 512: a1, a2, dq, dk, dv
+          against the two softmaxes in dense float32 math; then forward
+          and backward device ms at T 8192
   rotary  QK-norm and RoPE in the projections' own layout (the kernel
           pair of ops/rotary.py) at the block-diffusion cell's shapes,
           q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
@@ -632,6 +641,146 @@ def phase_window(seed, rehearse):
                     ", ".join("%s %.3f" % kv for kv in ops.most_common(6)),
                     apart))
             assert apart <= FLASH_GRAD_TOL, apart
+
+
+def phase_scan(seed, rehearse):
+    """The selective scan's chunked kernel pair (ISSUE 40) at the cell
+    phi4flash_train_T8k's shape: s and dt [1, 8192, 5120] bf16, 16
+    states a channel: y and the gradients of all six inputs against the
+    float32 ``lax.scan`` form at ``highest`` on the same values, and
+    the device ms of both, forward and forward + backward, beside the
+    bytes the kernels must move at the HBM peak."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import selective_scan as ss
+    b, t, c, n = (1, 64, 256, 16) if rehearse else (1, 8192, 5120, 16)
+    force = "interpret" if rehearse else None
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    bf = lambda x: x.astype(jnp.bfloat16)
+    f32 = lambda x: x.astype(jnp.float32)
+    ops = (bf(jax.random.normal(ks[0], (b, t, c))),
+           bf(jax.nn.softplus(jax.random.normal(ks[1], (b, t, c)) - 3.0)),
+           -jnp.exp(0.5 * jax.random.normal(ks[2], (c, n))),
+           bf(jax.random.normal(ks[3], (b, t, n))),
+           bf(jax.random.normal(ks[4], (b, t, n))),
+           jax.random.normal(ks[5], (c,)))
+    dy = bf(jax.random.normal(ks[6], (b, t, c)))
+
+    def both(scan, *a):
+        y, vjp = jax.vjp(scan, *a)
+        return (y,) + vjp(dy.astype(y.dtype))
+
+    kernels = jax.jit(functools.partial(both, functools.partial(
+        ss.selective_scan, force=force)))
+    steps = jax.jit(functools.partial(both, ss.scan_steps))
+    t0 = time.perf_counter()
+    text = "" if rehearse else compiled_text(kernels, *ops)
+    ms, got, kinds = _device_ms(kernels, ops, 4, rehearse, "scan")
+    with jax.default_matmul_precision("highest"):
+        ms_steps, want, _ = _device_ms(steps, tuple(f32(x) for x in ops), 1,
+                                       rehearse, "scan_steps")
+    errs = _far(got, want)
+    # the state is float32: the step form on the same values with its
+    # state HELD in bfloat16 between steps lies well further from the
+    # float32 one than the kernels on the same float32 values (their
+    # y on bf16 operands is rounded once, at 2^-9 of its value)
+    ops32 = tuple(f32(x) for x in ops)
+    held_low = _far([jax.jit(functools.partial(
+        ss.scan_steps, state_dtype=jnp.bfloat16))(*ops32)], want[:1])[0]
+    exact = _far([jax.jit(functools.partial(
+        ss.selective_scan, force=force))(*ops32)], want[:1])[0]
+    # one forward and one backward: 8 [T, C] and 6 [T, N] bf16 values
+    need = (8 * c + 6 * n) * t * b * 2
+    log("[scan] s/dt [%d, %d, %d] bf16, %d states: y %.3e ds %.3e ddt %.3e "
+        "dA %.3e dB %.3e dC %.3e dD %.3e from the float32 step form "
+        "(%.1f s); forward + backward %.3f ms a call on the device (%s; "
+        "floor %.3f ms at the HBM peak), the step form %.3f ms; y of the "
+        "kernels on the same values in float32 %.3e, of the step form "
+        "with a bfloat16 state %.3e" % (
+            b, t, c, n, *errs, time.perf_counter() - t0, ms,
+            ", ".join("%s %.3f" % kv for kv in kinds.most_common(4)),
+            need / 819e9 * 1e3, ms_steps, exact, held_low))
+    assert max(errs) <= FLASH_GRAD_TOL, errs
+    assert exact <= 1e-4 and held_low >= 10 * exact, (exact, held_low)
+    if not rehearse:
+        assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+        assert " while(" not in text
+
+
+def phase_diff(seed, rehearse):
+    """Differential attention through the streamed kernels (ISSUE 40)
+    at the cell phi4flash_train_T8k's heads: 40 query and 20 key/value
+    heads of 64, values of 128, T 4096, full and under a window of 512:
+    a1, a2 and dq, dk, dv against the two softmaxes written out in
+    dense float32 math, a differential head at a time; then forward +
+    backward device ms at the cell's T 8192."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import flash_attention as fa
+    t, h, hkv, d, window = (256, 8, 4, 64, 100) if rehearse else (
+        4096, 40, 20, 64, 512)
+    rng = np.random.RandomState(seed)
+    mk = lambda rows, n: jnp.asarray(rng.randn(1, rows, n * d) * 0.5,
+                                     jnp.bfloat16)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def attend(w):
+        def loss(dy, q, k, v):
+            a = jnp.stack(fa.flash_diff_bthd(q, k, v, h, hkv, window=w))
+            return (f32(a) * f32(dy)).sum(), a
+        return loss
+
+    def written_out(w):
+        def loss(dy, q, k, v):
+            rows = q.shape[1]
+            ahead = jnp.arange(rows)[:, None] - jnp.arange(rows)[None, :]
+            seen = (ahead >= 0) if w is None else (ahead >= 0) & (ahead < w)
+            qh, kh, vh = (x.reshape(rows, -1, d) for x in (q[0], k[0], v[0]))
+
+            def head(p):
+                r = p // (h // hkv)
+                value = jnp.concatenate([vh[:, 2 * r], vh[:, 2 * r + 1]], -1)
+                return jnp.stack([jax.nn.softmax(jnp.where(
+                    seen, qh[:, 2 * p + turn] @ kh[:, 2 * r + turn].T
+                    * d ** -0.5, -jnp.inf), -1) @ value
+                    for turn in (0, 1)])            # [2, T, 2D]
+            a = jax.lax.map(head, jnp.arange(h // 2))     # [P, 2, T, 2D]
+            a = a.transpose(1, 2, 0, 3).reshape(2, 1, rows, h * d)
+            return (a * dy).sum(), a
+        return loss
+
+    t0 = time.perf_counter()
+    for w in (None, window):
+        dy = jnp.asarray(rng.randn(2, 1, t, h * d) * 0.5, jnp.bfloat16)
+        q, k, v = mk(t, h), mk(t, hkv), mk(t, hkv)
+        before = sum(n for key, n in fa._LOWERINGS.snapshot().items()
+                     if "dense" in key)
+        kernel = jax.jit(jax.value_and_grad(attend(w), (1, 2, 3),
+                                            has_aux=True))
+        (_, out), got = kernel(dy, q, k, v)
+        with jax.default_matmul_precision("highest"):
+            (_, ref), want = jax.jit(jax.value_and_grad(
+                written_out(w), (1, 2, 3), has_aux=True))(
+                    f32(dy), f32(q), f32(k), f32(v))
+        errs = _far((out,) + got, (ref,) + want)
+        dense = sum(n for key, n in fa._LOWERINGS.snapshot().items()
+                    if "dense" in key) - before
+        log("[diff] q [1, %d, %d] k/v [.., %d] bf16, window %s: a1/a2 "
+            "%.3e dq %.3e dk %.3e dv %.3e from the two softmaxes in dense "
+            "float32 math (%.1f s); dense lowerings %d" % (
+                t, h * d, hkv * d, w, *errs, time.perf_counter() - t0,
+                dense))
+        assert max(errs) <= FLASH_GRAD_TOL, errs
+        assert rehearse or not dense
+    t = 512 if rehearse else 8192
+    dy = jnp.asarray(rng.randn(2, 1, t, h * d) * 0.5, jnp.bfloat16)
+    q, k, v = mk(t, h), mk(t, hkv), mk(t, hkv)
+    for w in (None, window):
+        call = jax.jit(jax.grad(attend(w), (1, 2, 3), has_aux=True))
+        ms, _, kinds = _device_ms(call, (dy, q, k, v), 4, rehearse, "diff")
+        log("[diff] T %d, window %s: forward + backward %.3f ms a call on "
+            "the device (%s)" % (t, w, ms, ", ".join(
+                "%s %.3f" % kv for kv in kinds.most_common(6))))
 
 
 def phase_rotary(seed, rehearse):
@@ -1233,7 +1382,8 @@ def main():
                          "dp2 x tp2 mesh and its one-device baseline")
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
-                         "(flash, gqa, own_block, mla, window, rotary, "
+                         "(flash, gqa, own_block, mla, window, scan, diff, "
+                         "rotary, "
                          "experts, "
                          "rows, "
                          "train, serve); all of them if not given")
@@ -1259,7 +1409,9 @@ def main():
     else:
         phases = {"flash": phase_flash, "gqa": phase_gqa,
                   "own_block": phase_own_block, "mla": phase_mla,
-                  "window": phase_window, "rotary": phase_rotary, "experts": phase_experts,
+                  "window": phase_window, "scan": phase_scan,
+                  "diff": phase_diff, "rotary": phase_rotary,
+                  "experts": phase_experts,
                   "rows": phase_rows,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}

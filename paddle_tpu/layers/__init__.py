@@ -28,6 +28,7 @@ from .parallel_layers import (  # noqa: F401
     sparse_moe,
 )
 from .sequence_layers import *  # noqa: F401,F403
+from .state_space import *  # noqa: F401,F403
 from .compat import *  # noqa: F401,F403
 from .control_flow import *  # noqa: F401,F403
 from . import control_flow  # noqa: F401

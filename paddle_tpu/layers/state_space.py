@@ -1,0 +1,153 @@
+"""Layers of a hybrid state-space / attention decoder (ISSUE 40): the
+selective scan and the ops round it (``ops/selective_scan.py``),
+differential attention (``ops/diff_attention.py``) and a head tied to
+the embedding. Each is one Program op under its own type, so that a
+device trace gives each its scope."""
+
+import math
+
+import numpy as np
+
+from ..initializer import (ConstantInitializer, NormalInitializer,
+                           NumpyArrayInitializer, UniformInitializer)
+from ..param_attr import ParamAttr
+from .layer_helper import LayerHelper
+
+__all__ = ["ssm_conv", "ssm_dt", "selective_scan", "ssm_gate", "gmu_gate",
+           "diff_attention", "diff_attn", "tied_head"]
+
+
+def _param(helper, name, shape, initializer):
+    return helper.create_parameter(
+        ParamAttr(name=name, initializer=initializer), shape=shape,
+        dtype="float32")
+
+
+def _same(helper, x, shape=None):
+    return helper.create_variable_for_type_inference(
+        x.dtype, shape=x.shape if shape is None else shape)
+
+
+def ssm_conv(x, width=4, name=None):
+    """``silu(bias + causal depthwise conv over time)`` of x [B, T, C]:
+    parameters ``<name>_w`` [width, C] and ``<name>_b`` [C], both
+    U(-width^-0.5, width^-0.5) (a depthwise Conv1d's default)."""
+    helper = LayerHelper("ssm_conv", name=name)
+    c, bound = int(x.shape[-1]), width ** -0.5
+    w = _param(helper, helper.name + "_w", [width, c],
+               UniformInitializer(-bound, bound))
+    b = _param(helper, helper.name + "_b", [c],
+               UniformInitializer(-bound, bound))
+    out = _same(helper, x)
+    helper.append_op(type="ssm_conv",
+                     inputs={"X": [x], "Filter": [w], "Bias": [b]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def ssm_dt(x, dt_min=1e-3, dt_max=1e-1, name=None):
+    """``softplus(x + bias)``, the scan's step size: parameter
+    ``<name>`` [C], initialised so that softplus(bias) is spread
+    log-uniformly over [dt_min, dt_max] (Mamba's)."""
+    helper = LayerHelper("ssm_dt", name=name)
+    c = int(x.shape[-1])
+    dt = np.exp(np.linspace(math.log(dt_min), math.log(dt_max), c))
+    bias = _param(helper, helper.name, [c], NumpyArrayInitializer(
+        (dt + np.log(-np.expm1(-dt))).astype("float32")))
+    out = _same(helper, x)
+    helper.append_op(type="ssm_dt", inputs={"X": [x], "Bias": [bias]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def selective_scan(x, dt, b, c, d_state=16, chunk=0, force="", name=None):
+    """The selective scan of x [B, T, C] with steps dt [B, T, C], inputs
+    b and outputs c [B, T, N]: parameters ``<name>_a_log`` [C, N] (A =
+    -exp(.), initialised log(1 .. N) in every channel) and ``<name>_d``
+    [C] (ones). The state is float32 whatever x is. `chunk` and `force`
+    are ``ops/selective_scan.selective_scan``'s (0 and "": its own
+    choice; a CPU rehearsal pins ``"interpret"`` and a short chunk)."""
+    helper = LayerHelper("selective_scan", name=name)
+    ch = int(x.shape[-1])
+    a_log = _param(helper, helper.name + "_a_log", [ch, d_state],
+                   NumpyArrayInitializer(np.tile(np.log(np.arange(
+                       1, d_state + 1, dtype="float32")), (ch, 1))))
+    d = _param(helper, helper.name + "_d", [ch], ConstantInitializer(1.0))
+    out = _same(helper, x)
+    helper.append_op(type="selective_scan",
+                     inputs={"X": [x], "Dt": [dt], "ALog": [a_log],
+                             "B": [b], "C": [c], "D": [d]},
+                     outputs={"Out": [out]},
+                     attrs={"chunk": int(chunk), "force": str(force)})
+    return out
+
+
+def _gated(op_type, x, gate, name):
+    helper = LayerHelper(op_type, name=name)
+    out = _same(helper, gate)
+    helper.append_op(type=op_type, inputs={"X": [x], "Gate": [gate]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def ssm_gate(y, z, name=None):
+    """``y * silu(z)``: a Mamba mixer's output gate."""
+    return _gated("ssm_gate", y, z, name)
+
+
+def gmu_gate(memory, g, name=None):
+    """``memory * silu(g)``: a gated memory unit, the memory another
+    layer's scan output at the same positions."""
+    return _gated("gmu_gate", memory, g, name)
+
+
+def diff_attention(q, k, v, n_head, n_kv_head, window=0, kind="full",
+                   name=None):
+    """The two softmaxes of differential attention
+    (``ops/diff_attention.py``): q [B, T, H*D], k and v [B, T, Hkv*D];
+    returns ``(a1, a2)``, [B, T, (H/2)*2D] each."""
+    helper = LayerHelper("diff_attention", name=name)
+    a1, a2 = _same(helper, q), _same(helper, q)
+    helper.append_op(
+        type="diff_attention",
+        inputs={"Q": [q], "K": [k], "V": [v]},
+        outputs={"A1": [a1], "A2": [a2]},
+        attrs={"n_head": int(n_head), "n_kv_head": int(n_kv_head),
+               "window": int(window), "kind": str(kind)})
+    return a1, a2
+
+
+def diff_attn(a1, a2, head_dim, lambda_init, epsilon=1e-5, lambda_std=0.1,
+              name=None):
+    """``RMSNorm(a1 - lam a2) * (1 - lambda_init)`` over a1, a2 [B, T,
+    P * 2D]: parameters ``<name>_lq1``, ``_lk1``, ``_lq2``, ``_lk2``
+    [D] (N(0, lambda_std)) and ``<name>_subln`` [2D] (ones)."""
+    helper = LayerHelper("diff_attn", name=name)
+    lam = {part: _param(helper, "%s_%s" % (helper.name, part), [head_dim],
+                        NormalInitializer(0.0, lambda_std))
+           for part in ("lq1", "lk1", "lq2", "lk2")}
+    scale = _param(helper, helper.name + "_subln", [2 * head_dim],
+                   ConstantInitializer(1.0))
+    out = _same(helper, a1)
+    helper.append_op(
+        type="diff_attn",
+        inputs={"A1": [a1], "A2": [a2], "LambdaQ1": [lam["lq1"]],
+                "LambdaK1": [lam["lk1"]], "LambdaQ2": [lam["lq2"]],
+                "LambdaK2": [lam["lk2"]], "Scale": [scale]},
+        outputs={"Out": [out]},
+        attrs={"lambda_init": float(lambda_init), "epsilon": float(epsilon)})
+    return out
+
+
+def tied_head(x, table, name=None):
+    """Logits of x [B, T, d] against the embedding's OWN parameter
+    `table` [V, d]: a ``mul`` that contracts the table's second
+    dimension (``transpose_Y``), no copy and no second parameter, so
+    the table's gradient is the sum of its two uses."""
+    helper = LayerHelper("tied_head", name=name)
+    out = _same(helper, x, tuple(x.shape[:-1]) + (int(table.shape[0]),))
+    helper.append_op(type="mul", inputs={"X": [x], "Y": [table]},
+                     outputs={"Out": [out]},
+                     attrs={"x_num_col_dims": len(x.shape) - 1,
+                            "y_num_col_dims": 1, "transpose_Y": True})
+    return out

@@ -10,11 +10,13 @@ from . import (  # noqa: F401
     conv,
     crf_ctc,
     detection_ops,
+    diff_attention,
     elementwise,
     fused,
     hyper_connection,
     latent_attention,
     rnn_ops,
+    selective_scan,
     loss,
     math,
     metrics_ops,

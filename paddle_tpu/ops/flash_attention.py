@@ -1839,6 +1839,59 @@ def flash_bthd_lse(q, k, v, n_head, causal=False, scale=None,
                    window=window)
 
 
+def diff_heads(q, n_head, turn):
+    """q [B, T, H*D] with the lanes of every other head zeroed: those of
+    the odd heads (`turn` 0) or of the even ones (`turn` 1). Read as H /
+    2 heads of 2D lanes, head p is then (q_2p, 0) or (0, q_2p+1), and
+    against a PAIR of key heads side by side, (k_2r, k_2r+1), which is
+    the layout a projection leaves them in, its product over the 2D
+    lanes is q_2p . k_2r or q_2p+1 . k_2r+1: the foreign half adds
+    exact zeros. One select over q, nothing moved."""
+    d = q.shape[-1] // n_head
+    lane = lax.broadcasted_iota(jnp.int32, (1, 1, q.shape[-1]), 2)
+    return jnp.where((lane // d) % 2 == turn, q, jnp.zeros((), q.dtype))
+
+
+def flash_diff_bthd(q, k, v, n_head, n_kv_head, window=None, scale=None,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    force=None):
+    """The two softmaxes of differential attention (arXiv:2410.05258)
+    over grouped heads, causal, through the streamed kernels as they
+    are (ISSUE 40). q [B, T, H*D]; k and v [B, T, Hkv*D], Hkv even and
+    dividing H. Differential head p of H / 2 is the query pair (q_2p,
+    q_2p+1); key/value pair r of Hkv / 2 is (k_2r, k_2r+1) with the
+    value v_r = [v_2r; v_2r+1], 2D wide; p reads r = p // (H / Hkv).
+    Returns ``(a1, a2)``, [B, T, (H/2)*2D] each: ``a1_p = softmax(q_2p
+    k_2r^T scale) v_r``, ``a2_p = softmax(q_2p+1 k_2r+1^T scale) v_r``;
+    `scale` defaults to D^-0.5, `window` is flash_bthd's.
+
+    How: a head of D 64 with a value of 128 is, to the kernels, one
+    head of 128 whose query has zeros in the lanes of the other key of
+    its pair (diff_heads): what a kernel that holds two heads of 64 to
+    a 128-lane block does inside for each of its turns (_each_head),
+    done here once for each turn of every pair. So each softmax is ONE
+    call of grouped-query attention, H / 2 heads of 2D reading Hkv / 2:
+    k and v go in as the projections left them, neither repeated nor
+    moved, one head to a 128-lane block, the value as wide as the key;
+    the MXU's passes are those a head of 64 costs anyway (a contraction
+    of 64 half fills it). What it pays is q written twice with half
+    its lanes zeroed and the two calls' dq, dk and dv added (XLA; the
+    reader ``diff_attn_glue_dev_share_pct`` has their time). The
+    kernels trace nothing they did not trace for such a call before;
+    each lowering counts under the entry ``diff`` with the widths as
+    laid out."""
+    d = q.shape[-1] // n_head
+    if n_head % 2 or n_kv_head % 2 or n_head % n_kv_head:
+        raise ValueError(
+            "differential attention pairs its heads: %d query and %d "
+            "key/value heads cannot be paired and grouped"
+            % (n_head, n_kv_head))
+    return tuple(
+        _attend(diff_heads(q, n_head, turn), k, v, n_head // 2, True,
+                scale or d ** -0.5, block_q, block_k, force, "diff", False,
+                n_kv_head // 2, window=window) for turn in (0, 1))
+
+
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     force=None):

@@ -48,6 +48,10 @@ def _mul(ctx, op):
     yn = op.attr("y_num_col_dims", 1)
     x2, xshape = _flatten2d(x, xn)
     y2 = y.reshape(functools.reduce(lambda a, b: a * b, y.shape[:yn], 1), -1)
+    if op.attr("transpose_Y", False):
+        # a tied head: Y is the embedding's own [V, d] table, contracted
+        # over its second dimension (nothing is transposed in memory)
+        y = y2 = y2.T
     out = jnp.matmul(x2, y2, preferred_element_type=_acc_type(x))
     from ..amp import amp_out
     out = amp_out(out, out_dtype)

@@ -882,6 +882,13 @@ COVERED_ELSEWHERE = {
     "hyper_connection": "test_latent_moe.py",
     "causal_attention": "test_windowed_moe.py",
     "sigmoid_mul": "test_windowed_moe.py",
+    "diff_attention": "test_hybrid_ssm.py",
+    "diff_attn": "test_hybrid_ssm.py",
+    "gmu_gate": "test_selective_scan.py",
+    "selective_scan": "test_selective_scan.py",
+    "ssm_conv": "test_selective_scan.py",
+    "ssm_dt": "test_selective_scan.py",
+    "ssm_gate": "test_selective_scan.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",

@@ -584,3 +584,75 @@ def test_one_streamed_backward_kernel_compiles_for_v5e(chip, b, t, form):
     size = lambda dims: math.prod(int(x) for x in dims.split(","))
     assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
                ) <= b * t * h * d
+
+
+# ISSUE 40: the selective scan's chunked kernel pair at the cell
+# `phi4flash_train_T8k`'s shape (one packed 8,192-token sequence, 5,120
+# channels of 16 states, bf16 operands), and differential attention's
+# window layer there (40 query and 20 key/value heads of 64, values of
+# 128, a window of 512).
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_selective_scan_compiles_for_v5e(chip, direction):
+    """s and dt [1, 8192, 5120] bf16, 16 states: ONE kernel a direction
+    (the backward re-runs the forward's), named as a device trace will
+    show them; the compiled program holds no [8192, 5120, 16] value, and
+    no while loop: time is walked by the kernels' grids and the loops
+    inside them, not by 8,192 trips of XLA's."""
+    import math
+    import re
+    from paddle_tpu.ops.selective_scan import selective_scan
+    b, t, c, n = 1, 8192, 5120, 16
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+    avals = (sd((b, t, c)), sd((b, t, c)), sd((c, n), jnp.float32),
+             sd((b, t, n)), sd((b, t, n)), sd((c,), jnp.float32))
+
+    def fwd(*a):
+        return selective_scan(*a, force="pallas")
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=tuple(range(6)))
+    text = _compiled_text(fn, *avals)
+    names = ["selective_scan_fwd"] + (["selective_scan_bwd"]
+                                      if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    assert " while(" not in text
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    # the largest: B_t or C_t over 128 lanes, [1, 8192, 16, 128]
+    assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
+               ) <= b * t * c < b * t * c * n
+
+
+def test_differential_window_attention_compiles_for_v5e(chip):
+    """q [1, 8192, 2560] against k, v [1, 8192, 1280] under a window of
+    512, forward and backward: each softmax the forward and ONE backward
+    kernel of the streamed set, no dense lowering and no [T, T] value:
+    the largest buffer the program names is q's size."""
+    import math
+    import re
+    from paddle_tpu.ops.flash_attention import flash_diff_bthd
+    b, t, h, hkv, d = 1, 8192, 40, 20, 64
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=chip)
+    dense = lambda: sum(
+        v for key, v in FA._LOWERINGS.snapshot().items()
+        if key[FA._LOWERINGS.label_names.index("path")] == "dense")
+    before = dense()
+
+    def loss(q, k, v):
+        a1, a2 = flash_diff_bthd(q, k, v, h, hkv, window=512,
+                                 force="pallas")
+        return (a1.astype(jnp.float32) - 0.5 * a2.astype(jnp.float32)).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert dense() == before
+    assert text.count("tpu_custom_call") == 4
+    assert len(re.findall(r"%flash_fwd(?:\.\d+)? = ", text)) == 2
+    assert len(re.findall(r"%flash_bwd(?:\.\d+)? = ", text)) == 2
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
+               ) <= b * t * h * d
