@@ -103,10 +103,15 @@ class BlockGuard:
 class recompute(BlockGuard):
     """Rematerialization region (``with layers.recompute(): ...``): ops
     built inside the block re-run during the backward pass instead of
-    storing their activations (jax.checkpoint over the sub-block). Wrap
-    each transformer layer to train longer sequences / bigger batches in
-    the same HBM at ~1/3 extra forward FLOPs. Fetch intermediates
-    OUTSIDE a region — exporting them would defeat the remat."""
+    storing their activations (jax.checkpoint over the sub-block). One
+    thing is kept and not re-run: where attention takes the flash
+    kernels, the forward kernel's output and lse rows, which are all the
+    backward kernels read of it (B x T x H*Dv x 2 bytes a call in bf16);
+    the projections, norms and FFN round it are recomputed. Wrap each
+    transformer layer to train longer sequences / bigger batches in the
+    same HBM at ~1/3 extra forward FLOPs, less the attention kernel's.
+    Fetch intermediates OUTSIDE a region — exporting them would defeat
+    the remat."""
 
     def __init__(self):
         super().__init__(default_main_program())

@@ -210,8 +210,11 @@ def transformer_lm(vocab_size=4096, max_len=256, n_layer=4, n_head=8,
     so self-attention runs through the fused flash path; `mask` still
     weights the loss. recompute=True wraps each decoder layer in a
     layers.recompute() region (jax.checkpoint): layer activations are
-    recomputed in the backward pass, trading ~1/3 extra forward FLOPs
-    for activation memory — the long-context lever."""
+    recomputed in the backward pass, all but the flash forward kernel's
+    output and lse rows, which a region keeps (B x T x d_model x 2 bytes
+    a layer in bf16) so that the kernel runs once; that trades ~1/3
+    extra forward FLOPs, less attention's, for activation memory — the
+    long-context lever."""
     d_key = d_value = d_model // n_head
     src = layers.data("src", [max_len], dtype="int64")
     pos = layers.data("pos", [max_len], dtype="int64")

@@ -17,7 +17,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core import registry
+from ..monitor import metrics as _metrics
 from .common import I64
+from .flash_attention import KEPT_IN_REGIONS
 from ..core.registry import register, LowerContext
 
 
@@ -165,6 +167,35 @@ def _conditional_block(ctx, op):
         ctx.env[n] = v
 
 
+_REG = _metrics.registry()
+_REGIONS = _REG.counter(
+    "ptpu_recompute_regions_total",
+    "recompute regions lowered (one a lowering of the op, none a step)")
+_KEPT_BYTES = _REG.counter(
+    "ptpu_recompute_kept_bytes_total",
+    "bytes a step that recompute regions keep from their forward to "
+    "their backward in place of recomputing them, added where a "
+    "region's gradient is traced from the shape and dtype of each value "
+    "its policy saves, by the value's name (flash_out, flash_lse: a "
+    "flash forward kernel's results, ops/flash_attention.py); a region "
+    "with no such value in it, or one that is never differentiated, "
+    "adds nothing",
+    ("name",))
+_KEEPS = jax.checkpoint_policies.save_only_these_names(*KEPT_IN_REGIONS)
+
+
+def _region_policy(prim, *avals, **params):
+    """What a recompute region saves: the values named in
+    flash_attention.KEPT_IN_REGIONS and nothing else. JAX asks once for
+    every equation of a region whose gradient it traces, which is where
+    the kept bytes are counted."""
+    keeps = _KEEPS(prim, *avals, **params)
+    if keeps:
+        _KEPT_BYTES.inc(sum(a.size * a.dtype.itemsize for a in avals),
+                        name=params["name"])
+    return keeps
+
+
 @register("recompute_block")
 def _recompute_block(ctx, op):
     """Rematerialization region: lower the sub-block under jax.checkpoint
@@ -174,6 +205,14 @@ def _recompute_block(ctx, op):
     done by the AD system rather than liveness analysis. Grads flow
     through the region; RNG-consuming ops (dropout) reuse one region key,
     so the recompute replays identical masks.
+
+    ONE thing inside a region is kept and not recomputed: the output and
+    the log-sum-exp rows of a flash forward kernel (_region_policy), all
+    that the flash backward reads of it, at B x T x H*Dv x 2 bytes (bf16)
+    a call. So the kernel runs once a layer, and its q, k and v are still
+    recomputed with the projections that make them. A region with no
+    flash kernel in it (the dense path, plain layers) keeps nothing and
+    lowers as under a bare jax.checkpoint.
 
     Outputs exported from the region are the sub-block writes consumed
     by LATER ops of the parent block (looking through their sub-blocks),
@@ -249,7 +288,8 @@ def _recompute_block(ctx, op):
                   if k.startswith(_NANGUARD) and k not in base_env}
         return tuple(env[n] for n in out_names), lods, guards
 
-    outs, lods, guards = jax.checkpoint(f)(
+    _REGIONS.inc()
+    outs, lods, guards = jax.checkpoint(f, policy=_region_policy)(
         tuple(ctx.env[n] for n in in_names), region_key)
     for n, v in zip(out_names, outs):
         ctx.env[n] = v

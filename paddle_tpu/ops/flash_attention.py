@@ -147,6 +147,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..monitor import metrics as _metrics
 
@@ -1470,6 +1471,26 @@ def _bwd_pallas2(res, dy, n_head, mask, scale, block_q, block_k, interpret):
     return dq, dk, dv, dq2, dk2
 
 
+# The names a recompute region keeps by (ops/control_flow.py's
+# recompute_block saves these and nothing else): the forward kernel's two
+# results, which are all of it that a backward kernel reads. Each fwd
+# rule below names them BEFORE they go into the primal result and the
+# residuals, so both ARE the named value. A name put on _flash's result
+# outside the custom_vjp covers a copy, and the kernel's own output,
+# which the backward holds, is recomputed all the same; a name on the
+# residuals alone leaves the primal result, which the output
+# projection's weight gradient reads, to be recomputed by the kernel
+# (tried: the forward runs twice again). Outside a region, and under a
+# jax.checkpoint with no policy, a name is an identity that XLA never
+# sees.
+KEPT_IN_REGIONS = ("flash_out", "flash_lse")
+
+
+def _named(out, lse):
+    return (checkpoint_name(out, KEPT_IN_REGIONS[0]),
+            checkpoint_name(lse, KEPT_IN_REGIONS[1]))
+
+
 # static: n_head, mask, scale, block_q, block_k, interpret
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash2(q, k, v, q2, k2, *static):
@@ -1477,7 +1498,7 @@ def _flash2(q, k, v, q2, k2, *static):
 
 
 def _flash2_fwd(q, k, v, q2, k2, *static):
-    out, lse = _fwd_pallas2(q, k, v, q2, k2, *static)
+    out, lse = _named(*_fwd_pallas2(q, k, v, q2, k2, *static))
     return out, (q, k, v, q2, k2, out, lse)
 
 
@@ -1499,7 +1520,7 @@ def _flash(q, k, v, *static):
 
 
 def _flash_fwd(q, k, v, *static):
-    out, lse = _fwd_pallas(q, k, v, *static)
+    out, lse = _named(*_fwd_pallas(q, k, v, *static))
     return out, (q, k, v, out, lse)
 
 
@@ -1530,7 +1551,7 @@ def _flash_lse(q, k, v, *static):
 
 
 def _flash_lse_fwd(q, k, v, *static):
-    out, lse = _fwd_pallas(q, k, v, *static)
+    out, lse = _named(*_fwd_pallas(q, k, v, *static))
     return (out, lse), (q, k, v, out, lse)
 
 

@@ -7,14 +7,25 @@ the whole forward) changes WHEN activations are computed, never what —
 loss and gradients must match the plain run bit-for-bit at test
 tolerances. Measured effect on the real chip (PERF.md): at T=8192 the
 flagship LM trains at 2x the plain batch in the same HBM.
+
+What a region keeps (ISSUE 42): the output and the lse rows of a flash
+forward kernel, by the names the kernels' fwd rules give them, so the
+kernel runs once a layer; everything else is recomputed, and a region
+with no kernel in it lowers as under a bare jax.checkpoint.
 """
 
+import collections
+
+import jax
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import unique_name
+from paddle_tpu.core.executor import _gather_state, _normalize_feeds
 from paddle_tpu.models import transformer as T
+from paddle_tpu.ops import control_flow as CF
+from paddle_tpu.ops import flash_attention as FA
 
 
 def _run_lm(recompute, checkpoint=False, dropout=0.0, prefix="x_"):
@@ -307,3 +318,140 @@ def test_recompute_region_general_graph():
         assert np.isfinite(float(np.asarray(l)))
         assert np.isfinite(np.asarray(g)).all()
         assert np.abs(np.asarray(g)).sum() > 0
+
+
+# -- what a region keeps by name (ISSUE 42) ---------------------------------
+_LAYERS, _B, _T, _H, _D = 2, 2, 128, 2, 128
+
+
+def _through(monkeypatch, rule):
+    """sp_attention's call of flash_bthd sent to the kernels in interpret
+    mode through ONE of the three custom_vjps, whose fwd rule names what
+    a region keeps: `_flash` (the call as it is), `_flash_lse` (ring
+    attention's entry: the lse rows are a result too, and weigh the
+    output here so that their cotangent is not zero) or `_flash2` (a
+    score of two parts: q again against head 0's key)."""
+    flash, flash_lse = FA.flash_bthd, FA.flash_bthd_lse
+
+    def entry(q, k, v, n_head, **kw):
+        if rule == "_flash":
+            return flash(q, k, v, n_head, force="interpret", **kw)
+        if rule == "_flash2":
+            return flash(q, k, v, n_head, force="interpret", q2=q,
+                         k2=k[..., :_D], **kw)
+        out, lse = flash_lse(q, k, v, n_head, force="interpret", **kw)
+        weight = jax.nn.sigmoid(lse).transpose(0, 2, 1)[..., None]
+        return (out.reshape(_B, _T, n_head, _D) * weight).reshape(out.shape)
+
+    monkeypatch.setattr(FA, "flash_bthd", entry)
+
+
+def _region_lm(recompute, prefix):
+    """A two-layer LM of 2 heads of 128 at T 128 (a kernel block is one
+    head and holds all of T), float32: (program, scope, feeds, fetch
+    names: the loss and the first two gradients)."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 5
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            unique_name.guard(prefix):
+        cost, _ = T.transformer_lm(vocab_size=64, max_len=_T,
+                                   n_layer=_LAYERS, n_head=_H,
+                                   d_model=_H * _D, d_inner=64,
+                                   packed=True, recompute=recompute)
+        pg = fluid.append_backward(cost)
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+    feeds = {k: np.asarray(v) for k, v in T.make_lm_batch(
+        np.random.RandomState(0), _B, _T, 64).items()}
+    return main, scope, feeds, (cost.name,) + tuple(
+        g.name for _, g in pg[:2])
+
+
+def _run(main, scope, feeds, fetch):
+    with fluid.scope_guard(scope):
+        return [np.asarray(v) for v in fluid.Executor(fluid.CPUPlace()).run(
+            main, feed=feeds, fetch_list=list(fetch))]
+
+
+def _step_jaxpr(main, scope, feeds, fetch):
+    """The jaxpr of the whole step, gradient included, as the Executor
+    builds it for these feeds and fetches."""
+    state, keys = _gather_state(main, scope)
+    feed_arrays, static_info = _normalize_feeds(feeds)
+    step = fluid.Executor(fluid.CPUPlace())._build(
+        main, tuple(sorted(feed_arrays)), fetch, keys, static_info)
+    return jax.make_jaxpr(step)(state, feed_arrays, jax.random.key(0)).jaxpr
+
+
+def _eqns(jaxpr):
+    """Every equation, sub-jaxprs included, in order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _equations(jaxpr):
+    """(primitive, operand avals, result avals) of every equation."""
+    return [(e.primitive.name, tuple(str(v.aval) for v in e.invars),
+             tuple(str(v.aval) for v in e.outvars)) for e in _eqns(jaxpr)]
+
+
+def _kernels(jaxpr):
+    """{name: count} of the jaxpr's pallas_call equations."""
+    return dict(collections.Counter(
+        e.params["name"] for e in _eqns(jaxpr)
+        if e.primitive.name == "pallas_call"))
+
+
+_RULES = ["_flash", "_flash_lse", "_flash2"]
+
+
+@pytest.mark.parametrize("rule", _RULES)
+def test_region_that_keeps_flash_results_matches_plain_to_the_bit(
+        monkeypatch, rule):
+    """Regions on against regions off with the kernels in the program:
+    the loss and the first gradients are the same bits (what is kept is
+    what the first run made; what is recomputed is the same ops on the
+    same operands), and the counters say what the regions kept: out
+    [B, T, H*D] and lse [B, H, T], float32 here, a layer."""
+    _through(monkeypatch, rule)
+    plain = _run(*_region_lm(False, "kp_"))
+    regions = CF._REGIONS.value()
+    kept = {n: CF._KEPT_BYTES.value(name=n) for n in FA.KEPT_IN_REGIONS}
+    got = _run(*_region_lm(True, "kr_"))
+    for a, b in zip(got, plain):
+        np.testing.assert_array_equal(a, b)
+    assert np.abs(got[2]).sum() > 0
+    assert CF._REGIONS.value() - regions == _LAYERS
+    assert {n: CF._KEPT_BYTES.value(name=n) - kept[n] for n in kept} == {
+        "flash_out": _LAYERS * _B * _T * _H * _D * 4,
+        "flash_lse": _LAYERS * _B * _H * _T * 4}
+
+
+@pytest.mark.parametrize("rule", _RULES)
+def test_region_runs_each_flash_forward_once(monkeypatch, rule):
+    """The step's jaxpr, gradient included, holds each layer's forward
+    kernel ONCE with regions on, as with regions off (under a bare
+    jax.checkpoint it held it twice: once forward, once again before
+    the backward kernels), and the backward kernels once."""
+    _through(monkeypatch, rule)
+    backward = (dict(flash_bwd_dq=_LAYERS, flash_bwd_dkv=_LAYERS)
+                if rule == "_flash2" else dict(flash_bwd=_LAYERS))
+    for recompute in (False, True):
+        kernels = _kernels(_step_jaxpr(*_region_lm(recompute, "kj_")))
+        assert kernels == dict(flash_fwd=_LAYERS, **backward), recompute
+
+
+def test_region_with_no_kernel_lowers_as_a_bare_checkpoint(monkeypatch):
+    """The dense path (no kernel, so no named value) under the region's
+    policy: the step's jaxpr is, equation for equation, what a bare
+    jax.checkpoint gives, and nothing is counted as kept."""
+    kept = CF._KEPT_BYTES.snapshot()
+    got = _equations(_step_jaxpr(*_region_lm(True, "kd_")))
+    assert CF._KEPT_BYTES.snapshot() == kept
+    monkeypatch.setattr(CF, "_region_policy", None)
+    bare = _equations(_step_jaxpr(*_region_lm(True, "kd_")))
+    assert not [e for e in got if e[0] == "pallas_call"]
+    assert len([e for e in got if "remat" in e[0]]) == _LAYERS
+    assert got == bare

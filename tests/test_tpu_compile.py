@@ -174,6 +174,54 @@ def test_nothing_moves_a_head_between_a_projection_and_the_kernels(chip):
         == {("4,2048,1024", "2,1,0")}
 
 
+def test_a_region_keeps_the_flash_results_and_runs_the_forward_once(chip):
+    """Two window layers at Trinity's widths (hidden 2048, 32 query
+    heads of 128 reading 4, one sequence of 16,384 under a window of
+    2048), projections round the kernels, each layer a recompute region
+    as `recompute_block` lowers one (ISSUE 42): the step compiled for the
+    v5e runs `flash_fwd` once a layer and `flash_bwd` once a layer, where
+    under a bare jax.checkpoint a forward runs again before its backward;
+    and what it holds more than the bare compile, by the compiler's own
+    count of temporaries, is at most the named values, out [T, 4096]
+    bf16 and lse [32, T] float32 a layer, and 1 MiB."""
+    import collections
+    import re
+    from paddle_tpu.ops import control_flow as CF
+    layers, t, hidden, h, hkv, d = 2, 16384, 2048, 32, 4, 128
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    x = aval(1, t, hidden)
+    ws = [(aval(hidden, h * d), aval(hidden, hkv * d), aval(hidden, hkv * d),
+           aval(h * d, hidden))] * layers
+
+    def layer(x, wq, wk, wv, wo):
+        a = flash_bthd(x @ wq, x @ wk, x @ wv, h, causal=True,
+                       force="pallas", n_kv_head=hkv, window=2048)
+        return x + a @ wo
+
+    def compiled(policy):
+        def loss(x, ws):
+            for w in ws:
+                x = jax.checkpoint(layer, policy=policy)(x, *w)
+            return x.astype(jnp.float32).sum()
+
+        step = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, ws).compile()
+        kernels = re.findall(r"^\s*%?(flash_\w+?)[.\d]* = ", step.as_text(),
+                             re.M)
+        return (dict(collections.Counter(kernels)),
+                step.memory_analysis().temp_size_in_bytes)
+
+    kernels, temporaries = compiled(CF._region_policy)
+    bare_kernels, bare_temporaries = compiled(None)
+    assert kernels == {"flash_fwd": layers, "flash_bwd": layers}
+    assert bare_kernels["flash_fwd"] > layers
+    assert bare_kernels["flash_bwd"] == layers
+    named = layers * (t * h * d * 2 + h * t * 4)
+    assert bare_temporaries < temporaries <= bare_temporaries + named + 2**20
+
+
 def test_flash_bthd_lowers_under_shard_map_dp2_tp2(topo, monkeypatch):
     """ParallelExecutor's dp2 x tp2 form of the op: batch over dp, the
     heads (a slice of the last dimension) over tp, eight heads a
