@@ -63,6 +63,12 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           cells' chunks, filled to the cells' share and filled whole:
           XLA's gather, and the scatter-add as XLA's op against the
           kernel of ops/moe_rows.py; device milliseconds and GB/s
+  hc      the hyper-connections' stages alone (ISSUE 43) at the cell
+          xing4_train_T4k's shape, a float32 stream [4096, 4 x 3584]:
+          "mix" and "merge", forward and backward, the jax.numpy form
+          against the kernels of ops/hyper_connection.py: device ms,
+          passes over the stream, GB/s, and the largest difference in
+          values and gradients
   train   T.transformer_lm -> Adam.minimize -> amp.enable_amp ->
           Executor(TPUPlace(0)); 5 steps on one batch; loss ~ ln(vocab)
           and falling; the flash kernel is in the compiled step
@@ -1373,6 +1379,97 @@ def phase_multichip(cfg, seed, rehearse):
     peak_hbm("chips4")
 
 
+def phase_hc(seed, rehearse):
+    """The hyper-connections' stages alone (ISSUE 43) at the cell
+    xing4_train_T4k's shape, a float32 stream [4096, 4 x 3584] and a
+    bfloat16 y: "mix" and "merge", forward and backward, as the
+    jax.numpy form and as the kernels of ops/hyper_connection.py, both
+    on the chip: device ms under the profiler (the backward is forward
+    and backward in one executable less the forward's), the passes over
+    the stream that time is at the HBM's 819 GB/s beside the passes the
+    stage must make (its bytes over the stream's 234.9 MB), GB/s on
+    those bytes, and the largest difference of the kernels' values and
+    gradients from the jax.numpy form's, as a share of the largest
+    value. XLA's `copy` ops are left out of the times: a backward kernel
+    overwrites the cotangent it is handed, which in a step is dead by
+    then and here is still the probe's, so XLA copies it first."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import hyper_connection as hc
+    n, d, rows, calls = (4, 128, 64, 2) if rehearse else (4, 3584, 4096, 8)
+    c = n * (n + 2)
+    rng = np.random.RandomState(seed)
+    f32 = lambda *shape, scale=1.0: jnp.asarray(rng.randn(*shape) * scale,
+                                                jnp.float32)
+    x, g = f32(rows, n * d), f32(rows, n * d)
+    proj, alpha = f32(n * d, c, scale=0.02), jnp.asarray([0.5, 0.7, 0.9])
+    bias = f32(c, scale=0.3)
+    y = f32(rows, d).astype(jnp.bfloat16)
+    stream = x.size * 4
+    kernels = "interpret" if rehearse else "pallas"
+
+    def mix(path, x, proj, alpha, bias):
+        return hc.mix_stage(x, proj, alpha, bias, n, 20, 1e-6,
+                            (-30.0, 30.0), force=path)
+
+    def merge(path, x, post, res, y):
+        return hc.merge_stage(x, post, res, y, n, force=path)
+
+    def forward(stage, *args):
+        out = stage(*args)      # the stream "mix" hands through is x
+        return out[:3] if stage.func is mix else out
+
+    def both(stage, *args_and_cotangents):
+        out, pull = jax.vjp(stage, *args_and_cotangents[:4])
+        cots = args_and_cotangents[4:]
+        return (out[:3], pull(cots)) if stage.func is mix \
+            else (out, pull(cots[0]))
+
+    _, post, res, _ = jax.jit(functools.partial(mix, "xla"))(
+        x, proj, alpha, bias)
+    dh, dpost, dres = f32(rows, d), f32(rows, n), f32(n, n, rows)
+    # stage: its arguments, its cotangents, the passes over the stream
+    # it must make forward and backward
+    stages = {"mix": (mix, (x, proj, alpha, bias), (dh, dpost, dres, g),
+                      1.25, 3.25),
+              "merge": (merge, (x, post, res, y), (g,), 2.125, 3.25)}
+    for name, (fn, args, cots, need_f, need_b) in stages.items():
+        got = {}
+        for path in ("xla", kernels):
+            stage = functools.partial(fn, path)
+            f_ms, _, f_ops = _device_ms(
+                jax.jit(functools.partial(forward, stage)), args, calls,
+                rehearse, "hc_trace")
+            fb_ms, got[path], fb_ops = _device_ms(
+                jax.jit(functools.partial(both, stage)), args + cots, calls,
+                rehearse, "hc_trace")
+            f_ms, fb_ms = f_ms - f_ops["copy"], fb_ms - fb_ops["copy"]
+            for way, ms, need in (("forward", f_ms, need_f),
+                                  ("backward", fb_ms - f_ms, need_b)):
+                log("[hc] %s %s, %s: %.3f ms = %.2f passes over the %.1f MB "
+                    "stream at 819 GB/s, where %.3f are needed (%.0f GB/s "
+                    "on those bytes)" % (
+                        name, way, path, ms, ms * 819e6 / stream,
+                        stream / 1e6, need, need * stream / 1e6 / ms))
+            top = lambda ops: ", ".join(
+                "%s %.3f" % kv for kv in ops.most_common(6))
+            log("[hc] %s %s ops, ms a call: forward %s; both %s" % (
+                name, path, top(f_ops), top(fb_ops)))
+        wide = lambda a: np.asarray(a, np.float32)
+        leaves = jax.tree_util.tree_leaves
+        errs = [(float(np.abs(wide(a) - wide(b)).max()
+                       / (np.abs(wide(b)).max() + 1e-30)), str(b.dtype))
+                for a, b in zip(leaves(got[kernels]), leaves(got["xla"]))]
+        log("[hc] %s: the kernels' values and gradients from the jax.numpy "
+            "form's, largest first: %s" % (name, " ".join(
+                "%.1e (%s)" % e for e in sorted(errs)[::-1])))
+        # a bfloat16 result (dy) may round the other way: 2^-8 of a value
+        assert all(e <= (2e-5 if dtype == "float32" else 2 ** -7)
+                   for e, dtype in errs), errs
+    if rehearse:
+        log("[hc] (REHEARSAL: a CPU's times, no device number)")
+
+
 # --------------------------------------------------------------------------
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -1385,7 +1482,7 @@ def main():
                          "(flash, gqa, own_block, mla, window, scan, diff, "
                          "rotary, "
                          "experts, "
-                         "rows, "
+                         "rows, hc, "
                          "train, serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: tiny size, Pallas kernels in "
@@ -1412,7 +1509,7 @@ def main():
                   "window": phase_window, "scan": phase_scan,
                   "diff": phase_diff, "rotary": phase_rotary,
                   "experts": phase_experts,
-                  "rows": phase_rows,
+                  "rows": phase_rows, "hc": phase_hc,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}
         for name in (args.phases.split(",") if args.phases else phases):

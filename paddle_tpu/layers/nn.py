@@ -609,13 +609,17 @@ def hyper_connection(x, lanes, stage, y=None, coefficients=None,
 
     * ``"widen"``: x ``[B, T, d]`` copied to the n lanes;
     * ``"mix"``: the stream's coefficients and the sublayer's input:
-      returns ``(H_pre X [B, T, d], (post, res))``, the pair to hand to
-      "merge". Parameters ``<name>.proj`` [n d, n (n + 2)] (N(0, 0.02)),
-      ``<name>.alpha`` [3] (`alpha_init`) and ``<name>.bias`` [n (n + 2)]
-      (zero but `res_diagonal` on the residual mix's diagonal, so that
-      it starts near the identity);
+      returns ``(H_pre X [B, T, d], (post, res, through))``, what to hand
+      to "merge": the coefficients and the stream itself, which "mix"
+      passes through so that the two stages' gradients to it are summed
+      where "mix"'s is made (``ops/hyper_connection.py``). Parameters
+      ``<name>.proj`` [n d, n (n + 2)] (N(0, 0.02)), ``<name>.alpha`` [3]
+      (`alpha_init`) and ``<name>.bias`` [n (n + 2)] (zero but
+      `res_diagonal` on the residual mix's diagonal, so that it starts
+      near the identity);
     * ``"merge"``: ``H_res X + H_post^T y`` for the sublayer's output y
-      ``[B, T, d]`` and `coefficients` from "mix";
+      ``[B, T, d]`` and `coefficients` from "mix" (the stream is read
+      from them where they carry it, else from x);
     * ``"narrow"``: the lanes summed, ``[B, T, d]``."""
     from ..initializer import (ConstantInitializer, NormalInitializer,
                                NumpyArrayInitializer)
@@ -643,6 +647,7 @@ def hyper_connection(x, lanes, stage, y=None, coefficients=None,
         out = new(lead + (width // n,))
         rows = int(np.prod(lead)) if all(int(s) > 0 for s in lead) else -1
         post, res = new((rows, n)), new((n, n, rows))
+        through = new(lead + (width,))
         attrs.update(sinkhorn_iters=int(sinkhorn_iters),
                      sinkhorn_eps=float(sinkhorn_eps),
                      clamp_min=float(clamp[0]), clamp_max=float(clamp[1]),
@@ -651,11 +656,12 @@ def hyper_connection(x, lanes, stage, y=None, coefficients=None,
             type="hyper_connection",
             inputs={"X": [x], "Proj": [proj], "Alpha": [alpha],
                     "Bias": [bias]},
-            outputs={"Out": [out], "Post": [post], "Res": [res]}, attrs=attrs)
-        return out, (post, res)
+            outputs={"Out": [out], "Post": [post], "Res": [res],
+                     "Through": [through]}, attrs=attrs)
+        return out, (post, res, through)
     if stage != "merge":
         raise ValueError("hyper_connection: no stage %r" % (stage,))
-    post, res = coefficients
+    post, res, x = (tuple(coefficients) + (x,))[:3]
     out = new(lead + (width,))
     helper.append_op(
         type="hyper_connection",

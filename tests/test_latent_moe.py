@@ -217,11 +217,19 @@ def test_hyper_connection_is_the_references():
 
 
 def test_hyper_connection_counter():
-    labels = dict(lanes="4", sinkhorn_iters="20")
-    was = HC._LOWERINGS.value(**labels)
-    HC.coefficients(_r(3, 32), _r(32, 24), jnp.ones(3), jnp.zeros(24), 4,
-                    20, 1e-6, (-30.0, 30.0))
-    assert HC._LOWERINGS.value(**labels) == was + 1
+    """One count a stage lowered, by the path it took: d 8 is no whole
+    lane tile, so both stages take the jax.numpy form."""
+    mixed = dict(lanes="4", sinkhorn_iters="20", path="xla", stage="mix")
+    merged = dict(lanes="4", sinkhorn_iters="", path="xla", stage="merge")
+    was = HC._LOWERINGS.value(**mixed), HC._LOWERINGS.value(**merged)
+    x = _r(3, 32)
+    h, post, res, through = HC.mix_stage(
+        x, _r(32, 24), jnp.ones(3), jnp.zeros(24), 4, 20, 1e-6,
+        (-30.0, 30.0))
+    assert through is x
+    HC.merge_stage(through, post, res, h, 4)
+    assert (HC._LOWERINGS.value(**mixed),
+            HC._LOWERINGS.value(**merged)) == (was[0] + 1, was[1] + 1)
 
 
 # -- the sigmoid router, its selection bias, and the shares ---------------------
