@@ -308,7 +308,7 @@ def _attend_pallas(q, pool_k, pool_v, btab, qpos, k_scale, v_scale,
 # --------------------------------------------------------------------------
 def _resolve_path(q, force):
     # every (dk, bs, pool dtype) compiles for the v5e — each block's
-    # last two dims are its array's own (tests/test_tpu_compile.py) —
+    # last two dims are its array's own (tests/test_tpu_compile_paged.py) —
     # so a TPU always takes the kernel; there is no shape carve-out
     # that could hand a TPU call to lax unseen
     if force is not None:

@@ -11,6 +11,7 @@ The ops: "rms_norm", "rope", "silu_mul", "block_diffusion_noise",
 mode against the reference's ``_rms`` and ``_rope``.
 """
 
+import functools
 import os
 import sys
 
@@ -20,6 +21,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+import small_model_test
+from flash_test import _with_grads
 from op_test import check_grad, check_output, run_op
 from paddle_tpu.ops import block_diffusion as BD
 from paddle_tpu.ops import rotary
@@ -87,6 +90,14 @@ def _largest(a, b):
     return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _out_and_grads(fn, x, w, dy):
+    """fn(x, w) and dy's pull-back to (x, w) as ONE program: the
+    reference walks the heads in Python, an op at a time eagerly."""
+    out, pull = jax.vjp(fn, x, w)
+    return out, pull(dy)
+
+
 # L 136: 272 rows a sequence, which the 256 rows that 1 MB of float32
 # holds at 1024 lanes do not divide: a block is the period's divisor, 136
 @pytest.mark.parametrize("wrap", [0, 136], ids=["by_index", "two_halves"])
@@ -107,18 +118,19 @@ def test_norm_rope_kernel_is_the_reference(n_head, wrap):
                              min(n_head * d, 1024))
     run = lambda force: lambda x, w: rotary.norm_rope(
         x, w, n_head, 1e6, wrap, 1e-6, force=force)
-    want, pull = jax.vjp(
-        lambda x, w: _reference_norm_rope(x, w, n_head, wrap), x, w)
+    want, pulled = _out_and_grads(
+        lambda x, w: _reference_norm_rope(x, w, n_head, wrap), x, w, dy)
     for force in ("interpret", "xla"):
-        got, vjp = jax.vjp(run(force), x, w)
+        got, grads = _out_and_grads(run(force), x, w, dy)
         assert _largest(got, want) < 1e-6
-        for mine, ref in zip(vjp(dy), pull(dy)):
+        for mine, ref in zip(grads, pulled):
             assert _largest(mine, ref) < 2e-6
     if wrap:
         twice = jnp.concatenate([x[:, :wrap]] * 2, 1)
-        got, vjp = jax.vjp(run("interpret"), twice, w)
+        got, (dx, _) = _out_and_grads(
+            run("interpret"), twice, w,
+            jnp.concatenate([dy[:, :wrap]] * 2, 1))
         np.testing.assert_array_equal(got[:, :wrap], got[:, wrap:])
-        dx = vjp(jnp.concatenate([dy[:, :wrap]] * 2, 1))[0]
         np.testing.assert_array_equal(dx[:, :wrap], dx[:, wrap:])
 
 
@@ -134,13 +146,12 @@ def test_norm_rope_kernel_serves_each_op_alone(norm, rotate):
     args = (n_head, 1e6 if rotate else None, 16, 1e-6)
     run = lambda x, w: rotary.norm_rope(x, w if norm else None, *args,
                                         force="interpret")
-    want, pull = jax.vjp(lambda x, w: _reference_norm_rope(
-        x, w, n_head, 16, norm, rotate), x, w)
-    got, vjp = jax.vjp(run, x, w)
+    want, pulled = _out_and_grads(lambda x, w: _reference_norm_rope(
+        x, w, n_head, 16, norm, rotate), x, w, dy)
+    got, (dx, dw) = _out_and_grads(run, x, w, dy)
     assert _largest(got, want) < 1e-6
-    dx, dw = vjp(dy)
-    assert _largest(dx, pull(dy)[0]) < 2e-6
-    assert _largest(dw, pull(dy)[1]) < 2e-6 if norm else not dw.any()
+    assert _largest(dx, pulled[0]) < 2e-6
+    assert _largest(dw, pulled[1]) < 2e-6 if norm else not dw.any()
     half = run(x.astype(jnp.bfloat16), w)
     assert half.dtype == jnp.bfloat16
     assert _largest(half.astype(jnp.float32), want) < 2 ** -7
@@ -260,10 +271,13 @@ def test_two_piece_attention_is_the_dense_mask(block, force):
     dy = jnp.asarray(_r(1, 2 * seq, h * d, seed=10))
     got = lambda q, k, v: BD.attention(q, k, v, h, hkv, block, force=force)
     want = lambda q, k, v: _dense_bd_attention(q, k, v, h, hkv, block)
-    np.testing.assert_allclose(got(q, k, v), want(q, k, v), atol=2e-5)
-    loss = lambda f: lambda *a: (f(*a) * dy).sum()
-    for name, a, b in zip("qkv", jax.grad(loss(got), (0, 1, 2))(q, k, v),
-                          jax.grad(loss(want), (0, 1, 2))(q, k, v)):
+    # each side's output and gradients as ONE program: eagerly the
+    # mask written out and its transpose compile an op at a time
+    (o_got, g_got), (o_want, g_want) = (
+        jax.jit(_with_grads(f, lambda o: (o * dy).sum()))(q, k, v)
+        for f in (got, want))
+    np.testing.assert_allclose(o_got, o_want, atol=2e-5)
+    for name, a, b in zip("qkv", g_got, g_want):
         assert np.isfinite(np.asarray(a)).all(), name
         np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
 
@@ -318,8 +332,10 @@ def test_expert_layer_is_the_dense_loop(routing):
     np.testing.assert_allclose(_held(*args, 4, HELD),
                                _dense_experts(*args, 4, HELD), atol=1e-5)
     sq = lambda f: lambda *a: (f(*a, 4, HELD) ** 2).sum()
-    got = jax.grad(sq(_held), (0, 1, 2, 3, 4))(*args)
-    want = jax.grad(sq(_dense_experts), (0, 1, 2, 3, 4))(*args)
+    # each as ONE program: eagerly the layer's loop, the dense loop over
+    # the experts and their transposes are compiled an op at a time
+    got = jax.jit(jax.grad(sq(_held), (0, 1, 2, 3, 4)))(*args)
+    want = jax.jit(jax.grad(sq(_dense_experts), (0, 1, 2, 3, 4)))(*args)
     for name, a, b in zip(("x", "router", "gate", "up", "down"), got, want):
         scale = float(jnp.max(jnp.abs(b))) + 1e-9
         assert float(jnp.max(jnp.abs(a - b))) / scale < 1e-4, name
@@ -383,16 +399,16 @@ CFG = {"vocab_size": 96, "num_hidden_layers": 2, "hidden_size": 32,
 SEQ = 32
 
 
-def _small_model():
-    from chipbench import cells
-    arch = cells.load_arch("sdar")
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 11
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        cost, logits = arch.build(CFG, SEQ)
-        forward = main.clone(for_test=True)
-    return arch, main, startup, forward, scope, cost, logits
+@pytest.fixture(scope="module")
+def _initialised():
+    return small_model_test.initialised("sdar", CFG, SEQ)
+
+
+@pytest.fixture
+def small_model(_initialised):
+    """(arch, main, forward, scope, cost, logits) as
+    initialised, ONCE a file (tests/small_model_test.py)."""
+    return small_model_test.as_initialised(*_initialised)
 
 
 def _batch(rows=2):
@@ -402,40 +418,41 @@ def _batch(rows=2):
             "mask": (rng.rand(rows, SEQ) > 0.2).astype(np.float32)}
 
 
-def test_small_model_loss_and_logits_are_the_references():
-    arch, main, startup, forward, scope, cost, logits = _small_model()
+def test_small_model_loss_and_logits_are_the_references(small_model):
+    arch, main, forward, scope, cost, logits = small_model
     feed = _batch()
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(scope):
-        exe.run(startup)
         params = arch.params_of_program(main, scope, CFG)
         assert int(params["salt"]) == 11 and int(params["step"]) == 0
         fetched = exe.run(forward, feed=feed, fetch_list=[cost, logits] + list(
             arch.router_choices(forward)))
     got_cost, got_logits, choices = fetched[0], fetched[1], fetched[2:]
-    want = arch.lm_loss(params, feed["src"], feed["label"], feed["mask"], CFG)
+    # the reference as ONE program each: eagerly it is a hundred small
+    # compilations, more seconds than the model under test takes
+    want = jax.jit(lambda p, *batch: arch.lm_loss(p, *batch, CFG))(
+        params, feed["src"], feed["label"], feed["mask"])
     np.testing.assert_allclose(got_cost, want, rtol=2e-5)
     assert len(choices) == 2 and choices[0].shape == (2, 2 * SEQ, 4)
+    logits_at = jax.jit(lambda p, tokens, chosen=None: arch.logits_at(
+        p, tokens, 0, SEQ, CFG, chosen))
     for row in range(1):       # logits_at is batch row 0's draw
-        ref = arch.logits_at(params, jnp.asarray(feed["src"][row]), 0, SEQ,
-                             CFG)
+        ref = logits_at(params, jnp.asarray(feed["src"][row]))
         np.testing.assert_allclose(got_logits[row], ref, atol=2e-5)
         # handed the program's own choices the reference changes nothing
-        handed = arch.logits_at(params, jnp.asarray(feed["src"][row]), 0,
-                                SEQ, CFG, np.stack([c[:1] for c in choices]))
+        handed = logits_at(params, jnp.asarray(feed["src"][row]),
+                           np.stack([c[:1] for c in choices]))
         np.testing.assert_allclose(handed, ref, atol=1e-6)
 
 
-def test_small_model_one_steps_gradients_are_the_references():
+def test_small_model_one_steps_gradients_are_the_references(small_model):
     """SGD at rate 1 turns a step's parameter change into its gradient:
     every parameter's against jax.grad of the reference's loss; the
     step counter and the experts' loads advance."""
-    arch, main, startup, _, scope, cost, _ = _small_model()
+    arch, main, _, scope, cost, _ = small_model
     feed = _batch()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        fluid.optimizer.SGD(learning_rate=1.0).minimize(cost)
+    with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
         before = arch.params_of_program(main, scope, CFG)
         exe.run(main, feed=feed, fetch_list=[cost])
         after = arch.params_of_program(main, scope, CFG)
@@ -444,9 +461,9 @@ def test_small_model_one_steps_gradients_are_the_references():
     assert sum(counters["expert_rows"]) == 2 * 2 * (2 * SEQ) * 4
     floats = lambda p: {k: v for k, v in p.items()
                         if k not in ("salt", "step")}
-    grads = jax.grad(lambda p: arch.lm_loss(
+    grads = jax.jit(jax.grad(lambda p: arch.lm_loss(
         {**p, "salt": before["salt"], "step": before["step"]},
-        feed["src"], feed["label"], feed["mask"], CFG))(floats(before))
+        feed["src"], feed["label"], feed["mask"], CFG)))(floats(before))
     moved = jax.tree.map(lambda a, b: a - b, floats(before), floats(after))
     flat_g, _ = jax.tree_util.tree_flatten_with_path(grads)
     flat_m = jax.tree.leaves(moved)

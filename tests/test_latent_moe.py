@@ -9,6 +9,7 @@ small model against the benchmark's float32 reference
 "hyper_connection" and the new inputs of "routed_experts".
 """
 
+import functools
 import os
 import sys
 
@@ -18,6 +19,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+import small_model_test
+from flash_test import _with_grads
 from paddle_tpu.ops import flash_attention as FA
 from paddle_tpu.ops import hyper_connection as HC
 from paddle_tpu.ops import latent_attention as LA
@@ -43,9 +46,11 @@ def _two_parts(b, h, t, d, d2, seed=0):
             _r(b, t, d2, seed=seed + 4, scale=0.3))
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6))
 def _dense_two_parts(q, k, v, q2, k2, h, scale):
     """softmax((q k^T + q2 k2^T) scale) v written out, a head at a
-    time, k2 the one key every head reads."""
+    time, k2 the one key every head reads (ONE program: eagerly an op
+    at a time is compiled)."""
     b, t, _ = q.shape
     heads = lambda x: x.reshape(b, t, h, -1).transpose(0, 2, 1, 3)
     s = jnp.einsum("bhqd,bhkd->bhqk", heads(q), heads(k)) \
@@ -73,16 +78,16 @@ def test_two_part_kernels_against_dense_float32(t, d2, block):
                              force="interpret", block_q=block, block_k=block,
                              q2=a[3], k2=a[4])
 
-    np.testing.assert_allclose(kernels(*args), _dense_two_parts(
-        *args, h, scale), atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(kernels(*a) * w),
-                   argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(lambda *a: jnp.sum(_dense_two_parts(*a, h, scale) * w),
-                    argnums=(0, 1, 2, 3, 4))(*args)
+    # each side's output and five gradients as ONE program
+    (o_got, got), (o_want, want) = (
+        jax.jit(_with_grads(f, lambda o: jnp.sum(o * w)))(*args)
+        for f in (kernels, lambda *a: _dense_two_parts(*a, h, scale)))
+    np.testing.assert_allclose(o_got, o_want, atol=2e-6)
     for name, g, r in zip(("dq_nope", "dk_nope", "dv", "dq_pe", "dk_pe"),
                           got, want):
         assert g.shape == r.shape
-        err = float(jnp.max(jnp.abs(g - r)) / jnp.max(jnp.abs(r)))
+        g, r = np.asarray(g), np.asarray(r)
+        err = float(np.max(np.abs(g - r)) / np.max(np.abs(r)))
         assert err < 1e-5, (name, err)
 
 
@@ -321,7 +326,7 @@ CFG = {"arch": "xing", "vocab_size": 96, "num_hidden_layers": 3,
        "n_routed_experts": 4, "published": {"n_routed_experts": 8},
        "first_expert": 2, "num_experts_per_tok": 2, "n_shared_experts": 1,
        "norm_topk_prob": True, "routed_scaling_factor": 2,
-       "bias_update_rate": 1e-3, "hc_mult": 4, "hc_sinkhorn_iters": 20,
+       "bias_update_rate": 1e-3, "hc_mult": 4, "hc_sinkhorn_iters": 3,
        "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
        "mhc_h_res_clamp_max": 30, "rope_theta": 10000,
        "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 64,
@@ -332,16 +337,16 @@ CFG = {"arch": "xing", "vocab_size": 96, "num_hidden_layers": 3,
 SEQ = 32
 
 
-def _small_model():
-    from chipbench import cells
-    arch = cells.load_arch("xing")
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 11
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        cost, logits = arch.build(CFG, SEQ)
-        forward = main.clone(for_test=True)
-    return arch, main, startup, forward, scope, cost, logits
+@pytest.fixture(scope="module")
+def _initialised():
+    return small_model_test.initialised("xing", CFG, SEQ)
+
+
+@pytest.fixture
+def small_model(_initialised):
+    """(arch, main, forward, scope, cost, logits) as
+    initialised, ONCE a file (tests/small_model_test.py)."""
+    return small_model_test.as_initialised(*_initialised)
 
 
 def _batch(rows=2):
@@ -351,14 +356,13 @@ def _batch(rows=2):
             "mask": (rng.rand(rows, SEQ) > 0.2).astype(np.float32)}
 
 
-def test_small_model_loss_and_logits_are_the_references():
+def test_small_model_loss_and_logits_are_the_references(small_model):
     """The for_test clone's loss and logits, with every routed layer's
     choices fetched from INSIDE its recompute region in the same run."""
-    arch, main, startup, forward, scope, cost, logits = _small_model()
+    arch, main, forward, scope, cost, logits = small_model
     feed = _batch()
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(scope):
-        exe.run(startup)
         params = arch.params_of_program(main, scope, CFG)
         names = arch.router_choices(forward)
         fetched = exe.run(forward, feed=feed,
@@ -367,33 +371,34 @@ def test_small_model_loss_and_logits_are_the_references():
     assert sum(op.type == "recompute_block"
                for op in forward.global_block().ops) == 3
     got_cost, got_logits, choices = fetched[0], fetched[1], fetched[2:]
-    want = arch.lm_loss(params, feed["src"], feed["label"], feed["mask"], CFG)
+    # the reference as ONE program each: eagerly it is some 150 small
+    # compilations, more seconds than the model under test takes
+    want = jax.jit(lambda p, *batch: arch.lm_loss(p, *batch, CFG))(
+        params, feed["src"], feed["label"], feed["mask"])
     np.testing.assert_allclose(got_cost, want, rtol=2e-5)
     assert len(choices) == 2 and choices[0].shape == (2, SEQ, 2)
     # a for_test run counts nothing and moves no bias
     assert counters["steps"] == [0] and sum(counters["expert_rows"]) == 0
+    logits_at = jax.jit(lambda p, tokens, chosen=None: arch.logits_at(
+        p, tokens, 0, SEQ, CFG, chosen))
     for row in range(2):
-        ref = arch.logits_at(params, jnp.asarray(feed["src"][row]), 0, SEQ,
-                             CFG)
+        ref = logits_at(params, jnp.asarray(feed["src"][row]))
         np.testing.assert_allclose(got_logits[row], ref, atol=3e-5)
-        handed = arch.logits_at(
-            params, jnp.asarray(feed["src"][row]), 0, SEQ, CFG,
-            np.stack([c[row:row + 1] for c in choices]))
+        handed = logits_at(params, jnp.asarray(feed["src"][row]),
+                           np.stack([c[row:row + 1] for c in choices]))
         np.testing.assert_allclose(handed, ref, atol=1e-6)
 
 
-def test_small_model_one_steps_gradients_are_the_references():
+def test_small_model_one_steps_gradients_are_the_references(small_model):
     """SGD at rate 1 turns a step's parameter change into its gradient:
     every parameter's against jax.grad of the reference's loss, through
     the recompute regions. The regions run twice a step (forward and
     rematerialised) and the counts, the step counter and the selection
     bias move ONCE."""
-    arch, main, startup, _, scope, cost, _ = _small_model()
+    arch, main, _, scope, cost, _ = small_model
     feed = _batch()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        fluid.optimizer.SGD(learning_rate=1.0).minimize(cost)
+    with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
         before = arch.params_of_program(main, scope, CFG)
         exe.run(main, feed=feed, fetch_list=[cost])
         after = arch.params_of_program(main, scope, CFG)
@@ -419,7 +424,7 @@ def test_small_model_one_steps_gradients_are_the_references():
         return arch.lm_loss(whole, feed["src"], feed["label"], feed["mask"],
                             CFG)
 
-    grads = jax.grad(loss)(floats(before))
+    grads = jax.jit(jax.grad(loss))(floats(before))
     moved = jax.tree.map(lambda a, b: a - b, floats(before), floats(after))
     flat_g, _ = jax.tree_util.tree_flatten_with_path(grads)
     flat_m = jax.tree.leaves(moved)

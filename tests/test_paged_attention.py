@@ -10,7 +10,7 @@ module holds the pins the kernel tier itself needs:
   * kernel math vs a dense-softmax reference: the lax chain-walk path
     (grouped and ungrouped), the 5-D full-pool + static-layer calling
     shape, the γ+1 multi-query shape, and the dynamic ``nblk`` bound;
-  * interpret-mode Pallas parity (tests/test_flash_attention.py
+  * interpret-mode Pallas parity (tests/test_flash_entries.py
     style): the TPU kernel's math checked on CPU via interpret=True
     against the lax reference;
   * the EXPLICIT block-vs-gather A/B the identity lattice rests on:

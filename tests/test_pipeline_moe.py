@@ -45,7 +45,7 @@ def test_gpipe_differentiable():
     def loss(params):
         return jnp.sum(parallel.gpipe(stage_fn, params, xs, mesh) ** 2)
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)    # ONE program, not an op at a time
     arr = np.asarray(g["w"])
     assert np.isfinite(arr).all()
     assert np.abs(arr).max() > 0
@@ -172,7 +172,7 @@ def test_moe_top2_grads_reach_gate_and_experts():
         out, aux = parallel.moe_ffn(x, p["g"], p["u"], p["d"], top_k=2)
         return jnp.sum(out ** 2) + 0.01 * aux
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)    # ONE program, not an op at a time
     for k in ("g", "u", "d"):
         arr = np.asarray(g[k])
         assert np.isfinite(arr).all() and np.abs(arr).max() > 0, k
@@ -225,7 +225,7 @@ def test_gpipe_heterogeneous_stage_params():
         return jnp.sum(parallel.gpipe(stage_fn, ps, jnp.asarray(xs),
                                       mesh, axis_name="pp") ** 2)
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)    # ONE program, not an op at a time
     assert np.abs(np.asarray(g[0]["w"])).max() > 0
     assert np.abs(np.asarray(g[1]["s"])).max() > 0
 
@@ -265,7 +265,7 @@ def test_gpipe_interleaved_matches_sequential():
             stage_fn, ps, jnp.asarray(xs), mesh, n_chunks=v,
             axis_name="pp") ** 2)
 
-    g = np.asarray(jax.grad(loss)(params)["w"])
+    g = np.asarray(jax.jit(jax.grad(loss))(params)["w"])
     assert np.isfinite(g).all()
     assert (np.abs(g).reshape(s * v, -1).max(axis=1) > 0).all()
 

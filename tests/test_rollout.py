@@ -438,6 +438,15 @@ CHAOS_SPEC = {
     "kill": [{"target": "shadow", "after": 2},
              {"target": "canary", "after": 1}],
 }
+# The chaos pass's verdict waits on the EVENT it needs: enough joined
+# pairs that the one cell the shadow kill fells cannot decide it. A
+# felled cell takes its in-flight copies with it (its 2 slots and the
+# router's window of 3: at most 5), each joining as a disagreeing pair
+# that carries the error; at DELTA's gate of 6 pairs the verdict landed
+# on whatever had joined when the kill struck (14 pairs with 3 cut down
+# read 0.786 against the 0.9 asked: 3 runs of 4 alone, PR 44), which is
+# a race. 50 clean pairs to 5 cut down is 0.909.
+CHAOS_DELTA = dict(DELTA, min_pairs=55)
 
 
 def _run_rollout_chaos(arts, reqs, seq, seed, tmp_path, tag):
@@ -469,7 +478,7 @@ def _run_rollout_chaos(arts, reqs, seq, seed, tmp_path, tag):
 
         ctl = RolloutController(
             kvs.endpoint, router, auto, arts["v2"],
-            {"delta": DELTA}, candidates=2, shadow_fraction=1.0,
+            {"delta": CHAOS_DELTA}, candidates=2, shadow_fraction=1.0,
             canary_weight=0.4, verdict_timeout=60.0, max_respawns=4,
             slots=2, ttl=0.4, prefill_chunk=4)
         done = {}
@@ -500,7 +509,7 @@ def _run_rollout_chaos(arts, reqs, seq, seed, tmp_path, tag):
         assert st["verdicts"]["shadow"]["verdict"] == "PASS"
         assert st["verdicts"]["canary"]["verdict"] == "PASS"
         assert st["verdicts"]["shadow"]["pairs"] \
-            >= DELTA["min_pairs"]
+            >= CHAOS_DELTA["min_pairs"]
         assert st["convergence_s"] and st["convergence_s"] > 0
 
         # chaos actually fired: frame faults + both mid-phase kills
@@ -516,7 +525,7 @@ def _run_rollout_chaos(arts, reqs, seq, seed, tmp_path, tag):
         assert rst["failed"] == 0
         assert rst["shed"] == 0
         assert rst["completed"] == rst["requests"] == len(out)
-        assert rst["mirror_pairs"] >= DELTA["min_pairs"]
+        assert rst["mirror_pairs"] >= CHAOS_DELTA["min_pairs"]
         assert rst["canary_served"] >= 1
 
         # the fleet converged to v2-only; elasticity was untouched
@@ -563,7 +572,7 @@ def test_rollout_chaos_pass_promotes(rng, arts, tmp_path):
     assert [(v["phase"], v["verdict"]) for v in verd] == \
         [("shadow", "PASS"), ("canary", "PASS")]
     pairs = [r for r in rows if r["ev"] == "mirror_pair"]
-    assert len(pairs) >= DELTA["min_pairs"]
+    assert len(pairs) >= CHAOS_DELTA["min_pairs"]
     assert all(r["version"] == "v2" and r["rid"] for r in pairs)
     # same weights -> every CLEAN pair agrees; a copy cut down by the
     # chaos kill joins as a disagreeing pair carrying the error (the

@@ -6,6 +6,8 @@ operands, step sizes that make the state decay to nothing or hardly at
 all; the dispatch and its counter; the ops round the scan.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,19 +16,39 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.ops import selective_scan as SS
+from flash_test import _with_grads
 
 F32 = jnp.float32
 
 
 def _operands(b, t, c, n, dtype, dt_bias=0.0, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
-    draw = lambda k, *shape: jax.random.normal(k, shape, F32)
-    return (draw(ks[0], b, t, c).astype(dtype),
-            jax.nn.softplus(draw(ks[1], b, t, c) + dt_bias).astype(dtype),
-            -jnp.exp(draw(ks[2], c, n) * 0.5),
-            draw(ks[3], b, t, n).astype(dtype),
-            draw(ks[4], b, t, n).astype(dtype), draw(ks[5], c),
-            draw(ks[6], b, t, c))
+    """s, dt, A, B, C, D and a cotangent for y, drawn and rounded to
+    `dtype` on the host: no program is compiled for them."""
+    rng = np.random.RandomState(seed)
+    draw = lambda *shape: rng.randn(*shape).astype(np.float32)
+    cast = lambda x, dt=F32: jnp.asarray(x.astype(jnp.dtype(dt)))
+    return (cast(draw(b, t, c), dtype),
+            cast(np.logaddexp(draw(b, t, c) + np.float32(dt_bias), 0),
+                 dtype),                                    # softplus
+            cast(-np.exp(draw(c, n) * np.float32(0.5))),
+            cast(draw(b, t, n), dtype), cast(draw(b, t, n), dtype),
+            cast(draw(c)), cast(draw(b, t, c)))
+
+
+@functools.lru_cache(maxsize=None)
+def _y_and_gradients(chunk=None, group=None):
+    """(w, the six operands) -> (y, d sum(y w) / d each) as one compiled
+    program, the forward run once: of the kernels at a chunk and a
+    group, of the float32 step form without. Kept, so that cases of one
+    shape share what they compile."""
+    if chunk:
+        fn = lambda *a: SS.selective_scan(*a, chunk=chunk, group=group,
+                                          force="interpret")
+    else:
+        fn = lambda *a: SS.scan_steps(*(x.astype(F32) for x in a))
+
+    return jax.jit(lambda w, *ops: _with_grads(
+        fn, lambda y: jnp.sum(y.astype(F32) * w))(*ops))
 
 
 # (B, T, C, chunk, group): T a multiple of the chunk and not, under one
@@ -55,19 +77,15 @@ def test_kernels_match_the_step_form(dtype, dt_bias, b, t, c, chunk, group):
     is its last input alone; ``dt_small``: steps near 2.5e-3, decays
     near 1, the state sums the whole sequence."""
     *ops, w = _operands(b, t, c, 16, dtype, dt_bias, seed=t + c)
-    run = lambda *a: SS.selective_scan(*a, chunk=chunk, group=group,
-                                       force="interpret")
-    truth = lambda *a: SS.scan_steps(*(x.astype(F32) for x in a))
-    y, want = run(*ops), truth(*ops)
+    (y, grads), (want, wants) = (side(w, *ops) for side in (
+        _y_and_gradients(chunk, group), _y_and_gradients()))
     assert y.shape == (b, t, c) and y.dtype == dtype
     tol = 1e-5 if dtype == F32 else 1.5e-2
+    f32 = lambda x: np.asarray(x).astype(np.float32)
     close = lambda name, got, ref: np.testing.assert_allclose(
-        got.astype(F32), ref, err_msg=name,
-        atol=tol * max(float(jnp.max(jnp.abs(ref))), 1e-3))
+        f32(got), f32(ref), err_msg=name,
+        atol=tol * max(float(np.max(np.abs(f32(ref)))), 1e-3))
     close("y", y, want)
-    loss = lambda fn: lambda *a: jnp.sum(fn(*a).astype(F32) * w)
-    grads = jax.grad(loss(run), tuple(range(6)))(*ops)
-    wants = jax.grad(loss(truth), tuple(range(6)))(*ops)
     for name, got, ref, op in zip(("ds", "ddt", "dA", "dB", "dC", "dD"),
                                   grads, wants, ops):
         assert got.shape == op.shape and got.dtype == op.dtype, name

@@ -524,6 +524,15 @@ def _chaos_round(tmp_path, seed):
                 handles.append(
                     router.submit(features=_feats(rng, 1)[0]))
                 if i == 9:
+                    # the kill is to strike a cache that HOLDS rows of
+                    # the first incarnation (the invalidation asserted
+                    # below is of them): wait for that event, requests
+                    # served, not for the 0.2 s this loop has slept,
+                    # which a loaded worker's first scoring (its
+                    # compile alone) can outlast. Requests 8 and 9 may
+                    # still be in flight
+                    for h in handles[:8]:
+                        h.result(timeout=120)
                     # kill shard 0 mid-serve: checkpoint first (the
                     # durable state a real pserver already has), then
                     # the process dies

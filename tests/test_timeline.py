@@ -17,7 +17,7 @@ What is pinned here, on the CPU (counts and names, never a speed):
   * the kernels' names are in their jaxprs and the six serving scopes
     in the decode step's optimized HLO (the compile for a described
     v5e, where the kernel's name reaches the lowered text, is in
-    tests/test_tpu_compile.py).
+    tests/test_tpu_compile_paged.py).
 """
 
 import glob

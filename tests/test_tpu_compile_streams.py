@@ -1,0 +1,143 @@
+"""The kernels of the residual streams and the scan compiled for a
+described v5e (tests/tpu_compile_test.py says how and why): the
+selective scan's chunked pair (``ops/selective_scan.py``) and the
+hyper-connections' four (``ops/hyper_connection.py``), each at its
+cell's shape.
+"""
+
+import pytest
+
+from tpu_compile_test import _compiled_text, chip, topo  # noqa: F401
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+
+# ISSUE 40: the selective scan's chunked kernel pair at the cell
+# `phi4flash_train_T8k`'s shape (one packed 8,192-token sequence, 5,120
+# channels of 16 states, bf16 operands).
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_selective_scan_compiles_for_v5e(chip, direction):
+    """s and dt [1, 8192, 5120] bf16, 16 states: ONE kernel a direction
+    (the backward re-runs the forward's), named as a device trace will
+    show them; the compiled program holds no [8192, 5120, 16] value, and
+    no while loop: time is walked by the kernels' grids and the loops
+    inside them, not by 8,192 trips of XLA's."""
+    import math
+    import re
+    from paddle_tpu.ops.selective_scan import selective_scan
+    b, t, c, n = 1, 8192, 5120, 16
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+    avals = (sd((b, t, c)), sd((b, t, c)), sd((c, n), jnp.float32),
+             sd((b, t, n)), sd((b, t, n)), sd((c,), jnp.float32))
+
+    def fwd(*a):
+        return selective_scan(*a, force="pallas")
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=tuple(range(6)))
+    text = _compiled_text(fn, *avals)
+    names = ["selective_scan_fwd"] + (["selective_scan_bwd"]
+                                      if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    assert " while(" not in text
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    # the largest: B_t or C_t over 128 lanes, [1, 8192, 16, 128]
+    assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
+               ) <= b * t * c < b * t * c * n
+
+
+# the hyper-connections' kernels (ISSUE 43) at the cell xing4_train_T4k's
+# shape: a float32 stream [4096, 4 x 3584] round a stand-in sublayer that
+# hands back bfloat16, two sublayers to a recompute region as a layer of
+# the model has them.
+def test_hyper_connection_kernels_compile_for_v5e(chip):
+    """A sublayer-pass is four custom calls under the scope
+    `hyper_connection`: `hc_mix_fwd` and `hc_merge_fwd` forward,
+    `hc_merge_bwd` and `hc_mix_bwd` backward (a region's second forward
+    runs `hc_mix_fwd` again, and `hc_merge_fwd` where a later sublayer
+    reads its result). XLA itself makes NO pass over the stream between
+    "widen" and "narrow": no fusion, copy or add of the compiled step has
+    a float32 [4096, 14336] operand or result but those two stages'; the
+    stream's two cotangents a sublayer are summed inside `hc_mix_bwd`.
+    Each kernel asks for the scoped VMEM its blocks come to."""
+    import collections
+    import re
+    from paddle_tpu.ops import control_flow as CF
+    from paddle_tpu.ops import hyper_connection as HC
+    n, d, rows, regions = 4, 3584, 4096, 2
+    c, width = n * (n + 2), n * d
+    sd = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+
+    def sublayer(x, proj, alpha, bias, w):
+        with jax.named_scope("hyper_connection.1"):
+            h, post, res, through = HC.mix_stage(
+                x, proj, alpha, bias, n, 20, 1e-6, (-30.0, 30.0),
+                force="pallas")
+        y = jnp.tanh(h.astype(jnp.bfloat16) @ w)
+        with jax.named_scope("hyper_connection.2"):
+            return HC.merge_stage(through, post, res, y, n, force="pallas")
+
+    def layer(x, first, second):
+        return sublayer(sublayer(x, *first), *second)
+
+    def loss(e, params):
+        with jax.named_scope("hyper_connection.0"):
+            x = jnp.tile(e, (1, n))
+        for p in params:
+            x = jax.checkpoint(layer, policy=CF._region_policy)(x, *p)
+        with jax.named_scope("hyper_connection.3"):
+            return jnp.square(sum(HC._lanes(x, n))).sum()
+
+    p = (sd((width, c)), sd((3,)), sd((c,)), sd((d, d), jnp.bfloat16))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sd((rows, d)), [(p, p)] * regions)
+    asked = {int(x) for x in re.findall(
+        r'scoped_memory_configs[^\]]*?size\\22: (\d+)', lowered.as_text())}
+    stream, small = 4 * width, 4 * 128
+    w_bytes, dpt = 2 * width * 128, 4 * width * 72
+    blocks = {      # a grid step's rows, a row's bytes, the resident bytes
+        "hc_mix_fwd": (128, stream + 4 * d + small, w_bytes + 2 * small),
+        "hc_merge_fwd": (64, 2 * stream + 2 * d + small, 0),
+        "hc_merge_bwd": (64, 3 * stream + 4 * d + 2 * small, 0),
+        "hc_mix_bwd": (64, 3 * stream + 4 * d + 3 * small,
+                       w_bytes + 2 * small + dpt)}
+    # row blocks are double buffered, resident ones fetched once
+    want = {k: 2 * bm * row + resident + HC._SPARE_BYTES
+            for k, (bm, row, resident) in blocks.items()}
+    assert HC._block_rows(rows, width, 1) == 128
+    assert HC._block_rows(rows, width, 2) == HC._block_rows(rows, width, 3) \
+        == 64
+    # forward, a region's second forward (mix twice, merge once), backward
+    calls = {"hc_mix_fwd": 4 * regions, "hc_merge_fwd": 3 * regions,
+             "hc_merge_bwd": 2 * regions, "hc_mix_bwd": 2 * regions}
+    assert asked == set(want.values()), (asked, want)
+    assert max(asked) < 48 * 2 ** 20
+
+    text = lowered.compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    big = "f32[%d,%d]" % (rows, width)
+    passes = collections.Counter()
+    for line in entry.splitlines():
+        head, _, meta = line.partition(", metadata")
+        made = re.match(r"\s*(?:ROOT )?%?([\w.\-]+?)(?:\.\d+)? = .*? "
+                        r"([a-z][\w\-]*)\(", head)
+        if not made or big not in head or made.group(2) in (
+                "parameter", "get-tuple-element", "tuple", "bitcast"):
+            continue
+        scope = re.search(r'op_name="[^"]*?(hyper_connection\.\d)', meta)
+        assert scope, line[:300]
+        passes[made.group(1) if made.group(2) == "custom-call"
+               else "xla in " + scope.group(1)] += 1
+    assert {k: v for k, v in passes.items() if k in calls} == calls, passes
+    # "widen" is fused into the first kernel's operand or is one fusion;
+    # "narrow"'s backward is one fusion (the loss's gradient, tiled)
+    assert set(passes) - set(calls) <= {"xla in hyper_connection.0",
+                                        "xla in hyper_connection.3"}, passes
+    assert sum(v for k, v in passes.items() if k not in calls) <= 3, passes

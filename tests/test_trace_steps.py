@@ -211,12 +211,19 @@ def test_device_waited_true_after_a_numpy_fetch_none_with_no_fetch():
     assert _mine(t0)[-1]["device_waited"] is None
 
 
-def test_a_dropped_fetch_reads_none_and_lives_no_longer():
+def test_a_dropped_fetch_reads_none_and_lives_no_longer(monkeypatch):
+    """The device still at work when the step returns, as on a chip:
+    said here, not left to the race. On the CPU a step this small is
+    done by its root's exit about one time in five under load (61 of
+    300 beside six busy workers, PR 44), and a fetch that was done
+    reads True whoever holds it."""
     import weakref
     step, _, _ = _trainer()
     step(return_numpy=False)
     t0 = time.perf_counter()
-    kept = step(return_numpy=False)
+    with monkeypatch.context() as at_work:
+        at_work.setattr(trt, "_fetch_done", lambda fetch: False)
+        kept = step(return_numpy=False)
     ref = weakref.ref(kept)
     del kept                             # the ledger holds it weakly
     gc.collect()

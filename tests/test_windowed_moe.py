@@ -1,11 +1,10 @@
-"""A window bound in the flash kernels and the window-and-full
-mixture-of-experts model on it (ISSUE 38), at small sizes with seeded
-weights on the CPU: the windowed kernels in interpret mode against the
-dense form, the walk's static cuts and what they count, the ops
-"causal_attention" and "sigmoid_mul" and ``qk_norm_rope`` without its
-rotation, the shares of a 16-way expert-parallel group adding up to the
-uncut layer, and the whole small model against the benchmark's float32
-reference (``chipbench/reference/afmoe_lm.py``).
+"""The window-and-full mixture-of-experts model on a window bound in
+the flash kernels (ISSUE 38), at small sizes with seeded weights on the
+CPU (the windowed kernels themselves: tests/test_flash_window.py): the
+ops "causal_attention" and "sigmoid_mul" and ``qk_norm_rope`` without
+its rotation, the shares of a 16-way expert-parallel group adding up to
+the uncut layer, and the whole small model against the benchmark's
+float32 reference (``chipbench/reference/afmoe_lm.py``).
 """
 
 import os
@@ -17,208 +16,19 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+import small_model_test
 from paddle_tpu.ops import causal_attention as CA
-from paddle_tpu.ops import flash_attention as FA
 from paddle_tpu.ops import rotary
 from paddle_tpu.parallel import moe
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench.reference import afmoe_lm  # noqa: E402
+from flash_test import _band_inputs, _band_written_out  # noqa: E402
 
 
 def _r(*shape, seed=0, scale=0.5):
     return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
                        jnp.float32)
-
-
-# -- the window bound through the flash kernels --------------------------------
-
-def _band_written_out(q, k, v, h, hkv, window):
-    """softmax(q k^T / sqrt(D)) v with `i - window < j <= i` written
-    out, float32, query head a reading key/value head a // (h / hkv)."""
-    b, t, hd = q.shape
-    f32 = lambda x: x.astype(jnp.float32)
-    qh = FA.heads_first(f32(q), h)
-    kh, vh = (jnp.repeat(FA.heads_first(f32(x), hkv), h // hkv, 1)
-              for x in (k, v))
-    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * (hd // h) ** -0.5
-    s = jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
-    return FA.heads_last(jnp.einsum("bhqk,bhkd->bhqd",
-                                    jax.nn.softmax(s, -1), vh))
-
-
-def _qkv(t, h, hkv, d, dtype, seed):
-    mk = lambda n, s: _r(1, t, n * d, seed=s).astype(dtype)
-    return mk(h, seed), mk(hkv, seed + 1), mk(hkv, seed + 2), mk(h, seed + 3)
-
-
-# T 512 in streamed blocks of 128 (panels of 128) unless said otherwise
-_WINDOWS = [
-    (512, 128, 100, "under_a_tile"), (512, 128, 128, "one_block"),
-    (512, 128, 200, "no_multiple_of_a_block"), (512, 128, 256, "two_blocks"),
-    (512, 128, 511, "just_under_t"), (512, 128, 1, "its_own_key_alone"),
-    (1024, 512, 300, "panels_of_256"), (512, None, 200, "all_of_t_one_block")]
-
-
-@pytest.fixture(params=["fused_streamed", "two_kernels"])
-def streamed_backward(request, monkeypatch):
-    """What a streamed T's backward runs: the ONE kernel (ISSUE 39), or,
-    its byte bound set to nothing, the two it replaced (a T too long
-    for the bound keeps them)."""
-    if request.param == "two_kernels":
-        monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
-    return {"fused_streamed": ["flash_bwd"],
-            "two_kernels": ["flash_bwd_dq", "flash_bwd_dkv"]}[request.param]
-
-
-@pytest.mark.parametrize("t, block, window", [w[:3] for w in _WINDOWS],
-                         ids=[w[3] for w in _WINDOWS])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_windowed_kernels_match_the_band_written_out(streamed_backward,
-                                                     dtype, t, block,
-                                                     window):
-    """4 query heads of 128 reading ONE key/value head under a window,
-    in interpret mode against dense float32 math with the band written
-    out: out, dq, and dk, dv summed over the group; the dense form
-    (the CPU path) beside them. Streamed, the forward and ONE backward
-    kernel, or the two beyond its bound; all of T in one block, the
-    fused backward."""
-    h, hkv, d = 4, 1, 128
-    q, k, v, dy = _qkv(t, h, hkv, d, dtype, seed=t + window)
-    kw = dict(causal=True, block_q=block, block_k=block, n_kv_head=hkv,
-              window=window)
-    run = lambda q, k, v: FA.flash_bthd(q, k, v, h, force="interpret", **kw)
-    dense = lambda q, k, v: FA.flash_bthd(q, k, v, h, force="dense", **kw)
-    want = lambda q, k, v: _band_written_out(q, k, v, h, hkv, window)
-    f32 = lambda x: x.astype(jnp.float32)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    close = lambda name, a, b: np.testing.assert_allclose(
-        f32(a), f32(b), atol=tol * max(float(jnp.max(jnp.abs(f32(b)))), 0.1),
-        err_msg=name)
-    o = run(q, k, v)
-    assert o.shape == q.shape and o.dtype == dtype
-    close("out", o, want(q, k, v))
-    close("dense out", dense(q, k, v), want(q, k, v))
-    loss = lambda fn: lambda *a: (f32(fn(*a)) * f32(dy)).sum()
-    grad = jax.grad(loss(run), (0, 1, 2))
-    names = [eqn.params["name"] for eqn in _pallas_eqns(
-        jax.make_jaxpr(grad)(q, k, v).jaxpr)]
-    assert names == ["flash_fwd"] + (["flash_bwd"] if block is None
-                                     else streamed_backward)
-    truth = jax.grad(loss(want), (0, 1, 2))(f32(q), f32(k), f32(v))
-    for name, a, b, c in zip(("dq", "dk", "dv"), grad(q, k, v), truth,
-                             jax.grad(loss(dense), (0, 1, 2))(q, k, v)):
-        assert a.shape == b.shape and a.dtype == dtype
-        close(name, a, b)
-        close("dense " + name, c, b)
-
-
-def _pallas_eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_eqns(sub)
-
-
-def test_the_grids_key_axis_holds_the_bands_steps_alone(streamed_backward):
-    """T 2048 in blocks of 256 under a window of 512: a q block's band
-    is its own block and the two before it, so the forward's grid is
-    (heads, 8, 3) where causal's is (heads, 8, 8), the backward's
-    (the ONE kernel's, by keys; the two kernels') likewise; with an lse
-    output the same kernels."""
-    h, t, d = 2, 2048, 128
-    q = jnp.zeros((1, t, h * d), jnp.float32)
-    grids = lambda **kw: [
-        tuple(eqn.params["grid_mapping"].grid) for eqn in _pallas_eqns(
-            jax.make_jaxpr(jax.grad(lambda q, k, v: FA.flash_bthd(
-                q, k, v, h, causal=True, force="interpret", block_q=256,
-                block_k=256, **kw).sum(), (0, 1, 2)))(q, q, q).jaxpr)]
-    calls = 1 + len(streamed_backward)
-    assert grids() == [(h, 8, 8)] * calls
-    assert grids(window=512) == [(h, 8, 3)] * calls
-    assert grids(window=514) == [(h, 8, 4)] * calls
-    assert grids(window=2048) == [(h, 8, 8)] * calls       # plain causal
-    o, lse = FA.flash_bthd_lse(_r(1, 512, h * d), _r(1, 512, h * d, seed=1),
-                               _r(1, 512, h * d, seed=2), h, causal=True,
-                               force="interpret", block_q=128, block_k=128,
-                               window=130)
-    _, want = FA.flash_bthd_lse(_r(1, 512, h * d), _r(1, 512, h * d, seed=1),
-                                _r(1, 512, h * d, seed=2), h, causal=True,
-                                force="dense", window=130)
-    np.testing.assert_allclose(lse, want, atol=1e-5)
-
-
-@pytest.mark.parametrize("t, block, tile, window, ratio", [
-    (16384, 1024, 256, 2048, 1.125), (4096, 1024, 256, 2048, 1.1248),
-    (16384, 1024, 1024, 2048, 1.5), (2048, 2048, 256, 512, 1.4996)],
-    ids=["the_cell", "the_smoke_phase", "blocks_merely_masked", "one_block"])
-def test_the_walks_cuts_count_what_they_compute(t, block, tile, window,
-                                                ratio):
-    """`band_scores` counts from the cuts `_walk` runs: by queries and
-    by keys alike, never under the band's own count, and at the cell's
-    shape 1.125 of it (ten sixteenths of the diagonal block and of the
-    block on the lower edge, one block whole)."""
-    computed, useful = FA.band_scores(t, block, tile, window)
-    assert useful == sum(min(i + 1, window) for i in range(t))
-    assert FA.band_scores(t, block, tile, window, True) == (computed, useful)
-    assert computed >= useful
-    assert round(computed / useful, 4) == ratio
-    # every cut's segments lie inside the block, masked ones static
-    for delta in range(FA._band_steps(window, block, t // block)):
-        for mine, segments in FA._band_cuts(delta, block, tile,
-                                            window) or []:
-            assert 0 <= mine.start < mine.stop <= block
-            for cols, off, how in segments:
-                assert 0 <= cols.start < cols.stop <= block
-                assert (off is None) == (how is None)
-
-
-def test_a_window_counts_itself_and_composes_with_nothing_else():
-    """`ptpu_flash_lowerings_total` carries the window ("0": none, and
-    a window that holds all of T is none), `ptpu_flash_band_scores_total`
-    the walk's scores where the kernels run; a mask in blocks, `strict`,
-    `own_block`, a second part or no `causal` beside a window raise;
-    unequal blocks go dense."""
-    h, d, t = 2, 128, 512
-    q, k, v, _ = _qkv(t, h, h, d, jnp.float32, seed=5)
-    labels = dict(path="interpret", entry="bthd", heads_per_block="1",
-                  backward="fused_streamed", mask="causal", kv_groups="1",
-                  key_width="128", value_width="128", second_part="none")
-    count = lambda w: FA._LOWERINGS.value(window=str(w), **labels)
-    scores = lambda kind, walk="forward": FA._BAND_SCORES.value(
-        window="200", walk=walk, kind=kind)
-    walks = lambda: [scores("computed", "backward_by_" + by)
-                     for by in ("queries", "keys")]
-    was = count(200), count(0), scores("computed"), scores("useful")
-    walked = walks()
-    kw = dict(causal=True, force="interpret", block_q=128, block_k=128)
-    FA.flash_bthd(q, k, v, h, window=200, **kw)
-    FA.flash_bthd(q, k, v, h, window=t, **kw)
-    FA.flash_bthd(q, k, v, h, **kw)
-    assert (count(200), count(0)) == (was[0] + 1, was[1] + 2)
-    computed, useful = FA.band_scores(t, 128, 128, 200)
-    assert scores("computed") == was[2] + computed
-    assert scores("useful") == was[3] + useful
-    # the ONE streamed kernel walks by keys alone; the two kernels, for
-    # a T over its bound, by queries too
-    assert walks() == [walked[0], walked[1] + computed]
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
-        FA.flash_bthd(q, k, v, h, window=200, **kw)
-    assert walks() == [walked[0] + computed, walked[1] + 2 * computed]
-    dense = dict(labels, path="dense", backward="none", window="200")
-    before = FA._LOWERINGS.value(**dense)
-    FA.flash_bthd(q, k, v, h, causal=True, force="interpret", block_q=256,
-                  block_k=128, window=200)
-    assert FA._LOWERINGS.value(**dense) == before + 1
-    for bad in (dict(mask_block=4), dict(strict=True),
-                dict(mask_block=4, own_block=True), dict(causal=False),
-                dict(q2=q[..., :64 * h], k2=k[..., :64])):
-        with pytest.raises(ValueError):
-            FA.flash_bthd(q, k, v, h, **{**kw, "window": 200, **bad})
 
 
 # -- the ops --------------------------------------------------------------------
@@ -229,7 +39,7 @@ def test_the_ops_scope_their_kernels_and_leave_the_rotation_out():
     QK-norm alone through `ops/rotary.norm_rope`; `sigmoid_mul` gates in
     float32 and hands back x's dtype."""
     h, hkv, d, t = 4, 2, 32, 64
-    q, k, v, _ = _qkv(t, h, hkv, d, jnp.float32, seed=9)
+    q, k, v, _ = _band_inputs(t, h, hkv, d, jnp.float32, seed=9)
     for window, scope in ((16, "window"), (0, "full")):
         text = str(jax.make_jaxpr(lambda q, k, v: CA.causal_attention(
             q, k, v, h, hkv, window))(q, k, v).pretty_print(
@@ -317,16 +127,16 @@ CFG = {"arch": "afmoe", "vocab_size": 96, "num_hidden_layers": 4,
 SEQ = 32
 
 
-def _small_model():
-    from chipbench import cells
-    arch = cells.load_arch("afmoe")
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 11
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        cost, logits = arch.build(CFG, SEQ)
-        forward = main.clone(for_test=True)
-    return arch, main, startup, forward, scope, cost, logits
+@pytest.fixture(scope="module")
+def _initialised():
+    return small_model_test.initialised("afmoe", CFG, SEQ)
+
+
+@pytest.fixture
+def small_model(_initialised):
+    """(arch, main, forward, scope, cost, logits) as
+    initialised, ONCE a file (tests/small_model_test.py)."""
+    return small_model_test.as_initialised(*_initialised)
 
 
 def _batch(rows=2):
@@ -336,15 +146,14 @@ def _batch(rows=2):
             "mask": (rng.rand(rows, SEQ) > 0.2).astype(np.float32)}
 
 
-def test_small_model_loss_and_logits_are_the_references():
+def test_small_model_loss_and_logits_are_the_references(small_model):
     """The for_test clone's loss and logits, with every routed layer's
     choices fetched from INSIDE its recompute region in the same run;
     the stack's kinds as the program's ops state them."""
-    arch, main, startup, forward, scope, cost, logits = _small_model()
+    arch, main, forward, scope, cost, logits = small_model
     feed = _batch()
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(scope):
-        exe.run(startup)
         params = arch.params_of_program(main, scope, CFG)
         names = arch.router_choices(forward)
         fetched = exe.run(forward, feed=feed,
@@ -359,32 +168,33 @@ def test_small_model_loss_and_logits_are_the_references():
     assert sum(op.type == "rms_norm" for op in ops) == 4 * 4 + 1
     assert sum(op.type == "sigmoid_mul" for op in ops) == 4
     got_cost, got_logits, choices = fetched[0], fetched[1], fetched[2:]
-    want = arch.lm_loss(params, feed["src"], feed["label"], feed["mask"], CFG)
+    # the reference as ONE program each: eagerly it is a hundred small
+    # compilations, more seconds than the model under test takes
+    want = jax.jit(lambda p, *batch: arch.lm_loss(p, *batch, CFG))(
+        params, feed["src"], feed["label"], feed["mask"])
     np.testing.assert_allclose(got_cost, want, rtol=2e-5)
     assert len(choices) == 3 and choices[0].shape == (2, SEQ, 2)
     # a for_test run counts nothing and moves no bias
     assert counters["steps"] == [0] and sum(counters["expert_rows"]) == 0
+    logits_at = jax.jit(lambda p, tokens, chosen=None: arch.logits_at(
+        p, tokens, 0, SEQ, CFG, chosen))
     for row in range(2):
-        ref = arch.logits_at(params, jnp.asarray(feed["src"][row]), 0, SEQ,
-                             CFG)
+        ref = logits_at(params, jnp.asarray(feed["src"][row]))
         np.testing.assert_allclose(got_logits[row], ref, atol=3e-5)
-        handed = arch.logits_at(
-            params, jnp.asarray(feed["src"][row]), 0, SEQ, CFG,
-            np.stack([c[row:row + 1] for c in choices]))
+        handed = logits_at(params, jnp.asarray(feed["src"][row]),
+                           np.stack([c[row:row + 1] for c in choices]))
         np.testing.assert_allclose(handed, ref, atol=1e-6)
 
 
-def test_small_model_one_steps_gradients_are_the_references():
+def test_small_model_one_steps_gradients_are_the_references(small_model):
     """SGD at rate 1 turns a step's parameter change into its gradient:
     every parameter's against jax.grad of the reference's loss, through
     the recompute regions; the counts, the step counter and the
     selection bias move ONCE a step."""
-    arch, main, startup, _, scope, cost, _ = _small_model()
+    arch, main, _, scope, cost, _ = small_model
     feed = _batch()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope):
-        fluid.optimizer.SGD(learning_rate=1.0).minimize(cost)
+    with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
         before = arch.params_of_program(main, scope, CFG)
         exe.run(main, feed=feed, fetch_list=[cost])
         after = arch.params_of_program(main, scope, CFG)
@@ -406,7 +216,7 @@ def test_small_model_one_steps_gradients_are_the_references():
         return arch.lm_loss(whole, feed["src"], feed["label"], feed["mask"],
                             CFG)
 
-    grads = jax.grad(loss)(floats(before))
+    grads = jax.jit(jax.grad(loss))(floats(before))
     moved = jax.tree.map(lambda a, b: a - b, floats(before), floats(after))
     flat_g, _ = jax.tree_util.tree_flatten_with_path(grads)
     flat_m = jax.tree.leaves(moved)
