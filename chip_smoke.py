@@ -878,10 +878,11 @@ def phase_experts(seed, rehearse):
         wr = mk(d, e) * 0.02
         wg, wu, wd = (mk(held, d, f) * d ** -0.5, mk(held, d, f) * d ** -0.5,
                       mk(held, f, d) * f ** -0.5)
-        # the series' last label is `rows`: what adds a chunk's rows
+        # the series' label `rows`: what adds a chunk's rows
+        rows_at = moe._LOWERINGS.label_names.index("rows")
         by_kernel = lambda: sum(
             v for key, v in moe._LOWERINGS.snapshot().items()
-            if key[-1] == ("interpret" if rehearse else "pallas"))
+            if key[rows_at] == ("interpret" if rehearse else "pallas"))
         was = by_kernel()
 
         def layer(x, wr, wg, wu, wd):

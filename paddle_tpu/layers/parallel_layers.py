@@ -95,13 +95,15 @@ def sparse_moe(x, num_experts, d_inner, capacity_factor=1.25,
 def routed_experts(x, num_experts, experts_held, first_expert, top_k,
                    d_inner, norm_topk=True, name=None, score_func="softmax",
                    routed_scaling_factor=1.0, bias_update_rate=None,
-                   shared_expert=False, router_std=0.02):
-    """One chip's share of a mixture of SiLU-gated experts over
+                   shared_expert=False, router_std=0.02, router_input=None,
+                   activation="silu"):
+    """One chip's share of a mixture of gated experts over
     ``[B, T, D]`` input, dropless (``parallel/moe.routed_experts``): a
     float32 router over all `num_experts`, the `top_k` largest a row
     (their weights divided by their sum where `norm_topk`), and the
     `experts_held` experts with ids from `first_expert` computed here,
-    ``w_down(silu(w_gate x) * (w_up x))`` of width `d_inner`; what the
+    ``w_down(act(w_gate x) * (w_up x))`` of width `d_inner`, act
+    `activation` ("silu", or "relu"); what the
     other experts would add is left out, as an expert-parallel group
     leaves it to its other members. Returns ``(out, aux_loss, choices,
     load)``: `aux_loss` is ``E * sum_e f_e P_e`` (scale it and add it to
@@ -119,7 +121,16 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     (``moe.bias_step``) and adds one to the persistable
     ``<name>.steps`` [1] int32. `shared_expert` labels the lowering's count:
     the caller adds a shared expert's output to `out`. `router_std` is
-    the router's initialisation, N(0, `router_std`)."""
+    the router's initialisation, N(0, `router_std`).
+
+    `router_input` (None: `x`) is what the router reads, a variable of
+    x's shape: a router that stands before the sublayers that make the
+    experts' input. The router's weights send their gradient to it, the
+    experts theirs to `x`. Under a "relu" gate the zeros are exact, and
+    every train run adds to the persistable ``<name>.gate_on`` [2]
+    float32 the hidden units the gate left on and the hidden units
+    there were, over the step's pairs on held experts, and one to
+    ``<name>.steps``."""
     helper = LayerHelper("routed_experts", name=name)
     d = int(x.shape[-1])
     param = lambda suffix, shape, std: helper.create_parameter(
@@ -142,6 +153,15 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
                "LoadOut": [load]}
     attrs = {"first_expert": int(first_expert), "top_k": int(top_k),
              "norm_topk": bool(norm_topk)}
+    if router_input is not None:
+        inputs["RouterX"] = [router_input]
+    if activation != "silu":
+        attrs["activation"] = str(activation)
+    if activation == "relu":
+        gate_on = create_global_var([2], 0.0, "float32", persistable=True,
+                                    name=helper.name + ".gate_on")
+        gate_on.stop_gradient = True
+        inputs["GateOn"], outputs["GateOnOut"] = [gate_on], [gate_on]
     if (score_func, routed_scaling_factor, shared_expert) != (
             "softmax", 1.0, False):
         attrs.update(score_func=str(score_func),
@@ -151,11 +171,12 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
         bias = create_global_var([num_experts], 0.0, "float32",
                                  persistable=True, name=helper.name + ".bias")
         bias.stop_gradient = True
+        inputs["Bias"], outputs["BiasOut"] = [bias], [bias]
+        attrs["bias_update_rate"] = float(bias_update_rate)
+    if bias_update_rate is not None or activation == "relu":
         steps = create_global_var([1], 0, "int32", persistable=True,
                                   name=helper.name + ".steps")
-        inputs.update(Bias=[bias], Steps=[steps])
-        outputs.update(BiasOut=[bias], StepsOut=[steps])
-        attrs["bias_update_rate"] = float(bias_update_rate)
+        inputs["Steps"], outputs["StepsOut"] = [steps], [steps]
     helper.append_op(type="routed_experts", inputs=inputs, outputs=outputs,
                      attrs=attrs)
     return out, aux, choices, load

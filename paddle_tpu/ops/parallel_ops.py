@@ -135,7 +135,7 @@ def _moe_ffn(ctx, op):
 
 @register("routed_experts")
 def _routed_experts(ctx, op):
-    """One chip's share of a mixture of SiLU-gated experts, dropless
+    """One chip's share of a mixture of gated experts, dropless
     (parallel/moe.routed_experts). Inputs X [B, T, D], RouterW [D, E]
     over all E experts, WGate / WUp [Eh, D, F] and WDown [Eh, F, D] of
     the Eh experts held here, Load [E] int32 (persistable); attrs
@@ -148,7 +148,13 @@ def _routed_experts(ctx, op):
     shared_expert are `moe.routed_experts`'; with a Bias [E] float32
     (persistable, no gradient) the choice is the k largest of score +
     bias, and a train run writes BiasOut = `moe.bias_step` of the
-    step's own counts at `bias_update_rate` and StepsOut = Steps + 1."""
+    step's own counts at `bias_update_rate` and StepsOut = Steps + 1.
+    With a RouterX [B, T, D] the router reads it and not X; attr
+    activation ("silu" / "relu") is the experts' gate. With a GateOn [2]
+    float32 (persistable) a train run writes GateOnOut = GateOn + (the
+    hidden units the gate left on, the hidden units there were) over the
+    step's pairs on held experts. Steps [1] int32, where the layer keeps
+    it (beside a Bias or a GateOn), counts the train runs."""
     from ..amp import maybe_bf16
     from ..parallel import moe
     x = ctx.in1(op, "X")
@@ -158,22 +164,33 @@ def _routed_experts(ctx, op):
         ctx.in1(op, "WGate"), ctx.in1(op, "WUp"), ctx.in1(op, "WDown"))
     k = int(op.attr("top_k"))
     bias = ctx.in1(op, "Bias") if op.input("Bias") else None
-    out, aux, counts, experts = moe.routed_experts(
+    first = int(op.attr("first_expert", 0))
+    train = not (op.attr("is_test", False) or ctx.is_test)
+    count_gate = train and bool(op.input("GateOn"))
+    out, aux, counts, experts, *on = moe.routed_experts(
         x.reshape(-1, shape[-1]), router_w, w_gate, w_up, w_down,
-        router_w.shape[1],
-        first_expert=int(op.attr("first_expert", 0)), top_k=k,
+        router_w.shape[1], first_expert=first, top_k=k,
         norm_topk=bool(op.attr("norm_topk", True)),
         score=op.attr("score_func", "softmax"), bias=bias,
         scaling=float(op.attr("routed_scaling_factor", 1.0)),
-        shared_expert=bool(op.attr("shared_expert", False)))
+        shared_expert=bool(op.attr("shared_expert", False)),
+        router_x=ctx.in1(op, "RouterX").reshape(-1, shape[-1])
+        if op.input("RouterX") else None,
+        activation=op.attr("activation", "silu"), count_gate=count_gate)
     ctx.set_out(op, "Out", out.reshape(shape))
     ctx.set_out(op, "AuxLoss", aux)
     ctx.set_out(op, "Indices", experts.reshape(shape[:-1] + (k,)))
-    if not (op.attr("is_test", False) or ctx.is_test):
+    if train:
         ctx.set_out(op, "LoadOut", ctx.in1(op, "Load") + counts)
+        if count_gate:
+            pairs = jnp.sum(lax.dynamic_slice_in_dim(
+                counts, first, w_gate.shape[0]))
+            ctx.set_out(op, "GateOnOut", ctx.in1(op, "GateOn") + jnp.stack(
+                [on[0], pairs * w_gate.shape[2]]).astype(jnp.float32))
         if bias is not None:
             ctx.set_out(op, "BiasOut", moe.bias_step(
                 bias, counts, float(op.attr("bias_update_rate", 0.0))))
+        if op.input("Steps"):
             ctx.set_out(op, "StepsOut", ctx.in1(op, "Steps") + 1)
 
 

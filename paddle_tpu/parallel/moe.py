@@ -202,10 +202,13 @@ _LOWERINGS = _REG.counter(
     "lowerings of the routed expert layer at trace time (none a step): "
     "the grouped matmul's path, the experts routed over, those held here, "
     "the experts a row takes, the router's score function (softmax, "
-    "sigmoid), whether a shared expert rides beside the routed ones, and "
-    "what adds a chunk's rows to their tokens (pallas, interpret, xla)",
+    "sigmoid), whether a shared expert rides beside the routed ones, "
+    "what adds a chunk's rows to their tokens (pallas, interpret, xla), the "
+    "experts' gate (silu, relu) and whose rows the router reads (own: the "
+    "experts' input; given: a tensor of its own)",
     ("path", "experts", "experts_held", "top_k", "score", "shared_expert",
-     "rows"))
+     "rows", "activation", "router_input"))
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
@@ -240,13 +243,21 @@ def bias_step(bias, counts, rate):
     return bias + rate * jnp.sign(jnp.mean(counts) - counts)
 
 
-def _swiglu_experts(xs, w_gate, w_up, w_down, sizes):
+def _swiglu_experts(xs, w_gate, w_up, w_down, sizes, gate="silu",
+                    live=None):
     """The held experts on sorted rows: xs [C, d], `sizes` rows to each
-    expert in turn; rows past their sum are not computed."""
+    expert in turn; rows past their sum are not computed. `gate` is the
+    activation of the gate's half ("silu", "relu"). Given `live`, the
+    rows that hold a pair, also how many of their hidden units the gate
+    leaves on (``xs w_gate > 0``; int32): ``(y, on)``."""
     rd = functools.partial(lax.ragged_dot, group_sizes=sizes,
                            preferred_element_type=jnp.float32)
-    h = jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)
-    return rd(h.astype(xs.dtype), w_down)
+    g = rd(xs, w_gate)
+    y = rd((_GATES[gate](g) * rd(xs, w_up)).astype(xs.dtype), w_down)
+    if live is None:
+        return y
+    there = jnp.arange(xs.shape[0], dtype=jnp.int32) < live
+    return y, jnp.sum((g > 0) & there[:, None], dtype=jnp.int32)
 
 
 def _chunk(c, cap, order, ends, k):
@@ -258,45 +269,55 @@ def _chunk(c, cap, order, ends, k):
     return pairs, pairs // k, inside[-1], sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
 def _held_experts(x, weight, w_gate, w_up, w_down, order, ends, cap, how,
-                  dtype):
+                  dtype, gate, counted):
     return _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap,
-                     how, dtype)[0]
+                     how, dtype, gate, counted)[0]
 
 
-def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype):
-    """`how` adds a chunk's rows to their tokens ("pallas" /
-    "interpret" / "xla", moe_rows._resolve_path); the result is rounded
-    to x's dtype and returned as `dtype`, the layer's. The places of a
-    gathered chunk past its pairs hold other experts' rows: ragged_dot
-    computes no row past the sum of its sizes, and every other reader
-    masks them or stops at `count`."""
+def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
+              gate, counted):
+    """``(out, on)``. `how` adds a chunk's rows to their tokens
+    ("pallas" / "interpret" / "xla", moe_rows._resolve_path); the result
+    is rounded to x's dtype and returned as `dtype`, the layer's. The
+    places of a gathered chunk past its pairs hold other experts' rows:
+    ragged_dot computes no row past the sum of its sizes, and every
+    other reader masks them or stops at `count`. `on` is None, or where
+    `counted` the hidden units `gate` leaves on over all the pairs
+    (`_swiglu_experts`): the loop carries it beside the output."""
     k = weight.shape[1]
 
-    def body(c, out):
+    def body(c, carry):
+        out, on = carry
         pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
-        y = _swiglu_experts(x[rows], w_gate, w_up, w_down, sizes)
+        y = _swiglu_experts(x[rows], w_gate, w_up, w_down, sizes, gate,
+                            count if counted else None)
+        if counted:
+            y, here = y
+            on = on + here
         return moe_rows.scatter_add(out, x.shape, y, rows,
-                                    weight.reshape(-1)[pairs], count, how)
+                                    weight.reshape(-1)[pairs], count,
+                                    how), on
 
-    out = lax.fori_loop(0, (ends[-1] + cap - 1) // cap, body,
-                        moe_rows.zeros(x.shape, how))
-    return moe_rows.result(out, x.shape, x.dtype, how, dtype), (
+    out, on = lax.fori_loop(
+        0, (ends[-1] + cap - 1) // cap, body,
+        (moe_rows.zeros(x.shape, how), jnp.int32(0) if counted else None))
+    return (moe_rows.result(out, x.shape, x.dtype, how, dtype), on), (
         x, weight, w_gate, w_up, w_down, order, ends)
 
 
-def _held_bwd(cap, how, dtype, res, dout):
+def _held_bwd(cap, how, dtype, gate, counted, res, douts):
     x, weight, w_gate, w_up, w_down, order, ends = res
     k = weight.shape[1]
-    dout = dout.astype(x.dtype)
+    dout = douts[0].astype(x.dtype)
 
     def body(c, carry):
         dx, dweight, dws = carry
         pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
         there = jnp.arange(cap, dtype=jnp.int32) < count
         y, vjp = jax.vjp(
-            lambda xs, *ws: _swiglu_experts(xs, *ws, sizes),
+            lambda xs, *ws: _swiglu_experts(xs, *ws, sizes, gate),
             x[rows], w_gate, w_up, w_down)
         dy = jnp.where(there[:, None], dout[rows], 0).astype(jnp.float32)
         # a pair's weight multiplies its sorted row, and its gradient is
@@ -324,22 +345,31 @@ _held_experts.defvjp(_held_fwd, _held_bwd)
 
 def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
                    first_expert=0, top_k=8, norm_topk=True, score="softmax",
-                   bias=None, scaling=1.0, shared_expert=False, force=None):
-    """One chip's share of a mixture of SiLU-gated experts, dropless.
+                   bias=None, scaling=1.0, shared_expert=False, force=None,
+                   router_x=None, activation="silu", count_gate=False):
+    """One chip's share of a mixture of gated experts, dropless.
 
     x [N, d]; router_w [d, E] over ALL `num_experts`; w_gate, w_up
     [Eh, d, f] and w_down [Eh, f, d]: the Eh experts held here, ids
     `first_expert` .. `first_expert` + Eh - 1. Returns
 
       out     [N, d], x's dtype: sum over a row's chosen experts THAT ARE
-              HELD HERE of weight * w_down(silu(w_gate x) * (w_up x));
-              what the other experts would add is left out
+              HELD HERE of weight * w_down(act(w_gate x) * (w_up x)),
+              act `activation` ("silu", or "relu"); what the other
+              experts would add is left out
       aux     E * sum_e f_e P_e over all E (f_e the rows that chose e
               over N, constant; P_e the mean router probability)
       counts  [E] int32, the rows that chose each expert
       experts [N, k] int32, the router's choices
 
-    The router is float32 and reads x as it comes; the experts compute
+    and, where `count_gate`, a fifth: over the pairs on held experts,
+    the hidden units whose gate is on (``w_gate x > 0``, what a ReLU
+    gate passes; int32, no gradient).
+
+    The router is float32 and reads x as it comes, or `router_x` [N, d]
+    where given (a router that stands before the sublayers that make
+    the experts' input: its weights' gradient then reaches `router_x`,
+    the experts' x); the experts compute
     in their weights' dtype (bfloat16 under AMP), accumulating in
     float32. Every held pair is computed, also when all rows choose
     held experts. `score`, `bias` and `scaling` are `route`'s;
@@ -351,34 +381,45 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     / "interpret" / "xla") is for tests."""
     n, d = x.shape
     held = w_gate.shape[0]
+    if activation not in _GATES:
+        raise ValueError("routed_experts: the gate is one of %s, got %r"
+                         % (sorted(_GATES), activation))
     adder = moe_rows._resolve_path(x.shape, x, force)
     _LOWERINGS.inc(path="ragged_dot", experts=str(num_experts),
                    experts_held=str(held), top_k=str(top_k), score=score,
-                   shared_expert=str(bool(shared_expert)).lower(), rows=adder)
+                   shared_expert=str(bool(shared_expert)).lower(), rows=adder,
+                   activation=activation,
+                   router_input="own" if router_x is None else "given")
     # the plain softmax router is called as it always was, four
     # arguments: its callers' stand-ins (tests) have that signature
     how = {} if (score, bias, scaling) == ("softmax", None, 1.0) else {
         "score": score, "bias": bias, "scaling": scaling}
-    probs, weight, experts = route(x, router_w, top_k, norm_topk, **how)
-    counts = jnp.sum(experts[..., None] == jnp.arange(num_experts),
-                     axis=(0, 1), dtype=jnp.int32)
-    aux = num_experts * jnp.sum(
-        lax.stop_gradient(counts.astype(jnp.float32) / n)
-        * jnp.mean(probs, axis=0))
+    # the scope holds what reads the router's input alone, the matmul,
+    # the scores, the top-k and the sort: a trace tells it from the rest
+    with jax.named_scope("route"):
+        probs, weight, experts = route(x if router_x is None else router_x,
+                                       router_w, top_k, norm_topk, **how)
+        counts = jnp.sum(experts[..., None] == jnp.arange(num_experts),
+                         axis=(0, 1), dtype=jnp.int32)
+        aux = num_experts * jnp.sum(
+            lax.stop_gradient(counts.astype(jnp.float32) / n)
+            * jnp.mean(probs, axis=0))
 
-    # the pairs on held experts, sorted by expert (stable: by row within
-    # one); the others sort behind them and are never visited
-    local = experts.reshape(-1) - first_expert
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    ends = jnp.cumsum(lax.dynamic_slice_in_dim(
-        counts, first_expert, held)).astype(jnp.int32)
+        # the pairs on held experts, sorted by expert (stable: by row
+        # within one); the others sort behind them and are never visited
+        local = experts.reshape(-1) - first_expert
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        ends = jnp.cumsum(lax.dynamic_slice_in_dim(
+            counts, first_expert, held)).astype(jnp.int32)
     # a chunk: twice what uniform routing sends here, in whole tiles of
     # the grouped matmul
     pairs = n * top_k
     cap = min(-(-2 * pairs * held // num_experts // 512) * 512,
               -(-pairs // 8) * 8)
     order = jnp.pad(order, (0, -(-pairs // cap) * cap - pairs))
-    out = _held_experts(x.astype(w_gate.dtype), weight, w_gate, w_up,
-                        w_down, order, ends, cap, adder, x.dtype)
-    return out, aux, counts, experts.astype(jnp.int32)
+    out, on = _held_experts(x.astype(w_gate.dtype), weight, w_gate, w_up,
+                            w_down, order, ends, cap, adder, x.dtype,
+                            activation, bool(count_gate))
+    got = out, aux, counts, experts.astype(jnp.int32)
+    return got + (on,) if count_gate else got

@@ -358,7 +358,7 @@ def test_aux_loss_and_lowering_counter():
     x, wr, wg, wu, wd = _experts(seed=5)
     labels = dict(path="ragged_dot", experts=str(E), experts_held=str(HELD),
                   top_k=str(K), score="softmax", shared_expert="false",
-                  rows="xla")
+                  rows="xla", activation="silu", router_input="own")
     was = moe._LOWERINGS.value(**labels)
     _, aux, counts, idx = moe.routed_experts(x, wr, wg[:4], wu[:4], wd[:4],
                                              E, 0, K, True)

@@ -96,6 +96,46 @@ def test_windowed_kernels_match_the_band_written_out(streamed_backward,
         close("dense " + name, c, b)
 
 
+@pytest.mark.parametrize("window", [100, None],
+                         ids=["window_100", "no_window"])
+def test_a_group_of_seven_query_heads_reads_its_own_kv_head(
+        streamed_backward, window):
+    """ISSUE 46: 14 query heads of 128 reading TWO key/value heads,
+    query head j the head j // 7, T 256 in four streamed blocks of 64
+    (a window of 100 cuts a block), in interpret mode against dense
+    float32 math with the mask written out: out, dq, and dk, dv summed
+    over each group of seven, through the ONE backward kernel and
+    through the two. A kernel that read head j % 2 would pass no
+    tolerance here."""
+    h, hkv, d, t, block = 14, 2, 128, 256, 64
+    q, k, v, dy = _band_inputs(t, h, hkv, d, jnp.float32, seed=46)
+    weigh = lambda o: (o * dy).sum()
+    want = jax.jit(_with_grads(
+        lambda q, k, v: _band_written_out(q, k, v, h, hkv, window or t),
+        weigh))(q, k, v)
+    run = lambda q, k, v: FA.flash_bthd(
+        q, k, v, h, force="interpret", causal=True, block_q=block,
+        block_k=block, n_kv_head=hkv, window=window)
+    was = FA._LOWERINGS.snapshot()
+    eqns, (o, grads) = _traced_once(_with_grads(run, weigh), q, k, v)
+    assert [eqn.params["name"] for eqn in eqns] == ["flash_fwd"] \
+        + streamed_backward
+    (key,) = [key for key, n in FA._LOWERINGS.snapshot().items()
+              if n != was.get(key, 0)]
+    label = dict(zip(FA._LOWERINGS.label_names, key))
+    assert (label["path"], label["kv_groups"], label["window"]) == (
+        "interpret", "7", str(window or 0))
+    _assert_within("out", o, want[0], tol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), grads, want[1]):
+        assert a.shape == b.shape
+        _assert_within(name, a, b, tol=2e-5)
+    # the other mapping is far off: the groups are told apart
+    other = _band_written_out(q, jnp.roll(k.reshape(1, t, hkv, d), 1, 2
+                                          ).reshape(k.shape), v, h, hkv,
+                              window or t)
+    assert float(jnp.max(jnp.abs(other - want[0]))) > 0.05
+
+
 def test_the_grids_key_axis_holds_the_bands_steps_alone(streamed_backward):
     """T 2048 in blocks of 256 under a window of 512: a q block's band
     is its own block and the two before it, so the forward's grid is

@@ -312,7 +312,7 @@ def test_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
     assert float(jnp.max(jnp.abs(whole - shares))) > 1e-2   # once, not never
     labels = dict(path="ragged_dot", experts=str(E), experts_held="2",
                   top_k=str(K), score="sigmoid", shared_expert="true",
-                  rows="xla")
+                  rows="xla", activation="silu", router_input="own")
     assert moe._LOWERINGS.value(**labels) >= 9
 
 

@@ -228,7 +228,8 @@ def test_routed_experts_by_the_kernel_matches_xlas_scatter_add(
 def test_the_lowering_counter_says_what_moved_the_rows():
     x, wr, wg, wu, wd = _layer(256, 3)
     labels = dict(path="ragged_dot", experts=str(E), experts_held=str(HELD),
-                  top_k="2", score="softmax", shared_expert="false")
+                  top_k="2", score="softmax", shared_expert="false",
+                  activation="silu", router_input="own")
     was = {rows: moe._LOWERINGS.value(rows=rows, **labels)
            for rows in ("interpret", "xla", "pallas")}
     moe.routed_experts(x, wr, wg, wu, wd, E, 0, 2, force="interpret")

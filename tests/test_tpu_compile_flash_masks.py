@@ -2,8 +2,9 @@
 described v5e (tests/tpu_compile_test.py says how and why), each at its
 cell's real width, which is their point: block diffusion's
 (``sdar_train_bd4k``), latent attention's two-part score
-(``xing4_train_T4k``), the window's (``trinity_train_T16k``) and
-differential attention's (``phi4flash_train_T8k``). The plain entries
+(``xing4_train_T4k``), the window's (``trinity_train_T16k``), a group of seven query heads
+(``smallthinker_train_T16k``) and differential attention's
+(``phi4flash_train_T8k``). The plain entries
 are tests/test_tpu_compile_flash.py's.
 """
 
@@ -209,6 +210,46 @@ def test_one_streamed_backward_kernel_compiles_for_v5e(chip, b, t, form):
     assert "f32[%d,%d,%d]" % (b, rows_k, h * d) in calls["flash_bwd"]
     made = set(re.findall(r"= " + re.escape(stat) + r"\S* ([\w-]+)\(", text))
     assert made <= {"get-tuple-element"}, made     # flash_fwd's lse alone
+    size = lambda dims: math.prod(int(x) for x in dims.split(","))
+    assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
+               ) <= b * t * h * d
+
+
+# ISSUE 46: a group of SEVEN query heads to a key/value head. The cell
+# `smallthinker_train_T16k`'s layers: one packed 16,384-token sequence, 28
+# query heads of 128 (wider than the stream's 2560) reading 4, a window of
+# 4096 on three layers of four and plain causal on the fourth.
+@pytest.mark.parametrize("window", [4096, None],
+                         ids=["window_4096", "full"])
+def test_a_group_of_seven_compiles_for_v5e(chip, window):
+    """q [1, 16384, 3584] against k, v [1, 16384, 512], forward and
+    backward: the forward and the ONE streamed backward kernel
+    (flash_bwd, dq held in VMEM across the key blocks; not the pair
+    flash_bwd_dq + flash_bwd_dkv), and no dense path: no [T, T] value,
+    the largest buffer the program names is an operand's size."""
+    import math
+    import re
+    b, t, h, hkv, d = 1, 16384, 28, 4, 128
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=chip)
+    labels = dict(kv_groups="7", window=str(window or 0))
+    count = lambda **kw: sum(
+        v for key, v in FA._LOWERINGS.snapshot().items()
+        if all(key[FA._LOWERINGS.label_names.index(k)] == want
+               for k, want in {**labels, **kw}.items()))
+    was = (count(backward="fused_streamed"),)
+
+    def loss(q, k, v):
+        return flash_bthd(q, k, v, h, causal=True, n_kv_head=hkv,
+                          force="pallas", window=window
+                          ).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert count(backward="fused_streamed") == was[0] + 1
+    assert count(path="dense") == count(backward="two_kernels") == 0
+    assert text.count("tpu_custom_call") == 2
+    calls = set(re.findall(r"%(flash_\w+?)(?:\.\d+)? = ", text))
+    assert calls == {"flash_fwd", "flash_bwd"}
     size = lambda dims: math.prod(int(x) for x in dims.split(","))
     assert max(size(dims) for dims in re.findall(r"[fb]\w*\[([\d,]+)\]", text)
                ) <= b * t * h * d
