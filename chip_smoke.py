@@ -859,9 +859,10 @@ def phase_experts(seed, rehearse):
     densely (float32, `highest`), output and gradients, under the
     router's own choices and with every row sent to held experts, at
     the shapes of the two routed cells (ISSUE 35: the rows go back to
-    their tokens by the kernel of ops/moe_rows.py); and the grouped
-    matmuls' gradients with NaN in a chunk's tail past its pairs, which
-    must reach nothing."""
+    their tokens by the kernel of ops/moe_rows.py); and a chunk's
+    forward and written backward (ISSUE 47) with NaN in the rows, the
+    cotangents and the weights of the tail past its pairs, which must
+    reach nothing."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.parallel import moe
@@ -925,27 +926,32 @@ def phase_experts(seed, rehearse):
             assert max(errs) <= EXPERT_TOL, errs
         assert by_kernel() > was, "the layer did not take the row kernel"
 
-        # a chunk's tail past its pairs holds other experts' rows, and
-        # might hold anything: NaN there must reach no gradient of the
-        # grouped matmuls
+        # a chunk's tail past its pairs holds other experts' rows, their
+        # cotangents and their weights, gathered as they are (ISSUE 47:
+        # the written backward masks none of them), and might hold
+        # anything: NaN there must reach no row under the pairs and no
+        # gradient of the grouped matmuls
         cap = min(4096, n)
         sizes = jnp.full((held,), cap // (2 * held), jnp.int32)
         live = int(sizes.sum())
-        xs, dy = bf16(mk(cap, d)), mk(cap, d).at[live:].set(0.0)
+        xs, dy, w = bf16(mk(cap, d)), bf16(mk(cap, d)), mk(cap)
+        w_gu = jnp.concatenate([bf16(wg), bf16(wu)], axis=2)
 
-        def pull(xs):
-            y, vjp = jax.vjp(lambda xs, *ws: moe._swiglu_experts(
-                xs, *ws, sizes), xs, bf16(wg), bf16(wu), bf16(wd))
-            return (y[:live],) + tuple(
-                g[:live] if g.shape[0] == cap else g for g in vjp(dy))
+        def pull(xs, dy, w):
+            y = moe._swiglu_experts(xs, w_gu, bf16(wd), sizes)
+            dw, dxs, *dws = moe._swiglu_experts_bwd(xs, dy, w, w_gu,
+                                                    bf16(wd), sizes)
+            return (y[:live], dw[:live], dxs[:live]) + tuple(dws)
 
-        clean = jax.jit(pull)(xs)
-        dirty = jax.jit(pull)(xs.at[live:].set(jnp.nan))
+        clean = jax.jit(pull)(xs, dy, w)
+        dirty = jax.jit(pull)(*(a.at[live:].set(jnp.nan)
+                                for a in (xs, dy, w)))
         worst = max(float(jnp.max(jnp.abs(
             a.astype(jnp.float32) - b.astype(jnp.float32))))
             for a, b in zip(clean, dirty))
-        log("[experts] NaN in the %d places past %d pairs of a chunk: the "
-            "grouped matmuls' output and gradients move by %.1e"
+        log("[experts] NaN in the %d places past %d pairs of a chunk (rows, "
+            "cotangents, weights): the grouped matmuls' output and the "
+            "written backward's six results move by %.1e"
             % (cap - live, live, worst))
         assert worst == 0.0, worst
 

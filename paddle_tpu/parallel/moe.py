@@ -193,9 +193,19 @@ def moe_ffn_pp_sharded(x, gate_w, w_up_local, w_down_local, ep_axis,
 # visits only the places of the chunk that hold pairs (ops/moe_rows.py,
 # ISSUE 35) where the device and the width allow, XLA's scatter-add over
 # the whole chunk elsewhere; the gather is XLA's on every path.
-# The backward is written out: it recomputes a chunk's hidden
-# activations from x instead of keeping them, so that nothing of a
-# chunk's size outlives its iteration.
+# The backward is written out round the grouped matmuls (ISSUE 47),
+# not derived from the forward: a chunk's pass is 2 of them forward
+# (gate and up against their weights side by side; down) and 5 backward
+# (`_swiglu_experts_bwd`), all on operands of the weights' dtype with
+# float32 sums. It gathers a chunk's rows of x and of the cotangent as
+# they are (no mask, no float32 copy: the grouped matmuls leave the
+# places past the pairs out, the row kernel stops at the count, and the
+# one [cap]-long mask is on the pairs' weights' gradient), recomputes
+# the hidden activations from x instead of keeping them, so that nothing
+# of a chunk's size outlives its iteration, applies the pairs' weights on
+# the hidden side ([cap, f], the narrow one) and gets their gradient
+# there too, so the down projection's forward is not run again and no
+# float32 value of [cap, d] is made but what a kernel writes.
 _REG = _metrics.registry()
 _LOWERINGS = _REG.counter(
     "ptpu_moe_lowerings_total",
@@ -243,21 +253,83 @@ def bias_step(bias, counts, rate):
     return bias + rate * jnp.sign(jnp.mean(counts) - counts)
 
 
-def _swiglu_experts(xs, w_gate, w_up, w_down, sizes, gate="silu",
-                    live=None):
-    """The held experts on sorted rows: xs [C, d], `sizes` rows to each
-    expert in turn; rows past their sum are not computed. `gate` is the
-    activation of the gate's half ("silu", "relu"). Given `live`, the
-    rows that hold a pair, also how many of their hidden units the gate
-    leaves on (``xs w_gate > 0``; int32): ``(y, on)``."""
-    rd = functools.partial(lax.ragged_dot, group_sizes=sizes,
-                           preferred_element_type=jnp.float32)
-    g = rd(xs, w_gate)
-    y = rd((_GATES[gate](g) * rd(xs, w_up)).astype(xs.dtype), w_down)
+def _grouped(sizes):
+    """The two grouped matmuls on sorted rows, `sizes` rows to each
+    expert in turn, as (rd, by_expert): ``rd(rows [C, a], weights
+    [Eh, a, b]) -> [C, b]`` computes no row past the sum of the sizes
+    (`dtype`: what it writes, float32 unless told; it sums in float32
+    either way), and ``by_expert(a [C, m], b [C, n]) -> [Eh, m, n]``
+    float32 contracts each expert's own rows and no others (zeros for
+    an expert with none). Both are `ragged-dot` kernels on a TPU, which
+    round a float32 operand to bfloat16 inside at twice the bytes: hand
+    them the weights' dtype."""
+    rd = lambda a, b, dtype=jnp.float32: lax.ragged_dot(
+        a, b, sizes, preferred_element_type=dtype)
+    dims = lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(([0], [0]), ([], [])),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return rd, lambda a, b: lax.ragged_dot_general(
+        a, b, sizes, dims, preferred_element_type=jnp.float32)
+
+
+def _gate_and_up(rd, xs, w_gu):
+    """(g, u) [C, f] float32 of xs [C, d]: ONE grouped matmul against
+    the gate's and the up projection's weights side by side, `w_gu`
+    [Eh, d, 2f]."""
+    gu = rd(xs, w_gu)
+    f = gu.shape[1] // 2
+    return gu[:, :f], gu[:, f:]
+
+
+def _swiglu_experts(xs, w_gu, w_down, sizes, gate="silu", live=None):
+    """The held experts on sorted rows, forward: xs [C, d], `sizes` rows
+    to each expert in turn; rows past their sum are not computed. Two
+    grouped matmuls: `_gate_and_up`, then ``act(g) * u``, rounded to
+    xs's dtype, against `w_down`. `gate` is the activation of the
+    gate's half ("silu", "relu"). Given `live`, the rows that hold a
+    pair, also how many of their hidden units the gate leaves on
+    (``xs w_gate > 0``; int32): ``(y, on)``."""
+    rd, _ = _grouped(sizes)
+    g, u = _gate_and_up(rd, xs, w_gu)
+    y = rd((_GATES[gate](g) * u).astype(xs.dtype), w_down)
     if live is None:
         return y
     there = jnp.arange(xs.shape[0], dtype=jnp.int32) < live
     return y, jnp.sum((g > 0) & there[:, None], dtype=jnp.int32)
+
+
+def _swiglu_experts_bwd(xs, dy, w, w_gu, w_down, sizes, gate="silu"):
+    """`_swiglu_experts`' backward for one chunk, written out: five
+    grouped matmuls, every operand in xs's dtype (bfloat16 under AMP)
+    and every sum float32. xs, dy [C, d]: the chunk's rows of x and of
+    the output's cotangent AS GATHERED; w [C] float32: the pairs'
+    weights, which the forward applies after the down projection. The
+    places past the chunk's pairs hold other experts' rows and weights:
+    they reach no row the caller reads and no weight gradient (see
+    `_grouped`). Returns
+
+      dweight  [C] float32: ``<dy, y> = <dy w_down^T, h>``, so the down
+               projection's forward is not run again
+      dxs      [C, d], xs's dtype
+      dw_gu, dw_down  float32, the chunk's own sums
+
+    Recomputed: g, u and ``h = act(g) * u`` (rounded as the forward
+    rounds it). Rounded to xs's dtype where they enter a grouped
+    matmul: ``h * w`` (the pair's weight rides on the hidden side of
+    dw_down, so dy goes in as it is) and ``[dg, du]``, the cotangents
+    of g and u side by side (so dxs is one product, not the sum of
+    two); dxs is written in xs's dtype from its float32 sums."""
+    rd, by_expert = _grouped(sizes)
+    flip = lambda a: jnp.swapaxes(a, 1, 2)
+    g, u = _gate_and_up(rd, xs, w_gu)
+    # _GATES stays the one definition of the activations
+    h, pull = jax.vjp(lambda g, u: _GATES[gate](g) * u, g, u)
+    h = h.astype(xs.dtype).astype(jnp.float32)
+    dh = rd(dy, flip(w_down))
+    w = w[:, None]
+    dgu = jnp.concatenate(pull(dh * w), axis=1).astype(xs.dtype)
+    return (jnp.sum(dh * h, axis=1), rd(dgu, flip(w_gu), xs.dtype),
+            by_expert(xs, dgu), by_expert((h * w).astype(xs.dtype), dy))
 
 
 def _chunk(c, cap, order, ends, k):
@@ -285,13 +357,19 @@ def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
     ragged_dot computes no row past the sum of its sizes, and every
     other reader masks them or stops at `count`. `on` is None, or where
     `counted` the hidden units `gate` leaves on over all the pairs
-    (`_swiglu_experts`): the loop carries it beside the output."""
+    (`_swiglu_experts`): the loop carries it beside the output.
+
+    The gate's and the up projection's weights are put side by side
+    once a pass, `[Eh, d, 2f]` (the parameters keep their layout), and
+    that is what the backward keeps of them; nothing of a chunk's size
+    is kept."""
     k = weight.shape[1]
+    w_gu = jnp.concatenate([w_gate, w_up], axis=2)
 
     def body(c, carry):
         out, on = carry
         pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
-        y = _swiglu_experts(x[rows], w_gate, w_up, w_down, sizes, gate,
+        y = _swiglu_experts(x[rows], w_gu, w_down, sizes, gate,
                             count if counted else None)
         if counted:
             y, here = y
@@ -304,39 +382,44 @@ def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
         0, (ends[-1] + cap - 1) // cap, body,
         (moe_rows.zeros(x.shape, how), jnp.int32(0) if counted else None))
     return (moe_rows.result(out, x.shape, x.dtype, how, dtype), on), (
-        x, weight, w_gate, w_up, w_down, order, ends)
+        x, weight, w_gu, w_down, order, ends)
 
 
 def _held_bwd(cap, how, dtype, gate, counted, res, douts):
-    x, weight, w_gate, w_up, w_down, order, ends = res
+    x, weight, w_gu, w_down, order, ends = res
     k = weight.shape[1]
     dout = douts[0].astype(x.dtype)
 
-    def body(c, carry):
-        dx, dweight, dws = carry
+    def chunk(c, dx, dweight):
+        """(dx and dweight with chunk c's pairs added, the chunk's own
+        float32 gradients of `w_gu` and `w_down`)."""
         pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
+        dw, dxs, *dws = _swiglu_experts_bwd(
+            x[rows], dout[rows], weight.reshape(-1)[pairs], w_gu, w_down,
+            sizes, gate)
+        # places past `count` name other experts' pairs
         there = jnp.arange(cap, dtype=jnp.int32) < count
-        y, vjp = jax.vjp(
-            lambda xs, *ws: _swiglu_experts(xs, *ws, sizes, gate),
-            x[rows], w_gate, w_up, w_down)
-        dy = jnp.where(there[:, None], dout[rows], 0).astype(jnp.float32)
-        # a pair's weight multiplies its sorted row, and its gradient is
-        # that row's product with the row's cotangent
-        dweight = dweight.at[pairs].add(
-            jnp.where(there, jnp.sum(dy * y, axis=1), 0.0))
-        dxs, *dw = vjp(dy * weight.reshape(-1)[pairs][:, None])
-        dx = moe_rows.scatter_add(dx, x.shape, dxs, rows, None, count, how)
-        return dx, dweight, [a + b.astype(jnp.float32)
-                             for a, b in zip(dws, dw)]
+        return (moe_rows.scatter_add(dx, x.shape, dxs, rows, None, count,
+                                     how),
+                dweight.at[pairs].add(jnp.where(there, dw, 0.0)), dws)
 
+    def body(c, carry):
+        dx, dweight, dws = chunk(c, *carry[:2])
+        return dx, dweight, [a + b for a, b in zip(carry[2], dws)]
+
+    # chunk 0 runs ahead of the loop, so that the weights' float32
+    # gradients START as its results: a layer runs one chunk as a rule,
+    # and a loop that carried them from zeros would read and write each
+    # once more for it. With no pair at all chunk 0 gives exact zeros.
     dx, dweight, dws = lax.fori_loop(
-        0, (ends[-1] + cap - 1) // cap, body,
-        (moe_rows.zeros(x.shape, how),
-         jnp.zeros(weight.size, weight.dtype),
-         [jnp.zeros(w.shape, jnp.float32) for w in (w_gate, w_up, w_down)]))
+        1, (ends[-1] + cap - 1) // cap, body,
+        chunk(0, moe_rows.zeros(x.shape, how),
+              jnp.zeros(weight.size, weight.dtype)))
+    f = w_gu.shape[2] // 2
     return (moe_rows.result(dx, x.shape, x.dtype, how),
             dweight.reshape(weight.shape),
-            *(d.astype(w.dtype) for d, w in zip(dws, (w_gate, w_up, w_down))),
+            *(d.astype(w_down.dtype) for d in (
+                dws[0][:, :, :f], dws[0][:, :, f:], dws[1])),
             None, None)
 
 

@@ -293,21 +293,24 @@ def _experts(seed=0):
     return mk(N, D), mk(D, E), mk(E, D, F), mk(E, D, F), mk(E, F, D)
 
 
-def _dense_experts(x, wr, wg, wu, wd, first, held):
-    """Every expert of the share on every row."""
+def _dense_experts(x, wr, wg, wu, wd, first, held, act=jax.nn.silu,
+                   dtype=jnp.float32):
+    """Every expert of the share on every row, in float32; `dtype`: what
+    the layer rounds the experts' input to (its weights' dtype)."""
     _, w, idx = moe.route(x, wr, K, True)
+    x = x.astype(dtype).astype(jnp.float32)
     out = 0.0
     for e in range(first, first + held):
         w_e = jnp.sum(jnp.where(idx == e, w, 0.0), 1)
-        out = out + w_e[:, None] * (
-            (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+        out = out + w_e[:, None] * ((act(x @ wg[e]) * (x @ wu[e])) @ wd[e])
     return out
 
 
-def _held(x, wr, wg, wu, wd, first, held):
-    return moe.routed_experts(x, wr, wg[first:first + held],
-                              wu[first:first + held], wd[first:first + held],
-                              E, first, K, True)[0]
+def _held(x, wr, wg, wu, wd, first, held, activation="silu",
+          dtype=jnp.float32):
+    return moe.routed_experts(
+        x, wr, *(w[first:first + held].astype(dtype) for w in (wg, wu, wd)),
+        E, first, K, True, activation=activation)[0]
 
 
 def _one_sided(x, wr):
@@ -315,30 +318,65 @@ def _one_sided(x, wr):
     return x.at[:, 0].set(1.0), (wr * 0.01).at[0, 4:8].set(50.0)
 
 
-@pytest.mark.parametrize("routing", ["uniform", "all_on_held"])
-def test_expert_layer_is_the_dense_loop(routing):
-    """Output and every gradient (x, router, the three weights) against
-    a dense loop over the held experts; with every row sent to held
-    experts the layer runs several chunks and drops nothing."""
+@pytest.mark.parametrize("gate", ["silu", "relu"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("routing", ["uniform", "all_on_held", "two_chunks",
+                                     "no_held_pair"])
+def test_expert_layer_is_the_dense_loop(routing, dtype, gate):
+    """Output and every gradient (x, router, the three weights) of the
+    written backward (ISSUE 47) against `jax.grad` of a dense loop over
+    the held experts, float32 and with bfloat16 experts (whose weights
+    both sides round alike; the layer then rounds the hidden activations
+    and the cotangents where they enter a grouped matmul, the dense loop
+    nothing). `all_on_held`: every row on held experts, nothing dropped,
+    one full chunk. `two_chunks`: twice the rows, so the pairs pass one
+    chunk and the loop over chunks runs twice (chunk 0's gradients plus
+    an added chunk's). `no_held_pair`: the share at 8 holds no chosen
+    expert: zeros, exactly."""
     x, wr, wg, wu, wd = _experts()
-    if routing == "all_on_held":
+    first = 4
+    if routing == "two_chunks":
+        x = jnp.concatenate([x, _experts(seed=1)[0]])
+    if routing != "uniform":
         x, wr = _one_sided(x, wr)
+    if routing == "no_held_pair":
+        first = 8
+    wg, wu, wd = (w.astype(dtype).astype(jnp.float32) for w in (wg, wu, wd))
     args = (x, wr, wg, wu, wd)
-    _, _, counts, _ = moe.routed_experts(x, wr, wg[4:8], wu[4:8], wd[4:8],
-                                         E, 4, K, True)
-    assert int(counts.sum()) == N * K
-    if routing == "all_on_held":
-        assert counts.tolist() == [0] * 4 + [N] * 4 + [0] * 8
-    np.testing.assert_allclose(_held(*args, 4, HELD),
-                               _dense_experts(*args, 4, HELD), atol=1e-5)
-    sq = lambda f: lambda *a: (f(*a, 4, HELD) ** 2).sum()
+    n = x.shape[0]
+    _, _, counts, _ = moe.routed_experts(
+        x, wr, wg[first:first + 4], wu[first:first + 4],
+        wd[first:first + 4], E, first, K, True)
+    assert int(counts.sum()) == n * K
+    if routing != "uniform":
+        assert counts.tolist() == [0] * 4 + [n] * 4 + [0] * 8
+    if routing == "two_chunks":     # 768 pairs in chunks of 512
+        assert n * K > -(-2 * n * K * HELD // E // 512) * 512
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[gate]
+    held = functools.partial(_held, activation=gate, dtype=dtype)
+    dense = functools.partial(_dense_experts, act=act, dtype=dtype)
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    want_out = dense(*args, first, HELD)
+    np.testing.assert_allclose(
+        held(*args, first, HELD), want_out,
+        atol=1e-5 if dtype == jnp.float32
+        else tol * float(jnp.max(jnp.abs(want_out))) + 1e-30)
+    sq = lambda f: lambda *a: (f(*a, first, HELD) ** 2).sum()
     # each as ONE program: eagerly the layer's loop, the dense loop over
     # the experts and their transposes are compiled an op at a time
-    got = jax.jit(jax.grad(sq(_held), (0, 1, 2, 3, 4)))(*args)
-    want = jax.jit(jax.grad(sq(_dense_experts), (0, 1, 2, 3, 4)))(*args)
+    got = jax.jit(jax.grad(sq(held), (0, 1, 2, 3, 4)))(*args)
+    want = jax.jit(jax.grad(sq(dense), (0, 1, 2, 3, 4)))(*args)
     for name, a, b in zip(("x", "router", "gate", "up", "down"), got, want):
+        if routing == "no_held_pair":
+            assert float(jnp.max(jnp.abs(a))) == 0.0 == float(
+                jnp.max(jnp.abs(b))), name
+            continue
+        # the layer's share of the weights' gradients: the held experts'
+        if name in ("gate", "up", "down"):
+            assert float(jnp.max(jnp.abs(a[:first]))) == 0.0, name
         scale = float(jnp.max(jnp.abs(b))) + 1e-9
-        assert float(jnp.max(jnp.abs(a - b))) / scale < 1e-4, name
+        assert float(jnp.max(jnp.abs(a - b))) / scale < tol, name
 
 
 @pytest.mark.parametrize("routing", ["uniform", "all_on_held"])

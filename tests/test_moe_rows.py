@@ -165,43 +165,29 @@ def test_a_chunk_of_no_whole_block_is_padded_with_more_tail():
 E, HELD, F = 16, 4, 32
 ROUTERS = {
     "softmax_top8": dict(top_k=8, score="softmax"),
+    "softmax_top8_relu": dict(top_k=8, score="softmax", activation="relu"),
     "sigmoid_top4_bias": dict(top_k=4, score="sigmoid", scaling=2.0,
                               bias=jnp.linspace(-0.2, 0.2, E)),
 }
 
 
-def _layer(d, seed):
+def _layer(d, seed, n=N):
     rng = np.random.RandomState(seed)
     mk = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
-    return (mk(N, d), mk(d, E) * 0.3, mk(HELD, d, F) * d ** -0.5,
+    return (mk(n, d), mk(d, E) * 0.3, mk(HELD, d, F) * d ** -0.5,
             mk(HELD, d, F) * d ** -0.5, mk(HELD, F, d) * F ** -0.5)
 
 
-@pytest.mark.parametrize("all_held", [False, True],
-                         ids=["routers_own", "every_row_on_held_experts"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("router", sorted(ROUTERS))
-def test_routed_experts_by_the_kernel_matches_xlas_scatter_add(
-        router, dtype, all_held):
-    """Output and all five gradients, `force="interpret"` against
-    `force="xla"`. With every row on held experts the loop runs several
-    chunks and nothing is dropped. float32 experts agree to rounding;
-    under bfloat16 dx parts by a bfloat16 ulp or two: the kernel reads
-    the grouped matmul's bfloat16 dxs as written, XLA's fused
-    scatter-add keeps excess precision."""
-    how = dict(ROUTERS[router])
-    k = how.pop("top_k")
-    x, wr, wg, wu, wd = _layer(256, len(router))
-    if all_held:
-        # one huge feature decides the choice: experts 0 .. k - 1 of the
-        # 16, so a held share of 8 holds them all
-        x = x.at[:, 0].set(8.0)
-        wr = (wr * 0.01).at[0, :k].set(5.0)
-        how.pop("bias", None)
-    held = 8 if all_held else HELD
-    if all_held:
-        wg, wu, wd = (jnp.concatenate([w, w * 0.5]) for w in (wg, wu, wd))
+def _on_the_first(x, wr, k):
+    """One huge feature decides the choice: experts 0 .. k - 1 of the
+    16, whatever the row."""
+    return x.at[:, 0].set(8.0), (wr * 0.01).at[0, :k].set(5.0)
+
+
+def _kernel_against_xla(args, k, dtype, how):
+    """((out, counts), the five gradients) by `force="interpret"`, the
+    same by `force="xla"` held to it: float32 experts agree to rounding;
+    under bfloat16 dx parts by a bfloat16 ulp or two."""
     def loss(force, x, wr, wg, wu, wd):
         out, aux, counts, _ = moe.routed_experts(
             x, wr, wg.astype(dtype), wu.astype(dtype), wd.astype(dtype), E,
@@ -212,17 +198,58 @@ def test_routed_experts_by_the_kernel_matches_xlas_scatter_add(
     # the layer's loop and its transpose are compiled an op at a time
     got, want = (jax.jit(jax.value_and_grad(
         functools.partial(loss, force), (0, 1, 2, 3, 4), has_aux=True))(
-            x, wr, wg, wu, wd) for force in ("interpret", "xla"))
-    (out, counts), (out_xla, _) = got[0][1], want[0][1]
-    if all_held:
-        assert int(counts[:held].sum()) == N * k
+            *args) for force in ("interpret", "xla"))
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     close = lambda a, b: np.testing.assert_allclose(
         np.asarray(a, np.float32), np.asarray(b, np.float32),
         atol=tol * float(jnp.max(jnp.abs(b.astype(jnp.float32))) + 1e-6))
-    close(out, out_xla)
+    close(got[0][1][0], want[0][1][0])
     for a, b in zip(got[1], want[1]):
         close(a, b)
+    return got[0][1], got[1]
+
+
+@pytest.mark.parametrize("all_held", [False, True],
+                         ids=["routers_own", "every_row_on_held_experts"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_routed_experts_by_the_kernel_matches_xlas_scatter_add(
+        router, dtype, all_held):
+    """Output and all five gradients, `force="interpret"` against
+    `force="xla"`, a SiLU and a ReLU gate. With every row on held
+    experts the one chunk is full and nothing is dropped (two chunks:
+    the next test). float32 experts agree to rounding; under bfloat16
+    dx parts by a bfloat16 ulp or two: the kernel reads the grouped
+    matmul's bfloat16 dxs as written, XLA's fused scatter-add keeps
+    excess precision."""
+    how = dict(ROUTERS[router])
+    k = how.pop("top_k")
+    x, wr, wg, wu, wd = _layer(256, len(router))
+    if all_held:
+        # experts 0 .. k - 1 of the 16, so a held share of 8 holds them
+        x, wr = _on_the_first(x, wr, k)
+        how.pop("bias", None)
+        wg, wu, wd = (jnp.concatenate([w, w * 0.5]) for w in (wg, wu, wd))
+    (_, counts), _ = _kernel_against_xla((x, wr, wg, wu, wd), k, dtype, how)
+    if all_held:
+        assert int(counts[:8].sum()) == N * k
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_a_second_chunk_adds_to_the_firsts_gradients(dtype):
+    """256 rows, every one on the 4 held experts of 16: 1,024 pairs in
+    chunks of 512, so both loops run twice: the backward's carry is
+    chunk 0's own gradients (ISSUE 47) with chunk 1's added, and every
+    expert's rows lie across the two."""
+    x, wr, wg, wu, wd = _layer(256, 47, n=4 * N)
+    x, wr = _on_the_first(x, wr, 4)
+    (_, counts), grads = _kernel_against_xla(
+        (x, wr, wg, wu, wd), 4, dtype, dict(score="sigmoid", scaling=2.0))
+    assert counts.tolist() == [4 * N] * 4 + [0] * 12
+    # (the router's scores are saturated, its gradient an exact zero)
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for g in grads[:1] + grads[2:])
 
 
 def test_the_lowering_counter_says_what_moved_the_rows():

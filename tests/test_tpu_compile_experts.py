@@ -2,7 +2,10 @@
 (``parallel/moe.py``, ``ops/moe_rows.py``) compiled for a described v5e
 (tests/tpu_compile_test.py says how and why): 16,384 rows over 16 held
 of 128 experts, whose grouped matmuls are XLA's own `ragged-dot`
-kernels, and Xing4.0's 4,096 rows.
+kernels, Xing4.0's 4,096 rows and SmallThinker's ReLU-gated 16 of 64;
+and the traced program of the layer's gradient held to the written
+backward's count of grouped matmuls and to their operands' types
+(ISSUE 47).
 """
 
 import pytest
@@ -11,47 +14,129 @@ from tpu_compile_test import _compiled_text, chip, topo  # noqa: F401
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax.extend.core import Literal  # noqa: E402
 
 
-@pytest.mark.parametrize("shape", [(16384, 2048, 768, 128, 16, 8),
-                                   (4096, 3584, 1024, 64, 8, 4)],
-                         ids=["sdar_train_bd4k", "xing4_train_T4k"])
-def test_routed_experts_compile_for_v5e(chip, shape):
+SHAPES = {"sdar_train_bd4k": (16384, 2048, 768, 128, 16, 8, "silu"),
+          "xing4_train_T4k": (4096, 3584, 1024, 64, 8, 4, "silu"),
+          "smallthinker_train_T16k": (16384, 2560, 768, 64, 16, 6, "relu")}
+
+
+def _layer_gradient(shape, sharding=None):
+    """(value and the five gradients of one expert layer as the cells'
+    steps call it: float32 rows and router, bfloat16 experts; its
+    arguments' shapes)."""
+    from paddle_tpu.parallel import moe
+    n, d, f, e, held, k, gate = shape
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=sharding)
+    w_in = sds((held, d, f), jnp.bfloat16)
+
+    def loss(x, wr, wg, wu, wd):
+        out, aux, _, _ = moe.routed_experts(x, wr, wg, wu, wd, e, 0, k,
+                                            force="pallas", activation=gate)
+        return out.astype(jnp.float32).sum() + aux
+
+    # the value too: XLA drops a forward whose result nobody reads
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), (
+        sds((n, d), jnp.float32), sds((d, e), jnp.float32), w_in, w_in,
+        sds((held, f, d), jnp.bfloat16))
+
+
+def _jaxprs(jaxpr):
+    """A jaxpr and the jaxprs inside it."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _jaxprs(sub)
+
+
+def _sums_of_grouped_matmuls(jaxpr):
+    """The additions whose two terms are both grouped matmuls' results,
+    as they come or cast."""
+    found = []
+    for part in _jaxprs(jaxpr):
+        grouped = set()
+        for eqn in part.eqns:
+            name, ins = eqn.primitive.name, [
+                v for v in eqn.invars if not isinstance(v, Literal)]
+            if name == "ragged_dot_general" or (
+                    name in ("convert_element_type", "transpose")
+                    and ins and ins[0] in grouped):
+                grouped.update(eqn.outvars)
+            elif name in ("add", "add_any") and len(ins) == 2 and all(
+                    v in grouped for v in ins):
+                found.append(eqn)
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_the_backward_is_the_written_one(cell):
+    """The guard that keeps autodiff from growing back (ISSUE 47), read
+    off the traced program of the layer's gradient; nothing is compiled.
+    A pass over a chunk is 2 grouped matmuls forward (gate and up side
+    by side, down) and 5 backward (the hidden activations again, dh,
+    dW_down, dxs, dW_gate-and-up): the forward's loop holds 2, the
+    backward's 5 and chunk 0, run ahead of it, 5 more. Every one takes
+    bfloat16 operands and sums in float32 (dxs alone is WRITTEN as
+    bfloat16, as the parent rounded it); and no float32 value of a
+    chunk's rows by the model's width is the sum of two others: the
+    gate's and the up projection's cotangents are one product."""
+    n, d, f, e, held, k, _ = SHAPES[cell]
+    fn, avals = _layer_gradient(SHAPES[cell])
+    cap = 2 * n * k * held // e
+    top = jax.make_jaxpr(fn)(*avals).jaxpr
+    grouped = lambda jaxpr: [eqn for part in _jaxprs(jaxpr)
+                             for eqn in part.eqns
+                             if eqn.primitive.name == "ragged_dot_general"]
+    assert len(grouped(top)) == 12
+    loops = [len(grouped(eqn.params["body_jaxpr"].jaxpr))
+             for eqn in top.eqns if eqn.primitive.name == "while"]
+    assert sorted(m for m in loops if m) == [2, 5]
+    for eqn in grouped(top):
+        lhs, rhs = (v.aval for v in eqn.invars[:2])
+        assert lhs.dtype == rhs.dtype == jnp.bfloat16, eqn
+        out, = eqn.outvars
+        wide_rows = out.aval.shape == (cap, d) and lhs.shape == (cap, 2 * f)
+        assert out.aval.dtype == (jnp.bfloat16 if wide_rows
+                                  else jnp.float32), eqn
+    sums = _sums_of_grouped_matmuls(top)
+    assert not sums, sums[:2]
+
+
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_routed_experts_compile_for_v5e(chip, cell):
     """A routed cell's expert layer, forward and backward: grouped
     matmuls as XLA's ragged-dot kernels inside the two loops over chunks,
     on a chunk's rows (32,768; 4,096): no hidden activation of the worst
     case's N * top_k rows exists. ISSUE 35: a chunk's rows go back to
-    their tokens by `moe_scatter_add_rows` (once forward, once for dx)
-    and each accumulator leaves its slab by `moe_leave_slab`, under
-    their own names; XLA scatters nothing of x's width (what is left of
-    that kind is the pairs' weights, one number a place), and its
-    gathers of a chunk's rows stay: x forward, x and dout backward."""
+    their tokens by `moe_scatter_add_rows` (once forward, once for dx of
+    chunk 0, run ahead of the backward's loop since ISSUE 47, and once
+    in that loop) and each accumulator leaves its slab by
+    `moe_leave_slab`, under their own names; XLA scatters nothing of
+    x's width (what is left of that kind is the pairs' weights, one
+    number a place), and its gathers of a chunk's rows stay: x forward, x and dout backward.
+    ISSUE 47: the `ragged-dot` kernels read bfloat16 operands alone (the
+    parent's backward handed six of twelve a float32 one, rounded inside
+    the kernel at twice the bytes)."""
     import re
-    from paddle_tpu.parallel import moe
-    n, d, f, e, held, k = shape
-    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                    sharding=chip)
-    x = sds((n, d), jnp.float32)
-    wr = sds((d, e), jnp.float32)
-    w_in, w_out = sds((held, d, f), jnp.bfloat16), sds((held, f, d),
-                                                       jnp.bfloat16)
-
-    def loss(x, wr, wg, wu, wd):
-        out, aux, _, _ = moe.routed_experts(x, wr, wg, wu, wd, e, 0, k,
-                                            force="pallas")
-        return out.astype(jnp.float32).sum() + aux
-
-    # the value too: XLA drops a forward whose result nobody reads
-    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
-                          x, wr, w_in, w_in, w_out)
+    n, d, f, e, held, k, _ = SHAPES[cell]
+    fn, avals = _layer_gradient(SHAPES[cell], chip)
+    text = _compiled_text(fn, *avals)
     assert "ragged-dot" in text and "while" in text
     hidden = {int(rows) for rows in re.findall(
         r"(?:bf16|f32)\[(\d+),%d\]" % f, text)}
     cap = 2 * n * k * held // e
     assert hidden and max(hidden) == cap
+    kernels = re.findall(r"%ragged-dot-none[.\d]* = \S+ custom-call\(.*?"
+                         r"operand_layout_constraints=\{(.*?)\}", text)
+    assert len(kernels) == 12 and not any("f32[" in ops for ops in kernels)
     calls = lambda name: len(re.findall(
         r"%%%s[.\d]* = \S+ custom-call\(" % name, text))
-    assert calls("moe_scatter_add_rows") == 2
+    assert calls("moe_scatter_add_rows") == 3
     assert calls("moe_leave_slab") == 2
     wide = lambda kind: [line for line in text.splitlines() if re.search(
         r" %s\(" % kind, line) and re.search(r"\[\d+,%d\]" % d, line)]
