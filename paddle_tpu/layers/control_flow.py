@@ -103,13 +103,17 @@ class BlockGuard:
 class recompute(BlockGuard):
     """Rematerialization region (``with layers.recompute(): ...``): ops
     built inside the block re-run during the backward pass instead of
-    storing their activations (jax.checkpoint over the sub-block). One
-    thing is kept and not re-run: where attention takes the flash
+    storing their activations (jax.checkpoint over the sub-block). Two
+    things are kept and not re-run: where attention takes the flash
     kernels, the forward kernel's output and lse rows, which are all the
     backward kernels read of it (B x T x H*Dv x 2 bytes a call in bf16);
-    the projections, norms and FFN round it are recomputed. Wrap each
-    transformer layer to train longer sequences / bigger batches in the
-    same HBM at ~1/3 extra forward FLOPs, less the attention kernel's.
+    and, on a device that states how much it may hold, the results of
+    the regions' matmuls (`mul` ops) for as many as fit what the step
+    leaves free, the widest contractions first (ops/control_flow.py,
+    _plan_kept_muls; nothing to set). The norms, gates and whatever does
+    not fit are recomputed. Wrap each transformer layer to train longer
+    sequences / bigger batches in the same HBM at up to ~1/3 extra
+    forward FLOPs.
     Fetch intermediates OUTSIDE a region — exporting them would defeat
     the remat."""
 

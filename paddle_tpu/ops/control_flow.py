@@ -12,9 +12,13 @@ reference's RecurrentGradOp entirely. Variable-length sequences use masking
 equivalent of shrink_rnn_memory.
 """
 
+import logging
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import registry
 from ..monitor import metrics as _metrics
@@ -177,23 +181,235 @@ _KEPT_BYTES = _REG.counter(
     "their backward in place of recomputing them, added where a "
     "region's gradient is traced from the shape and dtype of each value "
     "its policy saves, by the value's name (flash_out, flash_lse: a "
-    "flash forward kernel's results, ops/flash_attention.py); a region "
-    "with no such value in it, or one that is never differentiated, "
-    "adds nothing",
+    "flash forward kernel's results, ops/flash_attention.py; mul_out: "
+    "the results of the `mul` ops that the block's plan admitted); a "
+    "region with no such value in it, or one that is never "
+    "differentiated, adds nothing",
     ("name",))
-_KEEPS = jax.checkpoint_policies.save_only_these_names(*KEPT_IN_REGIONS)
+_MUL_PLAN = _REG.gauge(
+    "ptpu_recompute_mul_plan",
+    "the last plan of a block's regions (_plan_kept_muls), set where "
+    "its first region is lowered: `candidates` the `mul` results a "
+    "backward rule reads, `admitted` those of them that are kept, "
+    "`admitted_bytes` their bytes and `budget_bytes` what they had to "
+    "fit in",
+    ("what",))
+# the ONE name of a `mul` result that a region keeps
+MUL_OUT = "mul_out"
+_KEEPS = jax.checkpoint_policies.save_only_these_names(*KEPT_IN_REGIONS,
+                                                       MUL_OUT)
+_LOG = logging.getLogger(__name__)
 
 
 def _region_policy(prim, *avals, **params):
     """What a recompute region saves: the values named in
-    flash_attention.KEPT_IN_REGIONS and nothing else. JAX asks once for
-    every equation of a region whose gradient it traces, which is where
-    the kept bytes are counted."""
+    flash_attention.KEPT_IN_REGIONS, the `mul` results that the block's
+    plan named MUL_OUT, and nothing else. JAX asks once for every
+    equation of a region whose gradient it traces, which is where the
+    kept bytes are counted."""
     keeps = _KEEPS(prim, *avals, **params)
     if keeps:
         _KEPT_BYTES.inc(sum(a.size * a.dtype.itemsize for a in avals),
                         name=params["name"])
     return keeps
+
+
+def _op_reads(o, seen=None):
+    """The names an op may read: its declared inputs PLUS everything
+    read inside any sub-block it carries (While/recurrent/IfElse bodies
+    do not re-declare their body reads as parent-op inputs)."""
+    seen = set() if seen is None else seen
+    names = set(o.input_names)
+    for a in o.attrs.values():
+        blocks = a if isinstance(a, (list, tuple)) else [a]
+        for b in blocks:
+            if hasattr(b, "ops") and id(b) not in seen:
+                seen.add(id(b))
+                for o2 in b.ops:
+                    names |= _op_reads(o2, seen)
+    return names
+
+
+def _later_reads(ops, idx):
+    """What the ops after ops[idx] may read."""
+    return set().union(*(_op_reads(o) for o in ops[idx + 1:]))
+
+
+def _device_limit(ctx):
+    """The bytes the executor's device says it may hold
+    (memory_stats()["bytes_limit"]), 0 where the backend gives none, as
+    the CPU does: the plan then admits nothing and a region lowers as
+    under PR 42."""
+    place = getattr(ctx.executor, "place", None)
+    if place is None:
+        return 0
+    return int((place.jax_device().memory_stats() or {}).get(
+        "bytes_limit", 0))
+
+
+# the ops whose backward rules read none of their operands: a value
+# that only they read, up to the region's end, is dead in the region's
+# second forward
+_SUMS = ("elementwise_add", "elementwise_sub", "sum")
+
+
+def _read_by_a_backward_rule(ops, idx):
+    """Whether some op after ops[idx] other than an addition reads its
+    result, directly or through additions: the LAST product of a
+    branch (down, out_proj, wo), which goes into the stream and nowhere
+    else, is not, and keeping it would save no work."""
+    through = set(ops[idx].output_names)
+    for o in ops[idx + 1:]:
+        if _op_reads(o) & through:
+            if o.type not in _SUMS:
+                return True
+            through.update(o.output_names)
+    return False
+
+
+class _Unsized(Exception):
+    """A Program variable whose declared shape does not give its size."""
+
+
+def _plan_kept_muls(ctx):
+    """Which `mul` results the regions of ctx.block keep from their
+    forward to their backward, as {id(op)}: chosen ONCE, from the
+    Program's static shapes, where the block's first region is lowered
+    (no second trace, no compile), by what differs between programs and
+    nothing else: the widths, the rows and what the device has free.
+
+    * candidates: every `mul` of a region whose result a backward rule
+      of the region reads (_read_by_a_backward_rule), with the bytes of
+      its result (rows x columns x the itemsize amp.result_dtype gives)
+      and what making it again costs (2 x rows x K x columns FLOPs);
+    * order: FLOPs a byte, highest first (2 K over the itemsize: the
+      wide-K products first), program order among equals;
+    * budget: the device's limit (_device_limit) less the step's state
+      as the trace holds it (every persistable value: parameters,
+      Adam's moments) less a reserve for the two moments at which a
+      backward holds most. What is live at both: what the ops before
+      the last region write outside regions and what the regions hand
+      on (the stream between layers). Then the larger of the HEAD (what
+      the ops after the last region write, and the widest of it, the
+      logits, once more for its gradient) and TWICE the largest
+      region's own variables (its second forward's values and their
+      cotangents). A variable counts its declared bytes (a `mul`
+      result what AMP makes it, a reshape nothing: it is a view), so a
+      float32 variable that AMP holds in bf16 counts double; the
+      gradients are not taken off besides: XLA frees one as its update
+      has read it, and they come as the values they are made from go
+      (PERF.md section 6, PR 48, has the reckoning beside the compiled
+      peaks);
+    * admit in order while the sum fits.
+
+    No marker in the block (nothing is differentiated), no limit to
+    read, or a variable whose shape does not say its size: nothing is
+    admitted."""
+    from ..amp import result_dtype
+    block, env = ctx.block, ctx.env
+    ops = list(block.ops) if block is not None else []
+    marker = next((i for i, o in enumerate(ops) if o.type in (
+        "backward_marker", "calc_gradient_marker")), None)
+    limit = _device_limit(ctx) if marker is not None else 0
+    regions = [i for i, o in enumerate(ops[:marker])
+               if o.type == "recompute_block"]
+    if not limit or not regions:
+        return frozenset()
+    # of each variable the walk has met: its Program variable (a
+    # region's own live in its sub-block, which a later region's does
+    # not see), its elements and its bytes
+    declared, elements, nbytes = {}, {}, {}
+
+    def var_of(blk, name):
+        if name not in declared:
+            declared[name] = blk._find_var_recursive(name)
+        return declared[name]
+
+    def count(name):
+        if name in env:
+            return env[name].size
+        if name not in elements:
+            raise _Unsized(name)
+        return elements[name]
+
+    def sized(blk, o):
+        """Bytes of what `o` writes, by the declared shapes: a -1 is
+        what it is in the first operand that has one, a reshape keeps
+        its operand's elements and is a view of it."""
+        written = set(o.output_names)
+        for name in written:
+            var = var_of(blk, name)
+            if var is None or var.shape is None:
+                raise _Unsized(name)
+            n = math.prod(s for s in var.shape if s >= 0)
+            if o.type == "reshape":
+                n = count(o.input("X")[0])
+            elif min(var.shape, default=0) < 0:
+                like = next((v for v in (var_of(blk, r) for r in sorted(
+                    _op_reads(o))) if v is not None and v.shape
+                    and min(v.shape) < 0), None)
+                if like is None:
+                    raise _Unsized(name)
+                n *= count(like.name) // math.prod(
+                    s for s in like.shape if s >= 0)
+            dtype = result_dtype(var.dtype) if o.type == "mul" \
+                else var.dtype
+            elements[name] = n
+            nbytes[name] = 0 if var.persistable or o.type == "reshape" \
+                else n * jnp.dtype(dtype).itemsize
+        return sum(nbytes[n] for n in written)
+
+    candidates, stream, head, widest, largest = [], 0, 0, 0, 0
+    try:
+        for i, o in enumerate(ops[:marker]):
+            if o.type != "recompute_block":
+                size = sized(block, o)
+                if i < regions[-1]:
+                    stream += size
+                else:
+                    head, widest = head + size, max(widest, size)
+                continue
+            sub = o.attr("sub_block")
+            own = 0
+            for j, m in enumerate(sub.ops):
+                size = sized(sub, m)
+                own += size
+                if m.type == "mul" and _read_by_a_backward_rule(sub.ops, j):
+                    y = var_of(sub, m.input("Y")[0])
+                    yn = m.attr("y_num_col_dims", 1)
+                    k = math.prod(y.shape[yn:] if m.attr(
+                        "transpose_Y", False) else y.shape[:yn])
+                    cost = 2 * k * elements[m.output("Out")[0]]
+                    candidates.append((cost / size, size, id(m)))
+            largest = max(largest, own)
+            stream += sum(nbytes[n] for n in
+                          set(o.output("Out")) & _later_reads(ops, i))
+    except _Unsized as e:
+        _LOG.info("recompute: the shape of %s does not say its size; no "
+                  "mul result is kept", e)
+        return frozenset()
+    state = sum(env[n].size * env[n].dtype.itemsize
+                for n, v in block.vars.items() if v.persistable and n in env)
+    reserve = stream + max(head + widest, 2 * largest)
+    budget = max(0, limit - state - reserve)
+    admitted, total = set(), 0
+    # (a stable sort: program order among equals)
+    for _, size, op_id in sorted(candidates, key=lambda c: -c[0]):
+        if total + size > budget:
+            break
+        admitted.add(op_id)
+        total += size
+    for what, value in (("candidates", len(candidates)),
+                        ("admitted", len(admitted)),
+                        ("admitted_bytes", total),
+                        ("budget_bytes", budget)):
+        _MUL_PLAN.set(value, what=what)
+    _LOG.info("recompute: %d of %d mul results kept, %d bytes of a "
+              "budget of %d (the device's limit %d less state %d and a "
+              "reserve of %d: stream %d, head %d + %d, largest region "
+              "2 x %d)", len(admitted), len(candidates), total, budget,
+              limit, state, reserve, stream, head, widest, largest)
+    return frozenset(admitted)
 
 
 @register("recompute_block")
@@ -206,13 +422,19 @@ def _recompute_block(ctx, op):
     through the region; RNG-consuming ops (dropout) reuse one region key,
     so the recompute replays identical masks.
 
-    ONE thing inside a region is kept and not recomputed: the output and
-    the log-sum-exp rows of a flash forward kernel (_region_policy), all
-    that the flash backward reads of it, at B x T x H*Dv x 2 bytes (bf16)
-    a call. So the kernel runs once a layer, and its q, k and v are still
-    recomputed with the projections that make them. A region with no
-    flash kernel in it (the dense path, plain layers) keeps nothing and
-    lowers as under a bare jax.checkpoint.
+    TWO things inside a region are kept and not recomputed
+    (_region_policy). The output and the log-sum-exp rows of a flash
+    forward kernel, all that the flash backward reads of it, at B x T x
+    H*Dv x 2 bytes (bf16) a call, so the kernel runs once a layer. And
+    the results of the `mul` ops that the block's plan admitted
+    (_plan_kept_muls: the products a backward rule reads, the costliest
+    a byte first, while they fit what the device has free), each the
+    value the next op reads, at the precision the forward made it; the
+    plan is made for all the block's regions where the first is
+    lowered. The norms, gates, scans and expert layers round them are
+    still recomputed. A region with no flash kernel in it on a device
+    that states no limit (the CPU) keeps nothing and lowers as under a
+    bare jax.checkpoint.
 
     Outputs exported from the region are the sub-block writes consumed
     by LATER ops of the parent block (looking through their sub-blocks),
@@ -229,24 +451,7 @@ def _recompute_block(ctx, op):
         raise RuntimeError(
             "recompute_block op not found in its parent block's op list "
             "— the lowering must run on the block that owns the op")
-    # names a later op may read: its declared inputs PLUS everything read
-    # inside any sub-block it carries (While/recurrent/IfElse bodies do
-    # not re-declare their body reads as parent-op inputs)
-    def op_reads(o, seen=None):
-        seen = set() if seen is None else seen
-        names = {n for ns in o.inputs.values() for n in ns}
-        for a in o.attrs.values():
-            blocks = a if isinstance(a, (list, tuple)) else [a]
-            for b in blocks:
-                if hasattr(b, "ops") and id(b) not in seen:
-                    seen.add(id(b))
-                    for o2 in b.ops:
-                        names |= op_reads(o2, seen)
-        return names
-
-    later_reads = set()
-    for o in parent_ops[my_idx + 1:]:
-        later_reads |= op_reads(o)
+    later_reads = _later_reads(parent_ops, my_idx)
     persistable = {v.name for v in ctx.block.vars.values()
                    if getattr(v, "persistable", False)} \
         if ctx.block is not None else set()
@@ -254,6 +459,11 @@ def _recompute_block(ctx, op):
     out_names = [n for n in op.output("Out")
                  if n in later_reads or n in persistable or n in fetches]
     in_names = [n for n in op.input("X") if n in ctx.env]
+
+    # the block's plan, made where its first region is lowered
+    kept = getattr(ctx, "_kept_muls", None)
+    if kept is None:
+        kept = ctx._kept_muls = _plan_kept_muls(ctx)
 
     base_env = dict(ctx.env)
     region_key = ctx._rng_fn()
@@ -279,6 +489,9 @@ def _recompute_block(ctx, op):
         sctx._op_seq = op_seq         # and so do the ops' scope numbers
         for op2 in block.ops:
             _lower_op(sctx, op2)
+            if id(op2) in kept:
+                out = op2.output("Out")[0]
+                env[out] = checkpoint_name(env[out], MUL_OUT)
         # exports: region outputs + their @LOD lengths (sequence ops
         # inside the region may have changed them) + per-op NaN guards
         # (the every-op-output contract holds inside regions too)

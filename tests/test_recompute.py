@@ -11,7 +11,10 @@ flagship LM trains at 2x the plain batch in the same HBM.
 What a region keeps (ISSUE 42): the output and the lse rows of a flash
 forward kernel, by the names the kernels' fwd rules give them, so the
 kernel runs once a layer; everything else is recomputed, and a region
-with no kernel in it lowers as under a bare jax.checkpoint.
+with no kernel in it lowers as under a bare jax.checkpoint. Since
+ISSUE 48 also the results of the regions' `mul` ops, for as many as a
+byte budget reckoned from the device's limit admits: nothing on the
+CPU, which states no limit.
 """
 
 import collections
@@ -455,3 +458,129 @@ def test_region_with_no_kernel_lowers_as_a_bare_checkpoint(monkeypatch):
     assert not [e for e in got if e[0] == "pallas_call"]
     assert len([e for e in got if "remat" in e[0]]) == _LAYERS
     assert got == bare
+
+
+# -- the `mul` results a region keeps while they fit (ISSUE 48) --------------
+_ROWS = 4 * 8            # batch 4 x 8 rows a sequence
+
+
+def _two_regions(prefix):
+    """Two regions of three products each on a float32 stream [4, 8, 16]:
+    region A 16 -> 64 -> 32 -> 16, region B 16 -> 128 -> 16 -> 16, each
+    `x + last(tanh(second(tanh(first(x)))))`. Four results a backward
+    rule reads, of four widths (so a shape says which): A1 [.., 64] K
+    16, A2 [.., 32] K 64, B1 [.., 128] K 16, B2 [.., 16] K 128; the two
+    last products go into the stream and nowhere else. By FLOPs a byte
+    (2 K / 4): B2, A2, then A1 before B1 (program order among equals).
+    Returns (program, scope, feeds, fetch names: the loss and the six
+    weights' gradients)."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 3
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            unique_name.guard(prefix):
+        x = fluid.layers.data("x", [8, 16])
+        for widths in ((64, 32), (128, 16)):
+            with fluid.layers.recompute():
+                h = x
+                for width in widths:
+                    h = fluid.layers.fc(h, width, num_flatten_dims=2,
+                                        act="tanh", bias_attr=False)
+                x = fluid.layers.elementwise_add(x, fluid.layers.fc(
+                    h, 16, num_flatten_dims=2, bias_attr=False))
+        loss = fluid.layers.mean(fluid.layers.square(x))
+        pg = fluid.append_backward(loss)
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+    feeds = {"x": np.random.RandomState(1).rand(4, 8, 16).astype(np.float32)}
+    return main, scope, feeds, (loss.name,) + tuple(g.name for _, g in pg)
+
+
+_KEPT_WIDTH = {"A1": 64, "A2": 32, "B1": 128, "B2": 16}
+
+
+def _named_mul_out(jaxpr):
+    """The widths of the values the step's jaxpr names `mul_out`."""
+    return sorted(e.outvars[0].aval.shape[-1] for e in _eqns(jaxpr)
+                  if e.primitive.name == "name"
+                  and e.params["name"] == CF.MUL_OUT)
+
+
+def _products(jaxpr):
+    return sum(e.primitive.name == "dot_general" for e in _eqns(jaxpr))
+
+
+def _limit_for(monkeypatch, budget, prefix):
+    """Hand the plan a device limit that leaves `budget` bytes for the
+    program of _two_regions: what a limit of 2**40 leaves says what the
+    state and the reserve take."""
+    monkeypatch.setattr(CF, "_device_limit", lambda ctx: 2 ** 40)
+    _step_jaxpr(*_two_regions(prefix))
+    taken = 2 ** 40 - int(CF._MUL_PLAN.value(what="budget_bytes"))
+    monkeypatch.setattr(CF, "_device_limit", lambda ctx: taken + budget)
+
+
+@pytest.mark.parametrize("budget, kept", [
+    (0, []), (4 * _ROWS * 16 - 1, []), (4 * _ROWS * 16, ["B2"]),
+    (4 * _ROWS * (16 + 32), ["B2", "A2"]),
+    # A1 does not fit, and B1, which would, comes after it
+    (4 * _ROWS * (16 + 32 + 64) - 1, ["B2", "A2"]),
+    (4 * _ROWS * (16 + 32 + 64), ["B2", "A2", "A1"]),
+    (2 ** 30, ["B2", "A2", "A1", "B1"])],
+    ids=["nothing", "a_byte_short", "one", "two", "stops_at_the_budget",
+         "three", "all"])
+def test_regions_keep_the_mul_results_the_budget_admits(monkeypatch, budget,
+                                                        kept):
+    """With a device limit handed in, the two regions keep exactly the
+    results the budget admits, the costliest a byte first, and stop at
+    the first that does not fit: the step's jaxpr names those and no
+    other, runs one product fewer for each (a kept result is not made
+    again), the plan's gauge and the kept-bytes counter say so; the
+    last product of a region is never a candidate."""
+    _limit_for(monkeypatch, budget, "mb_")
+    before = CF._KEPT_BYTES.value(name=CF.MUL_OUT)
+    jaxpr = _step_jaxpr(*_two_regions("mk_"))
+    assert _named_mul_out(jaxpr) == sorted(_KEPT_WIDTH[k] for k in kept)
+    nbytes = 4 * _ROWS * sum(_KEPT_WIDTH[k] for k in kept)
+    assert {w: CF._MUL_PLAN.value(what=w) for w in (
+        "candidates", "admitted", "admitted_bytes", "budget_bytes")} == {
+        "candidates": 4, "admitted": len(kept), "admitted_bytes": nbytes,
+        "budget_bytes": budget}
+    assert CF._KEPT_BYTES.value(name=CF.MUL_OUT) - before == nbytes
+    monkeypatch.setattr(CF, "_device_limit", lambda ctx: 0)
+    assert _products(jaxpr) == _products(
+        _step_jaxpr(*_two_regions("mk_"))) - len(kept)
+
+
+@pytest.mark.parametrize("limit", [None, 0], ids=["cpu", "limit_0"])
+def test_no_limit_to_read_lowers_a_region_as_before(monkeypatch, limit):
+    """On a backend that states no limit (the CPU, as it is) and with a
+    limit of 0 handed in, the step's jaxpr is, equation for equation,
+    what PR 42's policy gives (flash_out, flash_lse and nothing else):
+    no value carries the new name and nothing is counted as kept."""
+    if limit is not None:
+        monkeypatch.setattr(CF, "_device_limit", lambda ctx: limit)
+    kept = CF._KEPT_BYTES.snapshot()
+    got = _step_jaxpr(*_two_regions("mz_"))
+    assert CF._KEPT_BYTES.snapshot() == kept
+    assert _named_mul_out(got) == []
+    monkeypatch.setattr(CF, "_region_policy",
+                        jax.checkpoint_policies.save_only_these_names(
+                            *FA.KEPT_IN_REGIONS))
+    assert _equations(got) == _equations(
+        _step_jaxpr(*_two_regions("mz_")))
+
+
+@pytest.mark.parametrize("amp", [False, True], ids=["float32", "bf16_amp"])
+def test_kept_mul_results_change_no_bit(monkeypatch, amp):
+    """The loss and all six gradients with every result kept against
+    none kept: the same bits, in float32 and under bf16 AMP (a kept
+    result is the value the second forward would have made again, at
+    the precision the first made it)."""
+    with fluid.amp.amp_guard(amp):
+        none = _run(*_two_regions("me_"))
+        monkeypatch.setattr(CF, "_device_limit", lambda ctx: 2 ** 40)
+        kept = _run(*_two_regions("me_"))
+    assert CF._MUL_PLAN.value(what="admitted") == 4
+    assert all(np.abs(g).sum() > 0 for g in kept[1:])
+    for a, b in zip(kept, none):
+        np.testing.assert_array_equal(a, b)

@@ -177,6 +177,76 @@ def test_a_region_keeps_the_flash_results_and_runs_the_forward_once(chip):
     assert bare_temporaries < temporaries <= bare_temporaries + named + 2**20
 
 
+def test_a_region_keeps_its_mul_results_and_runs_each_product_once(chip):
+    """Two layers at Phi-4-mini-flash's MLP widths (one sequence of
+    8,192, 2,560 -> 10,240 -> 2,560, SiLU-gated, bf16 results of float32
+    sums as `mul` makes them under AMP), each a recompute region whose
+    gate and up products carry the name a region's plan gives an
+    admitted `mul` result (ISSUE 48): the step compiled for the v5e
+    makes each [8192, 10240] product ONCE, in the forward, and none
+    under `rematted_computation`, where with no name it makes each
+    again before the backward; what it holds more, by the compiler's
+    own count of temporaries, is at most the named values, two
+    [8192, 10240] bf16 a layer, and 1 MiB; and wherever the
+    `reduce_precision` that JAX puts on a kept value is left in the
+    text, it is the ROOT of the fusion that holds the product's
+    `convolution`, not a pass of its own over 168 MB."""
+    import re
+    from jax.ad_checkpoint import checkpoint_name
+    from paddle_tpu.ops import control_flow as CF
+    layers, t, d, ffn = 2, 8192, 2560, 10240
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    x = aval(t, d)
+    ws = [(aval(d, ffn), aval(d, ffn), aval(ffn, d))] * layers
+
+    def mul(x, w):
+        return jnp.matmul(x, w, preferred_element_type=jnp.float32
+                          ).astype(jnp.bfloat16)
+
+    def compiled(name):
+        def layer(x, wg, wu, wd):
+            gate, up = name(mul(x, wg)), name(mul(x, wu))
+            return x + mul(jax.nn.silu(gate) * up, wd)
+
+        def loss(x, ws):
+            for w in ws:
+                x = jax.checkpoint(layer, policy=CF._region_policy)(x, *w)
+            return x.astype(jnp.float32).sum()
+
+        step = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, ws).compile()
+        return step.as_text(), step.memory_analysis().temp_size_in_bytes
+
+    def products(text):
+        """(in the forward, recomputed) [t, ffn] results of a
+        `convolution` read straight from x (a backward one reads dy)."""
+        names = re.findall(
+            r"= \w+\[%d,%d\]\S* convolution\(.*op_name=\"([^\"]+)\""
+            % (t, ffn), text)
+        again = [n for n in names if "rematted_computation" in n]
+        forward = [n for n in names if "transpose(" not in n]
+        return len(forward) - len(again), len(again)
+
+    text, temporaries = compiled(lambda v: checkpoint_name(v, CF.MUL_OUT))
+    bare_text, bare_temporaries = compiled(lambda v: v)
+    assert products(text) == (2 * layers, 0)
+    # (the last layer's second forward is its first over again, and XLA
+    # makes the two one)
+    assert products(bare_text)[1] >= 2 * (layers - 1)
+    named = layers * 2 * t * ffn * 2
+    assert bare_temporaries < temporaries <= bare_temporaries + named + 2**20
+    computations = re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \()", text)
+    rounded = [c for c in computations if re.search(
+        r"ROOT \S+ = bf16\[%d,%d\]\S* reduce-precision\(" % (t, ffn), c)]
+    # (the first layer's two: the last layer's backward follows its
+    # forward at once, and XLA drops the op there)
+    assert rounded and len(rounded) == text.count(" reduce-precision(")
+    assert all(" convolution(" in c for c in rounded)
+    assert "reduce-precision" not in bare_text
+
+
 def test_flash_bthd_lowers_under_shard_map_dp2_tp2(topo, monkeypatch):
     """ParallelExecutor's dp2 x tp2 form of the op: batch over dp, the
     heads (a slice of the last dimension) over tp, eight heads a
