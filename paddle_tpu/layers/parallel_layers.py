@@ -96,7 +96,7 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
                    d_inner, norm_topk=True, name=None, score_func="softmax",
                    routed_scaling_factor=1.0, bias_update_rate=None,
                    shared_expert=False, router_std=0.02, router_input=None,
-                   activation="silu"):
+                   activation="silu", norm_topk_eps=0.0):
     """One chip's share of a mixture of gated experts over
     ``[B, T, D]`` input, dropless (``parallel/moe.routed_experts``): a
     float32 router over all `num_experts`, the `top_k` largest a row
@@ -113,7 +113,8 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     are named ``<name>.router``, ``.w_gate``, ``.w_up``, ``.w_down``.
 
     `score_func` "sigmoid" scores each expert by itself, and the chosen
-    weights are multiplied by `routed_scaling_factor`. A
+    weights are multiplied by `routed_scaling_factor`; `norm_topk_eps`
+    is added to their sum before `norm_topk` divides by it. A
     `bias_update_rate` u (not None) routes without an auxiliary loss: a
     persistable ``<name>.bias`` [num_experts], zero at first and never
     differentiated, is added to the scores for the CHOICE alone, and
@@ -157,6 +158,8 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
         inputs["RouterX"] = [router_input]
     if activation != "silu":
         attrs["activation"] = str(activation)
+    if norm_topk_eps:
+        attrs["norm_topk_eps"] = float(norm_topk_eps)
     if activation == "relu":
         gate_on = create_global_var([2], 0.0, "float32", persistable=True,
                                     name=helper.name + ".gate_on")

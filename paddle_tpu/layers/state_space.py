@@ -1,7 +1,8 @@
 """Layers of a hybrid state-space / attention decoder (ISSUE 40): the
 selective scan and the ops round it (``ops/selective_scan.py``),
-differential attention (``ops/diff_attention.py``) and a head tied to
-the embedding. Each is one Program op under its own type, so that a
+differential attention (``ops/diff_attention.py``), a head tied to
+the embedding, and the gated short convolution of a convolution-only
+mixer (ISSUE 49, ``ops/short_conv.py``). Each is one Program op under its own type, so that a
 device trace gives each its scope."""
 
 import math
@@ -14,7 +15,7 @@ from ..param_attr import ParamAttr
 from .layer_helper import LayerHelper
 
 __all__ = ["ssm_conv", "ssm_dt", "selective_scan", "ssm_gate", "gmu_gate",
-           "diff_attention", "diff_attn", "tied_head"]
+           "diff_attention", "diff_attn", "tied_head", "gated_short_conv"]
 
 
 def _param(helper, name, shape, initializer):
@@ -41,6 +42,23 @@ def ssm_conv(x, width=4, name=None):
     out = _same(helper, x)
     helper.append_op(type="ssm_conv",
                      inputs={"X": [x], "Filter": [w], "Bias": [b]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def gated_short_conv(x, width=3, name=None):
+    """``C * conv(B * X)`` of x [B, T, 3C], the parts B, C and X of one
+    projection side by side (``ops/short_conv.py``): a causal depthwise
+    convolution of `width` taps over time between two gates, no bias
+    and no activation. Parameter ``<name>_w`` [width, C], U(-width^-0.5,
+    width^-0.5) (a depthwise Conv1d's default). Returns [B, T, C]."""
+    helper = LayerHelper("gated_short_conv", name=name)
+    c, bound = int(x.shape[-1]) // 3, width ** -0.5
+    w = _param(helper, helper.name + "_w", [width, c],
+               UniformInitializer(-bound, bound))
+    out = _same(helper, x, tuple(x.shape[:-1]) + (c,))
+    helper.append_op(type="gated_short_conv",
+                     inputs={"X": [x], "Filter": [w]},
                      outputs={"Out": [out]})
     return out
 

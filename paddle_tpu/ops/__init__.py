@@ -17,6 +17,7 @@ from . import (  # noqa: F401
     latent_attention,
     rnn_ops,
     selective_scan,
+    short_conv,
     loss,
     math,
     metrics_ops,

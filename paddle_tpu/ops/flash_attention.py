@@ -50,7 +50,15 @@ kernel runs (_each_head) and are separated without moving lanes: head
 a's q (k and v in dk/dv) is the block with the other heads' lanes
 zeroed, so s_a contracts all W lanes and the foreign ones add exact
 zeros; every product that comes out W lanes wide keeps head a's D by
-a lane select where it is stored (_only, _put). A [T, 64] operand
+a lane select where it is stored (_only, _put). Under grouped
+key/value heads a block of ONE head reads its group's key/value head
+through the index map (_specs); where a block holds g > 1 heads and g
+divides the group (two heads of 64 in groups of four: ISSUE 49), all
+of its heads read the same key/value head, and _attend hands the
+kernels k and v with each key/value head under each of its query heads'
+lanes, [B, T, H*D], made before the custom_vjp so that autodiff folds
+dk and dv back over the group: the kernels run as without groups. A
+group that g does not divide is dense math. A [T, 64] operand
 padded to 128 lanes in VMEM before, so two heads take the bytes one
 took, and a contraction or an output of 64 half fills the MXU: the
 passes are those of one head at a time. T must be a multiple of the block size
@@ -1175,6 +1183,17 @@ def _group_sum(dkv, group, d, dtype):
          for a in range(0, len(heads), group)], -1).astype(dtype)
 
 
+def _under_query_heads(kv, d, group):
+    """k or v [B, T, Hkv*D] with each head `group` times side by side,
+    [B, T, Hkv*group*D]: head h of the result is head h // group of kv.
+    As lane slices put side by side, which XLA writes in one pass
+    (_group_sum has the reason: a [.., Hkv, group, D] view is a copy
+    into another tiling first)."""
+    return jnp.concatenate(
+        [kv[..., a * d:(a + 1) * d] for a in range(kv.shape[-1] // d)
+         for _ in range(group)], -1)
+
+
 def _backward_for(q, n_head, mask, block_q, block_k):
     """_backward_of for the operand q [B, T, H*D] of a one-part call."""
     t, hd = q.shape[1:]
@@ -1706,7 +1725,9 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
     [B, H, T]) `with_lse`. `entry` labels the count: the layout the
     caller came in. k and v may hold `n_kv_head` < H heads, [B, T,
     Hkv*D]: query head h reads head h // (H / Hkv), and the kernels
-    take that where a block is one head (D a multiple of 128). q2
+    take that where a block is one head (D a multiple of 128) and,
+    with k and v spread under the query heads' lanes, where the heads
+    of a block divide a group (two heads of 64 in groups of 4). q2
     [B, T, H*D2] and k2 [B, T, D2] add q2_h k2^T to head h's scores
     (the kernels: one head to a block, no groups, D2 a multiple of 128
     or dividing it in as many heads as divide H); `scale` then defaults
@@ -1744,11 +1765,14 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
         jax.ShapeDtypeStruct((b, n_head, rows, d), q.dtype), scale, block_q,
         block_k, force)
     g = heads_per_block(n_head, d)
-    # what the kernels cannot take goes the dense way whoever asked: a
-    # group of query heads shares a block of k only where a block is one
-    # head, and a mask's block must divide every tile's edge
+    group = n_head // n_kv_head
+    # what the kernels cannot take goes the dense way whoever asked: the
+    # g > 1 heads of a block read ONE key/value head only where g
+    # divides the group (`spread`, below), and a mask's block must
+    # divide every tile's edge
+    spread = group > 1 and g > 1 and group % g == 0
     edge = _tile(_backward_blocks(rows, g * d, bq, bk)[0], _TILE)
-    if (n_kv_head != n_head and g > 1) or (
+    if (group > 1 and g > 1 and not spread) or (
             mask and any(x % (1 << mask[0]) for x in (bq, bk, edge))):
         path = "dense"
     # the own-block form's halves are walked in equal blocks, and it has
@@ -1768,7 +1792,7 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
                 else _backward_for(q, n_head, mask, bq, bk))
     _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
                    backward=backward, mask=mask_label,
-                   kv_groups=str(n_head // n_kv_head),
+                   kv_groups=str(group),
                    key_width=str(d + d2),
                    value_width=str(v.shape[-1] // n_kv_head),
                    second_part="shared" if d2 else "none",
@@ -1795,6 +1819,14 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
         # folds dk2's lane groups back into one
         return _flash2(q, k, v, q2, jnp.tile(k2, (1, 1, g2)), n_head, mask,
                        scale, bq, bk, path == "interpret")
+    if spread:
+        # several heads to a block, all of one group: each key/value
+        # head under each of its query heads' lanes, [B, T, H*D], made
+        # here, outside the custom_vjp, so that autodiff folds dk and dv
+        # back over the group; the kernels then run as without groups
+        k, v = _under_query_heads(k, d, group), _under_query_heads(
+            v, d, group)
+        n_kv_head = n_head
     return (_flash_lse if with_lse else _flash)(
         q, k, v, n_head, n_kv_head, mask, scale, bq, bk,
         path == "interpret")

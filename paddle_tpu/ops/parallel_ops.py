@@ -150,7 +150,9 @@ def _routed_experts(ctx, op):
     bias, and a train run writes BiasOut = `moe.bias_step` of the
     step's own counts at `bias_update_rate` and StepsOut = Steps + 1.
     With a RouterX [B, T, D] the router reads it and not X; attr
-    activation ("silu" / "relu") is the experts' gate. With a GateOn [2]
+    activation ("silu" / "relu") is the experts' gate; attr norm_topk_eps
+    (0 where the layer sets none) is added to the chosen weights' sum
+    before they are divided by it. With a GateOn [2]
     float32 (persistable) a train run writes GateOnOut = GateOn + (the
     hidden units the gate left on, the hidden units there were) over the
     step's pairs on held experts. Steps [1] int32, where the layer keeps
@@ -176,7 +178,8 @@ def _routed_experts(ctx, op):
         shared_expert=bool(op.attr("shared_expert", False)),
         router_x=ctx.in1(op, "RouterX").reshape(-1, shape[-1])
         if op.input("RouterX") else None,
-        activation=op.attr("activation", "silu"), count_gate=count_gate)
+        activation=op.attr("activation", "silu"), count_gate=count_gate,
+        norm_eps=float(op.attr("norm_topk_eps", 0.0)))
     ctx.set_out(op, "Out", out.reshape(shape))
     ctx.set_out(op, "AuxLoss", aux)
     ctx.set_out(op, "Indices", experts.reshape(shape[:-1] + (k,)))

@@ -68,6 +68,7 @@ from jax import lax
 from ..core.registry import register
 from ..monitor import metrics as _metrics
 from .flash_attention import _on_tpu
+from .short_conv import causal_taps
 
 _LANES = 128
 _CHUNK = 128        # steps a grid step walks
@@ -394,14 +395,11 @@ def selective_scan(s, dt, a, b, c, d, chunk=None, group=None, force=None):
 def causal_conv_silu(x, w, bias):
     """``silu(bias + sum_i w[i] * x_{t - K + 1 + i})`` over time, each
     channel by itself, zeros before the sequence: x [B, T, C], w [K, C]
-    (K 4), bias [C]. K shifted slices added up, float32 inside."""
+    (K 4), bias [C]. K shifted slices added up (``short_conv.causal_taps``),
+    float32 inside."""
     f32 = jnp.float32
-    k, t = w.shape[0], x.shape[1]
-    x32 = jnp.pad(x.astype(f32), [(0, 0), (k - 1, 0), (0, 0)])
-    out = bias.astype(f32)
-    for i in range(k):
-        out = out + w[i].astype(f32) * x32[:, i:i + t]
-    return jax.nn.silu(out).astype(x.dtype)
+    return jax.nn.silu(causal_taps(x.astype(f32), w, bias.astype(f32))
+                       ).astype(x.dtype)
 
 
 @register("ssm_conv")

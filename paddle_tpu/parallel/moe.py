@@ -222,11 +222,12 @@ _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
-          scaling=1.0):
+          scaling=1.0, norm_eps=0.0):
     """(scores [N, E], weights [N, k], experts [N, k]): the float32
     router. `score` over ALL experts ("softmax", or "sigmoid": each
     expert's own), the k largest, their weights divided by their sum
-    where `norm_topk`, times `scaling`. A selection `bias` [E] is added
+    (plus `norm_eps` where a model adds one) where `norm_topk`, times
+    `scaling`. A selection `bias` [E] is added
     for the CHOICE alone (the k largest of score + bias, no gradient):
     the weights are the unbiased scores at the chosen."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -239,7 +240,10 @@ def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
         _, top_i = lax.top_k(lax.stop_gradient(probs + bias), top_k)
         top_p = jnp.take_along_axis(probs, top_i, axis=1)
     if norm_topk:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        if norm_eps:        # (else lowered as it was before the epsilon)
+            total = total + norm_eps
+        top_p = top_p / total
     if scaling != 1.0:
         top_p = top_p * scaling
     return probs, top_p, top_i
@@ -429,7 +433,8 @@ _held_experts.defvjp(_held_fwd, _held_bwd)
 def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
                    first_expert=0, top_k=8, norm_topk=True, score="softmax",
                    bias=None, scaling=1.0, shared_expert=False, force=None,
-                   router_x=None, activation="silu", count_gate=False):
+                   router_x=None, activation="silu", count_gate=False,
+                   norm_eps=0.0):
     """One chip's share of a mixture of gated experts, dropless.
 
     x [N, d]; router_w [d, E] over ALL `num_experts`; w_gate, w_up
@@ -455,7 +460,7 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     the experts' x); the experts compute
     in their weights' dtype (bfloat16 under AMP), accumulating in
     float32. Every held pair is computed, also when all rows choose
-    held experts. `score`, `bias` and `scaling` are `route`'s;
+    held experts. `score`, `bias`, `scaling` and `norm_eps` are `route`'s;
     `shared_expert` says that the caller runs a shared expert beside
     this layer (the counter's label: nothing here computes it, and a
     chip's share of the layer holds it once). A chunk's rows go back to
@@ -477,6 +482,8 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     # arguments: its callers' stand-ins (tests) have that signature
     how = {} if (score, bias, scaling) == ("softmax", None, 1.0) else {
         "score": score, "bias": bias, "scaling": scaling}
+    if norm_eps:
+        how["norm_eps"] = norm_eps
     # the scope holds what reads the router's input alone, the matmul,
     # the scores, the top-k and the sort: a trace tells it from the rest
     with jax.named_scope("route"):

@@ -12,26 +12,30 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops import flash_attention as FA
 from flash_test import (_assert_close, _bthd_inputs, _dense_lse, _f32,
-                        _grads_of, _host32, _kernels_and_grads,
+                        _gqa_inputs, _grads_of, _host32, _kernels_and_grads,
                         _pallas_names)
 
 
 # -- one backward kernel where a block holds all of T (PR 31) -----------------
 
-@pytest.mark.parametrize("h, d, g", [
-    pytest.param(4, 64, 2, id="H4-D64-g2"),
-    pytest.param(2, 128, 1, id="H2-D128-g1"),
-    pytest.param(3, 64, 3, id="H3-D64-all_of_H")])
+@pytest.mark.parametrize("h, hkv, d, g", [
+    pytest.param(4, 4, 64, 2, id="H4-D64-g2"),
+    pytest.param(2, 2, 128, 1, id="H2-D128-g1"),
+    pytest.param(3, 3, 64, 3, id="H3-D64-all_of_H"),
+    pytest.param(8, 2, 64, 2, id="H8-Hkv2-D64-g2-groups_of_4")])
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("with_dlse", [False, True], ids=["out", "out_lse"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_fused_backward_matches_dense(monkeypatch, dtype, with_dlse, causal,
-                                      h, d, g):
+                                      h, hkv, d, g):
     """dq, dk, dv of the ONE backward kernel (all of T 256 in a block,
     two panels of 128 keys, the fewest that have dq accumulated across
     them in scratch) against dense float32 math, with and without an
-    lse cotangent folded into the delta the kernel makes and keeps."""
+    lse cotangent folded into the delta the kernel makes and keeps; two
+    heads of 64 to a block under groups of 4 (ISSUE 49: k and v spread
+    under the query heads' lanes, dk and dv folded back over a group
+    in k's own shape)."""
     t = 256
     monkeypatch.setattr(FA, "_TILE", 128)
     monkeypatch.setattr(FA, "_PANEL_SCORES", 128 * t)
@@ -39,7 +43,8 @@ def test_fused_backward_matches_dense(monkeypatch, dtype, with_dlse, causal,
     assert t // FA._tile(t, FA._TILE) == 2
     assert FA._backward_of(t, g * d, t, t,
                            itemsize=jnp.dtype(dtype).itemsize) == "fused"
-    q, k, v, dy, dlse = _bthd_inputs(h, d, dtype, t=t, seed=8)
+    q, k, v, dy, dlse = _bthd_inputs(h, d, dtype, t=t, seed=8) \
+        if hkv == h else _gqa_inputs(h, hkv, d, t, dtype, seed=8)
     scale = d ** -0.5
 
     def loss(att):
@@ -50,22 +55,21 @@ def test_fused_backward_matches_dense(monkeypatch, dtype, with_dlse, causal,
         return f
 
     def ref(q, k, v):
-        o, lse = _dense_lse(*(FA.heads_first(x, h) for x in (q, k, v)),
-                            causal, scale)
+        o, lse = _dense_lse(FA.heads_first(q, h), FA.heads_first(k, hkv),
+                            FA.heads_first(v, hkv), causal, scale)
         return FA.heads_last(o), lse
 
     def got(q, k, v):
+        kw = dict(causal=causal, force="interpret", n_kv_head=hkv)
         if with_dlse:
-            return FA.flash_bthd_lse(q, k, v, h, causal=causal,
-                                     force="interpret")
-        return FA.flash_bthd(q, k, v, h, causal=causal,
-                             force="interpret"), None
+            return FA.flash_bthd_lse(q, k, v, h, **kw)
+        return FA.flash_bthd(q, k, v, h, **kw), None
 
     names, grads = _kernels_and_grads(loss(got), q, k, v)
     assert names == ["flash_fwd", "flash_bwd"]
     g_ref = _grads_of(loss(ref), *_host32(q, k, v))
-    for name, a, b in zip(("dq", "dk", "dv"), grads, g_ref):
-        assert a.shape == q.shape and a.dtype == dtype
+    for name, a, b, x in zip(("dq", "dk", "dv"), grads, g_ref, (q, k, v)):
+        assert a.shape == x.shape and a.dtype == dtype
         _assert_close(name, a, b, 5e-3 if dtype == jnp.float32 else 2e-2)
 
 

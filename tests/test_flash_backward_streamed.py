@@ -101,6 +101,8 @@ _STREAMED = [
                  id="causal-two_heads_of_64_to_a_block"),
     pytest.param(3, 3, 64, 512, 256, 256, dict(causal=True),
                  id="causal-all_of_H_192_lanes"),
+    pytest.param(8, 2, 64, 256, 64, 64, dict(causal=True),
+                 id="causal-two_heads_of_64_to_a_block-groups_of_4"),
     pytest.param(2, 1, 128, 768, 256, 128, dict(causal=True),
                  id="causal-nq3_nk6"),
     pytest.param(2, 1, 128, 768, 128, 384, dict(causal=True),
@@ -166,7 +168,8 @@ def test_streamed_backward_matches_dense(monkeypatch, dtype, backward, h,
     scratch across the key blocks, delta made at a q block's first
     visit) and, the byte bound set to nothing, through the two kernels
     it replaced: every mask form, grouped heads 8:1, two heads to a
-    block and all of H, unequal counts of q and key blocks, and an lse
+    block (alone and, ISSUE 49, in groups of 4 reading one key/value
+    head) and all of H, unequal counts of q and key blocks, and an lse
     cotangent where the form gives an lse."""
     monkeypatch.setattr(FA, "_TILE", 128)
     if backward == "two_kernels":

@@ -262,6 +262,51 @@ def test_bthd_entry_matches_dense(dtype, block, causal, h, d, g):
         _assert_close(name, a, b, tol_g)
 
 
+@pytest.mark.parametrize("h, hkv, d, g, path", [
+    pytest.param(8, 2, 64, 2, "interpret", id="H8-Hkv2-D64-g2-groups_of_4"),
+    pytest.param(8, 4, 64, 2, "interpret", id="H8-Hkv4-D64-g2-groups_of_2"),
+    pytest.param(4, 1, 32, 4, "interpret", id="H4-Hkv1-D32-g4-one_group"),
+    pytest.param(6, 2, 64, 2, "dense", id="H6-Hkv2-D64-g2-groups_of_3")])
+@pytest.mark.parametrize("block", [None, 128], ids=["one_block", "streamed"])
+def test_grouped_heads_several_to_a_block_take_the_kernels(block, h, hkv, d,
+                                                           g, path):
+    """ISSUE 49: where the g > 1 heads of a block divide a group of
+    query heads, all of them read ONE key/value head, and the kernels
+    run on k and v spread under the query heads' lanes: out, lse, dq,
+    and dk, dv in k's own shape against dense float32 math, and the
+    lowering's labels (`path`, `heads_per_block`, `kv_groups`). A
+    group that g does not divide is still dense math, and says so."""
+    assert FA.heads_per_block(h, d) == g
+    q, k, v, dy, dlse = _gqa_inputs(h, hkv, d, 256, jnp.float32)
+    kw = dict(causal=True, force="interpret", n_kv_head=hkv, block_q=block,
+              block_k=block)
+    labels = dict(path=path, entry="bthd", heads_per_block=str(g),
+                  backward="none" if path == "dense" else
+                  "fused" if block is None else "fused_streamed",
+                  mask="causal", kv_groups=str(h // hkv), key_width=str(d),
+                  value_width=str(d), second_part="none", window="0")
+    was = FA._LOWERINGS.value(**labels)
+
+    def weigh(outs):
+        o, lse = outs
+        return (o * dy).sum() + (lse * dlse).sum()
+
+    def ref(q, k, v):
+        o, lse = _dense_lse(FA.heads_first(q, h), FA.heads_first(k, hkv),
+                            FA.heads_first(v, hkv), True, d ** -0.5)
+        return FA.heads_last(o), lse
+
+    (o_ref, lse_ref), g_ref = jax.jit(_with_grads(ref, weigh))(q, k, v)
+    (o, lse), grads = jax.jit(_with_grads(
+        lambda q, k, v: FA.flash_bthd_lse(q, k, v, h, **kw), weigh))(q, k, v)
+    assert FA._LOWERINGS.value(**labels) == was + 1
+    _assert_close("out", o, o_ref, 2e-3)
+    _assert_close("lse", lse, lse_ref, 2e-3)
+    for name, a, b, x in zip(("dq", "dk", "dv"), grads, g_ref, (q, k, v)):
+        assert a.shape == x.shape and a.dtype == x.dtype
+        _assert_close(name, a, b, 5e-3)
+
+
 @pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out_lse"])
 def test_bhtd_wrappers_equal_the_bthd_entry_bit_for_bit(with_lse):
     """flash_attention / flash_attention_lse on [B, H, T, D] are the new
@@ -467,20 +512,22 @@ def test_lowering_counter_says_which_path_engaged():
 
 
 def test_what_the_kernels_cannot_take_goes_dense():
-    """A group of query heads shares a block of k only where a block is
-    one head, and a mask's block must divide the tiles: anything else is
-    dense math, also when a caller forces the kernel; the counter's
-    `mask` and `kv_groups` labels say what was asked."""
+    """A group of query heads shares a block of k where a block is one
+    head or its heads divide the group (ISSUE 49), and a mask's block
+    must divide the tiles: anything else is dense math, also when a
+    caller forces the kernel; the counter's `mask` and `kv_groups`
+    labels say what was asked. Two heads of 64 to a block in groups of
+    THREE: a block would read two key/value heads."""
     count = FA._LOWERINGS
-    q, k, v, _, _ = _gqa_inputs(4, 2, 64, 256, jnp.float32)
+    q, k, v, _, _ = _gqa_inputs(6, 2, 64, 256, jnp.float32)
     labels = dict(path="dense", entry="bthd", heads_per_block="2",
-                  backward="none", mask="block_causal_strict", kv_groups="2",
+                  backward="none", mask="block_causal_strict", kv_groups="3",
                   key_width="64", value_width="64", second_part="none", window="0")
     was = count.value(**labels)
-    o = FA.flash_bthd(q, k, v, 4, causal=True, force="interpret",
+    o = FA.flash_bthd(q, k, v, 6, causal=True, force="interpret",
                       n_kv_head=2, mask_block=4, strict=True)
     assert count.value(**labels) == was + 1
-    o_ref, _, seen = _dense_block_causal(q, k, v, 4, 2, 4, True)
+    o_ref, _, seen = _dense_block_causal(q, k, v, 6, 2, 4, True)
     _assert_close("out", jnp.where(seen[None, :, None], o, 0), o_ref, 1e-5)
     q, k, v, _, _ = _gqa_inputs(4, 2, 128, 256, jnp.float32)
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",

@@ -158,8 +158,8 @@ def test_own_blocks_first_noised_rows_see_themselves_alone():
 def test_own_block_form_counts_itself_and_goes_dense_where_it_must():
     """The counter's `mask` label reads `block_causal_own`, the backward
     `fused_streamed` also where a half is one block; what the kernels
-    cannot take (unequal blocks, two heads of 64 under grouped keys) is
-    the dense mask; `strict`, a full mask or an odd count of rows with
+    cannot take (unequal blocks, two heads of 64 to a block in groups of
+    three) is the dense mask; `strict`, a full mask or an odd count of rows with
     the form is a ValueError."""
     count = FA._LOWERINGS
     q, k, v, _, _ = _gqa_inputs(4, 2, 128, 512, jnp.float32)
@@ -179,11 +179,14 @@ def test_own_block_form_counts_itself_and_goes_dense_where_it_must():
     want = _dense_block_causal(q, k, v, 4, 2, 4, False, own=True)[0]
     _assert_close("kernels", o, want, 1e-5)
     _assert_close("dense", o_dense, want, 1e-5)
-    q, k, v, _, _ = _gqa_inputs(4, 2, 64, 512, jnp.float32)
-    labels.update(heads_per_block="2", key_width="64", value_width="64")
+    # (groups of 3: two heads of 64 to a block do not divide one)
+    q, k, v, _, _ = _gqa_inputs(6, 2, 64, 512, jnp.float32)
+    labels.update(heads_per_block="2", key_width="64", value_width="64",
+                  kv_groups="3")
     was = count.value(**labels)
-    FA.flash_bthd(q, k, v, 4, **kw)
+    FA.flash_bthd(q, k, v, 6, **kw)
     assert count.value(**labels) == was + 1
+    q, k, v, _, _ = _gqa_inputs(4, 2, 64, 512, jnp.float32)
     for bad in (dict(strict=True), dict(causal=False)):
         with pytest.raises(ValueError):
             FA.flash_bthd(q, k, v, 4, **dict(kw, **bad))

@@ -889,6 +889,7 @@ COVERED_ELSEWHERE = {
     "ssm_conv": "test_selective_scan.py",
     "ssm_dt": "test_selective_scan.py",
     "ssm_gate": "test_selective_scan.py",
+    "gated_short_conv": "test_short_conv.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",

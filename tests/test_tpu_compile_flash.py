@@ -80,6 +80,37 @@ def test_flash_bthd_compiles_for_v5e(chip, monkeypatch, b, t, h, d, dtype,
         assert "%" + name + "." in text or "%" + name + " " in text
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_grouped_heads_of_64_compile_for_v5e_at_32768_rows(chip, direction):
+    """32 query heads of 64 reading 8, one sequence of 32,768 (ISSUE
+    49): two heads to a block, both of one group, k and v spread under
+    the query heads' lanes before the kernels. One forward kernel; the
+    gradient adds ONE `flash_bwd`, dq for all 32,768 rows of a block of
+    two heads resident in VMEM (16 MB float32, the byte bound's edge),
+    and gives dk and dv in k's own shape."""
+    t, h, hkv, d = 32768, 32, 8, 64
+    q = jax.ShapeDtypeStruct((1, t, h * d), jnp.bfloat16, sharding=chip)
+    k = jax.ShapeDtypeStruct((1, t, hkv * d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_bthd(q, k, v, h, causal=True, force="pallas",
+                          n_kv_head=hkv)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    step = jax.jit(fn).lower(q, k, k).compile()
+    text = step.as_text()
+    names = ["flash_fwd"] + (["flash_bwd"] if direction == "bwd" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    if direction == "bwd":
+        assert [o.shape for o in jax.tree.leaves(step.out_info)] == [
+            (1, t, h * d), (1, t, hkv * d), (1, t, hkv * d)]
+
+
 @pytest.mark.parametrize("t, asks", [(2048, False), (4096, True)],
                          ids=["one_block", "streamed"])
 def test_only_the_streamed_backward_asks_for_scoped_vmem(chip, t, asks):
