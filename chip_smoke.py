@@ -50,9 +50,12 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           and backward device ms at T 8192
   rotary  QK-norm and RoPE in the projections' own layout (the kernel
           pair of ops/rotary.py) at the block-diffusion cell's shapes,
-          q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128]: output, dx and
-          dScale against float32 math, and a call's time forward and
-          backward beside the HBM floor
+          q [2, 8192, 32 x 128] and k [2, 8192, 4 x 128], and at
+          lfm2_train_T32k's, [1, 32768, 32 x 64] and [.., 8 x 64], two
+          heads to a lane tile (ISSUE 50): output, dx and dScale
+          against float32 math, and a call's device ms forward and
+          backward beside the HBM floor; heads of 64 beside the
+          jax.numpy form they left
   experts the dropless expert layer at both routed cells' shapes
           (16,384 rows of 2048 over 16 held of 128 experts, softmax
           top-8; 4,096 rows of 3584 over 8 held of 64, sigmoid top-4):
@@ -790,68 +793,58 @@ def phase_diff(seed, rehearse):
 
 
 def phase_rotary(seed, rehearse):
-    """The kernel pair that norms and turns q and k in the block-
-    diffusion step (ISSUE 33), at the cell's shapes, wrap L: output, dx
-    and dScale against the same math in float32 on the heads' view, and
-    the time of a call, forward and backward, beside the bytes it must
-    move at the HBM peak (x in and out forward; dy and x in, dx out
-    backward; q and k together 0.37 / 0.55 ms)."""
+    """The kernel pair that norms and turns q and k, at the block-
+    diffusion cell's shapes (ISSUE 33: heads of 128, wrap L) and at
+    `lfm2_train_T32k`'s (ISSUE 50: 32 and 8 heads of 64, two to a lane
+    tile, T 32,768): output, dx and dScale against the same math in
+    float32 on the heads' view, and a call's device ms under the
+    profiler, forward and backward, beside the bytes it must move at
+    the HBM peak (x in and out forward; dy and x in, dx out backward).
+    Heads of 64 time the jax.numpy form too, the one they left."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import rotary
-    b, t, d, calls = (2, 64, 128, 2) if rehearse else (2, 8192, 128, 16)
-    rng = np.random.RandomState(seed)
     f32 = lambda x: x.astype(jnp.float32)
-    total = [0.0, 0.0, 0.0, 0.0]
+    rng = np.random.RandomState(seed)
 
     def both(turn, x, w, dy):
         out, vjp = jax.vjp(turn, x, w)
         return (out,) + vjp(dy)
 
-    for n_head in (4, 2) if rehearse else (32, 4):
+    shapes = ((2, 64, 4, 128, 32), (2, 64, 2, 128, 32), (1, 128, 4, 64, 0),
+              (1, 128, 2, 64, 0)) if rehearse else (
+        (2, 8192, 32, 128, 4096), (2, 8192, 4, 128, 4096),
+        (1, 32768, 32, 64, 0), (1, 32768, 8, 64, 0))
+    for b, t, n_head, d, wrap in shapes:
         x, dy = (jnp.asarray(rng.randn(b, t, n_head * d) * 0.5, jnp.bfloat16)
                  for _ in range(2))
         w = jnp.asarray(1.0 + 0.1 * rng.randn(d), jnp.float32)
         turn = lambda x, w, force=None: rotary.norm_rope(
-            x, w, n_head, 1e6, t // 2, 1e-6, force=force)
-        step = jax.jit(functools.partial(both, turn)).lower(x, w, dy).compile()
-        got, text = step(x, w, dy), step.as_text()
+            x, w, n_head, 1e6, wrap, 1e-6, force=force)
         want = jax.jit(functools.partial(both, functools.partial(
             turn, force="xla")))(f32(x), w, f32(dy))
-        errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
-                for a, r in zip(got, want)]
-
-        # `calls` kernel calls in one executable, each fed the last one's
-        # result, so that the host's dispatch is paid once
-        def chain(x, w, dy):
-            for _ in range(calls):
-                x = turn(x, w)
-            pull = jax.vjp(turn, x, w)[1]
-            for _ in range(calls):
-                dy = pull(dy)[0]
-            return x, dy
-
-        forward = jax.jit(lambda x, w, dy: chain(x, w, dy)[0])
-        timed = []
-        for fn in (forward, jax.jit(chain)):
-            jax.block_until_ready(fn(x, w, dy))
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(x, w, dy))
-            timed.append((time.perf_counter() - t0) * 1e3 / calls)
         floor = [k * x.size * 2 / 819e9 * 1e3 for k in (2, 3)]
-        # forward, its floor, backward, its floor
-        ms = (timed[0], floor[0], timed[1] - timed[0], floor[1])
-        total = [a + b for a, b in zip(total, ms)]
-        log("[rotary] x [%d, %d, %d x %d] bf16, wrap %d: out %.3e dx %.3e "
-            "dScale %.3e from float32 math; a call %.3f ms forward (floor "
-            "%.3f), %.3f ms backward (floor %.3f)" % (
-                b, t, n_head, d, t // 2, *errs, *ms))
-        assert max(errs) <= FLASH_GRAD_TOL, errs
-        if not rehearse:
-            assert "qk_norm_rope_fwd" in text and "qk_norm_rope_bwd" in text
-    log("[rotary] q and k together: %.3f ms forward (floor %.3f), %.3f ms "
-        "backward (floor %.3f)%s" % (*total, "  (REHEARSAL: a CPU's time, "
-                                     "no device number)" if rehearse else ""))
+        for force in (None, "xla") if d < 128 else (None,):
+            forward = jax.jit(functools.partial(turn, force=force))
+            step = jax.jit(functools.partial(both, functools.partial(
+                turn, force=force))).lower(x, w, dy).compile()
+            errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+                    for a, r in zip(step(x, w, dy), want)]
+            ms = [_device_ms(fn, args, 8, rehearse, "rotary")[0]
+                  for fn, args in ((forward, (x, w)), (step, (x, w, dy)))]
+            log("[rotary] x [%d, %d, %d x %d] bf16, wrap %d, %s: out %.3e "
+                "dx %.3e dScale %.3e from float32 math; a call %.3f ms "
+                "forward (floor %.3f), %.3f ms backward (floor %.3f)%s" % (
+                    b, t, n_head, d, wrap,
+                    "the jax.numpy form" if force else "the kernels", *errs,
+                    ms[0], floor[0], ms[1] - ms[0], floor[1],
+                    "  (REHEARSAL: a CPU's time, no device number)"
+                    if rehearse else ""))
+            assert max(errs) <= FLASH_GRAD_TOL, errs
+            if not rehearse:
+                text = step.as_text()
+                assert ("qk_norm_rope_fwd" in text) == (force is None)
+                assert ("qk_norm_rope_bwd" in text) == (force is None)
 
 
 def phase_experts(seed, rehearse):

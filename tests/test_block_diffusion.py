@@ -99,32 +99,47 @@ def _out_and_grads(fn, x, w, dy):
 
 
 # L 136: 272 rows a sequence, which the 256 rows that 1 MB of float32
-# holds at 1024 lanes do not divide: a block is the period's divisor, 136
+# holds at 1024 lanes do not divide: a block is the period's divisor, 136.
+# Heads of 64 sit two to a lane tile in the same kernels (ISSUE 50)
 @pytest.mark.parametrize("wrap", [0, 136], ids=["by_index", "two_halves"])
-@pytest.mark.parametrize("n_head", [32, 4], ids=["q_heads", "kv_heads"])
-def test_norm_rope_kernel_is_the_reference(n_head, wrap):
-    """The kernel pair in interpret mode at D 128: the output and the
-    gradients of x and of Scale against the reference's `_rms` and
-    `_rope` and against the jax.numpy form; under `wrap` the gradient
-    passes through the shared positions: the clean half's rows turn as
-    the noised half's do."""
-    d, t = 128, 272
-    x = jnp.asarray(_r(2, t, n_head * d, seed=21))
+@pytest.mark.parametrize("n_head, d, dtype", [
+    (32, 128, "float32"), (4, 128, "float32"), (32, 64, "float32"),
+    (8, 64, "float32"), (2, 64, "float32"), (32, 64, "bfloat16"),
+    (8, 64, "bfloat16")],
+    ids=["q_heads", "kv_heads", "q_heads_of_64", "kv_heads_of_64",
+         "one_tile_of_64", "q_heads_of_64_bf16", "kv_heads_of_64_bf16"])
+def test_norm_rope_kernel_is_the_reference(n_head, d, dtype, wrap):
+    """The kernel pair in interpret mode: the output and the gradients
+    of x and of Scale against the reference's `_rms` and `_rope` (dense
+    float32 math on the heads' view) and against the jax.numpy form;
+    under `wrap` the gradient passes through the shared positions: the
+    clean half's rows turn as the noised half's do. bfloat16 rounds the
+    output and dx alone: float32 inside, Scale's gradient in Scale's
+    dtype."""
+    # bfloat16's sublane tiles hold 16 rows: twice the rows all through
+    rows_of = 2 if dtype == "bfloat16" else 1
+    t, wrap = 272 * rows_of, wrap * rows_of
+    x, dy = (jnp.asarray(_r(2, t, n_head * d, seed=seed)).astype(dtype)
+             for seed in (21, 23))
     w = jnp.asarray(1.0 + _r(d, seed=22, scale=0.1))
-    dy = jnp.asarray(_r(2, t, n_head * d, seed=23))
     rows, lanes = rotary._blocks(2 * t, rotary._period(t, wrap), n_head * d,
-                                 d, 4)
-    assert (rows, lanes) == (136 if wrap or n_head == 32 else 272,
-                             min(n_head * d, 1024))
+                                 d, x.dtype.itemsize)
+    # at most eight heads to a block, and 1024 lanes
+    assert lanes == min(n_head, 8) * d
+    assert rows == rows_of * (136 if wrap or lanes == 1024 else 272)
     run = lambda force: lambda x, w: rotary.norm_rope(
         x, w, n_head, 1e6, wrap, 1e-6, force=force)
+    f32 = lambda v: v.astype(jnp.float32)
     want, pulled = _out_and_grads(
-        lambda x, w: _reference_norm_rope(x, w, n_head, wrap), x, w, dy)
+        lambda x, w: _reference_norm_rope(x, w, n_head, wrap), f32(x), w,
+        f32(dy))
+    out_tol, dx_tol = (1e-6, 2e-6) if dtype == "float32" else (2 ** -7,) * 2
     for force in ("interpret", "xla"):
         got, grads = _out_and_grads(run(force), x, w, dy)
-        assert _largest(got, want) < 1e-6
-        for mine, ref in zip(grads, pulled):
-            assert _largest(mine, ref) < 2e-6
+        assert got.dtype == grads[0].dtype == x.dtype
+        assert _largest(got, want) < out_tol
+        assert _largest(grads[0], pulled[0]) < dx_tol
+        assert _largest(grads[1], pulled[1]) < 2e-6
     if wrap:
         twice = jnp.concatenate([x[:, :wrap]] * 2, 1)
         got, (dx, _) = _out_and_grads(
@@ -136,25 +151,32 @@ def test_norm_rope_kernel_is_the_reference(n_head, wrap):
 
 @pytest.mark.parametrize("norm, rotate", [(True, False), (False, True)],
                          ids=["norm_alone", "rope_alone"])
-def test_norm_rope_kernel_serves_each_op_alone(norm, rotate):
+@pytest.mark.parametrize("n_head, d, wrap", [
+    (4, 128, 16), (2, 64, 0), (8, 64, 16), (32, 64, 16)],
+    ids=["heads_of_128", "one_tile_of_64", "kv_heads_of_64",
+         "q_heads_of_64"])
+def test_norm_rope_kernel_serves_each_op_alone(n_head, d, wrap, norm, rotate):
     """One flag off: the grouped `rms_norm` and `rope` lower to the same
     kernel body; bfloat16 in, bfloat16 out, float32 inside."""
-    n_head, d, t = 4, 128, 32
+    t = 32
     x = jnp.asarray(_r(2, t, n_head * d, seed=24))
     w = jnp.asarray(1.0 + _r(d, seed=25, scale=0.1))
     dy = jnp.asarray(_r(2, t, n_head * d, seed=26))
-    args = (n_head, 1e6 if rotate else None, 16, 1e-6)
+    args = (n_head, 1e6 if rotate else None, wrap, 1e-6)
     run = lambda x, w: rotary.norm_rope(x, w if norm else None, *args,
                                         force="interpret")
     want, pulled = _out_and_grads(lambda x, w: _reference_norm_rope(
-        x, w, n_head, 16, norm, rotate), x, w, dy)
+        x, w, n_head, wrap, norm, rotate), x, w, dy)
     got, (dx, dw) = _out_and_grads(run, x, w, dy)
     assert _largest(got, want) < 1e-6
     assert _largest(dx, pulled[0]) < 2e-6
     assert _largest(dw, pulled[1]) < 2e-6 if norm else not dw.any()
-    half = run(x.astype(jnp.bfloat16), w)
-    assert half.dtype == jnp.bfloat16
+    half, (dx, dw) = _out_and_grads(run, x.astype(jnp.bfloat16), w,
+                                    dy.astype(jnp.bfloat16))
+    assert half.dtype == dx.dtype == jnp.bfloat16
     assert _largest(half.astype(jnp.float32), want) < 2 ** -7
+    assert _largest(dx.astype(jnp.float32), pulled[0]) < 2 ** -6
+    assert _largest(dw, pulled[1]) < 2 ** -7 if norm else not dw.any()
 
 
 def test_qk_norm_rope_op_is_rope_of_rms_norm():
@@ -172,17 +194,18 @@ def test_qk_norm_rope_op_is_rope_of_rms_norm():
     check_grad("qk_norm_rope", {"X": x, "Scale": w}, attrs, ["X", "Scale"])
 
 
-def test_rotary_lowering_counter_says_which_path_engaged():
+@pytest.mark.parametrize("d", [128, 64])
+def test_rotary_lowering_counter_says_which_path_engaged(d):
     """`ptpu_rotary_lowerings_total{path, heads, head_dim, norm,
     rotate}`: one count a lowering, whichever path: the kernel in
     interpret mode, the jax.numpy form off the chip (what the Program's
     ops take on the CPU, at any head size), and `pallas` never here."""
     count = rotary._LOWERINGS
-    x, w = jnp.asarray(_r(1, 16, 2 * 128, seed=29)), jnp.ones(128)
+    x, w = jnp.asarray(_r(1, 16, 2 * d, seed=29)), jnp.ones(d)
     for force, path, scale, theta in (
             ("interpret", "interpret", w, 1e6), (None, "xla", w, 1e6),
             (None, "xla", w, None), ("interpret", "interpret", None, 1e6)):
-        labels = dict(path=path, heads="2", head_dim="128",
+        labels = dict(path=path, heads="2", head_dim=str(d),
                       norm=str(scale is not None).lower(),
                       rotate=str(theta is not None).lower())
         was = count.value(**labels)
@@ -197,6 +220,33 @@ def test_rotary_lowering_counter_says_which_path_engaged():
     rendered = fluid.monitor.metrics.registry().render_prometheus()
     assert "ptpu_rotary_lowerings_total" in rendered
     assert 'ptpu_rotary_lowerings_total{path="pallas"' not in rendered
+
+
+def test_heads_that_are_not_whole_lane_tiles_keep_the_jax_numpy_form(
+        monkeypatch):
+    """An odd number of heads of 64 and a single one (MLA's rotary key)
+    are not whole lane tiles: the jax.numpy form under auto, counted as
+    such, nothing raised; the kernel forced there says why not. On a
+    TPU the shape alone says which path (ISSUE 50)."""
+    count = rotary._LOWERINGS
+    for heads in (3, 1):
+        labels = dict(path="xla", heads=str(heads), head_dim="64",
+                      norm="false", rotate="true")
+        was = count.value(**labels)
+        part = jnp.asarray(_r(1, 16, heads * 64, seed=30))
+        want = _reference_norm_rope(part, None, heads, 0, norm=False)
+        assert _largest(rotary.norm_rope(part, None, heads, 1e6), want) < 1e-6
+        assert count.value(**labels) == was + 1
+        with pytest.raises(ValueError):
+            rotary.norm_rope(part, None, heads, 1e6, force="interpret")
+    monkeypatch.setattr(rotary, "_on_tpu", lambda x: True)
+    for heads, d, path in (
+            (2, 128, "pallas"), (32, 64, "pallas"), (8, 64, "pallas"),
+            (3, 64, "xla"), (1, 64, "xla"), (2, 96, "xla"), (2, 192, "xla")):
+        rows, lanes = rotary._blocks(32, 32, heads * d, d, 2)
+        assert lanes % 128 == 0 or path == "xla"
+        assert rotary._resolve_path(jnp.zeros((1, 32, heads * d)), d, rows,
+                                    True, None) == path
 
 
 def test_silu_mul_op():

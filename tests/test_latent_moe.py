@@ -139,9 +139,26 @@ def test_what_the_two_part_kernels_cannot_take_goes_dense():
         FA.flash_bthd(q, k, v, 4, q2=q2, k2=k2[..., :16])
 
 
-def test_mla_attention_op_turns_both_rotary_parts():
+@pytest.mark.parametrize("on_a_tpu", [False, True],
+                         ids=["jax_numpy", "as_a_tpu_dispatches"])
+def test_mla_attention_op_turns_both_rotary_parts(on_a_tpu, monkeypatch):
     """The op against the reference's own pieces: YaRN's frequencies,
-    rotate-half on q_pe and on the one k_pe, the two-part score."""
+    rotate-half on q_pe and on the one k_pe, the two-part score. As a
+    TPU dispatches (ISSUE 50; the kernel in interpret mode where the
+    shape alone would take it): the query's part, heads of 64 two to a
+    lane tile, takes the kernel pair of ``ops/rotary.py``, and the ONE
+    key head of 64, half a tile, keeps the jax.numpy form."""
+    if on_a_tpu:
+        resolve = rotary._resolve_path
+        monkeypatch.setattr(rotary, "_on_tpu", lambda x: True)
+        monkeypatch.setattr(
+            rotary, "_resolve_path", lambda *args: resolve(*args).replace(
+                "pallas", "interpret"))
+    count = lambda path, heads: rotary._LOWERINGS.value(
+        path=path, heads=str(heads), head_dim="64", norm="false",
+        rotate="true")
+    paths = [("interpret" if on_a_tpu else "xla", 2), ("xla", 1)]
+    before = [count(*labels) for labels in paths]
     cfg = {"qk_rope_head_dim": 64, "rope_theta": 10000,
            "qk_nope_head_dim": 128,
            "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 64,
@@ -164,6 +181,7 @@ def test_mla_attention_op_turns_both_rotary_parts():
     want = _dense_two_parts(q, k, v, turned_q.reshape(1, t, h * 64),
                             turned_k[None], h, scale)
     np.testing.assert_allclose(got, want, atol=2e-6)
+    assert [count(*labels) for labels in paths] == [n + 1 for n in before]
 
 
 # -- Sinkhorn-Knopp and the hyper-connection -----------------------------------
