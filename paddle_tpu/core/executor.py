@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from . import registry
 from .enforce import EnforceError, op_error
-from .program import Program, Variable, default_main_program
+from .program import Parameter, Program, Variable, default_main_program
 from .scope import Scope, global_scope
 from .places import CPUPlace, Place, _default_place
 from .lod import LoDTensor
@@ -1157,6 +1157,7 @@ class Executor:
             # LoD feeds arrive pre-split host-side ([k, ...] stacked by
             # _normalize_feeds(accum_steps=k)) and are scanned by index
             # — see static_info @ACCUM_LOD in _lower_with_grad_accum
+        op_log = _OpLog(ops, bwd_idx)
 
         def step(state, feeds, rng_key):
             n_splits = [0]
@@ -1174,6 +1175,7 @@ class Executor:
                                         static_info=static_info,
                                         fetch_names=fetch_names)
             ctx.check_nan = check_nan
+            ctx._op_log = op_log
             if accum_steps > 1:
                 self._lower_with_grad_accum(ctx, ops, bwd_idx, block,
                                             feeds, accum_steps,
@@ -1265,6 +1267,7 @@ class Executor:
                                          fetch_names=getattr(
                                              ctx, "fetch_names", ()))
             fctx.check_nan = getattr(ctx, "check_nan", False)
+            fctx._op_log = ctx._op_log
             wrt_set = set(wrt_names)
             for op in ops[:bwd_idx]:
                 # a host op (e.g. prefetch) that PRODUCES a wrt name is a
@@ -1282,6 +1285,9 @@ class Executor:
                 if id(op) in skip_op_ids:
                     continue
                 _lower_op(fctx, op)
+            # the ops after the marker are numbered on from the forward's,
+            # so that a scope names ONE op of a build (the op ledger's key)
+            ctx._op_seq = fctx._op_seq
             # scalar objective: mean-reduce each target (loss is already
             # scalar in the common case; calc_gradient uses unit cotangents,
             # i.e. sum of each target's elements)
@@ -1433,8 +1439,10 @@ class Executor:
                                          fetch_names=getattr(
                                              ctx, "fetch_names", ()))
             fctx.check_nan = getattr(ctx, "check_nan", False)
+            fctx._op_log = ctx._op_log
             for op in ops[:bwd_idx]:
                 _lower_op(fctx, op)
+            ctx._op_seq = fctx._op_seq    # as in _lower_with_grad
             loss = env[target_names[0]]
             if use_ckpt:
                 # checkpoint composes with accumulation: per-microbatch
@@ -1624,8 +1632,12 @@ def _lower_op(ctx, op):
         # a recompute region is not an op of the model: the ops inside
         # it name themselves (numbered on from here), so that a trace
         # attributes a layer's time to its ops and not to the region
-        scope = contextlib.nullcontext() if op.type == "recompute_block" \
-            else jax.named_scope("%s.%d" % (op.type, seq))
+        named = op.type != "recompute_block"
+        scope = jax.named_scope("%s.%d" % (op.type, seq)) if named \
+            else contextlib.nullcontext()
+        # the op ledger's row goes under the scope's own two halves
+        log = ctx._op_log if named else None
+        row = ctx._op_row = None if log is None else log.row(ctx, op, seq)
         with scope:
             info.lower(ctx, op)
     except EnforceError:
@@ -1633,8 +1645,59 @@ def _lower_op(ctx, op):
     except Exception as e:  # annotate with op context (enforce.h:203 parity)
         raise op_error(op, ctx.env, e) from e
     _propagate_lod(ctx, op)
+    if row is not None:
+        row["outputs"] = _described(ctx.env, op.outputs)
     if getattr(ctx, "check_nan", False):
         _record_nan_guards(ctx, op)
+
+
+def _described(env, slots):
+    """{slot: ((variable, shape, dtype), ...)} of an op's inputs or
+    outputs as the trace holds them, in plain values (a value with no
+    shape, a tensor array's list, gives None twice)."""
+    def one(name):
+        v = env.get(name)
+        shape, dtype = getattr(v, "shape", None), getattr(v, "dtype", None)
+        return (name, None if shape is None else tuple(map(int, shape)),
+                None if dtype is None else str(dtype))
+    return {slot: tuple(one(n) for n in names)
+            for slot, names in slots.items()}
+
+
+class _OpLog:
+    """What one build writes its table of the op ledger with
+    (``paddle_tpu.trace.ops``): the table's rows and, from the Program,
+    the names the step's gradients flow through. Made where the step
+    is built; every context that lowers the build's ops shares it, and
+    ``_lower_op`` asks it for each op's row. The eager interpreter's
+    contexts have none: it lowers on every run."""
+
+    def __init__(self, ops, bwd_idx):
+        marker = None if bwd_idx is None else ops[bwd_idx]
+        self.rows = _trc.op_table(
+            marker is not None and marker.type == "backward_marker")
+        self.reach = frozenset()
+        if marker is not None:
+            from ..ops.control_flow import reached_from
+            self.reach = reached_from(ops[:bwd_idx],
+                                      Executor._parse_marker(marker)[0])
+
+    def row(self, ctx, op, seq):
+        find = ctx.block._find_var_recursive if ctx.block is not None \
+            else lambda name: None
+        row = self.rows[seq] = {
+            "seq": seq, "type": op.type,
+            "inputs": _described(ctx.env, op.inputs), "outputs": {},
+            "weights": tuple(n for n in op.input_names
+                             if isinstance(find(n), Parameter)),
+            "region": ctx._op_region, "kept": None}
+        if op.type in ("mul", "matmul"):
+            # which of the product's two gradients the step takes: the
+            # operands a differentiated parameter reaches
+            row["grads"] = tuple(
+                g for g, slot in (("x", "X"), ("w", "Y"))
+                if set(op.input(slot)) & self.reach)
+        return row
 
 
 def _record_nan_guards(ctx, op):

@@ -73,6 +73,14 @@ class LowerContext:
         # what the caller will fetch — rematerialization regions consult
         # this so a fetched region output is exported instead of dropped
         self.fetch_names = tuple(fetch_names or ())
+        # the op ledger (paddle_tpu.trace.ops): what a build's lowering
+        # writes its rows with (core/executor.py _OpLog; None where no
+        # table is being written: the eager interpreter, a loop's body),
+        # the row of the op being lowered and, in a context that lowers
+        # a recompute region's ops, the region's index
+        self._op_log = None
+        self._op_row = None
+        self._op_region = None
 
     # -- value access --------------------------------------------------------
     def get(self, name):
@@ -106,6 +114,14 @@ class LowerContext:
 
     def rng(self):
         return self._rng_fn()
+
+    def note(self, **fields):
+        """A lowering's own words on its op's row of the op ledger (the
+        shape a product runs at, what a plan decided): numbers, strings
+        and tuples, never an array or a tracer. Nothing where no table
+        is being written."""
+        if self._op_row is not None:
+            self._op_row.update(fields)
 
     # -- helpers -------------------------------------------------------------
     @staticmethod

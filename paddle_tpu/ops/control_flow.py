@@ -235,6 +235,26 @@ def _later_reads(ops, idx):
     return set().union(*(_op_reads(o) for o in ops[idx + 1:]))
 
 
+def reached_from(ops, names):
+    """The names that `names` reach through `ops` in program order:
+    what an op writes is reached where it may read a reached name. A
+    region's ops are walked one by one (they write the region's
+    outputs under their own names), any other op that carries
+    sub-blocks is taken whole. The op ledger asks it which products a
+    step differentiates through (core/executor.py _OpLog)."""
+    reach = set(names)
+
+    def walk(ops):
+        for o in ops:
+            if o.type == "recompute_block":
+                walk(o.attr("sub_block").ops)
+            elif _op_reads(o) & reach:
+                reach.update(o.output_names)
+
+    walk(ops)
+    return frozenset(reach)
+
+
 def _device_limit(ctx):
     """The bytes the executor's device says it may hold
     (memory_stats()["bytes_limit"]), 0 where the backend gives none, as
@@ -451,6 +471,7 @@ def _recompute_block(ctx, op):
         raise RuntimeError(
             "recompute_block op not found in its parent block's op list "
             "— the lowering must run on the block that owns the op")
+    region = sum(o.type == "recompute_block" for o in parent_ops[:my_idx])
     later_reads = _later_reads(parent_ops, my_idx)
     persistable = {v.name for v in ctx.block.vars.values()
                    if getattr(v, "persistable", False)} \
@@ -487,11 +508,14 @@ def _recompute_block(ctx, op):
         sctx.check_nan = getattr(ctx, "check_nan", False)
         sctx._nan_idx = guard_start   # program-order guard keys continue
         sctx._op_seq = op_seq         # and so do the ops' scope numbers
+        # the op ledger's rows of these ops say which region they sit in
+        sctx._op_log, sctx._op_region = ctx._op_log, region
         for op2 in block.ops:
             _lower_op(sctx, op2)
             if id(op2) in kept:
                 out = op2.output("Out")[0]
                 env[out] = checkpoint_name(env[out], MUL_OUT)
+                sctx.note(kept=MUL_OUT)
         # exports: region outputs + their @LOD lengths (sequence ops
         # inside the region may have changed them) + per-op NaN guards
         # (the every-op-output contract holds inside regions too)

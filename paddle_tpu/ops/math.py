@@ -52,6 +52,7 @@ def _mul(ctx, op):
         # a tied head: Y is the embedding's own [V, d] table, contracted
         # over its second dimension (nothing is transposed in memory)
         y = y2 = y2.T
+    ctx.note(mkn=(x2.shape[0],) + y2.shape, operand_dtype=str(x2.dtype))
     out = jnp.matmul(x2, y2, preferred_element_type=_acc_type(x))
     from ..amp import amp_out
     out = amp_out(out, out_dtype)
@@ -70,6 +71,10 @@ def _matmul(ctx, op):
     if op.attr("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim > 1 else y
     out = jnp.matmul(x, y, preferred_element_type=_acc_type(x))
+    if out.ndim >= 2:
+        # batched or not: 2 M K N is the product's FLOPs
+        ctx.note(mkn=(out.size // out.shape[-1], x.shape[-1],
+                      out.shape[-1]), operand_dtype=str(x.dtype))
     from ..amp import amp_out
     out = amp_out(out, out_dtype)
     alpha = op.attr("alpha", 1.0)

@@ -56,6 +56,13 @@ Design points:
     (``device_waited``), and collections of 1 ms or more as event rows
     between them. ``steps()`` reads it. It is what says where a run
     lost time when no profiler was on.
+  * The op ledger (ISSUE 51): a build of a step leaves ONE row for
+    every Program op it lowers, under the number the op's device scope
+    carries (``mul.226`` is row 226 of type ``mul``), in a table headed
+    by the step root that built it. Written while the step is traced
+    and at no other time; the last 8 builds are kept. ``ops()`` reads
+    it. It is what says which weight, shape and recompute region a
+    profile's ``mul.226`` is.
   * The span log reuses monitor's FlightRecorder (bounded JSONL,
     atomic-append, in-band truncation marker). Rows:
       span        {trace, span, parent, name, t0, dur, pid, proc, tid,
@@ -84,7 +91,7 @@ __all__ = [
     "tracer", "span", "annotate", "current_span", "active_trace_id",
     "extract", "maybe_enable_from_flags", "detached_span", "child_span",
     "retain_trace", "tail_armed", "tail_dump", "phase", "steps",
-    "fetched",
+    "fetched", "op_table", "ops",
 ]
 
 _DEFAULT_MAX_BYTES = 64 << 20
@@ -465,6 +472,66 @@ def steps(root=None, since=None):
             row["phases"] = _short(row["root"], row["phases"])
         rows.append(row)
     return rows
+
+
+# -- the op ledger -----------------------------------------------------------
+
+# a table a build, oldest first: a run of the benchmark makes three (the
+# start-up program, the for-test forward, the step)
+_OP_BUILDS = collections.deque(maxlen=8)
+
+
+def op_table(backward):
+    """An executor opens a build's table where it builds a step, and
+    gets the dict its lowering writes the rows into, ``{seq: row}``: a
+    retrace of the build writes row ``seq`` again, so the table never
+    grows past the build's ops. The header is taken here: the thread's
+    open step root and its step number (the step whose row of the step
+    ledger is ``fresh``; None for a build outside any root),
+    ``backward`` (the Program has a ``backward_marker``: every build's
+    jitted function is called ``step``, so the name cannot tell the
+    train step's table from the start-up program's) and ``t_build`` on
+    the step ledger's clock."""
+    root = getattr(_thread, "root", None)
+    table = {"root": None if root is None else root._name,
+             "backward": bool(backward),
+             "step": None if root is None else root._row["step"],
+             "t_build": _now(), "rows": {}}
+    _OP_BUILDS.append(table)
+    return table["rows"]
+
+
+def ops(root=None, backward=None):
+    """The op ledger: ``(header, rows)`` of the newest build (of the
+    last 8) whose ``root`` (``exe.step`` / ``pexe.step``) and
+    ``backward`` are the ones asked for, None where there is none. The
+    header is ``root``, ``backward``, ``step``, ``t_build`` (see
+    ``op_table``) and ``count``, the number of rows. The rows are
+    copies, by ``seq``: one for every Program op the build lowered
+    under a device scope, holding numbers, strings and tuples only.
+
+    ``seq``, ``type``: the two halves of the op's scope name
+    (``<type>.<seq>``, the name its device ops carry in a profile);
+    ``inputs``, ``outputs``: ``{slot: ((variable, shape, dtype), ...)}``
+    as the trace saw them; ``weights``: the inputs that are parameters;
+    ``region``: the index of the ``layers.recompute`` region the op
+    sits in, else None; ``kept``: ``"mul_out"`` where the region's plan
+    keeps the op's result for the backward, else None. A ``mul`` or
+    ``matmul`` row also has ``mkn`` (the flattened ``[M, K] x [K, N]``
+    the product runs at), ``operand_dtype`` (after AMP's cast) and
+    ``grads``: ``"x"`` / ``"w"`` for each operand the step
+    differentiates through, told from the Program (a for-test clone
+    has neither)."""
+    for table in reversed(_OP_BUILDS):
+        if root is not None and table["root"] != root:
+            continue
+        if backward is not None and table["backward"] != backward:
+            continue
+        rows = [dict(row) for _, row in sorted(table["rows"].items())]
+        header = {k: v for k, v in table.items() if k != "rows"}
+        header["count"] = len(rows)
+        return header, rows
+    return None
 
 
 class _TailRing:
