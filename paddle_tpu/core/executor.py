@@ -743,11 +743,12 @@ class Executor:
         # a cache miss is exe.build twice: here, and round the first
         # call below, which traces, lowers and compiles
         fresh = entry is None
+        build = lambda: self._build(
+            program, tuple(sorted(feed_arrays)), fetch_names, state_keys,
+            static_info, check_nan=check_nan)
         if fresh:
             with _trc.phase("exe.build", step=step):
-                fn = self._build(program, tuple(sorted(feed_arrays)),
-                                 fetch_names, state_keys, static_info,
-                                 check_nan=check_nan)
+                fn = build()
                 entry = jax.jit(fn, donate_argnums=(0,))
                 if use_program_cache:
                     self._cache[key] = entry
@@ -786,6 +787,11 @@ class Executor:
         with _trc.phase("exe.build" if fresh else "exe.dispatch",
                         step=step), \
                 jax.default_device(self.place.jax_device()):
+            if fresh:
+                entry = self._first_compile(
+                    program, entry, (state, feed_arrays, rng_key), build)
+                if use_program_cache:
+                    self._cache[key] = entry
             if _prof._enabled:
                 # step-level event; sync INSIDE the event so the row
                 # records real step time, not async dispatch; with
@@ -830,6 +836,45 @@ class Executor:
             if return_numpy:
                 return [as_numpy(v) for v in fetches]
             return list(fetches)
+
+    def _first_compile(self, program, entry, args, rebuild):
+        """Lower and compile, ahead of its first call, the step of a
+        program whose recompute regions keep values by a plan
+        (ops/control_flow.py _plan_kept; any other program's `entry` is
+        handed back as it is): the plan's reserve is a reckoning, and
+        the compiled executable is what confirms it. Its
+        memory_analysis() is said beside the plan's figures
+        (control_flow.compiled_step), and where the compile fails with
+        RESOURCE_EXHAUSTED the step is built and lowered once more with
+        a plan of nothing, which is said and counted too. Returns the
+        jitted step to call: `entry`, whose first call finds the
+        executable JAX kept for it (one trace, one compile), or the
+        fallback's."""
+        from ..ops import control_flow as _regions
+        if not _regions.plans(program):
+            return entry
+        fell_back = False
+        try:
+            compiled = entry.lower(*args).compile()
+        except Exception as e:        # jaxlib's XlaRuntimeError
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            _regions._LOG.warning(
+                "recompute: the step did not compile with the regions' "
+                "plan (%s); lowering once more with a plan of nothing",
+                str(e).split("\n")[0][:300])
+            fell_back = self._keep_nothing = True
+            try:
+                entry = jax.jit(rebuild(), donate_argnums=(0,))
+                compiled = entry.lower(*args).compile()
+            finally:
+                self._keep_nothing = False
+        try:
+            memory = compiled.memory_analysis()
+        except Exception:             # a backend that gives none
+            memory = None
+        _regions.compiled_step(memory, fell_back)
+        return entry
 
     # ------------------------------------------------------------------
     def _run_eager(self, program, feed_arrays, fetch_names, scope,

@@ -103,17 +103,27 @@ class BlockGuard:
 class recompute(BlockGuard):
     """Rematerialization region (``with layers.recompute(): ...``): ops
     built inside the block re-run during the backward pass instead of
-    storing their activations (jax.checkpoint over the sub-block). Two
-    things are kept and not re-run: where attention takes the flash
-    kernels, the forward kernel's output and lse rows, which are all the
-    backward kernels read of it (B x T x H*Dv x 2 bytes a call in bf16);
-    and, on a device that states how much it may hold, the results of
-    the regions' matmuls (`mul` ops) for as many as fit what the step
-    leaves free, the widest contractions first (ops/control_flow.py,
-    _plan_kept_muls; nothing to set). The norms, gates and whatever does
-    not fit are recomputed. Wrap each transformer layer to train longer
-    sequences / bigger batches in the same HBM at up to ~1/3 extra
-    forward FLOPs.
+    storing their activations (jax.checkpoint over the sub-block). What
+    is kept and not re-run: where attention takes the flash kernels,
+    the forward kernel's output and lse rows, which are all the
+    backward kernels read of it (B x T x H*Dv x 2 bytes a call in
+    bf16); and, on a device that states how much it may hold, whatever
+    the block's plan admits of the values a backward rule reads
+    (ops/control_flow.py, _plan_kept; nothing to set): the results of
+    the regions' matmuls (`mul` ops) and short convolutions, an expert
+    layer's output where a norm or a stream's merge reads it (its loop
+    then runs once), its router's logits, choices and sorted pairs, and
+    its held weights in the dtype it computes in. The plan prices each
+    in seconds to make it again over the bytes it holds, takes the
+    costliest a byte first, and charges the LAST region's values to the
+    head's moment alone and the others also to the last region's
+    backward, where a step holds most; the executor says at the step's
+    first call what the compiled step holds beside that reckoning, and
+    lowers once more with a plan of nothing if the compile runs out of
+    memory. Still run twice: the norms, the rotary embedding, the
+    gates, the hyper-connections, the scans, and what did not fit. Wrap
+    each transformer layer to train longer sequences / bigger batches
+    in the same HBM at up to ~1/3 extra forward FLOPs.
     Fetch intermediates OUTSIDE a region — exporting them would defeat
     the remat."""
 

@@ -24,7 +24,9 @@ from ..core import registry
 from ..monitor import metrics as _metrics
 from .common import I64
 from .flash_attention import KEPT_IN_REGIONS
+from .short_conv import CONV_OUT
 from ..core.registry import register, LowerContext
+from ..parallel.moe import EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS
 
 
 def _trace_block(ctx, block, env):
@@ -181,37 +183,84 @@ _KEPT_BYTES = _REG.counter(
     "their backward in place of recomputing them, added where a "
     "region's gradient is traced from the shape and dtype of each value "
     "its policy saves, by the value's name (flash_out, flash_lse: a "
-    "flash forward kernel's results, ops/flash_attention.py; mul_out: "
-    "the results of the `mul` ops that the block's plan admitted); a "
-    "region with no such value in it, or one that is never "
-    "differentiated, adds nothing",
+    "flash forward kernel's results, ops/flash_attention.py; mul_out, "
+    "short_conv_out, experts_out, experts_route, experts_weights: what "
+    "the block's plan admitted, by kind); a region with no such value "
+    "in it, or one that is never differentiated, adds nothing",
     ("name",))
 _MUL_PLAN = _REG.gauge(
     "ptpu_recompute_mul_plan",
-    "the last plan of a block's regions (_plan_kept_muls), set where "
-    "its first region is lowered: `candidates` the `mul` results a "
-    "backward rule reads, `admitted` those of them that are kept, "
-    "`admitted_bytes` their bytes and `budget_bytes` what they had to "
-    "fit in",
+    "the `mul` series of ptpu_recompute_plan under its first name: "
+    "`candidates`, `admitted`, `admitted_bytes` of the last plan's `mul` "
+    "results, and `budget_bytes`, the room the regions before the last "
+    "had to fit in",
     ("what",))
+_PLAN = _REG.gauge(
+    "ptpu_recompute_plan",
+    "the last plan of a block's regions (_plan_kept), set where its "
+    "first region is lowered, by the kind of value (the name it is kept "
+    "under): `candidates` the values of the kind that a backward rule "
+    "reads, `admitted` those of them that are kept and `admitted_bytes` "
+    "their bytes; under kind `all` also `budget_bytes` (the room for "
+    "what the regions before the last keep) and `head_budget_bytes` "
+    "(the room for what all regions keep)",
+    ("kind", "what"))
+_COMPILED = _REG.gauge(
+    "ptpu_recompute_compiled_bytes",
+    "memory_analysis() of the last compiled step whose program holds "
+    "recompute regions, read at the step's first call: `argument`, "
+    "`output`, `alias`, `temp`, and `limit`, the device's bytes_limit "
+    "(0 where it states none)",
+    ("what",))
+_FALLBACKS = _REG.counter(
+    "ptpu_recompute_plan_fallbacks_total",
+    "steps lowered a second time with a plan of nothing because the "
+    "compile with the regions' plan failed with RESOURCE_EXHAUSTED")
 # the ONE name of a `mul` result that a region keeps
 MUL_OUT = "mul_out"
-_KEEPS = jax.checkpoint_policies.save_only_these_names(*KEPT_IN_REGIONS,
-                                                       MUL_OUT)
 _LOG = logging.getLogger(__name__)
+if not _LOG.handlers:
+    # the plan's lines (two a build of a program with regions) are what
+    # says on a chip how far a step stands from the device's limit:
+    # they show with no logging set up, on the standard error
+    _LOG.addHandler(logging.StreamHandler())
+    _LOG.handlers[0].setFormatter(logging.Formatter("[paddle_tpu] %(message)s"))
+    _LOG.setLevel(logging.INFO)
+
+# (bf16 FLOP/s, HBM bytes/s) by device kind, for the ORDER of the
+# plan's candidates (seconds to make a value again over its bytes);
+# a kind that is not here is reckoned as a v5e
+_RATES = {"TPU v5 lite": (197e12, 819e9), "TPU v5e": (197e12, 819e9),
+          "TPU v4": (275e12, 1228e9), "TPU v5": (459e12, 2765e9),
+          "TPU v5p": (459e12, 2765e9), "TPU v6 lite": (918e12, 1640e9)}
 
 
-def _region_policy(prim, *avals, **params):
-    """What a recompute region saves: the values named in
-    flash_attention.KEPT_IN_REGIONS, the `mul` results that the block's
-    plan named MUL_OUT, and nothing else. JAX asks once for every
-    equation of a region whose gradient it traces, which is where the
-    kept bytes are counted."""
-    keeps = _KEEPS(prim, *avals, **params)
-    if keeps:
-        _KEPT_BYTES.inc(sum(a.size * a.dtype.itemsize for a in avals),
-                        name=params["name"])
-    return keeps
+def _saves(names):
+    """The policy of a region that saves the values named in `names`
+    and nothing else. JAX asks once for every equation of a region
+    whose gradient it traces, which is where the kept bytes are
+    counted."""
+    keeps = jax.checkpoint_policies.save_only_these_names(*names)
+
+    def policy(prim, *avals, **params):
+        kept = keeps(prim, *avals, **params)
+        if kept:
+            _KEPT_BYTES.inc(sum(a.size * a.dtype.itemsize for a in avals),
+                            name=params["name"])
+        return kept
+
+    return policy
+
+
+# What a recompute region saves where its plan names nothing inside an
+# op's own lowering: the values named in flash_attention.KEPT_IN_REGIONS
+# and the results that the block's plan named where their ops were
+# lowered (MUL_OUT, CONV_OUT, EXPERTS_OUT), and nothing else.
+# EXPERTS_ROUTE and EXPERTS_WEIGHTS are given inside the expert layer
+# wherever it is lowered, and saved by the regions whose plan admitted
+# them.
+_IN_EVERY_REGION = KEPT_IN_REGIONS + (MUL_OUT, CONV_OUT, EXPERTS_OUT)
+_region_policy = _saves(_IN_EVERY_REGION)
 
 
 def _op_reads(o, seen=None):
@@ -255,16 +304,20 @@ def reached_from(ops, names):
     return frozenset(reach)
 
 
+def _device(ctx):
+    place = getattr(ctx.executor, "place", None)
+    return None if place is None else place.jax_device()
+
+
 def _device_limit(ctx):
     """The bytes the executor's device says it may hold
     (memory_stats()["bytes_limit"]), 0 where the backend gives none, as
     the CPU does: the plan then admits nothing and a region lowers as
     under PR 42."""
-    place = getattr(ctx.executor, "place", None)
-    if place is None:
+    device = _device(ctx)
+    if device is None:
         return 0
-    return int((place.jax_device().memory_stats() or {}).get(
-        "bytes_limit", 0))
+    return int((device.memory_stats() or {}).get("bytes_limit", 0))
 
 
 # the ops whose backward rules read none of their operands: a value
@@ -273,12 +326,14 @@ def _device_limit(ctx):
 _SUMS = ("elementwise_add", "elementwise_sub", "sum")
 
 
-def _read_by_a_backward_rule(ops, idx):
+def _read_by_a_backward_rule(ops, idx, slot=None):
     """Whether some op after ops[idx] other than an addition reads its
-    result, directly or through additions: the LAST product of a
-    branch (down, out_proj, wo), which goes into the stream and nowhere
-    else, is not, and keeping it would save no work."""
-    through = set(ops[idx].output_names)
+    result (its output `slot` alone where one is given), directly or
+    through additions: the LAST product of a branch (down, out_proj,
+    wo), which goes into the stream and nowhere else, is not, and
+    keeping it would save no work."""
+    through = set(ops[idx].output_names if slot is None
+                  else ops[idx].output(slot))
     for o in ops[idx + 1:]:
         if _op_reads(o) & through:
             if o.type not in _SUMS:
@@ -291,41 +346,80 @@ class _Unsized(Exception):
     """A Program variable whose declared shape does not give its size."""
 
 
-def _plan_kept_muls(ctx):
-    """Which `mul` results the regions of ctx.block keep from their
-    forward to their backward, as {id(op)}: chosen ONCE, from the
+def _plan_kept(ctx):
+    """What the regions of ctx.block keep from their forward to their
+    backward besides the flash kernels' results: ``(ops, names)``,
+    `ops` {id(op): the name the region gives the op's result} and
+    `names` {region index: the names its policy saves besides} (those
+    the expert layer gives inside its lowering). Chosen ONCE, from the
     Program's static shapes, where the block's first region is lowered
     (no second trace, no compile), by what differs between programs and
-    nothing else: the widths, the rows and what the device has free.
+    nothing else: the ops, the widths, the rows and what the device has
+    free.
 
-    * candidates: every `mul` of a region whose result a backward rule
-      of the region reads (_read_by_a_backward_rule), with the bytes of
-      its result (rows x columns x the itemsize amp.result_dtype gives)
-      and what making it again costs (2 x rows x K x columns FLOPs);
-    * order: FLOPs a byte, highest first (2 K over the itemsize: the
-      wide-K products first), program order among equals;
-    * budget: the device's limit (_device_limit) less the step's state
+    * candidates, each (name, bytes kept, seconds to make it again),
+      and only where a backward rule of the region reads the value
+      (_read_by_a_backward_rule), seconds being FLOPs over the bf16
+      peak plus bytes moved over the HBM's rate (_RATES, by the
+      device's kind), from the declared shapes:
+      - MUL_OUT, a `mul` result: rows x columns x the itemsize
+        amp.result_dtype gives; 2 x rows x K x columns FLOPs and the
+        result written once;
+      - short_conv.CONV_OUT, a `gated_short_conv` result, which the
+        projection after it reads for its weight's gradient: the op's
+        forward bytes (X read, the result written);
+      - moe.EXPERTS_OUT, a `routed_experts` output, ONLY where an op
+        other than an addition reads it (a norm after the layer, a
+        stream's merge): otherwise the second forward needs none of it
+        and the layer's loop is dead there already. Cost: the layer's
+        forward, two grouped matmuls over the pairs uniform routing
+        sends to the held experts, the rows gathered and added back;
+      - moe.EXPERTS_ROUTE, what the layer's and the router's backward
+        read of the scope `route` (the logits, the choice, the chosen
+        scores, the sorted pairs): the router's float32 matmul, the
+        top-k, the gather and the sort again;
+      - moe.EXPERTS_WEIGHTS, the held experts' weights as the layer
+        computes with them: read as declared, written in the compute
+        dtype.
+      NOT candidates: the stream's norms (XLA fuses them with their
+      neighbours; a kernel there lost 2 ms in PR 33), `qk_norm_rope`,
+      the hyper-connections' `h` and `zs`, the scans: a region runs
+      them twice still.
+    * order: seconds a byte, highest first (among `mul` results 2 K
+      over the itemsize, as before: the wide-K products first); among
+      equals the LAST region's first, then program order: the backward
+      runs the regions last to first, so the last region's values are
+      held the shortest.
+    * room: the device's limit (_device_limit) less the step's state
       as the trace holds it (every persistable value: parameters,
-      Adam's moments) less a reserve for the two moments at which a
-      backward holds most. What is live at both: what the ops before
-      the last region write outside regions and what the regions hand
-      on (the stream between layers). Then the larger of the HEAD (what
+      Adam's moments) less a reserve, at the two kinds of moment at
+      which a step holds most. What is live at both: what the ops
+      before the last region write outside regions and what the
+      regions hand on (the stream between layers). (1) The HEAD: what
       the ops after the last region write, and the widest of it, the
-      logits, once more for its gradient) and TWICE the largest
-      region's own variables (its second forward's values and their
-      cotangents). A variable counts its declared bytes (a `mul`
-      result what AMP makes it, a reshape nothing: it is a view), so a
-      float32 variable that AMP holds in bf16 counts double; the
-      gradients are not taken off besides: XLA frees one as its update
-      has read it, and they come as the values they are made from go
-      (PERF.md section 6, PR 48, has the reckoning beside the compiled
-      peaks);
-    * admit in order while the sum fits.
+      logits, once more for its gradient; every kept value is live
+      there. (2) A region's backward: TWICE the largest region's own
+      variables at their declared bytes (a `mul` result what AMP makes
+      it, a reshape nothing: it is a view), which stands for the second
+      forward's values, their cotangents and what an op holds inside
+      (the expert layer's chunk: PERF.md section 6, PR 52, has the
+      compiled working sets beside it). There a region's OWN kept
+      values stand where their recomputed copies would, and the values
+      of the regions after it are gone: the moment that holds most is
+      the last region's backward, with what every region BEFORE it
+      keeps. So a value of the last region is charged to the head
+      alone, any other to both. The gradients are not taken off
+      besides: XLA frees one as its update has read it (PERF.md
+      section 6, PR 48).
+    * admit in order what fits at every moment it is charged to; a
+      candidate that does not fit is passed over.
 
     No marker in the block (nothing is differentiated), no limit to
-    read, or a variable whose shape does not say its size: nothing is
+    read, a variable whose shape does not say its size, or an executor
+    that has fallen back (Executor._first_compile): nothing is
     admitted."""
     from ..amp import result_dtype
+    nothing = {}, {}
     block, env = ctx.block, ctx.env
     ops = list(block.ops) if block is not None else []
     marker = next((i for i, o in enumerate(ops) if o.type in (
@@ -333,12 +427,19 @@ def _plan_kept_muls(ctx):
     limit = _device_limit(ctx) if marker is not None else 0
     regions = [i for i, o in enumerate(ops[:marker])
                if o.type == "recompute_block"]
+    _LAST.clear()
     if not limit or not regions:
-        return frozenset()
+        return nothing
+    if getattr(ctx.executor, "_keep_nothing", False):
+        _LOG.info("recompute: a plan of nothing (the step did not "
+                  "compile with the regions' plan)")
+        return nothing
+    peak, hbm = _RATES.get(getattr(_device(ctx), "device_kind", None),
+                           _RATES["TPU v5e"])
     # of each variable the walk has met: its Program variable (a
     # region's own live in its sub-block, which a later region's does
-    # not see), its elements and its bytes
-    declared, elements, nbytes = {}, {}, {}
+    # not see), its elements and its bytes; and the `mul` results' names
+    declared, elements, nbytes, products = {}, {}, {}, set()
 
     def var_of(blk, name):
         if name not in declared:
@@ -374,10 +475,67 @@ def _plan_kept_muls(ctx):
                     s for s in like.shape if s >= 0)
             dtype = result_dtype(var.dtype) if o.type == "mul" \
                 else var.dtype
+            if o.type == "mul":
+                products.add(name)
             elements[name] = n
             nbytes[name] = 0 if var.persistable or o.type == "reshape" \
                 else n * jnp.dtype(dtype).itemsize
         return sum(nbytes[n] for n in written)
+
+    def itemsize(blk, name):
+        """Of a value as the trace will hold it: a `mul` result's is
+        AMP's, any other's its declared one."""
+        if name in env:
+            return env[name].dtype.itemsize
+        var = var_of(blk, name)
+        return jnp.dtype(result_dtype(var.dtype) if name in products
+                         else var.dtype).itemsize
+
+    def priced(blk, sub_ops, j):
+        """The candidates op j of a region gives: (name, bytes kept,
+        seconds to make them again, id(op) or None for a name the
+        op's own lowering gives)."""
+        m = sub_ops[j]
+        if m.type == "mul" and _read_by_a_backward_rule(sub_ops, j):
+            out = m.output("Out")[0]
+            y = var_of(blk, m.input("Y")[0])
+            yn = m.attr("y_num_col_dims", 1)
+            k = math.prod(y.shape[yn:] if m.attr(
+                "transpose_Y", False) else y.shape[:yn])
+            size = nbytes[out]
+            yield MUL_OUT, size, 2 * k * elements[out] / peak \
+                + size / hbm, id(m)
+        elif m.type == "gated_short_conv" \
+                and _read_by_a_backward_rule(sub_ops, j):
+            # (the op hands on its input's dtype)
+            x, out = m.input("X")[0], m.output("Out")[0]
+            size = elements[out] * itemsize(blk, x)
+            yield CONV_OUT, size, (
+                count(x) * itemsize(blk, x) + size) / hbm, id(m)
+        elif m.type == "routed_experts":
+            x, out = m.input("X")[0], m.output("Out")[0]
+            w = var_of(blk, m.input("WGate")[0])
+            held, d, f = w.shape
+            experts = var_of(blk, m.input("RouterW")[0]).shape[1]
+            top_k = int(m.attr("top_k"))
+            n = count(x) // d
+            pairs = n * top_k * held // experts
+            declared_w = jnp.dtype(w.dtype).itemsize
+            computed_w = jnp.dtype(result_dtype(w.dtype)).itemsize
+            if _read_by_a_backward_rule(sub_ops, j, "Out"):
+                size = elements[out] * itemsize(blk, x)
+                yield EXPERTS_OUT, size, 6 * pairs * d * f / peak + (
+                    (2 * computed_w + 4) * pairs * d + 8 * n * d
+                    + 3 * held * d * f * computed_w) / hbm, id(m)
+            # the logits, the choices with their scores, the pairs'
+            # order; read again: x in float32 for the router's six
+            # bf16 passes, the scores, a sort's passes over the pairs
+            yield EXPERTS_ROUTE, 4 * (n * experts + 3 * n * top_k + held), \
+                12 * n * d * experts / peak + (4 * n * d + 12 * n * experts
+                + 8 * n * top_k * math.ceil(math.log2(max(
+                    n * top_k, 2)))) / hbm, None
+            yield EXPERTS_WEIGHTS, 3 * held * d * f * computed_w, \
+                3 * held * d * f * (declared_w + computed_w) / hbm, None
 
     candidates, stream, head, widest, largest = [], 0, 0, 0, 0
     try:
@@ -390,46 +548,119 @@ def _plan_kept_muls(ctx):
                     head, widest = head + size, max(widest, size)
                 continue
             sub = o.attr("sub_block")
+            region = regions.index(i)
             own = 0
             for j, m in enumerate(sub.ops):
-                size = sized(sub, m)
-                own += size
-                if m.type == "mul" and _read_by_a_backward_rule(sub.ops, j):
-                    y = var_of(sub, m.input("Y")[0])
-                    yn = m.attr("y_num_col_dims", 1)
-                    k = math.prod(y.shape[yn:] if m.attr(
-                        "transpose_Y", False) else y.shape[:yn])
-                    cost = 2 * k * elements[m.output("Out")[0]]
-                    candidates.append((cost / size, size, id(m)))
+                own += sized(sub, m)
+                for name, size, seconds, op_id in priced(sub, sub.ops, j):
+                    # (six digits: equals stay equal whatever their size)
+                    candidates.append((float("%.6g" % (
+                        seconds / max(size, 1))), name, size, region, op_id))
             largest = max(largest, own)
             stream += sum(nbytes[n] for n in
                           set(o.output("Out")) & _later_reads(ops, i))
     except _Unsized as e:
-        _LOG.info("recompute: the shape of %s does not say its size; no "
-                  "mul result is kept", e)
-        return frozenset()
+        _LOG.info("recompute: the shape of %s does not say its size; "
+                  "nothing is kept", e)
+        return nothing
     state = sum(env[n].size * env[n].dtype.itemsize
                 for n, v in block.vars.items() if v.persistable and n in env)
-    reserve = stream + max(head + widest, 2 * largest)
-    budget = max(0, limit - state - reserve)
-    admitted, total = set(), 0
-    # (a stable sort: program order among equals)
-    for _, size, op_id in sorted(candidates, key=lambda c: -c[0]):
-        if total + size > budget:
-            break
-        admitted.add(op_id)
-        total += size
-    for what, value in (("candidates", len(candidates)),
-                        ("admitted", len(admitted)),
-                        ("admitted_bytes", total),
-                        ("budget_bytes", budget)):
-        _MUL_PLAN.set(value, what=what)
-    _LOG.info("recompute: %d of %d mul results kept, %d bytes of a "
-              "budget of %d (the device's limit %d less state %d and a "
-              "reserve of %d: stream %d, head %d + %d, largest region "
-              "2 x %d)", len(admitted), len(candidates), total, budget,
-              limit, state, reserve, stream, head, widest, largest)
-    return frozenset(admitted)
+    # the room at the head, for all that is kept, and at the last
+    # region's backward, for what the regions before it keep
+    at_head = max(0, limit - state - stream - (head + widest))
+    before_last = max(0, limit - state - stream - 2 * largest)
+    last = len(regions) - 1
+    kept_ops, kept_names, all_kept, early_kept = {}, {}, 0, 0
+    by_kind = {name: [0, 0, 0] for name in (
+        MUL_OUT, CONV_OUT, EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS)}
+    # (a stable sort: the last region's first among equals, then
+    # program order)
+    for _, name, size, region, op_id in sorted(
+            candidates, key=lambda c: (-c[0], c[3] != last)):
+        kind = by_kind[name]
+        kind[0] += 1
+        early = size if region != last else 0
+        if all_kept + size > at_head or early_kept + early > before_last:
+            continue
+        all_kept, early_kept = all_kept + size, early_kept + early
+        kind[1:] = kind[1] + 1, kind[2] + size
+        if op_id is None:
+            kept_names.setdefault(region, set()).add(name)
+        else:
+            kept_ops[op_id] = name
+    for name, counts in by_kind.items():
+        for what, value in zip(("candidates", "admitted", "admitted_bytes"),
+                               counts):
+            _PLAN.set(value, kind=name, what=what)
+            if name == MUL_OUT:
+                _MUL_PLAN.set(value, what=what)
+    _MUL_PLAN.set(before_last, what="budget_bytes")
+    _PLAN.set(before_last, kind="all", what="budget_bytes")
+    _PLAN.set(at_head, kind="all", what="head_budget_bytes")
+    _LAST.update(limit=limit, state=state, kept=all_kept,
+                 kept_before_last=early_kept, stream=stream,
+                 head=head + widest, region=2 * largest)
+    _LOG.info(
+        "recompute: kept %s; %d bytes in all of a room of %d at the head, "
+        "%d of them in the regions before the last of a room of %d at its "
+        "backward (the device's limit %d less state %d, stream %d and: "
+        "head %d + %d; largest region 2 x %d)",
+        ", ".join("%d of %d %s (%d bytes)" % (c[1], c[0], name, c[2])
+                  for name, c in sorted(by_kind.items()) if c[0])
+        or "nothing",
+        all_kept, at_head, early_kept, before_last, limit, state, stream,
+        head, widest, largest)
+    return kept_ops, {r: frozenset(n) for r, n in kept_names.items()}
+
+
+# the last plan's reckoning, for the line that sets the compiled step's
+# memory_analysis() beside it (compiled_step)
+_LAST = {}
+
+
+def plans(program):
+    """Whether `program`'s step is lowered under a regions' plan: its
+    main block holds a recompute region before a gradient marker. The
+    executor asks before a step's first call (Executor._first_compile)."""
+    types = [o.type for o in program.global_block().ops]
+    marker = next((i for i, t in enumerate(types) if t in (
+        "backward_marker", "calc_gradient_marker")), None)
+    return marker is not None and "recompute_block" in types[:marker]
+
+
+def compiled_step(memory, fell_back=False):
+    """Say what the compiled step holds beside what the plan reckoned:
+    `memory` is the executable's memory_analysis() (None where the
+    backend gives none), read by the executor at the step's first call.
+    `fell_back`: this is the second lowering, with a plan of nothing."""
+    if fell_back:
+        _FALLBACKS.inc()
+    if memory is None:
+        return
+    sizes = {what: int(getattr(memory, what + "_size_in_bytes", 0))
+             for what in ("argument", "output", "alias", "temp")}
+    limit = _LAST.get("limit", 0)
+    for what, value in dict(sizes, limit=limit).items():
+        _COMPILED.set(value, what=what)
+    held = sizes["argument"] + sizes["temp"]
+    said = "recompute: the compiled step holds %d bytes: arguments %d + " \
+        "temporaries %d (outputs %d, %d of them the arguments' own)" % (
+            held, sizes["argument"], sizes["temp"], sizes["output"],
+            sizes["alias"])
+    if not _LAST:
+        _LOG.info("%s; %s", said, "a plan of nothing, after the compile with "
+                  "the plan ran out of memory" if fell_back
+                  else "no plan was made")
+        return
+    _LOG.info(
+        "%s, %d under the device's limit of %d; the plan reckoned %d: "
+        "state %d + stream %d + the larger of [head %d + kept %d] and "
+        "[region %d + kept before the last %d]", said, limit - held, limit,
+        _LAST["state"] + _LAST["stream"] + max(
+            _LAST["head"] + _LAST["kept"],
+            _LAST["region"] + _LAST["kept_before_last"]),
+        _LAST["state"], _LAST["stream"], _LAST["head"], _LAST["kept"],
+        _LAST["region"], _LAST["kept_before_last"])
 
 
 @register("recompute_block")
@@ -442,19 +673,25 @@ def _recompute_block(ctx, op):
     through the region; RNG-consuming ops (dropout) reuse one region key,
     so the recompute replays identical masks.
 
-    TWO things inside a region are kept and not recomputed
-    (_region_policy). The output and the log-sum-exp rows of a flash
-    forward kernel, all that the flash backward reads of it, at B x T x
-    H*Dv x 2 bytes (bf16) a call, so the kernel runs once a layer. And
-    the results of the `mul` ops that the block's plan admitted
-    (_plan_kept_muls: the products a backward rule reads, the costliest
-    a byte first, while they fit what the device has free), each the
-    value the next op reads, at the precision the forward made it; the
-    plan is made for all the block's regions where the first is
-    lowered. The norms, gates, scans and expert layers round them are
-    still recomputed. A region with no flash kernel in it on a device
-    that states no limit (the CPU) keeps nothing and lowers as under a
-    bare jax.checkpoint.
+    What a region keeps and does not recompute (its policy: _saves).
+    The output and the log-sum-exp rows of a flash forward kernel, all
+    that the flash backward reads of it, at B x T x H*Dv x 2 bytes
+    (bf16) a call, so the kernel runs once a layer. And what the
+    block's plan admitted (_plan_kept, made for all the block's regions
+    where the first is lowered: every kind of value priced in seconds
+    to make it again over its bytes, the costliest a byte first, while
+    they fit what the device has free at the head and at the last
+    region's backward): the results of `mul` ops that a backward rule
+    reads, of `gated_short_conv` ops, an expert layer's output where a
+    norm or a merge reads it (so that the layer's loop runs once), the
+    router's scores, choices and sorted pairs, and the held experts'
+    weights in their compute dtype; each the value the next op reads,
+    at the precision the forward made it. A region still runs TWICE:
+    the stream's norms, `qk_norm_rope` / `rope`, the gates, the
+    hyper-connections' stages, the scans, the keys and values spread
+    under grouped heads, and whatever the plan passed over. A region
+    with no flash kernel in it on a device that states no limit (the
+    CPU) keeps nothing and lowers as under a bare jax.checkpoint.
 
     Outputs exported from the region are the sub-block writes consumed
     by LATER ops of the parent block (looking through their sub-blocks),
@@ -482,9 +719,10 @@ def _recompute_block(ctx, op):
     in_names = [n for n in op.input("X") if n in ctx.env]
 
     # the block's plan, made where its first region is lowered
-    kept = getattr(ctx, "_kept_muls", None)
-    if kept is None:
-        kept = ctx._kept_muls = _plan_kept_muls(ctx)
+    plan = getattr(ctx, "_kept_plan", None)
+    if plan is None:
+        plan = ctx._kept_plan = _plan_kept(ctx)
+    kept, named_inside = plan[0], plan[1].get(region, frozenset())
 
     base_env = dict(ctx.env)
     region_key = ctx._rng_fn()
@@ -512,10 +750,14 @@ def _recompute_block(ctx, op):
         sctx._op_log, sctx._op_region = ctx._op_log, region
         for op2 in block.ops:
             _lower_op(sctx, op2)
-            if id(op2) in kept:
+            names = [kept[id(op2)]] if id(op2) in kept else []
+            if names:
                 out = op2.output("Out")[0]
-                env[out] = checkpoint_name(env[out], MUL_OUT)
-                sctx.note(kept=MUL_OUT)
+                env[out] = checkpoint_name(env[out], names[0])
+            if op2.type == "routed_experts":
+                names += sorted(named_inside)
+            if names:
+                sctx.note(kept="+".join(names))
         # exports: region outputs + their @LOD lengths (sequence ops
         # inside the region may have changed them) + per-op NaN guards
         # (the every-op-output contract holds inside regions too)
@@ -526,7 +768,9 @@ def _recompute_block(ctx, op):
         return tuple(env[n] for n in out_names), lods, guards
 
     _REGIONS.inc()
-    outs, lods, guards = jax.checkpoint(f, policy=_region_policy)(
+    policy = _saves(_IN_EVERY_REGION + tuple(sorted(named_inside))) \
+        if named_inside else _region_policy
+    outs, lods, guards = jax.checkpoint(f, policy=policy)(
         tuple(ctx.env[n] for n in in_names), region_key)
     for n, v in zip(out_names, outs):
         ctx.env[n] = v

@@ -17,7 +17,10 @@ autodiff's transpose of them into the passes backward; no kernel (the
 cell ``lfm2_train_T32k`` reads what that costs:
 ``short_conv_dev_share_pct``). Each lowering counts itself at trace
 time in ``ptpu_short_conv_lowerings_total{taps, channels}``, and its
-device rows carry the Program op's scope.
+device rows carry the Program op's scope. A ``layers.recompute``
+region may keep the op's result from its forward to its backward under
+the name `CONV_OUT` (``ops/control_flow.py``: the block's plan names it
+where the op is lowered, as it names a `mul` result).
 """
 
 import jax.numpy as jnp
@@ -31,6 +34,8 @@ _LOWERINGS = _REG.counter(
     "gated short convolution lowerings at trace time (one a lowering of "
     "the op, none a step): the taps and the channels of a part",
     ("taps", "channels"))
+# the name of the op's result where a recompute region keeps it
+CONV_OUT = "short_conv_out"
 
 
 def causal_taps(x32, w, start=None):

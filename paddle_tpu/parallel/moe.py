@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..monitor import metrics as _metrics
 from ..ops import moe_rows
@@ -219,6 +220,20 @@ _LOWERINGS = _REG.counter(
     ("path", "experts", "experts_held", "top_k", "score", "shared_expert",
      "rows", "activation", "router_input"))
 _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# What a `layers.recompute` region may keep of the layer, by the name
+# the value carries (ops/control_flow.py: the block's plan prices each
+# and the region's policy saves the names it admitted; outside a region
+# a name is the identity). EXPERTS_OUT: the layer's output, named by the
+# region where the plan admitted it. EXPERTS_ROUTE: the router's logits,
+# choices and chosen scores and the sorted pairs (`route`'s logits,
+# top_i and top_p, `order`, `ends`), all that the layer's and the
+# router's backward read of the scope `route` but passes over [N, E]
+# and [N, k], about N x (E + 3 k) x 4 bytes. EXPERTS_WEIGHTS: the
+# held experts' weights in the dtype they compute in, gate and up side
+# by side, as `_held_fwd` hands them to `_held_bwd`.
+EXPERTS_OUT = "experts_out"
+EXPERTS_ROUTE = "experts_route"
+EXPERTS_WEIGHTS = "experts_weights"
 
 
 def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
@@ -230,15 +245,25 @@ def route(x, router_w, top_k, norm_topk, score="softmax", bias=None,
     `scaling`. A selection `bias` [E] is added
     for the CHOICE alone (the k largest of score + bias, no gradient):
     the weights are the unbiased scores at the chosen."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
+    # By name (EXPERTS_ROUTE), each BEFORE the ops whose backward rules
+    # read it, so that a region which saves the name runs neither the
+    # matmul, the top-k nor the gather of the chosen scores again: the
+    # logits (the scores' backward reads its own result, which the
+    # second forward makes from them in one pass over [N, E]), the
+    # choice, the chosen scores.
+    logits = checkpoint_name(
+        jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST), EXPERTS_ROUTE)
     probs = jax.nn.sigmoid(logits) if score == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    if bias is None:
-        top_p, top_i = lax.top_k(probs, top_k)
-    else:
-        _, top_i = lax.top_k(lax.stop_gradient(probs + bias), top_k)
+    top_p, top_i = lax.top_k(
+        probs if bias is None else lax.stop_gradient(probs + bias), top_k)
+    top_i = checkpoint_name(top_i, EXPERTS_ROUTE)
+    if bias is not None:
         top_p = jnp.take_along_axis(probs, top_i, axis=1)
+    # (with no bias the top-k gives the chosen scores itself, and its
+    # own backward reads its own choice: it runs again)
+    top_p = checkpoint_name(top_p, EXPERTS_ROUTE)
     if norm_topk:
         total = jnp.sum(top_p, axis=-1, keepdims=True)
         if norm_eps:        # (else lowered as it was before the epsilon)
@@ -365,10 +390,14 @@ def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
 
     The gate's and the up projection's weights are put side by side
     once a pass, `[Eh, d, 2f]` (the parameters keep their layout), and
-    that is what the backward keeps of them; nothing of a chunk's size
-    is kept."""
+    that is what the backward keeps of them, under the name
+    EXPERTS_WEIGHTS beside `w_down` as it came (cast by the caller): a
+    recompute region that saves the name makes neither again. Nothing
+    of a chunk's size is kept."""
     k = weight.shape[1]
-    w_gu = jnp.concatenate([w_gate, w_up], axis=2)
+    w_gu = checkpoint_name(jnp.concatenate([w_gate, w_up], axis=2),
+                           EXPERTS_WEIGHTS)
+    w_down = checkpoint_name(w_down, EXPERTS_WEIGHTS)
 
     def body(c, carry):
         out, on = carry
@@ -499,9 +528,10 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
         # within one); the others sort behind them and are never visited
         local = experts.reshape(-1) - first_expert
         key = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        ends = jnp.cumsum(lax.dynamic_slice_in_dim(
-            counts, first_expert, held)).astype(jnp.int32)
+        order = checkpoint_name(
+            jnp.argsort(key, stable=True).astype(jnp.int32), EXPERTS_ROUTE)
+        ends = checkpoint_name(jnp.cumsum(lax.dynamic_slice_in_dim(
+            counts, first_expert, held)).astype(jnp.int32), EXPERTS_ROUTE)
     # a chunk: twice what uniform routing sends here, in whole tiles of
     # the grouped matmul
     pairs = n * top_k

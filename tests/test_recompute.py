@@ -14,7 +14,13 @@ kernel runs once a layer; everything else is recomputed, and a region
 with no kernel in it lowers as under a bare jax.checkpoint. Since
 ISSUE 48 also the results of the regions' `mul` ops, for as many as a
 byte budget reckoned from the device's limit admits: nothing on the
-CPU, which states no limit.
+CPU, which states no limit. Since ISSUE 52 the plan prices every kind
+of value a region could keep (a `mul` result, a short convolution's, an
+expert layer's output, its router's results, its weights as it computes
+with them) and charges the last region's values to the head alone: its
+backward follows at once, and the values of the regions before it are
+what that backward holds besides (tests/test_recompute_kinds.py has the
+new kinds' cases).
 """
 
 import collections
@@ -509,34 +515,49 @@ def _products(jaxpr):
     return sum(e.primitive.name == "dot_general" for e in _eqns(jaxpr))
 
 
-def _limit_for(monkeypatch, budget, prefix):
-    """Hand the plan a device limit that leaves `budget` bytes for the
-    program of _two_regions: what a limit of 2**40 leaves says what the
-    state and the reserve take."""
+def _limit_for(monkeypatch, budget, prefix, program=None):
+    """Hand the plan a device limit that leaves `budget` bytes for what
+    the regions BEFORE THE LAST keep in the program of _two_regions
+    (or `program`, a builder like it): what a limit of 2**40 leaves
+    says what the state and the reserve take. The room at the head is
+    wider by what the largest region's term stands over the head's,
+    which is returned; a negative `budget` leaves nothing before the
+    last region and that much less at the head."""
+    program = program or _two_regions
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: 2 ** 40)
-    _step_jaxpr(*_two_regions(prefix))
-    taken = 2 ** 40 - int(CF._MUL_PLAN.value(what="budget_bytes"))
-    monkeypatch.setattr(CF, "_device_limit", lambda ctx: taken + budget)
+    _step_jaxpr(*program(prefix))
+    room = int(CF._MUL_PLAN.value(what="budget_bytes"))
+    wider = int(CF._PLAN.value(kind="all", what="head_budget_bytes")) - room
+    monkeypatch.setattr(CF, "_device_limit",
+                        lambda ctx: 2 ** 40 - room + budget)
+    return wider
 
 
+# (the head's room in _two_regions' program stands 77,820 bytes over
+# the room before the last region: 2 x 40,960 of region B against the
+# head's 2,052 + 2,048)
 @pytest.mark.parametrize("budget, kept", [
-    (0, []), (4 * _ROWS * 16 - 1, []), (4 * _ROWS * 16, ["B2"]),
-    (4 * _ROWS * (16 + 32), ["B2", "A2"]),
-    # A1 does not fit, and B1, which would, comes after it
-    (4 * _ROWS * (16 + 32 + 64) - 1, ["B2", "A2"]),
-    (4 * _ROWS * (16 + 32 + 64), ["B2", "A2", "A1"]),
-    (2 ** 30, ["B2", "A2", "A1", "B1"])],
-    ids=["nothing", "a_byte_short", "one", "two", "stops_at_the_budget",
-         "three", "all"])
+    (-77820, []), (-77820 + 4 * _ROWS * 16, ["B2"]),
+    # A2 and A1 do not fit before the last region; B1, after A2 in the
+    # order, is the last region's and fits at the head
+    (0, ["B2", "B1"]), (4 * _ROWS * 32 - 1, ["B2", "B1"]),
+    (4 * _ROWS * 32, ["B2", "A2", "B1"]),
+    (4 * _ROWS * (32 + 64) - 1, ["B2", "A2", "B1"]),
+    (4 * _ROWS * (32 + 64), ["B2", "A2", "B1", "A1"])],
+    ids=["nothing", "one_at_the_head", "the_last_regions_alone",
+         "a_byte_short", "one_before_the_last", "stops_at_the_room",
+         "all"])
 def test_regions_keep_the_mul_results_the_budget_admits(monkeypatch, budget,
                                                         kept):
     """With a device limit handed in, the two regions keep exactly the
-    results the budget admits, the costliest a byte first, and stop at
-    the first that does not fit: the step's jaxpr names those and no
-    other, runs one product fewer for each (a kept result is not made
-    again), the plan's gauge and the kept-bytes counter say so; the
-    last product of a region is never a candidate."""
-    _limit_for(monkeypatch, budget, "mb_")
+    results the room admits, the costliest a byte first and the last
+    region's first among equals (B2, A2, B1, A1): B's are charged to
+    the head alone, A's also to the last region's backward, and one
+    that does not fit is passed over. The step's jaxpr names those and
+    no other, runs one product fewer for each (a kept result is not
+    made again), the plan's gauges and the kept-bytes counter say so;
+    the last product of a region is never a candidate."""
+    assert _limit_for(monkeypatch, budget, "mb_") == 77820
     before = CF._KEPT_BYTES.value(name=CF.MUL_OUT)
     jaxpr = _step_jaxpr(*_two_regions("mk_"))
     assert _named_mul_out(jaxpr) == sorted(_KEPT_WIDTH[k] for k in kept)
@@ -544,7 +565,12 @@ def test_regions_keep_the_mul_results_the_budget_admits(monkeypatch, budget,
     assert {w: CF._MUL_PLAN.value(what=w) for w in (
         "candidates", "admitted", "admitted_bytes", "budget_bytes")} == {
         "candidates": 4, "admitted": len(kept), "admitted_bytes": nbytes,
-        "budget_bytes": budget}
+        "budget_bytes": max(budget, 0)}
+    assert {w: CF._PLAN.value(kind=CF.MUL_OUT, what=w) for w in (
+        "candidates", "admitted", "admitted_bytes")} == {
+        "candidates": 4, "admitted": len(kept), "admitted_bytes": nbytes}
+    assert CF._PLAN.value(kind="all", what="head_budget_bytes") \
+        == 77820 + budget
     assert CF._KEPT_BYTES.value(name=CF.MUL_OUT) - before == nbytes
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: 0)
     assert _products(jaxpr) == _products(
