@@ -2,8 +2,10 @@
 selective scan and the ops round it (``ops/selective_scan.py``),
 differential attention (``ops/diff_attention.py``), a head tied to
 the embedding, and the gated short convolution of a convolution-only
-mixer (ISSUE 49, ``ops/short_conv.py``). Each is one Program op under its own type, so that a
-device trace gives each its scope."""
+mixer (ISSUE 49, ``ops/short_conv.py``), and the gated delta rule of a
+linear-attention layer with the ops round it (ISSUE 53,
+``ops/delta_rule.py``). Each is one Program op under its own type, so
+that a device trace gives each its scope."""
 
 import math
 
@@ -15,7 +17,9 @@ from ..param_attr import ParamAttr
 from .layer_helper import LayerHelper
 
 __all__ = ["ssm_conv", "ssm_dt", "selective_scan", "ssm_gate", "gmu_gate",
-           "diff_attention", "diff_attn", "tied_head", "gated_short_conv"]
+           "diff_attention", "diff_attn", "tied_head", "gated_short_conv",
+           "l2_norm_scale", "delta_gates", "gated_delta_rule",
+           "gated_rms_norm"]
 
 
 def _param(helper, name, shape, initializer):
@@ -29,19 +33,21 @@ def _same(helper, x, shape=None):
         x.dtype, shape=x.shape if shape is None else shape)
 
 
-def ssm_conv(x, width=4, name=None):
+def ssm_conv(x, width=4, bias=True, name=None):
     """``silu(bias + causal depthwise conv over time)`` of x [B, T, C]:
-    parameters ``<name>_w`` [width, C] and ``<name>_b`` [C], both
-    U(-width^-0.5, width^-0.5) (a depthwise Conv1d's default)."""
+    parameters ``<name>_w`` [width, C] and, where `bias`, ``<name>_b``
+    [C], both U(-width^-0.5, width^-0.5) (a depthwise Conv1d's
+    default)."""
     helper = LayerHelper("ssm_conv", name=name)
     c, bound = int(x.shape[-1]), width ** -0.5
-    w = _param(helper, helper.name + "_w", [width, c],
-               UniformInitializer(-bound, bound))
-    b = _param(helper, helper.name + "_b", [c],
-               UniformInitializer(-bound, bound))
+    inputs = {"X": [x], "Filter": [_param(
+        helper, helper.name + "_w", [width, c],
+        UniformInitializer(-bound, bound))]}
+    if bias:
+        inputs["Bias"] = [_param(helper, helper.name + "_b", [c],
+                                 UniformInitializer(-bound, bound))]
     out = _same(helper, x)
-    helper.append_op(type="ssm_conv",
-                     inputs={"X": [x], "Filter": [w], "Bias": [b]},
+    helper.append_op(type="ssm_conv", inputs=inputs,
                      outputs={"Out": [out]})
     return out
 
@@ -63,15 +69,21 @@ def gated_short_conv(x, width=3, name=None):
     return out
 
 
+def _step_bias(c, dt_min, dt_max):
+    """[c] biases whose softplus is spread log-uniformly over [dt_min,
+    dt_max] (Mamba's)."""
+    dt = np.exp(np.linspace(math.log(dt_min), math.log(dt_max), c))
+    return NumpyArrayInitializer(
+        (dt + np.log(-np.expm1(-dt))).astype("float32"))
+
+
 def ssm_dt(x, dt_min=1e-3, dt_max=1e-1, name=None):
     """``softplus(x + bias)``, the scan's step size: parameter
     ``<name>`` [C], initialised so that softplus(bias) is spread
     log-uniformly over [dt_min, dt_max] (Mamba's)."""
     helper = LayerHelper("ssm_dt", name=name)
     c = int(x.shape[-1])
-    dt = np.exp(np.linspace(math.log(dt_min), math.log(dt_max), c))
-    bias = _param(helper, helper.name, [c], NumpyArrayInitializer(
-        (dt + np.log(-np.expm1(-dt))).astype("float32")))
+    bias = _param(helper, helper.name, [c], _step_bias(c, dt_min, dt_max))
     out = _same(helper, x)
     helper.append_op(type="ssm_dt", inputs={"X": [x], "Bias": [bias]},
                      outputs={"Out": [out]})
@@ -154,6 +166,73 @@ def diff_attn(a1, a2, head_dim, lambda_init, epsilon=1e-5, lambda_std=0.1,
                 "LambdaK2": [lam["lk2"]], "Scale": [scale]},
         outputs={"Out": [out]},
         attrs={"lambda_init": float(lambda_init), "epsilon": float(epsilon)})
+    return out
+
+
+def l2_norm_scale(x, n_head, scale=1.0, epsilon=1e-6, name=None):
+    """Each head's rows of x [B, T, H * D] over their l2 norm, times
+    `scale`: ``x / sqrt(sum(x^2) + epsilon) * scale``; no parameter."""
+    helper = LayerHelper("l2_norm_scale", name=name)
+    out = _same(helper, x)
+    helper.append_op(type="l2_norm_scale", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"n_head": int(n_head), "scale": float(scale),
+                            "epsilon": float(epsilon)})
+    return out
+
+
+def delta_gates(x_a, x_b, beta_scale=1.0, a_max=16.0, dt_min=1e-3,
+                dt_max=1e-1, name=None):
+    """The two gates of a gated delta rule from x_a and x_b [B, T, H]:
+    ``g = -exp(a_log) * softplus(x_a + dt_bias)`` (the log of a row's
+    decay) and ``beta = beta_scale * sigmoid(x_b)``, both float32.
+    Parameters ``<name>_a_log`` [H] (``exp(.)`` at the H quantiles of
+    U(0, `a_max`)) and ``<name>_dt_bias`` [H] (``ssm_dt``'s spread)."""
+    helper = LayerHelper("delta_gates", name=name)
+    h = int(x_a.shape[-1])
+    a_log = _param(helper, helper.name + "_a_log", [h],
+                   NumpyArrayInitializer(np.log(
+                       a_max * (np.arange(h) + 0.5) / h).astype("float32")))
+    dt_bias = _param(helper, helper.name + "_dt_bias", [h],
+                     _step_bias(h, dt_min, dt_max))
+    g, beta = _same(helper, x_a), _same(helper, x_a)
+    helper.append_op(type="delta_gates",
+                     inputs={"XA": [x_a], "XB": [x_b], "ALog": [a_log],
+                             "DtBias": [dt_bias]},
+                     outputs={"G": [g], "Beta": [beta]},
+                     attrs={"beta_scale": float(beta_scale)})
+    return g, beta
+
+
+def gated_delta_rule(q, k, v, g, beta, n_head, chunk=0, name=None):
+    """The gated delta rule (``ops/delta_rule.py``) over `n_head` heads:
+    q and k [B, T, H * d_k] (normed, the query scaled), v [B, T, H *
+    d_v], g and beta [B, T, H]; the state a head ``[d_k, d_v]`` float32
+    whatever the operands are. `chunk` is
+    ``ops/delta_rule.gated_delta_rule``'s (0: its own choice).
+    Returns [B, T, H * d_v]; no parameter."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = _same(helper, v)
+    helper.append_op(type="gated_delta_rule",
+                     inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
+                             "Beta": [beta]},
+                     outputs={"Out": [out]},
+                     attrs={"n_head": int(n_head), "chunk": int(chunk)})
+    return out
+
+
+def gated_rms_norm(x, gate, head_dim, epsilon=1e-6, name=None):
+    """``RMSNorm(x) * silu(gate)`` over each head of `head_dim` values
+    of x [B, T, H * D], the norm before the gate: parameter ``<name>``
+    [D] (ones), one weight for every head."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    scale = _param(helper, helper.name, [head_dim],
+                   ConstantInitializer(1.0))
+    out = _same(helper, gate)
+    helper.append_op(type="gated_rms_norm",
+                     inputs={"X": [x], "Gate": [gate], "Scale": [scale]},
+                     outputs={"Out": [out]},
+                     attrs={"epsilon": float(epsilon)})
     return out
 
 

@@ -24,6 +24,7 @@ from ..core import registry
 from ..monitor import metrics as _metrics
 from .common import I64
 from .flash_attention import KEPT_IN_REGIONS
+from .delta_rule import CHUNK as DELTA_CHUNK, DELTA_OUT
 from .short_conv import CONV_OUT
 from ..core.registry import register, LowerContext
 from ..parallel.moe import EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS
@@ -184,7 +185,8 @@ _KEPT_BYTES = _REG.counter(
     "region's gradient is traced from the shape and dtype of each value "
     "its policy saves, by the value's name (flash_out, flash_lse: a "
     "flash forward kernel's results, ops/flash_attention.py; mul_out, "
-    "short_conv_out, experts_out, experts_route, experts_weights: what "
+    "short_conv_out, delta_rule_out, experts_out, experts_route, "
+    "experts_weights: what "
     "the block's plan admitted, by kind); a region with no such value "
     "in it, or one that is never differentiated, adds nothing",
     ("name",))
@@ -255,11 +257,12 @@ def _saves(names):
 # What a recompute region saves where its plan names nothing inside an
 # op's own lowering: the values named in flash_attention.KEPT_IN_REGIONS
 # and the results that the block's plan named where their ops were
-# lowered (MUL_OUT, CONV_OUT, EXPERTS_OUT), and nothing else.
+# lowered (MUL_OUT, CONV_OUT, DELTA_OUT, EXPERTS_OUT), and nothing else.
 # EXPERTS_ROUTE and EXPERTS_WEIGHTS are given inside the expert layer
 # wherever it is lowered, and saved by the regions whose plan admitted
 # them.
-_IN_EVERY_REGION = KEPT_IN_REGIONS + (MUL_OUT, CONV_OUT, EXPERTS_OUT)
+_IN_EVERY_REGION = KEPT_IN_REGIONS + (MUL_OUT, CONV_OUT, DELTA_OUT,
+                                      EXPERTS_OUT)
 _region_policy = _saves(_IN_EVERY_REGION)
 
 
@@ -368,6 +371,11 @@ def _plan_kept(ctx):
       - short_conv.CONV_OUT, a `gated_short_conv` result, which the
         projection after it reads for its weight's gradient: the op's
         forward bytes (X read, the result written);
+      - delta_rule.DELTA_OUT, a `gated_delta_rule` result, which the
+        gated norm after it reads: what keeping it spares the second
+        forward, the rule's two output products (float32, six bf16
+        passes) and the chunk states they read; the walk itself runs
+        again, for the backward reads its states;
       - moe.EXPERTS_OUT, a `routed_experts` output, ONLY where an op
         other than an addition reads it (a norm after the layer, a
         stream's merge): otherwise the second forward needs none of it
@@ -512,6 +520,17 @@ def _plan_kept(ctx):
             size = elements[out] * itemsize(blk, x)
             yield CONV_OUT, size, (
                 count(x) * itemsize(blk, x) + size) / hbm, id(m)
+        elif m.type == "gated_delta_rule" \
+                and _read_by_a_backward_rule(sub_ops, j):
+            # (the op hands on V's dtype, which a convolution hands on
+            # from a `mul`: the declared one, float32, is the safe side)
+            q, v, out = m.input("Q")[0], m.input("V")[0], m.output("Out")[0]
+            heads = int(m.attr("n_head"))
+            chunk = int(m.attr("chunk", 0)) or DELTA_CHUNK
+            d_k = var_of(blk, q).shape[-1] // heads
+            size = elements[out] * itemsize(blk, v)
+            yield DELTA_OUT, size, 12 * elements[out] * (d_k + chunk) / peak \
+                + (4 * elements[out] * d_k / chunk + size) / hbm, id(m)
         elif m.type == "routed_experts":
             x, out = m.input("X")[0], m.output("Out")[0]
             w = var_of(blk, m.input("WGate")[0])
@@ -572,7 +591,8 @@ def _plan_kept(ctx):
     last = len(regions) - 1
     kept_ops, kept_names, all_kept, early_kept = {}, {}, 0, 0
     by_kind = {name: [0, 0, 0] for name in (
-        MUL_OUT, CONV_OUT, EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS)}
+        MUL_OUT, CONV_OUT, DELTA_OUT, EXPERTS_OUT, EXPERTS_ROUTE,
+        EXPERTS_WEIGHTS)}
     # (a stable sort: the last region's first among equals, then
     # program order)
     for _, name, size, region, op_id in sorted(
@@ -682,7 +702,8 @@ def _recompute_block(ctx, op):
     to make it again over its bytes, the costliest a byte first, while
     they fit what the device has free at the head and at the last
     region's backward): the results of `mul` ops that a backward rule
-    reads, of `gated_short_conv` ops, an expert layer's output where a
+    reads, of `gated_short_conv` and `gated_delta_rule` ops, an expert
+    layer's output where a
     norm or a merge reads it (so that the layer's loop runs once), the
     router's scores, choices and sorted pairs, and the held experts'
     weights in their compute dtype; each the value the next op reads,

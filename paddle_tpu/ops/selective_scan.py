@@ -392,20 +392,22 @@ def selective_scan(s, dt, a, b, c, d, chunk=None, group=None, force=None):
 
 # -- the ops round the scan -------------------------------------------------
 
-def causal_conv_silu(x, w, bias):
+def causal_conv_silu(x, w, bias=None):
     """``silu(bias + sum_i w[i] * x_{t - K + 1 + i})`` over time, each
     channel by itself, zeros before the sequence: x [B, T, C], w [K, C]
-    (K 4), bias [C]. K shifted slices added up (``short_conv.causal_taps``),
-    float32 inside."""
+    (K 4), bias [C] or None (no bias). K shifted slices added up
+    (``short_conv.causal_taps``), float32 inside."""
     f32 = jnp.float32
-    return jax.nn.silu(causal_taps(x.astype(f32), w, bias.astype(f32))
+    return jax.nn.silu(causal_taps(
+        x.astype(f32), w, None if bias is None else bias.astype(f32))
                        ).astype(x.dtype)
 
 
 @register("ssm_conv")
 def _ssm_conv(ctx, op):
-    """X [B, T, C], Filter [K, C], Bias [C] -> Out: the causal depthwise
-    convolution in front of the scan, and its SiLU."""
+    """X [B, T, C], Filter [K, C], Bias [C] (optional) -> Out: the
+    causal depthwise convolution in front of a scan or a delta rule,
+    and its SiLU."""
     ctx.set_out(op, "Out", causal_conv_silu(
         ctx.in1(op, "X"), ctx.in1(op, "Filter"), ctx.in1(op, "Bias")))
 

@@ -95,18 +95,30 @@ def rng():
 
 
 # tests/chipbench/ is the benchmark's own (BENCHMARK.json, "paths"): a PR
-# that is no `benchmark` PR adds files there and edits none. One test
-# there asserts that ITS PR's entries are the last of BENCHMARK.json's
-# lists, which ends with the next cell appended. Until a `benchmark` PR
-# repairs the pin it is expected to fail, and what else it asserts is
-# run by the test named beside it, against the lists as that PR left
-# them.
+# that is no `benchmark` PR adds files there and edits none. A test
+# there that asserts that ITS PR's entries are the last of
+# BENCHMARK.json's lists, or that a list names its first cell alone,
+# ends with the next cell appended. Until a `benchmark` PR repairs the
+# pin (the test should read the cells off BENCHMARK.json) it is expected
+# to fail, and what else it asserts is run by the test named beside it,
+# against the lists as that PR left them.
 _PINS_THE_BENCHMARKS_END = {
     "test_chipbench_smallthinker.py::"
     "test_the_configuration_holds_to_its_source":
         "pins PR 46's entries as BENCHMARK.json's last; PR 49 appended a "
         "cell. Its other assertions run in test_chipbench_lfm2.py::"
         "test_smallthinkers_files_hold_to_their_source_as_pr_46_left_them",
+    "test_chipbench_oplog.py::test_the_entries_in_benchmark_json":
+        "pins PR 51's four metrics as per_layer's last and their lists as "
+        "every cell's; PR 53 appended a cell and two metrics. Its "
+        "assertions run in test_chipbench_olmo_hybrid.py::"
+        "test_a_pinned_entry_is_as_its_pr_left_it",
+    "test_chipbench_norm_rope.py::"
+    "test_the_entry_names_the_block_diffusion_cell_alone":
+        "pins norm_rope_dev_share_pct's list to PR 33's cell; PR 53 "
+        "appended the cell whose eleven rms_norm ops no other reader "
+        "reads. Its assertion runs in test_chipbench_olmo_hybrid.py::"
+        "test_a_pinned_entry_is_as_its_pr_left_it",
 }
 
 

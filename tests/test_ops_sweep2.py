@@ -890,6 +890,10 @@ COVERED_ELSEWHERE = {
     "ssm_dt": "test_selective_scan.py",
     "ssm_gate": "test_selective_scan.py",
     "gated_short_conv": "test_short_conv.py",
+    "gated_delta_rule": "test_delta_rule.py",
+    "l2_norm_scale": "test_delta_rule.py",
+    "delta_gates": "test_delta_rule.py",
+    "gated_rms_norm": "test_delta_rule.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",

@@ -12,6 +12,7 @@ these cases share.
 import logging
 import os
 import sys
+import time
 
 import jax
 import numpy as np
@@ -350,10 +351,13 @@ def test_the_first_call_says_what_the_compiled_step_holds(monkeypatch,
     calls = compiles(monkeypatch, 0)
     main, scope, feeds, fetch = TR._two_regions("fc_")
     exe = fluid.Executor(fluid.CPUPlace())
-    seen = len(runtime.compile_log())
+    # (by the rows' clock, not by their count: the log is bounded, and
+    # a worker that has run other files before this one holds it full,
+    # so that every new row pushes an old one out and the count stands)
+    since = time.perf_counter()
     of_step = lambda: sorted(
-        r["what"] for r in runtime.compile_log()[seen:]
-        if r["fun_name"] in ("step", "jit(step)"))
+        r["what"] for r in runtime.compile_log()
+        if r["end"] >= since and r["fun_name"] in ("step", "jit(step)"))
     once = ["backend_compile_duration", "jaxpr_to_mlir_module_duration",
             "jaxpr_trace_duration"]
     with fluid.scope_guard(scope), caplog.at_level(logging.INFO):

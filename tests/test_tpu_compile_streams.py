@@ -2,7 +2,9 @@
 described v5e (tests/tpu_compile_test.py says how and why): the
 selective scan's chunked pair (``ops/selective_scan.py``) and the
 hyper-connections' four (``ops/hyper_connection.py``), each at its
-cell's shape.
+cell's shape; and the gated delta rule's chunk walk
+(``ops/delta_rule.py``: ``jax.numpy``, no kernel), whose lowering and
+temporaries are checked here where no chip is.
 """
 
 import pytest
@@ -141,3 +143,42 @@ def test_hyper_connection_kernels_compile_for_v5e(chip):
     assert set(passes) - set(calls) <= {"xla in hyper_connection.0",
                                         "xla in hyper_connection.3"}, passes
     assert sum(v for k, v in passes.items() if k not in calls) <= 3, passes
+
+
+# ISSUE 53: the gated delta rule at the cell `olmohybrid_train_T8k`'s
+# shape (one packed 8,192-token sequence, 15 heads, keys of 96, values of
+# 192, bf16 operands, float32 gates): no kernel, so what is held here is
+# what XLA makes of the chunk walk.
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_gated_delta_rule_compiles_for_v5e(chip, direction):
+    """q and k [1, 8192, 15, 96], v [1, 8192, 15, 192]: the walk is ONE
+    while loop of 128 trips forward (one more, reversed, for the
+    gradient), never 8,192; no custom call; the state is float32 (no
+    bf16 value as large as the chunk states); the largest value is the
+    chunk states, [128, 1, 15, 96, 192] float32 (141 MB), and the
+    temporaries stay under 0.75 GiB forward (0.47 today) and 3 GiB
+    with the gradient of all five inputs (2.3)."""
+    import math
+    import re
+    from paddle_tpu.ops.delta_rule import gated_delta_rule
+    b, t, h, d_k, d_v = 1, 8192, 15, 96, 192
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+    avals = (sd((b, t, h, d_k)), sd((b, t, h, d_k)), sd((b, t, h, d_v)),
+             sd((b, t, h), jnp.float32), sd((b, t, h), jnp.float32))
+    loss = lambda *a: gated_delta_rule(*a).astype(jnp.float32).sum()
+    fn = gated_delta_rule if direction == "fwd" \
+        else jax.grad(loss, argnums=tuple(range(5)))
+    compiled = jax.jit(fn).lower(*avals).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # the walk's loops, each over the 128 chunks' stacked values
+    assert text.count(" while(") == (1 if direction == "fwd" else 2)
+    assert "f32[%d,%d,%d,%d,%d]" % (t // 64, b, h, d_k, d_v) in text
+    states = t // 64 * h * d_k * d_v
+    sizes = {(kind, math.prod(int(x) for x in dims.split(",")))
+             for kind, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text)}
+    assert max(size for _, size in sizes) == states
+    assert max(size for kind, size in sizes if kind == "bf16") < states
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (0.75 if direction == "fwd" else 3) * 2 ** 30, temp
