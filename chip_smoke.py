@@ -72,6 +72,12 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           against the kernels of ops/hyper_connection.py: device ms,
           passes over the stream, GB/s, and the largest difference in
           values and gradients
+  delta   the gated delta rule's kernel pair (ISSUE 54) at the cell
+          olmohybrid_train_T8k's shape, q and k [1, 8192, 15 x 96], v
+          [.., 15 x 192] bf16, float32 gates: o and the gradients of
+          all five inputs against the jax.numpy chunk walk on the same
+          values, in bf16 and in float32; device ms forward and
+          forward + backward at chunks of 64 and 128 beside the walk's
   train   T.transformer_lm -> Adam.minimize -> amp.enable_amp ->
           Executor(TPUPlace(0)); 5 steps on one batch; loss ~ ln(vocab)
           and falling; the flash kernel is in the compiled step
@@ -714,6 +720,69 @@ def phase_scan(seed, rehearse):
     if not rehearse:
         assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
         assert " while(" not in text
+
+
+def phase_delta(seed, rehearse):
+    """The gated delta rule's kernel pair (ISSUE 54) at the cell
+    olmohybrid_train_T8k's shape: o and the five gradients of
+    ``delta_rule_fwd`` / ``delta_rule_bwd`` against the jax.numpy chunk
+    walk and its autodiff on the same values, bf16 operands and
+    float32 ones (where the two must agree to float32 rounding: the
+    state, the solve and the decays are float32 in both), and the
+    device ms of both, forward and forward + backward, at chunks of 64
+    and of 128 rows."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import delta_rule as dr
+    b, t, h, d_k, d_v = (1, 96, 2, 8, 16) if rehearse \
+        else (1, 8192, 15, 96, 192)
+    force = "interpret" if rehearse else "pallas"
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+    ops32 = (unit(jax.random.normal(ks[0], (b, t, h, d_k))) * d_k ** -0.5,
+             unit(jax.random.normal(ks[1], (b, t, h, d_k))),
+             jax.random.normal(ks[2], (b, t, h, d_v)),
+             -0.1 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h))),
+             2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h))))
+    ops16 = tuple(x.astype(jnp.bfloat16) for x in ops32[:3]) + ops32[3:]
+    do = jax.random.normal(ks[5], (b, t, h, d_v))
+
+    def both(rule, *a):
+        o, vjp = jax.vjp(rule, *a)
+        return (o,) + vjp(do.astype(o.dtype))
+
+    def forms(chunk, path):
+        rule = functools.partial(dr.gated_delta_rule, chunk=chunk,
+                                 force=path)
+        return jax.jit(rule), jax.jit(functools.partial(both, rule))
+
+    f32 = lambda xs: [x.astype(jnp.float32) for x in xs]
+    for chunk in (16, 32) if rehearse else (64, 128):
+        t0 = time.perf_counter()
+        fwd, fb = forms(chunk, force)
+        walk_fwd, walk_fb = forms(chunk, "chunked")
+        text = "" if rehearse else compiled_text(fb, *ops16)
+        ms_f, _, _ = _device_ms(fwd, ops16, 4, rehearse, "delta_f")
+        ms, got, kinds = _device_ms(fb, ops16, 4, rehearse, "delta_fb")
+        ms_wf, _, _ = _device_ms(walk_fwd, ops16, 2, rehearse, "delta_wf")
+        ms_w, want, _ = _device_ms(walk_fb, ops16, 2, rehearse, "delta_w")
+        errs = _far(got, f32(want))
+        exact = _far(fb(*ops32), f32(walk_fb(*ops32)))
+        log("[delta] q/k [%d, %d, %d x %d] v [.. x %d] chunks of %d: bf16 "
+            "operands o %.3e dq %.3e dk %.3e dv %.3e dg %.3e dbeta %.3e "
+            "from the jax.numpy walk, float32 operands %s (%.1f s); "
+            "forward %.3f ms, forward + backward %.3f ms a call on the "
+            "device (%s); the walk %.3f and %.3f ms" % (
+                b, t, h, d_k, d_v, chunk, *errs,
+                " ".join("%.2e" % e for e in exact),
+                time.perf_counter() - t0, ms_f, ms,
+                ", ".join("%s %.3f" % kv for kv in kinds.most_common(5)),
+                ms_wf, ms_w))
+        assert max(errs) <= FLASH_GRAD_TOL, errs
+        assert max(exact) <= 2e-5, exact
+        if not rehearse:
+            assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
+            assert " while(" not in text
 
 
 def phase_diff(seed, rehearse):
@@ -1479,7 +1548,8 @@ def main():
                          "dp2 x tp2 mesh and its one-device baseline")
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
-                         "(flash, gqa, own_block, mla, window, scan, diff, "
+                         "(flash, gqa, own_block, mla, window, scan, delta, "
+                         "diff, "
                          "rotary, "
                          "experts, "
                          "rows, hc, "
@@ -1507,6 +1577,7 @@ def main():
         phases = {"flash": phase_flash, "gqa": phase_gqa,
                   "own_block": phase_own_block, "mla": phase_mla,
                   "window": phase_window, "scan": phase_scan,
+                  "delta": phase_delta,
                   "diff": phase_diff, "rotary": phase_rotary,
                   "experts": phase_experts,
                   "rows": phase_rows, "hc": phase_hc,

@@ -81,6 +81,11 @@ class LowerContext:
         self._op_log = None
         self._op_row = None
         self._op_region = None
+        # in a context that lowers a recompute region's ops: what the
+        # block's plan keeps of them, {id(op): the name its result is
+        # kept by} (ops/control_flow.py _plan_kept); an op whose
+        # lowering names its own values reads it (gated_delta_rule)
+        self.kept_ops = {}
 
     # -- value access --------------------------------------------------------
     def get(self, name):

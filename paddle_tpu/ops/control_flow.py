@@ -24,7 +24,7 @@ from ..core import registry
 from ..monitor import metrics as _metrics
 from .common import I64
 from .flash_attention import KEPT_IN_REGIONS
-from .delta_rule import CHUNK as DELTA_CHUNK, DELTA_OUT
+from .delta_rule import DELTA_OUT, DELTA_STATES, kept_by_a_region
 from .short_conv import CONV_OUT
 from ..core.registry import register, LowerContext
 from ..parallel.moe import EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS
@@ -187,7 +187,9 @@ _KEPT_BYTES = _REG.counter(
     "flash forward kernel's results, ops/flash_attention.py; mul_out, "
     "short_conv_out, delta_rule_out, experts_out, experts_route, "
     "experts_weights: what "
-    "the block's plan admitted, by kind); a region with no such value "
+    "the block's plan admitted, by kind; delta_rule_states: the chunks' "
+    "starting states that the kind delta_rule_out holds beside the "
+    "result under the rule's kernels); a region with no such value "
     "in it, or one that is never differentiated, adds nothing",
     ("name",))
 _MUL_PLAN = _REG.gauge(
@@ -257,12 +259,14 @@ def _saves(names):
 # What a recompute region saves where its plan names nothing inside an
 # op's own lowering: the values named in flash_attention.KEPT_IN_REGIONS
 # and the results that the block's plan named where their ops were
-# lowered (MUL_OUT, CONV_OUT, DELTA_OUT, EXPERTS_OUT), and nothing else.
+# lowered (MUL_OUT, CONV_OUT, EXPERTS_OUT; DELTA_OUT and DELTA_STATES,
+# which the rule's own lowering gives where the plan kept the op), and
+# nothing else.
 # EXPERTS_ROUTE and EXPERTS_WEIGHTS are given inside the expert layer
 # wherever it is lowered, and saved by the regions whose plan admitted
 # them.
 _IN_EVERY_REGION = KEPT_IN_REGIONS + (MUL_OUT, CONV_OUT, DELTA_OUT,
-                                      EXPERTS_OUT)
+                                      DELTA_STATES, EXPERTS_OUT)
 _region_policy = _saves(_IN_EVERY_REGION)
 
 
@@ -372,10 +376,15 @@ def _plan_kept(ctx):
         projection after it reads for its weight's gradient: the op's
         forward bytes (X read, the result written);
       - delta_rule.DELTA_OUT, a `gated_delta_rule` result, which the
-        gated norm after it reads: what keeping it spares the second
-        forward, the rule's two output products (float32, six bf16
-        passes) and the chunk states they read; the walk itself runs
-        again, for the backward reads its states;
+        gated norm after it reads, priced by the path the op takes
+        (delta_rule.kept_by_a_region): under its kernels the result and
+        the chunks' starting states (DELTA_STATES, named beside it
+        inside the op's custom rule) are all the backward kernel reads
+        of the forward, so the kind holds both and spares the whole
+        forward kernel; under the jax.numpy walk the result alone,
+        which spares the two output products and the chunk states they
+        read: that walk runs again, for autodiff's backward reads its
+        states;
       - moe.EXPERTS_OUT, a `routed_experts` output, ONLY where an op
         other than an addition reads it (a norm after the layer, a
         stream's merge): otherwise the second forward needs none of it
@@ -526,11 +535,12 @@ def _plan_kept(ctx):
             # from a `mul`: the declared one, float32, is the safe side)
             q, v, out = m.input("Q")[0], m.input("V")[0], m.output("Out")[0]
             heads = int(m.attr("n_head"))
-            chunk = int(m.attr("chunk", 0)) or DELTA_CHUNK
             d_k = var_of(blk, q).shape[-1] // heads
-            size = elements[out] * itemsize(blk, v)
-            yield DELTA_OUT, size, 12 * elements[out] * (d_k + chunk) / peak \
-                + (4 * elements[out] * d_k / chunk + size) / hbm, id(m)
+            d_v = var_of(blk, v).shape[-1] // heads
+            size, flops, moved = kept_by_a_region(
+                elements[out] // (heads * d_v), heads, d_k, d_v,
+                itemsize(blk, v), int(m.attr("chunk", 0)) or None)
+            yield DELTA_OUT, size, flops / peak + moved / hbm, id(m)
         elif m.type == "routed_experts":
             x, out = m.input("X")[0], m.output("Out")[0]
             w = var_of(blk, m.input("WGate")[0])
@@ -769,10 +779,13 @@ def _recompute_block(ctx, op):
         sctx._op_seq = op_seq         # and so do the ops' scope numbers
         # the op ledger's rows of these ops say which region they sit in
         sctx._op_log, sctx._op_region = ctx._op_log, region
+        sctx.kept_ops = kept
         for op2 in block.ops:
             _lower_op(sctx, op2)
             names = [kept[id(op2)]] if id(op2) in kept else []
-            if names:
+            # (the rule names its result itself, and under its kernels
+            # the states beside it, inside its custom rule)
+            if names and op2.type != "gated_delta_rule":
                 out = op2.output("Out")[0]
                 env[out] = checkpoint_name(env[out], names[0])
             if op2.type == "routed_experts":

@@ -181,28 +181,48 @@ def test_a_heads_size_is_an_argument_and_no_quotient():
     assert shapes["m_l1_wq"] == (32, 8) and shapes["m_l1_wo"] == (8, 32)
 
 
+@pytest.mark.parametrize("path", ["walk", "kernels"])
 def test_the_rules_result_is_a_candidate_of_the_plan_and_kept_where_it_fits(
-        monkeypatch):
+        path, monkeypatch):
     """Two linear layers and a full one, every layer a region. With room
     the plan admits both rules' results (the gated norm after a rule
     reads it) beside every product, each carries its name once, and a
     traced gradient counts its bytes; with no room none is named, and
-    the step is the same bits either way."""
-    build = lambda: _lm("k_")[:4]
+    the step is the same bits either way. Under the rule's kernels
+    (interpret mode here: the path rule is steered in the test, the
+    program has no option for it) the ONE kind holds the chunks'
+    starting states too, under their own name beside the result's,
+    all that the backward kernel reads of the forward."""
+    kernels = path == "kernels"
+    if kernels:
+        monkeypatch.setattr(DR, "_resolve_path", lambda d_k, d_v, on_tpu,
+                            force=None: force or "interpret")
+    build = lambda: _lm("k_", delta_chunk=16 if kernels else 8)[:4]
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: 2 ** 40)
-    before = CF._KEPT_BYTES.value(name=DR.DELTA_OUT)
+    names = (DR.DELTA_OUT, DR.DELTA_STATES)
+    counted = lambda: [CF._KEPT_BYTES.value(name=n) for n in names]
+    before = counted()
     kept = TR._step_jaxpr(*build())
     said = lambda what: int(CF._PLAN.value(kind=DR.DELTA_OUT, what=what))
     assert (said("candidates"), said("admitted")) == (2, 2)
-    # [2, 16, 2 x 8] float32 a rule
-    assert said("admitted_bytes") == 2 * 2 * 16 * 16 * 4
-    assert _named(kept, DR.DELTA_OUT) == 2
-    assert CF._KEPT_BYTES.value(name=DR.DELTA_OUT) - before \
-        == said("admitted_bytes")
+    # a rule: the result [2, 16, 2 x 8] float32 and, under the kernels,
+    # the ONE chunk's starting state of 2 x 2 heads, [4, 8] float32
+    out, states = 2 * 16 * 16 * 4, 4 * 4 * 8 * 4 if kernels else 0
+    assert said("admitted_bytes") == 2 * (out + states)
+    assert [_named(kept, n) for n in names] == [2, 2 if kernels else 0]
+    assert [now - was for now, was in zip(counted(), before)] \
+        == [2 * out, 2 * states]
+    # with both kept the forward kernel is dead in the second forward:
+    # once a rule, where a region that keeps nothing runs it twice
+    rules = lambda jaxpr: [TR._kernels(jaxpr).get(k, 0) for k in (
+        "delta_rule_fwd", "delta_rule_bwd")]
+    assert rules(kept) == ([2, 2] if kernels else [0, 0])
     with jax.disable_jit():
         with_room = TR._run(*build())
         monkeypatch.setattr(CF, "_device_limit", lambda ctx: 0)
-        assert _named(TR._step_jaxpr(*build()), DR.DELTA_OUT) == 0
+        bare = TR._step_jaxpr(*build())
+        assert [_named(bare, n) for n in names] == [0, 0]
+        assert rules(bare) == ([4, 2] if kernels else [0, 0])
         without = TR._run(*build())
     assert all(np.abs(g).sum() > 0 for g in with_room[1:])
     for a, b in zip(with_room, without):

@@ -2,9 +2,10 @@
 described v5e (tests/tpu_compile_test.py says how and why): the
 selective scan's chunked pair (``ops/selective_scan.py``) and the
 hyper-connections' four (``ops/hyper_connection.py``), each at its
-cell's shape; and the gated delta rule's chunk walk
-(``ops/delta_rule.py``: ``jax.numpy``, no kernel), whose lowering and
-temporaries are checked here where no chip is.
+cell's shape; and the gated delta rule (``ops/delta_rule.py``): its
+kernel pair, the path a v5e takes at the cell's shape, and the
+``jax.numpy`` chunk walk that every other device and shape takes, whose
+lowering and temporaries are checked here where no chip is.
 """
 
 import pytest
@@ -145,13 +146,73 @@ def test_hyper_connection_kernels_compile_for_v5e(chip):
     assert sum(v for k, v in passes.items() if k not in calls) <= 3, passes
 
 
-# ISSUE 53: the gated delta rule at the cell `olmohybrid_train_T8k`'s
-# shape (one packed 8,192-token sequence, 15 heads, keys of 96, values of
-# 192, bf16 operands, float32 gates): no kernel, so what is held here is
-# what XLA makes of the chunk walk.
+# ISSUEs 53 and 54: the gated delta rule at the cell
+# `olmohybrid_train_T8k`'s shape (one packed 8,192-token sequence, 15
+# heads, keys of 96, values of 192, bf16 operands, float32 gates).
+def _delta_rule_compiled(chip, direction, path):
+    from paddle_tpu.ops.delta_rule import gated_delta_rule
+    b, t, h, d_k, d_v = 1, 8192, 15, 96, 192
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+    avals = (sd((b, t, h, d_k)), sd((b, t, h, d_k)), sd((b, t, h, d_v)),
+             sd((b, t, h), jnp.float32), sd((b, t, h), jnp.float32))
+    # (the described chip is not the default backend: the path a v5e
+    # takes by itself is pinned here, as the scan's is above)
+    rule = lambda *a: gated_delta_rule(*a, force=path)
+    loss = lambda *a: rule(*a).astype(jnp.float32).sum()
+    fn = rule if direction == "fwd" \
+        else jax.grad(loss, argnums=tuple(range(5)))
+    return jax.jit(fn).lower(*avals).compile(), (b, t, h, d_k, d_v)
+
+
+def _float_sizes(text):
+    """{(dtype, the dimensions other than 1, sorted)} of every f32 and
+    bf16 value in the compiled text."""
+    import re
+    return {(kind, tuple(sorted(int(x) for x in dims.split(",")
+                                if int(x) > 1)))
+            for kind, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text)}
+
+
 @pytest.mark.parametrize("direction", ["fwd", "grad"])
 def test_gated_delta_rule_compiles_for_v5e(chip, direction):
-    """q and k [1, 8192, 15, 96], v [1, 8192, 15, 192]: the walk is ONE
+    """q and k [1, 8192, 15, 96], v [1, 8192, 15, 192] on the path a
+    v5e takes: Mosaic accepts ``delta_rule_fwd`` (and, for the gradient
+    of all five inputs, ``delta_rule_bwd``: ONE kernel a direction) at
+    widths that are no whole lane tiles, inside the compiler's own
+    16 MiB of VMEM (the kernels ask for no limit of their own); the
+    chunks are walked by the kernels' grids, so no while loop is left;
+    the chunks' starting states are float32, [15, 128, 96, 192] at the
+    kernels' chunks of 64 rows (141 MB), the largest float32 value
+    there is and the largest of all; and nothing of a chunk's [C, C]
+    parts (the decays, the Gram products, T_) reaches HBM: no float32
+    value of [15, 128, 64, 64]."""
+    import math
+    from paddle_tpu.ops import delta_rule as DR
+    compiled, (b, t, h, d_k, d_v) = _delta_rule_compiled(
+        chip, direction, "pallas")
+    text = compiled.as_text()
+    names = ["delta_rule_fwd"] + (["delta_rule_bwd"]
+                                  if direction == "grad" else [])
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert "%" + name + "." in text or "%" + name + " " in text
+    assert "vmem_limit_bytes" not in text
+    assert " while(" not in text
+    c = DR.KERNEL_CHUNK
+    sizes = _float_sizes(text)
+    states = tuple(sorted((h, t // c, d_k, d_v)))
+    assert ("f32", states) in sizes and ("bf16", states) not in sizes
+    assert max(math.prod(dims) for _, dims in sizes) == math.prod(states)
+    assert ("f32", tuple(sorted((h, t // c, c, c)))) not in sizes
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (0.5 if direction == "fwd" else 0.75) * 2 ** 30, temp
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_gated_delta_rules_jax_numpy_walk_compiles_for_v5e(chip, direction):
+    """The same shapes on the path every other device and shape takes
+    (``force="chunked"``): the walk is ONE
     while loop of 128 trips forward (one more, reversed, for the
     gradient), never 8,192; no custom call; the state is float32 (no
     bf16 value as large as the chunk states); the largest value is the
@@ -159,25 +220,15 @@ def test_gated_delta_rule_compiles_for_v5e(chip, direction):
     temporaries stay under 0.75 GiB forward (0.47 today) and 3 GiB
     with the gradient of all five inputs (2.3)."""
     import math
-    import re
-    from paddle_tpu.ops.delta_rule import gated_delta_rule
-    b, t, h, d_k, d_v = 1, 8192, 15, 96, 192
-    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=chip)
-    avals = (sd((b, t, h, d_k)), sd((b, t, h, d_k)), sd((b, t, h, d_v)),
-             sd((b, t, h), jnp.float32), sd((b, t, h), jnp.float32))
-    loss = lambda *a: gated_delta_rule(*a).astype(jnp.float32).sum()
-    fn = gated_delta_rule if direction == "fwd" \
-        else jax.grad(loss, argnums=tuple(range(5)))
-    compiled = jax.jit(fn).lower(*avals).compile()
+    compiled, (b, t, h, d_k, d_v) = _delta_rule_compiled(
+        chip, direction, "chunked")
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     # the walk's loops, each over the 128 chunks' stacked values
     assert text.count(" while(") == (1 if direction == "fwd" else 2)
     assert "f32[%d,%d,%d,%d,%d]" % (t // 64, b, h, d_k, d_v) in text
     states = t // 64 * h * d_k * d_v
-    sizes = {(kind, math.prod(int(x) for x in dims.split(",")))
-             for kind, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text)}
+    sizes = {(kind, math.prod(dims)) for kind, dims in _float_sizes(text)}
     assert max(size for _, size in sizes) == states
     assert max(size for kind, size in sizes if kind == "bf16") < states
     temp = compiled.memory_analysis().temp_size_in_bytes
