@@ -1735,7 +1735,8 @@ class _OpLog:
             "inputs": _described(ctx.env, op.inputs), "outputs": {},
             "weights": tuple(n for n in op.input_names
                              if isinstance(find(n), Parameter)),
-            "region": ctx._op_region, "kept": None}
+            "region": ctx._op_region, "kept": None,
+            "module": op.attr("module")}
         if op.type in ("mul", "matmul"):
             # which of the product's two gradients the step takes: the
             # operands a differentiated parameter reaches

@@ -314,6 +314,8 @@ class Block:
     # -- ops ----------------------------------------------------------------
     def append_op(self, type, inputs=None, outputs=None, attrs=None):
         op = Operator(self, type, inputs, outputs, attrs)
+        if self.program._module is not None:     # layers.module
+            op.attrs.setdefault("module", self.program._module)
         self.ops.append(op)
         self.program._bump_version()
         _infer_shape(self, op)
@@ -368,6 +370,10 @@ class Program:
     ``_version`` increments on every mutation; the Executor's compiled-step
     cache keys on it (replacement for executor.py:165's program cache).
     """
+
+    # the module whose ops are being built (``layers.module``): every op
+    # appended meanwhile carries it as its attr ``module``
+    _module = None
 
     def __init__(self):
         self.blocks = [Block(self, 0)]
@@ -559,6 +565,7 @@ _TEST_MODE_OPS = {
     # train steps: a for_test clone reads them and leaves them
     "block_diffusion_noise": ("is_test",),
     "routed_experts": ("is_test",),
+    "step_sum": ("is_test",),
 }
 
 

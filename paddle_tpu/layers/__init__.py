@@ -36,5 +36,5 @@ from .rnn_layers import *  # noqa: F401,F403
 from .tensor import (  # noqa: F401
     argmax, argmin, assign, cast, concat, create_global_var, create_tensor,
     expand, fill_constant, fill_constant_batch_size_like, gather, increment,
-    ones, reshape, scatter, slice, split, sums, transpose, zeros,
+    ones, reshape, scatter, slice, split, step_sum, sums, transpose, zeros,
 )

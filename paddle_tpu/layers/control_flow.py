@@ -10,6 +10,8 @@ over the full batch and merges rows by mask (static shapes) instead of
 physically partitioning the batch; Switch builds a select chain.
 """
 
+import contextlib
+
 import numpy as np
 
 from .layer_helper import LayerHelper
@@ -20,7 +22,7 @@ from ..core.program import default_main_program, Variable
 __all__ = ["While", "StaticRNN", "DynamicRNN", "IfElse", "Switch",
            "increment", "array_read", "array_write", "array_length",
            "less_than", "equal", "lod_rank_table", "max_sequence_len",
-           "create_array", "zeros_like", "recompute"]
+           "create_array", "zeros_like", "recompute", "module"]
 
 
 from .tensor import increment  # noqa: F401  (single implementation)
@@ -150,6 +152,24 @@ class recompute(BlockGuard):
                 outputs={"Out": sorted(created)},
                 attrs={"sub_block": sub_block})
         return False
+
+
+@contextlib.contextmanager
+def module(name):
+    """``with layers.module("mtp"): ...``: every op built inside, in a
+    recompute region or out of one, carries the attr ``module`` =
+    `name`, and its row of the op ledger (``paddle_tpu.trace.ops``)
+    says so: a profile's device ops, whose scopes are the rows' keys,
+    can then be told by the part of the model that built them where
+    their types cannot (a second block behind the layer stack, a second
+    head). Outside any such context an op has no such attr and its row
+    says None. An inner context's name stands for its ops."""
+    program = default_main_program()
+    outer, program._module = program._module, str(name)
+    try:
+        yield
+    finally:
+        program._module = outer
 
 
 class While:

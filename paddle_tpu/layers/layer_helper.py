@@ -56,6 +56,10 @@ class LayerHelper:
         param = self.block.create_parameter(
             shape=shape, dtype=dtype, **attr.to_kwargs())
         sb = self.startup_program.global_block()
+        if attr.name in sb.vars:
+            # a parameter shared by name (a table looked up twice, a
+            # head multiplied twice): initialised where it was first made
+            return param
         sparam = sb.create_parameter(
             shape=shape, dtype=dtype, **attr.to_kwargs())
         init(sparam, sb)

@@ -238,6 +238,21 @@ def increment(x, value=1.0, in_place=True):
     return out
 
 
+def step_sum(x, name):
+    """A program counter: the persistable float32 ``[1]`` named `name`,
+    zero at first, to which every TRAIN run of the program adds the
+    scalar `x` on the device (a for_test clone reads it and leaves it;
+    no gradient passes through). Read it from the scope once a window,
+    as a router's ``load``."""
+    helper = LayerHelper("step_sum", name=name)
+    total = create_global_var([1], 0.0, "float32", persistable=True,
+                              name=name)
+    total.stop_gradient = True
+    helper.append_op(type="step_sum", inputs={"X": [x], "Sum": [total]},
+                     outputs={"SumOut": [total]})
+    return total
+
+
 def slice(input, axes, starts, ends, name=None):
     """fluid.layers.slice parity (slice_op.cc)."""
     helper = LayerHelper("slice", name=name)

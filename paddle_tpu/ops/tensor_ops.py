@@ -296,6 +296,17 @@ def _increment(ctx, op):
     ctx.set_out(op, "Out", x + jnp.asarray(op.attr("step", 1.0), x.dtype))
 
 
+@register("step_sum")
+def _step_sum(ctx, op):
+    """SumOut = Sum [1] float32 + the scalar X in a train run, Sum in a
+    for_test clone's: a counter of the program's own, so no gradient."""
+    total = ctx.in1(op, "Sum")
+    if not (op.attr("is_test", False) or ctx.is_test):
+        total = total + jax.lax.stop_gradient(
+            ctx.in1(op, "X")).astype(total.dtype).reshape(total.shape)
+    ctx.set_out(op, "SumOut", total)
+
+
 @register("multiplex")
 def _multiplex(ctx, op):
     ids = ctx.in1(op, "Ids").astype(jnp.int32).reshape(-1)

@@ -516,7 +516,11 @@ def ops(root=None, backward=None):
     as the trace saw them; ``weights``: the inputs that are parameters;
     ``region``: the index of the ``layers.recompute`` region the op
     sits in, else None; ``kept``: ``"mul_out"`` where the region's plan
-    keeps the op's result for the backward, else None. A ``mul`` or
+    keeps the op's result for the backward, else None; ``module``: the
+    name of the ``layers.module`` context the op was built in, else
+    None (a region's second forward and the backward run under the
+    forward op's scope, so their device ops find the same row). A
+    ``mul`` or
     ``matmul`` row also has ``mkn`` (the flattened ``[M, K] x [K, N]``
     the product runs at), ``operand_dtype`` (after AMP's cast) and
     ``grads``: ``"x"`` / ``"w"`` for each operand the step

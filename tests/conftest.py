@@ -119,6 +119,12 @@ _PINS_THE_BENCHMARKS_END = {
         "appended the cell whose eleven rms_norm ops no other reader "
         "reads. Its assertion runs in test_chipbench_olmo_hybrid.py::"
         "test_a_pinned_entry_is_as_its_pr_left_it",
+    "test_chipbench_olmo_hybrid.py::test_a_pinned_entry_is_as_its_pr_left_it"
+    "[test_chipbench_oplog-test_the_entries_in_benchmark_json]":
+        "runs PR 51's pin against the benchmark less what PR 53 appended; "
+        "PR 55 appended a cell and two metrics more. The pin's assertions "
+        "run in test_chipbench_joyai.py::"
+        "test_pr_51s_pinned_entries_are_as_their_pr_left_them",
 }
 
 

@@ -338,3 +338,92 @@ def test_checkpoints_backward_names_its_second_forward(limit):
     # the backward's two products, one a gradient
     assert "dot_general" in prim(backward) and "tanh" not in prim(backward)
     assert "transpose" in prim(backward) or "mul" in prim(backward)
+
+
+# -- a row says which module of the model built its op (ISSUE 55) -------------
+
+def _two_headed(prefix):
+    """A stream, one region and a head, then inside
+    ``layers.module("second")`` one more region and the SAME head and
+    table once more, a second loss added to the cost, and both terms
+    summed over the train steps (``layers.step_sum``)."""
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    L = fluid.layers
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            unique_name.guard(prefix):
+        ids = L.data("ids", [8, 1], dtype="int64")
+        table = fluid.ParamAttr(name="word_emb")
+        fc = lambda x, name: L.fc(x, 16, num_flatten_dims=2, act="tanh",
+                                  bias_attr=False,
+                                  param_attr=fluid.ParamAttr(name=name))
+        x = L.embedding(ids, size=[32, 16], param_attr=table)
+        with L.recompute():
+            x = L.elementwise_add(x, fc(x, "first_w"))
+        head = lambda x: L.mean(L.square(L.tied_head(
+            x, main.global_block().var("word_emb"))))
+        first = head(x)
+        L.step_sum(first, "first_loss_sum")
+        with L.module("second"):
+            again = L.embedding(ids, size=[32, 16], param_attr=table)
+            with L.recompute():
+                x = L.elementwise_add(x, fc(again, "second_w"))
+            second = head(x)
+            L.step_sum(second, "second_loss_sum")
+        loss = L.elementwise_add(first, L.scale(second, 0.5))
+        forward = main.clone(for_test=True)
+        fluid.optimizer.SGD(1.0).minimize(loss)
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+    return main, startup, forward, scope, {
+        "ids": np.arange(32).reshape(4, 8, 1) % 32}, (loss, first, second)
+
+
+def test_a_row_says_which_module_built_its_op(limit):
+    main, startup, forward, scope, feeds, (loss, first, second) = \
+        _two_headed("m_")
+    # a table shared by name is initialised ONCE
+    inits = [n for op in startup.global_block().ops for n in op.output_names]
+    assert inits.count("word_emb") == 1
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        table, w1, w2 = (np.array(scope.find_var(n))
+                         for n in ("word_emb", "first_w", "second_w"))
+        terms = exe.run(main, feed=feeds, fetch_list=[first, second])
+        moved = table - np.array(scope.find_var("word_emb"))
+    _, rows = trace.ops(root="exe.step", backward=True)
+    by_module = {}
+    for r in rows:
+        by_module.setdefault(r["module"], []).append(r)
+    assert set(by_module) == {None, "second"}
+    # a region's ops inside the module say so with their region, and
+    # the ops round the module say nothing
+    assert {(r["type"], r["region"]) for r in by_module["second"]} >= {
+        ("lookup_table", None), ("mul", 1), ("tanh", 1), ("mul", None),
+        ("step_sum", None)}
+    assert {r["region"] for r in by_module[None]} == {None, 0}
+    assert {r["type"] for r in by_module[None]} >= {"sgd", "step_sum"}
+    weights = lambda rs: sorted(w for r in rs if r["type"] == "mul"
+                                for w in r["weights"])
+    assert weights(by_module["second"]) == ["second_w", "word_emb"]
+    assert weights(by_module[None]) == ["first_w", "word_emb"]
+    # the for_test clone's ops carry it too, and its run adds nothing
+    # to the sums a train run adds to
+    with fluid.scope_guard(scope):
+        exe.run(forward, feed=feeds, fetch_list=[loss])
+        sums = [float(np.asarray(scope.find_var(n))[0])
+                for n in ("first_loss_sum", "second_loss_sum")]
+    assert sums == pytest.approx([float(t) for t in terms], rel=1e-6)
+    _, rows = trace.ops(root="exe.step", backward=False)
+    assert {r["module"] for r in rows} == {None, "second"}
+
+    # one parameter, two uses: at rate 1 the table's step is the sum of
+    # its four gradients (two look-ups, two heads)
+    def cost(table):
+        ids = feeds["ids"][..., 0]
+        x = table[ids]
+        x = x + jnp.tanh(x @ w1)
+        head = lambda x: jnp.mean(jnp.square(x @ table.T))
+        return head(x) + 0.5 * head(x + jnp.tanh(table[ids] @ w2))
+
+    np.testing.assert_allclose(moved, jax.grad(cost)(jnp.asarray(table)),
+                               rtol=2e-4, atol=1e-6)

@@ -880,6 +880,7 @@ COVERED_ELSEWHERE = {
     "qk_norm_rope": "test_block_diffusion.py",
     "mla_attention": "test_latent_moe.py",
     "hyper_connection": "test_latent_moe.py",
+    "step_sum": "test_op_ledger.py",
     "causal_attention": "test_windowed_moe.py",
     "sigmoid_mul": "test_windowed_moe.py",
     "diff_attention": "test_hybrid_ssm.py",
