@@ -27,11 +27,12 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           as the two, beside the form it replaced (two calls merged by
           lse, the own blocks as dense math)
   mla     the two-part score of latent attention through the streamed
-          kernels at the cell xing4_train_T4k's shape (one sequence of
-          [4096, 32 x 128] with q_pe [4096, 32 x 64] reading ONE k_pe
-          [4096, 64], values 128 wide): dq_nope, dq_pe, dk_nope, dk_pe
-          (summed over the 32 heads in the kernel) and dv against dense
-          float32 math
+          kernels at the shapes of the cells xing4_train_T4k and
+          joyai_train_T8k (one sequence of [T, 32 x 128] with q_pe
+          [T, 32 x 64] reading ONE k_pe [T, 64], values 128 wide, T 4096
+          and 8192): dq_nope, dq_pe, dk_nope, dk_pe (summed over the 32
+          heads) and dv against dense float32 math, the backward as ONE
+          kernel (ISSUE 56) and as the two, both timed
   window  a window bound in the streamed kernels (ISSUE 38): q
           [2, 4096, 32 x 128] reading k/v [2, 4096, 4 x 128] under a
           window of 2048 keys: output, dq, dk, dv against dense float32
@@ -532,54 +533,92 @@ def phase_own_block(seed, rehearse):
 
 
 def phase_mla(seed, rehearse):
-    """The kernels of the latent-attention step (ISSUE 34): a score of
-    two parts, q_nope k_nope^T over 128 lanes a head plus q_pe k_pe^T
-    over 64 lanes against ONE key that all 32 heads read, values 128
-    wide, T 4096 streamed, causal; every gradient against dense float32
-    math with the shared key broadcast in the einsum alone."""
+    """The kernels of the latent-attention step (ISSUEs 34 and 56): a
+    score of two parts, q_nope k_nope^T over 128 lanes a head plus
+    q_pe k_pe^T over 64 lanes against ONE key that all 32 heads read,
+    values 128 wide, causal, streamed, at the cells' two lengths
+    (`xing4_train_T4k`'s 4096 and `joyai_train_T8k`'s 8192); every
+    gradient against dense float32 math with the shared key broadcast
+    in the einsum alone (eight heads at a time at T 8192), through the
+    ONE backward kernel `flash_bwd` and, with no shape within its byte
+    bound, through `flash_bwd_dq` + `flash_bwd_dkv`; then both timed
+    under the profiler."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import flash_attention as fa
-    b, t, h, d, d2 = (1, 256, 4, 128, 64) if rehearse else (
-        1, 4096, 32, 128, 64)
-    rng = np.random.RandomState(seed)
-    mk = lambda lanes: jnp.asarray(rng.randn(b, t, lanes) * 0.5,
-                                   jnp.bfloat16)
-    q, k, v, q2, k2, dy = mk(h * d), mk(h * d), mk(h * d), mk(h * d2), \
-        mk(d2), mk(h * d)
+    b, h, d, d2 = (1, 4, 128, 64) if rehearse else (1, 32, 128, 64)
     f32 = lambda x: x.astype(jnp.float32)
     scale = (d + d2) ** -0.5 * (0.1 * math.log(64) + 1) ** 2
-
-    def kernel(q, k, v, q2, k2):
-        out = fa.flash_bthd(q, k, v, h, causal=True, scale=scale, q2=q2,
-                            k2=k2)
-        return (f32(out) * f32(dy)).sum()
-
-    def dense(q, k, v, q2, k2):
-        o, _ = fa._dense_lse(*(fa.heads_first(x, h) for x in (q, k, v)),
-                             True, scale, (0, 0), fa.heads_first(q2, h), k2)
-        return (fa.heads_last(o) * f32(dy)).sum()
-
-    t0 = time.perf_counter()
     wrt = (0, 1, 2, 3, 4)
-    grad = jax.jit(jax.grad(kernel, wrt)).lower(q, k, v, q2, k2).compile()
-    got, text = grad(q, k, v, q2, k2), grad.as_text()
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.grad(dense, wrt))(*(f32(x) for x in
-                                               (q, k, v, q2, k2)))
-    errs = [float(jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
-            for a, r in zip(got, want)]
-    log("[mla] q/k/v [%d, %d, %d] q_pe [.., %d] k_pe [.., %d] bf16 causal: "
-        "dq_nope %.3e dk_nope %.3e dv %.3e dq_pe %.3e dk_pe %.3e from the "
-        "dense float32 gradients (%.1f s); tpu_custom_call sites %d" % (
-            b, t, h * d, h * d2, d2, *errs, time.perf_counter() - t0,
-            text.count("tpu_custom_call")))
-    assert max(errs) <= FLASH_GRAD_TOL, errs
-    if not rehearse:
-        assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    for t in (256, 512) if rehearse else (4096, 8192):
+        rng = np.random.RandomState(seed + t)
+        mk = lambda lanes: jnp.asarray(rng.randn(b, t, lanes) * 0.5,
+                                       jnp.bfloat16)
+        q, k, v, q2, k2, dy = mk(h * d), mk(h * d), mk(h * d), \
+            mk(h * d2), mk(d2), mk(h * d)
+
+        def kernel(q, k, v, q2, k2):
+            out = fa.flash_bthd(q, k, v, h, causal=True, scale=scale, q2=q2,
+                                k2=k2)
+            return (f32(out) * f32(dy)).sum()
+
+        # the dense side `part` heads at a time (32 heads of 4096^2
+        # float32 scores are what the device holds beside them): q's, k's,
+        # v's and q_pe's gradients are the parts side by side, k_pe's
+        # their sum
+        part = max(1, min(h, h * 4096 ** 2 // t ** 2))
+
+        def dense(q, k, v, q2, k2, dy):
+            o, _ = fa._dense_lse(
+                *(fa.heads_first(x, part) for x in (q, k, v)), True, scale,
+                (0, 0), fa.heads_first(q2, part), k2)
+            return (fa.heads_last(o) * dy).sum()
+
+        t0 = time.perf_counter()
+        compile_grad = lambda: jax.jit(jax.grad(kernel, wrt)).lower(
+            q, k, v, q2, k2).compile()
+        with _backwards_lowered(fa) as ran:
+            grad = compile_grad()
+        with _two_kernels(fa):
+            grad_two = compile_grad()
+        got, text = grad(q, k, v, q2, k2), grad.as_text()
+        dense_grad = jax.jit(jax.grad(dense, wrt))
+        heads = lambda x, a, w: f32(x[..., a * w:(a + part) * w])
+        with jax.default_matmul_precision("highest"):
+            parts = [dense_grad(heads(q, a, d), heads(k, a, d),
+                                heads(v, a, d), heads(q2, a, d2), f32(k2),
+                                heads(dy, a, d)) for a in range(0, h, part)]
+        want = [jnp.concatenate(xs, -1) for xs in list(zip(*parts))[:4]] + [
+            sum(x[4] for x in parts)]
+        errs = _far(got, want)
+        errs_two = _far(grad_two(q, k, v, q2, k2), want)
+        log("[mla] q/k/v [%d, %d, %d] q_pe [.., %d] k_pe [.., %d] bf16 "
+            "causal, backward %s: dq_nope %.3e dk_nope %.3e dv %.3e dq_pe "
+            "%.3e dk_pe %.3e from the dense float32 gradients (the two "
+            "kernels: %.3e %.3e %.3e %.3e %.3e) (%.1f s); tpu_custom_call "
+            "sites %d" % (b, t, h * d, h * d2, d2, "+".join(ran), *errs,
+                          *errs_two, time.perf_counter() - t0,
+                          text.count("tpu_custom_call")))
+        assert max(errs + errs_two) <= FLASH_GRAD_TOL, (errs, errs_two)
+        if rehearse:
+            continue
+        # the ONE kernel within its byte bound, the two it replaced where
+        # no shape is
+        assert ran == ["fused_streamed"], ran
+        assert "flash_bwd" in text and "flash_bwd_dq" not in text \
+            and "flash_bwd_dkv" not in text
+        two = grad_two.as_text()
+        assert "flash_bwd_dq" in two and "flash_bwd_dkv" in two
         # nothing of k_pe's size times the heads, and no operand padded
         # to 256 lanes a head, is made round the kernels
         assert "%d,%d]" % (t, h * 2 * d) not in text
+        for label, call in (("fused_streamed", grad),
+                            ("two_kernels", grad_two)):
+            ms, _, ops = _device_ms(call, (q, k, v, q2, k2), 6, rehearse,
+                                    "mla_%s_T%d" % (label, t))
+            log("[mla] T %d %s: forward + backward %.3f ms a layer on the "
+                "device (%s)" % (t, label, ms, ", ".join(
+                    "%s %.3f" % kv for kv in ops.most_common(6))))
 
 
 def phase_window(seed, rehearse):

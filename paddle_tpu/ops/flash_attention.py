@@ -8,8 +8,8 @@ VMEM; backward recomputes probabilities from the saved log-sum-exp rows
 in ONE kernel (flash_bwd), each tile's s, p, dp and ds computed once and
 dq, dk, dv all made from them: where a block holds all of T (PR 31) and
 where T is streamed, dq for all rows held in VMEM across the key blocks
-(ISSUE 39). The standard two-kernel dq / dk+dv scheme is left for a
-score of two parts and for a T whose dq VMEM cannot hold.
+(ISSUE 39), a score of two parts included (ISSUE 56). The standard
+two-kernel dq / dk+dv scheme is left for a T whose dq VMEM cannot hold.
 
 Reference capability: the reference's attention is composed matmul +
 softmax ops (nets.py:168 scaled_dot_product_attention,
@@ -98,9 +98,9 @@ T 16,384 dq is 8 MB and its output block 4 MB twice, which the 16 MB
 default would not hold).
 "two_kernels", flash_bwd_dq then flash_bwd_dkv, each recomputing s and
 dp, where what would stay resident passes _RESIDENT_DQ_BYTES (T above
-32,768 at one head of 128 in bf16: ring attention's longest shards) and
-for a score of two parts, whose flash_bwd_dkv runs ALL heads under one
-resident block of k2.
+32,768 at one head of 128 in bf16: ring attention's longest shards; a
+score of two parts keeps a pair of heads' dq and dq2, so T above 8,192
+at a second part of 64 in bf16).
 
 Dispatch: `flash_bthd(q, k, v, n_head, causal, scale)` uses the kernel
 on TPU and the dense jnp math elsewhere (CPU tests exercise the kernel
@@ -115,9 +115,11 @@ mask, kv_groups, key_width, value_width, second_part}` (backward:
 A score of two parts (PR 34): `flash_bthd(..., q2=, k2=)` adds
 `q2_h k2^T` to head h's scores, k2 ONE key [B, T, D2] that every head
 reads (latent attention's rotary part): key D + D2 wide, value D. The
-streamed forward and the two backward kernels take it on what they
-are handed (`part2`; see "A score of two parts" below); handed no
-second part they trace exactly what they traced before.
+streamed forward, the ONE streamed backward kernel (ISSUE 56: eight
+matmuls a tile where the two kernels run eleven) and the two backward
+kernels take it on what they are handed (`part2`; see "A score of two
+parts" below); handed no second part they trace exactly what they
+traced before.
 
 The own-block mask form (PR 37): `flash_bthd(..., causal=True,
 mask_block=m, own_block=True)` is block diffusion's whole attention.
@@ -208,6 +210,8 @@ _ONE_BLOCK_BYTES = 512 * 1024   # a [T, D] operand in VMEM this small: one block
 # heads' first step to its last. A quarter of a v5e core's 128 MiB:
 # T 16,384 at one head of 128 in bf16 is 16 MiB, T 32,768 the last that
 # fits; longer (ring attention's longest shards) takes the two kernels.
+# A score of two parts keeps a pair of heads' dq and their dq2: 24 MiB at
+# T 8,192 with a second part of 64 in bf16, the last that fits.
 _RESIDENT_DQ_BYTES = 32 * 1024 * 1024
 _TILE = 256                     # a panel's rows on the diagonal
 _PANEL_SCORES = 1024 * 1024     # an unmasked panel's scores (4 MB in float32)
@@ -778,11 +782,11 @@ def _fwd_pallas(q, k, v, n_head, n_kv_head, mask, scale, block_q, block_k,
 # kernel, flash_bwd, wherever dq for all rows of a block of heads can
 # stay in VMEM: a panel's s, p, dp and ds are computed once and dq, dk
 # and dv all come out of them, five matmuls a tile: _bwd_fused_kernel
-# where one block holds all of T, _bwd_one_kernel where T is streamed.
-# Beyond its byte bound, and for a score of two parts, the two kernels:
-# flash_bwd_dq walks a q block's keys and flash_bwd_dkv a key block's
-# queries, each recomputing s and dp (seven matmuls for five useful, two
-# exp a score for one).
+# where one block holds all of T, _bwd_one_kernel where T is streamed
+# (and for every T of a score of two parts). Beyond its byte bound the
+# two kernels: flash_bwd_dq walks a q block's keys and flash_bwd_dkv a
+# key block's queries, each recomputing s and dp (seven matmuls for five
+# useful, eleven for eight under two parts, two exp a score for one).
 def _delta(dy_ref, o_ref, dlse_ref, a, d, g):
     """Column [rows, 1] of head `a`'s delta for the block's rows, and
     where the head's lanes are in a [rows, W] tile. The lse output's
@@ -845,7 +849,7 @@ def _bwd_fused_kernel(*refs, mask, scale, t, tile, d, g, has_dlse):
 
 
 def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
-                    blocks_q, d, g, has_dlse, band=None):
+                    blocks_q, d, g, has_dlse, band=None, part2=None):
     """flash_bwd, grid (B * H / g, nK, steps_q), the q blocks innermost:
     one block of heads. Walks by keys with the scores transposed
     [tk, tq], as flash_bwd_dkv does, so that dv = p^T dy and dk = ds^T q
@@ -865,10 +869,29 @@ def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
     queries in a block, the keys in several) they finish inside their
     panel and there is no such scratch. `nq` is what the inner axis
     counts (the band's steps under a window), `blocks_q` the q blocks
-    there are."""
+    there are.
+
+    A score of two parts (`part2` = (D2, g2, H); one head to a block,
+    see "A score of two parts" below): grid (B * H / g2, nK, g2 * nQ),
+    the g2 heads that share a 128-lane block of q2 taking turns under
+    one key block, each walking its q blocks. st takes `kk2 q2^T` on
+    top, k2 being the shared key under THIS head's lanes of the block
+    alone, so that q2's other lanes meet zeros, `dq2 += dst^T k2` comes
+    out in this head's lanes of a float32 scratch that all g2 heads add
+    into (resident as dq is, stored with it), and `dk2 += dst q2` keeps
+    them by a lane select. dq and delta have a slot a head of the pair;
+    dq's output block holds the pair's g2 * D lanes. dk2, the sum over
+    ALL heads of one key, cannot stay resident with the heads on the
+    outer axis: it is summed over the pair's steps in float32 scratch
+    and written as the pair's float32 partial, which _bwd_pallas2 adds
+    up after the kernel."""
     q_ref, k_ref, v_ref, dy_ref, o_ref, lse_ref = refs[:6]
     dlse_ref = refs[6] if has_dlse else None
-    if _own(mask):
+    if part2:
+        (q2_ref, k2_ref, dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref,
+         *scratch) = refs[6 + has_dlse:]
+        *scratch, dq2_s, dk2_s = scratch
+    elif _own(mask):
         # as in flash_bwd_dkv: the noised keys and values of the key
         # block's rows, and their gradients, whole at the step i == j
         (kn_ref, vn_ref, dq_ref, dk_ref, dv_ref, dkn_ref, dvn_ref,
@@ -877,11 +900,26 @@ def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
         dq_ref, dk_ref, dv_ref, *scratch = refs[6 + has_dlse:]
     dq_s, *dkv_s, delta_s = scratch
     i, j = _block_ids(nq, nk, by_keys=True)
+    if part2:
+        a2, i = _unfold(pl.program_id(2), part2[1], nq)
     # the q block of this step, and whether no key block came to it yet
     at, first = i, j == 0
     if band:
         at = jnp.minimum(i + j, blocks_q - 1)
         first = (first | (i == nq - 1)) & (i + j < blocks_q)
+    slot = at       # where this head keeps its dq and delta
+    if part2:
+        slot = a2 * nq + at
+        # the pair's first and last head under its resident blocks
+        opens, closes = a2 == 0, a2 == part2[1] - 1
+
+        @_when(opens & (i == 0))
+        def _init2():
+            dk2_s[:] = jnp.zeros_like(dk2_s)
+
+        @_when(opens & first)
+        def _first2():
+            dq2_s[at] = jnp.zeros(dq2_s.shape[1:], dq2_s.dtype)
 
     if dkv_s:
         dk_s, dv_s = dkv_s
@@ -893,13 +931,13 @@ def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
 
     @_when(first)
     def _first():
-        dq_s[at] = jnp.zeros(dq_s.shape[1:], dq_s.dtype)
+        dq_s[slot] = jnp.zeros(dq_s.shape[1:], dq_s.dtype)
 
     def head(a):
         @_when(first)
         def _row():
             delta, _ = _delta(dy_ref, o_ref, dlse_ref, a, d, g)
-            delta_s[at, a] = delta.T                 # [tq, 1] -> [1, tq]
+            delta_s[slot, a] = delta.T               # [tq, 1] -> [1, tq]
 
         def panel(cols, segments):
             own = _own(segments[0][2])      # the noised keys' own panel
@@ -910,19 +948,34 @@ def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
             k = _only(k, mine)
             kk = k * scale
             v = _only((vn_ref if own else v_ref)[0, cols, :], mine)
-            dk = dv = 0.0
+            if part2:
+                k2 = k2_ref[0, cols, :]
+                mine2 = _lanes(k2.shape, a2, *part2[:2])
+                k2 = _only(k2, mine2)
+                kk2 = k2 * scale
+            dk = dv = dk2 = 0.0
             for rows, off, how in segments:
                 q = q_ref[0, rows, :]
                 dy = dy_ref[0, rows, :]
                 st = _dot(kk, q, _NT)                # [tk, tq]
+                if part2:
+                    q2 = q2_ref[0, rows, :]
+                    st = st + _dot(kk2, q2, _NT)
                 if off is not None:
                     st = _causal(st, off, 1, how)
                 pt = jnp.exp(st - lse_ref[a, :, rows])   # row [1, tq]
                 dv = dv + _dot(pt.astype(dy.dtype), dy, _NN)
-                dst = pt * (_dot(v, dy, _NT) - delta_s[at, a, :, rows])
-                dk = dk + _dot(dst.astype(q.dtype), q, _NN)
-                dq_s[at, rows] = dq_s[at, rows] + _dot(
-                    dst.T.astype(k.dtype), k, _NN)
+                dst = pt * (_dot(v, dy, _NT) - delta_s[slot, a, :, rows])
+                ds = dst.astype(q.dtype)
+                dk = dk + _dot(ds, q, _NN)
+                dq = dq_s[slot, rows]
+                turned = dst.T.astype(k.dtype)
+                dq_s[slot, rows] = dq + _dot(turned, k, _NN)
+                if part2:
+                    dk2 = dk2 + _dot(ds, q2, _NN)
+                    dq2_s[at, rows] = dq2_s[at, rows] + _dot(turned, k2, _NN)
+            if part2:
+                dk2_s[cols] = dk2_s[cols] + _only(dk2, mine2)
             if own or not dkv_s:
                 to_k, to_v = (dkn_ref, dvn_ref) if own else (dk_ref, dv_ref)
                 whole = (0, cols, slice(None))
@@ -942,11 +995,27 @@ def _bwd_one_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk,
             dk_ref[0] = (dk_s[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
 
-    @_when((i == nq - 1) & (j == nk - 1))
+    if part2:
+        @_when(closes & (i == nq - 1))
+        def _final2():
+            dk2_ref[0] = dk2_s[:] * scale
+
+    last = (i == nq - 1) & (j == nk - 1)
+    if part2:
+        last = closes & last
+
+    @_when(last)
     def _store_dq():
+        # the q blocks down the rows; a pair's heads side by side
+        w = g * d
         for n in range(blocks_q):
-            dq_ref[0, n * block_q:(n + 1) * block_q, :] = (
-                dq_s[n] * scale).astype(dq_ref.dtype)
+            rows = slice(n * block_q, (n + 1) * block_q)
+            for n2 in range(dq_s.shape[0] // blocks_q):
+                dq_ref[0, rows, n2 * w:(n2 + 1) * w] = (
+                    dq_s[n2 * blocks_q + n] * scale).astype(dq_ref.dtype)
+            if part2:
+                dq2_ref[0, rows, :] = (dq2_s[n] * scale).astype(
+                    dq2_ref.dtype)
 
 
 def _bwd_dq_kernel(*refs, mask, scale, block_q, block_k, tile, nq, nk, d,
@@ -1129,26 +1198,31 @@ def _backward_blocks(t, w, block_q, block_k):
     return bq, bk
 
 
-def _backward_of(t, w, block_q, block_k, mask=None, itemsize=2):
-    """Which backward a one-part call of `t` rows (a HALF of them under
-    the own-block form) runs, from its shapes alone: what _bwd_pallas is
+def _backward_of(t, w, block_q, block_k, mask=None, itemsize=2, second=0):
+    """Which backward a call of `t` rows (a HALF of them under the
+    own-block form) runs, from its shapes alone: what _bwd_pallas is
     told and the lowering counter says. The one kernel where what it
     keeps in VMEM for all rows of a block of heads, dq in float32 and
     its output block twice, is within _RESIDENT_DQ_BYTES: "fused" where
     the backward's blocks hold all of T (always within: a block is no
     larger than _ONE_BLOCK_BYTES; the own-block form's rows are two
     halves, so never one block), "fused_streamed" where T is streamed;
-    else "two_kernels"."""
-    if not _own(mask) and _backward_blocks(t, w, block_q, block_k) == (t, t):
+    else "two_kernels". A score of two parts (`second`: the lanes of
+    q2's block; `w` then the lanes of the heads that share it) keeps
+    dq2 resident beside dq and has no kernel for one block apart: the
+    streamed one runs it."""
+    if not (_own(mask) or second) and _backward_blocks(
+            t, w, block_q, block_k) == (t, t):
         return "fused"
     rows = 2 * t if _own(mask) else t
-    if rows * max(w, _LANES) * (4 + 2 * itemsize) <= _RESIDENT_DQ_BYTES:
+    if rows * (max(w, _LANES) + second) * (
+            4 + 2 * itemsize) <= _RESIDENT_DQ_BYTES:
         return "fused_streamed"
     return "two_kernels"
 
 
 def _one_kernel_vmem_bytes(t, w, bq, bk, g, itemsize, kv_itemsize, n_kv,
-                           n_stats):
+                           n_stats, heads=1, w2=0):
     """The scoped VMEM the streamed flash_bwd asks for, from its shapes:
     dq for all rows in float32 and its output block, each operand and
     output block twice (the pipeline's two buffers), the float32 scratch
@@ -1159,12 +1233,15 @@ def _one_kernel_vmem_bytes(t, w, bq, bk, g, itemsize, kv_itemsize, n_kv,
     limit it accepted, compiled for a described v5e: 28 MiB at T 16,384,
     23 under the own-block form at 2 x 4,096, 15 at T 4,096; PR 39), and
     a larger limit costs the kernel nothing: the same device time under
-    36 MiB and under 70."""
+    36 MiB and under 70. A score of two parts: `heads` heads of `w`
+    lanes share a q2 block of `w2` lanes; dq of each and dq2 stay
+    resident, q2 and k2 are two operand blocks more and dk2 one float32
+    output block with its scratch."""
     w = max(w, _LANES)
-    resident = t * w * (4 + 2 * itemsize)
-    blocks = 2 * w * itemsize * (3 * bq + n_kv * bk)
-    grads = n_kv * bk * w * 2 * kv_itemsize + 2 * bk * w * 4
-    stats = g * 8 * 4 * (2 * n_stats * bq + t)
+    resident = t * (heads * w + w2) * (4 + 2 * itemsize)
+    blocks = 2 * itemsize * (w * (3 * bq + n_kv * bk) + w2 * (bq + bk))
+    grads = n_kv * bk * w * 2 * kv_itemsize + 2 * bk * w * 4 + 3 * bk * w2 * 4
+    stats = heads * g * 8 * 4 * (2 * n_stats * bq + t)
     panel = 4 * 4 * min(bq * bk, max(_PANEL_SCORES, _LANES * bk))
     return resident + blocks + grads + stats + panel
 
@@ -1201,6 +1278,16 @@ def _backward_for(q, n_head, mask, block_q, block_k):
     return _backward_of(t // 2 if _own(mask) else t,
                         heads_per_block(n_head, d) * d, block_q, block_k,
                         mask, q.dtype.itemsize)
+
+
+def _backward2_for(q, q2, n_head, block_q, block_k):
+    """_backward_of for q [B, T, H*D] and q2 [B, T, H*D2] of a two-part
+    call: the heads that share a block of q2 stay resident together."""
+    t, hd = q.shape[1:]
+    g2 = _part2_of(n_head, q2)[1]
+    return _backward_of(t, g2 * hd // n_head, block_q, block_k,
+                        itemsize=q.dtype.itemsize,
+                        second=g2 * q2.shape[-1] // n_head)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8, 9))
@@ -1358,13 +1445,20 @@ def _bwd_pallas(res, dy, n_head, n_kv_head, mask, scale, block_q, block_k,
 # with the shared key REPEATED under each of the g2 lane groups (a
 # [B, T, 128] operand that _attend makes outside the custom_vjp, whose
 # transpose folds dk2's lane groups back). The kernels are the streamed
-# three with `part2` = (D2, g2, H) set, under the same names; a block is
+# ones with `part2` = (D2, g2, H) set, under the same names; a block is
 # one head (D a multiple of 128). Their grids differ from the one-part
-# grids where an output is shared between heads: flash_bwd_dq runs the
-# g2 heads of a q2 block one after the other, so that dq2's block stays
-# in VMEM while each stores its lanes; flash_bwd_dkv runs ALL heads
-# under one resident block of k2 and sums dk2 over them in float32
-# scratch, so the sum over 32 heads never exists in HBM a head at a time.
+# grids where an output is shared between heads. The ONE backward
+# kernel flash_bwd (ISSUE 56; _bwd_one_kernel has the account) runs the
+# g2 heads of a q2 block in turn under each key block, grid
+# (B * H / g2, nK, g2 * nQ): dq of both heads and dq2 stay in VMEM for
+# all of T (T x 128 x 24 bytes at g2 2 in bf16: 25 MB at T 8,192), and
+# dk2 leaves the kernel as float32 partials [B * H / g2, T, 128], a
+# pair of heads each, that XLA adds up (16 x 8192 x 128 x 4 B = 67 MB
+# written and read a call at T 8,192). Beyond the byte bound the two
+# kernels: flash_bwd_dq runs the g2 heads of a q2 block one after the
+# other, so that dq2's block stays in VMEM while each stores its lanes;
+# flash_bwd_dkv runs ALL heads under one resident block of k2 and sums
+# dk2 over them in float32 scratch.
 def _part2_of(n_head, q2):
     d2 = q2.shape[-1] // n_head
     g2 = max(_LANES // d2, 1)
@@ -1430,8 +1524,10 @@ def _fwd_pallas2(q, k, v, q2, k2, n_head, mask, scale, block_q, block_k,
     return out, lse.reshape(b, n_head, t)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
-def _bwd_pallas2(res, dy, n_head, mask, scale, block_q, block_k, interpret):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
+def _bwd_pallas2(res, dy, n_head, mask, scale, block_q, block_k, interpret,
+                 backward):
+    """`backward`: _backward_of's word for these shapes (_backward2_for)."""
     q, k, v, q2, k2, o, lse = res
     b, t, hd = q.shape
     d = hd // n_head
@@ -1444,6 +1540,48 @@ def _bwd_pallas2(res, dy, n_head, mask, scale, block_q, block_k, interpret):
     stat_shape = jax.ShapeDtypeStruct((b * n_head, 1, t), jnp.float32)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
     pairs = n_head // g2
+
+    if backward == "fused_streamed":
+        def fold(s):
+            a2, i = _unfold(s[2], g2, nq)
+            return s[0] // pairs, s[0] % pairs * g2 + a2, i, s[1]
+
+        rows, keys, stat, rows2, keys2 = _specs2(n_head, g2, w2, fold)
+        # what stays in VMEM from a pair's first step to its last: dq,
+        # the pair's heads side by side, and dq2, both for all of T
+        whole = lambda w: pl.BlockSpec(
+            (1, t, w), lambda *s: (s[0] // pairs, 0, s[0] % pairs))
+        dq, dk, dv, dq2, dk2 = pl.pallas_call(
+            functools.partial(_bwd_one_kernel, mask=mask, scale=scale,
+                              block_q=bq, block_k=bk, nq=nq, nk=nk,
+                              blocks_q=nq, tile=tile, d=d, g=1,
+                              has_dlse=False, part2=part2),
+            grid=(b * pairs, nk, g2 * nq),
+            in_specs=[rows(bq, d), keys(bk, d), keys(bk, d), rows(bq, d),
+                      rows(bq, d), stat(bq), rows2(bq), keys2(bk)],
+            out_specs=[whole(g2 * d), keys(bk, d), keys(bk, d), whole(w2),
+                       pl.BlockSpec((1, bk, w2), lambda *s: (s[0], s[1], 0))],
+            out_shape=[like(q), like(k), like(v), like(q2),
+                       jax.ShapeDtypeStruct((b * pairs, t, w2), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((g2 * nq, bq, d), jnp.float32)]
+            + [pltpu.VMEM((bk, d), jnp.float32)] * (2 * (nq > 1))
+            + [pltpu.VMEM((g2 * nq, 1, 1, bq), jnp.float32),
+               pltpu.VMEM((nq, bq, w2), jnp.float32),
+               pltpu.VMEM((bk, w2), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_one_kernel_vmem_bytes(
+                    t, d, bq, bk, 1, q.dtype.itemsize, k.dtype.itemsize, 2,
+                    1, g2, w2)),
+            interpret=interpret,
+            name="flash_bwd",
+        )(q, k, v, dy, o, lse3, q2, k2)
+        # the pairs' partials of the one shared key, added as slices
+        # (_group_sum's way: a reduce over a reshaped view is a copy
+        # into another tiling first)
+        dk2 = dk2.reshape(b, pairs, t, w2)
+        dk2 = functools.reduce(
+            jnp.add, [dk2[:, n] for n in range(pairs)]).astype(k2.dtype)
+        return dq, dk, dv, dq2, dk2
 
     def fold_dq(s):
         a2, j = _unfold(s[2], g2, nk)
@@ -1523,7 +1661,9 @@ def _flash2_fwd(q, k, v, q2, k2, *static):
 
 def _flash2_bwd(*args):
     *static, res, dy = args
-    return _bwd_pallas2(res, dy, *static)
+    n_head, _, _, block_q, block_k, _ = static
+    return _bwd_pallas2(res, dy, *static, _backward2_for(
+        res[0], res[3], n_head, block_q, block_k))
 
 
 _flash2.defvjp(_flash2_fwd, _flash2_bwd)
@@ -1616,9 +1756,9 @@ _LOWERINGS = _REG.counter(
     "none a step): the path taken, the layout of the entry called, the "
     "heads a kernel block holds, the backward its gradient would run "
     "(fused: one kernel, all of T in a block; fused_streamed: one "
-    "kernel too, T streamed and dq for all rows held in VMEM; two_kernels: "
-    "dq, then dk and dv, for a second score part or a T over that "
-    "kernel's byte bound; none: dense math), "
+    "kernel too, T streamed and dq for all rows held in VMEM, with or "
+    "without a second score part; two_kernels: dq, then dk and dv, for a "
+    "T over that kernel's byte bound; none: dense math), "
     "the mask (none, causal, block_causal, block_causal_strict, "
     "block_causal_own: block diffusion's [noised; clean] halves), the "
     "query heads that read one key/value head, a head's key and value "
@@ -1788,7 +1928,8 @@ def _attend(q, k, v, n_head, causal, scale, block_q, block_k, force,
             path = "dense"
     if v.shape[-1] != k.shape[-1]:      # the kernels' value is D wide
         path = "dense"
-    backward = ("none" if path == "dense" else "two_kernels" if d2
+    backward = ("none" if path == "dense"
+                else _backward2_for(q, q2, n_head, bq, bk) if d2
                 else _backward_for(q, n_head, mask, bq, bk))
     _LOWERINGS.inc(path=path, entry=entry, heads_per_block=str(g),
                    backward=backward, mask=mask_label,

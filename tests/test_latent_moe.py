@@ -106,15 +106,16 @@ def test_one_part_alone_is_todays_kernel():
         q, k, v, h, causal=True, scale=scale, force="interpret",
         block_q=128, block_k=128, q2=jnp.zeros_like(q2), k2=k2)
     np.testing.assert_array_equal(one(q, k, v), two(q, k, v))
-    # the one-part streamed backward is ONE kernel since ISSUE 39 and the
-    # two-part one still two: the same sums in another order, held to
-    # the float32 tolerance of this file's other gradient tests
+    # both streamed backwards are ONE kernel (the one-part one since
+    # ISSUE 39, the two-part one since ISSUE 56) on grids of their own:
+    # the same sums in another order, held to the float32 tolerance of
+    # this file's other gradient tests
     for a, b in zip(jax.grad(lambda *x: one(*x).sum(), (0, 1, 2))(q, k, v),
                     jax.grad(lambda *x: two(*x).sum(), (0, 1, 2))(q, k, v)):
         assert float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))) < 1e-5
     count = FA._LOWERINGS
     labels = dict(path="interpret", entry="bthd", heads_per_block="1",
-                  backward="two_kernels", mask="causal", kv_groups="1",
+                  backward="fused_streamed", mask="causal", kv_groups="1",
                   key_width="192", value_width="128", second_part="shared",
                   window="0")
     was = count.value(**labels)

@@ -90,23 +90,41 @@ def test_block_diffusion_attention_compiles_for_v5e(chip):
 
 
 # --------------------------------------------------------------------------
-# ISSUE 34: latent attention's score of two parts at the cell
-# xing4_train_T4k's shape, T 4096 streamed: q_nope / k_nope / v
-# [1, 4096, 32 x 128], q_pe [1, 4096, 32 x 64] and ONE k_pe [1, 4096, 64].
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_two_part_score_compiles_for_v5e(chip, direction):
-    """The three streamed kernels with a second score part, under their
-    own names; nothing of k_pe's size times the heads exists (the shared
-    key is repeated to ONE 128-lane tile, [1, 4096, 128]), no operand
-    is padded to 256 lanes a head, and dk_pe's sum over the 32 heads
-    is made in the kernel: the only results between the kernels and the
-    gradients are the fold of that one tile."""
+# ISSUEs 34 and 56: latent attention's score of two parts at the cells'
+# shapes, streamed: q_nope / k_nope / v [1, T, 32 x 128], q_pe
+# [1, T, 32 x 64] and ONE k_pe [1, T, 64], T 4096 (xing4_train_T4k) and
+# 8192 (joyai_train_T8k).
+@pytest.mark.parametrize("direction, t, backward", [
+    ("fwd", 4096, None), ("bwd", 4096, "fused_streamed"),
+    ("bwd", 8192, "fused_streamed"), ("bwd", 4096, "two_kernels")],
+    ids=["fwd_T4k", "bwd_T4k", "bwd_T8k", "bwd_T4k_over_the_bound"])
+def test_two_part_score_compiles_for_v5e(chip, monkeypatch, direction, t,
+                                         backward):
+    """The streamed kernels with a second score part, under their own
+    names: the forward, and the ONE backward kernel flash_bwd, whose
+    scoped VMEM (_one_kernel_vmem_bytes: both heads' dq of a pair and
+    dq_pe for all of T, 25 MB of the 48 asked for at T 8192) the
+    compiler accepts; with no shape within the byte bound, flash_bwd_dq
+    + flash_bwd_dkv as before. Nothing of k_pe's size times the heads
+    exists (the shared key is repeated to ONE 128-lane tile,
+    [1, T, 128]), no operand is padded to 256 lanes a head, and dk_pe's
+    sum over the heads is made in the kernel a pair of heads at a time:
+    what lies between the kernels and the gradients is the 16 pairs'
+    float32 partials [16, T, 128], their sum, and the fold of that one
+    tile."""
     import math
     import re
-    b, t, h, d, d2 = 1, 4096, 32, 128, 64
+    b, h, d, d2 = 1, 32, 128, 64
     sds = lambda lanes: jax.ShapeDtypeStruct((b, t, lanes), jnp.bfloat16,
                                              sharding=chip)
     avals = (sds(h * d), sds(h * d), sds(h * d), sds(h * d2), sds(d2))
+    if backward == "two_kernels":
+        monkeypatch.setattr(FA, "_RESIDENT_DQ_BYTES", 0)
+    count = lambda: sum(
+        v for key, v in FA._LOWERINGS.snapshot().items()
+        if key[FA._LOWERINGS.label_names.index("backward")] == backward
+        and key[FA._LOWERINGS.label_names.index("second_part")] == "shared")
+    was = count()
 
     def fwd(q, k, v, q2, k2):
         return flash_bthd(q, k, v, h, causal=True, force="pallas", q2=q2,
@@ -118,16 +136,20 @@ def test_two_part_score_compiles_for_v5e(chip, direction):
     fn = fwd if direction == "fwd" else jax.grad(loss,
                                                  argnums=(0, 1, 2, 3, 4))
     text = _compiled_text(fn, *avals)
-    names = ["flash_fwd"] + (_TWO if direction == "bwd" else [])
+    names = ["flash_fwd"] + {None: [], "fused_streamed": _ONE,
+                             "two_kernels": _TWO}[backward]
     assert text.count("tpu_custom_call") == len(names)
-    for name in names:
-        assert "%" + name + "." in text or "%" + name + " " in text
+    assert set(re.findall(r"%(flash_\w+?)(?:\.\d+)? = ", text)) == set(names)
+    if backward:
+        assert count() == was + 1
     sizes = {math.prod(int(x) for x in dims.split(","))
              for dims in re.findall(r"(?:bf16|f32)\[([\d,]+)\]", text)}
     # operands and gradients as they come, the statistics' rows, the
-    # one tile of k_pe, and nothing wider
+    # one tile of k_pe, a pair's partial of it, and nothing wider
     assert max(sizes) == b * t * h * d
     assert b * t * h * 2 * d not in sizes and b * t * h * (d + d2) not in sizes
+    if backward == "fused_streamed":
+        assert "f32[%d,%d,128]" % (h // 2, t) in text
 
 
 # ISSUE 38: a window bound. The cell `trinity_train_T16k`'s window
