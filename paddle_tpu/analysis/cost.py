@@ -3,15 +3,39 @@
 The roll-up the reference framework never had at the IR level (its cost
 model lived in per-op C++ GetExpectedKernelType heuristics); here every
 jaxpr eqn gets a (flops, bytes) estimate so rules can rank diagnostics
-by how much compute sits behind them. Matmul FLOPs come from
-ops/matmul_stats.dot_general_flops — the same accounting the fused
-conv+BN kernel uses for its perf claims.
+by how much compute sits behind them.
 """
 
 import numpy as np
 
-from ..ops.matmul_stats import dot_general_flops
 from .engine import sub_jaxprs, aval_nbytes as _aval_bytes
+
+
+def matmul_flops(m, k, n):
+    """FLOPs of an [M,K] @ [K,N] matmul (multiply-accumulate = 2 ops)."""
+    return 2.0 * float(m) * float(k) * float(n)
+
+
+def dot_general_flops(lhs_shape, rhs_shape, dimension_numbers):
+    """FLOPs of a lax.dot_general from its shapes + dimension_numbers —
+    the per-eqn cost the jaxpr analyzer rolls up. Batch dims multiply,
+    contracting dims form K, the rest form M / N."""
+    (lc, rc), (lb, rb) = dimension_numbers
+    batch = 1.0
+    for i in lb:
+        batch *= lhs_shape[i]
+    k = 1.0
+    for i in lc:
+        k *= lhs_shape[i]
+    m = 1.0
+    for i in range(len(lhs_shape)):
+        if i not in lb and i not in lc:
+            m *= lhs_shape[i]
+    n = 1.0
+    for i in range(len(rhs_shape)):
+        if i not in rb and i not in rc:
+            n *= rhs_shape[i]
+    return batch * matmul_flops(m, k, n)
 
 # eqns that are pure data movement / metadata: zero FLOPs, bytes only
 _MOVEMENT = {

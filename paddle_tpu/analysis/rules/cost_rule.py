@@ -1,7 +1,6 @@
 """R006 static cost model roll-up.
 
-Per-eqn FLOPs/bytes (analysis/cost.py, matmul FLOPs shared with
-ops/matmul_stats) aggregated into a per-graph summary plus hotspot
+Per-eqn FLOPs/bytes (analysis/cost.py) aggregated into a per-graph summary plus hotspot
 diagnostics, so every other rule's findings can be read against "what
 actually costs something". A single eqn above ``hot_flops`` is flagged
 for sharding/fusion review — on a multi-chip mesh that eqn is the one

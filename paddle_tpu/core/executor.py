@@ -19,10 +19,25 @@ Autodiff: a ``backward_marker`` op recorded by append_backward (core/backward.py
 switches the tracer into ``jax.value_and_grad`` over the forward segment —
 replacing the reference's per-op GradOpDescMaker machinery (backward.py:425)
 with JAX's program transform.
+
+The step is written once: ``Executor._step`` is the skeleton under
+``Executor.run``, ``Executor.run_steps`` and ParallelExecutor's two, and it
+owns what every call of a compiled step does — the cache key
+(``_cache_key``: every trace-time toggle is listed there and nowhere else),
+the random key of a logical step (``_step_keys``; the eager path's too), the
+build, ``jax.jit`` and the compile ahead of the first call
+(``_first_compile``), the monitor's timing and the profiler's event, and the
+commit of state and fetches. An entry hands it an ``_Entry`` that says only
+what differs: the root's and the monitor's label, what joins the key, an AMP
+value to pin, gradient accumulation, whether host (IO) ops go to the eager
+path, a check of the feeds, and how state and feeds are laid out on the
+devices and fetched values brought back. A new toggle goes into
+``_cache_key``; a new way to run a step is a new ``_Entry``.
 """
 
 import contextlib
 import os
+import time
 
 import numpy as np
 import jax
@@ -37,6 +52,7 @@ from .lod import LoDTensor
 from ..trace import runtime as _trc
 
 _NANGUARD = "__nanguard__"
+_NO_CONTEXT = contextlib.nullcontext()
 
 
 def _flag_on(name):
@@ -367,6 +383,112 @@ def _feed_signature(feed):
         for k, v in feed.items()))
 
 
+def _stage_feeds(feeds, k, accum_steps, plan_cache):
+    """One call's feeds as the compiled step takes them: (arrays,
+    static_info, signature of ONE logical step). ``k`` None: one feed
+    dict, normalized; else a megastep's, a list of k feed dicts stacked
+    or one pre-stacked ``[k, ...]`` dict checked."""
+    if k is None:
+        arrays, static_info = _normalize_feeds(feeds, accum_steps,
+                                               plan_cache=plan_cache)
+        return arrays, static_info, _feed_signature(arrays)
+    if isinstance(feeds, dict):
+        return _stage_prestacked_feeds(feeds, k)
+    return _stack_step_feeds(feeds, plan_cache=plan_cache)
+
+
+def _cache_key(program, feed_sig, fetch_names, state_keys, amp, check_nan,
+               static_info, k, extras):
+    """The key of a compiled step in an executor's cache, for every
+    entry. EVERY toggle a lowering consults at trace time is listed
+    here, and nowhere else, or flipping it after a run silently reuses
+    a stale trace. The Program object itself is part of the key (kept
+    alive by the cache) so id-reuse after GC can never alias two
+    programs; ``k`` parts a megastep's scan from the one step, and
+    ``extras`` is what the entry's own lowering reads beside (a
+    ParallelExecutor's sharding hints and accumulation)."""
+    return (program, program._version, feed_sig, fetch_names, state_keys,
+            amp, check_nan, tuple(sorted(static_info.items())), k, extras)
+
+
+def _step_keys(program, n, k=None):
+    """The random key of logical step ``n`` of ``program`` — k of them,
+    stacked, for a megastep's steps n .. n+k-1: one RNG stream position
+    per LOGICAL step under every entry, so a megastep's output is
+    bitwise equal to k sequential run() calls (dropout masks included).
+    The seed is folded to 32 bits, so any ``random_seed`` runs."""
+    base = program.random_seed * 1000003 + n
+    if k is None:
+        return jax.random.key(np.uint32(base & 0xFFFFFFFF))
+    return jax.vmap(jax.random.key)(jnp.asarray(
+        [np.uint32((base + i) & 0xFFFFFFFF) for i in range(k)]))
+
+
+def _megastep(step):
+    """Wrap a built step in a lax.scan over K stacked batches: ONE
+    compile unit keyed on K, one dispatch per K logical steps."""
+    def mega(state, feeds_k, keys):
+        def body(carry, xs):
+            feeds_i, key_i = xs
+            fetches, new_state, guards, fetch_lods = step(
+                carry, feeds_i, key_i)
+            extra = sorted(set(new_state) - set(carry))
+            if extra:       # trace-time check, not a runtime branch
+                raise ValueError(
+                    "run_steps: the program materializes new "
+                    "persistable vars %s inside the step — the "
+                    "scan carry pytree must be stable. run() the "
+                    "startup/first step once, then megastep."
+                    % extra)
+            carry = {n: new_state[n] for n in carry}
+            return carry, (fetches, guards, fetch_lods)
+
+        final, (fetches_k, guards_k, lods_k) = jax.lax.scan(
+            body, state, (feeds_k, keys))
+        return fetches_k, final, guards_k, lods_k
+
+    return mega
+
+
+def _amp_pinned(fn, amp):
+    """``fn`` under AMP held at ``amp`` whenever it is traced: a
+    lowering reads the AMP flag at TRACE time, so an entry that keys
+    its cache on a value of its own pins it for the trace and restores
+    the ambient one (no global leak)."""
+    from ..amp import amp_guard
+
+    def step(state, feeds, key):
+        with amp_guard(amp):
+            return fn(state, feeds, key)
+
+    return step
+
+
+class _Entry:
+    """What an entry point hands ``Executor._step``: all of a step that
+    is not the skeleton's. The class is ``Executor.run``'s and
+    ``.run_steps``'s own entry; ParallelExecutor's derives from it."""
+
+    prefix = "exe"        # of the root and the phases; the monitor's label
+    devices = 1           # chips that run the step (the MFU's denominator)
+    key_extras = ()       # joins the cache key
+    amp = None            # None: the ambient AMP; else pinned for the trace
+    accum = (1, None)     # gradient accumulation: steps, loss norm
+    # a program with host (IO) ops goes to the eager path (a megastep
+    # of one is refused); False: its ops are traced as any other's
+    host_ops = True
+    # check_feeds(feeds, feed_arrays) raises on feeds the entry cannot
+    # take; place(state, feed_arrays) -> (state, feeds) laid out on the
+    # devices, under the phase "place" (None: the call runs under
+    # jax.default_device(the executor's place)); pull(fetches,
+    # fetch_lods, guards) -> the same as the host sees them, under the
+    # phase "pull"
+    check_feeds = place = pull = None
+
+
+_EXE = _Entry()
+
+
 class Executor:
     """Single-device executor (CPU or one TPU chip).
 
@@ -386,7 +508,10 @@ class Executor:
         # per-call normalization derivation and reuse committed device
         # buffers (PERF.md round-5 in-process serving re-marshal fix)
         self._feed_plans = FeedPlanCache(device_fn=self.place.jax_device)
-        self._rng_counter = 0
+        self._rng_counter = 0     # logical steps run: the next one's number
+        self._inflight = []       # megasteps dispatched and not yet fetched
+        self._mesh = None         # a ParallelExecutor's; lowerings read it
+        self._keep_nothing = False    # _first_compile's fallback is lowering
         import uuid
         import weakref
         # per-PROGRAM step counters for host-op send tags (retry
@@ -418,23 +543,13 @@ class Executor:
     # ------------------------------------------------------------------
     def close(self):
         self._cache.clear()
-        plans = getattr(self, "_feed_plans", None)  # __new__-built exe
-        if plans is not None:
-            plans.clear()
+        self._feed_plans.clear()
 
     def run(self, program=None, feed=None, fetch_list=None,
             feed_var_name="feed", fetch_var_name="fetch", scope=None,
             return_numpy=True, use_program_cache=True):
-        # the step's root, numbered: always an annotation in the JAX
-        # profiler's timeline (it records only while a profiler session
-        # runs); with the tracer armed also the distributed-trace root
-        # span: RPC verb spans issued while this step runs (pserver
-        # sends/gets, prefetches) nest under it, making the step the
-        # unit of the fleet timeline
-        with _trc.span("exe.step", step=self._rng_counter):
-            return self._run_impl(program, feed, fetch_list,
-                                  feed_var_name, fetch_var_name, scope,
-                                  return_numpy, use_program_cache)
+        return self._step(_EXE, program, scope, fetch_list, feed, None,
+                          return_numpy, use_program_cache)
 
     # -- megastep execution (ISSUE 7) ----------------------------------
     def run_steps(self, program=None, feeds=None, fetch_list=None,
@@ -469,10 +584,8 @@ class Executor:
         steps), and programs with host (IO) ops or newly-materialized
         persistables (startup programs) are rejected — run() those."""
         feeds, k = self._check_run_steps_args(feeds, k)
-        with _trc.span("exe.step", step=self._rng_counter, k=k):
-            return self._run_steps_impl(program, feeds, fetch_list,
-                                        scope, return_numpy, k,
-                                        use_program_cache)
+        return self._step(_EXE, program, scope, fetch_list, feeds, k,
+                          return_numpy, use_program_cache)
 
     @staticmethod
     def _check_run_steps_args(feeds, k):
@@ -494,145 +607,241 @@ class Executor:
             raise ValueError("run_steps needs k >= 1, got %d" % k)
         return feeds, k
 
-    def _run_steps_impl(self, program, feeds, fetch_list, scope,
-                        return_numpy, k, use_program_cache):
-        import time as _time
-        step = self._rng_counter          # phases as in _run_impl
-        with _trc.phase("exe.feed", step=step):
-            program = program or default_main_program()
-            fetch_list = list(fetch_list or [])
-            scope = scope or global_scope()
-            fetch_names = tuple(
-                f.name if isinstance(f, Variable) else str(f)
-                for f in fetch_list)
-            if any(registry.is_host_op(o.type)
-                   for o in program.global_block().ops):
-                raise NotImplementedError(
-                    "run_steps cannot fuse programs with host (IO) ops "
-                    "— send/recv/prefetch must hit the wire once per "
-                    "step; use run() per step")
-            if isinstance(feeds, dict):
-                feeds_k, static_info, sig = _stage_prestacked_feeds(
-                    feeds, k)
-            else:
-                feeds_k, static_info, sig = _stack_step_feeds(
-                    feeds, plan_cache=getattr(self, "_feed_plans", None))
-
-        with _trc.phase("exe.state", step=step):
-            state, state_keys = _gather_state(program, scope)
-
+    def _step(self, how, program, scope, fetch_list, feeds, k,
+              return_numpy, use_program_cache):
+        """One call of a compiled step: the skeleton under ``run`` and
+        ``run_steps`` here and under ParallelExecutor's two. ``how`` is
+        the entry's ``_Entry``; ``k`` is None for one step and the
+        number of logical steps for a megastep (``feeds`` then a list of
+        k feed dicts or one pre-stacked dict)."""
+        from .. import monitor as _mon
+        from .. import profiler as _prof
         from ..amp import amp_enabled
         from ..flags import get_flag
-        check_nan = _flag_on("PADDLE_TPU_CHECK_NAN_INF")
-        key = ("megastep", k, program, program._version, sig,
-               fetch_names, state_keys, amp_enabled(), check_nan,
-               get_flag("fuse_conv_bn"),
-               tuple(sorted(static_info.items())))
-        from .. import monitor as _mon
-        mon_on = _mon.enabled()
-        entry = self._cache.get(key) if use_program_cache else None
-        fresh = entry is None
-        if fresh:
-            with _trc.phase("exe.build", step=step):
-                mega = self._build_megastep(
-                    program, tuple(sorted(feeds_k)), fetch_names,
+        step = self._rng_counter
+        pre = how.prefix + "."
+        # the step's root, numbered: always an annotation in the JAX
+        # profiler's timeline (it records only while a profiler session
+        # runs); with the tracer armed also the distributed-trace root
+        # span: RPC verb spans issued while this step runs (pserver
+        # sends/gets, prefetches) nest under it, making the step the
+        # unit of the fleet timeline. The phases of a step (feed /
+        # state / build / place / dispatch / pull / commit; place and
+        # pull where the entry has them) are annotations numbered like
+        # their root; what lies between them is the root's self time
+        attrs = {"step": step} if k is None else {"step": step, "k": k}
+        with _trc.span(pre + "step", **attrs):
+            with _trc.phase(pre + "feed", step=step):
+                program = program or default_main_program()
+                scope = scope or global_scope()
+                fetch_names = tuple(
+                    f.name if isinstance(f, Variable) else str(f)
+                    for f in (fetch_list or ()))
+                host = how.host_ops and any(
+                    registry.is_host_op(o.type)
+                    for o in program.global_block().ops)
+                if host and k is not None:
+                    raise NotImplementedError(
+                        "run_steps cannot fuse programs with host (IO) "
+                        "ops — send/recv/prefetch must hit the wire "
+                        "once per step; use run() per step")
+                if k is None:
+                    feeds = dict(feeds or {})
+                feed_arrays, static_info, sig = _stage_feeds(
+                    feeds, k, how.accum[0], self._feed_plans)
+                if how.check_feeds is not None:
+                    how.check_feeds(feeds, feed_arrays)
+
+            # State = persistable vars of this program that exist in scope.
+            with _trc.phase(pre + "state", step=step):
+                state, state_keys = _gather_state(program, scope)
+            if host:
+                return self._run_host_ops(program, feed_arrays,
+                                          fetch_names, scope, static_info,
+                                          return_numpy)
+
+            check_nan = _flag_on("PADDLE_TPU_CHECK_NAN_INF")
+            use_amp = amp_enabled() if how.amp is None else how.amp
+            key = _cache_key(program, sig, fetch_names, state_keys,
+                             use_amp, check_nan, static_info, k,
+                             how.key_extras)
+            mon_on = _mon.enabled()
+            entry = self._cache.get(key) if use_program_cache else None
+            rng_key = _step_keys(program, step, k)
+            # a cache miss is the build phase twice: here, and round the
+            # first call below, which traces, lowers and compiles
+            fresh = entry is None
+            if fresh:
+                build = lambda: self._build_entry(
+                    how, program, tuple(sorted(feed_arrays)), fetch_names,
                     state_keys, static_info, check_nan, k)
-                entry = jax.jit(mega, donate_argnums=(0,))
-                if use_program_cache:
-                    self._cache[key] = entry
-                if mon_on and use_program_cache:
-                    rng0 = jax.vmap(jax.random.key)(
-                        jnp.zeros((k,), jnp.uint32))
-                    _mon.on_compile(
-                        program, key, key[4],
-                        cost_fn=lambda: _step_costs_safe(
-                            mega, dict(state), dict(feeds_k), rng0),
-                        tokens=_mon.tokens_in_feeds(feeds_k))
-        elif mon_on:
-            _mon.on_cache_hit()
+                with _trc.phase(pre + "build", step=step):
+                    fn = build()
+                    # Shardings are established by COMMITTING the inputs
+                    # (an entry's place), not by in_shardings:
+                    # constraining the jit would force a reshard of
+                    # step-2 state (whose committed sharding is whatever
+                    # step 1 produced), which multi-process arrays
+                    # cannot do. Committed-input propagation is the
+                    # standard JAX training-loop pattern and keeps
+                    # single- and multi-host behavior identical.
+                    entry = jax.jit(fn, donate_argnums=(0,))
+                    if use_program_cache:
+                        self._cache[key] = entry
+                    if mon_on and use_program_cache:
+                        # price the step with the static cost model
+                        # (traced once here, at compile time) so per-step
+                        # MFU is derivable; classify the compile against
+                        # this program's history.
+                        # use_program_cache=False is a DELIBERATE cache
+                        # bypass — counting each of its runs as a
+                        # recompile would report key churn that isn't
+                        # there
+                        _mon.on_compile(
+                            program, key, sig,
+                            cost_fn=lambda: _step_costs_safe(
+                                fn, dict(state), dict(feed_arrays),
+                                rng_key),
+                            executor=how.prefix,
+                            tokens=_mon.tokens_in_feeds(feed_arrays),
+                            devices=how.devices)
+            elif mon_on:
+                _mon.on_cache_hit()
+            self._rng_counter += k or 1
 
-        # one RNG stream position per LOGICAL step — the same
-        # derivation run() uses, so megastep output is bitwise equal to
-        # K sequential run() calls (dropout masks included)
-        base = program.random_seed * 1000003 + self._rng_counter
-        self._rng_counter += k
-        keys = jax.vmap(jax.random.key)(jnp.asarray(
-            [np.uint32(base + i) for i in range(k)]))
-
-        window = max(1, int(get_flag("megastep_inflight")))
-        inflight = self.__dict__.setdefault("_inflight", [])
-        while len(inflight) >= window:
-            # double-buffer window full: the OLDEST dispatch must
-            # retire before another joins the pipeline
-            jax.block_until_ready(inflight.pop(0))
-
-        t0 = _time.perf_counter() if mon_on else 0.0
-        if mon_on:
-            timer = _mon.step_timer(self)
-            do_sync = timer.begin(t0)
-        with _trc.phase("exe.build" if fresh else "exe.dispatch",
-                        step=step), \
-                jax.default_device(self.place.jax_device()):
-            fetches_k, new_state, guards_k, lods_k = entry(
-                state, feeds_k, keys)
-        if mon_on:
-            fb = _mon.feed_nbytes(feeds_k)
-            tk = _mon.tokens_in_feeds(feeds_k)
-            if do_sync:
-                jax.block_until_ready(fetches_k)
-                _mon.on_megastep(
-                    key, timer.end_synced(_time.perf_counter(), t0), k,
-                    feed_bytes=fb, tokens=tk)
+            if how.place is None:
+                args = (state, feed_arrays, rng_key)
+                on_place = jax.default_device(self.place.jax_device())
             else:
-                _mon.on_megastep(key, _time.perf_counter() - t0, k,
-                                 feed_bytes=fb, tokens=tk, synced=False)
+                # state is placed per its sharding once; jit keeps the
+                # placement on subsequent steps
+                with _trc.phase(pre + "place", step=step):
+                    args = how.place(state, feed_arrays) + (rng_key,)
+                on_place = _NO_CONTEXT
+            if k is not None:
+                window = max(1, int(get_flag("megastep_inflight")))
+                while len(self._inflight) >= window:
+                    # double-buffer window full: the OLDEST dispatch
+                    # must retire before another joins the pipeline
+                    jax.block_until_ready(self._inflight.pop(0))
 
-        with _trc.phase("exe.commit", step=step):
-            _trc.fetched(fetches_k)
-            for n, v in new_state.items():
-                scope.set(n, v)
-            if check_nan:
-                self._check_guards_steps(guards_k, k)
-            out = self._split_step_fetches(fetch_names, fetches_k,
-                                           lods_k, k, return_numpy)
-            if check_nan:
-                for i, fi in enumerate(out):
-                    self._check_nan_inf(fetch_names, fi)
-            if not return_numpy:
-                # async dispatch: hand back device handles and track
-                # the un-fetched dispatch in the in-flight window
-                inflight.append(fetches_k)
-            return out
+            do_sync = False
+            if mon_on:
+                # monitor_sync_every=N amortization: sync once per N
+                # steps so async dispatch pipelines keep pipelining; the
+                # synced step reports the window-average as per-step
+                # latency. With the profiler on every step blocks
+                # anyway — keep the already-paid exact latencies
+                # instead of window-averaging
+                t0 = time.perf_counter()
+                timer = _mon.step_timer(self)
+                do_sync = timer.begin(t0) or _prof._enabled
+            # step-level profiler event; sync INSIDE the event so the
+            # row records real step time, not async dispatch; with
+            # profile_memory on it also samples live/peak HBM per
+            # compiled step (the step IS the op)
+            event = _prof.RecordEvent(pre + "run(compiled)") \
+                if _prof._enabled else _NO_CONTEXT
+            with _trc.phase(pre + ("build" if fresh else "dispatch"),
+                            step=step), on_place:
+                if fresh:
+                    entry = self._first_compile(program, entry, args,
+                                                build)
+                    if use_program_cache:
+                        self._cache[key] = entry
+                with event:
+                    fetches, new_state, guards, fetch_lods = entry(*args)
+                    if do_sync or _prof._enabled:
+                        # sync inside the phase: the histogram must
+                        # record real step latency, not async dispatch
+                        jax.block_until_ready(fetches)
+                _trc.fetched(fetches)
+            if mon_on:
+                now = time.perf_counter()
+                dt = timer.end_synced(now, t0) if do_sync else now - t0
+                said = dict(feed_bytes=_mon.feed_nbytes(feed_arrays),
+                            tokens=_mon.tokens_in_feeds(feed_arrays),
+                            executor=how.prefix, synced=do_sync)
+                if k is None:
+                    _mon.on_step(key, dt, **said)
+                else:
+                    _mon.on_megastep(key, dt, k, **said)
 
-    def _build_megastep(self, program, feed_names, fetch_names,
-                        state_keys, static_info, check_nan, k):
-        """Wrap the compiled-step body in a lax.scan over K stacked
-        batches: ONE compile unit keyed on K, one dispatch per K
-        logical steps."""
-        step = self._build(program, feed_names, fetch_names, state_keys,
-                           static_info=static_info, check_nan=check_nan)
+            if how.pull is not None:
+                with _trc.phase(pre + "pull", step=step):
+                    fetches, fetch_lods, guards = how.pull(
+                        fetches, fetch_lods, guards)
+            with _trc.phase(pre + "commit", step=step):
+                # Commit updated persistable state back to the scope.
+                # New persistable vars materialized by this run (e.g.
+                # startup program initializers) are committed too —
+                # _build returns them in new_state.
+                for n, v in new_state.items():
+                    scope.set(n, v)
+                if k is None:
+                    if check_nan:
+                        self._check_guards(guards)
+                    out = self._trim_fetches(fetch_names, fetches,
+                                             fetch_lods)
+                    if return_numpy:
+                        out = [as_numpy(v) for v in out]
+                else:
+                    if check_nan:
+                        self._check_guards_steps(guards, k)
+                    out = self._split_step_fetches(
+                        fetch_names, fetches, fetch_lods, k, return_numpy)
+                    if not return_numpy:
+                        # async dispatch: hand back device handles and
+                        # track the un-fetched dispatch in the in-flight
+                        # window
+                        self._inflight.append(fetches)
+                if check_nan:
+                    for one in (out,) if k is None else out:
+                        self._check_nan_inf(fetch_names, one)
+                return out
 
-        def mega(state, feeds_k, keys):
-            def body(carry, xs):
-                feeds_i, key_i = xs
-                fetches, new_state, guards, fetch_lods = step(
-                    carry, feeds_i, key_i)
-                extra = sorted(set(new_state) - set(carry))
-                if extra:       # trace-time check, not a runtime branch
-                    raise ValueError(
-                        "run_steps: the program materializes new "
-                        "persistable vars %s inside the step — the "
-                        "scan carry pytree must be stable. run() the "
-                        "startup/first step once, then megastep."
-                        % extra)
-                carry = {n: new_state[n] for n in carry}
-                return carry, (fetches, guards, fetch_lods)
+    def _build_entry(self, how, program, feed_names, fetch_names,
+                     state_keys, static_info, check_nan, k):
+        """The function an entry's cache holds jitted: the step ``_build``
+        traces, as it is for ``Executor.run`` (device ops read
+        ``jit(step)/...``: no closure goes round it), scanned over k
+        batches for a megastep, and with AMP held at the entry's value
+        for the trace where the entry pins it."""
+        fn = self._build(program, feed_names, fetch_names, state_keys,
+                         static_info, check_nan, *how.accum)
+        if k is not None:
+            fn = _megastep(fn)
+        if how.amp is not None:
+            fn = _amp_pinned(fn, how.amp)
+        return fn
 
-            final, (fetches_k, guards_k, lods_k) = jax.lax.scan(
-                body, state, (feeds_k, keys))
-            return fetches_k, final, guards_k, lods_k
-
-        return mega
+    def _run_host_ops(self, program, feed_arrays, fetch_names, scope,
+                      static_info, return_numpy):
+        """Programs containing host (IO) ops — send/recv/listen_and_serv
+        — run in eager-interpreter mode: each lowering executes
+        immediately on concrete values, so IO happens for real. This is
+        the reference's op-by-op interpreter, kept ONLY for the
+        distributed edge where the reference also left graph land."""
+        # the send-tag sequence advances only on SUCCESS and is
+        # per-program: a retried step reuses its tag, so the server
+        # replaces (not doubles) the pending grad — elastic-recovery
+        # idempotency. The program nonce keeps two programs' tags
+        # distinct (a second SENDING program of the same grad names
+        # within one round is not supported).
+        import uuid
+        entry = self._run_seqs.get(program)
+        if entry is None:
+            entry = self._run_seqs.setdefault(
+                program, [0, uuid.uuid4().hex[:4]])
+        # seq/incarnation travel as ARGUMENTS, not instance state: a
+        # shared Executor driven from two threads must not cross-tag
+        # rounds
+        result = self._run_eager(
+            program, feed_arrays, fetch_names, scope, static_info,
+            return_numpy, run_seq=entry[0],
+            incarnation=self._incarnation + entry[1])
+        entry[0] += 1
+        return result
 
     @staticmethod
     def _split_step_fetches(fetch_names, fetches_k, lods_k, k,
@@ -665,177 +874,6 @@ class Executor:
                 raise FloatingPointError(
                     "%s (at megastep logical step %d of %d; state has "
                     "advanced the full megastep)" % (e, i, k)) from None
-
-    def _run_impl(self, program, feed, fetch_list, feed_var_name,
-                  fetch_var_name, scope, return_numpy,
-                  use_program_cache):
-        # the phases of a step (exe.feed / exe.state / exe.build /
-        # exe.dispatch / exe.commit) are annotations in the profiler's
-        # timeline, numbered like their exe.step root; what lies
-        # between them is the root's self time
-        step = self._rng_counter
-        with _trc.phase("exe.feed", step=step):
-            program = program or default_main_program()
-            feed = dict(feed or {})
-            fetch_list = list(fetch_list or [])
-            scope = scope or global_scope()
-
-            fetch_names = tuple(
-                f.name if isinstance(f, Variable) else str(f)
-                for f in fetch_list)
-
-            # Normalize feeds to arrays; remember LoD for LoDTensor
-            # feeds. static_info carries trace-time constants derived
-            # host-side from the feed — the per-feed BUCKETED max
-            # sequence length (next power of two), which bounds in-graph
-            # padding at ~Tmax instead of the total token count (the
-            # shape-key bucketing of SURVEY.md §7).
-            feed_arrays, static_info = _normalize_feeds(
-                feed, plan_cache=getattr(self, "_feed_plans", None))
-
-        # State = persistable vars of this program that exist in scope.
-        with _trc.phase("exe.state", step=step):
-            state, state_keys = _gather_state(program, scope)
-
-        # NB: the Program object itself is part of the key (kept alive by the
-        # cache) so id-reuse after GC can never alias two programs. The AMP
-        # flag changes lowering, so it is part of the key too.
-        # Programs containing host (IO) ops — send/recv/listen_and_serv —
-        # run in eager-interpreter mode: each lowering executes immediately
-        # on concrete values, so IO happens for real. This is the
-        # reference's op-by-op interpreter, kept ONLY for the distributed
-        # edge where the reference also left graph land.
-        if any(registry.is_host_op(o.type)
-               for o in program.global_block().ops):
-            # the send-tag sequence advances only on SUCCESS and is
-            # per-program: a retried step reuses its tag, so the server
-            # replaces (not doubles) the pending grad — elastic-recovery
-            # idempotency. The program nonce keeps two programs' tags
-            # distinct (a second SENDING program of the same grad names
-            # within one round is not supported).
-            import uuid
-            entry = self._run_seqs.get(program)
-            if entry is None:
-                entry = self._run_seqs.setdefault(
-                    program, [0, uuid.uuid4().hex[:4]])
-            # seq/incarnation travel as ARGUMENTS, not instance state:
-            # a shared Executor driven from two threads must not
-            # cross-tag rounds
-            result = self._run_eager(
-                program, feed_arrays, fetch_names, scope, static_info,
-                return_numpy, run_seq=entry[0],
-                incarnation=self._incarnation + entry[1])
-            entry[0] += 1
-            return result
-
-        from ..amp import amp_enabled
-        from ..flags import get_flag
-        check_nan = _flag_on("PADDLE_TPU_CHECK_NAN_INF")
-        # every toggle the lowering consults at trace time must key the
-        # cache, or flipping it after a run silently reuses a stale trace
-        key = (program, program._version, _feed_signature(feed_arrays),
-               fetch_names, state_keys, amp_enabled(), check_nan,
-               get_flag("fuse_conv_bn"),
-               tuple(sorted(static_info.items())))
-        from .. import monitor as _mon
-        mon_on = _mon.enabled()
-        entry = self._cache.get(key) if use_program_cache else None
-        # a cache miss is exe.build twice: here, and round the first
-        # call below, which traces, lowers and compiles
-        fresh = entry is None
-        build = lambda: self._build(
-            program, tuple(sorted(feed_arrays)), fetch_names, state_keys,
-            static_info, check_nan=check_nan)
-        if fresh:
-            with _trc.phase("exe.build", step=step):
-                fn = build()
-                entry = jax.jit(fn, donate_argnums=(0,))
-                if use_program_cache:
-                    self._cache[key] = entry
-                if mon_on and use_program_cache:
-                    # price the step with the static cost model (traced
-                    # once here, at compile time) so per-step MFU is
-                    # derivable; classify the compile against this
-                    # program's history. use_program_cache=False is a
-                    # DELIBERATE cache bypass — counting each of its
-                    # runs as a recompile would report key churn that
-                    # isn't there
-                    rng0 = jax.random.key(0)
-                    _mon.on_compile(
-                        program, key, key[2],
-                        cost_fn=lambda: _step_costs_safe(
-                            fn, dict(state), dict(feed_arrays), rng0),
-                        tokens=_mon.tokens_in_feeds(feed_arrays))
-        elif mon_on:
-            _mon.on_cache_hit()
-
-        rng_key = jax.random.key(
-            np.uint32(program.random_seed * 1000003 + self._rng_counter))
-        self._rng_counter += 1
-
-        import time as _time
-        from .. import profiler as _prof
-        t0 = _time.perf_counter() if mon_on else 0.0
-        if mon_on:
-            # monitor_sync_every=N amortization: sync once per N steps
-            # so async dispatch pipelines keep pipelining; the synced
-            # step reports the window-average as per-step latency
-            timer = _mon.step_timer(self)
-            # with the profiler on every step blocks anyway — keep the
-            # already-paid exact latencies instead of window-averaging
-            do_sync = timer.begin(t0) or _prof._enabled
-        with _trc.phase("exe.build" if fresh else "exe.dispatch",
-                        step=step), \
-                jax.default_device(self.place.jax_device()):
-            if fresh:
-                entry = self._first_compile(
-                    program, entry, (state, feed_arrays, rng_key), build)
-                if use_program_cache:
-                    self._cache[key] = entry
-            if _prof._enabled:
-                # step-level event; sync INSIDE the event so the row
-                # records real step time, not async dispatch; with
-                # profile_memory on it also samples live/peak HBM per
-                # compiled step (the step IS the op)
-                with _prof.RecordEvent("exe.run(compiled)"):
-                    fetches, new_state, guards, fetch_lods = entry(
-                        state, feed_arrays, rng_key)
-                    jax.block_until_ready(fetches)
-            else:
-                fetches, new_state, guards, fetch_lods = entry(
-                    state, feed_arrays, rng_key)
-                if mon_on and do_sync:
-                    # sync inside the span: the histogram must record
-                    # real step latency, not async dispatch time
-                    jax.block_until_ready(fetches)
-        if mon_on:
-            now = _time.perf_counter()
-            fb = _mon.feed_nbytes(feed_arrays)
-            tk = _mon.tokens_in_feeds(feed_arrays)
-            if do_sync:
-                _mon.on_step(key, timer.end_synced(now, t0),
-                             feed_bytes=fb, tokens=tk)
-            else:
-                _mon.on_step(key, now - t0, feed_bytes=fb, tokens=tk,
-                             synced=False)
-        with _trc.phase("exe.commit", step=step):
-            _trc.fetched(fetches)
-            fetches = self._trim_fetches(fetch_names, fetches, fetch_lods)
-
-            # Commit updated persistable state back to the scope. New
-            # persistable vars materialized by this run (e.g. startup
-            # program initializers) are committed too — _build returns
-            # them in new_state.
-            for n, v in new_state.items():
-                scope.set(n, v)
-
-            if check_nan:
-                self._check_guards(guards)
-                self._check_nan_inf(fetch_names, fetches)
-
-            if return_numpy:
-                return [as_numpy(v) for v in fetches]
-            return list(fetches)
 
     def _first_compile(self, program, entry, args, rebuild):
         """Lower and compile, ahead of its first call, the step of a
@@ -903,8 +941,7 @@ class Executor:
         env.update(feed_arrays)
 
         counter = [0]
-        base_key = jax.random.key(
-            np.uint32(program.random_seed * 1000003 + self._rng_counter))
+        base_key = _step_keys(program, self._rng_counter)
         self._rng_counter += 1
 
         def rng_fn():
@@ -912,7 +949,7 @@ class Executor:
             return jax.random.fold_in(base_key, counter[0])
 
         ctx = registry.LowerContext(env, rng_fn, executor=self, block=block,
-                                    mesh=getattr(self, "_mesh", None),
+                                    mesh=self._mesh,
                                     static_info=static_info,
                                     fetch_names=fetch_names)
         ctx.check_nan = _flag_on("PADDLE_TPU_CHECK_NAN_INF")
@@ -1129,7 +1166,7 @@ class Executor:
                     env = dict(array_env)
                     sctx = registry.LowerContext(
                         env, seg_rng, executor=self, block=block,
-                        mesh=getattr(self, "_mesh", None),
+                        mesh=self._mesh,
                         static_info=static_info,
                         fetch_names=getattr(ctx, "fetch_names", ()))
                     sctx.check_nan = check_nan
@@ -1216,7 +1253,7 @@ class Executor:
             env.update(feeds)
             ctx = registry.LowerContext(env, rng_fn, executor=self,
                                         block=block,
-                                        mesh=getattr(self, "_mesh", None),
+                                        mesh=self._mesh,
                                         static_info=static_info,
                                         fetch_names=fetch_names)
             ctx.check_nan = check_nan

@@ -411,12 +411,6 @@ _register("autoparallel_hbm_gb", float, 0.0,
           "(param shard + optimizer state + paged-KV pool, "
           "transform.autoparallel.plan_hbm_bytes) exceed it are "
           "REJECTED, not ranked. 0 = no capacity filter")
-_register("fuse_conv_bn", bool, False,
-          "fuse 1x1-conv + train-BN batch stats into one Pallas matmul "
-          "epilogue (ops/matmul_stats.py). Default OFF: measured SLOWER "
-          "than XLA's composed path on ResNet-50 (PERF.md round-4 "
-          "'conv+BN fusion probe'); kept as the committed evidence and "
-          "an opt-in for other shapes")
 
 
 def get_flag(name):

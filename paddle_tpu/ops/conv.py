@@ -38,19 +38,6 @@ def _conv_dnums(ndim, layout):
                                       (lhs, "OI" + sp, lhs))
 
 
-def _find_train_bn_consumer(ctx, out_name):
-    """The batch_norm op (train mode) consuming `out_name`, if any —
-    the conv+BN stat-fusion pattern (matmul_stats.py)."""
-    block = getattr(ctx, "block", None)
-    if block is None or getattr(ctx, "is_test", False):
-        return None
-    for o in block.ops:
-        if o.type == "batch_norm" and o.input("X") == [out_name] \
-                and not o.attr("is_test", False):
-            return o
-    return None
-
-
 def _conv_nd(ctx, op, ndim):
     x = ctx.in1(op, "Input")
     w = ctx.in1(op, "Filter")
@@ -63,10 +50,6 @@ def _conv_nd(ctx, op, ndim):
     groups = int(op.attr("groups", 1) or 1)
     layout = op.attr("data_format", op.attr("data_layout", "NCHW"))
     layout = "NHWC" if layout in ("NHWC", "NDHWC") else "NCHW"
-    if ndim == 4 and _maybe_conv1x1_bn_fused(
-            ctx, op, x, w, strides, paddings, dilations, groups, layout,
-            out_dtype):
-        return
     dn = _conv_dnums(ndim, layout)
     pad = [(p, p) for p in paddings]
     # bf16 path: all-bf16 with pet=None. On TPU the MXU accumulates bf16
@@ -83,52 +66,6 @@ def _conv_nd(ctx, op, ndim):
         preferred_element_type=pet)
     from ..amp import amp_out
     ctx.set_out(op, "Output", amp_out(out, out_dtype))
-
-
-def _maybe_conv1x1_bn_fused(ctx, op, x, w, strides, paddings, dilations,
-                            groups, layout, out_dtype):
-    """1x1-conv + train-BN stat fusion: the conv runs as a Pallas matmul
-    whose epilogue accumulates the per-channel shifted stats BN needs
-    (matmul_colstats), eliminating BN's extra read of the conv output —
-    the measured ~16 ms/step ResNet stat tax (PERF.md round-3
-    breakdown). The stats ride to the consumer BN via a ctx.env stash.
-    Returns True when it handled the op."""
-    # default OFF: the fusion was built for the ResNet BN stat tax, but
-    # the measured result went the other way — the Pallas matmul (the
-    # fusion vehicle) loses more against XLA's conv at the bandwidth-
-    # bound 1x1 shapes than the fused stats save (full model: 1134 vs
-    # 2491 img/s). Kept as an opt-in and as the committed evidence for
-    # that conclusion
-    # (PERF.md round-4 "ResNet conv+BN fusion probe").
-    from ..flags import get_flag
-    if not get_flag("fuse_conv_bn"):
-        return False
-    if (groups != 1 or layout != "NCHW" or w.shape[2:] != (1, 1)
-            or any(p != 0 for p in paddings)
-            or any(d != 1 for d in dilations)):
-        return False
-    out_name = op.output("Output")[0]
-    bn = _find_train_bn_consumer(ctx, out_name)
-    if bn is None:
-        return False
-    mean_names = bn.input("Mean")
-    if not mean_names or ctx.env.get(mean_names[0]) is None:
-        return False
-    from .matmul_stats import matmul_colstats
-    co, ci = int(w.shape[0]), int(w.shape[1])
-    sh, sw = strides
-    if sh != 1 or sw != 1:
-        x = x[:, :, ::sh, ::sw]        # 1x1 stride = spatial subsample
-    n, _, hh, ww = x.shape
-    c = jax.lax.stop_gradient(
-        ctx.env[mean_names[0]].astype(jnp.float32).reshape(co))
-    xt = jnp.transpose(x, (0, 2, 3, 1)).reshape(-1, ci)
-    y2, s1, s2 = matmul_colstats(xt, w.reshape(co, ci).T, c)
-    out = jnp.transpose(y2.reshape(n, hh, ww, co), (0, 3, 1, 2))
-    from ..amp import amp_out
-    ctx.env[out_name + "@BNSTATS"] = (s1, s2)
-    ctx.set_out(op, "Output", amp_out(out, out_dtype))
-    return True
 
 
 @register("conv2d")

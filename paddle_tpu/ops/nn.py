@@ -105,25 +105,17 @@ def _batch_norm(ctx, op):
         # E[x^2]-E[x]^2 form never cancels catastrophically (with c near
         # the true mean, s2/n ~ var instead of var + mean^2). Exact for
         # any c: var = E[(x-c)^2] - (E[x-c])^2, mean = c + E[x-c].
-        # A producing 1x1 conv may have ALREADY accumulated these sums
-        # in its matmul epilogue (conv.py _maybe_conv1x1_bn_fused /
-        # matmul_stats.py) — consume the stash and skip the extra read
-        # of x entirely (the ResNet BN bandwidth tax).
-        stash = ctx.env.pop(op.input("X")[0] + "@BNSTATS", None)
-        if stash is not None:
-            s1, s2 = stash
-        else:
-            # (Round-4 note: a raw-sum variant with the shift applied on
-            # the [C] results measured NO faster on the real model — the
-            # stat pass is structural XLA behavior with residual-block
-            # consumers, not a artifact of this x - c form; see PERF.md
-            # "ResNet conv+BN fusion probe".)
-            xf = x.astype(jnp.float32)
-            c = jax.lax.stop_gradient(mean_in.reshape(bshape)
-                                      .astype(jnp.float32))
-            xc = xf - c
-            s1 = jnp.sum(xc, axis=reduce_axes)
-            s2 = jnp.sum(jnp.square(xc), axis=reduce_axes)
+        # (Round-4 note: a raw-sum variant with the shift applied on
+        # the [C] results measured NO faster on the real model — the
+        # stat pass is structural XLA behavior with residual-block
+        # consumers, not a artifact of this x - c form; see PERF.md
+        # "ResNet conv+BN fusion probe".)
+        xf = x.astype(jnp.float32)
+        c = jax.lax.stop_gradient(mean_in.reshape(bshape)
+                                  .astype(jnp.float32))
+        xc = xf - c
+        s1 = jnp.sum(xc, axis=reduce_axes)
+        s2 = jnp.sum(jnp.square(xc), axis=reduce_axes)
         d1 = s1 / n
         mean = mean_in + d1
         var = jnp.maximum(s2 / n - jnp.square(d1), 0.0)

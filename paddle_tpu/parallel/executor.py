@@ -7,6 +7,14 @@ inserts NCCL all-reduce per gradient. Here: ONE jitted step function with
 input shardings — batch feeds sharded on the mesh's ``dp`` axis, state
 replicated (or sharded by `parallel.shard` hints for TP) — and XLA GSPMD
 derives every collective, overlapped with compute.
+
+The step itself is core's: ``run`` and ``run_steps`` call the inner
+Executor's ``_step`` (core/executor.py, whose head says what the skeleton
+owns) with an ``_OnMesh``, which holds what is this executor's own — the
+sharding hints and the accumulation in the cache key, ``use_bf16_compute``
+pinned for the trace, the dp checks on the feeds, ``_to_global`` under the
+phase ``pexe.place`` and ``_local_value`` under ``pexe.pull``. Core knows
+nothing of meshes: the arrow points from here to there only.
 """
 
 import numpy as np
@@ -15,12 +23,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from .mesh import (make_mesh, default_mesh, set_default_mesh,
                    spec_to_named_sharding)
-from ..core.program import default_main_program, Variable
+from ..core.program import default_main_program
 from ..core.scope import global_scope
-from ..core.executor import (Executor, as_numpy, _feed_signature,
-                             _gather_state)
+from ..core.executor import Executor, FeedPlanCache, _Entry
 from ..core.lod import LoDTensor
-from ..trace import runtime as _trc
+from ..core.places import TPUPlace, CPUPlace
 
 
 class ParallelExecutor:
@@ -54,20 +61,17 @@ class ParallelExecutor:
             # train params with a test ParallelExecutor).
             scope = share_vars_from._scope
         self._scope = scope or global_scope()
-        self._exe = Executor.__new__(Executor)
-        from ..core.places import TPUPlace, CPUPlace
+        # the inner executor runs every step (Executor._step); what is
+        # this executor's own it is handed in an _OnMesh a call
         dev = np.ravel(self.mesh.devices)[0]
-        self._exe.place = (TPUPlace(0) if dev.platform == "tpu"
-                           else CPUPlace())
-        self._exe._cache = {}
-        self._exe._rng_counter = 0
+        self._exe = Executor(TPUPlace(0) if dev.platform == "tpu"
+                             else CPUPlace())
         self._exe._mesh = self.mesh   # lowerings (sp/pp/ep ops) read this
-        self._cache = {}
         # feed-plan cache (plans only, no device commit: pexe feeds get
         # mesh shardings downstream) — repeated-shape batches skip the
         # per-call normalization derivation
-        from ..core.executor import FeedPlanCache
-        self._feed_plans = FeedPlanCache(device_fn=None)
+        self._exe._feed_plans = FeedPlanCache(device_fn=None)
+        self._cache = self._exe._cache
         self._loss_name = loss_name
         # DistributedStrategy execution knobs (mesh axes are consumed by
         # the model builders; these two belong to the executor)
@@ -97,10 +101,10 @@ class ParallelExecutor:
     def device_count(self):
         return int(np.prod(self.mesh.devices.shape))
 
-    def _data_sharding(self):
-        axes = [a for a in ("dp",) if a in self.mesh.axis_names]
-        return NamedSharding(self.mesh,
-                             PartitionSpec(axes[0] if axes else None))
+    def _data_sharding(self, batch_dim=0):
+        axis = "dp" if "dp" in self.mesh.axis_names else None
+        return NamedSharding(
+            self.mesh, PartitionSpec(*[None] * batch_dim + [axis]))
 
     def _state_sharding(self, name):
         spec = self._program._sharding_hints.get(name)
@@ -144,10 +148,9 @@ class ParallelExecutor:
                     "over: 'token:<feed_name>'." % sorted(toks))
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        # the step's root, numbered (see core Executor.run)
-        with _trc.span("pexe.step", step=self._exe._rng_counter):
-            return self._run_impl(fetch_list, feed, feed_dict,
-                                  return_numpy)
+        return self._exe._step(_OnMesh(self, None), self._program,
+                               self._scope, fetch_list, feed or feed_dict,
+                               None, return_numpy, True)
 
     @staticmethod
     def _local_value(v):
@@ -215,18 +218,7 @@ class ParallelExecutor:
         dim). Returns K per-step fetch lists. Async double buffering
         rides the same ``megastep_inflight`` window as the core
         executor when ``return_numpy=False``."""
-        from ..core.executor import Executor as _Exe
-        feeds, k = _Exe._check_run_steps_args(feeds, k)
-        with _trc.span("pexe.step", step=self._exe._rng_counter, k=k):
-            return self._run_steps_impl(fetch_list, feeds, k,
-                                        return_numpy)
-
-    def _run_steps_impl(self, fetch_list, feeds, k, return_numpy):
-        import time as _time
-        from ..core.executor import (Executor as _Exe, _flag_on,
-                                     _stack_step_feeds,
-                                     _stage_prestacked_feeds,
-                                     _step_costs_safe)
+        feeds, k = Executor._check_run_steps_args(feeds, k)
         if self._accum_steps > 1:
             raise ValueError(
                 "run_steps does not compose with gradient_"
@@ -234,317 +226,75 @@ class ParallelExecutor:
                 "the accumulation scan and change the optimizer "
                 "cadence. Megastep K already amortizes dispatch; use "
                 "one or the other." % self._accum_steps)
-        program = self._program
-        scope = self._scope
-        step = self._exe._rng_counter       # phases as in _run_impl
-        with _trc.phase("pexe.feed", step=step):
-            fetch_names = tuple(
-                f.name if isinstance(f, Variable) else str(f)
-                for f in (fetch_list or []))
-            if isinstance(feeds, dict):
-                feeds_k, static_info, sig = _stage_prestacked_feeds(
-                    feeds, k)
-            else:
-                feeds_k, static_info, sig = _stack_step_feeds(
-                    feeds, plan_cache=self._feed_plans)
+        return self._exe._step(_OnMesh(self, k), self._program,
+                               self._scope, fetch_list, feeds, k,
+                               return_numpy, True)
 
-            dp = 1
-            if "dp" in self.mesh.axis_names:
-                dp = self.mesh.shape["dp"]
-            # ragged LoD buffers stay replicated (SplitLoDTensor parity,
-            # same classification as _run_impl): the derived
-            # @LOD/@ACCUM vectors by suffix AND the flat token buffer
-            # itself, found by its original per-step feed value being a
-            # LoDTensor — its dim 1 is a data-dependent token total,
-            # not a batch dim
-            lod_keys = {n for n in feeds_k if n.endswith("@LOD")
-                        or n.endswith("@ACCUM_TOKENS")}
-            if not isinstance(feeds, dict):
-                lod_keys |= {n for f in feeds
-                             for n, v in (f or {}).items()
-                             if isinstance(v, LoDTensor)}
-            for n, v in feeds_k.items():
-                if n not in lod_keys and getattr(v, "ndim", 0) >= 2 \
-                        and v.shape[1] % dp != 0:
-                    raise ValueError(
-                        "megastep feed %r per-step batch dim %d not "
-                        "divisible by dp=%d" % (n, v.shape[1], dp))
 
-        with _trc.phase("pexe.state", step=step):
-            state, state_keys = _gather_state(program, scope)
+class _OnMesh(_Entry):
+    """One call's entry of a ParallelExecutor into ``Executor._step``:
+    the sharding hints and accumulation in the cache key, AMP pinned,
+    the dp checks on the feeds, and state and feeds laid out on the
+    mesh before the call and fetched values brought to the host after
+    it. ``k`` is None for ``run``, a megastep's for ``run_steps``."""
+
+    prefix = "pexe"
+    host_ops = False
+
+    def __init__(self, pexe, k):
+        from ..amp import amp_enabled
+        self.pexe = pexe
+        self.devices = pexe.device_count
+        self.accum = (pexe._accum_steps, pexe._accum_loss_norm)
         hints = tuple(sorted(
-            (n, tuple(v)) for n, v in program._sharding_hints.items()))
-        from ..amp import amp_enabled, enable_amp
-        from ..flags import get_flag
-        check_nan = _flag_on("PADDLE_TPU_CHECK_NAN_INF")
-        use_amp = self._force_bf16 if self._force_bf16 is not None \
+            (n, tuple(v))
+            for n, v in pexe._program._sharding_hints.items()))
+        self.key_extras = (hints,) + self.accum
+        self.amp = pexe._force_bf16 if pexe._force_bf16 is not None \
             else amp_enabled()
-        key = ("megastep", k, program, program._version, sig,
-               fetch_names, state_keys, hints, check_nan, use_amp,
-               get_flag("fuse_conv_bn"),
-               tuple(sorted(static_info.items())))
-        from .. import monitor as _mon
-        mon_on = _mon.enabled()
-        entry = self._cache.get(key)
-        fresh = entry is None
-        if not fresh and mon_on:
-            _mon.on_cache_hit()
-        if fresh:
-            with _trc.phase("pexe.build", step=step):
-                mega = self._exe._build_megastep(
-                    program, tuple(sorted(feeds_k)), fetch_names,
-                    state_keys, static_info, check_nan, k)
+        # a megastep's dim 0 is the scan dim: a step's batch is dim 1
+        self.batch_dim = 0 if k is None else 1
+        self.replicated = set()
 
-                def fn(state, feeds, keys, _fn=mega, _amp=use_amp):
-                    # pin AMP for the trace, restore after (see run())
-                    prev = amp_enabled()
-                    enable_amp(_amp)
-                    try:
-                        return _fn(state, feeds, keys)
-                    finally:
-                        enable_amp(prev)
+    def check_feeds(self, feeds, feed_arrays):
+        pexe, mesh, dim = self.pexe, self.pexe.mesh, self.batch_dim
+        if pexe._accum_steps > 1:
+            pexe._check_accum_weights(feed_arrays)
+        # ragged LoD buffers keep a replicated layout (SplitLoDTensor
+        # parity; GSPMD re-shards downstream): the derived @LOD/@ACCUM
+        # vectors by suffix AND the flat token buffer itself, found by
+        # its fed value being a LoDTensor — its leading dim is a
+        # data-dependent token total, not a batch dim
+        self.replicated.update(
+            n for n in feed_arrays
+            if n.endswith("@LOD") or n.endswith("@ACCUM_TOKENS"))
+        for f in [feeds] if isinstance(feeds, dict) else feeds:
+            self.replicated.update(n for n, v in (f or {}).items()
+                                   if isinstance(v, LoDTensor))
+        dp = mesh.shape["dp"] if "dp" in mesh.axis_names else 1
+        for n, v in feed_arrays.items():
+            if n not in self.replicated and getattr(v, "ndim", 0) > dim \
+                    and v.shape[dim] % dp != 0:
+                raise ValueError(
+                    "%sfeed %r batch dim %d not divisible by dp=%d "
+                    "(SplitLoDTensor parity requires equal chunks)"
+                    % ("megastep per-step " if dim else "", n,
+                       v.shape[dim], dp))
 
-                entry = jax.jit(fn, donate_argnums=(0,))
-                self._cache[key] = entry
-                if mon_on:
-                    import jax.numpy as _jnp
-                    rng0 = jax.vmap(jax.random.key)(
-                        _jnp.zeros((k,), _jnp.uint32))
-                    _mon.on_compile(
-                        program, key, key[4],
-                        cost_fn=lambda: _step_costs_safe(
-                            fn, dict(state), dict(feeds_k), rng0),
-                        executor="pexe",
-                        tokens=_mon.tokens_in_feeds(feeds_k),
-                        devices=self.device_count)
+    def place(self, state, feed_arrays):
+        pexe, dim = self.pexe, self.batch_dim
+        repl = NamedSharding(pexe.mesh, PartitionSpec())
+        data = pexe._data_sharding(dim)
+        return (
+            {n: pexe._to_global(v, pexe._state_sharding(n))
+             for n, v in state.items()},
+            {n: pexe._to_global(
+                v, repl if n in self.replicated
+                or getattr(v, "ndim", 0) <= dim else data)
+             for n, v in feed_arrays.items()})
 
-        base = program.random_seed * 1000003 + self._exe._rng_counter
-        self._exe._rng_counter += k
-        import jax.numpy as jnp
-        keys = jax.vmap(jax.random.key)(jnp.asarray(
-            [np.uint32(base + i) for i in range(k)]))
-
-        repl = NamedSharding(self.mesh, PartitionSpec())
-        dp_axis = None
-        if "dp" in self.mesh.axis_names:
-            dp_axis = "dp"
-        # dim 0 is the scan dim: shard each step's batch (dim 1) on dp
-        def feed_sharding(n, v):
-            if n in lod_keys or getattr(v, "ndim", 0) < 2 \
-                    or dp_axis is None:
-                return repl
-            return NamedSharding(self.mesh,
-                                 PartitionSpec(None, dp_axis))
-
-        with _trc.phase("pexe.place", step=step):
-            state_dev = {n: self._to_global(v, self._state_sharding(n))
-                         for n, v in state.items()}
-            feeds_dev = {n: self._to_global(v, feed_sharding(n, v))
-                         for n, v in feeds_k.items()}
-
-        window = max(1, int(get_flag("megastep_inflight")))
-        inflight = self.__dict__.setdefault("_inflight", [])
-        while len(inflight) >= window:
-            jax.block_until_ready(inflight.pop(0))
-
-        t0 = _time.perf_counter() if mon_on else 0.0
-        if mon_on:
-            timer = _mon.step_timer(self)
-            do_sync = timer.begin(t0)
-        with _trc.phase("pexe.build" if fresh else "pexe.dispatch",
-                        step=step):
-            fetches_k, new_state, guards_k, lods_k = entry(
-                state_dev, feeds_dev, keys)
-        if mon_on:
-            fb = _mon.feed_nbytes(feeds_k)
-            tk = _mon.tokens_in_feeds(feeds_k)
-            if do_sync:
-                jax.block_until_ready(fetches_k)
-                _mon.on_megastep(
-                    key, timer.end_synced(_time.perf_counter(), t0), k,
-                    feed_bytes=fb, tokens=tk, executor="pexe")
-            else:
-                _mon.on_megastep(key, _time.perf_counter() - t0, k,
-                                 feed_bytes=fb, tokens=tk,
-                                 executor="pexe", synced=False)
-
-        with _trc.phase("pexe.pull", step=step):
-            _trc.fetched(fetches_k)
-            fetches_k = [self._local_value(v) for v in fetches_k]
-            lods_k = {n: self._local_value(v) for n, v in lods_k.items()}
-            guards_k = {n: self._local_value(v)
-                        for n, v in guards_k.items()}
-        with _trc.phase("pexe.commit", step=step):
-            for n, v in new_state.items():
-                scope.set(n, v)
-            if check_nan:
-                _Exe._check_guards_steps(guards_k, k)
-            out = _Exe._split_step_fetches(fetch_names, fetches_k,
-                                           lods_k, k, return_numpy)
-            if check_nan:
-                for fi in out:
-                    _Exe._check_nan_inf(fetch_names, fi)
-            if not return_numpy:
-                inflight.append(fetches_k)
-            return out
-
-    def _run_impl(self, fetch_list, feed=None, feed_dict=None,
-                  return_numpy=True):
-        program = self._program
-        scope = self._scope
-        # the phases of a step, as core Executor._run_impl has them
-        # (pexe.feed / state / build / dispatch / commit), plus
-        # pexe.place round what lays state and feeds out on the mesh and
-        # pexe.pull round what brings fetched values to the host
-        step = self._exe._rng_counter
-        with _trc.phase("pexe.feed", step=step):
-            feed = dict(feed or feed_dict or {})
-            fetch_names = tuple(
-                f.name if isinstance(f, Variable) else str(f)
-                for f in (fetch_list or []))
-
-            dp = 1
-            if "dp" in self.mesh.axis_names:
-                dp = self.mesh.shape["dp"]
-            # ragged token buffers keep a replicated layout (their row
-            # count is data-dependent); GSPMD re-shards downstream.
-            # _normalize_feeds also buckets the flat LoD totals so
-            # signatures stay cache-stable.
-            from ..core.executor import _normalize_feeds
-            feed_arrays, static_info = _normalize_feeds(
-                feed, accum_steps=self._accum_steps,
-                plan_cache=self._feed_plans)
-            if self._accum_steps > 1:
-                self._check_accum_weights(feed_arrays)
-            lod_keys = {k for k in feed_arrays if k.endswith("@LOD")
-                        or k.endswith("@ACCUM_TOKENS")}
-            lod_keys |= {k for k, v in feed.items()
-                         if isinstance(v, LoDTensor)}
-            for k, v in feed_arrays.items():
-                if k in lod_keys:
-                    continue
-                if v.ndim >= 1 and v.shape[0] % dp != 0:
-                    raise ValueError(
-                        "feed %r batch dim %d not divisible by dp=%d "
-                        "(SplitLoDTensor parity requires equal chunks)"
-                        % (k, v.shape[0], dp))
-
-        with _trc.phase("pexe.state", step=step):
-            state, state_keys = _gather_state(program, scope)
-
-        hints = tuple(sorted(
-            (k, tuple(v)) for k, v in program._sharding_hints.items()))
-        from ..core.executor import _flag_on
-        from ..amp import amp_enabled, enable_amp
-        check_nan = _flag_on("PADDLE_TPU_CHECK_NAN_INF")
-        use_amp = self._force_bf16 if self._force_bf16 is not None \
-            else amp_enabled()
-        from ..flags import get_flag
-        key = (program, program._version, _feed_signature(feed_arrays),
-               fetch_names, state_keys, hints, check_nan, use_amp,
-               self._accum_steps, self._accum_loss_norm,
-               get_flag("fuse_conv_bn"),
-               tuple(sorted(static_info.items())))
-        from .. import monitor as _mon
-        mon_on = _mon.enabled()
-        entry = self._cache.get(key)
-        repl = NamedSharding(self.mesh, PartitionSpec())
-        fresh = entry is None
-        if not fresh and mon_on:
-            _mon.on_cache_hit()
-        if fresh:
-            with _trc.phase("pexe.build", step=step):
-                built = self._exe._build(
-                    program, tuple(sorted(feed_arrays)), fetch_names,
-                    state_keys, static_info=static_info,
-                    check_nan=check_nan, accum_steps=self._accum_steps,
-                    accum_loss_norm=self._accum_loss_norm)
-                if mon_on:
-                    from ..core.executor import _step_costs_safe
-                    rng0 = jax.random.key(0)
-                    _mon.on_compile(
-                        program, key, key[2],
-                        cost_fn=lambda: _step_costs_safe(
-                            built, dict(state), dict(feed_arrays), rng0),
-                        executor="pexe",
-                        tokens=_mon.tokens_in_feeds(feed_arrays),
-                        devices=self.device_count)
-
-                def fn(state, feeds, key, _fn=built, _amp=use_amp):
-                    # lowering reads the AMP flag at TRACE time; pin it
-                    # for the trace and restore the ambient value (no
-                    # global leak)
-                    prev = amp_enabled()
-                    enable_amp(_amp)
-                    try:
-                        return _fn(state, feeds, key)
-                    finally:
-                        enable_amp(prev)
-
-                # Shardings are established by COMMITTING the inputs
-                # (the device_put/make_array calls below), not by
-                # in_shardings: constraining the jit would force a
-                # reshard of step-2 state (whose committed sharding is
-                # whatever step 1 produced), which multi-process arrays
-                # cannot do. Committed-input propagation is the standard
-                # JAX training-loop pattern and keeps single- and
-                # multi-host behavior identical.
-                entry = jax.jit(fn, donate_argnums=(0,))
-                self._cache[key] = entry
-
-        rng_key = jax.random.key(
-            np.uint32(program.random_seed * 1000003
-                      + self._exe._rng_counter))
-        self._exe._rng_counter += 1
-
-        # place state per its sharding once; jit keeps the placement
-        # on subsequent steps (see _to_global)
-        with _trc.phase("pexe.place", step=step):
-            state_dev = {n: self._to_global(v, self._state_sharding(n))
-                         for n, v in state.items()}
-            data_sh = self._data_sharding()
-            feeds_dev = {k: self._to_global(v, repl if k in lod_keys
-                                            else data_sh)
-                         for k, v in feed_arrays.items()}
-
-        import time as _time
-        t0 = _time.perf_counter() if mon_on else 0.0
-        if mon_on:
-            # windowed sync (monitor_sync_every) — shared StepTimer,
-            # same windowing as core Executor.run
-            timer = _mon.step_timer(self)
-            do_sync = timer.begin(t0)
-        with _trc.phase("pexe.build" if fresh else "pexe.dispatch",
-                        step=step):
-            fetches, new_state, guards, fetch_lods = entry(
-                state_dev, feeds_dev, rng_key)
-        if mon_on:
-            fb = _mon.feed_nbytes(feed_arrays)
-            tk = _mon.tokens_in_feeds(feed_arrays)
-            if do_sync:
-                jax.block_until_ready(fetches)   # honest step latency
-                _mon.on_step(key,
-                             timer.end_synced(_time.perf_counter(), t0),
-                             feed_bytes=fb, tokens=tk, executor="pexe")
-            else:
-                _mon.on_step(key, _time.perf_counter() - t0,
-                             feed_bytes=fb, tokens=tk, executor="pexe",
-                             synced=False)
-
-        with _trc.phase("pexe.pull", step=step):
-            _trc.fetched(fetches)
-            fetches = [self._local_value(v) for v in fetches]
-            fetch_lods = {k: self._local_value(v)
-                          for k, v in fetch_lods.items()}
-            guards = {k: self._local_value(v) for k, v in guards.items()}
-            fetches = Executor._trim_fetches(fetch_names, fetches,
-                                             fetch_lods)
-            if return_numpy:
-                fetches = [as_numpy(v) for v in fetches]
-        with _trc.phase("pexe.commit", step=step):
-            for n, v in new_state.items():
-                scope.set(n, v)
-            if check_nan:
-                Executor._check_guards(guards)
-                Executor._check_nan_inf(fetch_names, fetches)
-            return list(fetches)
+    def pull(self, fetches, fetch_lods, guards):
+        local = self.pexe._local_value
+        return ([local(v) for v in fetches],
+                {n: local(v) for n, v in fetch_lods.items()},
+                {n: local(v) for n, v in guards.items()})

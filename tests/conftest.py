@@ -81,11 +81,17 @@ def _scan_lowerings_from_zero():
     (tests/test_hybrid_ssm.py, tests/chipbench/test_chipbench_sambay.py)
     while tests/test_selective_scan.py runs the step loop on purpose:
     under ``--dist loadfile`` a worker that was handed that file first
-    failed the other two. Every file starts the count from zero."""
+    failed the other two. The gated delta rule's counter is held the
+    same way (tests/chipbench/test_chipbench_olmo_hybrid.py: no
+    ``steps`` path; tests/test_delta_rule.py runs it on purpose), and
+    which files share a worker moves with every test file a PR adds
+    or takes away. Every file starts both counts from zero."""
     from paddle_tpu.monitor import metrics
-    counter = metrics.registry().get("ptpu_scan_lowerings_total")
-    if counter is not None:
-        counter.clear()
+    for name in ("ptpu_scan_lowerings_total",
+                 "ptpu_delta_rule_lowerings_total"):
+        counter = metrics.registry().get(name)
+        if counter is not None:
+            counter.clear()
     yield
 
 
