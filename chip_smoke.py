@@ -67,6 +67,14 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           cells' chunks, filled to the cells' share and filled whole:
           XLA's gather, and the scatter-add as XLA's op against the
           kernel of ops/moe_rows.py; device milliseconds and GB/s
+  embed   the embedding's gradient alone (ISSUE 58) at five cells'
+          shapes (T ids, a table [V, d]: 16384, 37984, 2560; 8192,
+          12544, 3840; 4096, 16384, 3584; 32768, 8192, 2048; 8192,
+          50272, 1024), ids drawn uniformly and Zipf-like (half the
+          places on 1% of the ids): XLA's scatter-add against the
+          kernel embedding_grad_rows of ops/embedding_grad.py with the
+          sort and the gather round it, device ms; the kernel's table
+          bit for bit the float32 sums in sorted stable order
   hc      the hyper-connections' stages alone (ISSUE 43) at the cell
           xing4_train_T4k's shape, a float32 stream [4096, 4 x 3584]:
           "mix" and "merge", forward and backward, the jax.numpy form
@@ -159,6 +167,9 @@ def kernels_in_interpret_mode():
         x, d, rows, rotate, force or "interpret")
     from paddle_tpu.ops import moe_rows
     moe_rows._resolve_path = lambda shape, like, force: force or "interpret"
+    from paddle_tpu.ops import embedding_grad
+    embedding_grad._resolve_path = (
+        lambda ids, shape, dtype, like, force: force or "interpret")
 
 
 # --------------------------------------------------------------------------
@@ -1131,6 +1142,62 @@ def phase_rows(seed, rehearse):
         log("[rows] (REHEARSAL: a CPU's times, no device number)")
 
 
+def phase_embed(seed, rehearse):
+    """The embedding's gradient alone (ISSUE 58): T float32 rows of d
+    summed into a table [V, d] at their ids, at the shapes of five
+    cells, the ids drawn uniformly (the benchmark's traffic: most ids
+    distinct, the most rows to write) and Zipf-like (half the places on
+    1% of the ids: a corpus's long runs). XLA's scatter-add (what
+    `jnp.take`'s gradient is) against ops/embedding_grad.py's path: the
+    sort of the ids, XLA's gather of dy by the order, and the kernel
+    `embedding_grad_rows`; device times of the jitted call under the
+    profiler, with the ops of the kernel's path apart. The kernel's
+    table must equal, bit for bit, float32 additions in sorted stable
+    order (numpy's, on the host); XLA's promises no order, so its
+    distance is only reported."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import embedding_grad as eg
+    cells = [("tiny", 200, 150, 256)] if rehearse else [
+        ("smallthinker_train_T16k", 16384, 37984, 2560),
+        ("olmohybrid_train_T8k", 8192, 12544, 3840),
+        ("xing4_train_T4k", 4096, 16384, 3584),
+        ("lfm2_train_T32k", 32768, 8192, 2048),
+        ("opt350m_train", 8192, 50272, 1024)]
+    path = "interpret" if rehearse else "pallas"
+    calls = 2 if rehearse else 10
+    rng = np.random.RandomState(seed + 58)
+    for cell, t, v, d in cells:
+        dy = rng.randn(t, d).astype(np.float32)
+        draws = {"uniform": rng.randint(0, v, t),
+                 "zipf-like": np.where(rng.rand(t) < 0.5,
+                                       rng.randint(0, max(v // 100, 1), t),
+                                       rng.randint(0, v, t))}
+        for draw, ids in draws.items():
+            ids = ids.astype(np.int32)
+            args = (jnp.asarray(ids), jnp.asarray(dy))
+            timed = lambda how: _device_ms(
+                jax.jit(lambda ids, dy: eg.embedding_grad(ids, dy, v, how)),
+                args, calls, rehearse, "embed_trace")
+            xla_ms, xla, _ = timed("xla")
+            ker_ms, got, ops = timed(path)
+            order = np.argsort(ids, kind="stable")
+            want = np.zeros((v, d), np.float32)
+            np.add.at(want, ids[order], dy[order])
+            apart = float(jnp.max(jnp.abs(xla - want)))
+            log("[embed] %s %s: %d ids (%d distinct) into [%d, %d]: XLA's "
+                "scatter-add %.3f ms, the sorted segment sum %.3f ms (%s); "
+                "%.0f MB at 819 GB/s is %.3f ms; XLA's sums from the sorted "
+                "order's by at most %.1e" % (
+                    cell, draw, t, len(np.unique(ids)), v, d, xla_ms, ker_ms,
+                    ", ".join("%s %.3f" % kv for kv in ops.most_common(5)),
+                    (t + v) * d * 4 / 1e6, (t + v) * d * 4 / 819e6, apart))
+            assert np.array_equal(np.asarray(got), want), (
+                "the kernel's table is not the sorted stable sums")
+    if rehearse:
+        log("[embed] (REHEARSAL: a CPU's times, no device number)")
+
+
 # --------------------------------------------------------------------------
 def phase_train(cfg, seed, rehearse):
     """benchmarks/transformer.py's build, 5 steps on one batch."""
@@ -1591,7 +1658,7 @@ def main():
                          "diff, "
                          "rotary, "
                          "experts, "
-                         "rows, hc, "
+                         "rows, embed, hc, "
                          "train, serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: tiny size, Pallas kernels in "
@@ -1619,7 +1686,8 @@ def main():
                   "delta": phase_delta,
                   "diff": phase_diff, "rotary": phase_rotary,
                   "experts": phase_experts,
-                  "rows": phase_rows, "hc": phase_hc,
+                  "rows": phase_rows, "embed": phase_embed,
+                  "hc": phase_hc,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}
         for name in (args.phases.split(",") if args.phases else phases):

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from ..core.program import convert_dtype, runtime_dtype
 from .common import I64
 from ..core.registry import register
+from . import embedding_grad
 
 
 def _np_dtype(d):
@@ -227,16 +228,38 @@ def _truncated_gaussian_random(ctx, op):
     ctx.set_out(op, "Out", out.astype(dtype))
 
 
+def _tied(op, table):
+    """Whether an op that is no lookup reads `table` as an operand: a
+    tied head's `mul`. XLA's scatter-add then accumulates in place into
+    that op's weight gradient; a kernel's separate result is summed
+    inside the head's weight-gradient fusion instead, which then waits
+    for the step's last gradient with its operands held:
+    `lfm2_train_T32k`'s compiled step 1.56 GB more temporaries (my chip
+    run, PR 58)."""
+    return any(table in other.input_names
+               and table not in other.input("Param")    # the optimizer's
+               for block in op.block.program.blocks for other in block.ops
+               if other.type != "lookup_table")
+
+
 @register("lookup_table")
 def _lookup_table(ctx, op):
     """Embedding lookup (operators/lookup_table_op.cc). ids may have a
-    trailing 1 dim (reference convention). padding_idx rows read as zero."""
+    trailing 1 dim (reference convention). padding_idx rows read as zero.
+    The table's gradient is ops/embedding_grad.py's where its dispatch
+    says so (a TPU, float32 rows of whole lane tiles); a sparse, a
+    distributed or a tied table keeps XLA's scatter-add, and so does a
+    step across a mesh (GSPMD cannot partition a Mosaic kernel)."""
     w = ctx.in1(op, "W")
     ids = ctx.in1(op, "Ids").astype(jnp.int32)
     if ids.ndim >= 2 and ids.shape[-1] == 1:
         ids = ids.reshape(ids.shape[:-1])
     padding_idx = op.attr("padding_idx", -1)
-    out = jnp.take(w, jnp.clip(ids, 0, w.shape[0] - 1), axis=0)
+    plain = (op.attr("is_sparse", False) or op.attr("is_distributed", False)
+             or (ctx.mesh is not None and ctx.mesh.size > 1)
+             or _tied(op, op.input("W")[0]))
+    out = embedding_grad.take_rows(w, jnp.clip(ids, 0, w.shape[0] - 1),
+                                   force="xla" if plain else None)
     if padding_idx is not None and padding_idx >= 0:
         mask = (ids != padding_idx)[..., None]
         out = out * mask.astype(out.dtype)

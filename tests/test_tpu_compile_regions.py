@@ -19,7 +19,8 @@ import jax  # noqa: E402
 import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu.core.executor import _normalize_feeds  # noqa: E402
 from paddle_tpu.ops import control_flow as CF  # noqa: E402
-from paddle_tpu.ops import flash_attention, moe_rows, rotary  # noqa: E402
+from paddle_tpu.ops import embedding_grad, flash_attention  # noqa: E402
+from paddle_tpu.ops import moe_rows, rotary  # noqa: E402
 from paddle_tpu.ops import short_conv  # noqa: E402
 from paddle_tpu.parallel import moe  # noqa: E402
 from test_recompute_kinds import (  # noqa: E402
@@ -29,8 +30,11 @@ from test_recompute_kinds import (  # noqa: E402
 @pytest.fixture
 def on_the_chip(monkeypatch):
     """The dispatchers ask JAX for its backend, which is the CPU here:
-    answer for the described chip, and hand the plan its limit."""
-    for module in (flash_attention, rotary, moe_rows):
+    answer for the described chip, and hand the plan its limit. (The
+    embedding's too: `lfm2_train_T32k`'s table is tied, so its lookup
+    keeps XLA's scatter-add; the kernel there would sum inside the
+    head's weight-gradient fusion and hold 1.56 GB more, PR 58.)"""
+    for module in (flash_attention, rotary, moe_rows, embedding_grad):
         monkeypatch.setattr(module, "_on_tpu", lambda x: True)
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: _V5E_LIMIT)
 

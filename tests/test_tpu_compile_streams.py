@@ -233,3 +233,33 @@ def test_gated_delta_rules_jax_numpy_walk_compiles_for_v5e(chip, direction):
     assert max(size for kind, size in sizes if kind == "bf16") < states
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (0.75 if direction == "fwd" else 3) * 2 ** 30, temp
+
+
+# ISSUE 58: the embedding's gradient alone (ops/embedding_grad.py) at the
+# two cells whose step it shortens, ids [T] into a float32 table [V, d].
+@pytest.mark.parametrize("shape", [(16384, 37984, 2560), (8192, 12544, 3840)],
+                         ids=["smallthinker_train_T16k",
+                              "olmohybrid_train_T8k"])
+def test_embedding_grad_compiles_for_v5e(chip, shape):
+    """The gradient of `take_rows` on the path a v5e takes: Mosaic
+    accepts the kernel (a row's read, add and write at a DYNAMIC sublane
+    of both blocks, which interpret mode does not judge), the compiled
+    program holds it by the name a device trace will show, XLA's
+    row-by-row `scatter` is gone from it, and the items' index math is
+    no `while` of gathers."""
+    import re
+    from paddle_tpu.ops import embedding_grad as eg
+    t, vocab, d = shape
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=chip)
+
+    def grad(w, ids, dy):
+        return jax.vjp(lambda w: eg.take_rows(w, ids, force="pallas"),
+                       w)[1](dy)[0]
+
+    text = _compiled_text(grad, sd((vocab, d), jnp.float32),
+                          sd((t,), jnp.int32), sd((t, d), jnp.float32))
+    assert text.count("tpu_custom_call") == 1
+    assert "%embedding_grad_rows." in text or "%embedding_grad_rows " in text
+    assert not re.search(r"f32\[%d,%d\]\S* scatter\(" % (vocab, d), text)
+    assert " scatter(" not in text and " while(" not in text
