@@ -31,6 +31,21 @@ def amp_guard(enable=True):
         _AMP["enabled"] = old
 
 
+@contextlib.contextmanager
+def float32():
+    """``with amp.float32(): ...``: every op built inside carries the
+    attr ``float32``, and a `mul` that carries it reads both operands
+    as float32, multiplies at the highest precision and hands on
+    float32, whatever AMP says: an exit gate's logit, whose ``log(1 -
+    sigmoid)`` a bfloat16 result would flatten and whose gradient it
+    would lose. The elementwise ops never narrow what they read, so a
+    float32 value stays one through them (the survival products, the
+    entropy); ``exit_distribution`` widens by itself."""
+    from .core.program import default_main_program
+    with default_main_program().op_attrs(float32=True):
+        yield
+
+
 def maybe_bf16(*arrays):
     """Cast fp32 arrays to bf16 when AMP is on (inputs to MXU ops)."""
     import jax.numpy as jnp

@@ -1223,6 +1223,9 @@ class Executor:
             if op.type in ("backward_marker", "calc_gradient_marker"):
                 bwd_idx = i
                 break
+        if bwd_idx is not None:
+            ops = _without_forward_only(ops, fetch_names)
+            bwd_idx = ops.index(block.ops[bwd_idx])
         if accum_steps > 1:
             if bwd_idx is None:
                 raise ValueError(
@@ -1652,6 +1655,16 @@ class Executor:
                 "(PADDLE_TPU_CHECK_NAN_INF)" % (var, op_type))
 
 
+def _without_forward_only(ops, fetch_names):
+    """`ops` of a block with a gradient marker less those built under
+    ``layers.forward_only``, which a train step does not lower; all of
+    `ops` where the run fetches one of their results."""
+    held_out = [o for o in ops if o.attr("forward_only")]
+    if set(fetch_names) & {n for o in held_out for n in o.output_names}:
+        return ops
+    return [o for o in ops if not o.attr("forward_only")]
+
+
 def _gather_state(program, scope):
     """The program's persistable vars that exist in the scope, and
     their sorted names (part of every cache key)."""
@@ -1714,7 +1727,8 @@ def _lower_op(ctx, op):
         # a recompute region is not an op of the model: the ops inside
         # it name themselves (numbered on from here), so that a trace
         # attributes a layer's time to its ops and not to the region
-        named = op.type != "recompute_block"
+        # (nor is a `repeat`: its visits' ops name themselves)
+        named = op.type not in ("recompute_block", "repeat")
         scope = jax.named_scope("%s.%d" % (op.type, seq)) if named \
             else contextlib.nullcontext()
         # the op ledger's row goes under the scope's own two halves

@@ -18,6 +18,7 @@ TPU-first differences from the reference:
     are resolved at trace time from the feed.
 """
 
+import contextlib
 import copy
 import json
 
@@ -314,8 +315,8 @@ class Block:
     # -- ops ----------------------------------------------------------------
     def append_op(self, type, inputs=None, outputs=None, attrs=None):
         op = Operator(self, type, inputs, outputs, attrs)
-        if self.program._module is not None:     # layers.module
-            op.attrs.setdefault("module", self.program._module)
+        for name, value in self.program._op_attrs.items():
+            op.attrs.setdefault(name, value)
         self.ops.append(op)
         self.program._bump_version()
         _infer_shape(self, op)
@@ -371,9 +372,8 @@ class Program:
     cache keys on it (replacement for executor.py:165's program cache).
     """
 
-    # the module whose ops are being built (``layers.module``): every op
-    # appended meanwhile carries it as its attr ``module``
-    _module = None
+    # what every op appended meanwhile carries as attrs (``op_attrs``)
+    _op_attrs = {}
 
     def __init__(self):
         self.blocks = [Block(self, 0)]
@@ -420,6 +420,19 @@ class Program:
             yield from blk.vars.values()
 
     # -- clone / prune -------------------------------------------------------
+    @contextlib.contextmanager
+    def op_attrs(self, **attrs):
+        """Every op appended inside carries `attrs` where it names none
+        of its own (``layers.module``, ``layers.forward_only``,
+        ``amp.float32``); an inner context's value stands for its
+        ops."""
+        outer = self._op_attrs
+        self._op_attrs = {**outer, **attrs}
+        try:
+            yield
+        finally:
+            self._op_attrs = outer
+
     def clone(self, for_test=False):
         """Deep copy. With for_test=True, marks the clone as inference-mode:
         ops like dropout/batch_norm lower in eval mode (parity with

@@ -19,7 +19,7 @@ from .nn import (  # noqa: F401
     square_error_cost, topk,
     block_diffusion_attention, block_diffusion_noise, qk_norm_rope,
     rms_norm, rope, silu_mul, hyper_connection, mla_attention,
-    causal_attention, sigmoid_mul,
+    causal_attention, sigmoid_mul, exit_distribution,
 )
 from .ops import *  # noqa: F401,F403
 from .math_ops import scale  # noqa: F401

@@ -22,7 +22,8 @@ from ..core.program import default_main_program, Variable
 __all__ = ["While", "StaticRNN", "DynamicRNN", "IfElse", "Switch",
            "increment", "array_read", "array_write", "array_length",
            "less_than", "equal", "lod_rank_table", "max_sequence_len",
-           "create_array", "zeros_like", "recompute", "module"]
+           "create_array", "zeros_like", "recompute", "module", "repeat",
+           "forward_only"]
 
 
 from .tensor import increment  # noqa: F401  (single implementation)
@@ -127,7 +128,13 @@ class recompute(BlockGuard):
     each transformer layer to train longer sequences / bigger batches
     in the same HBM at up to ~1/3 extra forward FLOPs.
     Fetch intermediates OUTSIDE a region — exporting them would defeat
-    the remat."""
+    the remat. A region inside a ``layers.repeat`` block is a region a
+    VISIT: it keeps its values `times` times, and the plan counts it so
+    (bytes and seconds), takes the float32 gradients of the parameters
+    that several visits read off the room (they are held from the last
+    visit's backward to the first's), and reckons a region that ends in
+    the loss (a visit's head and cross-entropy) as a head moment of its
+    own: its values and the widest of them once more."""
 
     def __init__(self):
         super().__init__(default_main_program())
@@ -164,12 +171,146 @@ def module(name):
     their types cannot (a second block behind the layer stack, a second
     head). Outside any such context an op has no such attr and its row
     says None. An inner context's name stands for its ops."""
-    program = default_main_program()
-    outer, program._module = program._module, str(name)
-    try:
+    with default_main_program().op_attrs(module=str(name)):
         yield
-    finally:
-        program._module = outer
+
+
+@contextlib.contextmanager
+def forward_only():
+    """``with layers.forward_only(): ...``: what a model hands out for
+    a FORWARD run and its train step never reads (a looped model's last
+    visit's logits beside its exit distribution). Every op built inside
+    carries the attr ``forward_only``: a program with a gradient marker
+    lowers none of them, so they have no row in the op ledger and count
+    nowhere in the regions' plan, unless the run fetches one of their
+    results; a ``for_test`` clone, which has no marker, lowers them as
+    any op."""
+    with default_main_program().op_attrs(forward_only=True):
+        yield
+
+
+class repeat:
+    """``times`` visits of ONE block over a carried stream, under the
+    same parameters at every visit (a looped, weight-shared stack)::
+
+        loop = layers.repeat(4)
+        with loop.block():
+            s = loop.carry(x)           # x at the first visit, then
+            ...                         # what the visit before handed on
+            loop.update(s, s_next)
+            loop.output(per_visit)      # handed out by every visit
+        (stacked,) = loop()             # [4, ...]: visit by visit
+        last = loop.final(s)            # s_next of the last visit
+
+    The Program holds the block ONCE: one ``repeat`` op in the parent
+    block, whose reads and writes are real inputs and outputs (as a
+    ``recompute_block``'s), so ``clone(for_test=True)`` and every scan
+    of names see it. A parameter read inside is read by every visit,
+    and its gradient is the sum over the visits. ``layers.recompute``
+    regions nest inside, and the block's plan counts each of them
+    `times` times (ops/control_flow.py, _plan_kept). The lowering
+    traces the block `times` times in a row, a visit under the scope
+    ``visit.<t>``; `times` 1 is the block itself."""
+
+    def __init__(self, times):
+        if int(times) < 1:
+            raise ValueError("repeat: times is %r, and a block runs at "
+                             "least once" % (times,))
+        self.times = int(times)
+        self._program = default_main_program()
+        self._sub_block = None
+        self._carried = []          # [boot var, inner var, updated name]
+        self._outputs = []          # (inner var, stacked outer var)
+        self._finals = {}           # inner name -> outer var
+
+    class _Block(BlockGuard):
+        def __init__(self, loop):
+            super().__init__(loop._program)
+            self.loop = loop
+
+        def __enter__(self):
+            super().__enter__()
+            self.loop._sub_block = self.program.current_block()
+            return self
+
+        def __exit__(self, *exc):
+            super().__exit__(*exc)
+            if exc[0] is None:
+                self.loop._complete()
+            return False
+
+    def block(self):
+        return repeat._Block(self)
+
+    def _parent(self):
+        if self._sub_block is None:
+            raise ValueError("repeat: call inside `with loop.block():`")
+        return self._program.block(self._sub_block.parent_idx)
+
+    def carry(self, init):
+        """The block's own variable that holds `init` at the first
+        visit and what ``update`` named at the visit before after."""
+        self._parent()
+        inner = self._sub_block.create_var(
+            name=unique_name.generate("repeat_carry"), dtype=init.dtype,
+            shape=init.shape)
+        self._carried.append([init, inner, None])
+        return inner
+
+    def update(self, carried, var):
+        for c in self._carried:
+            if c[1] is carried:
+                c[2] = var.name
+                return
+        raise ValueError("repeat: update of %r, which is no carried "
+                         "variable of this block" % carried.name)
+
+    def output(self, *outputs):
+        """Hand `outputs` out at every visit: ``loop()`` gives each
+        stacked ``[times, ...]``, visit by visit."""
+        parent = self._parent()
+        for o in outputs:
+            shape = None if o.shape is None else (self.times,) + tuple(
+                o.shape)
+            self._outputs.append((o, parent.create_var(
+                name=unique_name.generate("repeat_out"), dtype=o.dtype,
+                shape=shape)))
+
+    def _complete(self):
+        for c in self._carried:
+            if c[2] is None:
+                raise ValueError("repeat: carried %r is never updated"
+                                 % c[1].name)
+        parent = self._program.current_block()
+        for init, inner, _ in self._carried:
+            self._finals[inner.name] = parent.create_var(
+                name=unique_name.generate("repeat_final"),
+                dtype=init.dtype, shape=init.shape)
+        own = {c[1].name for c in self._carried}
+        reads, created = [], set(own)
+        for o in self._sub_block.ops:
+            for ns in o.inputs.values():
+                reads.extend(n for n in ns if n not in created)
+            for ns in o.outputs.values():
+                created.update(ns)
+        parent.append_op(
+            type="repeat",
+            inputs={"X": list(dict.fromkeys(reads)),
+                    "Init": [c[0].name for c in self._carried]},
+            outputs={"Out": [outer.name for _, outer in self._outputs],
+                     "Final": [self._finals[c[1].name].name
+                               for c in self._carried]},
+            attrs={"sub_block": self._sub_block, "times": self.times,
+                   "carry_names": [c[1].name for c in self._carried],
+                   "update_names": [c[2] for c in self._carried],
+                   "output_names": [o.name for o, _ in self._outputs]})
+
+    def final(self, carried):
+        """What the last visit handed on for `carried`."""
+        return self._finals[carried.name]
+
+    def __call__(self):
+        return [outer for _, outer in self._outputs]
 
 
 class While:

@@ -483,6 +483,19 @@ def rms_norm(input, epsilon=1e-6, groups=1, param_attr=None, name=None):
     return out
 
 
+def exit_distribution(gates, name=None):
+    """The log of a looped model's exit distribution from its exit
+    gate's logits `gates` ``[R, ...]``, visit by visit, float32: ``log
+    p_t = log sigmoid(g_t) + sum_{j<t} log(1 - sigmoid(g_j))`` for t <
+    R, and the remainder ``sum_{j<R} log(1 - sigmoid(g_j))`` at R."""
+    helper = LayerHelper("exit_distribution", name=name)
+    out = helper.create_variable_for_type_inference("float32",
+                                                    shape=gates.shape)
+    helper.append_op(type="exit_distribution", inputs={"X": [gates]},
+                     outputs={"Out": [out]})
+    return out
+
+
 def rope(input, n_head, theta=10000.0, wrap=0, name=None):
     """Rotary position embedding (rotate-half form) of ``[B, T, H * D]``
     by each row's position: its index in T, modulo `wrap` where given

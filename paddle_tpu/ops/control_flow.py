@@ -286,6 +286,10 @@ def _op_reads(o, seen=None):
     return names
 
 
+def _regions_in(ops):
+    return sum(o.type == "recompute_block" for o in ops)
+
+
 def _later_reads(ops, idx):
     """What the ops after ops[idx] may read."""
     return set().union(*(_op_reads(o) for o in ops[idx + 1:]))
@@ -304,6 +308,17 @@ def reached_from(ops, names):
         for o in ops:
             if o.type == "recompute_block":
                 walk(o.attr("sub_block").ops)
+            elif o.type == "repeat":
+                # a visit reads what the visit before handed on: twice,
+                # so that what an update reaches is reached as carried
+                for _ in range(2):
+                    reach.update(c for c, i, u in zip(
+                        o.attr("carry_names"), o.input("Init"),
+                        o.attr("update_names")) if {i, u} & reach)
+                    walk(o.attr("sub_block").ops)
+                if set(o.attr("update_names") + o.attr("output_names")) \
+                        & reach:
+                    reach.update(o.output_names)
             elif _op_reads(o) & reach:
                 reach.update(o.output_names)
 
@@ -428,6 +443,28 @@ def _plan_kept(ctx):
       alone, any other to both. The gradients are not taken off
       besides: XLA frees one as its update has read it (PERF.md
       section 6, PR 48).
+    * visits (a ``layers.repeat`` block, whose regions the walk finds
+      in the block and takes in the step's order, `times` times each):
+      a VISIT is one run of the block, and a region of it keeps its
+      values once a visit, so a candidate there holds `times` times the
+      bytes and spares `times` times the seconds (the same seconds a
+      byte: all its visits are admitted or none); what the block's
+      other ops write, and what its regions hand on, is stream `times`
+      times. Of the block's last region the last visit alone is "the
+      last region": the visits before it are charged to both moments.
+      The room is less, at both, by the float32 gradients of every
+      parameter the block reads where `times` is over 1: such a
+      gradient is the sum over the visits and is held from the last
+      visit's backward to the first's, which the rule above ("XLA
+      frees one as its update has read it") does not cover. A region
+      that ENDS IN THE LOSS (it holds a cross-entropy: a visit's head)
+      is a head moment of its own: its values and the widest of them
+      once more, where another region is reckoned at twice its values;
+      the larger of the two kinds stands at the last region's
+      backward. Its logits are priced as any `mul` result. What a
+      model builds under ``layers.forward_only`` (the last visit's
+      logits, for a forward run) is not lowered in a train step and
+      counts nowhere.
     * admit in order what fits at every moment it is charged to; a
       candidate that does not fit is passed over.
 
@@ -442,21 +479,14 @@ def _plan_kept(ctx):
     marker = next((i for i, o in enumerate(ops) if o.type in (
         "backward_marker", "calc_gradient_marker")), None)
     limit = _device_limit(ctx) if marker is not None else 0
-    regions = [i for i, o in enumerate(ops[:marker])
-               if o.type == "recompute_block"]
-    _LAST.clear()
-    if not limit or not regions:
-        return nothing
-    if getattr(ctx.executor, "_keep_nothing", False):
-        _LOG.info("recompute: a plan of nothing (the step did not "
-                  "compile with the regions' plan)")
-        return nothing
     peak, hbm = _RATES.get(getattr(_device(ctx), "device_kind", None),
                            _RATES["TPU v5e"])
     # of each variable the walk has met: its Program variable (a
     # region's own live in its sub-block, which a later region's does
     # not see), its elements and its bytes; and the `mul` results' names
     declared, elements, nbytes, products = {}, {}, {}, set()
+    carried = {c: i for o in ops[:marker] if o.type == "repeat"
+               for c, i in zip(o.attr("carry_names"), o.input("Init"))}
 
     def var_of(blk, name):
         if name not in declared:
@@ -466,6 +496,8 @@ def _plan_kept(ctx):
     def count(name):
         if name in env:
             return env[name].size
+        if name in carried:       # a `repeat` block's: what it starts as
+            return count(carried[name])
         if name not in elements:
             raise _Unsized(name)
         return elements[name]
@@ -566,28 +598,81 @@ def _plan_kept(ctx):
             yield EXPERTS_WEIGHTS, 3 * held * d * f * computed_w, \
                 3 * held * d * f * (declared_w + computed_w) / hbm, None
 
-    candidates, stream, head, widest, largest = [], 0, 0, 0, 0
+    # the units of the step in the order it runs them: (block, the
+    # block's ops, index, times, what the block hands on besides). A
+    # `repeat` block's ops come `times` times in the place of their op,
+    # which follows them for what it writes itself (the stacked outputs)
+    from ..core.executor import _without_forward_only
+    lowered = {id(o) for o in _without_forward_only(
+        ops, getattr(ctx, "fetch_names", ()))}
+    flat = []
+    for i, o in enumerate(ops[:marker]):
+        if o.type == "repeat":
+            sub = o.attr("sub_block")
+            handed = set(o.attr("update_names") + o.attr("output_names"))
+            flat += [(sub, list(sub.ops), j, int(o.attr("times")), handed)
+                     for j in range(len(sub.ops))]
+        flat.append((block, ops, i, 1, set()))
+    is_region = lambda unit: unit[1][unit[2]].type == "recompute_block"
+    last_at = max((k for k, u in enumerate(flat) if is_region(u)),
+                  default=None)
+    _LAST.clear()
+    if not limit or last_at is None:
+        return nothing
+    if getattr(ctx.executor, "_keep_nothing", False):
+        _LOG.info("recompute: a plan of nothing (the step did not "
+                  "compile with the regions' plan)")
+        return nothing
+    loops = [o for o in ops[:marker] if o.type == "repeat"]
+    # the float32 gradients of the parameters that several visits read
+    # are held from the last visit's backward to the first's
+    wrt = set(ops[marker].attr("param_names") or ())
+    shared = sum(env[n].size * env[n].dtype.itemsize for n in set().union(
+        *(_op_reads(o) for o in loops if int(o.attr("times")) > 1)) & wrt
+        if n in env)
+    visits = sum(u[3] for u in flat if is_region(u))
+
+    candidates, stream, head, widest, largest, at_loss = [], 0, 0, 0, 0, 0
+    first_of, region = {}, 0     # a block's first region, in step order
     try:
-        for i, o in enumerate(ops[:marker]):
+        for k, (blk, blk_ops, i, times, handed) in enumerate(flat):
+            o = blk_ops[i]
             if o.type != "recompute_block":
-                size = sized(block, o)
-                if i < regions[-1]:
-                    stream += size
-                else:
+                size = sized(blk, o)
+                if blk is block and id(o) not in lowered:
+                    continue                 # (layers.forward_only)
+                if k < last_at:
+                    stream += size * times
+                else:        # (of a block's visits the last alone)
+                    stream += size * (times - 1)
                     head, widest = head + size, max(widest, size)
                 continue
             sub = o.attr("sub_block")
-            region = regions.index(i)
-            own = 0
+            per_visit = _regions_in(blk_ops)
+            if id(blk) not in first_of:
+                first_of[id(blk)] = region
+                region += times * per_visit
+            indices = tuple(first_of[id(blk)] + t * per_visit
+                            + _regions_in(blk_ops[:i]) for t in range(times))
+            own, own_widest, ends_in_loss = 0, 0, False
             for j, m in enumerate(sub.ops):
-                own += sized(sub, m)
+                size = sized(sub, m)
+                own, own_widest = own + size, max(own_widest, size)
+                ends_in_loss |= m.type in ("softmax_with_cross_entropy",
+                                           "cross_entropy")
                 for name, size, seconds, op_id in priced(sub, sub.ops, j):
-                    # (six digits: equals stay equal whatever their size)
-                    candidates.append((float("%.6g" % (
-                        seconds / max(size, 1))), name, size, region, op_id))
-            largest = max(largest, own)
-            stream += sum(nbytes[n] for n in
-                          set(o.output("Out")) & _later_reads(ops, i))
+                    # (six digits: equals stay equal whatever their
+                    # size; a block's visits keep a value each)
+                    candidates.append((
+                        float("%.6g" % (seconds / max(size, 1))), name,
+                        size * times, k == last_at,
+                        size * (times - (k == last_at)), op_id, indices))
+            if ends_in_loss:
+                at_loss = max(at_loss, own + own_widest)
+            else:
+                largest = max(largest, own)
+            stream += times * sum(nbytes[n] for n in set(o.output("Out")) & (
+                _later_reads(blk_ops, i) | handed))
     except _Unsized as e:
         _LOG.info("recompute: the shape of %s does not say its size; "
                   "nothing is kept", e)
@@ -596,26 +681,26 @@ def _plan_kept(ctx):
                 for n, v in block.vars.items() if v.persistable and n in env)
     # the room at the head, for all that is kept, and at the last
     # region's backward, for what the regions before it keep
-    at_head = max(0, limit - state - stream - (head + widest))
-    before_last = max(0, limit - state - stream - 2 * largest)
-    last = len(regions) - 1
+    at_head = max(0, limit - state - shared - stream - (head + widest))
+    before_last = max(0, limit - state - shared - stream
+                      - max(2 * largest, at_loss))
     kept_ops, kept_names, all_kept, early_kept = {}, {}, 0, 0
     by_kind = {name: [0, 0, 0] for name in (
         MUL_OUT, CONV_OUT, DELTA_OUT, EXPERTS_OUT, EXPERTS_ROUTE,
         EXPERTS_WEIGHTS)}
     # (a stable sort: the last region's first among equals, then
-    # program order)
-    for _, name, size, region, op_id in sorted(
-            candidates, key=lambda c: (-c[0], c[3] != last)):
+    # the step's order)
+    for _, name, size, is_last, early, op_id, indices in sorted(
+            candidates, key=lambda c: (-c[0], not c[3])):
         kind = by_kind[name]
-        kind[0] += 1
-        early = size if region != last else 0
+        kind[0] += len(indices)
         if all_kept + size > at_head or early_kept + early > before_last:
             continue
         all_kept, early_kept = all_kept + size, early_kept + early
-        kind[1:] = kind[1] + 1, kind[2] + size
+        kind[1:] = kind[1] + len(indices), kind[2] + size
         if op_id is None:
-            kept_names.setdefault(region, set()).add(name)
+            for r in indices:
+                kept_names.setdefault(r, set()).add(name)
         else:
             kept_ops[op_id] = name
     for name, counts in by_kind.items():
@@ -627,9 +712,9 @@ def _plan_kept(ctx):
     _MUL_PLAN.set(before_last, what="budget_bytes")
     _PLAN.set(before_last, kind="all", what="budget_bytes")
     _PLAN.set(at_head, kind="all", what="head_budget_bytes")
-    _LAST.update(limit=limit, state=state, kept=all_kept,
+    _LAST.update(limit=limit, state=state + shared, kept=all_kept,
                  kept_before_last=early_kept, stream=stream,
-                 head=head + widest, region=2 * largest)
+                 head=head + widest, region=max(2 * largest, at_loss))
     _LOG.info(
         "recompute: kept %s; %d bytes in all of a room of %d at the head, "
         "%d of them in the regions before the last of a room of %d at its "
@@ -640,6 +725,13 @@ def _plan_kept(ctx):
         or "nothing",
         all_kept, at_head, early_kept, before_last, limit, state, stream,
         head, widest, largest)
+    if shared or at_loss:
+        _LOG.info(
+            "recompute: %d regions a step, the visits of a `repeat` block "
+            "counted each; the room is less by %d bytes of float32 "
+            "gradients of the parameters that several visits read, and a "
+            "region that ends in the loss holds %d bytes at its backward",
+            visits, shared, at_loss)
     return kept_ops, {r: frozenset(n) for r, n in kept_names.items()}
 
 
@@ -650,12 +742,15 @@ _LAST = {}
 
 def plans(program):
     """Whether `program`'s step is lowered under a regions' plan: its
-    main block holds a recompute region before a gradient marker. The
-    executor asks before a step's first call (Executor._first_compile)."""
-    types = [o.type for o in program.global_block().ops]
-    marker = next((i for i, t in enumerate(types) if t in (
+    main block, or a `repeat` block of it, holds a recompute region
+    before a gradient marker. The executor asks before a step's first
+    call (Executor._first_compile)."""
+    ops = program.global_block().ops
+    marker = next((i for i, o in enumerate(ops) if o.type in (
         "backward_marker", "calc_gradient_marker")), None)
-    return marker is not None and "recompute_block" in types[:marker]
+    return marker is not None and any(
+        o.type == "recompute_block" or o.type == "repeat"
+        and _regions_in(o.attr("sub_block").ops) for o in ops[:marker])
 
 
 def compiled_step(memory, fell_back=False):
@@ -739,10 +834,13 @@ def _recompute_block(ctx, op):
         raise RuntimeError(
             "recompute_block op not found in its parent block's op list "
             "— the lowering must run on the block that owns the op")
-    region = sum(o.type == "recompute_block" for o in parent_ops[:my_idx])
+    # (a region of a `repeat` block is numbered on from the regions
+    # before its visit: _repeat)
+    region = getattr(ctx, "_region_base", 0) + _regions_in(
+        parent_ops[:my_idx])
     later_reads = _later_reads(parent_ops, my_idx)
-    persistable = {v.name for v in ctx.block.vars.values()
-                   if getattr(v, "persistable", False)} \
+    persistable = {n for n in op.output("Out") if getattr(
+        ctx.block._find_var_recursive(n), "persistable", False)} \
         if ctx.block is not None else set()
     fetches = set(getattr(ctx, "fetch_names", ()))
     out_names = [n for n in op.output("Out")
@@ -811,6 +909,72 @@ def _recompute_block(ctx, op):
     ctx.env.update(lods)
     ctx.env.update(guards)
     ctx._nan_idx = guard_start + len(guards)
+
+
+_VISITS = _REG.counter(
+    "ptpu_repeat_visits_total",
+    "visits of `repeat` blocks lowered (`times` a lowering of the op, "
+    "none a step)")
+
+
+@register("repeat")
+def _repeat(ctx, op):
+    """``layers.repeat``: the sub-block `times` times in a row over the
+    carried values, every visit under the same parameters (whose
+    gradients are then the sums over the visits: the step differentiates
+    one function) and under the scope ``visit_<t>``. The visits' ops are
+    numbered on, so a scope names ONE op of ONE visit and the op ledger
+    has a row for each, with the visit's regions numbered on too; the
+    Program holds the block once.
+
+    inputs:  "X" what the block reads of the parent; "Init" the carried
+             values' first
+    outputs: "Out" each visit's outputs stacked ``[times, ...]``;
+             "Final" what the last visit handed on
+    attrs:   sub_block, times, carry_names, update_names, output_names
+
+    Recompute regions inside keep what the PARENT block's plan admits
+    (_plan_kept counts a region of this block `times` times); what a
+    region hands on to the next visit or out of the block is exported
+    from it as what a fetch names is."""
+    from ..core.executor import _lower_op
+    block, times = op.attr("sub_block"), int(op.attr("times"))
+    carries, updates = op.attr("carry_names"), op.attr("update_names")
+    handed = op.attr("output_names")
+    plan = getattr(ctx, "_kept_plan", None)
+    if plan is None:
+        plan = ctx._kept_plan = _plan_kept(ctx)
+    parent_ops = list(ctx.block.ops) if ctx.block is not None else []
+    my_idx = next((i for i, o in enumerate(parent_ops) if o is op), 0)
+    before = getattr(ctx, "_region_base", 0) + sum(
+        _regions_in(o.attr("sub_block").ops) * int(o.attr("times"))
+        if o.type == "repeat" else o.type == "recompute_block"
+        for o in parent_ops[:my_idx])
+    sctx = LowerContext(ctx.env, ctx._rng_fn, is_test=ctx.is_test,
+                        executor=ctx.executor, block=block, mesh=ctx.mesh,
+                        static_info=ctx.static_info,
+                        fetch_names=tuple(getattr(ctx, "fetch_names", ()))
+                        + tuple(updates) + tuple(handed))
+    sctx.check_nan = getattr(ctx, "check_nan", False)
+    sctx._nan_idx = getattr(ctx, "_nan_idx", 0)
+    sctx._op_seq = getattr(ctx, "_op_seq", 0)
+    sctx._op_log, sctx._kept_plan = ctx._op_log, plan
+    carried = tuple(ctx.get(n) for n in op.input("Init"))
+    outs = []
+    for t in range(times):
+        sctx._region_base = before + t * _regions_in(block.ops)
+        ctx.env.update(zip(carries, carried))
+        with jax.named_scope("visit_%d" % t):
+            for op2 in block.ops:
+                _lower_op(sctx, op2)
+        carried = tuple(ctx.env[n] for n in updates)
+        outs.append([ctx.env[n] for n in handed])
+    _VISITS.inc(times)
+    ctx._op_seq, ctx._nan_idx = sctx._op_seq, sctx._nan_idx
+    for name, value in zip(op.output("Final"), carried):
+        ctx.env[name] = value
+    for name, values in zip(op.output("Out"), zip(*outs)):
+        ctx.env[name] = jnp.stack(values)
 
 
 @register("select_rows_by_mask")

@@ -67,6 +67,22 @@ def _softmax_xent(ctx, op):
     ctx.set_out(op, "Loss", loss)
 
 
+@register("exit_distribution")
+def _exit_distribution(ctx, op):
+    """X ``[R, ...]``, an exit gate's logits visit by visit -> Out, the
+    LOG of the exit distribution, float32 whatever X is: with ``lambda_t
+    = sigmoid(X_t)`` and ``S_t = prod_{j<=t} (1 - lambda_j)``, ``p_t =
+    lambda_t S_{t-1}`` for t < R and ``p_R = S_{R-1}`` (the remainder:
+    the last gate is not read), so that the p sum to 1. In log space:
+    ``log(1 - sigmoid(x)) = log_sigmoid(-x)`` summed along the visits."""
+    x = ctx.in1(op, "X").astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-x[:-1]), axis=0)   # log S_1..
+    survived = jnp.concatenate([jnp.zeros_like(x[:1]), stay])
+    leave = jnp.concatenate([jax.nn.log_sigmoid(x[:-1]),
+                             jnp.zeros_like(x[:1])])
+    ctx.set_out(op, "Out", leave + survived)
+
+
 @register("sigmoid_cross_entropy_with_logits")
 def _sigmoid_xent(ctx, op):
     x = ctx.in1(op, "X")

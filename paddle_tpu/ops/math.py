@@ -42,8 +42,10 @@ def _flatten2d(x, num_col_dims):
 def _mul(ctx, op):
     x = ctx.in1(op, "X")
     y = ctx.in1(op, "Y")
-    out_dtype = x.dtype
-    x, y = _amp_cast(x, y)
+    wide = op.attr("float32", False)              # amp.float32
+    out_dtype = jnp.float32 if wide else x.dtype
+    x, y = (x.astype(out_dtype), y.astype(out_dtype)) if wide \
+        else _amp_cast(x, y)
     xn = op.attr("x_num_col_dims", 1)
     yn = op.attr("y_num_col_dims", 1)
     x2, xshape = _flatten2d(x, xn)
@@ -53,9 +55,12 @@ def _mul(ctx, op):
         # over its second dimension (nothing is transposed in memory)
         y = y2 = y2.T
     ctx.note(mkn=(x2.shape[0],) + y2.shape, operand_dtype=str(x2.dtype))
-    out = jnp.matmul(x2, y2, preferred_element_type=_acc_type(x))
-    from ..amp import amp_out
-    out = amp_out(out, out_dtype)
+    if wide:
+        out = jnp.matmul(x2, y2, precision=lax.Precision.HIGHEST)
+    else:
+        out = jnp.matmul(x2, y2, preferred_element_type=_acc_type(x))
+        from ..amp import amp_out
+        out = amp_out(out, out_dtype)
     out = out.reshape(xshape[:xn] + y.shape[yn:])
     ctx.set_out(op, "Out", out)
 

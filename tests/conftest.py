@@ -131,6 +131,12 @@ _PINS_THE_BENCHMARKS_END = {
         "PR 55 appended a cell and two metrics more. The pin's assertions "
         "run in test_chipbench_joyai.py::"
         "test_pr_51s_pinned_entries_are_as_their_pr_left_them",
+    "test_chipbench_joyai.py::"
+    "test_pr_51s_pinned_entries_are_as_their_pr_left_them":
+        "runs PR 51's pin against the benchmark less what PRs 53 and 55 "
+        "appended; PR 59 appended a cell and four metrics more. The pin's "
+        "assertions run in test_chipbench_ouro.py::"
+        "test_pr_51s_pinned_entries_are_as_their_pr_left_them",
 }
 
 

@@ -899,6 +899,9 @@ COVERED_ELSEWHERE = {
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",
     "routed_experts": "test_block_diffusion.py",
+    # a looped stack (ISSUE 59): the block written out, the reference
+    "repeat": "test_looped_lm.py",
+    "exit_distribution": "test_looped_lm.py",
 }
 
 # ops with no one-op test by design; each entry documents why
