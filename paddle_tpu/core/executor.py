@@ -1707,7 +1707,12 @@ def _lower_op_eager(ctx, op):
                 pass
 
 
-def _lower_op(ctx, op):
+def _lower_op(ctx, op, lower=None):
+    """Lower `op` into ctx.env under its device scope, with its row of
+    the op ledger, its LoD and its NaN guards. `lower`: what to call in
+    place of the op type's registered lowering (a region's head and
+    loss in row blocks stand for their ops with values' shapes:
+    ops/control_flow.py _loss_in_row_blocks)."""
     if op.type in ("feed", "fetch"):
         _lower_feed_fetch(ctx, op)
         return
@@ -1735,7 +1740,7 @@ def _lower_op(ctx, op):
         log = ctx._op_log if named else None
         row = ctx._op_row = None if log is None else log.row(ctx, op, seq)
         with scope:
-            info.lower(ctx, op)
+            (lower or info.lower)(ctx, op)
     except EnforceError:
         raise
     except Exception as e:  # annotate with op context (enforce.h:203 parity)

@@ -18,8 +18,10 @@ training objective's first stage.
   both read by the head and carried into the next visit.
 * Behind every visit, inside the loop: the ONE untied head and the
   tokens' next-token cross-entropy ``ell^(t)`` ``[B, T]`` float32
-  (``layers.module("loop_head")``, a recompute region of their own: the
-  logits of a visit are never held from its forward to its backward),
+  (``layers.module("loop_head")``, a recompute region of their own, which
+  is lowered in row blocks, ``ops/control_flow.py`` _loss_in_row_blocks:
+  the logits of a visit and their softmax are never whole values, in the
+  forward or in the backward, but a block of rows at a time),
   and the exit gate's logit ``g^(t) = s^(t) w_g + b_g`` ``[B, T]``, a
   ``Linear(d, 1)`` with bias in float32 whatever AMP says
   (``layers.module("exit")``, ``amp.float32``).

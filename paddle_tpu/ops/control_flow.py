@@ -12,6 +12,7 @@ reference's RecurrentGradOp entirely. Variable-length sequences use masking
 equivalent of shrink_rnn_memory.
 """
 
+import functools
 import logging
 import math
 
@@ -25,6 +26,8 @@ from ..monitor import metrics as _metrics
 from .common import I64
 from .flash_attention import KEPT_IN_REGIONS
 from .delta_rule import DELTA_OUT, DELTA_STATES, kept_by_a_region
+from .loss import hard_label_rows, hard_label_rows_grad
+from .math import _flatten2d, mul_rows
 from .short_conv import CONV_OUT
 from ..core.registry import register, LowerContext
 from ..parallel.moe import EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS
@@ -207,7 +210,11 @@ _PLAN = _REG.gauge(
     "reads, `admitted` those of them that are kept and `admitted_bytes` "
     "their bytes; under kind `all` also `budget_bytes` (the room for "
     "what the regions before the last keep) and `head_budget_bytes` "
-    "(the room for what all regions keep)",
+    "(the room for what all regions keep); under kind "
+    "`loss_in_row_blocks`, which keeps nothing, `regions`, how many "
+    "regions of the step run a head and its loss in row blocks (a "
+    "block's visits counted each; set with no limit to plan by too), "
+    "and `rows`, the rows of a block",
     ("kind", "what"))
 _COMPILED = _REG.gauge(
     "ptpu_recompute_compiled_bytes",
@@ -222,6 +229,10 @@ _FALLBACKS = _REG.counter(
     "compile with the regions' plan failed with RESOURCE_EXHAUSTED")
 # the ONE name of a `mul` result that a region keeps
 MUL_OUT = "mul_out"
+# the plan's kind of what keeps nothing: the regions of a step that run
+# a head and its loss in row blocks (`regions`, a block's visits counted
+# each) and the rows of a block (`rows`)
+LOSS_BLOCKS = "loss_in_row_blocks"
 _LOG = logging.getLogger(__name__)
 if not _LOG.handlers:
     # the plan's lines (two a build of a program with regions) are what
@@ -364,6 +375,91 @@ def _read_by_a_backward_rule(ops, idx, slot=None):
     return False
 
 
+# What a region of a block that is visited again holds at its backward,
+# in times its declared values, where every other region holds twice
+# them: EMPIRICAL, from `ouro_train_T8k`'s step compiled for a described
+# v5e, which at two stood over the limit (_plan_kept says the readings)
+_VISITED_REGION_TIMES = 3
+# The scope jax.checkpoint gives a region's second forward. The product
+# that a head in row blocks makes again in its backward rule carries it
+# too, inside its `mul` row's scope: a trace's readers tell a second
+# forward by this name, and the work is one whoever orders it
+_SECOND_FORWARD = "rematted_computation"
+# A region's head and loss in row blocks (_loss_in_row_blocks): the
+# float32 [rows, V] block of logits that the loss works on at a time
+# stays under this (1,024 rows at 49,152 columns), as
+# flash_attention._RESIDENT_DQ_BYTES bounds what ONE backward kernel holds
+_LOSS_BLOCK_BYTES = 256 * 2 ** 20
+
+
+def _block_rows(n, columns):
+    """The rows a block of a head and loss in row blocks holds, from the
+    shapes alone: all n where their float32 logits stay under
+    _LOSS_BLOCK_BYTES, else the largest divisor of n that does and is
+    whole sublane tiles (a multiple of 8); 0 where there is none."""
+    most = _LOSS_BLOCK_BYTES // (4 * columns)
+    if n <= most:
+        return n
+    return max((r for r in range(8, most + 1, 8) if n % r == 0), default=0)
+
+
+def _exports(blk, blk_ops, idx, fetched):
+    """What region blk_ops[idx] hands out of itself: of its outputs
+    those that a later op of its block may read, the persistable ones
+    and those in `fetched` (the run's fetch list and, in a `repeat`
+    block, what a visit hands on)."""
+    later = _later_reads(blk_ops, idx)
+    return [n for n in blk_ops[idx].output("Out")
+            if n in later or n in fetched or getattr(
+                blk._find_var_recursive(n), "persistable", False)]
+
+
+def _head_and_loss(sub_ops, exported, shape_of):
+    """What a region that is a head and its loss, and nothing else,
+    gives to lower it in row blocks: ``(mul, loss, never made, K, V)``,
+    the two ops, the names of the values that the block form never
+    makes (the logits under each of their shapes and the op's
+    `Softmax`) and the product's inner and outer width; None for every
+    other region. Told from the Program alone: the region's ops are
+    ONE `mul` that reads its operands from outside (`fc` without bias;
+    `shape_of` says its weight's shape), ONE `softmax_with_cross_entropy`
+    with `soft_label` false, and reshapes; the product's result reaches
+    the op's `Logits` through reshapes only, as ``[rows, V]``; nothing
+    else reads it; the op's `Softmax` is read by nobody; and none of
+    them is in `exported` (_exports)."""
+    kinds = [o.type for o in sub_ops]
+    if kinds.count("mul") != 1 \
+            or kinds.count("softmax_with_cross_entropy") != 1 \
+            or not set(kinds) <= {"mul", "reshape",
+                                  "softmax_with_cross_entropy"}:
+        return None
+    mul = sub_ops[kinds.index("mul")]
+    loss = sub_ops[kinds.index("softmax_with_cross_entropy")]
+    if set(mul.input_names) & set().union(*(
+            o.output_names for o in sub_ops)):
+        return None
+    y_shape, yn = shape_of(mul.input("Y")[0]), mul.attr("y_num_col_dims", 1)
+    k, v = math.prod(y_shape[:yn]), math.prod(y_shape[yn:])
+    if mul.attr("transpose_Y", False):
+        k, v = v, k
+    logits, shape = set(mul.output("Out")), [None] * (
+        mul.attr("x_num_col_dims", 1) + 1)
+    for o in sub_ops[kinds.index("mul") + 1:]:
+        if o.type == "reshape" and set(o.input_names) & logits:
+            logits.update(o.output_names)
+            if o.output("Out") == loss.input("Logits"):
+                shape = list(o.attr("shape"))
+    never_made = logits | set(loss.output("Softmax"))
+    if loss.attr("soft_label", False) \
+            or not set(loss.input("Logits")) <= logits \
+            or set(loss.input("Label")) & logits \
+            or len(shape) != 2 or shape[1] not in (v, None) \
+            or never_made & set(exported) or any(
+                set(loss.output("Softmax")) & _op_reads(o) for o in sub_ops):
+        return None
+    return mul, loss, frozenset(never_made), k, v
+
+
 class _Unsized(Exception):
     """A Program variable whose declared shape does not give its size."""
 
@@ -457,12 +553,32 @@ def _plan_kept(ctx):
       gradient is the sum over the visits and is held from the last
       visit's backward to the first's, which the rule above ("XLA
       frees one as its update has read it") does not cover. A region
+      of a block that is visited several times is reckoned at
+      _VISITED_REGION_TIMES its values at its backward, not at twice.
+      That three is EMPIRICAL, one cell's reading and no derivation:
+      at twice, the plan of `ouro_train_T8k` admits 64 products (2.62
+      GB) and the step compiled for a described v5e stands 0.85 GiB
+      over the limit; beside the state, the stream and what is kept it
+      holds 3.2 GB, 2.8 times a layer region's values, with two visits'
+      second forwards among its largest allocations at once (a second
+      forward waits on nothing but what was kept; PERF.md section 6,
+      PR 60; tests/test_recompute_visits.py pins both plans'
+      arithmetic). Ordering a layer region's second forward behind the
+      cotangent that comes into it, as a head's rule now does
+      (_rows_function), is what would let it be two again. A region
       that ENDS IN THE LOSS (it holds a cross-entropy: a visit's head)
       is a head moment of its own: its values and the widest of them
       once more, where another region is reckoned at twice its values;
       the larger of the two kinds stands at the last region's
-      backward. Its logits are priced as any `mul` result. What a
-      model builds under ``layers.forward_only`` (the last visit's
+      backward. Its logits are priced as any `mul` result. One that is
+      lowered IN ROW BLOCKS (_in_row_blocks: a head, its loss and
+      nothing else) never makes its logits nor its softmax: it holds,
+      at its backward, its other values, the logits' gradient whole in
+      the logits' dtype and, of ONE block, the logits and in float32
+      their softmax and its gradient; its product is no candidate,
+      there being nothing to keep; the plan counts such regions under
+      the kind LOSS_BLOCKS, with a limit to plan by or with none. What
+      a model builds under ``layers.forward_only`` (the last visit's
       logits, for a forward run) is not lowered in a train step and
       counts nowhere.
     * admit in order what fits at every moment it is charged to; a
@@ -617,23 +733,15 @@ def _plan_kept(ctx):
     last_at = max((k for k, u in enumerate(flat) if is_region(u)),
                   default=None)
     _LAST.clear()
-    if not limit or last_at is None:
+    for what in ("regions", "rows"):
+        _PLAN.set(0, kind=LOSS_BLOCKS, what=what)
+    if last_at is None:
         return nothing
-    if getattr(ctx.executor, "_keep_nothing", False):
-        _LOG.info("recompute: a plan of nothing (the step did not "
-                  "compile with the regions' plan)")
-        return nothing
-    loops = [o for o in ops[:marker] if o.type == "repeat"]
-    # the float32 gradients of the parameters that several visits read
-    # are held from the last visit's backward to the first's
-    wrt = set(ops[marker].attr("param_names") or ())
-    shared = sum(env[n].size * env[n].dtype.itemsize for n in set().union(
-        *(_op_reads(o) for o in loops if int(o.attr("times")) > 1)) & wrt
-        if n in env)
-    visits = sum(u[3] for u in flat if is_region(u))
 
-    candidates, stream, head, widest, largest, at_loss = [], 0, 0, 0, 0, 0
+    candidates, stream, head, widest, at_loss = [], 0, 0, 0, 0
+    largest = 0, 0        # (the term, the largest region's own values)
     first_of, region = {}, 0     # a block's first region, in step order
+    fetched, in_blocks, block_rows = set(getattr(ctx, "fetch_names", ())), 0, 0
     try:
         for k, (blk, blk_ops, i, times, handed) in enumerate(flat):
             o = blk_ops[i]
@@ -655,35 +763,71 @@ def _plan_kept(ctx):
             indices = tuple(first_of[id(blk)] + t * per_visit
                             + _regions_in(blk_ops[:i]) for t in range(times))
             own, own_widest, ends_in_loss = 0, 0, False
+            mul, _, never_made, rows, columns = _in_row_blocks(
+                ctx, sub.ops, _exports(blk, blk_ops, i, fetched | handed),
+                lambda name: var_of(sub, name).shape, count) or (
+                    None, None, frozenset(), 0, 0)
             for j, m in enumerate(sub.ops):
-                size = sized(sub, m)
+                size = sized(sub, m) - sum(
+                    nbytes[n] for n in set(m.output_names) & never_made)
                 own, own_widest = own + size, max(own_widest, size)
                 ends_in_loss |= m.type in ("softmax_with_cross_entropy",
                                            "cross_entropy")
-                for name, size, seconds, op_id in priced(sub, sub.ops, j):
+                for name, size, seconds, op_id in () if m is mul \
+                        else priced(sub, sub.ops, j):
                     # (six digits: equals stay equal whatever their
                     # size; a block's visits keep a value each)
                     candidates.append((
                         float("%.6g" % (seconds / max(size, 1))), name,
                         size * times, k == last_at,
                         size * (times - (k == last_at)), op_id, indices))
-            if ends_in_loss:
+            if rows:
+                # what the block form holds at its backward's worst: the
+                # logits' gradient whole, in the logits' dtype, beside
+                # ONE block's logits and, in float32, its softmax and
+                # its gradient
+                out = mul.output("Out")[0]
+                whole = elements[out] * itemsize(sub, out)
+                at_loss = max(at_loss, own + whole + rows * columns * (
+                    itemsize(sub, out) + 8))
+                in_blocks, block_rows = in_blocks + times, rows
+            elif ends_in_loss:
                 at_loss = max(at_loss, own + own_widest)
             else:
-                largest = max(largest, own)
+                largest = max(largest, ((
+                    _VISITED_REGION_TIMES if times > 1 else 2) * own, own))
             stream += times * sum(nbytes[n] for n in set(o.output("Out")) & (
                 _later_reads(blk_ops, i) | handed))
     except _Unsized as e:
-        _LOG.info("recompute: the shape of %s does not say its size; "
-                  "nothing is kept", e)
+        if limit:
+            _LOG.info("recompute: the shape of %s does not say its size; "
+                      "nothing is kept", e)
         return nothing
+    _PLAN.set(in_blocks, kind=LOSS_BLOCKS, what="regions")
+    _PLAN.set(block_rows, kind=LOSS_BLOCKS, what="rows")
+    if not limit:
+        return nothing
+    if getattr(ctx.executor, "_keep_nothing", False):
+        _LOG.info("recompute: a plan of nothing (the step did not "
+                  "compile with the regions' plan); %d regions run their "
+                  "head and loss in blocks of %d rows", in_blocks,
+                  block_rows)
+        return nothing
+    loops = [o for o in ops[:marker] if o.type == "repeat"]
+    # the float32 gradients of the parameters that several visits read
+    # are held from the last visit's backward to the first's
+    wrt = set(ops[marker].attr("param_names") or ())
+    shared = sum(env[n].size * env[n].dtype.itemsize for n in set().union(
+        *(_op_reads(o) for o in loops if int(o.attr("times")) > 1)) & wrt
+        if n in env)
+    visits = sum(u[3] for u in flat if is_region(u))
     state = sum(env[n].size * env[n].dtype.itemsize
                 for n, v in block.vars.items() if v.persistable and n in env)
     # the room at the head, for all that is kept, and at the last
     # region's backward, for what the regions before it keep
     at_head = max(0, limit - state - shared - stream - (head + widest))
     before_last = max(0, limit - state - shared - stream
-                      - max(2 * largest, at_loss))
+                      - max(largest[0], at_loss))
     kept_ops, kept_names, all_kept, early_kept = {}, {}, 0, 0
     by_kind = {name: [0, 0, 0] for name in (
         MUL_OUT, CONV_OUT, DELTA_OUT, EXPERTS_OUT, EXPERTS_ROUTE,
@@ -714,17 +858,19 @@ def _plan_kept(ctx):
     _PLAN.set(at_head, kind="all", what="head_budget_bytes")
     _LAST.update(limit=limit, state=state + shared, kept=all_kept,
                  kept_before_last=early_kept, stream=stream,
-                 head=head + widest, region=max(2 * largest, at_loss))
+                 head=head + widest, region=max(largest[0], at_loss))
     _LOG.info(
         "recompute: kept %s; %d bytes in all of a room of %d at the head, "
         "%d of them in the regions before the last of a room of %d at its "
         "backward (the device's limit %d less state %d, stream %d and: "
-        "head %d + %d; largest region 2 x %d)",
+        "head %d + %d; largest region %d x %d); %d regions run their head "
+        "and loss in blocks of %d rows",
         ", ".join("%d of %d %s (%d bytes)" % (c[1], c[0], name, c[2])
                   for name, c in sorted(by_kind.items()) if c[0])
         or "nothing",
         all_kept, at_head, early_kept, before_last, limit, state, stream,
-        head, widest, largest)
+        head, widest, largest[0] // max(largest[1], 1), largest[1],
+        in_blocks, block_rows)
     if shared or at_loss:
         _LOG.info(
             "recompute: %d regions a step, the visits of a `repeat` block "
@@ -788,6 +934,173 @@ def compiled_step(memory, fell_back=False):
         _LAST["region"], _LAST["kept_before_last"])
 
 
+def _in_row_blocks(ctx, sub_ops, exported, shape_of, elements_of):
+    """``(mul, loss, never made, rows, V)`` where a region of these ops
+    is lowered in blocks of `rows` rows (_head_and_loss says which
+    regions, _block_rows at how many rows), else None: so under
+    `is_test` and a mesh (a block of rows would cut across the batch's
+    shards), which lower as ever. `shape_of` and `elements_of` say a
+    variable's shape and size by its name: the lowering from the values
+    it holds, the plan from the Program, and they agree."""
+    if ctx.is_test or ctx.mesh is not None:
+        return None
+    head = _head_and_loss(sub_ops, exported, shape_of)
+    if not head:
+        return None
+    mul, loss, never_made, k, v = head
+    rows = _block_rows(elements_of(mul.input("X")[0]) // k, v)
+    return (mul, loss, never_made, rows, v) if rows else None
+
+
+def _loss_in_row_blocks(sctx, sub_ops, mul, loss, never_made, rows):
+    """Lower a region that is a head and its loss (_head_and_loss) as
+    ONE function in blocks of `rows` rows under a backward rule of its
+    own, into sctx.env. The rule IS the region's recompute, so the
+    function stands outside jax.checkpoint:
+
+    * forward, a block at a time: the block's logits as the `mul` makes
+      them (math.mul_rows: the operands' precision, the accumulator and
+      the rounding are its own), then the rows' loss in float32
+      (loss.hard_label_rows). Out come the op's `Loss` and, kept for
+      the backward, the operands, the labels and the two parts of the
+      rows' log-sum-exp: no ``[N, V]`` value outlives a block.
+    * backward, a block at a time: the logits made again by the same
+      product (the same bits), their gradient under the ROWS' own
+      cotangents (loss.hard_label_rows_grad) rounded to the logits'
+      dtype; the blocks joined into ONE ``[N, V]`` gradient, which the
+      `mul`'s own two transposes read (jax.linear_transpose of
+      mul_rows): `dx` and `dW` are the whole products they would be
+      under autodiff, in its order of summation.
+
+    The blocks are unrolled, so every device op carries ONE scope: the
+    products, forward, made again and transposed, the `mul` row's
+    (``mul.<seq>``), the loss's arithmetic and its gradient the
+    `softmax_with_cross_entropy` row's; the product made again carries
+    inside it the name of every region's second forward
+    (_SECOND_FORWARD), so a trace's readers book it as one and not as
+    the backward it is called from; both rows are written as ever,
+    the `mul`'s with `row_blocks` = (blocks, rows). The ops themselves
+    are traced for their shapes alone (what a row says of a value that
+    is never made), and with NaN guards on, the guard of each such
+    value reads the rows' log-sum-exp, which is non-finite where a
+    logit is NaN or +inf."""
+    from ..core.executor import _lower_op, _record_nan_guards
+    env, guarded = sctx.env, sctx.check_nan
+    sctx.check_nan = False
+    scopes = {}
+
+    def shapes_alone(ctx2, op2):
+        names = [n for n in op2.input_names if n in env]
+
+        def lowered(*values):
+            ctx2.env = dict(env, **dict(zip(names, values)))
+            registry.lookup(op2.type).lower(ctx2, op2)
+            return {n: ctx2.env[n] for n in op2.output_names}
+
+        try:
+            env.update(jax.eval_shape(lowered, *(env[n] for n in names)))
+        finally:
+            ctx2.env = env
+
+    for op2 in sub_ops:
+        scopes[id(op2)] = "%s.%d" % (op2.type, sctx._op_seq)
+        if not set(op2.output_names) & never_made:
+            _lower_op(sctx, op2)
+            continue
+        _lower_op(sctx, op2, lower=shapes_alone)
+        if op2 is mul:
+            x2, _ = _flatten2d(env[mul.input("X")[0]],
+                               mul.attr("x_num_col_dims", 1))
+            sctx.note(row_blocks=(x2.shape[0] // rows, rows))
+        elif op2 is loss:
+            # (outside the op's scope: inside it every product would
+            # carry the loss's scope before its own)
+            x_name = mul.input("X")[0]
+            env[loss.output("Loss")[0]], lse, handed_on = _rows_function(
+                mul, rows, scopes[id(mul)], scopes[id(loss)])(
+                    x2, env[mul.input("Y")[0]], env[loss.input("Label")[0]])
+            env[x_name] = handed_on.reshape(env[x_name].shape)
+    if guarded:
+        sctx.check_nan = True
+        env.update(dict.fromkeys(never_made, lax.stop_gradient(lse)))
+        for op2 in sub_ops:
+            _record_nan_guards(sctx, op2)
+    for name in never_made:
+        del env[name]
+
+
+def _rows_function(mul, rows, mul_scope, loss_scope):
+    """``f(x2 [N, K], w, label) -> (loss [N, 1], the rows' log-sum-exp
+    [N, 1], x2)`` of _loss_in_row_blocks, its device ops under the two
+    ops' scopes.
+
+    The ORDER is the function's own to state (lax.optimization_barrier:
+    no device op, no copy), for the data's order states none and XLA
+    then holds every block of every visit at once (the step of
+    `ouro_train_T8k` compiled for a described v5e: 3 GB of logits at
+    the forward's end, where it had put all four visits' heads, and as
+    much at the backward's start). A block waits for the block before
+    it. The function hands x2 ON, behind its loss, and what reads the
+    head's input after the region reads it from here: so the stack's
+    next visit waits for this one's head, and the cotangent of all
+    that comes INTO the rule, which waits for it: a visit's head goes
+    backward after the visits behind it have, as its layers do. And
+    what the rule reads of the forward it reads behind a barrier, as a
+    region's second forward does what jax.checkpoint kept: XLA would
+    else take the products made again for the forward's and hold every
+    block's logits from there to here."""
+    product = functools.partial(mul_rows, mul)
+    in_mul = functools.partial(jax.named_scope, mul_scope)
+    in_loss = functools.partial(jax.named_scope, loss_scope)
+    behind = lambda value, done: lax.optimization_barrier((value, done))
+
+    def blocks(*values):
+        """(the block's first row, its rows of each value)"""
+        return ((at,) + tuple(v[at:at + rows] for v in values)
+                for at in range(0, len(values[0]), rows))
+
+    def forward(x2, w, label):
+        label, parts = label.reshape(-1, 1), []
+        for _, x_b, label_b in blocks(x2, label):
+            if parts:
+                x_b, parts[-1] = behind(x_b, parts[-1])
+            with in_mul():
+                logits = product(x_b, w)
+            with in_loss():
+                parts.append(hard_label_rows(logits, label_b))
+        with in_loss():
+            made, top, total = (jnp.concatenate(p) for p in zip(*parts))
+        handed_on, made = behind(x2, made)
+        return (made, top + jnp.log(total), handed_on), (
+            x2, w, label, top, total)
+
+    def backward(kept, cotangents):
+        x2, w, label, top, total = kept
+        (x2, top, total), (g, _, after) = behind((x2, top, total),
+                                                 cotangents)
+        d_logits = None
+        for at, x_b, *rows_b in blocks(x2, label, top, total, g):
+            if d_logits is not None:
+                x_b, d_logits = behind(x_b, d_logits)
+            with in_mul(), jax.named_scope(_SECOND_FORWARD):
+                logits = product(x_b, w)
+            with in_loss():
+                # the blocks' gradients into ONE [N, V] value, in place
+                part = hard_label_rows_grad(logits, *rows_b)
+                if d_logits is None:
+                    d_logits = jnp.zeros((len(x2),) + part.shape[1:],
+                                         part.dtype)
+                d_logits = lax.dynamic_update_slice(d_logits, part, (at, 0))
+        with in_mul():
+            dx, = jax.linear_transpose(lambda a: product(a, w), x2)(d_logits)
+            dw, = jax.linear_transpose(lambda b: product(x2, b), w)(d_logits)
+            return dx + after, dw, None
+
+    f = jax.custom_vjp(lambda *operands: forward(*operands)[0])
+    f.defvjp(forward, backward)
+    return f
+
+
 @register("recompute_block")
 def _recompute_block(ctx, op):
     """Rematerialization region: lower the sub-block under jax.checkpoint
@@ -815,9 +1128,22 @@ def _recompute_block(ctx, op):
     at the precision the forward made it. A region still runs TWICE:
     the stream's norms, `qk_norm_rope` / `rope`, the gates, the
     hyper-connections' stages, the scans, the keys and values spread
-    under grouped heads, and whatever the plan passed over. A region
+    under grouped heads, a head's product where the region is the head
+    and its loss, and whatever the plan passed over. A region
     with no flash kernel in it on a device that states no limit (the
     CPU) keeps nothing and lowers as under a bare jax.checkpoint.
+
+    A region that is a head and its loss and nothing else
+    (_head_and_loss: a `mul`, a hard-label `softmax_with_cross_entropy`
+    and reshapes, the logits and the softmax read by nothing else) is
+    lowered, outside jax.checkpoint, as ONE function in row blocks
+    under a backward rule of its own (_loss_in_row_blocks): the logits
+    and their float32 softmax are no values any more, the product runs
+    twice (forward, and again a block at a time in the rule), and the
+    NaN guards of the values that are never made read the rows'
+    log-sum-exp. Soft labels, a `Softmax` that is read or fetched,
+    logits handed on, a bias, rows with no block that fits, `is_test`
+    and a mesh lower as every other region, bit for bit.
 
     Outputs exported from the region are the sub-block writes consumed
     by LATER ops of the parent block (looking through their sub-blocks),
@@ -838,13 +1164,8 @@ def _recompute_block(ctx, op):
     # before its visit: _repeat)
     region = getattr(ctx, "_region_base", 0) + _regions_in(
         parent_ops[:my_idx])
-    later_reads = _later_reads(parent_ops, my_idx)
-    persistable = {n for n in op.output("Out") if getattr(
-        ctx.block._find_var_recursive(n), "persistable", False)} \
-        if ctx.block is not None else set()
-    fetches = set(getattr(ctx, "fetch_names", ()))
-    out_names = [n for n in op.output("Out")
-                 if n in later_reads or n in persistable or n in fetches]
+    out_names = _exports(ctx.block, parent_ops, my_idx,
+                         set(getattr(ctx, "fetch_names", ())))
     in_names = [n for n in op.input("X") if n in ctx.env]
 
     # the block's plan, made where its first region is lowered
@@ -859,15 +1180,8 @@ def _recompute_block(ctx, op):
     op_seq = getattr(ctx, "_op_seq", 0)
     ctx._op_seq = op_seq + len(block.ops)
 
-    def f(vals, key):
-        env = dict(base_env)
-        env.update(zip(in_names, vals))
-        counter = [0]
-
-        def rfn():
-            counter[0] += 1
-            return jax.random.fold_in(key, counter[0])
-
+    def inside(env, rfn):
+        """The context the region's ops are lowered in."""
         sctx = LowerContext(env, rfn, is_test=ctx.is_test,
                             executor=ctx.executor, block=block,
                             mesh=ctx.mesh, static_info=ctx.static_info,
@@ -878,6 +1192,29 @@ def _recompute_block(ctx, op):
         # the op ledger's rows of these ops say which region they sit in
         sctx._op_log, sctx._op_region = ctx._op_log, region
         sctx.kept_ops = kept
+        return sctx
+
+    def handed_on(env):
+        """A region's exports: its outputs + their @LOD lengths
+        (sequence ops inside the region may have changed them) + per-op
+        NaN guards (the every-op-output contract holds inside regions
+        too)."""
+        lods = {n + "@LOD": env[n + "@LOD"] for n in out_names
+                if env.get(n + "@LOD") is not None}
+        guards = {k: v for k, v in env.items()
+                  if k.startswith(_NANGUARD) and k not in base_env}
+        return tuple(env[n] for n in out_names), lods, guards
+
+    def f(vals, key):
+        env = dict(base_env)
+        env.update(zip(in_names, vals))
+        counter = [0]
+
+        def rfn():
+            counter[0] += 1
+            return jax.random.fold_in(key, counter[0])
+
+        sctx = inside(env, rfn)
         for op2 in block.ops:
             _lower_op(sctx, op2)
             names = [kept[id(op2)]] if id(op2) in kept else []
@@ -890,20 +1227,27 @@ def _recompute_block(ctx, op):
                 names += sorted(named_inside)
             if names:
                 sctx.note(kept="+".join(names))
-        # exports: region outputs + their @LOD lengths (sequence ops
-        # inside the region may have changed them) + per-op NaN guards
-        # (the every-op-output contract holds inside regions too)
-        lods = {n + "@LOD": env[n + "@LOD"] for n in out_names
-                if env.get(n + "@LOD") is not None}
-        guards = {k: v for k, v in env.items()
-                  if k.startswith(_NANGUARD) and k not in base_env}
-        return tuple(env[n] for n in out_names), lods, guards
+        return handed_on(env)
 
     _REGIONS.inc()
-    policy = _saves(_IN_EVERY_REGION + tuple(sorted(named_inside))) \
-        if named_inside else _region_policy
-    outs, lods, guards = jax.checkpoint(f, policy=policy)(
-        tuple(ctx.env[n] for n in in_names), region_key)
+    head = _in_row_blocks(ctx, block.ops, out_names,
+                          lambda name: ctx.env[name].shape,
+                          lambda name: ctx.env[name].size)
+    if head:
+        # (no random key is drawn inside: a product, reshapes, a loss)
+        env = dict(base_env)
+        mul, loss, never_made, rows, _ = head
+        _loss_in_row_blocks(inside(env, None), block.ops, mul, loss,
+                            never_made, rows)
+        outs, lods, guards = handed_on(env)
+        # (the head's input as the rule hands it on: _rows_function)
+        x_name = mul.input("X")[0]
+        ctx.env[x_name] = env[x_name]
+    else:
+        policy = _saves(_IN_EVERY_REGION + tuple(sorted(named_inside))) \
+            if named_inside else _region_policy
+        outs, lods, guards = jax.checkpoint(f, policy=policy)(
+            tuple(ctx.env[n] for n in in_names), region_key)
     for n, v in zip(out_names, outs):
         ctx.env[n] = v
     ctx.env.update(lods)

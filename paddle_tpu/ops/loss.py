@@ -67,6 +67,39 @@ def _softmax_xent(ctx, op):
     ctx.set_out(op, "Loss", loss)
 
 
+def hard_label_rows(logits, label):
+    """ROWS of the hard-label `softmax_with_cross_entropy` without their
+    softmax: logits ``[rows, V]`` and label ``[rows, 1]`` or ``[rows]``
+    -> ``(loss, top, total)``, each ``[rows, 1]``, bit for bit the loss
+    `_softmax_xent` gives those rows (``jax.nn.log_softmax``'s
+    arithmetic, read at the label's column alone) and the two parts of
+    the rows' log-sum-exp, ``top + log(total)``: all that
+    `hard_label_rows_grad` needs of the forward."""
+    if logits.dtype == jnp.bfloat16:   # AMP: loss math in fp32
+        logits = logits.astype(jnp.float32)
+    top = jnp.max(logits, axis=-1, initial=-jnp.inf, keepdims=True)
+    shifted = logits - top
+    total = jnp.sum(jnp.exp(shifted), axis=-1, keepdims=True)
+    picked, _ = _gather_label_prob(shifted, label)
+    return -(picked - jnp.log(total)), top, total
+
+
+def hard_label_rows_grad(logits, label, top, total, g):
+    """The gradient of `hard_label_rows`' loss in its logits under the
+    rows' OWN cotangents g ``[rows, 1]``, in the logits' dtype: ``g
+    (softmax - onehot)``, written as autodiff writes it for
+    `_softmax_xent` (``(g / total) exp(logits - top)``, and ``- g`` at
+    the label's column), so the bits are its bits; rounded to the
+    logits' dtype where the cast's transpose rounds it."""
+    wide = logits.astype(jnp.float32) if logits.dtype == jnp.bfloat16 \
+        else logits
+    spread = (g / total) * jnp.exp(wide - top)
+    label = label.reshape(-1, 1).astype(jnp.int32)
+    label = jnp.where(label < 0, label + wide.shape[-1], label)
+    at_label = jax.lax.broadcasted_iota(jnp.int32, wide.shape, 1) == label
+    return jnp.where(at_label, spread - g, spread).astype(logits.dtype)
+
+
 @register("exit_distribution")
 def _exit_distribution(ctx, op):
     """X ``[R, ...]``, an exit gate's logits visit by visit -> Out, the

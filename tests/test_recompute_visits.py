@@ -25,7 +25,7 @@ from paddle_tpu import trace                                # noqa: E402
 from paddle_tpu.models.looped_lm import looped_lm           # noqa: E402
 from paddle_tpu.ops import control_flow as CF               # noqa: E402
 from paddle_tpu.parallel import moe                         # noqa: E402
-from test_recompute_kinds import _cell_plan                 # noqa: E402
+from test_recompute_kinds import _V5E_LIMIT, _cell_plan     # noqa: E402
 
 KINDS = (CF.MUL_OUT, "short_conv_out", "delta_rule_out", moe.EXPERTS_OUT,
          moe.EXPERTS_ROUTE, moe.EXPERTS_WEIGHTS)
@@ -87,30 +87,47 @@ HEAD = 2048 * 49152                                        # 100.66 M
 ROWS = 8192
 
 
-def test_the_plan_at_ouros_shapes_counts_the_visits(monkeypatch):
+def test_the_plan_at_ouros_shapes_counts_the_visits(monkeypatch, caplog):
     """`ouro_train_T8k`, 8 layers and a head region in a block of four
-    visits: 36 regions a step. Every region's candidate counts four
-    times: (8 x 7 products + the logits) x 4 = 228 `mul` results. The
-    room is less by the float32 gradients of everything the block reads
-    (all but the table: 2.05 GB), and the head region is the moment
-    that holds most: its own values (the logits in bf16, the softmax in
-    float32) and the widest once more, 4.03 GB. What fits beside them,
-    at the costliest a byte first, is the seven `down` products before
-    the last layer's at all four visits (K 5632: 28 x 33.5 MB); the
-    logits, 0.8 GB a visit for 2 x 8192 x 2048 x 49152 FLOPs, are
-    priced as any product of K 2048 and passed over."""
-    ops, names = _cell_plan(monkeypatch, "ouro_train_T8k")
+    visits: 36 regions a step. Every layer region's candidate counts
+    four times: 8 x 7 products x 4 = 224 `mul` results; the head's
+    product is none, for its region runs in row blocks (four regions,
+    1,024 rows a block: the float32 block of 49,152 columns stays under
+    256 MB) and makes no logits to keep. The room is less by the
+    float32 gradients of everything the block reads (all but the
+    table: 2.05 GB). A head region holds, at its backward, the logits'
+    gradient whole in bf16 and of ONE block the logits in bf16 and two
+    float32 values, 1.31 GB where the three ops under jax.checkpoint
+    held `logits + 2 * softmax`, 4.03; so the moment that holds most is
+    a LAYER region's backward, reckoned at three times its declared
+    values in a block that is visited again (nine float32 and five
+    bf16 `[8192, 2048]`, two bf16 and one float32 `[8192, 5632]`: 1.14
+    GB; the compile for the described chip confirms the three:
+    tests/test_tpu_compile_regions.py). What fits beside them, at the
+    costliest a byte first, is the seven `down` products before the
+    last layer's at all four visits (K 5632) and then, of the products
+    of K 2048 in the step's order, the first four `[8192, 2048]`: 44 x
+    33.5 MB, where the parent kept 28."""
+    with caplog.at_level("INFO", logger=CF.__name__):
+        ops, names = _cell_plan(monkeypatch, "ouro_train_T8k")
     assert names == {}
     said = _said()
-    assert said[CF.MUL_OUT] == [228, 28, 28 * ROWS * 2048 * 2]
-    assert set(ops.values()) == {CF.MUL_OUT} and len(ops) == 7
+    assert said[CF.MUL_OUT] == [224, 44, 44 * ROWS * 2048 * 2]
+    assert set(ops.values()) == {CF.MUL_OUT} and len(ops) == 11
+    assert [int(CF._PLAN.value(kind=CF.LOSS_BLOCKS, what=w))
+            for w in ("regions", "rows")] == [4, 1024]
     # (the state: 12 bytes a parameter, Adam's powers and rate and the
     # program's seven sums beside them)
     state = 12 * (8 * LAYER + 2 * HEAD + 2048 + 2048 + 1)
     shared = 4 * (8 * LAYER + HEAD + 2048 + 2048 + 1)
     assert 0 < CF._LAST["state"] - (state + shared) < 4096
-    logits, softmax = ROWS * 49152 * 2, ROWS * 49152 * 4
-    assert 0 <= CF._LAST["region"] - (logits + 2 * softmax) < 2 ** 20
+    wide, narrow = ROWS * 5632, ROWS * 2048
+    layer = 9 * 4 * narrow + 5 * 2 * narrow + 2 * 2 * wide + 4 * wide
+    assert CF._LAST["region"] == 3 * layer
+    gradient, block = ROWS * 49152 * 2, 1024 * 49152 * (2 + 4 + 4)
+    loss = ROWS * 4
+    assert "holds %d bytes at its backward" % (gradient + block + loss) \
+        in caplog.text
     # the stream: a float32 [8192, 2048] a layer visit and the final
     # norm's a visit (what a head region reads is that, and what it
     # hands on is a float32 a row)
@@ -120,6 +137,35 @@ def test_the_plan_at_ouros_shapes_counts_the_visits(monkeypatch):
     # the last visit's logits, which the program hands out for a
     # forward run, are never made in a train step: not at the head
     assert CF._LAST["head"] < 2 ** 28
+
+
+def test_the_plan_at_twice_a_visited_region_is_the_one_the_compile_refused(
+        monkeypatch):
+    """The three times of a region in a block that is visited again
+    (CF._VISITED_REGION_TIMES) is EMPIRICAL; this pins the arithmetic of
+    the reading it was taken from. At twice, as every other region is
+    reckoned, `ouro_train_T8k`'s plan has 1.14 GB more room and admits
+    64 of the 224 products, 2.62 GB: sixteen at all four visits, every
+    layer's `down` product, all of layer 0's other six (its `ffn_gate`
+    and `ffn_up` are `[8192, 5632]`) and layer 1's `wq` and `wk`. That
+    plan stands under the limit by its own arithmetic, and the step
+    compiled for
+    the described v5e stood 0.85 GiB OVER it (temporaries 10.47 GB at
+    2.62 kept; at three times: 9.25 at 1.48 kept, 0.31 GB under:
+    PERF.md section 6, PR 60, the slow test of
+    tests/test_tpu_compile_regions.py). Plan arithmetic only: nothing
+    is compiled here."""
+    monkeypatch.setattr(CF, "_VISITED_REGION_TIMES", 2)
+    ops, _ = _cell_plan(monkeypatch, "ouro_train_T8k")
+    wide, narrow = ROWS * 5632, ROWS * 2048
+    layer = 9 * 4 * narrow + 5 * 2 * narrow + 2 * 2 * wide + 4 * wide
+    assert CF._LAST["region"] == 2 * layer
+    kept = 4 * (8 + 4 + 2) * 2 * narrow + 4 * 2 * 2 * wide
+    assert _said()[CF.MUL_OUT] == [224, 64, kept] and len(ops) == 16
+    assert round(kept / 1e9, 2) == 2.62
+    reckoned = CF._LAST["state"] + CF._LAST["stream"] \
+        + CF._LAST["region"] + CF._LAST["kept_before_last"]
+    assert reckoned <= _V5E_LIMIT
 
 
 def _looped_step(limit, monkeypatch, amp, visits=3):
@@ -148,7 +194,8 @@ def _looped_step(limit, monkeypatch, amp, visits=3):
 @pytest.mark.parametrize("amp", [False, True], ids=["float32", "bf16_amp"])
 def test_what_the_visits_keep_changes_no_bit(monkeypatch, amp):
     """With room for everything every candidate of every visit is kept
-    (2 layers x 7 products + the logits, three visits: 45), the counter
+    (2 layers x 7 products, three visits: 42; the head's is none, its
+    region runs in row blocks and makes no logits to keep), the counter
     says that each visit saved its own, and the loss and every gradient
     are the bits of the step that keeps nothing. Each primitive runs by
     itself (jax.disable_jit), as in tests/test_recompute_kinds.py."""
@@ -156,7 +203,7 @@ def test_what_the_visits_keep_changes_no_bit(monkeypatch, amp):
         none, _, _ = _looped_step(0, monkeypatch, amp)
         before = CF._KEPT_BYTES.value(name=CF.MUL_OUT)
         kept, said, rows = _looped_step(2 ** 40, monkeypatch, amp)
-    assert said[CF.MUL_OUT][:2] == [45, 45]
+    assert said[CF.MUL_OUT][:2] == [42, 42]
     saved = CF._KEPT_BYTES.value(name=CF.MUL_OUT) - before
     assert saved > 0 and saved % said[CF.MUL_OUT][2] == 0
     assert all(np.abs(g).sum() > 0 for g in kept[1:])
@@ -168,7 +215,10 @@ def test_what_the_visits_keep_changes_no_bit(monkeypatch, amp):
     inside = [r for r in rows if r["region"] is not None]
     assert sorted({r["region"] for r in inside}) == list(range(9))
     muls = [r for r in inside if r["type"] == "mul"]
-    assert len(muls) == 45 and all(r["kept"] == CF.MUL_OUT for r in muls)
+    assert len(muls) == 45 and all(
+        (r["kept"], r.get("row_blocks")) == (
+            (None, (1, 32)) if r["module"] == "loop_head"
+            else (CF.MUL_OUT, None)) for r in muls)
     heads = [r for r in rows if r["module"] == "loop_head"]
     assert sorted({r["region"] for r in heads if r["region"] is not None}) \
         == [2, 5, 8]
@@ -206,7 +256,7 @@ def test_one_visit_takes_nothing_off_the_room(monkeypatch):
     table = 64 * 32
     weights = sum(int(np.prod(p.shape))
                   for p in main.global_block().all_parameters())
-    assert said[1][0] == 15 and said[2][0] == 30
+    assert said[1][0] == 14 and said[2][0] == 28
     # (and by the second visit's own sum of its loss, a float32)
     assert last[2]["state"] - last[1]["state"] == 4 * (weights - table) + 4
     assert said[2][2] == 2 * said[1][2]

@@ -3,8 +3,10 @@ included, compiled for a described v5e (tests/tpu_compile_test.py says
 how and why): `lfm2_train_T32k`, five regions at 32,768 rows, where the
 plan of what the regions keep (ops/control_flow.py _plan_kept) cannot
 admit everything and the compiled step's memory_analysis() is what
-confirms its reserve (ISSUE 52). Nothing runs: the state's shapes are
-the start-up program's, abstractly.
+confirms its reserve (ISSUE 52); and `ouro_train_T8k`, 36 regions a
+step, four of them a head and its loss in row blocks (ISSUE 60: a slow
+test). Nothing runs: the state's shapes are the start-up program's,
+abstractly.
 """
 
 import re
@@ -90,6 +92,49 @@ def test_lfm2s_step_keeps_what_fits_and_compiles_a_gib_under_the_limit(
             for n in (short_conv.CONV_OUT, moe.EXPERTS_WEIGHTS)}
     assert kept == {short_conv.CONV_OUT: 2 * 32768 * 2048,
                     moe.EXPERTS_WEIGHTS: 2 * 8 * 3 * 2048 * 1792}
+
+
+@pytest.mark.slow       # (the compile takes some three minutes on a CPU)
+def test_ouros_step_with_its_heads_in_row_blocks_fits_as_the_plan_reckons(
+        chip, on_the_chip):
+    """`ouro_train_T8k`'s step, four visits of eight layer regions and
+    a head region that runs in blocks of 1,024 rows: the compiled
+    step's arguments + temporaries stand under the v5e's limit with no
+    fall-back, and within 0.3 GB of what the plan reckoned (a region of
+    the visited block at THREE times its values: at twice, the plan
+    admits 64 products and the step stands 0.85 GiB over). Every
+    product with the head carries its visit's `mul` row's scope; the
+    rule is the region's recompute, and the blocks it makes again carry
+    `rematted_computation` inside that scope, as many as the forward's,
+    so a trace's readers book them as the second forward they are."""
+    with fluid.amp.amp_guard(True):
+        _, step, args, _ = _step(*built_cell("ouro_train_T8k"), chip)
+        compiled = step.lower(*args).compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held < _V5E_LIMIT, held
+    last = CF._LAST
+    reckoned = last["state"] + last["stream"] + max(
+        last["head"] + last["kept"],
+        last["region"] + last["kept_before_last"])
+    assert abs(reckoned - held) < 0.3e9, (reckoned, held)
+    said = lambda kind, *whats: tuple(
+        int(CF._PLAN.value(kind=kind, what=w)) for w in whats)
+    assert said(CF.LOSS_BLOCKS, "regions", "rows") == (4, 1024)
+    products, admitted = said(CF.MUL_OUT, "candidates", "admitted")
+    assert products == 224 and admitted > 28
+    heads = re.findall(r'(?m)^.*\b49152\b.* (?:convolution|fusion)\(.*'
+                       r'op_name="([^"]*dot_general)"', compiled.as_text())
+    assert all(re.search(r"/mul\.\d+/", n) for n in heads)
+    again = [n for n in heads if "rematted_computation" in n]
+    first = [n for n in heads if "transpose(jvp(" not in n]
+    assert len(again) == len(first), (len(again), len(first), len(heads))
+    scopes = lambda names: {re.search(r"/(mul\.\d+)/", n).group(1)
+                            for n in names}
+    assert scopes(again) == scopes(first) == scopes(heads) \
+        and len(scopes(heads)) == 4
+    assert all(re.search(r"/mul\.\d+/rematted_computation/dot_general$", n)
+               for n in again)
 
 
 def test_a_compile_that_runs_out_of_hbm_falls_back_to_a_plan_of_nothing(
