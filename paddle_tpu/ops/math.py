@@ -38,48 +38,46 @@ def _flatten2d(x, num_col_dims):
     return x.reshape(lead, -1), x.shape
 
 
-def _mul_operands(op, x, y):
-    """A `mul`'s operands cast as AMP (or the op's ``float32``) says ->
-    ``(x, y, the dtype its result is rounded by)``."""
-    out_dtype = jnp.float32 if op.attr("float32", False) else x.dtype
-    x, y = (x.astype(out_dtype), y.astype(out_dtype)) \
-        if op.attr("float32", False) else _amp_cast(x, y)
-    return x, y, out_dtype
-
-
-def _mul_product(op, x2, y, out_dtype):
-    """x2 ``[rows, K]`` by Y flattened as the op says, both cast already
-    (_mul_operands): accumulated wide, rounded as `amp_out` rounds."""
+def mul_rows(op, x2, y, note=None):
+    """The `mul` op's arithmetic on ROWS of its flattened X: x2 ``[rows,
+    K]`` and Y as the op reads them -> out ``[rows, columns]``. The
+    operands are cast as AMP (or the op's ``float32``) says, the product
+    accumulated wide and rounded as `amp_out` rounds; `note`, where
+    given, is told the product's sizes and its operands' dtype. X is
+    flat BEFORE it is cast: cast as ``[B, T, K]`` at B over 1 and
+    flattened after, XLA does not fuse what made X (a bias add, a ReLU)
+    into the product's operand, and the value makes a float32 round
+    trip through a relayout `copy` (PERF.md section 6, PR 61). A
+    region's head in row blocks (ops/control_flow.py
+    _loss_in_row_blocks) runs this a block at a time and transposes it
+    for the product's two gradients."""
+    float32 = op.attr("float32", False)           # amp.float32
+    out_dtype = jnp.float32 if float32 else x2.dtype
+    x2, y = (x2.astype(out_dtype), y.astype(out_dtype)) if float32 \
+        else _amp_cast(x2, y)
     yn = op.attr("y_num_col_dims", 1)
     y2 = y.reshape(functools.reduce(lambda a, b: a * b, y.shape[:yn], 1), -1)
     if op.attr("transpose_Y", False):
         # a tied head: Y is the embedding's own [V, d] table, contracted
         # over its second dimension (nothing is transposed in memory)
         y2 = y2.T
-    if op.attr("float32", False):                 # amp.float32
-        return jnp.matmul(x2, y2, precision=lax.Precision.HIGHEST)
-    out = jnp.matmul(x2, y2, preferred_element_type=_acc_type(x2))
-    from ..amp import amp_out
-    return amp_out(out, out_dtype)
-
-
-def mul_rows(op, x2, y):
-    """The `mul` op's arithmetic on ROWS of its flattened X: x2 ``[rows,
-    K]`` and Y as the op reads them -> out ``[rows, columns]``, the
-    operands' precision, the accumulator and the rounding `_mul`'s own;
-    of X only the rows handed in are cast. A region's head in row blocks
-    (ops/control_flow.py _loss_in_row_blocks) runs it a block at a time
-    and transposes it for the product's two gradients."""
-    return _mul_product(op, *_mul_operands(op, x2, y))
+    if float32:
+        out = jnp.matmul(x2, y2, precision=lax.Precision.HIGHEST)
+    else:
+        from ..amp import amp_out
+        out = amp_out(jnp.matmul(x2, y2, preferred_element_type=_acc_type(x2)),
+                      out_dtype)
+    if note is not None:
+        note(mkn=x2.shape + out.shape[1:], operand_dtype=str(x2.dtype))
+    return out
 
 
 @register("mul")
 def _mul(ctx, op):
-    x, y, out_dtype = _mul_operands(op, ctx.in1(op, "X"), ctx.in1(op, "Y"))
     xn = op.attr("x_num_col_dims", 1)
-    x2, xshape = _flatten2d(x, xn)
-    out = _mul_product(op, x2, y, out_dtype)
-    ctx.note(mkn=x2.shape + out.shape[1:], operand_dtype=str(x2.dtype))
+    x2, xshape = _flatten2d(ctx.in1(op, "X"), xn)
+    y = ctx.in1(op, "Y")
+    out = mul_rows(op, x2, y, note=ctx.note)
     columns = out.shape[1:] if op.attr("transpose_Y", False) \
         else y.shape[op.attr("y_num_col_dims", 1):]
     ctx.set_out(op, "Out", out.reshape(xshape[:xn] + columns))
