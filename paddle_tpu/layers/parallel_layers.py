@@ -103,7 +103,8 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     (their weights divided by their sum where `norm_topk`), and the
     `experts_held` experts with ids from `first_expert` computed here,
     ``w_down(act(w_gate x) * (w_up x))`` of width `d_inner`, act
-    `activation` ("silu", or "relu"); what the
+    `activation` ("silu", or "relu"; under "relu2" an expert has no gate
+    matrix and is ``w_down relu(w_up x)^2``, no ``.w_gate``); what the
     other experts would add is left out, as an expert-parallel group
     leaves it to its other members. Returns ``(out, aux_loss, choices,
     load)``: `aux_loss` is ``E * sum_e f_e P_e`` (scale it and add it to
@@ -127,7 +128,8 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
     `router_input` (None: `x`) is what the router reads, a variable of
     x's shape: a router that stands before the sublayers that make the
     experts' input. The router's weights send their gradient to it, the
-    experts theirs to `x`. Under a "relu" gate the zeros are exact, and
+    experts theirs to `x`. Under a "relu" gate (and behind "relu2") the
+    zeros are exact, and
     every train run adds to the persistable ``<name>.gate_on`` [2]
     float32 the hidden units the gate left on and the hidden units
     there were, over the step's pairs on held experts, and one to
@@ -138,7 +140,9 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
         ParamAttr(name="%s.%s" % (helper.name, suffix)), shape=shape,
         dtype="float32", default_initializer=Normal(0., std))
     router = param("router", [d, num_experts], router_std)
-    w_gate = param("w_gate", [experts_held, d, d_inner], d ** -0.5)
+    # (an expert of two matrices has no gate's)
+    w_gate = None if activation == "relu2" else param(
+        "w_gate", [experts_held, d, d_inner], d ** -0.5)
     w_up = param("w_up", [experts_held, d, d_inner], d ** -0.5)
     w_down = param("w_down", [experts_held, d_inner, d], d_inner ** -0.5)
     from .tensor import create_global_var
@@ -150,6 +154,8 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
         "int32", shape=tuple(x.shape[:-1]) + (top_k,), stop_gradient=True)
     inputs = {"X": [x], "RouterW": [router], "WGate": [w_gate],
               "WUp": [w_up], "WDown": [w_down], "Load": [load]}
+    if w_gate is None:
+        del inputs["WGate"]
     outputs = {"Out": [out], "AuxLoss": [aux], "Indices": [choices],
                "LoadOut": [load]}
     attrs = {"first_expert": int(first_expert), "top_k": int(top_k),
@@ -160,7 +166,8 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
         attrs["activation"] = str(activation)
     if norm_topk_eps:
         attrs["norm_topk_eps"] = float(norm_topk_eps)
-    if activation == "relu":
+    counts_gate = activation in ("relu", "relu2")    # exact zeros behind it
+    if counts_gate:
         gate_on = create_global_var([2], 0.0, "float32", persistable=True,
                                     name=helper.name + ".gate_on")
         gate_on.stop_gradient = True
@@ -176,7 +183,7 @@ def routed_experts(x, num_experts, experts_held, first_expert, top_k,
         bias.stop_gradient = True
         inputs["Bias"], outputs["BiasOut"] = [bias], [bias]
         attrs["bias_update_rate"] = float(bias_update_rate)
-    if bias_update_rate is not None or activation == "relu":
+    if bias_update_rate is not None or counts_gate:
         steps = create_global_var([1], 0, "int32", persistable=True,
                                   name=helper.name + ".steps")
         inputs["Steps"], outputs["StepsOut"] = [steps], [steps]

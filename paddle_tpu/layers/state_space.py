@@ -4,7 +4,9 @@ differential attention (``ops/diff_attention.py``), a head tied to
 the embedding, and the gated short convolution of a convolution-only
 mixer (ISSUE 49, ``ops/short_conv.py``), and the gated delta rule of a
 linear-attention layer with the ops round it (ISSUE 53,
-``ops/delta_rule.py``). Each is one Program op under its own type, so
+``ops/delta_rule.py``), and the state-space-dual scan of a Mamba-2
+mixer with the gate-then-norm behind it (ISSUE 62,
+``ops/ssd_scan.py``). Each is one Program op under its own type, so
 that a device trace gives each its scope."""
 
 import math
@@ -19,7 +21,7 @@ from .layer_helper import LayerHelper
 __all__ = ["ssm_conv", "ssm_dt", "selective_scan", "ssm_gate", "gmu_gate",
            "diff_attention", "diff_attn", "tied_head", "gated_short_conv",
            "l2_norm_scale", "delta_gates", "gated_delta_rule",
-           "gated_rms_norm"]
+           "gated_rms_norm", "ssd_scan", "gated_group_norm"]
 
 
 def _param(helper, name, shape, initializer):
@@ -109,6 +111,49 @@ def selective_scan(x, dt, b, c, d_state=16, chunk=0, force="", name=None):
                              "B": [b], "C": [c], "D": [d]},
                      outputs={"Out": [out]},
                      attrs={"chunk": int(chunk), "force": str(force)})
+    return out
+
+
+def ssd_scan(x, dt, b, c, n_head, n_group, a_max=16.0, chunk=0, name=None):
+    """The state-space-dual scan (``ops/ssd_scan.py``) of x [B, T, H *
+    P] with steps dt [B, T, H] (past their softplus), inputs b and
+    outputs c [B, T, G * N], `n_head` H heads in `n_group` G groups that
+    share b and c: parameters ``<name>_a_log`` [H] (A = -exp(.), ONE
+    number a head, ``exp(.)`` at the H quantiles of U(1, `a_max`):
+    Mamba-2's range) and ``<name>_d`` [H] (ones). The state a head is
+    ``[P, N]`` float32 whatever x is. `chunk` is
+    ``ops/ssd_scan.ssd_scan``'s (0: its own). Returns [B, T, H * P]."""
+    helper = LayerHelper("ssd_scan", name=name)
+    a_log = _param(helper, helper.name + "_a_log", [n_head],
+                   NumpyArrayInitializer(np.log(
+                       1.0 + (a_max - 1.0) * (np.arange(n_head) + 0.5)
+                       / n_head).astype("float32")))
+    d = _param(helper, helper.name + "_d", [n_head],
+               ConstantInitializer(1.0))
+    out = _same(helper, x)
+    helper.append_op(type="ssd_scan",
+                     inputs={"X": [x], "Dt": [dt], "ALog": [a_log],
+                             "B": [b], "C": [c], "D": [d]},
+                     outputs={"Out": [out]},
+                     attrs={"n_head": int(n_head), "n_group": int(n_group),
+                            "chunk": int(chunk)})
+    return out
+
+
+def gated_group_norm(x, gate, groups, epsilon=1e-5, name=None):
+    """``RMSNorm(x * silu(gate)) * w`` over each of `groups` equal
+    groups of the channels of x [B, T, C] by itself, the gate BEFORE
+    the norm (``gated_rms_norm`` norms first): parameter ``<name>`` [C]
+    (ones)."""
+    helper = LayerHelper("gated_group_norm", name=name)
+    scale = _param(helper, helper.name, [int(x.shape[-1])],
+                   ConstantInitializer(1.0))
+    out = _same(helper, x)
+    helper.append_op(type="gated_group_norm",
+                     inputs={"X": [x], "Gate": [gate], "Scale": [scale]},
+                     outputs={"Out": [out]},
+                     attrs={"groups": int(groups),
+                            "epsilon": float(epsilon)})
     return out
 
 

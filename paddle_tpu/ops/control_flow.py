@@ -29,8 +29,10 @@ from .delta_rule import DELTA_OUT, DELTA_STATES, kept_by_a_region
 from .loss import hard_label_rows, hard_label_rows_grad
 from .math import _flatten2d, mul_rows
 from .short_conv import CONV_OUT
+from .ssd_scan import saved_states_bytes
 from ..core.registry import register, LowerContext
-from ..parallel.moe import EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS
+from ..parallel.moe import (EXPERTS_OUT, EXPERTS_ROUTE, EXPERTS_WEIGHTS,
+                            hidden_width)
 
 
 def _trace_block(ctx, block, env):
@@ -501,7 +503,9 @@ def _plan_kept(ctx):
         stream's merge): otherwise the second forward needs none of it
         and the layer's loop is dead there already. Cost: the layer's
         forward, two grouped matmuls over the pairs uniform routing
-        sends to the held experts, the rows gathered and added back;
+        sends to the held experts (over an expert's three matrices, or
+        the two of one with no gate matrix), the rows gathered and
+        added back;
       - moe.EXPERTS_ROUTE, what the layer's and the router's backward
         read of the scope `route` (the logits, the choice, the chosen
         scores, the sorted pairs): the router's float32 matmul, the
@@ -511,8 +515,9 @@ def _plan_kept(ctx):
         dtype.
       NOT candidates: the stream's norms (XLA fuses them with their
       neighbours; a kernel there lost 2 ms in PR 33), `qk_norm_rope`,
-      the hyper-connections' `h` and `zs`, the scans: a region runs
-      them twice still.
+      the hyper-connections' `h` and `zs`, the scans (`selective_scan`,
+      and `ssd_scan` with the chunk states its forward kernel saves): a
+      region runs them twice still.
     * order: seconds a byte, highest first (among `mul` results 2 K
       over the itemsize, as before: the wide-K products first); among
       equals the LAST region's first, then program order: the backward
@@ -645,6 +650,13 @@ def _plan_kept(ctx):
             elements[name] = n
             nbytes[name] = 0 if var.persistable or o.type == "reshape" \
                 else n * jnp.dtype(dtype).itemsize
+            if o.type == "ssd_scan":
+                # beside its result the chunks' starting states, which
+                # its forward kernel saves and its backward reads
+                nbytes[name] += saved_states_bytes(
+                    n, var_of(blk, o.input("B")[0]).shape[-1]
+                    // int(o.attr("n_group")),
+                    int(o.attr("chunk", 0)) or None)
         return sum(nbytes[n] for n in written)
 
     def itemsize(blk, name):
@@ -691,8 +703,11 @@ def _plan_kept(ctx):
             yield DELTA_OUT, size, flops / peak + moved / hbm, id(m)
         elif m.type == "routed_experts":
             x, out = m.input("X")[0], m.output("Out")[0]
-            w = var_of(blk, m.input("WGate")[0])
+            w = var_of(blk, m.input("WUp")[0])
+            # an expert's matrices: gate, up and down, or up and down
+            mats = 3 if m.input("WGate") else 2
             held, d, f = w.shape
+            f = hidden_width(f, gated=mats == 3)
             experts = var_of(blk, m.input("RouterW")[0]).shape[1]
             top_k = int(m.attr("top_k"))
             n = count(x) // d
@@ -701,9 +716,9 @@ def _plan_kept(ctx):
             computed_w = jnp.dtype(result_dtype(w.dtype)).itemsize
             if _read_by_a_backward_rule(sub_ops, j, "Out"):
                 size = elements[out] * itemsize(blk, x)
-                yield EXPERTS_OUT, size, 6 * pairs * d * f / peak + (
+                yield EXPERTS_OUT, size, 2 * mats * pairs * d * f / peak + (
                     (2 * computed_w + 4) * pairs * d + 8 * n * d
-                    + 3 * held * d * f * computed_w) / hbm, id(m)
+                    + mats * held * d * f * computed_w) / hbm, id(m)
             # the logits, the choices with their scores, the pairs'
             # order; read again: x in float32 for the router's six
             # bf16 passes, the scores, a sort's passes over the pairs
@@ -711,8 +726,8 @@ def _plan_kept(ctx):
                 12 * n * d * experts / peak + (4 * n * d + 12 * n * experts
                 + 8 * n * top_k * math.ceil(math.log2(max(
                     n * top_k, 2)))) / hbm, None
-            yield EXPERTS_WEIGHTS, 3 * held * d * f * computed_w, \
-                3 * held * d * f * (declared_w + computed_w) / hbm, None
+            yield EXPERTS_WEIGHTS, mats * held * d * f * computed_w, \
+                mats * held * d * f * (declared_w + computed_w) / hbm, None
 
     # the units of the step in the order it runs them: (block, the
     # block's ops, index, times, what the block hands on besides). A

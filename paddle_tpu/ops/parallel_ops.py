@@ -150,7 +150,8 @@ def _routed_experts(ctx, op):
     bias, and a train run writes BiasOut = `moe.bias_step` of the
     step's own counts at `bias_update_rate` and StepsOut = Steps + 1.
     With a RouterX [B, T, D] the router reads it and not X; attr
-    activation ("silu" / "relu") is the experts' gate; attr norm_topk_eps
+    activation ("silu" / "relu") is the experts' gate, or with no WGate
+    ("relu2") the activation of an expert of two matrices; attr norm_topk_eps
     (0 where the layer sets none) is added to the chosen weights' sum
     before they are divided by it. With a GateOn [2]
     float32 (persistable) a train run writes GateOnOut = GateOn + (the
@@ -163,7 +164,8 @@ def _routed_experts(ctx, op):
     shape = x.shape
     router_w = ctx.in1(op, "RouterW")
     w_gate, w_up, w_down = maybe_bf16(
-        ctx.in1(op, "WGate"), ctx.in1(op, "WUp"), ctx.in1(op, "WDown"))
+        ctx.in1(op, "WGate") if op.input("WGate") else None,
+        ctx.in1(op, "WUp"), ctx.in1(op, "WDown"))
     k = int(op.attr("top_k"))
     bias = ctx.in1(op, "Bias") if op.input("Bias") else None
     first = int(op.attr("first_expert", 0))
@@ -187,9 +189,9 @@ def _routed_experts(ctx, op):
         ctx.set_out(op, "LoadOut", ctx.in1(op, "Load") + counts)
         if count_gate:
             pairs = jnp.sum(lax.dynamic_slice_in_dim(
-                counts, first, w_gate.shape[0]))
+                counts, first, w_up.shape[0]))
             ctx.set_out(op, "GateOnOut", ctx.in1(op, "GateOn") + jnp.stack(
-                [on[0], pairs * w_gate.shape[2]]).astype(jnp.float32))
+                [on[0], pairs * w_up.shape[2]]).astype(jnp.float32))
         if bias is not None:
             ctx.set_out(op, "BiasOut", moe.bias_step(
                 bias, counts, float(op.attr("bias_update_rate", 0.0))))

@@ -215,11 +215,32 @@ _LOWERINGS = _REG.counter(
     "the experts a row takes, the router's score function (softmax, "
     "sigmoid), whether a shared expert rides beside the routed ones, "
     "what adds a chunk's rows to their tokens (pallas, interpret, xla), the "
-    "experts' gate (silu, relu) and whose rows the router reads (own: the "
+    "experts' gate (silu, relu; relu2: the activation of an expert with "
+    "no gate matrix) and whose rows the router reads (own: the "
     "experts' input; given: a tensor of its own)",
     ("path", "experts", "experts_held", "top_k", "score", "shared_expert",
      "rows", "activation", "router_input"))
 _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# an expert of TWO matrices, ``w_down act(w_up x)``, no gate matrix
+# (ISSUE 62): `w_gate` is None all the way down, the up projection's
+# weights stand where gate and up stand side by side (`w_gu`), and the
+# hidden units the counts call "on" are those the ReLU passes
+_UNGATED = {"relu2": lambda u: jnp.square(jax.nn.relu(u))}
+# ... and its hidden width runs in whole tiles of 256 columns: XLA's
+# grouped-matmul kernels take the seven products of a chunk's pass at
+# f 2,048 in 0.59 of their time at f 1,856, 14.5 lane tiles (8 experts
+# of some 400 rows at d 2,688: 7.06 for 12.04 ms; at 1,920 they are
+# slower still, 14.06; my chip run, PR 62). The weights are padded with
+# zero columns and rows where they enter the layer, which the square of
+# a ReLU leaves exact zeros and no count sees (`0 > 0`); autodiff's rule
+# for the padding cuts the gradients back. The gated cells' widths are
+# whole tiles as published (768, 1,024, 1,792) and take no padding.
+_UNGATED_TILE = 256
+
+
+def hidden_width(f, gated=True):
+    """The hidden width an expert of published width f computes at."""
+    return f if gated else -(-f // _UNGATED_TILE) * _UNGATED_TILE
 # What a `layers.recompute` region may keep of the layer, by the name
 # the value carries (ops/control_flow.py: the block's plan prices each
 # and the region's policy saves the names it admitted; outside a region
@@ -319,8 +340,12 @@ def _swiglu_experts(xs, w_gu, w_down, sizes, gate="silu", live=None):
     pair, also how many of their hidden units the gate leaves on
     (``xs w_gate > 0``; int32): ``(y, on)``."""
     rd, _ = _grouped(sizes)
-    g, u = _gate_and_up(rd, xs, w_gu)
-    y = rd((_GATES[gate](g) * u).astype(xs.dtype), w_down)
+    if gate in _UNGATED:
+        g = rd(xs, w_gu)
+        y = rd(_UNGATED[gate](g).astype(xs.dtype), w_down)
+    else:
+        g, u = _gate_and_up(rd, xs, w_gu)
+        y = rd((_GATES[gate](g) * u).astype(xs.dtype), w_down)
     if live is None:
         return y
     there = jnp.arange(xs.shape[0], dtype=jnp.int32) < live
@@ -347,12 +372,17 @@ def _swiglu_experts_bwd(xs, dy, w, w_gu, w_down, sizes, gate="silu"):
     matmul: ``h * w`` (the pair's weight rides on the hidden side of
     dw_down, so dy goes in as it is) and ``[dg, du]``, the cotangents
     of g and u side by side (so dxs is one product, not the sum of
-    two); dxs is written in xs's dtype from its float32 sums."""
+    two); dxs is written in xs's dtype from its float32 sums. An
+    ungated expert (`_UNGATED`) has no u: `w_gu` is its up projection
+    alone, and the pass is 1 grouped matmul forward and 4 backward."""
     rd, by_expert = _grouped(sizes)
     flip = lambda a: jnp.swapaxes(a, 1, 2)
-    g, u = _gate_and_up(rd, xs, w_gu)
-    # _GATES stays the one definition of the activations
-    h, pull = jax.vjp(lambda g, u: _GATES[gate](g) * u, g, u)
+    # _GATES and _UNGATED stay the one definition of the activations
+    if gate in _UNGATED:
+        h, pull = jax.vjp(_UNGATED[gate], rd(xs, w_gu))
+    else:
+        h, pull = jax.vjp(lambda g, u: _GATES[gate](g) * u,
+                          *_gate_and_up(rd, xs, w_gu))
     h = h.astype(xs.dtype).astype(jnp.float32)
     dh = rd(dy, flip(w_down))
     w = w[:, None]
@@ -395,8 +425,8 @@ def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
     recompute region that saves the name makes neither again. Nothing
     of a chunk's size is kept."""
     k = weight.shape[1]
-    w_gu = checkpoint_name(jnp.concatenate([w_gate, w_up], axis=2),
-                           EXPERTS_WEIGHTS)
+    w_gu = checkpoint_name(w_up if w_gate is None else jnp.concatenate(
+        [w_gate, w_up], axis=2), EXPERTS_WEIGHTS)
     w_down = checkpoint_name(w_down, EXPERTS_WEIGHTS)
 
     def body(c, carry):
@@ -449,10 +479,12 @@ def _held_bwd(cap, how, dtype, gate, counted, res, douts):
         chunk(0, moe_rows.zeros(x.shape, how),
               jnp.zeros(weight.size, weight.dtype)))
     f = w_gu.shape[2] // 2
+    dw_gate_up = (None, dws[0]) if gate in _UNGATED else (
+        dws[0][:, :, :f], dws[0][:, :, f:])
     return (moe_rows.result(dx, x.shape, x.dtype, how),
             dweight.reshape(weight.shape),
-            *(d.astype(w_down.dtype) for d in (
-                dws[0][:, :, :f], dws[0][:, :, f:], dws[1])),
+            *(d if d is None else d.astype(w_down.dtype)
+              for d in dw_gate_up + (dws[1],)),
             None, None)
 
 
@@ -468,7 +500,10 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
 
     x [N, d]; router_w [d, E] over ALL `num_experts`; w_gate, w_up
     [Eh, d, f] and w_down [Eh, f, d]: the Eh experts held here, ids
-    `first_expert` .. `first_expert` + Eh - 1. Returns
+    `first_expert` .. `first_expert` + Eh - 1. With `w_gate` None an
+    expert is two matrices, ``w_down act(w_up x)``, act "relu2" (the
+    square of a ReLU), and "the gate is on" reads ``w_up x > 0``.
+    Returns
 
       out     [N, d], x's dtype: sum over a row's chosen experts THAT ARE
               HELD HERE of weight * w_down(act(w_gate x) * (w_up x)),
@@ -497,10 +532,16 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     whole lane tiles, by XLA's scatter-add elsewhere; `force` ("pallas"
     / "interpret" / "xla") is for tests."""
     n, d = x.shape
-    held = w_gate.shape[0]
-    if activation not in _GATES:
-        raise ValueError("routed_experts: the gate is one of %s, got %r"
-                         % (sorted(_GATES), activation))
+    held = w_up.shape[0]
+    if w_gate is None:
+        extra = hidden_width(w_up.shape[2], gated=False) - w_up.shape[2]
+        w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, extra)))
+        w_down = jnp.pad(w_down, ((0, 0), (0, extra), (0, 0)))
+    if activation not in (_UNGATED if w_gate is None else _GATES):
+        raise ValueError(
+            "routed_experts: a gated expert's activation is one of %s, an "
+            "ungated one's (w_gate None) one of %s, got %r"
+            % (sorted(_GATES), sorted(_UNGATED), activation))
     adder = moe_rows._resolve_path(x.shape, x, force)
     _LOWERINGS.inc(path="ragged_dot", experts=str(num_experts),
                    experts_held=str(held), top_k=str(top_k), score=score,
@@ -538,7 +579,7 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     cap = min(-(-2 * pairs * held // num_experts // 512) * 512,
               -(-pairs // 8) * 8)
     order = jnp.pad(order, (0, -(-pairs // cap) * cap - pairs))
-    out, on = _held_experts(x.astype(w_gate.dtype), weight, w_gate, w_up,
+    out, on = _held_experts(x.astype(w_up.dtype), weight, w_gate, w_up,
                             w_down, order, ends, cap, adder, x.dtype,
                             activation, bool(count_gate))
     got = out, aux, counts, experts.astype(jnp.int32)

@@ -84,11 +84,14 @@ def _scan_lowerings_from_zero():
     failed the other two. The gated delta rule's counter is held the
     same way (tests/chipbench/test_chipbench_olmo_hybrid.py: no
     ``steps`` path; tests/test_delta_rule.py runs it on purpose), and
+    the state-space-dual scan's (tests/test_nemotron_h.py: the chunk
+    walk alone; tests/test_ssd_scan.py runs every path), and
     which files share a worker moves with every test file a PR adds
-    or takes away. Every file starts both counts from zero."""
+    or takes away. Every file starts the three counts from zero."""
     from paddle_tpu.monitor import metrics
     for name in ("ptpu_scan_lowerings_total",
-                 "ptpu_delta_rule_lowerings_total"):
+                 "ptpu_delta_rule_lowerings_total",
+                 "ptpu_ssd_lowerings_total"):
         counter = metrics.registry().get(name)
         if counter is not None:
             counter.clear()
@@ -137,6 +140,14 @@ _PINS_THE_BENCHMARKS_END = {
         "appended; PR 59 appended a cell and four metrics more. The pin's "
         "assertions run in test_chipbench_ouro.py::"
         "test_pr_51s_pinned_entries_are_as_their_pr_left_them",
+    "test_chipbench_lfm2.py::"
+    "test_smallthinkers_files_hold_to_their_source_as_pr_46_left_them":
+        "runs PR 46's pin against the benchmark cut back to PR 46's last "
+        "entries, and the pin holds expert_gate_active_pct's list to PR "
+        "46's cell alone; PR 62 appended the second cell whose experts "
+        "count what their ReLU leaves on. Its assertions run in "
+        "test_chipbench_nemotron_h.py::"
+        "test_smallthinkers_files_hold_to_their_source_as_pr_46_left_them",
 }
 
 

@@ -895,6 +895,8 @@ COVERED_ELSEWHERE = {
     "l2_norm_scale": "test_delta_rule.py",
     "delta_gates": "test_delta_rule.py",
     "gated_rms_norm": "test_delta_rule.py",
+    "ssd_scan": "test_ssd_scan.py",
+    "gated_group_norm": "test_ssd_scan.py",
     "silu_mul": "test_block_diffusion.py",
     "block_diffusion_noise": "test_block_diffusion.py",
     "block_diffusion_attention": "test_block_diffusion.py",
