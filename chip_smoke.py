@@ -62,7 +62,17 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           top-8; 4,096 rows of 3584 over 8 held of 64, sigmoid top-4):
           output and gradients against every held expert evaluated
           densely, nothing dropped when every row chooses held experts,
-          a chunk's unread tail harmless to the grouped matmuls
+          a chunk's unread tail (NaN) harmless to the grouped matmuls,
+          the kernels of ops/grouped_matmul.py and XLA's alike; then
+          the phase `grouped`
+  grouped the held experts' grouped matmuls ALONE (ISSUE 63), the probe
+          by rows an expert: at the seven routed cells' chunks (256 to
+          4,096 rows an expert, Nemotron's ungated experts among them)
+          the three orientations of a pass's products, XLA's
+          `ragged-dot` kernels against `grouped_matmul_rows` / `_rows_t`
+          / `_by_expert` at row tiles of 128, 256 and 512: device ms
+          and share of the bf16 peak, the kernels' results held to
+          XLA's; `--phases grouped` runs it alone
   rows    the expert layer's row moves alone (ISSUE 35) at both
           cells' chunks, filled to the cells' share and filled whole:
           XLA's gather, and the scatter-add as XLA's op against the
@@ -167,6 +177,10 @@ def kernels_in_interpret_mode():
         x, d, rows, rotate, force or "interpret")
     from paddle_tpu.ops import moe_rows
     moe_rows._resolve_path = lambda shape, like, force: force or "interpret"
+    from paddle_tpu.ops import grouped_matmul
+    choose = grouped_matmul.choose
+    grouped_matmul.choose = lambda rows, widths, like, force=None: choose(
+        rows, widths, like, force or "interpret")
     from paddle_tpu.ops import embedding_grad
     embedding_grad._resolve_path = (
         lambda ids, shape, dtype, like, force: force or "interpret")
@@ -978,7 +992,7 @@ def phase_experts(seed, rehearse):
     import jax
     import jax.numpy as jnp
     from paddle_tpu.parallel import moe
-    shapes = [(256, 256, 32, 16, 4, 4, "softmax", 1.0)] if rehearse else [
+    shapes = [(256, 256, 128, 16, 4, 4, "softmax", 1.0)] if rehearse else [
         (16384, 2048, 768, 128, 16, 8, "softmax", 1.0),
         (4096, 3584, 1024, 64, 8, 4, "sigmoid", 2.0)]
     rng = np.random.RandomState(seed)
@@ -1049,23 +1063,181 @@ def phase_experts(seed, rehearse):
         xs, dy, w = bf16(mk(cap, d)), bf16(mk(cap, d)), mk(cap)
         w_gu = jnp.concatenate([bf16(wg), bf16(wu)], axis=2)
 
-        def pull(xs, dy, w):
-            y = moe._swiglu_experts(xs, w_gu, bf16(wd), sizes)
-            dw, dxs, *dws = moe._swiglu_experts_bwd(xs, dy, w, w_gu,
-                                                    bf16(wd), sizes)
-            return (y[:live], dw[:live], dxs[:live]) + tuple(dws)
+        from paddle_tpu.ops import grouped_matmul
+        kernels = grouped_matmul.choose(
+            cap, (d, f), xs, "interpret" if rehearse else None)
+        assert kernels[0] != "xla", kernels
+        for matmuls in (kernels, ("xla", 0)):
+            def pull(xs, dy, w):
+                y = moe._swiglu_experts(xs, w_gu, bf16(wd), sizes,
+                                        matmuls=matmuls)
+                dw, dxs, *dws = moe._swiglu_experts_bwd(
+                    xs, dy, w, w_gu, bf16(wd), sizes, matmuls=matmuls)
+                return (y[:live], dw[:live], dxs[:live]) + tuple(dws)
 
-        clean = jax.jit(pull)(xs, dy, w)
-        dirty = jax.jit(pull)(*(a.at[live:].set(jnp.nan)
-                                for a in (xs, dy, w)))
-        worst = max(float(jnp.max(jnp.abs(
-            a.astype(jnp.float32) - b.astype(jnp.float32))))
-            for a, b in zip(clean, dirty))
-        log("[experts] NaN in the %d places past %d pairs of a chunk (rows, "
-            "cotangents, weights): the grouped matmuls' output and the "
-            "written backward's six results move by %.1e"
-            % (cap - live, live, worst))
-        assert worst == 0.0, worst
+            clean = jax.jit(pull)(xs, dy, w)
+            dirty = jax.jit(pull)(*(a.at[live:].set(jnp.nan)
+                                    for a in (xs, dy, w)))
+            worst = max(float(jnp.max(jnp.abs(
+                a.astype(jnp.float32) - b.astype(jnp.float32))))
+                for a, b in zip(clean, dirty))
+            log("[experts] NaN in the %d places past %d pairs of a chunk "
+                "(rows, cotangents, weights), the grouped matmuls by %s: "
+                "their output and the written backward's six results move "
+                "by %.1e" % (cap - live, live, matmuls, worst))
+            assert worst == 0.0, worst
+    phase_grouped(seed, rehearse)
+
+
+# The seven routed cells' expert layers as their steps run them (ISSUE
+# 63): rows a step, d, the hidden width (an ungated expert's padded,
+# `moe.hidden_width`), whether a gate stands beside the up projection,
+# experts held, experts routed over, top-k.
+GROUPED_CELLS = [
+    ("nemotron3nano_train_T8k", 8192, 2688, 2048, False, 8, 128, 6),
+    ("xing4_train_T4k", 4096, 3584, 1024, True, 8, 64, 4),
+    ("joyai_train_T8k", 8192, 2048, 768, True, 16, 256, 8),
+    ("sdar_train_bd4k", 16384, 2048, 768, True, 16, 128, 8),
+    ("trinity_train_T16k", 16384, 2048, 1024, True, 8, 128, 8),
+    ("smallthinker_train_T16k", 16384, 2560, 768, True, 16, 64, 6),
+    ("lfm2_train_T32k", 32768, 2048, 1792, True, 8, 32, 4)]
+
+
+def _device_ms_each(programs, calls, rehearse, name):
+    """{label: device ms a call} of jitted `programs` {label: (fn,
+    args)}: every program run `calls` times under ONE trace and told
+    apart by its module's name, the median of its runs; in a rehearsal
+    the host's clock."""
+    import jax
+    from chipbench import tracing
+    trace_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "chiprun_out", name)
+    for fn, args in programs.values():
+        jax.block_until_ready(fn(*args))
+    wall = {}
+    tracing.start(trace_dir)
+    for label, (fn, args) in programs.items():
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        wall[label] = (time.perf_counter() - t0) * 1e3 / calls
+    tracing.stop()
+    if rehearse:
+        return wall
+    runs = {}
+    for row in tracing.load_rows(trace_dir):
+        if row["plane"].startswith("/device:") \
+                and row["line"] == tracing.MODULE_LINE:
+            runs.setdefault(tracing.module_name(row["name"]), []).append(
+                row["dur"] * 1e3)
+    assert all(len(runs.get(label, ())) == calls for label in programs), {
+        k: len(v) for k, v in runs.items()}
+    return {label: float(np.median(runs[label])) for label in programs}
+
+
+def phase_grouped(seed, rehearse, row_tiles=(128, 256, 512)):
+    """The probe by rows an expert (ISSUE 63, ROADMAP queue 1 S6): the
+    held experts' grouped matmuls ALONE at each routed cell's chunk,
+    filled as uniform routing fills it (half, the rows drawn to the
+    experts as a multinomial), the three orientations of a pass's seven
+    products, two products each as the layer makes them: `rows` (up,
+    down), `rows_t` (dh, dxs against the weights as they lie) and
+    `by_expert` (dW_up, dW_down); XLA's `ragged-dot` kernels against
+    the kernels of ops/grouped_matmul.py at row tiles of 128, 256 and
+    512, device ms of the jitted pair under the profiler, and the
+    share of the bf16 peak that the live rows' FLOPs make of it. Each
+    kernel's results are held to XLA's on the rows that hold pairs."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from paddle_tpu.ops import grouped_matmul as gm
+    from paddle_tpu.parallel import moe
+    cells = [("tiny", 256, 128, 128, True, 4, 16, 4)] if rehearse \
+        else GROUPED_CELLS
+    path = "interpret" if rehearse else "pallas"
+    calls = 1 if rehearse else 5
+    rng = np.random.RandomState(seed % 2 ** 31)
+    for cell, n, d, f, gated, held, e, k in cells:
+        pairs = n * k
+        cap = 2 * pairs * held // e
+        expected = pairs // e
+        sizes = jnp.asarray(rng.multinomial(
+            expected * held, [1.0 / held] * held), jnp.int32)
+        live = expected * held
+        wide = 2 * f if gated else f
+        keys = jax.random.split(jax.random.PRNGKey(seed % 2 ** 31), 6)
+        mk = lambda i, *shape: jax.random.normal(keys[i], shape,
+                                                 jnp.bfloat16)
+        xs, dy, h, dgu = mk(0, cap, d), mk(1, cap, d), mk(2, cap, f), \
+            mk(3, cap, wide)
+        w_gu, w_down = mk(4, held, d, wide), mk(5, held, f, d)
+
+        operands = (sizes, xs, dy, h, dgu, w_gu, w_down)
+
+        def pair(matmuls, which):
+            # (the operands as arguments: a jitted closure would carry
+            # them as constants of every program)
+            def fn(sizes, xs, dy, h, dgu, w_gu, w_down):
+                rd, rd_t, by_expert = moe._grouped(sizes, matmuls)
+                if which == "rows":
+                    return rd(xs, w_gu), rd(h, w_down)
+                if which == "rows_t":
+                    return rd_t(dy, w_down), rd_t(dgu, w_gu, jnp.bfloat16)
+                return by_expert(xs, dgu), by_expert(h, dy)
+            fn.__name__ = "%s_%s_%d" % (which, *matmuls)
+            return jax.jit(fn)
+
+        hows = [("xla", 0)] + [(path, tm) for tm in row_tiles
+                               if cap % tm == 0]
+        programs = {"%s_%s_%d" % (which, *m): (pair(m, which), operands)
+                    for which in ("rows", "rows_t", "by_expert")
+                    for m in hows}
+        ms = _device_ms_each(programs, calls, rehearse, "grouped_trace")
+        # the live rows' FLOPs of the two products of an orientation
+        flops = 2 * live * d * (wide + f)
+        chosen = gm.choose(cap, (d, f), xs,
+                           "interpret" if rehearse else None)
+        for which in ("rows", "rows_t", "by_expert"):
+            want = programs["%s_xla_0" % which][0](*operands)
+            for m in hows[1:]:
+                got = programs["%s_%s_%d" % (which, *m)][0](*operands)
+                for a, b in zip(got, want):
+                    if which != "by_expert":
+                        a, b = a[:live], b[:live]
+                    err = float(jnp.max(jnp.abs(
+                        a.astype(jnp.float32) - b.astype(jnp.float32)))
+                        / jnp.max(jnp.abs(b.astype(jnp.float32))))
+                    assert err <= 1e-2, (cell, which, m, err)
+            log("[grouped] %s: %d rows an expert (%d held, chunk %d, d %d, "
+                "f %d%s) %s: XLA %.3f ms (%.1f%% of the peak)%s"
+                % (cell, expected, held, cap, d, f,
+                   "" if gated else " ungated", which,
+                   ms["%s_xla_0" % which],
+                   flops / 197e12 * 1e5 / ms["%s_xla_0" % which],
+                   "".join("; tile %d %.3f ms (%.1f%%)" % (
+                       m[1], ms["%s_%s_%d" % (which, *m)],
+                       flops / 197e12 * 1e5 / ms["%s_%s_%d" % (which, *m)])
+                       for m in hows[1:])))
+        log("[grouped] %s: the layer takes %s; the six products (a pass "
+            "makes the up projection's twice): XLA %.3f ms%s"
+            % (cell, chosen, sum(ms["%s_xla_0" % w] for w in
+                                 ("rows", "rows_t", "by_expert")),
+               "".join("; tile %d %.3f ms" % (m[1], sum(
+                   ms["%s_%s_%d" % (w, *m)]
+                   for w in ("rows", "rows_t", "by_expert")))
+                   for m in hows[1:])))
+        # no pair at all (a chunk 0 that runs regardless): by_expert's
+        # exact zeros, and nothing hangs on a grid of no visit
+        if chosen[0] != "xla":
+            def no_pair(sizes, xs, dy, h, w_gu, w_down):
+                rd, rd_t, by_expert = moe._grouped(sizes * 0, chosen)
+                return rd(xs, w_gu), rd_t(dy, w_down), by_expert(h, dy)
+
+            none = jax.jit(no_pair)(sizes, xs, dy, h, w_gu, w_down)
+            assert float(jnp.max(jnp.abs(none[2]))) == 0.0
+    if rehearse:
+        log("[grouped] (REHEARSAL: a CPU's times, no device number)")
 
 
 def phase_rows(seed, rehearse):
@@ -1657,7 +1829,7 @@ def main():
                          "(flash, gqa, own_block, mla, window, scan, delta, "
                          "diff, "
                          "rotary, "
-                         "experts, "
+                         "experts, grouped, "
                          "rows, embed, hc, "
                          "train, serve); all of them if not given")
     ap.add_argument("--rehearse", action="store_true",
@@ -1685,12 +1857,14 @@ def main():
                   "window": phase_window, "scan": phase_scan,
                   "delta": phase_delta,
                   "diff": phase_diff, "rotary": phase_rotary,
-                  "experts": phase_experts,
+                  "experts": phase_experts, "grouped": phase_grouped,
                   "rows": phase_rows, "embed": phase_embed,
                   "hc": phase_hc,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}
-        for name in (args.phases.split(",") if args.phases else phases):
+        # (`experts` ends with `grouped`: not twice where all run)
+        for name in (args.phases.split(",") if args.phases
+                     else [name for name in phases if name != "grouped"]):
             phases[name](args.seed, args.rehearse)
     log("[cache] %d entries in %s at end"
         % (compile_cache.entries(cache_dir), cache_dir))

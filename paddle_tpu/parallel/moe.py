@@ -15,7 +15,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..monitor import metrics as _metrics
-from ..ops import moe_rows
+from ..ops import grouped_matmul, moe_rows
 
 
 def top1_gating(logits, capacity, rng=None, noise_std=0.0):
@@ -183,9 +183,14 @@ def moe_ffn_pp_sharded(x, gate_w, w_up_local, w_down_local, ep_axis,
 # experts, computes what the experts it holds give, and leaves the rest
 # out: nothing here stands in for the other chips or the exchange with
 # them. No token is dropped whatever the routing: the (row, expert) pairs
-# that fall on held experts are sorted by expert and go through
-# lax.ragged_dot (on a TPU XLA's own grouped-matmul kernel, which walks
-# the tiles that hold rows and no others) in CHUNKS of twice the pairs
+# that fall on held experts are sorted by expert and go through grouped
+# matmuls (`_grouped`: on a TPU the Pallas kernels of
+# ops/grouped_matmul.py, `grouped_matmul_rows` / `_rows_t` / `_by_expert`
+# in row tiles of 256, ISSUE 63: ahead of XLA's `ragged-dot` kernels at
+# every routed cell's shape, 256 to 4,096 rows an expert, so those are
+# off the TPU path; `lax.ragged_dot` on the CPU and where a width is no
+# whole lane tiles; either walks the tiles that hold rows and no others)
+# in CHUNKS of twice the pairs
 # uniform routing expects; a loop runs as many chunks as hold pairs, one
 # as a rule, all of them when every row chooses held experts. So the
 # matmuls' work follows the rows present, and memory a chunk, not the
@@ -235,6 +240,10 @@ _UNGATED = {"relu2": lambda u: jnp.square(jax.nn.relu(u))}
 # a ReLU leaves exact zeros and no count sees (`0 > 0`); autodiff's rule
 # for the padding cuts the gradients back. The gated cells' widths are
 # whole tiles as published (768, 1,024, 1,792) and take no padding.
+# Under the kernels of ops/grouped_matmul.py (ISSUE 63) the six products
+# take 2.41 ms at 1,920 columns, 15 lane tiles, and 2.41-2.44 at 2,048
+# (XLA's 12.37 and 5.75; my chip run, PR 63): a tie, so the tile stays,
+# and 1,856 as published is no whole lane tile, which the kernels want.
 _UNGATED_TILE = 256
 
 
@@ -303,22 +312,36 @@ def bias_step(bias, counts, rate):
     return bias + rate * jnp.sign(jnp.mean(counts) - counts)
 
 
-def _grouped(sizes):
-    """The two grouped matmuls on sorted rows, `sizes` rows to each
-    expert in turn, as (rd, by_expert): ``rd(rows [C, a], weights
+_RAGGED_DOT = ("xla", 0)    # `_grouped`'s `matmuls` for lax.ragged_dot
+
+
+def _grouped(sizes, matmuls=_RAGGED_DOT):
+    """The grouped matmuls on sorted rows, `sizes` rows to each expert
+    in turn, as (rd, rd_t, by_expert): ``rd(rows [C, a], weights
     [Eh, a, b]) -> [C, b]`` computes no row past the sum of the sizes
     (`dtype`: what it writes, float32 unless told; it sums in float32
-    either way), and ``by_expert(a [C, m], b [C, n]) -> [Eh, m, n]``
-    float32 contracts each expert's own rows and no others (zeros for
-    an expert with none). Both are `ragged-dot` kernels on a TPU, which
-    round a float32 operand to bfloat16 inside at twice the bytes: hand
-    them the weights' dtype."""
+    either way); ``rd_t(rows [C, a], weights [Eh, b, a]) -> [C, b]`` is
+    the same against each expert's weights transposed; and
+    ``by_expert(a [C, m], b [C, n]) -> [Eh, m, n]`` float32 contracts
+    each expert's own rows and no others (zeros for an expert with
+    none). `matmuls` = (path, row tile), `routed_experts`' choice:
+    ("pallas" | "interpret", tm) are the kernels `grouped_matmul_rows`,
+    `grouped_matmul_rows_t` (it reads the weights as they lie) and
+    `grouped_matmul_by_expert` of ops/grouped_matmul.py; ("xla", 0) is
+    `lax.ragged_dot` (on a TPU XLA's `ragged-dot` kernels of 512-row
+    tiles, which round a float32 operand to bfloat16 inside at twice
+    the bytes; against transposed weights XLA lays them out again).
+    Hand either the weights' dtype."""
+    path, tm = matmuls
+    if path != "xla":
+        return grouped_matmul.grouped(sizes, tm, path)
     rd = lambda a, b, dtype=jnp.float32: lax.ragged_dot(
         a, b, sizes, preferred_element_type=dtype)
+    rd_t = lambda a, b, dtype=jnp.float32: rd(a, jnp.swapaxes(b, 1, 2), dtype)
     dims = lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(([0], [0]), ([], [])),
         lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-    return rd, lambda a, b: lax.ragged_dot_general(
+    return rd, rd_t, lambda a, b: lax.ragged_dot_general(
         a, b, sizes, dims, preferred_element_type=jnp.float32)
 
 
@@ -331,15 +354,16 @@ def _gate_and_up(rd, xs, w_gu):
     return gu[:, :f], gu[:, f:]
 
 
-def _swiglu_experts(xs, w_gu, w_down, sizes, gate="silu", live=None):
+def _swiglu_experts(xs, w_gu, w_down, sizes, gate="silu", live=None,
+                    matmuls=_RAGGED_DOT):
     """The held experts on sorted rows, forward: xs [C, d], `sizes` rows
     to each expert in turn; rows past their sum are not computed. Two
     grouped matmuls: `_gate_and_up`, then ``act(g) * u``, rounded to
     xs's dtype, against `w_down`. `gate` is the activation of the
     gate's half ("silu", "relu"). Given `live`, the rows that hold a
     pair, also how many of their hidden units the gate leaves on
-    (``xs w_gate > 0``; int32): ``(y, on)``."""
-    rd, _ = _grouped(sizes)
+    (``xs w_gate > 0``; int32): ``(y, on)``. `matmuls`: `_grouped`'s."""
+    rd, _, _ = _grouped(sizes, matmuls)
     if gate in _UNGATED:
         g = rd(xs, w_gu)
         y = rd(_UNGATED[gate](g).astype(xs.dtype), w_down)
@@ -352,7 +376,8 @@ def _swiglu_experts(xs, w_gu, w_down, sizes, gate="silu", live=None):
     return y, jnp.sum((g > 0) & there[:, None], dtype=jnp.int32)
 
 
-def _swiglu_experts_bwd(xs, dy, w, w_gu, w_down, sizes, gate="silu"):
+def _swiglu_experts_bwd(xs, dy, w, w_gu, w_down, sizes, gate="silu",
+                        matmuls=_RAGGED_DOT):
     """`_swiglu_experts`' backward for one chunk, written out: five
     grouped matmuls, every operand in xs's dtype (bfloat16 under AMP)
     and every sum float32. xs, dy [C, d]: the chunk's rows of x and of
@@ -375,8 +400,7 @@ def _swiglu_experts_bwd(xs, dy, w, w_gu, w_down, sizes, gate="silu"):
     two); dxs is written in xs's dtype from its float32 sums. An
     ungated expert (`_UNGATED`) has no u: `w_gu` is its up projection
     alone, and the pass is 1 grouped matmul forward and 4 backward."""
-    rd, by_expert = _grouped(sizes)
-    flip = lambda a: jnp.swapaxes(a, 1, 2)
+    rd, rd_t, by_expert = _grouped(sizes, matmuls)
     # _GATES and _UNGATED stay the one definition of the activations
     if gate in _UNGATED:
         h, pull = jax.vjp(_UNGATED[gate], rd(xs, w_gu))
@@ -384,10 +408,10 @@ def _swiglu_experts_bwd(xs, dy, w, w_gu, w_down, sizes, gate="silu"):
         h, pull = jax.vjp(lambda g, u: _GATES[gate](g) * u,
                           *_gate_and_up(rd, xs, w_gu))
     h = h.astype(xs.dtype).astype(jnp.float32)
-    dh = rd(dy, flip(w_down))
+    dh = rd_t(dy, w_down)
     w = w[:, None]
     dgu = jnp.concatenate(pull(dh * w), axis=1).astype(xs.dtype)
-    return (jnp.sum(dh * h, axis=1), rd(dgu, flip(w_gu), xs.dtype),
+    return (jnp.sum(dh * h, axis=1), rd_t(dgu, w_gu, xs.dtype),
             by_expert(xs, dgu), by_expert((h * w).astype(xs.dtype), dy))
 
 
@@ -400,20 +424,21 @@ def _chunk(c, cap, order, ends, k):
     return pairs, pairs // k, inside[-1], sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
 def _held_experts(x, weight, w_gate, w_up, w_down, order, ends, cap, how,
-                  dtype, gate, counted):
+                  dtype, gate, counted, matmuls):
     return _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap,
-                     how, dtype, gate, counted)[0]
+                     how, dtype, gate, counted, matmuls)[0]
 
 
 def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
-              gate, counted):
+              gate, counted, matmuls):
     """``(out, on)``. `how` adds a chunk's rows to their tokens
-    ("pallas" / "interpret" / "xla", moe_rows._resolve_path); the result
+    ("pallas" / "interpret" / "xla", moe_rows._resolve_path) and
+    `matmuls` makes the grouped matmuls (`_grouped`); the result
     is rounded to x's dtype and returned as `dtype`, the layer's. The
     places of a gathered chunk past its pairs hold other experts' rows:
-    ragged_dot computes no row past the sum of its sizes, and every
+    a grouped matmul computes no row past the sum of its sizes, and every
     other reader masks them or stops at `count`. `on` is None, or where
     `counted` the hidden units `gate` leaves on over all the pairs
     (`_swiglu_experts`): the loop carries it beside the output.
@@ -433,7 +458,7 @@ def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
         out, on = carry
         pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
         y = _swiglu_experts(x[rows], w_gu, w_down, sizes, gate,
-                            count if counted else None)
+                            count if counted else None, matmuls)
         if counted:
             y, here = y
             on = on + here
@@ -448,7 +473,7 @@ def _held_fwd(x, weight, w_gate, w_up, w_down, order, ends, cap, how, dtype,
         x, weight, w_gu, w_down, order, ends)
 
 
-def _held_bwd(cap, how, dtype, gate, counted, res, douts):
+def _held_bwd(cap, how, dtype, gate, counted, matmuls, res, douts):
     x, weight, w_gu, w_down, order, ends = res
     k = weight.shape[1]
     dout = douts[0].astype(x.dtype)
@@ -459,7 +484,7 @@ def _held_bwd(cap, how, dtype, gate, counted, res, douts):
         pairs, rows, count, sizes = _chunk(c, cap, order, ends, k)
         dw, dxs, *dws = _swiglu_experts_bwd(
             x[rows], dout[rows], weight.reshape(-1)[pairs], w_gu, w_down,
-            sizes, gate)
+            sizes, gate, matmuls)
         # places past `count` name other experts' pairs
         there = jnp.arange(cap, dtype=jnp.int32) < count
         return (moe_rows.scatter_add(dx, x.shape, dxs, rows, None, count,
@@ -529,8 +554,11 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
     this layer (the counter's label: nothing here computes it, and a
     chip's share of the layer holds it once). A chunk's rows go back to
     their tokens by the kernel of `ops/moe_rows.py` on a TPU where d is
-    whole lane tiles, by XLA's scatter-add elsewhere; `force` ("pallas"
-    / "interpret" / "xla") is for tests."""
+    whole lane tiles, by XLA's scatter-add elsewhere, and the grouped
+    matmuls are the kernels of `ops/grouped_matmul.py` on a TPU where d
+    and the hidden width are whole lane tiles, `lax.ragged_dot`
+    elsewhere; `force` ("pallas" / "interpret" / "xla") is for tests
+    and moves both, each as far as its kernels can take the shapes."""
     n, d = x.shape
     held = w_up.shape[0]
     if w_gate is None:
@@ -543,7 +571,14 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
             "ungated one's (w_gate None) one of %s, got %r"
             % (sorted(_GATES), sorted(_UNGATED), activation))
     adder = moe_rows._resolve_path(x.shape, x, force)
-    _LOWERINGS.inc(path="ragged_dot", experts=str(num_experts),
+    # a chunk: twice what uniform routing sends here, in whole tiles of
+    # the grouped matmul
+    pairs = n * top_k
+    cap = min(-(-2 * pairs * held // num_experts // 512) * 512,
+              -(-pairs // 8) * 8)
+    matmuls = grouped_matmul.choose(cap, (d, w_up.shape[2]), x, force)
+    _LOWERINGS.inc(path="ragged_dot" if matmuls[0] == "xla"
+                   else "grouped_matmul", experts=str(num_experts),
                    experts_held=str(held), top_k=str(top_k), score=score,
                    shared_expert=str(bool(shared_expert)).lower(), rows=adder,
                    activation=activation,
@@ -573,14 +608,9 @@ def routed_experts(x, router_w, w_gate, w_up, w_down, num_experts,
             jnp.argsort(key, stable=True).astype(jnp.int32), EXPERTS_ROUTE)
         ends = checkpoint_name(jnp.cumsum(lax.dynamic_slice_in_dim(
             counts, first_expert, held)).astype(jnp.int32), EXPERTS_ROUTE)
-    # a chunk: twice what uniform routing sends here, in whole tiles of
-    # the grouped matmul
-    pairs = n * top_k
-    cap = min(-(-2 * pairs * held // num_experts // 512) * 512,
-              -(-pairs // 8) * 8)
     order = jnp.pad(order, (0, -(-pairs // cap) * cap - pairs))
     out, on = _held_experts(x.astype(w_up.dtype), weight, w_gate, w_up,
                             w_down, order, ends, cap, adder, x.dtype,
-                            activation, bool(count_gate))
+                            activation, bool(count_gate), matmuls)
     got = out, aux, counts, experts.astype(jnp.int32)
     return got + (on,) if count_gate else got

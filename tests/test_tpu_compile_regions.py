@@ -22,7 +22,7 @@ import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu.core.executor import _normalize_feeds  # noqa: E402
 from paddle_tpu.ops import control_flow as CF  # noqa: E402
 from paddle_tpu.ops import embedding_grad, flash_attention  # noqa: E402
-from paddle_tpu.ops import moe_rows, rotary  # noqa: E402
+from paddle_tpu.ops import grouped_matmul, moe_rows, rotary  # noqa: E402
 from paddle_tpu.ops import short_conv  # noqa: E402
 from paddle_tpu.parallel import moe  # noqa: E402
 from test_recompute_kinds import (  # noqa: E402
@@ -36,7 +36,8 @@ def on_the_chip(monkeypatch):
     embedding's too: `lfm2_train_T32k`'s table is tied, so its lookup
     keeps XLA's scatter-add; the kernel there would sum inside the
     head's weight-gradient fusion and hold 1.56 GB more, PR 58.)"""
-    for module in (flash_attention, rotary, moe_rows, embedding_grad):
+    for module in (flash_attention, rotary, moe_rows, grouped_matmul,
+                   embedding_grad):
         monkeypatch.setattr(module, "_on_tpu", lambda x: True)
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: _V5E_LIMIT)
 

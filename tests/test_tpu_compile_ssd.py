@@ -52,7 +52,8 @@ def test_the_ungated_expert_layer_compiles_at_the_cells_shape(chip):
         jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
         sds((n, d), jnp.float32), sds((d, e), jnp.float32),
         sds((held, d, f), jnp.bfloat16), sds((held, f, d), jnp.bfloat16))
-    assert "ragged" in text and "tpu_custom_call" in text
+    # (since ISSUE 63 the grouped matmuls are kernels too)
+    assert "grouped_matmul_rows" in text and "moe_scatter_add_rows" in text
 
 
 @pytest.mark.slow       # (the compile takes a minute on a CPU)
@@ -63,12 +64,12 @@ def test_the_cells_step_fits_under_its_plan(chip, monkeypatch):
     what the compiler holds."""
     import paddle_tpu as fluid
     from paddle_tpu.ops import control_flow as CF
-    from paddle_tpu.ops import (embedding_grad, flash_attention, moe_rows,
-                                rotary, ssd_scan)
+    from paddle_tpu.ops import (embedding_grad, flash_attention,
+                                grouped_matmul, moe_rows, rotary, ssd_scan)
     from test_recompute_kinds import _V5E_LIMIT, built_cell
     from test_tpu_compile_regions import _step
-    for module in (flash_attention, rotary, moe_rows, embedding_grad,
-                   ssd_scan):
+    for module in (flash_attention, rotary, moe_rows, grouped_matmul,
+                   embedding_grad, ssd_scan):
         monkeypatch.setattr(module, "_on_tpu", lambda x: True)
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: _V5E_LIMIT)
     with fluid.amp.amp_guard(True):
