@@ -44,6 +44,13 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           cell phi4flash_train_T8k's shape, s and dt [1, 8192, 5120]
           bf16, 16 states: y and the gradients of all six inputs
           against the float32 lax.scan form at `highest`, both timed
+  ssd     the state-space-dual scan's kernel pair (ISSUES 62, 64) at
+          both Mamba-2 cells' shapes, [1, 8192, 64 x 64] bf16 with 128
+          states: 8 groups of 8 heads (a grid step walks a group) and
+          ONE group of 64 (a block of 8 a grid step, dB and dC summed
+          over the blocks): y and all six gradients against the
+          jax.numpy chunk walk, device ms by kernel; ONLY where
+          `--phases ssd` asks for it
   diff    differential attention through the streamed kernels (ISSUE
           40): 40 query and 20 key/value heads of 64, values of 128,
           T 4096, full and under a window of 512: a1, a2, dq, dk, dv
@@ -784,6 +791,55 @@ def phase_scan(seed, rehearse):
     if not rehearse:
         assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
         assert " while(" not in text
+
+
+def phase_ssd(seed, rehearse):
+    """The state-space-dual scan's kernel pair (ISSUES 62, 64) at both
+    cells' shapes, 64 heads of 64 with 128 states over 8,192 rows: 8
+    groups of 8 heads (a grid step walks a group) and ONE group of 64
+    (a grid step walks a block of 8, dB and dC summed over the group's
+    eight blocks in VMEM): y and the gradients of all six inputs against
+    the jax.numpy chunk walk on the same bfloat16 values, and the
+    device ms a call by kernel. Runs where asked for by name."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ssd_scan as ssd
+    bsz, t, h, p, n = (1, 160, 16, 64, 16) if rehearse \
+        else (1, 8192, 64, 64, 128)
+    chunk = 128                # the kernels walk whole lane tiles
+    bf = lambda v: v.astype(jnp.bfloat16)
+    for g in ((2, 1) if rehearse else (8, 1)):
+        ks = jax.random.split(jax.random.PRNGKey(seed + g), 7)
+        ops = (bf(jax.random.normal(ks[0], (bsz, t, h, p))),
+               jax.nn.softplus(jax.random.normal(ks[1], (bsz, t, h)) - 4.0),
+               -jnp.exp(jax.random.uniform(ks[2], (h,), maxval=2.7)),
+               bf(0.3 * jax.random.normal(ks[3], (bsz, t, g, n))),
+               bf(0.3 * jax.random.normal(ks[4], (bsz, t, g, n))),
+               jnp.ones((h,)))
+        dy = bf(jax.random.normal(ks[5], (bsz, t, h, p)))
+
+        def both(force, *a):
+            y, vjp = jax.vjp(functools.partial(
+                ssd.ssd_scan, chunk=chunk, force=force), *a)
+            return (y,) + vjp(dy.astype(y.dtype))
+
+        kernels = jax.jit(functools.partial(
+            both, "interpret" if rehearse else "pallas"))
+        t0 = time.perf_counter()
+        text = "" if rehearse else compiled_text(kernels, *ops)
+        ms, got, kinds = _device_ms(kernels, ops, 4, rehearse, "ssd")
+        want = jax.jit(functools.partial(both, "chunked"))(*ops)
+        errs = _far(got, [w.astype(jnp.float32) for w in want])
+        log("[ssd] x [%d, %d, %d, %d] bf16, %d states, %d group(s) of %d "
+            "heads, %d a grid step: y %.3e dx %.3e ddt %.3e dA %.3e dB "
+            "%.3e dC %.3e dD %.3e from the jax.numpy chunk walk (%.1f s); "
+            "forward + backward %.3f ms a call on the device (%s)" % (
+                bsz, t, h, p, n, g, h // g, ssd._block_heads(h // g, p),
+                *errs, time.perf_counter() - t0, ms,
+                ", ".join("%s %.3f" % kv for kv in kinds.most_common(4))))
+        assert max(errs) <= FLASH_GRAD_TOL, errs
+        if not rehearse:
+            assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
 def phase_delta(seed, rehearse):
@@ -1826,7 +1882,8 @@ def main():
                          "dp2 x tp2 mesh and its one-device baseline")
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
-                         "(flash, gqa, own_block, mla, window, scan, delta, "
+                         "(flash, gqa, own_block, mla, window, scan, ssd, "
+                         "delta, "
                          "diff, "
                          "rotary, "
                          "experts, grouped, "
@@ -1855,16 +1912,18 @@ def main():
         phases = {"flash": phase_flash, "gqa": phase_gqa,
                   "own_block": phase_own_block, "mla": phase_mla,
                   "window": phase_window, "scan": phase_scan,
-                  "delta": phase_delta,
+                  "ssd": phase_ssd, "delta": phase_delta,
                   "diff": phase_diff, "rotary": phase_rotary,
                   "experts": phase_experts, "grouped": phase_grouped,
                   "rows": phase_rows, "embed": phase_embed,
                   "hc": phase_hc,
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}
-        # (`experts` ends with `grouped`: not twice where all run)
+        # (`experts` ends with `grouped`: not twice where all run; `ssd`
+        # is a cell's own check, `tests/test_ssd_scan.py` the CPU's)
         for name in (args.phases.split(",") if args.phases
-                     else [name for name in phases if name != "grouped"]):
+                     else [name for name in phases
+                           if name not in ("grouped", "ssd")]):
             phases[name](args.seed, args.rehearse)
     log("[cache] %d entries in %s at end"
         % (compile_cache.entries(cache_dir), cache_dir))

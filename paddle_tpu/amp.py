@@ -38,9 +38,12 @@ def float32():
     as float32, multiplies at the highest precision and hands on
     float32, whatever AMP says: an exit gate's logit, whose ``log(1 -
     sigmoid)`` a bfloat16 result would flatten and whose gradient it
-    would lose. The elementwise ops never narrow what they read, so a
-    float32 value stays one through them (the survival products, the
-    entropy); ``exit_distribution`` widens by itself."""
+    would lose; a `scale` that carries it widens what it reads before
+    it multiplies (a published multiplier that bfloat16 does not hold,
+    ``models/granite_hybrid.py``). The elementwise ops never narrow
+    what they read, so a float32 value stays one through them (the
+    survival products, the entropy); ``exit_distribution`` widens by
+    itself."""
     from .core.program import default_main_program
     with default_main_program().op_attrs(float32=True):
         yield

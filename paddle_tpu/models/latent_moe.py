@@ -76,9 +76,12 @@ from paddle_tpu.models.transformer import lm_cost
 from paddle_tpu.ops.rotary import yarn_inv_freq
 
 
-def _linear(x, size, name):
+def _linear(x, size, name, std=None):
+    """``x W``, no bias; W drawn N(0, `std`) where one is given (the
+    repo's default, Xavier, otherwise)."""
+    init = fluid.initializer.Normal(0., std) if std else None
     return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=fluid.ParamAttr(name=name))
+                     param_attr=fluid.ParamAttr(name=name, initializer=init))
 
 
 def _norm(x, name, eps):
@@ -86,12 +89,12 @@ def _norm(x, name, eps):
                            param_attr=fluid.ParamAttr(name=name))
 
 
-def gated_ffn(x, width, name):
+def gated_ffn(x, width, name, std=None):
     """``W_down(silu(W_gate x) * (W_up x))``: parameters ``<name>_gate``,
-    ``_up``, ``_down``."""
-    hidden = layers.silu_mul(_linear(x, width, name + "_gate"),
-                             _linear(x, width, name + "_up"))
-    return _linear(hidden, int(x.shape[-1]), name + "_down")
+    ``_up``, ``_down``, each as `_linear` draws it at `std`."""
+    hidden = layers.silu_mul(_linear(x, width, name + "_gate", std),
+                             _linear(x, width, name + "_up", std))
+    return _linear(hidden, int(x.shape[-1]), name + "_down", std)
 
 
 def attention_scale(d_nope, d_rope, rope):

@@ -47,24 +47,27 @@ MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 
 
 def mamba2_mixer(h, name, n_head, head_dim, n_group, d_state, d_conv, eps,
-                 dt_min=1e-3, dt_max=1e-1, scan_chunk=0):
+                 dt_min=1e-3, dt_max=1e-1, scan_chunk=0, a_max=16.0,
+                 std=None):
     """The Mamba-2 mixer over the normed stream h ``[B, T, d]``:
     parameters ``<name>_in_z`` / ``_in_x`` / ``_in_b`` / ``_in_c`` /
     ``_in_dt``, ``<name>_conv_x_w`` and ``_b`` (and ``conv_b``,
     ``conv_c``), ``<name>_dt_bias``, ``<name>_scan_a_log``,
-    ``_scan_d``, ``<name>_gnorm``, ``<name>_out``."""
+    ``_scan_d``, ``<name>_gnorm``, ``<name>_out``. `a_max` is
+    ``layers.ssd_scan``'s, `std` ``_linear``'s for the six
+    projections."""
     d_inner, d_bc = n_head * head_dim, n_group * d_state
     conv = lambda part, width: layers.ssm_conv(
-        _linear(h, width, "%s_in_%s" % (name, part)), d_conv,
+        _linear(h, width, "%s_in_%s" % (name, part), std), d_conv,
         name="%s_conv_%s" % (name, part))
-    z = _linear(h, d_inner, name + "_in_z")
-    dt = layers.ssm_dt(_linear(h, n_head, name + "_in_dt"), dt_min, dt_max,
-                       name=name + "_dt_bias")
+    z = _linear(h, d_inner, name + "_in_z", std)
+    dt = layers.ssm_dt(_linear(h, n_head, name + "_in_dt", std), dt_min,
+                       dt_max, name=name + "_dt_bias")
     y = layers.ssd_scan(conv("x", d_inner), dt, conv("b", d_bc),
-                        conv("c", d_bc), n_head, n_group, chunk=scan_chunk,
-                        name=name + "_scan")
+                        conv("c", d_bc), n_head, n_group, a_max=a_max,
+                        chunk=scan_chunk, name=name + "_scan")
     y = layers.gated_group_norm(y, z, n_group, eps, name=name + "_gnorm")
-    return _linear(y, int(h.shape[-1]), name + "_out")
+    return _linear(y, int(h.shape[-1]), name + "_out", std)
 
 
 def relu2_ffn(x, width, name):
@@ -74,14 +77,18 @@ def relu2_ffn(x, width, name):
     return _linear(hidden, int(x.shape[-1]), name + "_down")
 
 
-def attention_mixer(h, name, n_head, n_kv_head, head_dim):
+def attention_mixer(h, name, n_head, n_kv_head, head_dim, scale=0.0,
+                    std=None):
     """Grouped-query attention with no position signal over the normed
-    stream h: parameters ``<name>_wq``, ``_wk``, ``_wv``, ``_wo``."""
-    q = _linear(h, n_head * head_dim, name + "_wq")
-    k = _linear(h, n_kv_head * head_dim, name + "_wk")
-    v = _linear(h, n_kv_head * head_dim, name + "_wv")
-    return _linear(layers.causal_attention(q, k, v, n_head, n_kv_head),
-                   int(h.shape[-1]), name + "_wo")
+    stream h: parameters ``<name>_wq``, ``_wk``, ``_wv``, ``_wo``.
+    `scale` multiplies the scores (0: ``head_dim^-0.5``); `std` is
+    ``_linear``'s for the four."""
+    q = _linear(h, n_head * head_dim, name + "_wq", std)
+    k = _linear(h, n_kv_head * head_dim, name + "_wk", std)
+    v = _linear(h, n_kv_head * head_dim, name + "_wv", std)
+    return _linear(layers.causal_attention(q, k, v, n_head, n_kv_head,
+                                           scale=scale),
+                   int(h.shape[-1]), name + "_wo", std)
 
 
 def nemotron_h_lm(vocab_size, seq_len, pattern, d_model, n_head, n_kv_head,

@@ -357,8 +357,9 @@ def _device_limit(ctx):
 
 # the ops whose backward rules read none of their operands: a value
 # that only they read, up to the region's end, is dead in the region's
-# second forward
-_SUMS = ("elementwise_add", "elementwise_sub", "sum")
+# second forward (`scale`: a published multiplier on a sublayer's result
+# before it joins the stream, models/granite_hybrid.py)
+_SUMS = ("elementwise_add", "elementwise_sub", "sum", "scale")
 
 
 def _read_by_a_backward_rule(ops, idx, slot=None):

@@ -148,6 +148,12 @@ _PINS_THE_BENCHMARKS_END = {
         "count what their ReLU leaves on. Its assertions run in "
         "test_chipbench_nemotron_h.py::"
         "test_smallthinkers_files_hold_to_their_source_as_pr_46_left_them",
+    "test_chipbench_nemotron_h.py::test_the_entries_in_benchmark_json":
+        "pins ssd_roof_pct's and ssd_glue_dev_share_pct's lists to PR 62's "
+        "cell alone; PR 64 appended the cell whose nine Mamba-2 mixers the "
+        "same two readers read. Its assertions run in "
+        "test_chipbench_granite_hybrid.py::"
+        "test_pr_62s_pin_of_the_scan_readers_lists_as_pr_62_left_them",
 }
 
 

@@ -1,8 +1,10 @@
 """The state-space-dual scan of a Mamba-2 mixer (ops/ssd_scan.py, ISSUE
 62): the three paths against each other, values and all six gradients,
 with T no multiple of the chunk and heads in groups that share B_t and
-C_t; the kernels' operands in bfloat16 with a float32 state; the
-gate-then-norm over groups; the Program ops; the lowerings' counter."""
+C_t; ONE group of 16 and of 64 heads, walked in head blocks of 8 that
+sum its dB and dC between them (ISSUE 64); the kernels'
+operands in bfloat16 with a float32 state; the gate-then-norm over
+groups; the Program ops; the lowerings' counter."""
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +59,43 @@ def test_a_walk_gives_the_recurrence_and_all_its_gradients(truth, force,
                                    err_msg="d" + name)
 
 
+@pytest.fixture(scope="module", params=[(16, 1), (64, 1), (32, 2)],
+                ids=lambda hg: "%d_heads_in_%d" % hg)
+def wide(request):
+    """Heads of 64 columns, so that a grid step walks 8 of them: 2, 8
+    and 2 head blocks to a group."""
+    h, g = request.param
+    args, weight = _operands(5, bsz=1, t=40, h=h, g=g, p=64, n=16)
+    assert S._block_heads(h // g, 64) == 8
+    return args, weight, S.ssd_scan(*args, force="steps"), _grads(
+        args, weight, "steps", None)
+
+
+def test_a_groups_head_blocks_give_the_recurrence_and_all_its_gradients(
+        wide):
+    """40 rows in chunks of 16 (padded to 48), several head blocks to a
+    group: y, and dB and dC summed over ALL the group's heads, as the
+    row-by-row form gives them."""
+    args, weight, y, grads = wide
+    np.testing.assert_allclose(
+        S.ssd_scan(*args, chunk=16, force="interpret"), y, atol=2e-5 * float(
+            jnp.max(jnp.abs(y))))
+    for name, got, want in zip(NAMES, _grads(args, weight, "interpret", 16),
+                               grads):
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(got, want, atol=5e-6 * scale,
+                                   err_msg="d" + name)
+
+
+@pytest.mark.parametrize("per_group,p,want", [
+    (8, 64, 8), (64, 64, 8), (16, 64, 8), (4, 8, 4), (64, 8, 64),
+    (6, 128, 3), (2, 512, 1), (3, 1024, 1)])
+def test_a_grid_steps_heads_follow_from_the_shapes(per_group, p, want):
+    """The most heads that divide the group and fill 512 lanes at most;
+    a group of 8 heads of 64 is ONE block, as before ISSUE 64."""
+    assert S._block_heads(per_group, p) == want
+
+
 def test_the_terms_each_matter(truth):
     """D, the decay and the groups: the recurrence without each differs
     (so the agreement above is no agreement of zeros)."""
@@ -102,10 +141,18 @@ def test_the_lowerings_count_themselves():
     S.ssd_scan(*args, force="steps")
     S.ssd_scan(*args, chunk=8, force="chunked")
     got = {key: v for key, v in counter.snapshot().items()}
-    assert got == {("interpret", "fwd", "8", "8"): 1,
-                   ("interpret", "bwd", "8", "8"): 1,
-                   ("steps", "fwd", "0", "8"): 1,
-                   ("chunked", "fwd", "8", "8"): 1}
+    # (path, direction, chunk, d_state, a group's heads, a grid step's,
+    # the Gram products a group's chunk takes)
+    assert got == {("interpret", "fwd", "8", "8", "2", "2", "1"): 1,
+                   ("interpret", "bwd", "8", "8", "2", "2", "1"): 1,
+                   ("steps", "fwd", "0", "8", "2", "0", "0"): 1,
+                   ("chunked", "fwd", "8", "8", "2", "2", "1"): 1}
+    counter.clear()
+    args, weight = _operands(1, bsz=1, t=16, h=16, g=1, p=64, n=8)
+    _grads(args, weight, "interpret", 8)
+    # two head blocks to the group, a Gram product each
+    assert set(counter.snapshot()) == {
+        ("interpret", d, "8", "8", "16", "8", "2") for d in ("fwd", "bwd")}
 
 
 def test_what_is_refused():
@@ -176,3 +223,21 @@ def test_the_program_ops_train():
     for name, before in (("m_scan_a_log", a_log), ("m_scan_d", d),
                          ("m_gnorm", w)):
         assert np.abs(read(name) - before).max() > 1e-6, name
+
+
+def test_chip_smoke_rehearses_the_ssd_phase():
+    """``chip_smoke.py --phases ssd`` is the chip's own check of the
+    pair at both cells' shapes; here its rehearsal, kernels in interpret
+    mode, two groups of 8 heads and ONE of 16 in two head blocks."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py", "--rehearse", "--phases", "ssd"],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    lines = [l for l in out.stdout.splitlines() if l.startswith("[ssd]")]
+    assert len(lines) == 2 and "1 group(s) of 16 heads, 8 a grid step" \
+        in lines[1], lines
