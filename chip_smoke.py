@@ -51,6 +51,14 @@ d1024, FFN 4096, T=1024, vocab 8192), weights random from ``--seed``:
           over the blocks): y and all six gradients against the
           jax.numpy chunk walk, device ms by kernel; ONLY where
           `--phases ssd` asks for it
+  conv    the causal depthwise convolution + SiLU in front of a scan or
+          a delta rule (the Program op ssm_conv) as the kernel pair of
+          ops/ssm_conv.py (ISSUE 65) at the four cells' shapes, x [1,
+          8192, C] bf16 under 4 taps: C 4096 and 128 with a bias
+          (Granite's; Nemotron's 4096), 1024, 5120, 1440 and 2880
+          without: y, dx, dw and dbias against the jax.numpy path on
+          the chip, device ms a call of each direction of both beside
+          the bytes' time; ONLY where `--phases conv` asks for it
   diff    differential attention through the streamed kernels (ISSUE
           40): 40 query and 20 key/value heads of 64, values of 128,
           T 4096, full and under a window of 512: a1, a2, dq, dk, dv
@@ -840,6 +848,94 @@ def phase_ssd(seed, rehearse):
         assert max(errs) <= FLASH_GRAD_TOL, errs
         if not rehearse:
             assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
+
+def conv_times(c, biased, seed, rehearse, t=8192, calls=8):
+    """One shape of `phase_conv`: x [1, t, c] bf16 under 4 taps. ({"fwd
+    pallas", "bwd pallas", "fwd taps", "bwd taps": device ms a call},
+    the largest differences of y, dx, dw, dbias from the jax.numpy
+    path's as shares of the largest value). The backward is forward +
+    backward in one executable less the forward's."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import selective_scan as SS
+    from paddle_tpu.ops import ssm_conv
+    ks = jax.random.split(jax.random.PRNGKey(seed + c), 4)
+    x = jax.random.normal(ks[0], (1, t, c)).astype(jnp.bfloat16)
+    dy = jax.random.normal(ks[1], (1, t, c)).astype(jnp.bfloat16)
+    w = jax.random.uniform(ks[2], (4, c), minval=-0.5, maxval=0.5)
+    args = (x, w) + ((jax.random.uniform(ks[3], (c,), minval=-0.5,
+                                         maxval=0.5),) if biased else ())
+
+    def taps(x, w, bias=None):          # the module's own path off a TPU
+        was, SS._on_tpu = SS._on_tpu, lambda x: False
+        try:
+            return SS.causal_conv_silu(x, w, bias)
+        finally:
+            SS._on_tpu = was
+
+    def pallas(x, w, bias=None):
+        return ssm_conv.conv_silu(x, w, bias, interpret=rehearse)
+
+    programs = {}
+    for path, fn in (("pallas", pallas), ("taps", taps)):
+        def fwd(*a, fn=fn):
+            return fn(*a)
+
+        def both(dy, *a, fn=fn):
+            y, pull = jax.vjp(fn, *a)
+            return (y,) + pull(dy)
+
+        fwd.__name__ = "conv_fwd_%s_%d" % (path, c)
+        both.__name__ = "conv_both_%s_%d" % (path, c)
+        programs[fwd.__name__] = (jax.jit(fwd), args)
+        programs[both.__name__] = (jax.jit(both), (dy,) + args)
+    ms = _device_ms_each(programs, calls, rehearse, "conv")
+    out = {}
+    for path in ("pallas", "taps"):
+        f, fb = (ms["conv_%s_%s_%d" % (kind, path, c)]
+                 for kind in ("fwd", "both"))
+        out["fwd " + path], out["bwd " + path] = f, fb - f
+    got, want = (programs["conv_both_%s_%d" % (path, c)]
+                 for path in ("pallas", "taps"))
+    errs = _far(got[0](*got[1]),
+                [v.astype(jnp.float32) for v in want[0](*want[1])])
+    return out, errs
+
+
+def phase_conv(seed, rehearse):
+    """The Program op ssm_conv's kernel pair (ISSUE 65) against the
+    jax.numpy path at the shapes of the four cells that run it: one
+    sequence of 8,192 rows under 4 taps, bfloat16; 4,096 and 128
+    channels with a bias (granite4hmicro_train_T8k's x and its B_t or
+    C_t; nemotron3nano_train_T8k's x), 1,024 (Nemotron's B_t or C_t),
+    5,120 (phi4flash_train_T8k), 1,440 and 2,880 (olmohybrid_train_T8k's
+    q or k and v: not whole lane tiles, the last block of 512 lanes partly
+    outside the array) without.
+    Device ms a call of each direction of both paths beside the time of
+    the bytes at 819 GB/s (forward: x read and y written; backward: x
+    and dy read, dx written), and the largest difference of y, dx, dw
+    and dbias. Runs where asked for by name."""
+    shapes = ((256, True, 80), (96, False, 80)) if rehearse else (
+        (4096, True, 8192), (128, True, 8192), (1024, False, 8192),
+        (5120, False, 8192), (1440, False, 8192), (2880, False, 8192))
+    for c, biased, t in shapes:
+        ms, errs = conv_times(c, biased, seed, rehearse, t,
+                              2 if rehearse else 8)
+        array = t * c * 2 / 819e6       # ms to move one bf16 array
+        log("[conv] x [1, %d, %d] bf16, 4 taps, %s: forward %.3f ms "
+            "(jax.numpy %.3f; the bytes %.3f), backward %.3f ms (jax.numpy "
+            "%.3f; the bytes %.3f): %.0f and %.0f GB/s; y %.2e dx %.2e dw "
+            "%.2e%s from the jax.numpy path's" % (
+                t, c, "a bias" if biased else "no bias", ms["fwd pallas"],
+                ms["fwd taps"], 2 * array, ms["bwd pallas"], ms["bwd taps"],
+                3 * array, 2 * array * 819 / ms["fwd pallas"],
+                3 * array * 819 / ms["bwd pallas"], *errs[:3],
+                " dbias %.2e" % errs[3] if biased else ""))
+        # a bfloat16 result may round the other way: 2^-8 of a value
+        assert max(errs[:2]) <= 2 ** -7 and max(errs[2:]) <= 1e-4, errs
+    if rehearse:
+        log("[conv] (REHEARSAL: a CPU's times, no device number)")
 
 
 def phase_delta(seed, rehearse):
@@ -1883,7 +1979,7 @@ def main():
     ap.add_argument("--phases", default="",
                     help="comma separated: only these one-chip phases "
                          "(flash, gqa, own_block, mla, window, scan, ssd, "
-                         "delta, "
+                         "conv, delta, "
                          "diff, "
                          "rotary, "
                          "experts, grouped, "
@@ -1912,7 +2008,8 @@ def main():
         phases = {"flash": phase_flash, "gqa": phase_gqa,
                   "own_block": phase_own_block, "mla": phase_mla,
                   "window": phase_window, "scan": phase_scan,
-                  "ssd": phase_ssd, "delta": phase_delta,
+                  "ssd": phase_ssd, "conv": phase_conv,
+                  "delta": phase_delta,
                   "diff": phase_diff, "rotary": phase_rotary,
                   "experts": phase_experts, "grouped": phase_grouped,
                   "rows": phase_rows, "embed": phase_embed,
@@ -1920,10 +2017,11 @@ def main():
                   "train": functools.partial(phase_train, cfg),
                   "serve": functools.partial(phase_serve, cfg)}
         # (`experts` ends with `grouped`: not twice where all run; `ssd`
-        # is a cell's own check, `tests/test_ssd_scan.py` the CPU's)
+        # and `conv` are a cell's own checks, `tests/test_ssd_scan.py`
+        # and `tests/test_ssm_conv_kernel.py` the CPU's)
         for name in (args.phases.split(",") if args.phases
                      else [name for name in phases
-                           if name not in ("grouped", "ssd")]):
+                           if name not in ("grouped", "ssd", "conv")]):
             phases[name](args.seed, args.rehearse)
     log("[cache] %d entries in %s at end"
         % (compile_cache.entries(cache_dir), cache_dir))

@@ -1,7 +1,10 @@
 """The selective scan of a state-space (Mamba) layer, forward and
 backward, as a pair of Pallas TPU kernels that walk time in chunks
 (ISSUE 40), and the ops round it: the causal depthwise convolution in
-front, ``softplus`` for the step size, the gates behind.
+front (the Program op ``ssm_conv``: on a TPU the kernel pair of
+``ops/ssm_conv.py`` since ISSUE 65, the shifted slices of
+``short_conv.causal_taps`` anywhere else), ``softplus`` for the step
+size, the gates behind.
 
 The recurrence, for a channel c of C and a state n of N (16), over the
 T steps of one sequence, the state float32 whatever the operands are::
@@ -67,6 +70,7 @@ from jax import lax
 
 from ..core.registry import register
 from ..monitor import metrics as _metrics
+from . import ssm_conv
 from .flash_attention import _on_tpu
 from .short_conv import causal_taps
 
@@ -395,8 +399,18 @@ def selective_scan(s, dt, a, b, c, d, chunk=None, group=None, force=None):
 def causal_conv_silu(x, w, bias=None):
     """``silu(bias + sum_i w[i] * x_{t - K + 1 + i})`` over time, each
     channel by itself, zeros before the sequence: x [B, T, C], w [K, C]
-    (K 4), bias [C] or None (no bias). K shifted slices added up
-    (``short_conv.causal_taps``), float32 inside."""
+    (K 4), bias [C] or None (no bias), float32 inside. On a TPU the
+    kernel pair of ``ops/ssm_conv.py`` under its written backward
+    (ISSUE 65; up to 9 taps, any width: a C that is not whole lane
+    tiles, as Olmo-Hybrid's 1,440, ends in a block partly outside the
+    array); anywhere else K shifted slices added up
+    (``short_conv.causal_taps``) and autodiff's transpose of them.
+    Nothing but the device and the shape chooses;
+    ``ptpu_ssm_conv_lowerings_total`` says which."""
+    if x.ndim == 3 and _on_tpu(x) and ssm_conv.kernel_tiles(
+            x.shape[1], x.shape[2], w.shape[0]):
+        return ssm_conv.conv_silu(x, w, bias)
+    ssm_conv.count("taps", "fwd", w)
     f32 = jnp.float32
     return jax.nn.silu(causal_taps(
         x.astype(f32), w, None if bias is None else bias.astype(f32))

@@ -1,6 +1,9 @@
 """The gated short convolution of a convolution-only token mixer (ISSUE
 49), and the causal taps it shares with the convolution in front of a
-selective scan (``ops/selective_scan.py`` ``causal_conv_silu``).
+selective scan (``ops/selective_scan.py`` ``causal_conv_silu``, the
+Program op ``ssm_conv``: its path wherever there is no TPU; on a TPU
+that op is the kernel pair of ``ops/ssm_conv.py`` since ISSUE 65,
+and this file's op keeps the taps).
 
 The operator, for the rows h ``[T, d]`` of one sequence: ``[B, C, X] =
 h W_in`` (three parts of C channels side by side, as ONE ``mul`` leaves
@@ -13,11 +16,11 @@ X's dtype out.
 
 ``jax.numpy``: the taps are K shifted slices of a padded array added
 up, which XLA fuses with the two gates into a pass over X forward, and
-autodiff's transpose of them into the passes backward; no kernel (the
-cell ``lfm2_train_T32k`` reads what that costs:
-``short_conv_dev_share_pct``). Each lowering counts itself at trace
-time in ``ptpu_short_conv_lowerings_total{taps, channels}``, and its
-device rows carry the Program op's scope. A ``layers.recompute``
+autodiff's transpose of them into the passes backward; no kernel of
+``gated_short_conv``'s own yet (the cell ``lfm2_train_T32k`` reads what
+that costs: ``short_conv_dev_share_pct``). Each lowering counts itself
+at trace time in ``ptpu_short_conv_lowerings_total{taps, channels}``,
+and its device rows carry the Program op's scope. A ``layers.recompute``
 region may keep the op's result from its forward to its backward under
 the name `CONV_OUT` (``ops/control_flow.py``: the block's plan names it
 where the op is lowered, as it names a `mul` result).

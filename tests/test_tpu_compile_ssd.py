@@ -75,15 +75,22 @@ def test_the_cells_step_fits_under_its_plan(chip, monkeypatch, cell, under):
     what the compiler holds. `granite4hmicro_train_T8k`'s, ten regions
     of two sublayers each beside 12.35 GB of state (the tightest cell
     yet), under a plan that keeps 74 of 78 products: 1.45 GiB under,
-    the plan's reckoning 9.9% over the compiler's."""
+    the plan's reckoning 9.9% over the compiler's. Since ISSUE 65 (the
+    convolutions as kernels: no float32 copy of a mixer's x in HBM) the
+    compiler holds 15.29 GB of Granite's step, 0.06 less, and the same
+    plan, which fills the room it reckons (16.87 GB of the limit's
+    16.91), reads 10.3% over: that case FAILS its last assertion, the
+    tenth, until the plan reckons a region nearer to what the compiler
+    holds (PERF.md section 7; the limit is not the thing to move)."""
     import paddle_tpu as fluid
     from paddle_tpu.ops import control_flow as CF
     from paddle_tpu.ops import (embedding_grad, flash_attention,
-                                grouped_matmul, moe_rows, rotary, ssd_scan)
+                                grouped_matmul, moe_rows, rotary,
+                                selective_scan, ssd_scan)
     from test_recompute_kinds import _V5E_LIMIT, built_cell
     from test_tpu_compile_regions import _step
     for module in (flash_attention, rotary, moe_rows, grouped_matmul,
-                   embedding_grad, ssd_scan):
+                   embedding_grad, ssd_scan, selective_scan):
         monkeypatch.setattr(module, "_on_tpu", lambda x: True)
     monkeypatch.setattr(CF, "_device_limit", lambda ctx: _V5E_LIMIT)
     with fluid.amp.amp_guard(True):
@@ -96,6 +103,8 @@ def test_the_cells_step_fits_under_its_plan(chip, monkeypatch, cell, under):
     reckoned = last["state"] + last["stream"] + max(
         last["head"] + last["kept"],
         last["region"] + last["kept_before_last"])
-    assert abs(reckoned - held) < 0.1 * held, (reckoned, held)
     text = compiled.as_text()
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    # (since ISSUE 65 the convolutions in front of the scan are kernels too)
+    assert "ssm_conv_fwd" in text and "ssm_conv_bwd" in text
+    assert abs(reckoned - held) < 0.1 * held, (reckoned, held)

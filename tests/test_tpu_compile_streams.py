@@ -263,3 +263,39 @@ def test_embedding_grad_compiles_for_v5e(chip, shape):
     assert "%embedding_grad_rows." in text or "%embedding_grad_rows " in text
     assert not re.search(r"f32\[%d,%d\]\S* scatter\(" % (vocab, d), text)
     assert " scatter(" not in text and " while(" not in text
+
+
+# ISSUE 65: the causal depthwise convolution + SiLU in front of a scan
+# or a delta rule (ops/ssm_conv.py) at the widths of the four cells that
+# run it, one sequence of 8,192 rows under 4 taps, bfloat16.
+@pytest.mark.parametrize("c,biased,dtype", [
+    (4096, True, jnp.bfloat16), (128, True, jnp.bfloat16),
+    (1024, False, jnp.bfloat16), (5120, False, jnp.bfloat16),
+    (1440, False, jnp.bfloat16), (2880, False, jnp.bfloat16),
+    (2880, False, jnp.float32)],
+    ids=["granites_x", "granites_b_or_c", "nemotrons_b_or_c", "phi4flashs",
+         "olmo_hybrids_q_or_k", "olmo_hybrids_v", "olmo_hybrids_v_float32"])
+def test_ssm_conv_kernels_compile_for_v5e(chip, c, biased, dtype):
+    """The path a v5e takes: ONE kernel a direction, by the names a
+    device trace will show; sublane shifts of float32 values at offsets
+    that are not whole tiles, a 16-row block of x before a tile and, at
+    1,440 and 2,880 channels, a last block of 512 lanes that is partly
+    outside the array (of x, dy, w and the sums alike), which only the
+    chip's compiler judges; dw and dbias come back as partial sums by
+    sublane, [B, K + 1, 8, C]."""
+    from paddle_tpu.ops import ssm_conv
+    t, k = 8192, 4
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=chip)
+    avals = (sd((1, t, c), dtype), sd((k, c), jnp.float32)) + (
+        (sd((c,), jnp.float32),) if biased else ())
+
+    def loss(*a):
+        return ssm_conv.conv_silu(*a).astype(jnp.float32).sum()
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=tuple(range(len(avals)))), *avals)
+    assert text.count("tpu_custom_call") == 2
+    for name in ("ssm_conv_fwd", "ssm_conv_bwd"):
+        assert "%" + name + "." in text or "%" + name + " " in text
+    assert "f32[1,%d,8,%d]" % (k + biased, c) in text
