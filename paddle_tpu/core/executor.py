@@ -25,10 +25,11 @@ The step is written once: ``Executor._step`` is the skeleton under
 owns what every call of a compiled step does — the cache key
 (``_cache_key``: every trace-time toggle is listed there and nowhere else),
 the random key of a logical step (``_step_keys``; the eager path's too), the
-build, ``jax.jit`` and the compile ahead of the first call
-(``_first_compile``), the monitor's timing and the profiler's event, and the
-commit of state and fetches. An entry hands it an ``_Entry`` that says only
-what differs: the root's and the monitor's label, what joins the key, an AMP
+build, ``jax.jit`` and the compile ahead of a train step's first call
+(``_first_compile``: the executable the kernel ledger reads and a
+regions' plan is confirmed by), the monitor's timing and the profiler's
+event, and the commit of state and fetches. An entry hands it an
+``_Entry`` that says only what differs: the root's and the monitor's label, what joins the key, an AMP
 value to pin, gradient accumulation, whether host (IO) ops go to the eager
 path, a check of the feeds, and how state and feeds are laid out on the
 devices and fetched values brought back. A new toggle goes into
@@ -877,25 +878,33 @@ class Executor:
 
     def _first_compile(self, program, entry, args, rebuild):
         """Lower and compile, ahead of its first call, the step of a
-        program whose recompute regions keep values by a plan
-        (ops/control_flow.py _plan_kept; any other program's `entry` is
-        handed back as it is): the plan's reserve is a reckoning, and
-        the compiled executable is what confirms it. Its
+        program that trains (its main block has a `backward_marker`, or
+        a regions' plan; any other program's `entry`, the start-up
+        program's and a `for_test` clone's, is handed back as it is),
+        for two readers of the executable. The build's table of the op
+        ledger keeps it for the kernel ledger (paddle_tpu.trace.kernels:
+        what XLA fused into each kernel of the step), with its
+        memory_analysis() and the device's limit: one assignment here,
+        read only if asked.
+        And where the program's recompute regions keep values by a plan
+        (ops/control_flow.py _plan_kept), the plan's reserve is a
+        reckoning and the compiled executable is what confirms it: its
         memory_analysis() is said beside the plan's figures
         (control_flow.compiled_step), and where the compile fails with
         RESOURCE_EXHAUSTED the step is built and lowered once more with
         a plan of nothing, which is said and counted too. Returns the
         jitted step to call: `entry`, whose first call finds the
-        executable JAX kept for it (one trace, one compile), or the
-        fallback's."""
+        executable JAX kept for it (one trace, one lowering, one
+        compile), or the fallback's."""
         from ..ops import control_flow as _regions
-        if not _regions.plans(program):
+        planned, fell_back = _regions.plans(program), False
+        if not planned and not any(o.type == "backward_marker"
+                                   for o in program.global_block().ops):
             return entry
-        fell_back = False
         try:
             compiled = entry.lower(*args).compile()
         except Exception as e:        # jaxlib's XlaRuntimeError
-            if "RESOURCE_EXHAUSTED" not in str(e):
+            if not planned or "RESOURCE_EXHAUSTED" not in str(e):
                 raise
             _regions._LOG.warning(
                 "recompute: the step did not compile with the regions' "
@@ -911,7 +920,9 @@ class Executor:
             memory = compiled.memory_analysis()
         except Exception:             # a backend that gives none
             memory = None
-        _regions.compiled_step(memory, fell_back)
+        if planned:
+            _regions.compiled_step(memory, fell_back)
+        _trc.kernel_table(compiled, memory, _bytes_limit(args))
         return entry
 
     # ------------------------------------------------------------------
@@ -1750,6 +1761,19 @@ def _lower_op(ctx, op, lower=None):
         row["outputs"] = _described(ctx.env, op.outputs)
     if getattr(ctx, "check_nan", False):
         _record_nan_guards(ctx, op)
+
+
+def _bytes_limit(args):
+    """What the device a step's arguments sit on says it may hold
+    (memory_stats()["bytes_limit"]); None where the backend states none
+    (the CPU) or the arguments are on no device (shapes alone)."""
+    placed = next((leaf for leaf in jax.tree_util.tree_leaves(args)
+                   if isinstance(leaf, jax.Array)), None)
+    try:
+        device = min(placed.devices(), key=lambda d: d.id)
+        return (device.memory_stats() or {}).get("bytes_limit")
+    except Exception:                 # no array, or a deleted one
+        return None
 
 
 def _described(env, slots):

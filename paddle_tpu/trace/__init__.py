@@ -29,7 +29,7 @@ site is a single is-None check (same bar as resilience.faults).
 from .runtime import (  # noqa: F401
     Span, SpanContext, Tracer, active_trace_id, annotate, child_span,
     current_span, detached_span, disable, enable, enabled, extract,
-    fetched, maybe_enable_from_flags, op_table, ops, phase, retain_trace,
-    span, steps, tail_armed, tail_dump, tracer,
+    fetched, kernel_table, kernels, maybe_enable_from_flags, op_table, ops,
+    phase, retain_trace, span, steps, tail_armed, tail_dump, tracer,
 )
 from .clock import midpoint_offset, probe  # noqa: F401

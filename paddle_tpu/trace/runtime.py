@@ -63,6 +63,17 @@ Design points:
     and at no other time; the last 8 builds are kept. ``ops()`` reads
     it. It is what says which weight, shape and recompute region a
     profile's ``mul.226`` is.
+  * The kernel ledger (ISSUE 66): the build of a train step also keeps
+    the executable the executor compiled ahead of the step's first
+    call (``kernel_table``: one assignment, beside five integers of
+    its ``memory_analysis()``), in the SAME table of the ring. Nothing
+    is read until ``kernels()`` is first asked for that build: then
+    the executable's optimised HLO text is parsed once
+    (``trace/hlo.py``) into one row for every instruction that runs as
+    a device op, the rows are kept and the text and the executable let
+    go. A row's ``scopes`` are keys of the build's op rows. It is what
+    says which Program ops XLA fused into a profile's ``fusion.412``,
+    with the products' FLOPs and the kernel's bytes.
   * The span log reuses monitor's FlightRecorder (bounded JSONL,
     atomic-append, in-band truncation marker). Rows:
       span        {trace, span, parent, name, t0, dur, pid, proc, tid,
@@ -91,7 +102,7 @@ __all__ = [
     "tracer", "span", "annotate", "current_span", "active_trace_id",
     "extract", "maybe_enable_from_flags", "detached_span", "child_span",
     "retain_trace", "tail_armed", "tail_dump", "phase", "steps",
-    "fetched", "op_table", "ops",
+    "fetched", "op_table", "ops", "kernel_table", "kernels",
 ]
 
 _DEFAULT_MAX_BYTES = 64 << 20
@@ -479,6 +490,7 @@ def steps(root=None, since=None):
 # a table a build, oldest first: a run of the benchmark makes three (the
 # start-up program, the for-test forward, the step)
 _OP_BUILDS = collections.deque(maxlen=8)
+_OP_HEADER = ("root", "backward", "step", "t_build")
 
 
 def op_table(backward):
@@ -493,10 +505,11 @@ def op_table(backward):
     train step's table from the start-up program's) and ``t_build`` on
     the step ledger's clock."""
     root = getattr(_thread, "root", None)
-    table = {"root": None if root is None else root._name,
-             "backward": bool(backward),
-             "step": None if root is None else root._row["step"],
-             "t_build": _now(), "rows": {}}
+    table = _thread.build = {
+        "root": None if root is None else root._name,
+        "backward": bool(backward),
+        "step": None if root is None else root._row["step"],
+        "t_build": _now(), "rows": {}, "kernels": None}
     _OP_BUILDS.append(table)
     return table["rows"]
 
@@ -532,10 +545,96 @@ def ops(root=None, backward=None):
         if backward is not None and table["backward"] != backward:
             continue
         rows = [dict(row) for _, row in sorted(table["rows"].items())]
-        header = {k: v for k, v in table.items() if k != "rows"}
+        header = {k: table[k] for k in _OP_HEADER}
         header["count"] = len(rows)
         return header, rows
     return None
+
+
+# -- the kernel ledger -------------------------------------------------------
+
+_KERNELS_LOCK = threading.Lock()
+
+
+def kernel_table(compiled, memory=None, bytes_limit=None):
+    """An executor hands the thread's newest build (``op_table``) the
+    executable it compiled ahead of the step's first call: the build's
+    table keeps it, with ``memory`` (its ``memory_analysis()``, None
+    where the backend gives none) cut to five integers and the device's
+    ``bytes_limit``, until ``kernels()`` first reads it. One
+    assignment: nothing is parsed here."""
+    table = getattr(_thread, "build", None)
+    if table is None:
+        return
+    sizes = None if memory is None else dict(
+        {what: int(getattr(memory, what + "_size_in_bytes", 0))
+         for what in ("argument", "output", "alias", "temp")},
+        bytes_limit=int(bytes_limit) if bytes_limit else None)
+    table["kernels"] = {"compiled": compiled, "memory": sizes,
+                        "rows": None}
+
+
+def kernels(root=None, backward=None):
+    """The kernel ledger: ``(header, rows)`` of the newest build (of the
+    last 8) whose ``root`` and ``backward`` are the ones asked for (the
+    build ``ops`` gives for them), None where there is none or the
+    executor compiled none ahead (the start-up program, a ``for_test``
+    forward). The first call for a build parses the compiled step's
+    HLO text (``compiled.as_text()``, ``trace/hlo.py``), keeps the rows
+    in the build's table and lets the text and the executable go; a
+    later call copies the rows.
+
+    The header: ``root``, ``backward``, ``step`` (the op table's own),
+    ``module`` (the HLO module's name), ``count`` (of rows), ``memory``
+    (``argument``, ``output``, ``alias``, ``temp`` bytes of the
+    executable's ``memory_analysis()`` and the device's ``bytes_limit``,
+    each None where the backend states none; taken at the build),
+    ``parse_seconds`` (``as_text()`` and the parse, on
+    ``time.perf_counter()``) and ``text_bytes``.
+
+    A row is one instruction of the compiled step that runs as a device
+    op, under the ``name`` a profile's device event carries
+    (``fusion.412``): ``opcode``, ``fusion_kind``, ``computation``,
+    ``custom_call_target``, ``operands`` and ``results`` as ``((dtype,
+    shape), ...)``, ``bytes_in`` and ``bytes_out``, ``dots`` (every
+    product in it, nested fusions too: ``(op_name, lhs shape, rhs
+    shape, result shape, contracted size, flops)``), ``scopes``
+    (``{"<type>.<seq>": instructions}``: the Program ops XLA fused into
+    it, each the key ``seq`` of a row of ``ops()`` for the same build;
+    ``""`` counts the instructions that name none), ``nested`` (the
+    scopes that sit in a nested fusion), ``root_scope`` and
+    ``op_name`` (the instruction's own: what a profile books the whole
+    kernel to), ``passes`` (``fwd`` / ``second`` / ``bwd`` among the
+    body's names) and ``estimated_cycles`` (XLA's own, where it gives
+    one). ``trace/hlo.py`` says how each is read."""
+    for table in reversed(_OP_BUILDS):
+        if root is not None and table["root"] != root:
+            continue
+        if backward is not None and table["backward"] != backward:
+            continue
+        kept = table["kernels"]
+        if kept is None:
+            return None
+        with _KERNELS_LOCK:
+            if kept["rows"] is None:
+                _parse_kernels(kept)
+        header = {k: table[k] for k in _OP_HEADER[:3]}
+        header.update({k: v for k, v in kept.items()
+                       if k not in ("rows", "compiled")},
+                      count=len(kept["rows"]))
+        return header, [dict(row, scopes=dict(row["scopes"]))
+                        for row in kept["rows"]]
+    return None
+
+
+def _parse_kernels(kept):
+    from . import hlo
+    t0 = time.perf_counter()
+    text = kept["compiled"].as_text()
+    kept["module"], kept["rows"] = hlo.kernel_rows(text)
+    kept["text_bytes"] = len(text)
+    kept["parse_seconds"] = time.perf_counter() - t0
+    kept["compiled"] = None
 
 
 class _TailRing:

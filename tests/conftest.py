@@ -154,6 +154,23 @@ _PINS_THE_BENCHMARKS_END = {
         "same two readers read. Its assertions run in "
         "test_chipbench_granite_hybrid.py::"
         "test_pr_62s_pin_of_the_scan_readers_lists_as_pr_62_left_them",
+    "test_chipbench_ouro.py::test_the_entries_in_benchmark_json":
+        "pins the set of metrics PR 59's cell is listed on; PR 66 listed "
+        "every cell on the kernel ledger's four readers. Its assertions "
+        "run in test_chipbench_kernels.py::"
+        "test_a_cells_pinned_lists_are_as_its_pr_left_them",
+    "test_chipbench_granite_hybrid.py::test_the_entries_in_benchmark_json":
+        "pins the set of metrics PR 64's cell is listed on; PR 66 listed "
+        "every cell on the kernel ledger's four readers. Its assertions "
+        "run in test_chipbench_kernels.py::"
+        "test_a_cells_pinned_lists_are_as_its_pr_left_them",
+    "test_chipbench_granite_hybrid.py::"
+    "test_pr_62s_pin_of_the_scan_readers_lists_as_pr_62_left_them":
+        "runs PR 62's pin, which holds the set of metrics Nemotron's cell "
+        "is listed on, against the benchmark less the later CELLS; PR 66 "
+        "appended four METRICS that list every cell. Its assertions run in "
+        "test_chipbench_kernels.py::"
+        "test_a_cells_pinned_lists_are_as_its_pr_left_them",
 }
 
 
