@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
+from ..monitor import metrics as _metrics
 from .rotary import norm_rope
 
 
@@ -249,12 +250,64 @@ def _qk_norm_rope(ctx, op):
         else None, int(op.attr("wrap", 0)), op.attr("epsilon", 1e-6)))
 
 
+# `silu_mul` under a written backward whose results are VALUES (ISSUE
+# 67). As four lines of jax.numpy under autodiff, XLA's TPU pipeline
+# makes the activation, and its gradient, AGAIN inside the kernel of
+# each product that reads them (a nested fusion on the product's
+# operand): `ffn_down`'s weight gradient then waits on a sigmoid and
+# three operand streams where it would stream one, and ran at 35-42% of
+# the peak. Here the forward's result and the backward's two gradients
+# pass through `optimization_barrier`: no reader may make them again, so
+# every product of the MLP reads plain arrays, and XLA makes them in the
+# epilogue of the product before or in a loop fusion of their own. The
+# residuals are X and Y as they came (in a recompute region its kept
+# `mul_out`s): no float32 `[T, F]` and no `hidden` is saved.
+_REG = _metrics.registry()
+_SILU_MUL_LOWERINGS = _REG.counter(
+    "ptpu_silu_mul_lowerings_total",
+    "the gated FFN's activation (the Program op silu_mul) traced (one a "
+    "lowering of a direction, none a step): the path (rule: the written "
+    "backward whose results are values, the only one), the direction "
+    "and the width",
+    ("path", "direction", "width"))
+
+
+@jax.custom_vjp
+def silu_mul(x, y):
+    """``silu(x) * y``, float32 inside, x's dtype out."""
+    return _silu_mul_fwd(x, y)[0]
+
+
+def _silu_mul_fwd(x, y):
+    _SILU_MUL_LOWERINGS.inc(path="rule", direction="fwd",
+                            width=str(x.shape[-1]))
+    # (jax.nn.silu's own arithmetic, not the jitted function: a region
+    # whose kept X is float32 would save a call's result beside it)
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.logistic(xf) * y.astype(jnp.float32)
+    return jax.lax.optimization_barrier(out.astype(x.dtype)), (x, y)
+
+
+def _silu_mul_bwd(res, d):
+    x, y = res
+    _SILU_MUL_LOWERINGS.inc(path="rule", direction="bwd",
+                            width=str(x.shape[-1]))
+    f32 = jnp.float32
+    xf, d = x.astype(f32), d.astype(f32)
+    s = jax.lax.logistic(xf)
+    xs = xf * s
+    dx = d * y.astype(f32) * (s + xs * (1 - s))
+    return jax.lax.optimization_barrier(
+        (dx.astype(x.dtype), (d * xs).astype(y.dtype)))
+
+
+silu_mul.defvjp(_silu_mul_fwd, _silu_mul_bwd)
+
+
 @register("silu_mul")
 def _silu_mul(ctx, op):
     """silu(X) * Y: the gated FFN's hidden activation."""
-    x, y = ctx.in1(op, "X"), ctx.in1(op, "Y")
-    out = jax.nn.silu(x.astype(jnp.float32)) * y.astype(jnp.float32)
-    ctx.set_out(op, "Out", out.astype(x.dtype))
+    ctx.set_out(op, "Out", silu_mul(ctx.in1(op, "X"), ctx.in1(op, "Y")))
 
 
 @register("sigmoid_mul")

@@ -466,3 +466,62 @@ def test_the_weight_gradients_kernel_on_a_described_v5e(chip):
     # every product of the step is in some row, each once: the first
     # product (as many FLOPs as the second) takes no operand gradient
     assert [d[5] for r in rows for d in r["dots"]] == [2 * m * k * n] * 5
+
+
+def test_the_gated_mlps_products_read_values_on_a_described_v5e(
+        chip, monkeypatch):
+    """Two layers of ``h + W_down(silu_mul(hn W_gate, hn W_up))``, each
+    a ``layers.recompute`` region whose plan keeps both products, bf16
+    AMP and Adam, the Program's own step compiled for a described v5e
+    (ISSUE 67): the op's results are values behind barriers, so NO
+    kernel that holds a product makes the activation or its gradient
+    again on an operand (``silu_mul.<n>`` in no such row's ``nested``;
+    where XLA makes them is the epilogue of the product before), every
+    product is in some row once, and ``ffn_down``'s weight gradient
+    declares the bytes of its three arrays and Adam's moments, no
+    more."""
+    from paddle_tpu.models.latent_moe import gated_ffn
+    from paddle_tpu.ops import control_flow as CF
+    from test_recompute_kinds import _V5E_LIMIT
+    from test_tpu_compile_regions import _step
+    t, d, f, layers_n = 8192, 2048, 8192, 2
+    monkeypatch.setattr(CF, "_device_limit", lambda ctx: _V5E_LIMIT)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.amp.amp_guard(True), fluid.program_guard(main, startup), \
+            fluid.scope_guard(fluid.Scope()), unique_name.guard("glu_"):
+        h = fluid.layers.data("x", [t, d])
+        for i in range(layers_n):
+            with fluid.layers.recompute():
+                h = h + gated_ffn(fluid.layers.rms_norm(h), f, "glu%d" % i)
+        loss = fluid.layers.mean(fluid.layers.square(h))
+        fluid.optimizer.Adam(1e-3).minimize(loss)
+        _, step, args, _ = _step(main, startup, loss.name, {
+            "x": np.zeros((1, t, d), np.float32)}, chip)
+        compiled = step.lower(*args).compile()
+    assert int(CF._PLAN.value(kind=CF.MUL_OUT, what="admitted")) \
+        == 2 * layers_n
+    _, rows = hlo.kernel_rows(compiled.as_text())
+    gated = lambda names: sorted(s for s in names
+                                 if s.startswith("silu_mul."))
+    products = [r for r in rows if r["dots"]]
+    assert len(gated({s for r in rows for s in r["scopes"]})) == layers_n
+    assert [(r["name"], gated(r["nested"])) for r in products
+            if gated(r["nested"])] == []
+    # three products a layer, each forward, by its operand and by its
+    # weight, the kept ones not again
+    assert sorted(d[5] for r in products for d in r["dots"]) \
+        == [2 * t * d * f] * (9 * layers_n)
+    downs = {"mul.%d" % (int(s.split(".")[1]) + 1)
+             for r in rows for s in gated(r["scopes"])}
+    by_weight = [r for r in products for dot in r["dots"]
+                 if hlo.scope_of(dot[0]) in downs
+                 and hlo.pass_of(dot[0]) == "bwd" and dot[4] == t]
+    assert len(by_weight) == layers_n
+    for row in by_weight:
+        assert any(s.startswith("adam.") for s in row["scopes"])
+        # hidden, the cotangent (bf16; float32 from the loss itself),
+        # the weight and Adam's two moments, and a few scalars
+        assert row["bytes_in"] <= 2 * t * f + 4 * t * d + 3 * 4 * f * d \
+            + 1024, row
+    assert min(r["bytes_in"] for r in by_weight) \
+        <= 2 * t * f + 2 * t * d + 3 * 4 * f * d + 1024
